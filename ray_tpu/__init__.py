@@ -9,6 +9,12 @@ lightweight so worker processes start fast; accelerator code paths
 (models/ops/parallel/train) import jax lazily.
 """
 
+from ray_tpu.utils.jaxtools import compile_cache_dir as _compile_cache_dir
+
+# the program's one compile-cache placement, exported before anything can
+# import jax so this process and every child it spawns agree on it
+_compile_cache_dir()
+
 from ray_tpu._private.worker import (
     available_resources,
     cancel,

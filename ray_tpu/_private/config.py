@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import json
 import os
+import tempfile
 from typing import Any, Dict
 
 _ENV_PREFIX = "RAY_TPU_"
@@ -129,7 +130,6 @@ _d("prestart_workers", 2)  # idle workers spawned at raylet start
 # stack once and forks ready workers on demand; lease grants ADOPT a warm
 # worker instead of paying a cold interpreter+import start-up
 _d("worker_zygote_enabled", True)
-_d("zygote_preimport_jax", False)  # pre-import jax in the zygote (threads!)
 _d("zygote_fork_timeout_s", 20.0)
 # warm default-runtime-env workers the replenish loop keeps forked AND
 # registered so a lease grant is pure adoption (0 disables replenish; the
@@ -225,5 +225,5 @@ _d("serve_proxy_port", 8000)
 _d("serve_health_strikes", 30)
 
 # --- logging / session ---
-_d("session_root", "/tmp/ray_tpu_sessions")
+_d("session_root", os.path.join(tempfile.gettempdir(), "ray_tpu_sessions"))
 _d("log_to_driver", True)

@@ -1,6 +1,7 @@
 """ctypes binding for the native arena object store (src/object_store).
 
-Builds ``libray_tpu_store.so`` with g++ on first use (cached in build/);
+Builds ``libray_tpu_store-<digest>.so`` with g++ on first use through the
+shared loader (``native_build.build_and_load``, cached in build/ by content);
 the raylet's ObjectStoreServer uses it as the allocation backend when
 available (config ``object_store_backend=auto|cpp|shm``). Workers map the
 arena file directly for zero-copy reads/writes.
@@ -10,49 +11,26 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Optional, Tuple
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "src", "object_store", "store.cc")
-_BUILD_DIR = os.path.join(_REPO_ROOT, "build")
-_LIB = os.path.join(_BUILD_DIR, "libray_tpu_store.so")
+_LIB = os.path.join(_REPO_ROOT, "build", "libray_tpu_store.so")
 
 _lock = threading.Lock()
 _lib = None
-_build_failed = False
-
-
-def _build() -> bool:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _LIB + ".tmp"]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(_LIB + ".tmp", _LIB)
-        return True
-    except (subprocess.CalledProcessError, subprocess.TimeoutExpired, OSError):
-        return False
 
 
 def load_lib() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
+    global _lib
+    from ray_tpu._private.native_build import build_and_load
+
     with _lock:
         if _lib is not None:
             return _lib
-        if _build_failed:
-            return None
-        if not os.path.exists(_LIB):
-            src_mtime = os.path.getmtime(_SRC) if os.path.exists(_SRC) else 0
-            if not os.path.exists(_SRC) or not _build():
-                _build_failed = True
-                return None
-        elif os.path.exists(_SRC) and os.path.getmtime(_SRC) > os.path.getmtime(_LIB):
-            _build()  # refresh; fall back to stale lib on failure
-        try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
-            _build_failed = True
+        lib = build_and_load(_SRC, _LIB)
+        if lib is None:
             return None
         lib.rts_open.restype = ctypes.c_void_p
         lib.rts_open.argtypes = [ctypes.c_char_p, ctypes.c_uint64, ctypes.c_int]

@@ -1256,9 +1256,24 @@ class GcsServer:
     async def _health_check_loop(self):
         period = RAY_CONFIG.health_check_period_ms / 1000.0
         timeout = RAY_CONFIG.health_check_timeout_ms / 1000.0
+        last = time.monotonic()
         while True:
             await asyncio.sleep(period)
             now = time.monotonic()
+            # how late THIS loop woke. While the GCS (or the whole host) was
+            # stalled no heartbeat could be taken in either, so that time
+            # says nothing about any node: a process opening a TPU freezes
+            # a v5e host for 5-6 s (measured, PERF.md PR 21), longer than
+            # the timeout, and the raylet's next heartbeat is only now on
+            # its way
+            own_lag = now - last - period
+            last = now
+            if own_lag > period:
+                logger.warning("health check loop woke %.1fs late; not "
+                               "counting that against any node", own_lag)
+                for node_id in self.node_last_seen:
+                    self.node_last_seen[node_id] += own_lag
+                continue
             for node_id, info in list(self.nodes.items()):
                 if info.alive and now - self.node_last_seen.get(node_id, now) > timeout:
                     await self._mark_node_dead(node_id, "health check timeout")

@@ -20,6 +20,7 @@ Blob layout inside a segment (written client-side so the store never copies):
 from __future__ import annotations
 
 import asyncio
+import logging
 import mmap
 import os
 import struct
@@ -27,6 +28,8 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from ray_tpu._private.config import RAY_CONFIG
+
+logger = logging.getLogger("ray_tpu.object_store")
 
 _MAGIC = 0x52545055  # 'RTPU'
 _ALIGN = 64
@@ -246,9 +249,13 @@ class ObjectStoreServer:
 
                 self.arena = CppArena(self.arena_name, self.capacity)
                 self._arena_view = ShmSegment(self.arena_name)
-            except Exception:
+            except Exception as e:
                 if backend == "cpp":
                     raise
+                logger.error(
+                    "object_store_backend=auto: native arena unavailable "
+                    "(%s); this node falls back to the Python shm-file "
+                    "store", e)
                 self.arena = None
 
     def _shm_name(self, oid: bytes, attempt: int = 0) -> str:

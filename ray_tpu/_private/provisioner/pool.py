@@ -224,8 +224,6 @@ class WorkerProvisioner:
         parent_sock, child_sock = socket.socketpair()
         cmd = [sys.executable, "-m", "ray_tpu._private.provisioner.zygote",
                "--control-fd", str(child_sock.fileno())]
-        if RAY_CONFIG.zygote_preimport_jax:
-            cmd.append("--preimport-jax")
         self._proc = subprocess.Popen(
             cmd, env=self.raylet._spawn_env,
             pass_fds=[child_sock.fileno()],

@@ -11,8 +11,10 @@ length-prefixed JSON frames (4-byte big-endian length):
                                                   "code": c}
 
 Fork safety: the zygote is strictly single-threaded and never runs an event
-loop — every import below must keep it that way (JAX starts worker threads,
-so it is only pre-imported behind ``zygote_preimport_jax``). The fork child
+loop — every import below must keep it that way. JAX is NEVER imported
+here: it starts threads, and a process image that has touched the chip's
+runtime must not be forked into workers that each need the chip for
+themselves. Workers import jax after the fork. The fork child
 closes the control fd, resets inherited signal/prctl state, and enters the
 shared ``worker_main.run_worker`` bootstrap; the parent reaps children with
 ``waitpid(WNOHANG)`` and streams exit events back to the raylet.
@@ -28,7 +30,7 @@ import traceback
 from ray_tpu._private.provisioner.framing import FrameReader, send_frame
 
 
-def preimport(preimport_jax: bool = False) -> list:
+def preimport() -> list:
     """Pay the import cost ONCE, before any fork: everything a worker needs
     at start-up (serialization, rpc, the worker runtime) plus the usual
     numeric stack. Returns the module names made resident (for the pong)."""
@@ -45,8 +47,6 @@ def preimport(preimport_jax: bool = False) -> list:
         "ray_tpu._private.wire",
         "ray_tpu._private.worker_main",
     ]
-    if preimport_jax:
-        mods.append("jax")
     loaded = []
     for mod in mods:
         try:
@@ -113,8 +113,8 @@ def _child_main(control_fd: int, args: dict, zygote_pid: int) -> "None":
         os._exit(code)
 
 
-def serve(control_fd: int, preimport_jax: bool = False) -> None:
-    loaded = preimport(preimport_jax)
+def serve(control_fd: int) -> None:
+    loaded = preimport()
     reader = FrameReader()
     my_pid = os.getpid()
     while True:
@@ -175,12 +175,11 @@ def main():
 
     parser = argparse.ArgumentParser()
     parser.add_argument("--control-fd", type=int, required=True)
-    parser.add_argument("--preimport-jax", action="store_true")
     args = parser.parse_args()
     # stdout/stderr are the raylet's worker log; keep our own chatter out of
     # the frame channel (which is a dedicated fd)
     try:
-        serve(args.control_fd, preimport_jax=args.preimport_jax)
+        serve(args.control_fd)
     except KeyboardInterrupt:  # raylint: disable=EXC001 clean ^C shutdown path
         pass
     # zygote exits quietly when the raylet goes away; forked children notice

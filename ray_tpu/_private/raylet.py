@@ -880,10 +880,25 @@ class Raylet:
                 "worker_pid": w.pid}
 
     def _release_lease(self, lease_id: str):
-        entry = self.leases.pop(lease_id, None)
+        entry = self.leases.get(lease_id)
         if entry is None:
             return
         w, resources, pool_key = entry
+        if resources.get("TPU", 0) > 0 and w.pid in self.workers \
+                and w.proc.poll() is None:
+            # a process that opened a chip holds it until it exits: it can
+            # neither idle in the pool (the next TPU lease may land on
+            # another worker, which then cannot open the chip) nor give
+            # its TPU back while alive. Kill it; the monitor loop calls
+            # back here once the process is gone, and only then is the
+            # chip leasable again.
+            try:
+                w.proc.kill()
+            except Exception as e:
+                logger.debug("kill of TPU worker pid %s at lease return "
+                             "failed: %s", w.pid, e)
+            return
+        del self.leases[lease_id]
         pg, bundle_index = wire.loads(pool_key)
         pool = self._lease_pool(pg, bundle_index)
         if pool is not None:
@@ -1025,6 +1040,16 @@ class Raylet:
         for res in reserved.values():
             for k, v in res.items():
                 back[k] = back.get(k, 0.0) + v
+        # chips still held by a live worker leased from this group are NOT
+        # free: re-home that TPU share onto the node pool, so it comes
+        # back when the holder's lease is released (= its process is gone,
+        # see _release_lease) and not a moment earlier
+        for lease_id, (w, res, pool_key) in list(self.leases.items()):
+            chips = res.get("TPU", 0)
+            if chips > 0 and wire.loads(pool_key)[0] == pg_id:
+                back["TPU"] = back.get("TPU", 0.0) - chips
+                self.leases[lease_id] = (w, {"TPU": chips},
+                                         wire.dumps((None, -1)))
         resources_add(self.available, back)
         for fut in self._lease_waiters:
             if not fut.done():

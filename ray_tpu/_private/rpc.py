@@ -186,17 +186,21 @@ class RpcServer:
 
     async def stop(self):
         if self._server:
-            self._server.close()
-            try:
-                await self._server.wait_closed()
-            except Exception as e:
-                logger.debug("server wait_closed failed: %s", e)
+            self._server.close()  # stop accepting
+        # connections first: since Python 3.12 wait_closed() waits until
+        # every connection is gone, so awaiting it with clients still
+        # attached (a raylet's GCS client, workers) never returns
         for conn in list(self.connections.values()):
             try:
                 conn.writer.close()
             except Exception as e:
                 logger.debug("closing connection to %s failed: %s",
                              conn.peer, e)
+        if self._server:
+            try:
+                await self._server.wait_closed()
+            except Exception as e:
+                logger.debug("server wait_closed failed: %s", e)
 
     async def _on_client(self, reader, writer):
         conn = ServerConnection(reader, writer)

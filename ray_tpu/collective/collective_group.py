@@ -259,11 +259,11 @@ class XlaGroup:
     def _shmap(self, fn, in_spec, out_spec):
         import jax
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         return jax.jit(shard_map(
             fn, mesh=self.mesh, in_specs=in_spec, out_specs=out_spec,
-            check_rep=False))
+            check_vma=False))
 
     def _op(self, name, builder):
         fn = self._cache.get(name)

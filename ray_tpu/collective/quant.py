@@ -362,7 +362,7 @@ def quantized_psum_scatter_1d(mesh, axis_name: str, codec: QuantCodec):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = int(np.prod([s for nme, s in zip(mesh.axis_names, mesh.devices.shape)
@@ -393,7 +393,7 @@ def quantized_psum_scatter_1d(mesh, axis_name: str, codec: QuantCodec):
             return jnp.sum(vals, axis=0).reshape(-1)[:seg_len]
 
     return jax.jit(shard_map(f, mesh=mesh, in_specs=P(axis_name),
-                             out_specs=P(axis_name), check_rep=False))
+                             out_specs=P(axis_name), check_vma=False))
 
 
 def xla_wire_bytes(n_elements: int, world: int, codec: Optional[QuantCodec]

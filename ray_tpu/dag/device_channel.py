@@ -39,7 +39,7 @@ class IciTransfer:
         self.shift = shift
         n = mesh.devices.size
         perm = [(i, (i + shift) % n) for i in range(n)]
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec = P(self.axis)
@@ -51,7 +51,7 @@ class IciTransfer:
             return lax.ppermute(x, axis, perm)
 
         self._fn = jax.jit(shard_map(
-            _step, mesh=mesh, in_specs=spec, out_specs=spec, check_rep=False))
+            _step, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False))
         self.key = (id(mesh), shift)
         _COMPILE_COUNTS[self.key] = _COMPILE_COUNTS.get(self.key, 0) + 1
 

@@ -65,6 +65,7 @@ class LLMConfig:
     model_id: str = "tiny"           # key into models.transformer.CONFIGS
     checkpoint_path: Optional[str] = None  # msgpack params (orbax/flax) dir
     tokenizer: str = "byte"          # "byte" or a HF tokenizer name
+    seed: int = 0                    # random-init weights + sampling stream
     engine_config: EngineConfig = field(default_factory=EngineConfig)
     # serve-level
     num_replicas: int = 1

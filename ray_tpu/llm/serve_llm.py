@@ -36,7 +36,7 @@ class LLMServer:
 
             params = loads_trusted(params_blob)
         self.config = config
-        self.engine = JaxLLMEngine(config, params=params)
+        self.engine = JaxLLMEngine(config, params=params, seed=config.seed)
         self._futures: Dict[str, asyncio.Future] = {}
         self._pump_task: Optional[asyncio.Task] = None
 
@@ -47,14 +47,17 @@ class LLMServer:
                 outputs = await loop.run_in_executor(None, self.engine.step)
                 for out in outputs:
                     if out.finished and out.request_id in self._futures:
+                        toks = [t for t in out.token_ids
+                                if t != self.engine.tokenizer.eos_token_id]
+                        # build the answer BEFORE the future leaves the
+                        # table: if this raises, the handler below must
+                        # still find the request and fail it, not orphan it
+                        result = {"token_ids": out.token_ids,
+                                  "text": self.engine.tokenizer.decode(toks),
+                                  "finish_reason": out.finish_reason}
                         fut = self._futures.pop(out.request_id)
                         if not fut.done():
-                            toks = [t for t in out.token_ids
-                                    if t != self.engine.tokenizer.eos_token_id]
-                            fut.set_result(
-                                {"token_ids": out.token_ids,
-                                 "text": self.engine.tokenizer.decode(toks),
-                                 "finish_reason": out.finish_reason})
+                            fut.set_result(result)
                 await asyncio.sleep(0)
         except Exception as e:
             # fail every pending request rather than hanging its caller
@@ -95,15 +98,17 @@ class LLMServer:
               if k in body}
         if "messages" in body:
             out = await self.chat(body["messages"], **kw)
-            return {"id": uuid.uuid4().hex, "object": "chat.completion",
-                    "choices": [{"index": 0,
-                                 "message": {"role": "assistant",
-                                             "content": out["text"]},
-                                 "finish_reason": out["finish_reason"]}]}
-        out = await self.completions(body.get("prompt", ""), **kw)
-        return {"id": uuid.uuid4().hex, "object": "text_completion",
-                "choices": [{"index": 0, "text": out["text"],
-                             "finish_reason": out["finish_reason"]}]}
+            choice = {"message": {"role": "assistant",
+                                  "content": out["text"]}}
+            kind = "chat.completion"
+        else:
+            out = await self.completions(body.get("prompt", ""), **kw)
+            choice = {"text": out["text"]}
+            kind = "text_completion"
+        choice.update(index=0, token_ids=out["token_ids"],
+                      finish_reason=out["finish_reason"])
+        return {"id": uuid.uuid4().hex, "object": kind, "choices": [choice],
+                "usage": {"completion_tokens": len(out["token_ids"])}}
 
     async def update_weights(self, store_name: str,
                              version: Optional[int] = None) -> dict:
@@ -155,6 +160,15 @@ class LLMServer:
 
     def engine_metrics(self) -> dict:
         return dict(self.engine.metrics)
+
+    def device_info(self) -> dict:
+        """The device, its memory high-water mark and the compile cache as
+        THIS replica process sees them — the replica is the only process of
+        a serve app that may touch the chip, so these facts cannot be read
+        from the driver."""
+        from ray_tpu.utils import device_facts
+
+        return device_facts()
 
 
 def build_llm_deployment(config: LLMConfig, params: Any = None,

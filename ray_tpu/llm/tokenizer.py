@@ -34,8 +34,11 @@ class ByteTokenizer:
         return [self.BOS] + ids if add_bos else ids
 
     def decode(self, ids: Sequence[int]) -> str:
+        # a model's vocab may be wider than this tokenizer's (the byte
+        # tokenizer under a 32000-row embedding): ids beyond the byte range
+        # have no text, like the specials
         data = bytes(i - self._SPECIALS for i in ids
-                     if i >= self._SPECIALS)
+                     if self._SPECIALS <= i < self.vocab_size)
         return data.decode("utf-8", errors="replace")
 
 

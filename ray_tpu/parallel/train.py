@@ -249,14 +249,33 @@ class TrainStepBundle:
             init_fn,
             out_shardings=(self.param_shardings, self.opt_shard_shardings))
 
-        def loss_fn(params, tokens, targets, mask):
+        def local_loss_fn(params, tokens, targets, mask):
             # "losses" is valid for dense models too (empty -> aux sums to 0)
             logits, cols = self.model.apply(
                 {"params": params}, tokens, mutable=["losses"])
             aux = sum(jax.tree.leaves(cols.get("losses", {})))
             return lm_loss(logits, targets, mask) + cfg.moe_aux_coef * aux
 
-        self._loss_fn = loss_fn
+        # the shard_map tier traces this one: already per-device there
+        self._loss_fn = local_loss_fn
+
+        from ray_tpu.ops.attention import partitioned_over
+
+        def on_mesh(fn):
+            """For functions the jit-partitioned programs below trace: on a
+            mesh of several devices the attention kernel must know the
+            layout of its operands (Mosaic kernels are not
+            auto-partitionable)."""
+            if mesh.size == 1:
+                return fn
+
+            def traced(*args):
+                with partitioned_over(mesh, ("data", "fsdp"), "tensor"):
+                    return fn(*args)
+
+            return traced
+
+        loss_fn = on_mesh(local_loss_fn)
 
         def train_step(params, opt_state, batch):
             loss, grads = jax.value_and_grad(loss_fn)(
@@ -365,7 +384,7 @@ class TrainStepBundle:
                 {"params": params}, batch["tokens"], mutable=["losses"])
             return lm_loss(logits, batch["targets"], batch.get("mask"))
 
-        self.eval_step = jax.jit(eval_step)
+        self.eval_step = jax.jit(on_mesh(eval_step))
 
         # shape/dtype-keyed compile detection for the goodput ledger: a
         # batch key this bundle has not dispatched before means jit will
@@ -515,7 +534,7 @@ class TrainStepBundle:
             return
         jax = import_jax()
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh = self.mesh
@@ -547,7 +566,7 @@ class TrainStepBundle:
                 f, mesh=mesh,
                 in_specs=(P(), bspec, bspec, bspec),
                 out_specs=(P("data"), P("data"), grad_specs),
-                check_rep=False)(params, tokens, targets, mask)
+                check_vma=False)(params, tokens, targets, mask)
 
         self._fwd_bwd_local = jax.jit(
             local_fb,
@@ -646,7 +665,7 @@ class TrainStepBundle:
             in_specs = tuple(P("data") for _ in paths)
             out_specs = tuple(out_spec(d, p) for d, p in zip(dims, paths))
             return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, check_rep=False))
+                                     out_specs=out_specs, check_vma=False))
 
         self._bucket_programs = [
             (bucket, make_bucket_rs(bucket.paths))

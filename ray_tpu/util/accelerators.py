@@ -2,17 +2,24 @@
 
 Detects chips per host and slice metadata so the raylet can advertise
 ``TPU`` resources and slice labels (``TPUAcceleratorManager`` at tpu.py:267,
-pod-type inference :151). Detection order:
+pod-type inference :151). A chip belongs to one process at a time, so the
+raylet (a daemon that never exits) must learn the count WITHOUT opening a
+chip: nothing here imports jax. Detection order:
 
-1. explicit env overrides (``RAY_TPU_CHIPS``, ``TPU_VISIBLE_CHIPS``),
-2. GCE TPU-VM environment variables (``TPU_ACCELERATOR_TYPE``,
-   ``TPU_WORKER_ID``, set by the TPU runtime on real TPU VMs),
-3. jax device enumeration — only when ``RAY_TPU_DETECT_TPU=1``, because
-   importing jax is slow and must not happen in the raylet by default.
+1. explicit overrides (``RAY_TPU_CHIPS``, ``TPU_VISIBLE_CHIPS``),
+2. the chips' device nodes — one ``/dev/accel<N>`` per chip under the accel
+   driver, one ``/dev/vfio/<N>`` group per chip under vfio (what a v5e host
+   exposes). This counts the chips actually attached to THIS machine,
+3. ``TPU_ACCELERATOR_TYPE`` (GCE TPU-VM metadata). Last, because it names
+   the slice the image was built for, not what is attached: a one-chip
+   machine cut from a ``v5litepod-4`` image still says 4.
+
+The remaining TPU_* variables only become labels.
 """
 
 from __future__ import annotations
 
+import glob
 import os
 from typing import Dict, Tuple
 
@@ -41,7 +48,16 @@ def _chips_for_accelerator_type(acc_type: str) -> int:
     return min(total, per_host)
 
 
-def detect_tpu() -> Tuple[int, Dict[str, str]]:
+def _count_device_nodes(dev_root: str = "/dev") -> int:
+    """Chips attached to this host, read off their device nodes (listing a
+    directory opens no chip): ``accel<N>`` under the accel driver, else one
+    numbered IOMMU group per chip under ``vfio/`` (the bare ``vfio/vfio``
+    node is the container device, not a chip)."""
+    return len(glob.glob(os.path.join(dev_root, "accel[0-9]*"))
+               or glob.glob(os.path.join(dev_root, "vfio", "[0-9]*")))
+
+
+def detect_tpu(dev_root: str = "/dev") -> Tuple[int, Dict[str, str]]:
     """Returns (num_chips_on_host, labels)."""
     labels: Dict[str, str] = {}
     env_chips = os.environ.get("RAY_TPU_CHIPS") or os.environ.get("TPU_VISIBLE_CHIPS")
@@ -54,24 +70,13 @@ def detect_tpu() -> Tuple[int, Dict[str, str]]:
     worker_id = os.environ.get("TPU_WORKER_ID", "")
     topology = os.environ.get("TPU_TOPOLOGY", "")
 
-    chips = 0
     if env_chips:
-        try:
-            chips = len(env_chips.split(",")) if "," in env_chips else int(env_chips)
-        except ValueError:
-            chips = 0
-    elif acc_type:
-        chips = _chips_for_accelerator_type(acc_type)
-    elif os.environ.get("RAY_TPU_DETECT_TPU") == "1":
-        try:
-            import jax
-
-            devices = [d for d in jax.devices() if d.platform == "tpu"]
-            chips = len(devices)
-            if devices and not acc_type:
-                acc_type = getattr(devices[0], "device_kind", "tpu")
-        except Exception:
-            chips = 0
+        # a malformed override is an error, not "no TPU"
+        chips = len(env_chips.split(",")) if "," in env_chips else int(env_chips)
+    else:
+        chips = _count_device_nodes(dev_root)
+        if not chips and acc_type:
+            chips = _chips_for_accelerator_type(acc_type)
 
     if chips:
         if slice_name:

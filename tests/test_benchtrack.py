@@ -17,15 +17,17 @@ from tools import benchtrack  # noqa: E402
 
 
 def test_check_green_on_repo_artifacts():
-    """The tier-1 wiring: every checked-in BENCH/STRESS/SERVE/PIPE/OBS
-    artifact clears its per-metric threshold (and the OBS absolute
-    overhead bars)."""
+    """The tier-1 wiring: every checked-in STRESS/SERVE/PIPE/OBS artifact
+    clears its per-metric threshold (and the OBS absolute overhead bars).
+    No BENCH artifact is checked in: the tree holds no chip record yet (the
+    ledger the driver writes will be it); the BENCH extractor stays tested
+    on synthetic artifacts below."""
     failures, passes = benchtrack.check(str(REPO_ROOT))
     assert not failures, "\n".join(failures)
     # the gate saw real artifacts, it did not vacuously pass on nothing
     assert len(passes) >= 10
     families = {line.split()[0] for line in passes}
-    assert {"BENCH", "STRESS", "SERVE", "PIPE", "OBS"} <= families
+    assert {"STRESS", "SERVE", "PIPE", "OBS"} <= families
 
 
 def test_cli_check_exit_codes(tmp_path):

@@ -1,0 +1,155 @@
+"""The main path's kernels and programs compile for the v5e — asked of the
+TPU compiler itself, for a chip that is described and not attached.
+
+Interpret mode cannot show what these show: a slice that is not aligned to
+the tiling, too much fast memory in a kernel, a Mosaic kernel the
+partitioner is asked to split. A compile that passes is not a chip run:
+nothing here executes, so nothing here says a result is right or fast.
+
+All of them live in THIS one file and compile in the test's own process:
+the process that describes the topology loads the TPU's library and keeps it
+until it exits, so a second file (another xdist worker) or a child process
+could not. The topology is described inside a fixture, never at import.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import CONFIGS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep these compiles out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _qkv(shape, sharding):
+    return [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)] * 3
+
+
+def test_flash_forward_1b_shape(one_chip):
+    from ray_tpu.ops.attention import flash_attention
+
+    compiled = jax.jit(lambda q, k, v: flash_attention(q, k, v, True)).lower(
+        *_qkv((4, 2048, 16, 128), one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape,bwd_env", [
+    ((8, 1024, 16, 64), None),        # 350m: pallas backward by the rule
+    ((4, 2048, 16, 128), "pallas"),   # 1b: pallas backward when asked for
+], ids=["hd64", "hd128"])
+def test_flash_forward_and_pallas_backward(one_chip, monkeypatch, shape,
+                                           bwd_env):
+    from ray_tpu.ops.attention import flash_attention
+
+    if bwd_env:
+        monkeypatch.setenv("RAY_TPU_FLASH_BWD", bwd_env)
+    else:
+        monkeypatch.delenv("RAY_TPU_FLASH_BWD", raising=False)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(shape, one_chip)).compile()
+    # the forward, the dq kernel and the dk/dv kernel
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def _on(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def test_engine_decode_and_prefill_1b_widths(one_chip):
+    """The engine's two programs at 1b widths (depth cut to 2), at the
+    smoke's geometry: 8 slots x 2048; prefill must carry the flash kernel."""
+    import flax.linen as nn
+
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.llm.config import EngineConfig
+    from ray_tpu.models.transformer import Transformer
+
+    e = EngineConfig(max_num_seqs=8, max_model_len=2048)
+    # "auto" asks the attached backend, which is the CPU here: steer the
+    # dispatch the way a TPU backend would
+    cfg = dataclasses.replace(CONFIGS["1b"], n_layers=2,
+                              attention_impl="flash")
+    params = _on(jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))), one_chip)
+    cache = _on(jax.eval_shape(
+        lambda: mr.init_cache(cfg, e.num_pages, e.page_size)), one_chip)
+    B, MP = e.max_num_seqs, e.pages_per_seq
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+    decode = mr.decode_step.lower(params, cfg, cache, i32(B), i32(B),
+                                  i32(B, MP), active).compile()
+    assert decode.memory_analysis().temp_size_in_bytes < 16 << 30
+    prefill = mr.prefill.lower(params, cfg, cache, i32(B, 1024), i32(B),
+                               i32(B, MP)).compile()
+    assert "tpu_custom_call" in prefill.as_text()
+
+
+def test_sharded_update_step_partitions_over_four_chips(topo):
+    """The data-parallel sharded-update step on a data=4 mesh of described
+    chips, 1b widths (depth cut to 2): the flash kernel must survive the
+    partitioner (it is wrapped in a shard_map; bare, the compiler refuses:
+    "Mosaic kernels cannot be automatically partitioned") and the program
+    must carry the grad reduce-scatter and the param all-gather."""
+    from ray_tpu.parallel import TrainStepBundle, create_mesh, make_optimizer
+
+    cfg = dataclasses.replace(CONFIGS["1b"], n_layers=2, max_seq_len=2048,
+                              attention_impl="flash")
+    mesh = create_mesh({"data": 4, "fsdp": 1, "seq": 1, "tensor": 1,
+                        "expert": 1}, devices=topo.devices)
+    bundle = TrainStepBundle(
+        cfg, mesh, shard_update=True,
+        optimizer_factory=lambda fn: make_optimizer(clip_spec_fn=fn))
+
+    def sds(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+
+    batch = {k: jax.ShapeDtypeStruct((4, 2048), dt,
+                                     sharding=bundle.batch_sharding)
+             for k, dt in (("tokens", jnp.int32), ("targets", jnp.int32),
+                           ("mask", jnp.float32))}
+    compiled = bundle._fused_step_sharded.lower(
+        sds(bundle._abstract_params, bundle.param_shardings),
+        sds(bundle._abstract_opt, bundle.opt_shard_shardings),
+        batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "reduce-scatter" in text and "all-gather" in text

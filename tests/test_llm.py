@@ -162,6 +162,41 @@ def test_serve_llm(ray_local):
     serve_api.shutdown()
 
 
+def test_llm_server_answers_when_model_vocab_exceeds_tokenizer():
+    """What the first chip run hit at ``1b`` (vocab 32000 under the byte
+    tokenizer's 259): generated ids beyond the tokenizer's range must decode
+    to no text — not raise inside the pump and orphan the request — and the
+    answer carries the token ids themselves."""
+    import asyncio
+
+    from ray_tpu.llm.serve_llm import LLMServer
+    from ray_tpu.llm.tokenizer import ByteTokenizer
+
+    assert ByteTokenizer().decode([1, 72 + 3, 300, 31999, 105 + 3]) == "Hi"
+    cfg = make_config()
+    cfg.model_overrides = dict(cfg.model_overrides, vocab_size=2048)
+    server = LLMServer(cfg)
+
+    async def ask():
+        return await asyncio.wait_for(
+            server({"prompt": "hello", "max_tokens": 12}), 120)
+
+    out = asyncio.run(ask())
+    ids = out["choices"][0]["token_ids"]
+    assert len(ids) == out["usage"]["completion_tokens"] == 12
+    assert any(t >= ByteTokenizer.vocab_size for t in ids), ids
+
+    # a failure while building an answer fails THAT request, it does not hang
+    server.engine.tokenizer.decode = lambda ids: 1 / 0
+
+    async def ask_broken():
+        return await asyncio.wait_for(
+            server({"prompt": "hello", "max_tokens": 2}), 120)
+
+    with pytest.raises(RuntimeError, match="engine step failed"):
+        asyncio.run(ask_broken())
+
+
 @pytest.mark.isolated
 def test_data_llm_processor(ray_local):
     from ray_tpu import data as rdata

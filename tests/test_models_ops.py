@@ -41,7 +41,7 @@ def test_flash_grads_match():
 
 
 def test_ring_attention_matches_reference():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = create_mesh({"seq": 8})
@@ -53,14 +53,14 @@ def test_ring_attention_matches_reference():
     ring = shard_map(
         lambda q, k, v: ring_attention(q, k, v, "seq", causal=True),
         mesh=mesh, in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"),
-        check_rep=False)
+        check_vma=False)
     out = ring(q, k, v)
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3, rtol=2e-2)
 
 
 def test_ulysses_matches_reference():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = create_mesh({"seq": 2}, devices=jax.devices()[:2])
@@ -71,7 +71,7 @@ def test_ulysses_matches_reference():
     uly = shard_map(
         lambda q, k, v: ulysses_attention(q, k, v, "seq", causal=True),
         mesh=mesh, in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"),
-        check_rep=False)
+        check_vma=False)
     out = uly(q, k, v)
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3, rtol=2e-2)

@@ -32,7 +32,13 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
+
+# A CPU-tier tool (it prices host-side instrumentation on a toy step): this
+# process runs JAX next to a cluster of workers, and a chip belongs to one
+# process at a time — pin every process to the CPU backend before jax loads.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def _bench_span_record(n: int = 20_000) -> float:
@@ -192,8 +198,6 @@ def main(argv=None):
     parser.add_argument("--tasks", type=int, default=200)
     parser.add_argument("--rounds", type=int, default=3)
     args = parser.parse_args(argv)
-
-    import os
 
     # fast history sampling so the scrape bench has points to serve
     os.environ.setdefault("RAY_TPU_METRICS_HISTORY_INTERVAL_S", "0.5")

@@ -45,6 +45,11 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# A CPU-tier tool: this process AND every stage actor run JAX, and a chip
+# belongs to one process at a time — so the whole gang is pinned to the
+# (virtual-device) CPU backend before anything imports jax.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 
 def _bench_cfg(n_layers: int):
     from ray_tpu.models.transformer import CONFIGS

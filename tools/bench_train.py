@@ -174,6 +174,10 @@ def main() -> int:
 
         started = not ray_tpu.is_initialized()
         if started:
+            # this process has touched JAX and holds whatever chips there
+            # are; the reducer's workers run the CPU collective tier, so
+            # pin every child to the CPU backend (children inherit)
+            os.environ["JAX_PLATFORMS"] = "cpu"
             ray_tpu.init(num_cpus=2)
         try:
             result.update(bench_reducer())

@@ -454,7 +454,7 @@ _TRACING_TRANSFORMS = {
     "jax.jit", "jax.grad", "jax.value_and_grad", "jax.vmap", "jax.pmap",
     "jax.checkpoint", "jax.remat", "jax.custom_jvp", "jax.custom_vjp",
     "jax.lax.scan", "jax.lax.while_loop", "jax.lax.cond",
-    "jax.experimental.shard_map.shard_map", "shard_map.shard_map",
+    "jax.shard_map",
 }
 
 
