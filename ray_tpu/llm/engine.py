@@ -14,20 +14,51 @@ two compiled XLA programs (prefill per shape bucket, one decode step):
 
 The engine is synchronous and single-threaded by design — actor wrappers
 (serve_llm.LLMServer) give it an async front end.
+
+What the engine says about itself (``LLMServer.engine_metrics()`` returns a
+copy of ``engine.metrics``: flat, numeric, only ever growing, every key there
+from ``__init__``, so two snapshots subtract):
+
+- counts: ``steps`` (calls of ``step()`` that ran a program),
+  ``prefill_steps``, ``decode_steps``, ``admitted``, ``prefill_tokens`` (real
+  prompt positions) against ``prefill_batch_tokens`` (``B x S`` of every
+  prefill call: what the device computes), ``generated_tokens``,
+  ``preempted``, ``compiles`` (first use of a prefill bucket or of decode);
+- host milliseconds (``perf_counter_ns``): ``step_ms`` = ``host_ms`` +
+  ``readback_ms`` (blocked on the device in ``np.asarray(tokens)``); the
+  phases ``admit_ms``, ``prefill_dispatch_ms``, ``decode_dispatch_ms``,
+  ``sample_dispatch_ms``, ``readback_ms``, ``emit_ms`` add up to ``step_ms``;
+  ``between_steps_ms`` is the caller's time from one ``step()`` to the next
+  while work was left;
+- per request, summed: ``queue_wait_ms`` (``add_request`` to first
+  admission) and ``ttft_ms`` (``add_request`` to first token).
+
+The same boundaries are spans on the profiler's clock
+(``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
+that owns the engine shows ``ray_tpu/engine.step`` on the host plane and,
+inside it, ``engine.admit``, ``.prefill_dispatch`` (arguments ``bucket``,
+``admitted``), ``.decode_dispatch``, ``.sample_dispatch``, ``.readback``,
+``.emit`` and, around a shape's first use, ``.compile``. With
+``RAY_TPU_ENABLE_TRACING`` a finished request also leaves ``engine.queued``,
+``engine.prefill`` and ``engine.decode`` spans (``request_id``) under the
+span that called ``add_request`` (``/api/timeline``). An operator's guide is
+in ``ray_tpu/serve/README.md``.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import math
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ray_tpu.llm.config import EngineConfig, LLMConfig, SamplingParams
 from ray_tpu.llm.tokenizer import get_tokenizer
+from ray_tpu.util import goodput, tracing
 
 
 @dataclasses.dataclass
@@ -40,6 +71,12 @@ class _Request:
     pages: List[int] = dataclasses.field(default_factory=list)
     finished: bool = False
     finish_reason: Optional[str] = None
+    # perf_counter seconds; 0.0 = not yet
+    t_added: float = dataclasses.field(default_factory=time.perf_counter)
+    t_admitted: float = 0.0
+    t_first_token: float = 0.0
+    # tracing.current_context() of add_request's caller
+    trace_ctx: Optional[Tuple[str, str]] = None
 
     @property
     def cache_tokens(self) -> List[int]:
@@ -96,8 +133,20 @@ class JaxLLMEngine:
         self._waiting: collections.deque[_Request] = collections.deque()
         self._requests: Dict[str, _Request] = {}
         self._rng = jax.random.PRNGKey(seed)
-        self.metrics = {"prefill_tokens": 0, "decode_steps": 0,
-                        "generated_tokens": 0, "preempted": 0}
+        self._compile_watch = goodput.CompileWatch()
+        # perf_counter_ns at the end of the last step() that left work
+        self._step_ended_ns: Optional[int] = None
+        # see the module docstring; snapshots are subtracted key by key, so
+        # every key is here from the start and none ever decreases
+        self.metrics = {
+            "prefill_tokens": 0, "decode_steps": 0, "generated_tokens": 0,
+            "preempted": 0, "steps": 0, "prefill_steps": 0, "admitted": 0,
+            "prefill_batch_tokens": 0, "compiles": 0,
+            "step_ms": 0.0, "host_ms": 0.0, "readback_ms": 0.0,
+            "admit_ms": 0.0, "prefill_dispatch_ms": 0.0,
+            "decode_dispatch_ms": 0.0, "sample_dispatch_ms": 0.0,
+            "emit_ms": 0.0, "between_steps_ms": 0.0,
+            "queue_wait_ms": 0.0, "ttft_ms": 0.0}
 
     # -- params ------------------------------------------------------------
 
@@ -135,7 +184,8 @@ class JaxLLMEngine:
                 f"request needs {need_total} KV pages but the engine has "
                 f"{self.ecfg.num_pages - 1}; raise num_pages or lower "
                 f"max_tokens/prompt length")
-        req = _Request(request_id, tokens, params)
+        req = _Request(request_id, tokens, params,
+                       trace_ctx=tracing.current_context())
         self._requests[request_id] = req
         self._waiting.append(req)
 
@@ -223,66 +273,127 @@ class JaxLLMEngine:
     def _sample(self, logits) -> np.ndarray:
         import jax.numpy as jnp
 
-        steps = np.array(
-            [len(s.generated) if s is not None else 0 for s in self._slots],
-            np.int32)
-        toks = self._mr.sample_tokens(
-            logits, self._next_rng(), jnp.asarray(self._temps),
-            jnp.asarray(self._top_ks), jnp.asarray(self._top_ps),
-            jnp.asarray(self._seeds), jnp.asarray(steps),
-            max_top_k=self.ecfg.max_top_k)
-        return np.asarray(toks)
+        with self._phase("sample_dispatch"):
+            steps = np.array(
+                [len(s.generated) if s is not None else 0
+                 for s in self._slots], np.int32)
+            toks = self._mr.sample_tokens(
+                logits, self._next_rng(), jnp.asarray(self._temps),
+                jnp.asarray(self._top_ks), jnp.asarray(self._top_ps),
+                jnp.asarray(self._seeds), jnp.asarray(steps),
+                max_top_k=self.ecfg.max_top_k)
+        with self._phase("readback"):  # blocks until the device is done
+            return np.asarray(toks)
 
     # -- the step ----------------------------------------------------------
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **attrs):
+        """One phase of ``step()``: the span ``engine.<name>`` on the
+        profiler's clock and its host time in ``metrics[<name>_ms]``."""
+        t0 = time.perf_counter_ns()
+        try:
+            with tracing.annotate("engine." + name, **attrs):
+                yield
+        finally:
+            self.metrics[name + "_ms"] += (time.perf_counter_ns() - t0) / 1e6
+
+    def _first_use(self, program: str, bucket: int = 0):
+        """Around a model program's call: the span ``engine.compile`` when
+        this engine has not yet called it at this shape (jit traces and
+        compiles, or reads its cache, before it returns)."""
+        if self._compile_watch.observe(program, (bucket,)) is None:
+            return contextlib.nullcontext()
+        self.metrics["compiles"] += 1
+        return tracing.annotate("engine.compile", program=program,
+                                bucket=bucket)
 
     def step(self, decode: bool = True) -> List[RequestOutput]:
         """One scheduling step. ``decode=False`` runs only the admit+prefill
         phase — the prefill side of PD disaggregation (reference serving
         pattern: serving_patterns/prefill_decode/pd_server.py:31)."""
+        m = self.metrics
+        t0 = time.perf_counter_ns()
+        if self._step_ended_ns is not None:
+            m["between_steps_ms"] += (t0 - self._step_ended_ns) / 1e6
+        readback0 = m["readback_ms"]
+        programs0 = m["prefill_steps"] + m["decode_steps"]
+        with tracing.annotate("engine.step"):
+            outputs = self._step(decode)
+        t1 = time.perf_counter_ns()
+        step_ms = (t1 - t0) / 1e6
+        if m["prefill_steps"] + m["decode_steps"] > programs0:
+            m["steps"] += 1
+        m["step_ms"] += step_ms
+        m["host_ms"] += step_ms - (m["readback_ms"] - readback0)
+        self._step_ended_ns = t1 if self.has_unfinished() else None
+        return outputs
+
+    def _step(self, decode: bool) -> List[RequestOutput]:
         import jax.numpy as jnp
 
         outputs: List[RequestOutput] = []
-        e, mr = self.ecfg, self._mr
+        e, mr, m = self.ecfg, self._mr, self.metrics
         B = e.max_num_seqs
 
         # 1) admit + batched prefill (one bucketed program, full-B batch)
-        admitted = self._try_admit()
+        with self._phase("admit"):
+            admitted = self._try_admit()
+            if admitted:
+                now = time.perf_counter()
+                max_len = max(len(r.cache_tokens) for r in admitted)
+                S = self._prefill_bucket(max_len)
+                toks = np.zeros((B, S), np.int32)
+                lens = np.zeros(B, np.int32)
+                for r in admitted:
+                    full = r.cache_tokens
+                    toks[r.slot, :len(full)] = full
+                    lens[r.slot] = len(full)
+                    if not r.t_admitted:  # not a re-admission after preemption
+                        r.t_admitted = now
+                        m["queue_wait_ms"] += (now - r.t_added) * 1e3
         if admitted:
-            max_len = max(len(r.cache_tokens) for r in admitted)
-            S = self._prefill_bucket(max_len)
-            toks = np.zeros((B, S), np.int32)
-            lens = np.zeros(B, np.int32)
-            for r in admitted:
-                full = r.cache_tokens
-                toks[r.slot, :len(full)] = full
-                lens[r.slot] = len(full)
-            logits, self.cache = mr.prefill(
-                self.params, self.mcfg, self.cache, jnp.asarray(toks),
-                jnp.asarray(lens), jnp.asarray(self._block_tables))
+            with self._phase("prefill_dispatch", bucket=S,
+                             admitted=len(admitted)), \
+                    self._first_use("prefill", S):
+                logits, self.cache = mr.prefill(
+                    self.params, self.mcfg, self.cache, jnp.asarray(toks),
+                    jnp.asarray(lens), jnp.asarray(self._block_tables))
             toks_np = self._sample(logits)
-            self.metrics["prefill_tokens"] += int(lens.sum())
-            for r in admitted:
-                self._active[r.slot] = True
-                self._emit(r, int(toks_np[r.slot]), outputs)
+            m["prefill_steps"] += 1
+            m["admitted"] += len(admitted)
+            m["prefill_tokens"] += int(lens.sum())
+            m["prefill_batch_tokens"] += B * S
+            with self._phase("emit"):
+                for r in admitted:
+                    self._active[r.slot] = True
+                    self._emit(r, int(toks_np[r.slot]), outputs)
 
         # 2) one decode step for all active slots
         if decode and self._active.any():
-            # page-boundary allocation; preempt to waiting on exhaustion
-            for req in [s for s in self._slots if s is not None]:
-                if self._active[req.slot] and not self._ensure_page(req):
-                    self.metrics["preempted"] += 1
-                    self._requeue(req)
-            if self._active.any():
-                logits, self.cache = mr.decode_step(
-                    self.params, self.mcfg, self.cache,
-                    jnp.asarray(self._last_tokens), jnp.asarray(self._seq_lens),
-                    jnp.asarray(self._block_tables), jnp.asarray(self._active))
+            with self._phase("decode_dispatch"):
+                # page-boundary allocation; preempt to waiting on exhaustion
+                for req in [s for s in self._slots if s is not None]:
+                    if self._active[req.slot] and not self._ensure_page(req):
+                        m["preempted"] += 1
+                        self._requeue(req)
+                decoding = bool(self._active.any())
+                if decoding:
+                    with self._first_use("decode"):
+                        logits, self.cache = mr.decode_step(
+                            self.params, self.mcfg, self.cache,
+                            jnp.asarray(self._last_tokens),
+                            jnp.asarray(self._seq_lens),
+                            jnp.asarray(self._block_tables),
+                            jnp.asarray(self._active))
+            if decoding:
                 toks_np = self._sample(logits)
-                self.metrics["decode_steps"] += 1
-                for req in list(self._slots):
-                    if req is not None and self._active[req.slot]:
-                        self._seq_lens[req.slot] += 1
-                        self._emit(req, int(toks_np[req.slot]), outputs)
+                m["decode_steps"] += 1
+                with self._phase("emit"):
+                    for req in list(self._slots):
+                        if req is not None and self._active[req.slot]:
+                            self._seq_lens[req.slot] += 1
+                            self._emit(req, int(toks_np[req.slot]), outputs)
         return outputs
 
     def _requeue(self, req: _Request) -> None:
@@ -297,6 +408,9 @@ class JaxLLMEngine:
         req.generated.append(token)
         self._last_tokens[req.slot] = token
         self.metrics["generated_tokens"] += 1
+        if not req.t_first_token:
+            req.t_first_token = time.perf_counter()
+            self.metrics["ttft_ms"] += (req.t_first_token - req.t_added) * 1e3
         eos = self.tokenizer.eos_token_id
         total = len(req.prompt_tokens) + len(req.generated)
         if token == eos or token in req.params.stop_token_ids:
@@ -308,9 +422,27 @@ class JaxLLMEngine:
         if req.finished:
             self._release(req)
             self._requests.pop(req.request_id, None)
+            if tracing.enabled():
+                self._record_request_spans(req)
         outputs.append(RequestOutput(
             req.request_id, list(req.generated), req.finished,
             req.finish_reason))
+
+    def _record_request_spans(self, req: _Request) -> None:
+        """A finished request's life as three spans in the GCS trace table,
+        children of the span that called ``add_request``."""
+        now = time.perf_counter()
+        wall = time.time() - now  # perf_counter -> the spans' wall clock
+        ids = {}
+        if req.trace_ctx is not None:
+            ids = {"trace_id": req.trace_ctx[0], "parent_id": req.trace_ctx[1]}
+        for name, start, end in (
+                ("engine.queued", req.t_added, req.t_admitted),
+                ("engine.prefill", req.t_admitted, req.t_first_token),
+                ("engine.decode", req.t_first_token, now)):
+            tracing.record_span(name, start + wall, end + wall,
+                                category="llm", request_id=req.request_id,
+                                **ids)
 
     # -- PD disaggregation (KV page export / import) -----------------------
     # Reference: serving_patterns/prefill_decode/pd_server.py + the vLLM
@@ -377,7 +509,9 @@ class JaxLLMEngine:
         if not free_slots or len(self._free_pages) < n_pages:
             raise RuntimeError("decode engine has no capacity; retry")
         req = _Request(state["request_id"], list(state["prompt_tokens"]),
-                       state["params"])
+                       state["params"], trace_ctx=tracing.current_context())
+        # queued and prefilled elsewhere: neither wait is this engine's
+        req.t_admitted = req.t_first_token = req.t_added
         req.generated = list(state["generated"])
         req.slot = free_slots[0]
         req.pages = [self._free_pages.popleft() for _ in range(n_pages)]

@@ -1,12 +1,16 @@
-"""Attention ops: pallas TPU flash-attention forward + reference path.
+"""Attention ops: pallas TPU flash-attention forward and backward, and the
+pure-XLA reference path.
 
-The MXU-friendly hot op of the flagship model. The pallas kernel implements
-the standard online-softmax flash pattern (one (batch*head, q-block) program,
-fori_loop over k-blocks held in VMEM); the backward pass recomputes with the
-reference implementation (flash-bwd kernel is a later-round optimization —
-rematerialized bwd keeps HBM usage flat at the cost of one extra forward).
+The MXU-friendly hot op of the flagship model. Three pallas kernels, named so
+that a device trace shows them under one key each: ``flash_fwd`` (the
+standard online-softmax flash pattern: one (batch*head, q-block) program,
+fori_loop over k-blocks held in VMEM; saves the row logsumexp),
+``flash_bwd_dq`` and ``flash_bwd_dkv`` (FlashAttention-2 style, softmax
+rebuilt from the saved logsumexp). ``_use_pallas_bwd`` picks the backward:
+the pallas pair at head_dim <= 64, a rematerialised backward through
+``reference_attention`` at 128 and above.
 
-CI runs the kernel in pallas interpret mode on CPU (SURVEY.md §4 implication:
+CI runs the kernels in pallas interpret mode on CPU (SURVEY.md §4 implication:
 every accelerator feature needs a hardware-free tier).
 """
 
@@ -130,6 +134,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool,
             jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return _from_bh(out, B, H), lse
 
@@ -255,6 +260,7 @@ def flash_attention_bwd(q, k, v, o, lse, g, causal: bool,
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **common),
@@ -276,6 +282,7 @@ def flash_attention_bwd(q, k, v, o, lse, g, causal: bool,
             jax.ShapeDtypeStruct((B * H, S, D), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse, delta)
     return (_from_bh(dq, B, H), _from_bh(dk, B, H), _from_bh(dv, B, H))
 

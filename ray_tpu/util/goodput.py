@@ -43,6 +43,8 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, Optional, Tuple
 
+from ray_tpu.util.tracing import annotate
+
 __all__ = [
     "BUCKETS", "CompileWatch", "add", "batch_key", "count", "enabled",
     "flush_payload", "note_mfu", "region", "reset", "reset_after_fork",
@@ -174,25 +176,28 @@ def region(bucket: str):
     """Attribute the enclosed wall time to ``bucket``. Nesting is
     exclusive: a nested region's full duration (its own time plus its
     children's) is subtracted from the parent frame, so concurrent-with
-    -nothing code attributes each second to exactly one bucket."""
-    if not enabled():
-        yield
-        return
-    stack = getattr(_tls, "stack", None)
-    if stack is None:
-        stack = _tls.stack = []
-    frame = [bucket, time.perf_counter(), 0.0]  # bucket, t0, child_s
-    stack.append(frame)
-    try:
-        yield
-    finally:
-        stack.pop()
-        dt = time.perf_counter() - frame[1]
-        own = max(0.0, dt - frame[2])
-        with _lock:
-            _add_locked(bucket, own)
-        if stack:
-            stack[-1][2] += dt
+    -nothing code attributes each second to exactly one bucket. The
+    region is also a ``ray_tpu/goodput.<bucket>`` span on the profiler's
+    clock (``tracing.annotate``), ledger enabled or not."""
+    with annotate("goodput." + bucket):
+        if not enabled():
+            yield
+            return
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        frame = [bucket, time.perf_counter(), 0.0]  # bucket, t0, child_s
+        stack.append(frame)
+        try:
+            yield
+        finally:
+            stack.pop()
+            dt = time.perf_counter() - frame[1]
+            own = max(0.0, dt - frame[2])
+            with _lock:
+                _add_locked(bucket, own)
+            if stack:
+                stack[-1][2] += dt
 
 
 def snapshot() -> Dict[str, Any]:

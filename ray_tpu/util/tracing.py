@@ -25,6 +25,13 @@ flag propagates to workers through the runtime env) or per-session via
 
     with ray_tpu.util.tracing.profile("tokenize"):
         ...
+
+Beside the device: :func:`annotate` is the one span primitive on the
+PROFILER's clock. It buffers and ships nothing; the span exists only in a
+``jax.profiler`` session taken by this process, where it lies on the host
+plane as ``ray_tpu/<name>`` beside the device's own lines. :func:`profile`
+and ``goodput.region`` enter it, so every block the runtime already marks
+shows in such a trace, whether or not ``RAY_TPU_ENABLE_TRACING`` is set.
 """
 
 from __future__ import annotations
@@ -34,6 +41,7 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 from ray_tpu._private import wire
 import threading
 import time
@@ -198,29 +206,47 @@ def _timer_flush():
     flush()
 
 
+ANNOTATION_PREFIX = "ray_tpu/"
+
+
+def annotate(name: str, **attrs):
+    """A host span named ``ray_tpu/<name>`` on the profiler's clock: a
+    ``jax.profiler.TraceAnnotation`` (``attrs`` become its arguments) when
+    this process has imported JAX, else a null context. The driver, the GCS
+    and the raylets never import JAX for this. Outside a profiler session
+    the annotation is inert, and it never selects what the device runs."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **attrs)
+
+
 @contextlib.contextmanager
 def profile(name: str, category: str = "user", **extra):
     """Custom user span (reference: ray.util.tracing via profile events).
 
     Runs as a child of the active span (the executing task, or an enclosing
     profile block) and installs itself as current for the duration, so
-    nested profile blocks and nested ``.remote()`` submissions tree up."""
-    if not enabled():
-        yield
-        return
-    parent = _ctx.get()
-    span_id = new_span_id()
-    trace_id = parent[0] if parent is not None else new_trace_id()
-    token = _ctx.set((trace_id, span_id))
-    t0 = time.time()
-    try:
-        yield
-    finally:
-        reset_context(token)
-        record_span(name, t0, time.time(), category=category,
-                    trace_id=trace_id, span_id=span_id,
-                    parent_id=parent[1] if parent is not None else None,
-                    **extra)
+    nested profile blocks and nested ``.remote()`` submissions tree up.
+    Always an :func:`annotate` span too; the GCS record is what
+    ``RAY_TPU_ENABLE_TRACING`` gates."""
+    with annotate(name):
+        if not enabled():
+            yield
+            return
+        parent = _ctx.get()
+        span_id = new_span_id()
+        trace_id = parent[0] if parent is not None else new_trace_id()
+        token = _ctx.set((trace_id, span_id))
+        t0 = time.time()
+        try:
+            yield
+        finally:
+            reset_context(token)
+            record_span(name, t0, time.time(), category=category,
+                        trace_id=trace_id, span_id=span_id,
+                        parent_id=parent[1] if parent is not None else None,
+                        **extra)
 
 
 def flush(block: bool = True):
