@@ -12,10 +12,14 @@ Layout:
 - ``block_tables``:        [max_num_seqs, pages_per_seq] int32 page ids
 - page 0 is scratch: masked-out writes (padding, inactive slots) land there.
 
-The decode step gathers each slot's pages into a [B, Lmax] view and runs
-grouped-query attention against it; the gather is a single XLA dynamic-gather
-that TPUs handle well. A pallas paged-attention kernel can swap in underneath
-without changing the cache layout.
+Both programs take the cache donated and write it in place: per layer, one
+scatter for K and one for V whose operand is the whole 5-D array and whose
+indices are (layer, page, offset), B rows in decode and B x S in prefill.
+Nothing slices a layer out or writes one back, so a step's cache traffic is
+the rows it writes, not the cache. Decode then gathers each slot's pages from
+the same array by (layer, block_tables) into a [B, Lmax] view and runs
+grouped-query attention against it. A pallas paged-attention kernel that
+reads only live pages can swap in underneath without changing the layout.
 
 Weights come from ``ray_tpu.models.transformer.Transformer`` — this module
 reads the same param pytree (checkpoint-compatible with training).
@@ -70,15 +74,6 @@ def _qkv(x, p, cfg, positions):
     return q, k, v
 
 
-def _scatter_kv(cache_layer, new, flat_idx):
-    """Write new KV rows into the flat page view at flat_idx (0 = scratch)."""
-    L_dims = cache_layer.shape  # (NP, P, KVH, HD)
-    flat = cache_layer.reshape(L_dims[0] * L_dims[1], L_dims[2], L_dims[3])
-    flat = flat.at[flat_idx.reshape(-1)].set(
-        new.reshape(-1, new.shape[-2], new.shape[-1]), mode="drop")
-    return flat.reshape(L_dims)
-
-
 # ---------------------------------------------------------------------------
 # prefill
 # ---------------------------------------------------------------------------
@@ -103,7 +98,8 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
     # padding tokens scatter to scratch page 0
     page_for = jnp.take_along_axis(
         block_tables, (positions // P).astype(jnp.int32), axis=1)
-    flat_idx = jnp.where(in_prompt, page_for * P + positions % P, 0)
+    page = jnp.where(in_prompt, page_for, 0)
+    offset = jnp.where(in_prompt, positions % P, 0)
 
     x = p["embed"].astype(cfg.dtype)[tokens]
     new_k, new_v = cache.k, cache.v
@@ -111,8 +107,8 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
         lp = p[f"layer_{i}"]
         h = _rmsnorm(x, lp["attn_norm"]["scale"])
         q, k, v = _qkv(h, lp["attn"], cfg, positions)
-        new_k = new_k.at[i].set(_scatter_kv(new_k[i], k, flat_idx))
-        new_v = new_v.at[i].set(_scatter_kv(new_v[i], v, flat_idx))
+        new_k = new_k.at[i, page, offset].set(k, mode="drop")
+        new_v = new_v.at[i, page, offset].set(v, mode="drop")
         if cfg.n_kv_heads != cfg.n_heads:
             rep = cfg.n_heads // cfg.n_kv_heads
             k = jnp.repeat(k, rep, axis=2)
@@ -153,17 +149,15 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
     """
     p = params["params"]
     B = last_tokens.shape[0]
-    L, NP, P, KVH, HD = cache.k.shape
+    P, KVH, HD = cache.k.shape[2:]
     MP = block_tables.shape[1]
     Lmax = MP * P
     G = cfg.n_heads // cfg.n_kv_heads
 
     positions = seq_lens[:, None].astype(jnp.int32)  # [B, 1]
     cur_page = jnp.take_along_axis(block_tables, positions // P, axis=1)[:, 0]
-    flat_write = jnp.where(active, cur_page * P + seq_lens % P, 0)[:, None]  # [B,1]
-    # gather view: every slot's pages flattened to [B, Lmax]
-    gather_idx = (block_tables[:, :, None] * P
-                  + jnp.arange(P, dtype=jnp.int32)[None, None]).reshape(B, Lmax)
+    page = jnp.where(active, cur_page, 0)  # [B]; inactive slots -> scratch
+    offset = jnp.where(active, seq_lens % P, 0)
     kv_mask = (jnp.arange(Lmax, dtype=jnp.int32)[None] <= seq_lens[:, None]) \
         & active[:, None]
     scale = 1.0 / (HD ** 0.5)
@@ -174,12 +168,11 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
         lp = p[f"layer_{i}"]
         h = _rmsnorm(x, lp["attn_norm"]["scale"])
         q, k, v = _qkv(h, lp["attn"], cfg, positions)  # q [B,1,H,hd]
-        new_k = new_k.at[i].set(_scatter_kv(new_k[i], k, flat_write))
-        new_v = new_v.at[i].set(_scatter_kv(new_v[i], v, flat_write))
-        flat_k = new_k[i].reshape(NP * P, KVH, HD)
-        flat_v = new_v[i].reshape(NP * P, KVH, HD)
-        k_all = flat_k[gather_idx]  # [B, Lmax, KVH, HD]
-        v_all = flat_v[gather_idx]
+        new_k = new_k.at[i, page, offset].set(k[:, 0], mode="drop")
+        new_v = new_v.at[i, page, offset].set(v[:, 0], mode="drop")
+        # every slot's pages, straight from the 5-D cache: [B, Lmax, KVH, HD]
+        k_all = new_k[i, block_tables].reshape(B, Lmax, KVH, HD)
+        v_all = new_v[i, block_tables].reshape(B, Lmax, KVH, HD)
         # grouped-query attention without materializing repeated heads
         qg = q[:, 0].reshape(B, KVH, G, HD)
         scores = jnp.einsum("bkgd,blkd->bkgl", qg, k_all,

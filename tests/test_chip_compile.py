@@ -89,9 +89,10 @@ def _on(tree, sharding):
         tree)
 
 
-def test_engine_decode_and_prefill_1b_widths(one_chip):
-    """The engine's two programs at 1b widths (depth cut to 2), at the
-    smoke's geometry: 8 slots x 2048; prefill must carry the flash kernel."""
+def _lower_engine(one_chip, n_layers, bucket):
+    """The engine's two programs at 1b widths (GQA 16/8, head_dim 128: the
+    serve cell's heads), lowered for the described chip at the smoke's and
+    the serve cell's geometry: 8 slots x 2048, pages of 16."""
     import flax.linen as nn
 
     from ray_tpu.llm import model_runner as mr
@@ -101,7 +102,7 @@ def test_engine_decode_and_prefill_1b_widths(one_chip):
     e = EngineConfig(max_num_seqs=8, max_model_len=2048)
     # "auto" asks the attached backend, which is the CPU here: steer the
     # dispatch the way a TPU backend would
-    cfg = dataclasses.replace(CONFIGS["1b"], n_layers=2,
+    cfg = dataclasses.replace(CONFIGS["1b"], n_layers=n_layers,
                               attention_impl="flash")
     params = _on(jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))), one_chip)
@@ -113,12 +114,49 @@ def test_engine_decode_and_prefill_1b_widths(one_chip):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
-    decode = mr.decode_step.lower(params, cfg, cache, i32(B), i32(B),
-                                  i32(B, MP), active).compile()
+    return cache, {
+        "decode_step": lambda: mr.decode_step.lower(
+            params, cfg, cache, i32(B), i32(B), i32(B, MP), active),
+        "prefill": lambda: mr.prefill.lower(
+            params, cfg, cache, i32(B, bucket), i32(B), i32(B, MP))}
+
+
+def test_engine_decode_and_prefill_1b_widths(one_chip):
+    """Depth cut to 2; prefill must carry the flash kernel."""
+    _, lower = _lower_engine(one_chip, n_layers=2, bucket=1024)
+    decode = lower["decode_step"]().compile()
     assert decode.memory_analysis().temp_size_in_bytes < 16 << 30
-    prefill = mr.prefill.lower(params, cfg, cache, i32(B, 1024), i32(B),
-                               i32(B, MP)).compile()
-    assert "tpu_custom_call" in prefill.as_text()
+    assert "tpu_custom_call" in lower["prefill"]().compile().as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_engine_writes_the_kv_cache_in_place(one_chip, program):
+    """The compiled program scatters the new rows into the donated cache and
+    holds no copy of a layer of it: no ``dynamic-update-slice`` whose result
+    is the cache, no array of a layer's shape out of a fusion or a copy, and
+    both caches aliased to the outputs. Depth 4, so that one cache (134 MB)
+    is past what the compiler would stage into fast memory whole, as the
+    full-depth program's is."""
+    import re
+
+    cache, lower = _lower_engine(one_chip, n_layers=4, bucket=256)
+    compiled = lower[program]().compile()
+    L, NP, P, KVH, HD = cache.k.shape
+    made = re.findall(r"^\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(",
+                      compiled.as_text(), re.M)
+    whole = f"bf16[{L},{NP},{P},{KVH},{HD}]"
+    flat = f"bf16[{L * NP * P},{KVH},{HD}]"  # the same buffer, bitcast
+    layer = [f"bf16[{dims}]" for dims in (f"1,{NP},{P},{KVH},{HD}",
+                                          f"{NP},{P},{KVH},{HD}",
+                                          f"{NP * P},{KVH},{HD}")]
+    assert not [res for res, op in made
+                if op == "dynamic-update-slice" and whole in res]
+    assert not [(op, res) for res, op in made
+                if op in ("fusion", "copy") and any(s in res for s in layer)]
+    assert sum(op == "scatter" and (whole in res or flat in res)
+               for res, op in made) == 2 * L
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        2 * cache.k.size * cache.k.dtype.itemsize
 
 
 def test_sharded_update_step_partitions_over_four_chips(topo):

@@ -47,6 +47,105 @@ def test_decode_matches_training_forward(engine):
     assert out.token_ids == expect
 
 
+def _plain_kv(params, cfg, tokens):
+    """K (after RoPE) and V of every layer for one whole sequence, by a dense
+    causal forward with no cache: [(k [T, KVH, HD], v [T, KVH, HD])] * L."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import _rope
+
+    p = params["params"]
+    T = len(tokens)
+    pos = jnp.arange(T)[None]
+    x = p["embed"][jnp.asarray(tokens)][None]
+
+    def norm(x, scale):
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * scale
+
+    out = []
+    for i in range(cfg.n_layers):
+        lp = p[f"layer_{i}"]
+        h = norm(x, lp["attn_norm"]["scale"])
+        q = _rope(jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["q_proj"]["kernel"]),
+                  pos, cfg.rope_theta)
+        k = _rope(jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["k_proj"]["kernel"]),
+                  pos, cfg.rope_theta)
+        v = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["v_proj"]["kernel"])
+        out.append((np.asarray(k[0]), np.asarray(v[0])))
+        rep = cfg.n_heads // cfg.n_kv_heads
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, 2)) / cfg.head_dim ** 0.5
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+        a = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), jnp.repeat(v, rep, 2))
+        x = x + jnp.einsum("bshk,hkd->bsd", a, lp["attn"]["o_proj"]["kernel"])
+        h = norm(x, lp["mlp_norm"]["scale"])
+        m = lp["mlp"]
+        x = x + (jax.nn.silu(h @ m["gate_proj"]["kernel"]) * (h @ m["up_proj"]["kernel"])
+                 ) @ m["down_proj"]["kernel"]
+    return out
+
+
+@pytest.mark.parametrize("n_kv_heads", [2, 4], ids=["gqa4-2", "mha4-4"])
+def test_cache_rows_land_at_their_pages_and_nothing_else_moves(n_kv_heads):
+    """``prefill`` then four ``decode_step``s on a cache of random bits, with
+    unordered non-contiguous pages, an inactive slot, padded prompts and
+    sequences that cross a page boundary: every written row sits at (layer,
+    block_table[b, pos // P], pos % P) and holds the K/V a dense forward
+    gives, and every other row outside scratch page 0 keeps its bits."""
+    import dataclasses
+
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.models.transformer import CONFIGS, Transformer
+
+    cfg = dataclasses.replace(CONFIGS["tiny"], n_kv_heads=n_kv_heads,
+                              dtype=jnp.float32, attention_impl="xla")
+    params = nn.meta.unbox(Transformer(cfg).init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32)))
+    P, NP, S, steps = 4, 21, 8, 4
+    block_tables = np.array([[7, 3, 12, 5], [9, 2, 15, 1], [14, 6, 11, 4],
+                             [8, 10, 13, 16]], np.int32)  # pages 17-20: no one's
+    lengths = np.array([6, 3, 8, 0], np.int32)  # two padded, one full, one idle
+    active = lengths > 0
+    rng = np.random.default_rng(0)
+    fed = rng.integers(1, cfg.vocab_size, (4, S + steps)).astype(np.int32)
+    shape = (cfg.n_layers, NP, P, n_kv_heads, cfg.head_dim)
+    before = [rng.standard_normal(shape).astype(np.float32) for _ in "kv"]
+    cache = mr.KVCache(*(jnp.asarray(a) for a in before))
+
+    prompt = np.where(np.arange(S)[None] < lengths[:, None], fed[:, :S], 0)
+    _, cache = mr.prefill(params, cfg, cache, jnp.asarray(prompt),
+                          jnp.asarray(lengths), jnp.asarray(block_tables))
+    seqs = [list(prompt[b, :lengths[b]]) for b in range(4)]
+    for t in range(steps):
+        last = fed[:, S + t]
+        _, cache = mr.decode_step(
+            params, cfg, cache, jnp.asarray(last),
+            jnp.asarray([len(s) for s in seqs], jnp.int32),
+            jnp.asarray(block_tables), jnp.asarray(active))
+        for b in np.flatnonzero(active):
+            seqs[b].append(last[b])
+    assert [len(s) for s in seqs] == [10, 7, 12, 0]  # 7 and 12 crossed a page
+
+    got = [np.asarray(a) for a in cache]
+    untouched = np.ones(shape[:3], bool)
+    untouched[:, 0] = False  # scratch page: masked writes land there
+    for b in np.flatnonzero(active):
+        pos = np.arange(len(seqs[b]))
+        page, off = block_tables[b, pos // P], pos % P
+        for i, kv in enumerate(_plain_kv(params, cfg, seqs[b])):
+            for g, want in zip(got, kv):
+                np.testing.assert_allclose(g[i, page, off], want,
+                                           rtol=1e-4, atol=1e-5)
+            untouched[i, page, off] = False
+    assert untouched.sum() == cfg.n_layers * (20 * P - 29)
+    for g, was in zip(got, before):
+        assert np.array_equal(g[untouched], was[untouched])
+
+
 def test_continuous_batching_matches_sequential(engine):
     prompts = ["hello world", "the quick brown fox", "a", "zzzz"]
     batched = engine.generate(prompts, SamplingParams(max_tokens=8))
