@@ -2,11 +2,13 @@
 """Compiles a cell's programs for a DESCRIBED v5e (``v5e:2x2``), no chip
 attached, and prints the compiler's memory analysis per device.
 
-    JAX_PLATFORMS=cpu python3 benchmarks/aot.py --workload <name> [--layers N] [--per-chip-batch B]
+    JAX_PLATFORMS=cpu python3 benchmarks/aot.py --workload <name> [--set KEY=VALUE ...] [--per-chip-batch B]
 
 A compile that passes is not a chip run: nothing executes, so this says what
 fits and which kernels and collectives the program holds, never a time. It is
-how the depth and batch of the train configurations were settled (PERF.md).
+how the depth and batch of the train configurations were settled (PERF.md):
+``--set`` replaces a number of the configuration file (its depth, say) for
+this compile.
 """
 
 from __future__ import annotations
@@ -38,7 +40,8 @@ def _mem(compiled) -> dict:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--layers", type=int, default=None)
+    ap.add_argument("--set", action="append", default=[], metavar="KEY=VALUE",
+                    help="a whole number in place of the configuration file's")
     ap.add_argument("--per-chip-batch", type=int, default=None)
     args = ap.parse_args()
 
@@ -54,13 +57,16 @@ def main() -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     cell = Cell(args.workload, os.path.join(REPO, "BENCHMARK.json"))
     conf, job = dict(cell.config), cell.config["job"]
-    if args.layers:
-        conf["num_hidden_layers"] = args.layers
+    changed = {k: int(v) for k, v in (kv.split("=", 1) for kv in args.set)}
+    unknown = sorted(set(changed) - set(conf))
+    if unknown:
+        raise SystemExit(f"--set: {cell.entry['config']} has no key {unknown}")
+    conf.update(changed)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 
     def out(**kw):
-        print(json.dumps({"workload": cell.name,
-                          "layers": conf["num_hidden_layers"], **kw}), flush=True)
+        print(json.dumps({"workload": cell.name, "set": changed, **kw}),
+              flush=True)
 
     if job["kind"] == "train":
         shape = traffic.train_shape(cell.mix, job)
@@ -108,7 +114,7 @@ def main() -> int:
             tpu_custom_calls=fb.as_text().count("tpu_custom_call"))
         toks = jax.ShapeDtypeStruct((bundle.dp_size, CHECK_SEQ + 1), jnp.int32,
                                     sharding=bundle.batch_sharding)
-        ref = common.gradient_check(bundle, conf, mcfg.n_layers).lower(
+        ref = common.gradient_check(conf).lower(
             p_abs, p_abs, toks).compile()
         out(program="check_reference_gradient", **_mem(ref))
         return 0
@@ -140,11 +146,8 @@ def main() -> int:
     c = mr.decode_step.lower(params, mcfg, cache, i32(B), i32(B), i32(B, MP),
                              active).compile()
     out(program="decode_step", **_mem(c))
-    for n in traffic.serve_warmup_lengths(cell.mix, e.prefill_bucket_min,
-                                          e.max_model_len):
-        S = e.prefill_bucket_min
-        while S < n:
-            S *= 2
+    for S in traffic.serve_prefill_buckets(cell.mix, e.prefill_bucket_min,
+                                           e.max_model_len):
         c = mr.prefill.lower(params, mcfg, cache, i32(B, S), i32(B),
                              i32(B, MP)).compile()
         out(program=f"prefill_{S}", **_mem(c),

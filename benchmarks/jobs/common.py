@@ -12,7 +12,7 @@ from typing import Optional
 import jax
 import numpy as np
 
-from benchmarks.registry import program_overrides
+from benchmarks.registry import architecture
 from benchmarks.trace import reduce
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -35,17 +35,15 @@ class CompileCounter:
 
 def transformer_config(cfg: dict, max_seq_len: int):
     """The program's ``TransformerConfig`` at the published widths of a
-    configuration file: public widths go in as data, no preset is used."""
+    configuration file, as its architecture maps them: public widths go in as
+    data, no preset is used."""
     import dataclasses
 
     from ray_tpu.models.transformer import CONFIGS
 
-    hd = cfg["hidden_size"] // cfg["num_attention_heads"]
-    if hd != cfg["head_dim"]:
-        raise ValueError("the program derives head_dim = hidden/heads; "
-                         f"{cfg['name']} publishes {cfg['head_dim']}")
-    return dataclasses.replace(CONFIGS["tiny"],
-                               **program_overrides(cfg, max_seq_len))
+    return dataclasses.replace(
+        CONFIGS["tiny"],
+        **architecture(cfg).program_overrides(cfg, max_seq_len))
 
 
 def build_bundle(mcfg, job: dict, devices):
@@ -67,45 +65,21 @@ def build_bundle(mcfg, job: dict, devices):
     return TrainStepBundle(mcfg, mesh, optimizer=make_optimizer(**opt_kw))
 
 
-def reference_cfg(cfg: dict) -> dict:
-    """What the plain reference needs. ``rms_norm_eps`` is the program's
-    hard-coded 1e-6, not the published 1e-5: a departure the configuration
-    file lists, and one the program offers no way around."""
-    return {k: cfg[k] for k in ("num_attention_heads", "num_key_value_heads",
-                                "head_dim", "rope_theta",
-                                "tie_word_embeddings")} | {"rms_norm_eps": 1e-6}
+def reference_loss(conf: dict):
+    """``(params, tokens [rows, n+1]) -> loss`` of the architecture's plain
+    float32 reference on the program's own parameter tree."""
+    arch = architecture(conf)
+    rcfg = arch.reference_cfg(conf)
+
+    def ref_loss(params, toks):
+        with jax.default_matmul_precision("highest"):
+            return arch.loss(arch.to_reference_params(params, conf),
+                             toks[:, :-1], toks[:, 1:], rcfg)
+
+    return ref_loss
 
 
-def to_reference_params(p: dict, n_layers: int) -> dict:
-    """The program's parameter tree under the reference's plain names.
-    Reshapes only (heads folded into one axis); called inside a jit so no
-    copy of the weights outlives the check."""
-    def flat_in(k):   # [d, heads, hd] -> [d, heads*hd]
-        return k.reshape(k.shape[0], -1)
-
-    layers = []
-    for i in range(n_layers):
-        lp = p[f"layer_{i}"]
-        a, m = lp["attn"], lp["mlp"]
-        o = a["o_proj"]["kernel"]
-        layers.append({
-            "input_layernorm": lp["attn_norm"]["scale"],
-            "q_proj": flat_in(a["q_proj"]["kernel"]),
-            "k_proj": flat_in(a["k_proj"]["kernel"]),
-            "v_proj": flat_in(a["v_proj"]["kernel"]),
-            "o_proj": o.reshape(-1, o.shape[-1]),
-            "post_attention_layernorm": lp["mlp_norm"]["scale"],
-            "gate_proj": m["gate_proj"]["kernel"],
-            "up_proj": m["up_proj"]["kernel"],
-            "down_proj": m["down_proj"]["kernel"]})
-    out = {"embed_tokens": p["embed"], "norm": p["final_norm"]["scale"],
-           "layers": layers}
-    if "lm_head" in p:
-        out["lm_head"] = p["lm_head"]
-    return out
-
-
-def gradient_check(bundle, conf: dict, n_layers: int):
+def gradient_check(conf: dict):
     """A jitted ``(params, program_grads, tokens) -> (reference loss, per-leaf
     [|g - g_ref|^2, |g_ref|^2])``: the plain float32 reference's gradient on
     the same parameters and tokens, differentiated through the renaming so it
@@ -113,17 +87,10 @@ def gradient_check(bundle, conf: dict, n_layers: int):
     the one program so that no second full gradient outlives it."""
     import jax.numpy as jnp
 
-    from benchmarks.reference import decoder
-
-    rcfg = reference_cfg(conf)
+    ref_loss = reference_loss(conf)
 
     def distance(params, grads, toks):
-        def ref_loss(p):
-            with jax.default_matmul_precision("highest"):
-                return decoder.loss(to_reference_params(p, n_layers),
-                                    toks[:, :-1], toks[:, 1:], rcfg)
-
-        loss, ref = jax.value_and_grad(ref_loss)(params)
+        loss, ref = jax.value_and_grad(ref_loss)(params, toks)
 
         def sums(g, r):
             g, r = g.astype(jnp.float32), r.astype(jnp.float32)

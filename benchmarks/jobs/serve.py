@@ -1,11 +1,12 @@
 """The serve cells: the deployment (replica side) and the open-loop load
 (driver side). The replica is the only process that touches the chip.
 
-``BenchLLMServer`` is ``LLMServer`` plus what a measurement needs from inside
-the replica: the reference check, a watcher thread that snapshots the
-engine's counters at the window's edges and takes the profiler trace, and,
-under ``--trace 1`` alone, two host spans on the profiler's clock. With
-``--trace 0`` the request path is ``LLMServer``'s own, unwrapped.
+``BenchLLMServer`` is ``LLMServer`` at the configuration's published widths
+plus what a measurement needs from inside the replica: the reference check, a
+watcher thread that snapshots the engine's counters at the window's edges and
+takes the profiler trace, and, under ``--trace 1`` alone, two host spans on
+the profiler's clock. With ``--trace 0`` the request path is ``LLMServer``'s
+own, unwrapped.
 
 The replica hands its results over in a file: above the knee its actor queue
 is full of requests by design, and a control call would wait behind them.
@@ -25,9 +26,18 @@ from ray_tpu.llm.serve_llm import LLMServer
 
 class BenchLLMServer(LLMServer):
     def __init__(self, config, params_blob=None, bench: dict = None):
+        import dataclasses
+
         from benchmarks.jobs import common
+        from benchmarks.registry import architecture
 
         self._compiles = common.CompileCounter()
+        # the published widths go in here and not in the driver: the
+        # architecture's module imports JAX, which the driver stays off
+        conf = bench["config"]
+        config = dataclasses.replace(
+            config, model_overrides=architecture(conf).program_overrides(
+                conf, config.engine_config.max_model_len))
         super().__init__(config, params_blob)
         self._bench = bench
         self._tracer = common.Tracer(bench["trace"], bench["out_dir"] + "/trace")
@@ -63,8 +73,7 @@ class BenchLLMServer(LLMServer):
         import jax.numpy as jnp
         import numpy as np
 
-        from benchmarks.jobs import common
-        from benchmarks.reference import decoder
+        from benchmarks.registry import architecture
 
         eng, e, mcfg = self.engine, self.engine.ecfg, self.engine.mcfg
         if eng.has_unfinished():
@@ -101,13 +110,14 @@ class BenchLLMServer(LLMServer):
             got.append(np.asarray(logits[0]))
         got = np.stack(got)                       # [1 + decode_steps, vocab]
 
-        rcfg = common.reference_cfg(conf)
+        arch = architecture(conf)
+        rcfg = arch.reference_cfg(conf)
 
         @jax.jit
         def ref_logits(p, t):
             with jax.default_matmul_precision("highest"):
-                full = decoder.forward(
-                    common.to_reference_params(p["params"], mcfg.n_layers),
+                full = arch.forward(
+                    arch.to_reference_params(p["params"], conf),
                     t[None], rcfg)[0]
             return full[prompt_len - 1:]
         want = np.asarray(ref_logits(eng.params, jnp.asarray(toks)))

@@ -21,7 +21,6 @@ def train_loop(cfg: dict) -> None:
     import numpy as np
 
     from benchmarks.jobs import common
-    from benchmarks.reference import decoder
     from ray_tpu import train
     from ray_tpu.utils import compile_cache_dir, compile_cache_entries
 
@@ -63,7 +62,6 @@ def train_loop(cfg: dict) -> None:
     # of the gradient against the reference's, relative to the leaf's norm.
     # (2) The first step of the fused program at the cell's own shape: its loss
     # against the reference's, one sequence at a time.
-    rcfg = common.reference_cfg(conf)
     check_rows, check_seq = bundle.dp_size, min(CHECK_SEQ, seq)
     check_toks = common.token_batch(rng, check_rows, check_seq, mcfg.vocab_size)
     check_batch = {k: jax.device_put(v, bundle.batch_sharding) for k, v in {
@@ -71,7 +69,7 @@ def train_loop(cfg: dict) -> None:
         "mask": np.ones((check_rows, check_seq), np.float32)}.items()}
     t0 = time.perf_counter()
     check_loss, grads = bundle._fwd_bwd(params, check_batch)
-    ref_check_loss, sums = common.gradient_check(bundle, conf, mcfg.n_layers)(
+    ref_check_loss, sums = common.gradient_check(conf)(
         params, grads, jax.device_put(check_toks, bundle.batch_sharding))
     grad = common.gradient_distances(jax.device_get(sums))
     grad["check_loss_rel_err"] = (abs(float(check_loss) - float(ref_check_loss))
@@ -88,19 +86,14 @@ def train_loop(cfg: dict) -> None:
     # (overall / worst leaf): weights rounded to 8 bits 0.47 / 1.0, a
     # non-causal mask 1.4 / 1.7, and by the worst leaf (a q or k projection)
     # RoPE left out 0.95, the wrong theta 1.16, pairs rotated in the
-    # interleaved convention 1.25. It passes rms_norm_eps 1e-5 for 1e-6
+    # interleaved convention 1.25. It passes an RMSNorm epsilon of 1e-5 for 1e-6
     # (8.5e-3 / 2.6e-2).
     grad_tol, grad_leaf_tol = 2e-2, 6e-2
     grad["ok"] = bool(grad["grad_rel_err"] <= grad_tol
                       and grad["grad_leaf_rel_err_max"] <= grad_leaf_tol)
     grad["grad_tol"], grad["grad_leaf_tol"] = grad_tol, grad_leaf_tol
 
-    @jax.jit
-    def ref_loss(p, toks):
-        with jax.default_matmul_precision("highest"):
-            return decoder.loss(common.to_reference_params(p, mcfg.n_layers),
-                                toks[:, :-1], toks[:, 1:], rcfg)
-
+    ref_loss = jax.jit(common.reference_loss(conf))
     first_tokens, batch = fresh_batch()
     t0 = time.perf_counter()
     ref = float(np.mean([float(ref_loss(params, first_tokens[i:i + 1]))

@@ -61,7 +61,7 @@ class Failed(Exception):
 
 
 def run_train(cell, args, cluster, out_dir):
-    from benchmarks import flops, traffic
+    from benchmarks import traffic
     from benchmarks.jobs.train import train_loop
     from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 
@@ -100,8 +100,7 @@ def run_train(cell, args, cluster, out_dir):
            "spans": {"worker_start_s": m["worker_entered_at"] - fit_called},
            "counters": {},
            "facts": {"tokens_per_step": m["tokens_per_step"], "chips": cell.chips,
-                     "flops_per_token": flops.train_flops_per_token(
-                         conf, shape["seq_len"])}}
+                     **shape}}
     e2e = {"train_tokens_per_s": m["tokens_per_s"], "setup_s": setup_s}
     return {"correct": correct, "attempted": m["steps"],
             "failed": 0 if m["losses_finite"] else m["steps"],
@@ -115,7 +114,6 @@ def deploy_serve(cell, args, cluster, out_dir):
     import ray_tpu
     from benchmarks import traffic
     from benchmarks.jobs import serve as sjob
-    from benchmarks.registry import program_overrides
     from ray_tpu.llm import LLMConfig
     from ray_tpu.llm.config import EngineConfig
     from ray_tpu.serve import api as serve_api
@@ -124,11 +122,10 @@ def deploy_serve(cell, args, cluster, out_dir):
     job = conf["job"]
     eng = EngineConfig(**job["engine"])
     name = "llm"
-    overrides = program_overrides(conf, eng.max_model_len)
+    # the replica puts the configuration's widths in (``BenchLLMServer``)
     llm = LLMConfig(model_id="tiny", seed=args.seed % (2 ** 32),
                     engine_config=eng, num_replicas=job["num_replicas"],
-                    ray_actor_options={"num_cpus": 1.0, "num_tpus": cell.chips},
-                    model_overrides=overrides)
+                    ray_actor_options={"num_cpus": 1.0, "num_tpus": cell.chips})
     bench = {"trace": bool(args.trace), "out_dir": out_dir, "config": conf,
              "keep_trace_as": args.keep_trace}
     # the deployment options of build_llm_deployment (which hard-codes the
@@ -179,6 +176,9 @@ def deploy_serve(cell, args, cluster, out_dir):
     emit(note="reference_check", **check)
     return {"url": url, "call": call, "replica_up_s": replica_up_s,
             "warm": warm, "warm_s": warm_s, "check": check,
+            "shapes": {"max_num_seqs": eng.max_num_seqs,
+                       "prefill_buckets": traffic.serve_prefill_buckets(
+                           mix, eng.prefill_bucket_min, eng.max_model_len)},
             "shutdown": serve_api.shutdown}
 
 
@@ -262,7 +262,8 @@ def run_serve(cell, args, cluster, out_dir):
                                       "compiles_total", "waiting_at_end",
                                       "active_at_end")})
     ctx = {"trace": rep.get("trace"), "spans": {"replica_up_s": replica_up_s},
-           "counters": rep["counters"], "facts": {"chips": cell.chips}}
+           "counters": rep["counters"],
+           "facts": {"chips": cell.chips, **d["shapes"]}}
     correct = bool(check["ok"] and not failed and judged
                    and rep["compiles_in_window"] == 0)
     d["shutdown"]()
@@ -420,7 +421,8 @@ def main() -> int:
         raise Failed(f"the job ran on {device}, the cell needs "
                      f"{cell.chips} TPU chip(s)")
     peak = peak_for(device["kind"])
-    r["ctx"]["facts"]["peak_flops_per_s"] = peak["bf16_flops_per_s"]
+    r["ctx"]["facts"].update(peak_flops_per_s=peak["bf16_flops_per_s"],
+                             peak_hbm_bytes_per_s=peak["hbm_bytes_per_s"])
 
     line = {"correct": r["correct"], "attempted": r["attempted"],
             "failed": r["failed"]}

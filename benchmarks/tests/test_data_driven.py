@@ -1,24 +1,59 @@
 """A new cell is data: one ``workloads`` entry plus, at most, new files under
-``configs/``, ``traffic/`` and ``layer_metrics/`` found by name. Shown with a
-dummy configuration, mix and metric in a temporary directory: no file of
-``benchmarks/`` is edited, and the harness reads them."""
+``configs/``, ``traffic/``, ``layer_metrics/`` and ``architectures/`` found by
+name. Shown with a dummy configuration, mix, metrics and architecture in a
+temporary directory: no file of ``benchmarks/`` is edited, and the harness
+reads them."""
 
 import json
 import os
 
 import pytest
 
-from benchmarks import traffic
+from benchmarks import registry, traffic
 from benchmarks.registry import Cell, peak_for
+
+# an architecture nobody has seen: its module answers the harness's eight
+# questions for a "model" that is one matrix, and counts one kernel
+DUMMY_ARCHITECTURE = '''
+def program_overrides(cfg, max_seq_len):
+    return {"d_model": cfg["width"], "max_seq_len": max_seq_len}
+
+def reference_cfg(cfg):
+    return {"width": cfg["width"]}
+
+def to_reference_params(params, cfg):
+    return {"w": params["matrix"]}
+
+def forward(params, tokens, rcfg):
+    return params["w"][tokens]
+
+def loss(params, tokens, targets, rcfg):
+    return forward(params, tokens, rcfg).sum()
+
+def train_flops_per_token(cfg, seq_len):
+    return 6.0 * cfg["width"] * cfg["vocab_size"]
+
+def total_params(cfg):
+    return cfg["width"] * cfg["vocab_size"]
+
+def kernel_cost(kernel, cfg, facts):
+    if kernel != "lookup":
+        raise KeyError(kernel)
+    return 0.0, 2.0 * cfg["width"] * facts["max_num_seqs"]
+'''
 
 
 @pytest.fixture
 def dummy(tmp_path):
     root = tmp_path / "bench"
-    for d in ("configs", "traffic", "layer_metrics"):
+    for d in ("configs", "traffic", "layer_metrics", "architectures"):
         (root / d).mkdir(parents=True)
     (root / "configs" / "dummy.json").write_text(json.dumps({
-        "name": "dummy", "vocab_size": 1000, "job": {"kind": "serve"}}))
+        "name": "dummy", "adapter": "one_matrix", "width": 4096,
+        "vocab_size": 1000, "job": {"kind": "serve"}}))
+    (root / "architectures" / "one_matrix.py").write_text(DUMMY_ARCHITECTURE)
+    (root / "architectures" / "half_done.py").write_text(
+        DUMMY_ARCHITECTURE.replace("def kernel_cost", "def _kernel_cost"))
     (root / "traffic" / "dummy-mix.json").write_text(json.dumps({
         "kind": "serve", "arrival": {"rate_per_s": 8.0},
         "prompt_tokens": {"dist": "lognormal", "median": 100, "sigma": 1.0,
@@ -30,6 +65,9 @@ def dummy(tmp_path):
         "reduce": "span_quantile", "args": {"span": "wait_s", "q": 0.5, "scale": 1000.0}}))
     (root / "layer_metrics" / "dummy.absent.json").write_text(json.dumps({
         "reduce": "module_ms_per_exec", "args": {"module": "jit_nothing"}}))
+    (root / "layer_metrics" / "lookup_roofline.json").write_text(json.dumps({
+        "reduce": "roofline_share_percent",
+        "args": {"op": "^lookup ", "kernel": "lookup"}}))
     bench = {
         "run_seconds": 5,
         "configs": [{"name": "dummy", "file": "bench/configs/dummy.json"}],
@@ -38,7 +76,8 @@ def dummy(tmp_path):
         "end_to_end": [{"name": "dummy_e2e", "unit": "ms"},
                        {"name": "other_e2e", "unit": "ms", "workloads": ["elsewhere"]}],
         "per_layer": [{"name": "dummy.wait_ms", "unit": "ms"},
-                      {"name": "dummy.absent", "unit": "ms"}]}
+                      {"name": "dummy.absent", "unit": "ms"},
+                      {"name": "lookup_roofline", "unit": "%"}]}
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     return Cell("dummy.dummy-mix", str(tmp_path / "BENCHMARK.json"), root=str(root))
 
@@ -52,6 +91,36 @@ def test_cell_finds_its_files_by_name(dummy):
     # the metric with nothing to read is left out, not reported as 0
     assert dummy.per_layer_values(ctx) == {
         "dummy.wait_ms": {"value": 2.0, "unit": "ms"}}
+
+
+def test_a_configuration_brings_its_architecture(dummy):
+    """The module is found by the configuration's ``"adapter"``; a metric
+    over one of its kernels is a file naming the reduction, the operation and
+    the kernel."""
+    arch = dummy.architecture()
+    assert arch.program_overrides(dummy.config, 128) == {
+        "d_model": 4096, "max_seq_len": 128}
+    assert arch is registry.architecture(dummy.config, dummy.root)   # loaded once
+    # 3 calls of the kernel on each device took 30 us; a call moves
+    # 2 x 4096 x 8 bytes = 65,536, 0.08 us at 819 GB/s: 0.8 % of its roofline
+    ctx = {"trace": {"modules": {},
+                     "op_kinds": {"lookup f32[8,4096]": [30e-6, 3.0],
+                                  "lookup_table f32[1]": [1.0, 1.0]}},
+           "spans": {}, "counters": {},
+           "facts": {"max_num_seqs": 8, "peak_flops_per_s": 197e12,
+                     "peak_hbm_bytes_per_s": 819e9}}
+    assert dummy.per_layer_values(ctx) == {"lookup_roofline": {
+        "value": pytest.approx(100 * 3 * (65536 / 819e9) / 30e-6), "unit": "%"}}
+
+
+def test_an_unknown_or_unfinished_architecture_fails_by_name(dummy):
+    with pytest.raises(SystemExit, match=r"'two_matrices'.*half_done.*one_matrix"):
+        registry.architecture({"name": "x", "adapter": "two_matrices"}, dummy.root)
+    with pytest.raises(SystemExit, match=r"'half_done'.*lacks \['kernel_cost'\]"):
+        registry.architecture({"name": "x", "adapter": "half_done"}, dummy.root)
+    # a file that names none is a dense decoder, which this root does not have
+    with pytest.raises(SystemExit, match="'dense_decoder'"):
+        registry.architecture({"name": "x"}, dummy.root)
 
 
 def _window(rs, seconds):
@@ -136,6 +205,11 @@ def test_every_cell_of_benchmark_json_resolves():
     e2e = {m["name"] for m in bench["end_to_end"]}
     for w in bench["workloads"]:
         cell = Cell(w["name"], path)
+        arch = cell.architecture()
+        assert all(callable(getattr(arch, m)) for m in registry.MEMBERS)
+        job = cell.config["job"]
+        shapes = (traffic.train_shape(cell.mix, job) if job["kind"] == "train"
+                  else {})
         names = {m["name"] for m in cell.end_to_end()}
         assert "setup_s" in names and len(names) >= 2
         layer = cell.per_layer()
@@ -143,5 +217,12 @@ def test_every_cell_of_benchmark_json_resolves():
         for m in layer:
             # the metric's own file says how it is read and nothing that
             # BENCHMARK.json already says
-            assert set(cell.reader(m["name"])) <= {"reduce", "args"}
+            reader = cell.reader(m["name"])
+            assert set(reader) <= {"reduce", "args"}
             assert m["moves"] in e2e and m["moves"] in names
+            # a kernel's share names a kernel its cell's architecture counts,
+            # from the shapes this cell's run knows
+            if reader["reduce"] == "roofline_share_percent":
+                ops, nbytes = arch.kernel_cost(reader["args"]["kernel"],
+                                               cell.config, shapes)
+                assert ops > 0 and nbytes > 0

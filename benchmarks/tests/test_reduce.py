@@ -3,14 +3,22 @@ whose answers are plain, and the whole reduction on a trace recorded on the
 chip (``fixtures/``, from PR 23's first traced run; numbers worked out once by
 hand from a dump of that file)."""
 
+import json
 import os
+import types
 
 import pytest
 
+from benchmarks import registry
 from benchmarks.trace import reduce
 
 FIXTURES = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                         "trace", "fixtures")
+
+
+def counts(**members):
+    """``ctx["architecture"]`` of a made-up architecture with these counts."""
+    return lambda: types.SimpleNamespace(**members)
 
 
 def test_union_subtract_length():
@@ -75,7 +83,9 @@ def test_readers_return_none_when_there_is_nothing_to_read():
                      (reduce.span_quantile, {"span": "x", "q": 0.9}),
                      (reduce.span_value, {"span": "y"}),
                      (reduce.counter_ratio, {"num": "a", "den": "b"}),
-                     (reduce.mfu_percent, {"module": "m"})]:
+                     (reduce.mfu_percent, {"module": "m"}),
+                     (reduce.device_op_ms_per_exec, {"op": "^k", "module": "m"}),
+                     (reduce.roofline_share_percent, {"op": "^k", "kernel": "k"})]:
         assert fn(ctx, **args) is None
 
 
@@ -88,10 +98,53 @@ def test_quantile_is_nearest_rank():
 
 def test_mfu_uses_the_traced_window():
     ctx = {"trace": {"window_s": 4.0, "modules": {"jit_train_step": {"count": 10, "total_s": 3.9}}},
-           "facts": {"flops_per_token": 4e9, "tokens_per_step": 8192, "chips": 1,
-                     "peak_flops_per_s": 197e12}}
+           "facts": {"seq_len": 2048, "tokens_per_step": 8192, "chips": 1,
+                     "peak_flops_per_s": 197e12},
+           "config": {"billions": 4},
+           "architecture": counts(
+               train_flops_per_token=lambda cfg, seq: cfg["billions"] * 1e9 * seq / 2048)}
     assert reduce.mfu_percent(ctx, "jit_train_step") == pytest.approx(
         100 * 4e9 * (10 * 8192 / 4.0) / 197e12)
+
+
+def test_operations_are_read_by_name_on_four_devices():
+    """Two kinds of one kernel (two shapes) and a kind that only shares its
+    prefix, on four devices that each ran two steps; one device ran a call
+    less (a trace cut mid-step)."""
+    us = 1_000
+    per_device = [("%attn_fwd.1 = bf16[8,128]{1,0} custom-call(%q)", 0, 300 * us),
+                  ("%attn_fwd.2 = bf16[8,128]{1,0} custom-call(%q)", 400 * us, 700 * us),
+                  ("%attn_fwd.3 = bf16[8,256]{1,0} custom-call(%q)", 800 * us, 1000 * us),
+                  ("%attn_fwd_prep.4 = bf16[8]{0} fusion(%q)", 1000 * us, 1900 * us)]
+    planes = {"devices": {
+        f"/device:TPU:{i}": {
+            "modules": [("jit_step(7)", 0, 1000 * us), ("jit_step(7)", 1000 * us, 2000 * us)],
+            "ops": per_device[1 if i == 3 else 0:]} for i in range(4)},
+        "annotations": []}
+    s = reduce.summarize_planes(planes)
+    assert s["devices"] == 4 and s["modules"]["jit_step"]["count"] == 2
+    assert s["op_kinds"]["attn_fwd bf16[8,128]"] == [pytest.approx(525e-6), 1.75]
+    assert s["op_kinds"]["attn_fwd bf16[8,256]"] == [pytest.approx(200e-6), 1.0]
+    # every kind is kept, the printed table stays the top ten
+    assert len(s["op_kinds"]) == 3 and len(s["device_ops"]) == 3
+    ctx = {"trace": s, "spans": {}, "counters": {}, "config": {},
+           "facts": {"peak_flops_per_s": 100e12, "peak_hbm_bytes_per_s": 1e12},
+           "architecture": counts(kernel_cost=lambda kernel, cfg, facts: {
+               "attn_fwd": (5e9, 1e6), "streamed": (1e6, 80e6)}[kernel])}
+    # "^attn_fwd " takes both shapes and leaves attn_fwd_prep out:
+    # 725 us a device over 2 steps
+    assert reduce.device_op_ms_per_exec(ctx, "^attn_fwd ", "jit_step") == pytest.approx(0.3625)
+    assert reduce.device_op_ms_per_exec(ctx, "^attn_fwd", "jit_step") == pytest.approx(0.8125)
+    assert reduce.device_op_ms_per_exec(ctx, "^attn_fwd ", "jit_other") is None
+    assert reduce.device_op_ms_per_exec(ctx, "^attn_bwd ", "jit_step") is None
+    # 2.75 calls a device; a call needs 5e9 / 100e12 = 50 us of compute
+    # (its bytes 1 us): 137.5 of 725 us
+    assert reduce.roofline_share_percent(ctx, "^attn_fwd ", "attn_fwd") == \
+        pytest.approx(100 * 137.5 / 725)
+    # bound by its bytes: 80e6 / 1e12 = 80 us against 0.01 us of compute
+    assert reduce.roofline_share_percent(ctx, r"^attn_fwd bf16\[8,256\]", "streamed") == \
+        pytest.approx(100 * 80 / 200)
+    assert reduce.roofline_share_percent(ctx, "^attn_bwd ", "attn_fwd") is None
 
 
 def test_op_kind_folds_instances_and_layouts():
@@ -133,10 +186,28 @@ def test_recorded_chip_trace():
     gaps = dict(map(tuple, s["idle_gaps"]))
     assert sum(gaps.values()) == pytest.approx(s["window_s"] - s["busy_s"], abs=1e-9)
     assert s["annotations"] == 12
-    ctx = {"trace": s, "spans": {}, "counters": {},
-           "facts": {"flops_per_token": 4_026_630_144.0, "tokens_per_step": 8192,
-                     "chips": 1, "peak_flops_per_s": 197e12}}
+    with open(os.path.join(os.path.dirname(FIXTURES), os.pardir, "configs",
+                           "smollm2-1.7b.json")) as f:
+        config = json.load(f)
+    ctx = {"trace": s, "spans": {}, "counters": {}, "config": config,
+           "architecture": lambda: registry.architecture(config),
+           "facts": {"seq_len": 2048, "per_chip_batch": 4, "tokens_per_step": 8192,
+                     "chips": 1, "peak_flops_per_s": 197e12,
+                     "peak_hbm_bytes_per_s": 819e9}}
     assert reduce.module_ms_per_exec(ctx, "jit_train_step") == pytest.approx(403.5077, abs=1e-3)
     assert reduce.idle_share_percent(ctx) == pytest.approx(0.0094, abs=1e-3)
     # 2 x 8192 tokens in 0.807022 s = 20,301.8 tokens/s -> 41.50 % of 197 TFLOP/s
     assert reduce.mfu_percent(ctx, "jit_train_step") == pytest.approx(41.497, abs=1e-2)
+    # the three attention kernels, all named ``attn`` when this was recorded
+    # and told apart by what they return: 16 calls each (8 layers x 2 steps:
+    # one forward a layer, not two) in 110.48 / 91.09 / 127.21 ms, so 6.905 /
+    # 5.693 / 7.951 ms a call against 0.349 / 0.523 / 0.698 ms of required
+    # compute (test_dense_decoder.py) at 197 TFLOP/s
+    assert reduce.device_op_ms_per_exec(ctx, "^attn ", "jit_train_step") == \
+        pytest.approx(164.391, abs=1e-3)
+    for op, kernel, share in [
+            (r"^attn \(bf16\[128,2048,64\], f32", "flash_fwd", 5.054),
+            (r"^attn bf16", "flash_bwd_dq", 9.195),
+            (r"^attn \(bf16\[128,2048,64\], bf16", "flash_bwd_dkv", 8.779)]:
+        assert reduce.roofline_share_percent(ctx, op, kernel) == \
+            pytest.approx(share, abs=1e-3)

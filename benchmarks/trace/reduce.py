@@ -6,13 +6,18 @@ and the parent (which never touches JAX) only does arithmetic on plain dicts:
 
 1. ``summarize(path)`` reads the trace with ``jax.profiler.ProfileData`` and
    returns a small dict: busy/idle union per device, device time per XLA
-   module, exposed collective time, the top device operations and the idle
-   gaps attributed to the benchmark's host annotations.
-2. ``REDUCTIONS`` is the small fixed set of reductions a file under
-   ``layer_metrics/`` may name. Each takes the run's context
-   ``{"trace": summary-or-None, "spans": {...}, "counters": {...},
-   "facts": {...}}`` and returns a number, or ``None`` when there is nothing
-   to read (the harness then leaves the metric out of the line).
+   module, exposed collective time, seconds and events of every kind of
+   device operation, the top ten of them and the idle gaps attributed to the
+   benchmark's host annotations.
+2. ``REDUCTIONS`` are the reductions a file under ``layer_metrics/`` may
+   name. Each takes the run's context ``{"trace": summary-or-None, "spans":
+   {...}, "counters": {...}, "facts": {...}, "config": the configuration
+   file's dict, "architecture": a call that returns its module}`` and returns
+   a number, or ``None`` when there is nothing to read (the harness then
+   leaves the metric out of the line). A device operation is read by a
+   regular expression over its kind, and what a count needs comes from the
+   configuration's architecture: a metric over a new kernel or a new module
+   is a data file, and one over a new architecture adds that module.
 
 Interval arithmetic is in integer nanoseconds on the trace's own clock.
 """
@@ -220,6 +225,7 @@ def summarize_planes(planes: dict, top: int = 10) -> Optional[dict]:
     collective_ns = 0
     modules: Dict[str, dict] = {}
     op_ns: Dict[str, int] = {}
+    op_events: Dict[str, int] = {}
     gap_ns: Dict[str, int] = {}
     for d in devices.values():
         ops = leaves(d["ops"]) if d["ops"] else d["modules"]
@@ -232,6 +238,7 @@ def summarize_planes(planes: dict, top: int = 10) -> Optional[dict]:
         for nm, s, e in ops:
             k = op_kind(nm)
             op_ns[k] = op_ns.get(k, 0) + (e - s)
+            op_events[k] = op_events.get(k, 0) + 1
         for nm, s, e in d["modules"]:
             m = modules.setdefault(module_name(nm), {"count": 0, "total_ns": 0})
             m["count"] += 1
@@ -263,6 +270,8 @@ def summarize_planes(planes: dict, top: int = 10) -> Optional[dict]:
         "modules": {k: {"count": v["count"] / n, "total_s": v["total_ns"] / n / 1e9}
                     for k, v in modules.items()},
         "device_ops": ranked(op_ns),
+        # per device: [seconds, events] of every kind of operation
+        "op_kinds": {k: [v / n / 1e9, op_events[k] / n] for k, v in op_ns.items()},
         "idle_gaps": ranked(gap_ns),
         "annotations": len(planes["annotations"]),
     }
@@ -272,7 +281,7 @@ def summarize(path: str) -> Optional[dict]:
     return summarize_planes(read_planes(path))
 
 
-# -- the fixed set of reductions -------------------------------------------------
+# -- the reductions -----------------------------------------------------------------
 
 
 def _module(ctx, module):
@@ -324,18 +333,58 @@ def idle_share_percent(ctx):
 
 
 def mfu_percent(ctx, module):
-    """Required operations per token x tokens/s over chips x peak, with the
-    tokens/s of the traced window: the executions of the step's module the
-    trace holds, times the tokens of a step, over the trace's length (idle
-    gaps included). The run's own tokens/s would carry the cost of starting
-    and stopping the profiler."""
+    """Required operations per token (the architecture's count) x tokens/s
+    over chips x peak, with the tokens/s of the traced window: the executions
+    of the step's module the trace holds, times the tokens of a step, over the
+    trace's length (idle gaps included). The run's own tokens/s would carry
+    the cost of starting and stopping the profiler."""
     m, f = _module(ctx, module), ctx["facts"]
-    need = ("flops_per_token", "tokens_per_step", "chips", "peak_flops_per_s")
+    need = ("seq_len", "tokens_per_step", "chips", "peak_flops_per_s")
     if m is None or any(f.get(k) is None for k in need):
         return None
+    flops_per_token = ctx["architecture"]().train_flops_per_token(
+        ctx["config"], f["seq_len"])
     tokens_per_s = m["count"] * f["tokens_per_step"] / ctx["trace"]["window_s"]
-    return 100.0 * f["flops_per_token"] * tokens_per_s / (
+    return 100.0 * flops_per_token * tokens_per_s / (
         f["chips"] * f["peak_flops_per_s"])
+
+
+def _op_kinds(ctx, op):
+    """(seconds, events) per device of the operation kinds whose name the
+    regular expression ``op`` finds (``re.search``: anchor it), or ``None``."""
+    t = ctx.get("trace")
+    found = [v for k, v in (t or {}).get("op_kinds", {}).items()
+             if re.search(op, k)]
+    if not found:
+        return None
+    return sum(s for s, _ in found), sum(n for _, n in found)
+
+
+def device_op_ms_per_exec(ctx, op, module):
+    """Device time of the operation kinds matching ``op``, per execution of
+    ``module``: what a kernel, or a family of them, costs a step."""
+    m, found = _module(ctx, module), _op_kinds(ctx, op)
+    if m is None or found is None:
+        return None
+    return found[0] / m["count"] * 1e3
+
+
+def roofline_share_percent(ctx, op, kernel):
+    """The least time the chip could take for the calls of a kernel the trace
+    holds, over the time they took: calls x max(operations / peak operations/s,
+    bytes / peak bytes/s) over the seconds of the kinds matching ``op``, with
+    (operations, bytes) of one call from the architecture's
+    ``kernel_cost(kernel, config, facts)``."""
+    found, f = _op_kinds(ctx, op), ctx["facts"]
+    if found is None or not found[0] or any(
+            f.get(k) is None for k in ("peak_flops_per_s", "peak_hbm_bytes_per_s")):
+        return None
+    seconds, events = found
+    operations, nbytes = ctx["architecture"]().kernel_cost(
+        kernel, ctx["config"], f)
+    least = max(operations / f["peak_flops_per_s"],
+                nbytes / f["peak_hbm_bytes_per_s"])
+    return 100.0 * events * least / seconds
 
 
 REDUCTIONS = {
@@ -346,6 +395,8 @@ REDUCTIONS = {
     "exposed_collective_ms_per_exec": exposed_collective_ms_per_exec,
     "idle_share_percent": idle_share_percent,
     "mfu_percent": mfu_percent,
+    "device_op_ms_per_exec": device_op_ms_per_exec,
+    "roofline_share_percent": roofline_share_percent,
 }
 
 

@@ -99,17 +99,24 @@ def serve_schedule(mix: dict, seed: int, seconds: float,
             for t, n_prompt, n_out in events]
 
 
-def serve_warmup_lengths(mix: dict, bucket_min: int, max_model_len: int
-                         ) -> List[int]:
-    """One prompt length per prefill bucket this mix can reach (buckets are
-    powers of two from ``bucket_min``, as the engine pads), and no others."""
+def serve_prefill_buckets(mix: dict, bucket_min: int, max_model_len: int
+                          ) -> List[int]:
+    """The prefill buckets this mix can reach (powers of two from
+    ``bucket_min``, as the engine pads), and no others."""
     buckets = set()
     for n in stratified(mix["prompt_tokens"], 512):
         b = bucket_min
         while b < n:
             b *= 2
         buckets.add(min(b, max_model_len))
-    return [min(b, max_model_len - 1) - 8 for b in sorted(buckets)]
+    return sorted(buckets)
+
+
+def serve_warmup_lengths(mix: dict, bucket_min: int, max_model_len: int
+                         ) -> List[int]:
+    """One prompt length per prefill bucket this mix can reach."""
+    return [min(b, max_model_len - 1) - 8
+            for b in serve_prefill_buckets(mix, bucket_min, max_model_len)]
 
 
 # -- train batches -----------------------------------------------------------
