@@ -44,6 +44,12 @@ class EngineConfig:
     num_pages: Optional[int] = None  # default: enough for all slots + scratch
     max_top_k: int = 64             # static top-k width compiled into sampler
     prefill_bucket_min: int = 32    # pad prompts up to pow2 buckets >= this
+    # routed experts per layer of the model this deployment serves (0: a
+    # dense model). The engine refuses to start on a model with another
+    # number: a lost override would otherwise serve the dense preset under
+    # a sparse deployment's name, and a program that has no expert layer
+    # refuses such a deployment where it is described, not in a replica.
+    expect_experts: int = 0
 
     def __post_init__(self):
         if self.max_model_len % self.page_size:

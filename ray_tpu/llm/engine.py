@@ -31,13 +31,22 @@ from ``__init__``, so two snapshots subtract):
   ``between_steps_ms`` is the caller's time from one ``step()`` to the next
   while work was left;
 - per request, summed: ``queue_wait_ms`` (``add_request`` to first
-  admission) and ``ttft_ms`` (``add_request`` to first token).
+  admission) and ``ttft_ms`` (``add_request`` to first token);
+- what routing did in decode, for a model with experts (all 0 for a dense
+  one): ``moe_decode_layer_steps`` (decode steps x expert layers) and, summed
+  over those, ``moe_decode_assignments`` (active slots x top_k),
+  ``moe_decode_experts_touched`` (experts that got a row) and
+  ``moe_decode_max_load`` (rows of the fullest expert). They come from
+  ``KVCache.moe_load``, a few KB whose copy to the host starts at dispatch
+  and is read in ``emit`` after the tokens are back.
 
 The same boundaries are spans on the profiler's clock
 (``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
 that owns the engine shows ``ray_tpu/engine.step`` on the host plane and,
 inside it, ``engine.admit``, ``.prefill_dispatch`` (arguments ``bucket``,
-``admitted``), ``.decode_dispatch``, ``.sample_dispatch``, ``.readback``,
+``admitted``), ``.decode_dispatch`` (argument ``experts``: experts touched
+per layer in the newest decode step the host has read, models with experts
+only), ``.sample_dispatch``, ``.readback``,
 ``.emit`` and, around a shape's first use, ``.compile``. With
 ``RAY_TPU_ENABLE_TRACING`` a finished request also leaves ``engine.queued``,
 ``engine.prefill`` and ``engine.decode`` spans (``request_id``) under the
@@ -106,6 +115,10 @@ class JaxLLMEngine:
         self.config = config
         self.ecfg: EngineConfig = config.engine_config
         self.mcfg = config.transformer_config()
+        if self.mcfg.n_experts != self.ecfg.expect_experts:
+            raise ValueError(
+                f"the deployment expects {self.ecfg.expect_experts} experts "
+                f"a layer, the model has {self.mcfg.n_experts}")
         self.tokenizer = get_tokenizer(config.tokenizer)
         self._mr = model_runner
         self._jax = jax
@@ -146,7 +159,11 @@ class JaxLLMEngine:
             "admit_ms": 0.0, "prefill_dispatch_ms": 0.0,
             "decode_dispatch_ms": 0.0, "sample_dispatch_ms": 0.0,
             "emit_ms": 0.0, "between_steps_ms": 0.0,
-            "queue_wait_ms": 0.0, "ttft_ms": 0.0}
+            "queue_wait_ms": 0.0, "ttft_ms": 0.0,
+            "moe_decode_layer_steps": 0, "moe_decode_assignments": 0,
+            "moe_decode_experts_touched": 0, "moe_decode_max_load": 0}
+        # span attribute of decode_dispatch; none for a dense model
+        self._experts_attr: Dict[str, float] = {}
 
     # -- params ------------------------------------------------------------
 
@@ -371,7 +388,7 @@ class JaxLLMEngine:
 
         # 2) one decode step for all active slots
         if decode and self._active.any():
-            with self._phase("decode_dispatch"):
+            with self._phase("decode_dispatch", **self._experts_attr):
                 # page-boundary allocation; preempt to waiting on exhaustion
                 for req in [s for s in self._slots if s is not None]:
                     if self._active[req.slot] and not self._ensure_page(req):
@@ -386,15 +403,30 @@ class JaxLLMEngine:
                             jnp.asarray(self._seq_lens),
                             jnp.asarray(self._block_tables),
                             jnp.asarray(self._active))
+                    if self.cache.moe_load is not None:
+                        self.cache.moe_load.copy_to_host_async()
             if decoding:
                 toks_np = self._sample(logits)
                 m["decode_steps"] += 1
                 with self._phase("emit"):
+                    if self.cache.moe_load is not None:
+                        self._count_routing(np.asarray(self.cache.moe_load))
                     for req in list(self._slots):
                         if req is not None and self._active[req.slot]:
                             self._seq_lens[req.slot] += 1
                             self._emit(req, int(toks_np[req.slot]), outputs)
         return outputs
+
+    def _count_routing(self, load: np.ndarray) -> None:
+        """``load`` [expert layers, E]: real rows per expert in one decode
+        step."""
+        m = self.metrics
+        touched = int((load > 0).sum())
+        m["moe_decode_layer_steps"] += load.shape[0]
+        m["moe_decode_assignments"] += int(load.sum())
+        m["moe_decode_experts_touched"] += touched
+        m["moe_decode_max_load"] += int(load.max(axis=1).sum())
+        self._experts_attr = {"experts": touched / load.shape[0]}
 
     def _requeue(self, req: _Request) -> None:
         """Preempt a running request back to the waiting queue; its KV is
@@ -516,9 +548,9 @@ class JaxLLMEngine:
         req.slot = free_slots[0]
         req.pages = [self._free_pages.popleft() for _ in range(n_pages)]
         pages = jnp.asarray(np.asarray(req.pages, np.int32))
-        self.cache = self._mr.KVCache(
-            self.cache.k.at[:, pages].set(jnp.asarray(state["k"])),
-            self.cache.v.at[:, pages].set(jnp.asarray(state["v"])))
+        self.cache = self.cache._replace(
+            k=self.cache.k.at[:, pages].set(jnp.asarray(state["k"])),
+            v=self.cache.v.at[:, pages].set(jnp.asarray(state["v"])))
         row = self._block_tables[req.slot]
         row[:] = 0
         row[:n_pages] = req.pages

@@ -22,13 +22,20 @@ grouped-query attention against it. A pallas paged-attention kernel that
 reads only live pages can swap in underneath without changing the layout.
 
 Weights come from ``ray_tpu.models.transformer.Transformer`` — this module
-reads the same param pytree (checkpoint-compatible with training).
+reads the same param pytree (checkpoint-compatible with training). The dense
+layer math (norms, projections, RoPE, SwiGLU) is written out again here and
+must stay the arithmetic of ``models/transformer.py``; a layer with experts
+(``n_experts > 0``: the tree has ``moe`` where a dense layer has ``mlp``) is
+NOT the training module's capacity-bound dispatch but ``ops/moe.py``:
+dropless, rows that are padding or belong to an inactive slot reach no
+expert. What routing did in a call comes back beside the pages, as
+``KVCache.moe_load`` (per expert layer, how many real rows each expert got).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, NamedTuple, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,13 +44,22 @@ from ray_tpu.models.transformer import TransformerConfig, _rope
 
 
 class KVCache(NamedTuple):
+    """What a program takes donated and hands back: the pages, and for a
+    model with experts what the call's routing did (a dense model has no
+    such leaf, so nothing is added to its programs)."""
     k: jax.Array  # [L, NP, P, KVH, HD]
     v: jax.Array
+    moe_load: Optional[jax.Array] = None  # [expert layers, E] int32
 
 
 def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int) -> KVCache:
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
+    load = None
+    if cfg.n_experts:  # every moe_every-th layer has experts
+        layers = len(range(0, cfg.n_layers, max(cfg.moe_every, 1)))
+        load = jnp.zeros((layers, cfg.n_experts), jnp.int32)
+    return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype),
+                   load)
 
 
 # ---------------------------------------------------------------------------
@@ -51,7 +67,7 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int) -> KVCach
 # ---------------------------------------------------------------------------
 
 
-def _rmsnorm(x, scale, eps=1e-6):
+def _rmsnorm(x, scale, eps):
     x32 = x.astype(jnp.float32)
     norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (norm * scale).astype(x.dtype)
@@ -64,11 +80,33 @@ def _mlp(x, p, dtype):
     return jnp.einsum("...f,fd->...d", hidden, p["down_proj"]["kernel"].astype(dtype))
 
 
+def _ffn(x, lp, cfg, valid, name):
+    """The layer's MLP on x [B, S, D]: SwiGLU, or the expert layer for the
+    rows ``valid`` [B, S] marks real. Returns (y, load [E] or None)."""
+    if "moe" not in lp:
+        return _mlp(x, lp["mlp"], cfg.dtype), None
+    from ray_tpu.ops.moe import expert_layer
+
+    p = lp["moe"]
+    y, load = expert_layer(
+        x.reshape(-1, x.shape[-1]), valid.reshape(-1), p["router"]["kernel"],
+        p["gate_proj"], p["up_proj"], p["down_proj"],
+        top_k=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
+        name=name)
+    return y.reshape(x.shape), load
+
+
 def _qkv(x, p, cfg, positions):
     dtype = cfg.dtype
     q = jnp.einsum("...d,dhk->...hk", x, p["q_proj"]["kernel"].astype(dtype))
     k = jnp.einsum("...d,dhk->...hk", x, p["k_proj"]["kernel"].astype(dtype))
     v = jnp.einsum("...d,dhk->...hk", x, p["v_proj"]["kernel"].astype(dtype))
+    if cfg.qk_norm:
+        def whole(t, scale):  # the norm sees all heads as one vector
+            flat = t.reshape(*t.shape[:-2], -1)
+            return _rmsnorm(flat, scale, cfg.norm_eps).reshape(t.shape)
+        q = whole(q, p["q_norm"]["scale"])
+        k = whole(k, p["k_norm"]["scale"])
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
     return q, k, v
@@ -103,9 +141,10 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
 
     x = p["embed"].astype(cfg.dtype)[tokens]
     new_k, new_v = cache.k, cache.v
+    loads = []
     for i in range(cfg.n_layers):
         lp = p[f"layer_{i}"]
-        h = _rmsnorm(x, lp["attn_norm"]["scale"])
+        h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps)
         q, k, v = _qkv(h, lp["attn"], cfg, positions)
         new_k = new_k.at[i, page, offset].set(k, mode="drop")
         new_v = new_v.at[i, page, offset].set(v, mode="drop")
@@ -117,18 +156,28 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
         attn = jnp.einsum("...hk,hkd->...d",
                           attn, lp["attn"]["o_proj"]["kernel"].astype(cfg.dtype))
         h2 = x + attn
-        x = h2 + _mlp(_rmsnorm(h2, lp["mlp_norm"]["scale"]), lp["mlp"], cfg.dtype)
+        y, load = _ffn(_rmsnorm(h2, lp["mlp_norm"]["scale"], cfg.norm_eps),
+                       lp, cfg, in_prompt, "moe_gmm_prefill")
+        x = h2 + y
+        if load is not None:
+            loads.append(load)
 
     # hidden at the last prompt position only -> [B, d]
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    last = _rmsnorm(last, p["final_norm"]["scale"])
+    return _head(last, p, cfg), KVCache(new_k, new_v,
+                                        jnp.stack(loads) if loads else None)
+
+
+def _head(last, p, cfg):
+    """Final norm and output head on [B, d] -> float32 logits [B, vocab]."""
+    last = _rmsnorm(last, p["final_norm"]["scale"], cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = jnp.einsum("bd,vd->bv", last, p["embed"].astype(cfg.dtype))
     else:
         logits = jnp.einsum("bd,dv->bv", last, p["lm_head"].astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
-    return logits.astype(jnp.float32), KVCache(new_k, new_v)
+    return logits.astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -143,9 +192,9 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
                 ) -> Tuple[jax.Array, KVCache]:
     """One batched decode step over all slots: [B] tokens -> [B, vocab].
 
-    Inactive slots compute garbage into scratch page 0. The new token's KV is
-    written at position seq_lens before attention, so the mask is
-    pos <= seq_lens.
+    Inactive slots compute garbage into scratch page 0 and reach no expert.
+    The new token's KV is written at position seq_lens before attention, so
+    the mask is pos <= seq_lens.
     """
     p = params["params"]
     B = last_tokens.shape[0]
@@ -164,9 +213,10 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
 
     x = p["embed"].astype(cfg.dtype)[last_tokens[:, None]]  # [B, 1, d]
     new_k, new_v = cache.k, cache.v
+    loads = []
     for i in range(cfg.n_layers):
         lp = p[f"layer_{i}"]
-        h = _rmsnorm(x, lp["attn_norm"]["scale"])
+        h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps)
         q, k, v = _qkv(h, lp["attn"], cfg, positions)  # q [B,1,H,hd]
         new_k = new_k.at[i, page, offset].set(k[:, 0], mode="drop")
         new_v = new_v.at[i, page, offset].set(v[:, 0], mode="drop")
@@ -184,15 +234,14 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
         attn = jnp.einsum("...hk,hkd->...d",
                           attn, lp["attn"]["o_proj"]["kernel"].astype(cfg.dtype))
         h2 = x + attn
-        x = h2 + _mlp(_rmsnorm(h2, lp["mlp_norm"]["scale"]), lp["mlp"], cfg.dtype)
+        y, load = _ffn(_rmsnorm(h2, lp["mlp_norm"]["scale"], cfg.norm_eps),
+                       lp, cfg, active[:, None], "moe_gmm_decode")
+        x = h2 + y
+        if load is not None:
+            loads.append(load)
 
-    last = _rmsnorm(x[:, 0], p["final_norm"]["scale"])
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bd,vd->bv", last, p["embed"].astype(cfg.dtype))
-    else:
-        logits = jnp.einsum("bd,dv->bv", last, p["lm_head"].astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-    return logits.astype(jnp.float32), KVCache(new_k, new_v)
+    return _head(x[:, 0], p, cfg), KVCache(new_k, new_v,
+                                           jnp.stack(loads) if loads else None)
 
 
 # ---------------------------------------------------------------------------

@@ -59,7 +59,13 @@ class TransformerConfig:
     capacity_factor: float = 1.25
     moe_aux_coef: float = 0.01
     moe_every: int = 1  # every Nth block uses MoE (others stay dense)
+    # True: a token's top-k router weights are renormalised to sum to one
+    norm_topk_prob: bool = True
     tie_embeddings: bool = False
+    norm_eps: float = 1e-6  # every RMSNorm, here and in llm/model_runner.py
+    # RMSNorm over the whole q and k projections (all heads together),
+    # before the split into heads and RoPE
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -72,6 +78,7 @@ class TransformerConfig:
             + 2 * d * (self.n_kv_heads * self.head_dim)  # k, v
             + d * d  # o
             + 2 * d  # norms
+            + (d + self.n_kv_heads * self.head_dim if self.qk_norm else 0)
         )
         dense_mlp = 3 * d * f
         total = 0
@@ -141,11 +148,12 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 class RMSNorm(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.bfloat16
+    axis: Optional[str] = "embed"  # logical axis of the scale
 
     @nn.compact
     def __call__(self, x):
         scale = self.param(
-            "scale", nn.with_logical_partitioning(nn.initializers.ones, ("embed",)),
+            "scale", nn.with_logical_partitioning(nn.initializers.ones, (self.axis,)),
             (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
@@ -169,6 +177,12 @@ class Attention(nn.Module):
         q = dense((cfg.n_heads, hd), ("embed", "heads", "head_dim"), "q_proj")(x)
         k = dense((cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim"), "k_proj")(x)
         v = dense((cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim"), "v_proj")(x)
+        if cfg.qk_norm:
+            def whole(t, name):  # the norm sees all heads as one vector
+                flat = t.reshape(B, S, -1)
+                return RMSNorm(cfg.norm_eps, cfg.dtype, axis=None,
+                               name=name)(flat).reshape(t.shape)
+            q, k = whole(q, "q_norm"), whole(k, "k_norm")
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
         if cfg.n_kv_heads != cfg.n_heads:
@@ -211,7 +225,8 @@ class MLP(nn.Module):
 
 
 class MoEMLP(nn.Module):
-    """Top-k routed mixture-of-experts MLP (GShard-style dense dispatch).
+    """Top-k routed mixture-of-experts MLP (GShard-style dense dispatch),
+    the TRAINING side of the expert layer.
 
     Reference capability: the reference delegates MoE to vLLM/torch user
     code; here EP is native — expert-stacked weights carry the "expert"
@@ -219,7 +234,14 @@ class MoEMLP(nn.Module):
     the MXU, and XLA inserts the expert all-to-alls implied by the
     shardings. Token capacity is bounded (capacity_factor); overflow
     tokens fall through the residual (standard token dropping). The
-    load-balancing aux loss is sown under the "losses" collection."""
+    load-balancing aux loss is sown under the "losses" collection.
+
+    Its parameters (``router/kernel`` [D, E] float32, ``gate_proj`` and
+    ``up_proj`` [E, D, F], ``down_proj`` [E, F, D]) are the tree the serving
+    engine reads, but the engine computes the layer with ``ops/moe.py``:
+    dropless, sorted dispatch, a grouped matmul. The two agree where this
+    one drops nothing (``tests/test_moe.py``); that there are two is a debt
+    (ROADMAP S5), to be paid when a sparse model is trained on the chip."""
 
     cfg: TransformerConfig
 
@@ -252,8 +274,9 @@ class MoEMLP(nn.Module):
 
         # top-k expert choice per token
         gate_vals, expert_idx = jax.lax.top_k(probs, K)  # (G, g, K)
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9)
+        if cfg.norm_topk_prob:
+            gate_vals = gate_vals / jnp.maximum(
+                gate_vals.sum(-1, keepdims=True), 1e-9)
 
         # position of each (token, k) within its expert's capacity buffer,
         # per group; k-slots of a token are ordered before later tokens
@@ -313,10 +336,11 @@ class Block(nn.Module):
     def __call__(self, x, positions, segment_ids=None):
         cfg = self.cfg
         h = x + Attention(cfg, name="attn")(
-            RMSNorm(dtype=cfg.dtype, name="attn_norm")(x), positions, segment_ids)
+            RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x), positions,
+            segment_ids)
         h = nn.with_logical_constraint(h, ("batch", "seq", "embed"))
         mlp = MoEMLP(cfg, name="moe") if self.use_moe else MLP(cfg, name="mlp")
-        out = h + mlp(RMSNorm(dtype=cfg.dtype, name="mlp_norm")(h))
+        out = h + mlp(RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h))
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
@@ -345,7 +369,7 @@ class Transformer(nn.Module):
             use_moe = cfg.n_experts > 0 and i % max(cfg.moe_every, 1) == 0
             x = block(cfg, use_moe, name=f"layer_{i}")(
                 x, positions, segment_ids)
-        x = RMSNorm(dtype=cfg.dtype, name="final_norm")(x)
+        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
         if cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype))
         else:

@@ -1,0 +1,177 @@
+"""The expert layer of a sparse decoder: one definition of its arithmetic.
+
+``expert_layer`` is a pure function of the rows, which of them are real, and
+the layer's weights. Dropless: every real row reaches each of its ``top_k``
+experts, whatever the load; there is no capacity and nothing falls through.
+
+1. router logits and their softmax in float32, from the rows upcast to
+   float32 and at the highest matmul precision (``D x E`` is free, and matmul
+   rounding then has no say in which experts a row gets);
+2. ``top_k`` of the probabilities, renormalised to sum to one only with
+   ``norm_topk_prob``;
+3. rows that are padding or belong to an inactive slot get no expert: their
+   assignments sort behind every real one, lie in no group, cost no expert
+   compute and are not counted in the load;
+4. the ``T x top_k`` assignments sorted by expert (static shape whatever is
+   real), the per-expert group sizes, and a grouped matmul for each of the
+   three products of ``down(silu(gate(x)) * up(x))``;
+5. the weighted sum over a row's experts, back in row order.
+
+``grouped_matmul`` is a Pallas kernel: for each (group, row tile) pair that
+holds a real row it multiplies the tile by that group's matrix, so a step
+streams the touched experts' weights once and nothing else. ``name`` is the
+kernel's name in a device trace (the engine passes ``moe_gmm_decode`` and
+``moe_gmm_prefill``). Off the TPU the same kernel runs in interpret mode.
+
+The training module ``models/transformer.py:MoEMLP`` keeps its own one-hot
+dispatch with a capacity (ROADMAP S5); at a capacity that drops nothing it
+computes what this file computes (``tests/test_moe.py``).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# one [K, tn] block of an expert's matrix the kernel holds (twice: the
+# pipeline's two buffers), in elements: 2 MiB of bfloat16
+_RHS_BLOCK_ELEMS = 1 << 20
+
+
+def route(x: jax.Array, router: jax.Array, top_k: int,
+          norm_topk_prob: bool) -> Tuple[jax.Array, jax.Array]:
+    """x [T, D], router [D, E] -> (weights [T, top_k] float32, experts
+    [T, top_k] int32), the experts of a row in descending probability."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        weights = weights / jnp.maximum(
+            weights.sum(-1, keepdims=True), 1e-9)
+    return weights, experts.astype(jnp.int32)
+
+
+def _tiles(m: int, k: int, n: int, itemsize: int) -> Tuple[int, int]:
+    """(rows of a tile, columns of a weight block). Rows: a decode step's
+    assignments fit one 128-row tile; above that 256, where a block's
+    product takes about as long as its 2 MiB take to arrive. Columns: the
+    whole contraction axis stays in one block, so no accumulator is kept."""
+    sublane = 8 * 4 // itemsize
+    tm = 256 if m >= 256 else -(-m // sublane) * sublane
+    tn = n
+    while tn % 256 == 0 and k * tn > _RHS_BLOCK_ELEMS:
+        tn //= 2
+    return tm, tn
+
+
+def _group_tiles(group_sizes: jax.Array, tiles_m: int, tm: int):
+    """Which (group, row tile) pairs hold a real row, in order: group ``g``
+    owns rows [offsets[g], offsets[g + 1]) and so the tiles from its first
+    row's to its last row's; an empty group owns none. Returns ``offsets
+    [E + 1]``, ``group_ids [G]``, ``tile_ids [G]`` and how many of the ``G =
+    tiles_m + E - 1`` entries are in use (the rest repeat the last one)."""
+    E = group_sizes.shape[0]
+    G = tiles_m + E - 1
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    first = starts // tm
+    count = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    used = count.sum()
+    group_ids = jnp.repeat(jnp.arange(E, dtype=jnp.int32), count,
+                           total_repeat_length=G)
+    before = jnp.cumsum(count) - count        # entries of earlier groups
+    tile_ids = first[group_ids] + jnp.arange(G, dtype=jnp.int32) \
+        - before[group_ids]
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
+    return (offsets.astype(jnp.int32), group_ids,
+            jnp.clip(tile_ids, 0, tiles_m - 1).astype(jnp.int32), used)
+
+
+def _gmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, out, *, tm: int):
+    i = pl.program_id(1)
+    g = group_ids[i]
+    rows = tile_ids[i] * tm + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+    mine = (rows >= offsets[g]) & (rows < offsets[g + 1])
+    acc = jnp.dot(lhs[...], rhs[...], preferred_element_type=jnp.float32)
+    # a tile two groups share is visited by both, one after the other: keep
+    # what the earlier one wrote
+    out[...] = jnp.where(mine, acc.astype(out.dtype), out[...])
+
+
+def _gmm(lhs, rhs, group_sizes, *, name: str, interpret: bool):
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tm, tn = _tiles(m, k, n, lhs.dtype.itemsize)
+    assert m % tm == 0, (m, tm)
+    offsets, group_ids, tile_ids, used = _group_tiles(group_sizes, m // tm, tm)
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm),
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda j, i, off, gid, tid: (tid[i], 0)),
+                pl.BlockSpec((None, k, tn),
+                             lambda j, i, off, gid, tid: (gid[i], 0, j)),
+            ],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, i, off, gid, tid: (tid[i], j)),
+            grid=(n // tn, used),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(offsets, group_ids, tile_ids, lhs, rhs)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   *, name: str) -> jax.Array:
+    """lhs [M, K] with its rows sorted by group, rhs [E, K, N], group_sizes
+    [E] int32 -> [M, N]: row r of group g is ``lhs[r] @ rhs[g]``. Rows past
+    ``group_sizes.sum()`` belong to no group and are left unwritten (whatever
+    the buffer held): the caller masks them. M is a multiple of the row tile
+    (``_tiles``)."""
+    return jax.lax.platform_dependent(
+        lhs, rhs, group_sizes,
+        tpu=functools.partial(_gmm, name=name, interpret=False),
+        default=functools.partial(_gmm, name=name, interpret=True))
+
+
+def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
+                 w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
+                 top_k: int, norm_topk_prob: bool,
+                 name: str = "moe_gmm") -> Tuple[jax.Array, jax.Array]:
+    """x [T, D], valid [T] bool, router [D, E], w_gate / w_up [E, D, F],
+    w_down [E, F, D] -> (y [T, D] in x's dtype, load [E] int32). ``load`` is
+    the number of real rows each expert got; a row that is not valid gives
+    zeros and loads nobody."""
+    T, D = x.shape
+    E = router.shape[1]
+    weights, experts = route(x, router, top_k, norm_topk_prob)
+    # an assignment of an invalid row goes to "expert E": behind every group
+    flat = jnp.where(valid[:, None], experts, E).reshape(-1)
+    load = (flat[:, None] == jnp.arange(E, dtype=jnp.int32)).sum(
+        0, dtype=jnp.int32)
+    M = T * top_k
+    tm, _ = _tiles(M, D, w_gate.shape[2], x.dtype.itemsize)
+    padded = -(-M // tm) * tm
+    flat = jnp.pad(flat, (0, padded - M), constant_values=E)
+    order = jnp.argsort(flat, stable=True)          # sorted row -> assignment
+    rows = x[jnp.minimum(order // top_k, T - 1)]     # [padded, D]
+    gate = grouped_matmul(rows, w_gate.astype(x.dtype), load, name=name)
+    up = grouped_matmul(rows, w_up.astype(x.dtype), load, name=name)
+    out = grouped_matmul(jax.nn.silu(gate) * up, w_down.astype(x.dtype), load,
+                         name=name)
+    # assignment -> its sorted row, then the weighted sum over a row's experts
+    where = jnp.zeros(padded, jnp.int32).at[order].set(
+        jnp.arange(padded, dtype=jnp.int32))[:M].reshape(T, top_k)
+    picked = out[where].astype(jnp.float32)           # [T, top_k, D]
+    y = jnp.where(valid[:, None, None], picked * weights[..., None], 0.0)
+    return y.sum(1).astype(x.dtype), load

@@ -152,7 +152,7 @@ def _build_stage_module(cfg: TransformerConfig, start: int, stop: int,
                     x, positions, segment_ids)
             if not last:
                 return x
-            x = RMSNorm(dtype=c.dtype, name="final_norm")(x)
+            x = RMSNorm(c.norm_eps, c.dtype, name="final_norm")(x)
             if c.tie_embeddings:
                 # only reachable single-stage (StagePrograms rejects tied
                 # heads for S > 1), so `embed` is in scope

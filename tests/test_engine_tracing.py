@@ -20,7 +20,10 @@ KEYS = {
     "steps", "prefill_steps", "admitted", "prefill_batch_tokens", "compiles",
     "step_ms", "host_ms", "readback_ms", "admit_ms", "prefill_dispatch_ms",
     "decode_dispatch_ms", "sample_dispatch_ms", "emit_ms",
-    "between_steps_ms", "queue_wait_ms", "ttft_ms"}
+    "between_steps_ms", "queue_wait_ms", "ttft_ms",
+    # what routing did; a dense model's (this one's) stay 0
+    "moe_decode_layer_steps", "moe_decode_assignments",
+    "moe_decode_experts_touched", "moe_decode_max_load"}
 PHASES = ("admit_ms", "prefill_dispatch_ms", "decode_dispatch_ms",
           "sample_dispatch_ms", "readback_ms", "emit_ms")
 SLOTS, BUCKET = 8, 16
