@@ -4,9 +4,9 @@ Reference: python/ray/llm — LLMConfig (llm/_internal/serve/core/configs/
 llm_config.py:141), vLLM engine wrapper (engines/vllm/vllm_engine.py),
 OpenAI-compatible ingress, and batch-inference processors over Data
 (llm/_internal/batch/processor/). The TPU-native redesign replaces the vLLM
-CUDA engine with a JAX engine: paged KV cache in HBM, batched prefill and
-single-token decode steps compiled once per shape bucket, continuous
-batching in a host-side scheduler.
+CUDA engine with a JAX engine: paged KV cache in HBM, a one-request prefill
+per length bucket and a single-token decode step, each compiled once, and
+continuous batching in a host-side scheduler.
 
 Heavy modules (jax) load lazily: importing ``ray_tpu.llm`` must stay cheap
 for workers that only route requests.

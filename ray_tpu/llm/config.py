@@ -43,7 +43,9 @@ class EngineConfig:
     page_size: int = 16             # tokens per KV page
     num_pages: Optional[int] = None  # default: enough for all slots + scratch
     max_top_k: int = 64             # static top-k width compiled into sampler
-    prefill_bucket_min: int = 32    # pad prompts up to pow2 buckets >= this
+    # a prompt is prefilled alone, padded to the smallest power-of-two
+    # multiple of this that holds it (one compiled program a bucket)
+    prefill_bucket_min: int = 32
     # routed experts per layer of the model this deployment serves (0: a
     # dense model). The engine refuses to start on a model with another
     # number: a lost override would otherwise serve the dense preset under

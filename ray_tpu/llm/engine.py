@@ -3,14 +3,18 @@
 Reference capability: ray.llm wraps vLLM's AsyncLLMEngine
 (llm/_internal/serve/engines/vllm/vllm_engine.py) — request queue, paged KV
 cache, continuous batching. Here the engine is a host-side scheduler over
-two compiled XLA programs (prefill per shape bucket, one decode step):
+two compiled XLA programs (prefill per length bucket, one decode step):
 
 - slots: ``max_num_seqs`` concurrent sequences, fixed batch shape so decode
   is a single cached compilation;
 - pages: a free list of KV pages; sequences allocate pages on demand as they
   cross page boundaries (admission blocks when no pages are free);
-- scheduling per ``step()``: admit waiting requests into free slots (batched
-  bucketed prefill), then run one decode step for all active slots.
+- scheduling per ``step()``: admit waiting requests into free slots and
+  prefill each alone, one program call a request on a ``[1, S]`` batch with
+  ``S`` its own length bucket (a freed slot is refilled by one request as a
+  rule, and a row of padding costs what a real one costs); the calls run back
+  to back and one sampler call reads their first tokens. Then one decode
+  step for all active slots.
 
 The engine is synchronous and single-threaded by design — actor wrappers
 (serve_llm.LLMServer) give it an async front end.
@@ -20,10 +24,12 @@ copy of ``engine.metrics``: flat, numeric, only ever growing, every key there
 from ``__init__``, so two snapshots subtract):
 
 - counts: ``steps`` (calls of ``step()`` that ran a program),
-  ``prefill_steps``, ``decode_steps``, ``admitted``, ``prefill_tokens`` (real
-  prompt positions) against ``prefill_batch_tokens`` (``B x S`` of every
-  prefill call: what the device computes), ``generated_tokens``,
-  ``preempted``, ``compiles`` (first use of a prefill bucket or of decode);
+  ``prefill_steps`` (steps that ran a prefill phase), ``decode_steps``,
+  ``admitted`` (also the number of prefill program calls),
+  ``prefill_tokens`` (real prompt positions) against
+  ``prefill_batch_tokens`` (the ``S`` of every prefill call: what the device
+  computes), ``generated_tokens``, ``preempted``, ``compiles`` (first use of
+  a prefill bucket or of decode);
 - host milliseconds (``perf_counter_ns``): ``step_ms`` = ``host_ms`` +
   ``readback_ms`` (blocked on the device in ``np.asarray(tokens)``); the
   phases ``admit_ms``, ``prefill_dispatch_ms``, ``decode_dispatch_ms``,
@@ -44,9 +50,9 @@ The same boundaries are spans on the profiler's clock
 (``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
 that owns the engine shows ``ray_tpu/engine.step`` on the host plane and,
 inside it, ``engine.admit``, ``.prefill_dispatch`` (arguments ``bucket``,
-``admitted``), ``.decode_dispatch`` (argument ``experts``: experts touched
-per layer in the newest decode step the host has read, models with experts
-only), ``.sample_dispatch``, ``.readback``,
+the largest of the phase, and ``admitted``), ``.decode_dispatch`` (argument
+``experts``: experts touched per layer in the newest decode step the host
+has read, models with experts only), ``.sample_dispatch``, ``.readback``,
 ``.emit`` and, around a shape's first use, ``.compile``. With
 ``RAY_TPU_ENABLE_TRACING`` a finished request also leaves ``engine.queued``,
 ``engine.prefill`` and ``engine.decode`` spans (``request_id``) under the
@@ -141,6 +147,11 @@ class JaxLLMEngine:
         self._top_ks = np.zeros(B, np.int32)
         self._top_ps = np.ones(B, np.float32)
         self._seeds = np.full(B, -1, np.int32)  # -1 = engine-global stream
+        # where a prefill phase gathers its calls' logits, by slot, for the
+        # one sampler call; rows of slots not admitted in a phase are stale
+        # and their samples unread
+        self._prefill_logits = jax.numpy.asarray(
+            np.zeros((B, self.mcfg.vocab_size), np.float32))
         self._slots: List[Optional[_Request]] = [None] * B
         self._free_pages = collections.deque(range(1, e.num_pages))
         self._waiting: collections.deque[_Request] = collections.deque()
@@ -350,37 +361,42 @@ class JaxLLMEngine:
         import jax.numpy as jnp
 
         outputs: List[RequestOutput] = []
-        e, mr, m = self.ecfg, self._mr, self.metrics
-        B = e.max_num_seqs
+        mr, m = self._mr, self.metrics
 
-        # 1) admit + batched prefill (one bucketed program, full-B batch)
+        # 1) admit + prefill: one program call per admitted request, on its
+        # own row and its own length bucket, back to back (the donated cache
+        # chains them; nothing is read in between). Each call's logits land
+        # in the [B, vocab] buffer at the request's slot, so one sampler call
+        # and one blocking read serve the phase however many were admitted,
+        # and no shape depends on that number.
         with self._phase("admit"):
             admitted = self._try_admit()
-            if admitted:
-                now = time.perf_counter()
-                max_len = max(len(r.cache_tokens) for r in admitted)
-                S = self._prefill_bucket(max_len)
-                toks = np.zeros((B, S), np.int32)
-                lens = np.zeros(B, np.int32)
-                for r in admitted:
-                    full = r.cache_tokens
-                    toks[r.slot, :len(full)] = full
-                    lens[r.slot] = len(full)
-                    if not r.t_admitted:  # not a re-admission after preemption
-                        r.t_admitted = now
-                        m["queue_wait_ms"] += (now - r.t_added) * 1e3
+            now = time.perf_counter()
+            for r in admitted:
+                if not r.t_admitted:  # not a re-admission after preemption
+                    r.t_admitted = now
+                    m["queue_wait_ms"] += (now - r.t_added) * 1e3
         if admitted:
-            with self._phase("prefill_dispatch", bucket=S,
-                             admitted=len(admitted)), \
-                    self._first_use("prefill", S):
-                logits, self.cache = mr.prefill(
-                    self.params, self.mcfg, self.cache, jnp.asarray(toks),
-                    jnp.asarray(lens), jnp.asarray(self._block_tables))
-            toks_np = self._sample(logits)
+            slots = [r.slot for r in admitted]
+            buckets = [self._prefill_bucket(n) for n in self._seq_lens[slots]]
+            with self._phase("prefill_dispatch", bucket=max(buckets),
+                             admitted=len(admitted)):
+                for r, S in zip(admitted, buckets):
+                    row = slice(r.slot, r.slot + 1)
+                    toks = np.zeros((1, S), np.int32)
+                    toks[0, :self._seq_lens[r.slot]] = r.cache_tokens
+                    with self._first_use("prefill", S):
+                        logits, self.cache = mr.prefill(
+                            self.params, self.mcfg, self.cache,
+                            jnp.asarray(toks), jnp.asarray(self._seq_lens[row]),
+                            jnp.asarray(self._block_tables[row]))
+                        self._prefill_logits = mr.place_row(
+                            self._prefill_logits, logits, np.int32(r.slot))
+            toks_np = self._sample(self._prefill_logits)
             m["prefill_steps"] += 1
             m["admitted"] += len(admitted)
-            m["prefill_tokens"] += int(lens.sum())
-            m["prefill_batch_tokens"] += B * S
+            m["prefill_tokens"] += int(self._seq_lens[slots].sum())
+            m["prefill_batch_tokens"] += sum(buckets)
             with self._phase("emit"):
                 for r in admitted:
                     self._active[r.slot] = True

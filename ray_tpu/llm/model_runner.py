@@ -14,7 +14,9 @@ Layout:
 
 Both programs take the cache donated and write it in place: per layer, one
 scatter for K and one for V whose operand is the whole 5-D array and whose
-indices are (layer, page, offset), B rows in decode and B x S in prefill.
+indices are (layer, page, offset): B rows in decode, and in prefill the S
+positions of each row it is given (the engine gives it one admitted request
+a call; the batch axis is generic).
 Nothing slices a layer out or writes one back, so a step's cache traffic is
 the rows it writes, not the cache. Decode then gathers each slot's pages from
 the same array by (layer, block_tables) into a [B, Lmax] view and runs
@@ -167,6 +169,15 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
         x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return _head(last, p, cfg), KVCache(new_k, new_v,
                                         jnp.stack(loads) if loads else None)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def place_row(buffer: jax.Array, row: jax.Array, index: jax.Array) -> jax.Array:
+    """buffer [B, V] with ``row`` [1, V] written at row ``index``, a traced
+    scalar: one program whichever slot, so the engine can gather the logits
+    of one-row prefill calls by slot without a shape that depends on how
+    many there were."""
+    return jax.lax.dynamic_update_slice(buffer, row, (index, 0))
 
 
 def _head(last, p, cfg):

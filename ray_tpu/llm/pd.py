@@ -35,7 +35,7 @@ def _load_params_blob(params_blob):
 
 class PrefillWorker:
     """Actor owning a prefill-only engine (reference: the P side of
-    pd_server.py). Prompts run the batched prefill program; the KV state
+    pd_server.py). Prompts run the prefill program, one a call; the KV state
     leaves immediately, so this engine never decodes and its page pool
     turns over at prompt-ingest rate."""
 

@@ -97,11 +97,14 @@ SPARSE = dict(n_kv_heads=16, d_ff=1024, n_experts=64, experts_per_token=8,
               vocab_size=50304, param_dtype=jnp.bfloat16)
 
 
-def _lower_engine(one_chip, n_layers, bucket, sparse=False):
+def _lower_engine(one_chip, n_layers, bucket, sparse=False, rows=None,
+                  **widths):
     """The engine's two programs at 1b widths (GQA 16/8, head_dim 128: the
     serve cell's heads), lowered for the described chip at the smoke's and
     the serve cell's geometry: 8 slots x 2048, pages of 16. ``sparse``: the
-    widths and geometry of the sparse cell instead, 16 slots x 1024."""
+    widths and geometry of the sparse cell instead, 16 slots x 1024.
+    ``rows``: the prefill's batch, 1 as the engine calls it (one admitted
+    request a call), none = every slot as the benchmark's check calls it."""
     import flax.linen as nn
 
     from ray_tpu.llm import model_runner as mr
@@ -116,11 +119,13 @@ def _lower_engine(one_chip, n_layers, bucket, sparse=False):
     if sparse:
         e = EngineConfig(max_num_seqs=16, max_model_len=1024)
         cfg = dataclasses.replace(cfg, **SPARSE)
+    cfg = dataclasses.replace(cfg, **widths)
     params = _on(jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))), one_chip)
     cache = _on(jax.eval_shape(
         lambda: mr.init_cache(cfg, e.num_pages, e.page_size)), one_chip)
     B, MP = e.max_num_seqs, e.pages_per_seq
+    R = rows or B
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
@@ -130,7 +135,7 @@ def _lower_engine(one_chip, n_layers, bucket, sparse=False):
         "decode_step": lambda: mr.decode_step.lower(
             params, cfg, cache, i32(B), i32(B), i32(B, MP), active),
         "prefill": lambda: mr.prefill.lower(
-            params, cfg, cache, i32(B, bucket), i32(B), i32(B, MP))}
+            params, cfg, cache, i32(R, bucket), i32(R), i32(R, MP))}
 
 
 def test_engine_decode_and_prefill_1b_widths(one_chip):
@@ -154,6 +159,44 @@ def test_expert_layer_programs_compile_at_published_widths(one_chip):
     assert prefill.count("tpu_custom_call") == 8
     assert len(set(re.findall(r"%(moe_gmm_prefill\S*) = bf16\[16384,",
                               prefill))) == 6
+
+
+# the dense serve cell's widths (InternLM2-1.8B) where they differ from "1b"
+INTERNLM2 = dict(d_ff=8192, vocab_size=92544, tie_embeddings=False)
+
+
+@pytest.mark.parametrize("sparse,rows", [(False, 1), (False, None),
+                                         (True, 1), (True, None)],
+                         ids=["internlm2-1x256", "internlm2-8x256",
+                              "olmoe-1x128", "olmoe-16x128"])
+def test_one_row_prefill_compiles_beside_the_padded_batch(one_chip, sparse,
+                                                          rows):
+    """The prefill the engine calls, one admitted request's row at its length
+    bucket ([1, 256] at InternLM2 widths, [1, 128] at OLMoE widths), beside
+    the every-slot batch the benchmark's check still calls; depth cut to 2.
+    Both carry the flash kernel; the sparse one the same count of custom
+    calls and the six grouped matmuls under the name the trace's metric
+    reads, over 128 x 8 = 1,024 assignments where the batch has 16,384. What
+    the compiler says an execution holds live is printed (``-s``), and fits."""
+    bucket = 128 if sparse else 256
+    widths = {} if sparse else INTERNLM2
+    _, lower = _lower_engine(one_chip, n_layers=2, bucket=bucket,
+                             sparse=sparse, rows=rows, **widths)
+    compiled = lower["prefill"]().compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"prefill sparse={sparse} rows={rows or 'all'} x {bucket}: "
+          f"{live} bytes live")
+    assert 0 < live < 16 << 30
+    if sparse:
+        assert text.count("tpu_custom_call") == 8
+        assignments = (rows or 16) * bucket * 8
+        assert len(set(re.findall(
+            rf"%(moe_gmm_prefill\S*) = bf16\[{assignments},", text))) == 6
+    else:
+        assert text.count("tpu_custom_call") == 2  # flash_fwd, once a layer
 
 
 @pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])

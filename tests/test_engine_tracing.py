@@ -112,8 +112,9 @@ def test_metrics_complete_numeric_monotone(engine):
 
 
 def test_prefill_counters_equal_the_hand_count(engine):
-    """Eight prompts fill the eight slots in one prefill of 8 x 16; the
-    ninth waits for a slot and takes a prefill of 8 x 32 alone."""
+    """Eight prompts of 4 to 11 tokens fill the eight slots in one prefill
+    phase of eight one-row calls of 16 positions; the ninth, 20 tokens, waits
+    for a slot and takes one call of 32 alone."""
     m = engine.metrics
     lens = [4, 5, 6, 7, 8, 9, 10, 11]
     for i, n in enumerate(lens):
@@ -123,21 +124,120 @@ def test_prefill_counters_equal_the_hand_count(engine):
     ninth = engine._requests["ninth"]
     engine.step()
     assert (m["prefill_steps"], m["admitted"]) == (1, 8)
-    assert m["prefill_tokens"] == sum(lens)
-    assert m["prefill_batch_tokens"] == SLOTS * BUCKET
+    assert m["prefill_tokens"] == sum(lens) == 60
+    assert m["prefill_batch_tokens"] == 8 * BUCKET == 128
     assert ninth.t_admitted == 0.0
     waited = m["queue_wait_ms"]
     while engine.has_unfinished():
         engine.step()
     assert (m["prefill_steps"], m["admitted"]) == (2, 9)
-    assert m["prefill_tokens"] == sum(lens) + 20
-    assert m["prefill_batch_tokens"] == SLOTS * BUCKET + SLOTS * 2 * BUCKET
+    assert m["prefill_tokens"] == 60 + 20
+    assert m["prefill_batch_tokens"] == 128 + 2 * BUCKET == 160
     # the ninth waited at least one whole step for its slot
     assert ninth.t_admitted - ninth.t_added > 0
     assert m["queue_wait_ms"] - waited == pytest.approx(
         (ninth.t_admitted - ninth.t_added) * 1e3)
     assert m["ttft_ms"] > m["queue_wait_ms"] > 0
     assert m["generated_tokens"] >= 9
+
+
+def test_one_row_calls_leave_what_the_padded_batch_leaves(engine):
+    """A prefill phase of one-row calls, each at its own length bucket (16,
+    32, 16, 64 here), against ONE ``mr.prefill`` on the padded [8, 64] batch
+    of the same admitted set and block tables: the same K and V in every
+    page a prompt owns, the same first-token logits at every admitted slot
+    (bf16 activations: a 1-row and an 8-row product may round apart)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.llm import model_runner as mr
+
+    lens = [5, 20, 11, 40]
+    for i, n in enumerate(lens):
+        engine.add_request(f"r{i}", _prompt(n), SamplingParams(max_tokens=4))
+    engine.step(decode=False)
+    m = engine.metrics
+    assert (m["prefill_steps"], m["admitted"]) == (1, 4)
+    assert m["prefill_batch_tokens"] == 16 + 32 + 16 + 64
+    slots = [engine._requests[f"r{i}"].slot for i in range(4)]
+    tables = engine._block_tables.copy()
+    owned = np.unique(tables[slots])
+    owned = owned[owned > 0]
+    assert len(owned) == 1 + 2 + 1 + 3  # pages of 16 positions
+
+    toks = np.zeros((SLOTS, 64), np.int32)
+    full = np.zeros(SLOTS, np.int32)
+    for slot, n in zip(slots, lens):
+        toks[slot, :n] = _prompt(n)
+        full[slot] = n
+    e = engine.ecfg
+    want, cache = mr.prefill(
+        engine.params, engine.mcfg,
+        mr.init_cache(engine.mcfg, e.num_pages, e.page_size),
+        jnp.asarray(toks), jnp.asarray(full), jnp.asarray(tables))
+    for got_pages, want_pages in ((engine.cache.k, cache.k),
+                                  (engine.cache.v, cache.v)):
+        got_pages = np.asarray(got_pages[:, owned], np.float32)
+        want_pages = np.asarray(want_pages[:, owned], np.float32)
+        assert np.abs(want_pages).max() > 0.1
+        np.testing.assert_allclose(got_pages, want_pages, rtol=2e-2, atol=2e-2)
+    got = np.asarray(engine._prefill_logits)[slots]
+    want = np.asarray(want)[slots]
+    assert np.linalg.norm(got - want) <= 2e-2 * np.linalg.norm(want)
+    # and the tokens the engine went on with are those logits' own
+    firsts = [engine._requests[f"r{i}"].generated[0] for i in range(4)]
+    assert firsts == list(got.argmax(-1))
+
+
+def test_no_shape_depends_on_how_many_were_admitted(engine, monkeypatch):
+    """The benchmark warms one request per reachable length bucket, one at a
+    time, and nothing may compile in its window: after that warm-up, steps
+    that admit 1, 3 and all eight requests at once, of mixed buckets, compile
+    nothing, by the engine's own count and by the benchmark's (JAX's compile
+    events: any program or eager operation at a shape not yet run)."""
+    monkeypatch.syspath_prepend(REPO)
+    from benchmarks.jobs.common import CompileCounter
+
+    m = engine.metrics
+    for n in (10, 20, 40, 100):  # the four buckets of 16..128
+        engine.generate([_prompt(n)], SamplingParams(max_tokens=3))
+    assert m["compiles"] == 5  # four prefill buckets, decode
+    events = CompileCounter()
+    for i, burst in enumerate([(7,), (12, 33, 90), (5, 17, 70, 9, 30, 101, 16,
+                                                   64)]):
+        admitted, calls = m["admitted"], m["prefill_steps"]
+        for j, n in enumerate(burst):
+            engine.add_request(f"b{i}-{j}", _prompt(n),
+                               SamplingParams(max_tokens=2))
+        engine.step()
+        assert m["admitted"] - admitted == len(burst)
+        assert m["prefill_steps"] - calls == 1
+        while engine.has_unfinished():
+            engine.step()
+    assert m["compiles"] == 5
+    assert events.count == 0
+
+
+@pytest.mark.parametrize("others", [(), (9,), (40, 6, 100)],
+                         ids=["alone", "one-short", "three-mixed"])
+def test_seeded_first_token_is_independent_of_the_admitted_set(engine,
+                                                                others):
+    """A seeded request's tokens, the one sampled from prefill's logits
+    first, do not depend on what else the step admitted or on its slot: its
+    call is its own row at its own bucket, its stream its own seed's."""
+    sp = SamplingParams(max_tokens=6, temperature=1.0, seed=1234)
+    alone = engine.generate([_prompt(12)], sp)[0].token_ids
+    for i, n in enumerate(others):  # admitted first: the seeded one's slot moves
+        engine.add_request(f"o{i}", _prompt(n),
+                           SamplingParams(max_tokens=6, temperature=0.7))
+    engine.add_request("seeded", _prompt(12), sp)
+    seeded = engine._requests["seeded"]
+    engine.step()
+    assert seeded.slot == len(others)
+    assert engine.metrics["admitted"] == 1 + len(others) + 1
+    while engine.has_unfinished():
+        engine.step()
+    assert seeded.generated == alone
 
 
 @pytest.mark.parametrize("enter,name,attrs", [

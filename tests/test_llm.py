@@ -155,6 +155,25 @@ def test_continuous_batching_matches_sequential(engine):
     assert all(o.finished for o in batched)
 
 
+@pytest.mark.parametrize("sp", [
+    SamplingParams(max_tokens=8),
+    SamplingParams(max_tokens=8, temperature=0.9, top_k=16, seed=5),
+], ids=["greedy", "seeded"])
+def test_burst_of_four_length_buckets_matches_one_at_a_time(engine, sp):
+    """Prompts of 9, 25, 50 and 100 tokens (buckets 16 to 128) admitted in
+    one step are each prefilled alone at their own bucket: the burst gives
+    every prompt the tokens it gets when served alone, and the device
+    computed 16 + 32 + 64 + 128 positions for it, not 4 x 128."""
+    prompts = [list(range(3, 3 + n)) for n in (9, 25, 50, 100)]
+    singles = [engine.generate([p], sp)[0].token_ids for p in prompts]
+    computed = engine.metrics["prefill_batch_tokens"]
+    phases = engine.metrics["prefill_steps"]
+    burst = engine.generate(prompts, sp)
+    assert [o.token_ids for o in burst] == singles
+    assert engine.metrics["prefill_batch_tokens"] - computed == 240
+    assert engine.metrics["prefill_steps"] - phases == 1
+
+
 def test_generation_crosses_page_boundaries(engine):
     """Prompt of 14 + 40 new tokens crosses several 16-token pages."""
     prompt = list(range(3, 17))
