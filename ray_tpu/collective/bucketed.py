@@ -1,7 +1,8 @@
 """Bucketed asynchronous gradient collectives + cross-replica sharded update.
 
-The explicit-collective tier of the overlapped train step (the GSPMD tier
-lives in ``parallel/train.py``): a size-bounded bucket plan over the grad
+The explicit-collective tier of gradient sync, for workers that are
+separate processes (the device step's own sync is inside its one GSPMD
+program, ``parallel/train.py``): a size-bounded bucket plan over the grad
 tree (layer order), an async reducer that ships each bucket through
 ``ray_tpu.collective`` ops on a background thread — so bucket i's
 allreduce runs while the caller is still producing bucket i+1's grads or
@@ -26,8 +27,7 @@ folds that vector in tree order — the same association
 single-process reference bit-for-bit given bitwise-equal reduced grads.
 
 Every bucket collective lands as a ``train.bucket_allreduce`` span
-(nested under whatever span is active at submit time, e.g.
-``train.fwd_bwd``) and in the ``ray_tpu.train.allreduce_seconds``
+(nested under whatever span is active at submit time) and in the ``ray_tpu.train.allreduce_seconds``
 histogram, so ``/api/timeline`` shows the overlap.
 """
 

@@ -354,10 +354,10 @@ class XlaGroup:
 
     def allreduce_quantized(self, wire: dict, codec) -> dict:
         raise NotImplementedError(
-            "the XLA tier quantizes INSIDE compiled programs — use "
-            "collective.quant.quantized_psum_scatter_1d (or the traced "
-            "TrainStepBundle compression= path) instead of the explicit "
-            "store-actor exchange; the CPU backend implements this method")
+            "the XLA backend has no quantized exchange: its collectives "
+            "are compiled programs in the tensors' own dtype; the "
+            "explicit store-actor exchange is the CPU backend's "
+            "(grad_sync_backend=\"cpu\")")
 
     def broadcast(self, tensor, src_rank: int = 0):
         import jax

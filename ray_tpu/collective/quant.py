@@ -10,13 +10,13 @@ blocks of the flattened input:
   dtype jax itself depends on): per-block scaling maps the block amax to
   the e4m3 max (448), then a saturating cast; 1 byte/element.
 - ``bf16``  — a plain dtype narrowing (no scales); 2 bytes/element. Not a
-  block codec, but resolving here lets ``grad_dtype="bf16"`` ride the same
-  wire plumbing as the quantized tiers.
+  block codec, but resolving here lets ``grad_sync_compression="bf16"``
+  ride the same wire plumbing as the quantized tiers.
 
 The codecs are **pure numpy** so the CollectiveStore actor (the CPU-tier
-reduce point) can dequant-accumulate without importing jax; a jitted
-quantize→all_to_all→dequant reduce-scatter for on-device (ICI) byte
-reduction lives in :func:`quantized_psum_scatter_1d`.
+reduce point) can dequant-accumulate without importing jax. There is no
+on-device (ICI) quantized collective: the device step's gradient sync is
+the GSPMD program's own reduce-scatter, in the gradients' dtype.
 
 Error feedback (:class:`ErrorFeedback`): quantization error is *carried*,
 not lost — the caller adds the residual before encoding and stores
@@ -308,99 +308,3 @@ def reduce_wire_payloads(payloads, codec_spec: str) -> Dict[str, Any]:
             e = np.asarray(p["extra"], np.float32)
             extra = e if extra is None else extra + e
     return to_wire(quantize(total, codec), extra=extra)
-
-
-# -- XLA tier: jitted quantize -> all_to_all -> dequant reduce-scatter ------
-
-
-def jnp_block_encode(xb, codec_name: str):
-    """Traced (jnp) flavor of the block encode — the ONE home for the
-    quantization math shared by every XLA-tier program
-    (:func:`quantized_psum_scatter_1d` below and the TrainStepBundle
-    per-bucket reduce-scatter). ``xb`` is ``(..., nblocks, block)`` fp32;
-    returns ``(codes, scales)`` with scales shaped ``(..., nblocks)``."""
-    import jax.numpy as jnp
-
-    # same finite-safe contract as the numpy _sanitize_blocks: NaN -> 0,
-    # ±inf saturates to the block's finite amax — one overflowed element
-    # must not turn the block scale (and thus all `block` decoded values)
-    # into inf/NaN. Unconditional (no finite.all() fast path inside a
-    # traced program).
-    finite = jnp.isfinite(xb)
-    xf = jnp.where(finite, xb, 0.0)
-    amax = jnp.max(jnp.abs(xf), axis=-1)
-    cap = jnp.where(amax > 0, amax, 1.0)[..., None]
-    xb = jnp.where(jnp.isnan(xb), 0.0, jnp.clip(xb, -cap, cap))
-    if codec_name == "int8":
-        scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-        q = jnp.clip(jnp.round(xb / scale[..., None]),
-                     -127, 127).astype(jnp.int8)
-    else:  # fp8: clamp BEFORE the saturating cast — e4m3fn overflows to
-        # NaN above the finite max, and the fp32 division can land one
-        # ulp above it even though the scale maps amax to FP8_MAX exactly
-        scale = jnp.where(amax > 0, amax / FP8_MAX, 1.0)
-        q = jnp.clip(xb / scale[..., None], -FP8_MAX,
-                     FP8_MAX).astype(jnp.float8_e4m3fn)
-    return q, scale
-
-
-def quantized_psum_scatter_1d(mesh, axis_name: str, codec: QuantCodec):
-    """Build a jitted shard_map program computing ``psum_scatter`` of a
-    flat fp32 vector with int8/fp8 bytes on the wire.
-
-    Decomposition (the standard quantized-allreduce reduce-scatter leg):
-    each device splits its local vector into N per-owner segments,
-    block-quantizes each segment, ``all_to_all``s the uint8 codes + fp32
-    scales (THE wire leg — 1 byte/element instead of 4), then
-    dequant-accumulates its own segment in fp32. Output = this device's
-    tiled segment of the sum, exactly ``psum_scatter(..., tiled=True)``
-    semantics (to quantization error).
-
-    The local vector length must be divisible by ``N`` (callers pad);
-    block padding is internal (static shapes — the pad amount folds into
-    the program). Returns ``fn(local_vec) -> owned_segment``.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    n = int(np.prod([s for nme, s in zip(mesh.axis_names, mesh.devices.shape)
-                     if nme == axis_name]))
-    block = codec.block
-    if codec.name == "bf16":
-        def f(x):
-            seg = x.reshape(n, -1).astype(jnp.bfloat16)  # wire dtype
-            mine = jax.lax.all_to_all(seg, axis_name, split_axis=0,
-                                      concat_axis=0, tiled=False)
-            return jnp.sum(mine.astype(jnp.float32), axis=0)
-    else:
-        def f(x):
-            seg_len = x.shape[0] // n
-            nb = -(-seg_len // block)
-            pad = nb * block - seg_len
-            seg = x.reshape(n, seg_len)
-            if pad:
-                seg = jnp.pad(seg, ((0, 0), (0, pad)))
-            seg = seg.reshape(n, nb, block)
-            q, scale = jnp_block_encode(seg, codec.name)
-            # THE wire leg: 1-byte codes + per-block scales cross devices
-            qg = jax.lax.all_to_all(q, axis_name, split_axis=0,
-                                    concat_axis=0, tiled=False)
-            sg = jax.lax.all_to_all(scale, axis_name, split_axis=0,
-                                    concat_axis=0, tiled=False)
-            vals = qg.astype(jnp.float32) * sg[..., None]
-            return jnp.sum(vals, axis=0).reshape(-1)[:seg_len]
-
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=P(axis_name),
-                             out_specs=P(axis_name), check_vma=False))
-
-
-def xla_wire_bytes(n_elements: int, world: int, codec: Optional[QuantCodec]
-                   ) -> int:
-    """Per-device wire bytes of one reduce-scatter leg over ``n_elements``
-    (the (N-1)/N share that actually crosses links; fp32 when codec is
-    None). Analytic — CPU-emulated meshes have no byte counters."""
-    frac = (world - 1) / max(world, 1)
-    per = 4.0 if codec is None else codec.bytes_per_element
-    return int(n_elements * per * frac)

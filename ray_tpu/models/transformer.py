@@ -88,25 +88,6 @@ class TransformerConfig:
                              if moe else dense_mlp)
         return v * d + total + d + (0 if self.tie_embeddings else d * v)
 
-    def active_params(self) -> int:
-        """Params touched per token: MoE layers count only the
-        experts_per_token experts a token is routed to (MFU accounting)."""
-        if self.n_experts == 0:
-            return self.num_params()
-        d, f = self.d_model, self.d_ff
-        total = self.num_params()
-        for i in range(self.n_layers):
-            if i % max(self.moe_every, 1) == 0:
-                inactive = self.n_experts - self.experts_per_token
-                total -= inactive * 3 * d * f
-        return total
-
-    def flops_per_token(self) -> float:
-        """Approximate training FLOPs/token (fwd+bwd ~ 6*N_active +
-        attention)."""
-        return (6.0 * self.active_params()
-                + 12.0 * self.n_layers * self.d_model * self.max_seq_len)
-
 
 # preset configs (name -> config); "tiny" is the CI/test config
 CONFIGS = {

@@ -41,14 +41,10 @@ class ScalingConfig:
     # wire compression for the bucketed grad sync (collective/quant.py):
     # None (fp32, bit-identical to the uncompressed tier), "int8" / "fp8"
     # (block-quantized with error feedback; ~4x fewer wire bytes) or
-    # "bf16" (plain narrowing, 2x). Strictly opt-in; CPU backend only at
-    # this tier — on-device programs use TrainStepBundle(compression=...).
+    # "bf16" (plain narrowing, 2x). Strictly opt-in; CPU backend only —
+    # the device step (TrainStepBundle) syncs gradients inside its one
+    # GSPMD program, in their own dtype.
     grad_sync_compression: Optional[str] = None
-    # collective dtype of the TrainStepBundle sharded-path grad
-    # reduce-scatter: "fp32" (default, preserves the PR 12 bit-exact
-    # contract) or "bf16" (halves collective bytes; optimizer + params
-    # stay fp32 master copies). Composes with grad_sync_compression.
-    grad_dtype: str = "fp32"
 
     def bundle(self) -> Dict[str, float]:
         res = dict(self.resources_per_worker)

@@ -53,13 +53,12 @@ class TrainWorker:
 
         # fail at setup, not mid-train: only the CPU store-actor backend
         # implements the explicit quantized exchange (XlaGroup raises at
-        # the first bucket otherwise — the XLA tier quantizes inside
-        # compiled programs via TrainStepBundle(compression=...))
+        # the first bucket otherwise)
         if resolve_codec(compression) is not None and backend != "cpu":
             raise ValueError(
                 f"grad_sync_compression={compression!r} requires "
-                f"grad_sync_backend='cpu' (got {backend!r}); on-device "
-                f"programs use TrainStepBundle(compression=...) instead")
+                f"grad_sync_backend='cpu' (got {backend!r}): the XLA "
+                f"backend has no quantized exchange")
         init_sharded_optimizer_groups(self.world_size, self.rank,
                                       backend=backend, base_name=group_name)
         # a group is dedicated to ONE reducer (ops match by sequence

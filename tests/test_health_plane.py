@@ -428,20 +428,15 @@ def test_train_and_serve_builtin_spans(health_cluster, tmp_path):
     handle = serve.run(Echo.bind(), name="span_echo")
     assert ray_tpu.get(handle.remote(21), timeout=120) == 42
 
-    # spans: train phases from this process, serve phases cluster-wide
+    # spans: the train step from this process, serve phases cluster-wide
     def _spans():
         spans = tracing.get_spans()
         names = {s["name"] for s in spans}
-        want = {"train.step", "train.fwd_bwd", "train.optimizer",
-                "serve.route", "serve.queue", "serve.execute"}
+        want = {"train.step", "serve.route", "serve.queue", "serve.execute"}
         return spans if want <= names else None
 
     spans = _wait_for(_spans, timeout=30)
     assert spans is not None, {s["name"] for s in tracing.get_spans()}
-    by_name = {s["name"]: s for s in spans}
-    # the phase spans tree up under train.step
-    assert by_name["train.fwd_bwd"]["parent_id"] == \
-        by_name["train.step"]["span_id"]
 
     # chrome trace: the built-in spans render as slices
     out = str(tmp_path / "trace.json")
@@ -462,7 +457,6 @@ def test_train_and_serve_builtin_spans(health_cluster, tmp_path):
                                     timeout=30) as r:
             body = r.read().decode()
         want = ("ray_tpu_train_step_seconds_bucket",
-                "ray_tpu_train_fwd_bwd_seconds_count",
                 "ray_tpu_serve_execute_seconds_bucket",
                 "ray_tpu_serve_queue_seconds_count",
                 "ray_tpu_serve_requests")

@@ -61,7 +61,7 @@ def cluster():
     ray_tpu.shutdown()
     # fully restore tracing state: _enabled is a process-level cache, and
     # leaving it on would silently put every later test module in this
-    # pytest process on the traced (span-recording, phase-split) paths
+    # pytest process on the span-recording paths
     if prev is None:
         os.environ.pop("RAY_TPU_ENABLE_TRACING", None)
     else:
@@ -476,8 +476,8 @@ def test_bench_pipeline_smoke(cluster, tmp_path):
 
     out = str(tmp_path / "PIPE_smoke.json")
     # bench the untraced paths (the real PIPE_r* condition): the module
-    # fixture's tracing would otherwise switch bundle.step to the
-    # phase-split programs and double the smoke's compile bill
+    # fixture's tracing would otherwise record a span a step and a hop
+    # inside the timed loops
     tracing._enabled = False
     try:
         rows = bench_main(stages=(2,), microbatches=2, microbatch_size=1,

@@ -1,6 +1,5 @@
 """Quantized + delta comms tier (collective/quant.py + the compression
-knobs on the bucketed collectives, the traced train step, and PPO grad
-sync).
+knobs on the bucketed collectives and PPO grad sync).
 
 Contracts pinned here:
 
@@ -13,9 +12,7 @@ Contracts pinned here:
   equal tree size, and every rank still ends bitwise-identical to its
   peers;
 - compression is STRICTLY opt-in: compression=None paths reproduce the
-  PR 12 fp32 behavior exactly (bitwise), including the sharded-step
-  bit-exact contract (grad_dtype="fp32" default builds the identical
-  programs — asserted against the fused step).
+  PR 12 fp32 behavior exactly (bitwise).
 """
 
 import numpy as np
@@ -289,86 +286,6 @@ def test_sharded_optimizer_quantized_ranks_identical(cluster):
         assert np.abs(p0[key] - np.asarray(ref[key])).max() < 0.05 * denom
     for a in ranks:
         ray_tpu.kill(a)
-
-
-# ---------------------------------------------------------------------------
-# XLA tier: jitted quantize -> all_to_all -> dequant reduce-scatter
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("name,tol", [("int8", 0.02), ("fp8", 0.06),
-                                      ("bf16", 0.02)])
-def test_xla_quantized_reduce_scatter_matches_psum_scatter(name, tol):
-    import jax
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.array(jax.devices()), ("data",))
-    n = len(jax.devices())
-    fn = quant.quantized_psum_scatter_1d(mesh, "data", QuantCodec(name, 64))
-    rng = np.random.default_rng(0)
-    for L in (n * n * 64 * 2, n * n * 3):  # block-aligned AND ragged
-        x = rng.normal(size=L).astype(np.float32)
-        out = np.asarray(fn(x))
-        expect = x.reshape(n, n, -1).sum(axis=0).reshape(-1)
-        assert out.shape == (L // n,)
-        rel = np.abs(out - expect).max() / np.abs(expect).max()
-        assert rel < tol, (name, L, rel)
-    # the analytic wire accounting the bench reports: int8 ~4x under fp32
-    fp32 = quant.xla_wire_bytes(1 << 20, n, None)
-    q = quant.xla_wire_bytes(1 << 20, n, QuantCodec("int8"))
-    assert fp32 / q >= 3.5
-
-
-def test_traced_bundle_compression_and_bf16_flavors():
-    """TrainStepBundle: the traced sharded step with compression="int8"
-    and grad_dtype="bf16" both track the fp32 traced step; the default
-    (fp32, no compression) build path is byte-identical to PR 12 (same
-    program objects, no codec)."""
-    import os
-
-    import jax
-
-    from ray_tpu.models import CONFIGS
-    from ray_tpu.parallel import TrainStepBundle, create_mesh, make_optimizer
-    from ray_tpu.util import tracing
-
-    devs = jax.devices()
-    mesh = create_mesh({"data": len(devs), "fsdp": 1, "seq": 1, "tensor": 1,
-                        "expert": 1}, devices=devs)
-    factory = lambda spec_fn: make_optimizer(  # noqa: E731
-        learning_rate=1e-3, warmup_steps=5, total_steps=100,
-        clip_spec_fn=spec_fn)
-
-    def run(**kw):
-        b = TrainStepBundle(CONFIGS["tiny"], mesh, optimizer_factory=factory,
-                            shard_update=True, bucket_bytes=1 << 20, **kw)
-        params, opt = b.init_sharded(jax.random.PRNGKey(0))
-        batch = b.make_batch(np.random.default_rng(0), 16, 64)
-        params, opt, loss = b.step(params, opt, batch)
-        return b, float(loss), jax.tree_util.tree_leaves(params)[0]
-
-    base = TrainStepBundle(CONFIGS["tiny"], mesh, optimizer_factory=factory,
-                           shard_update=True, bucket_bytes=1 << 20)
-    assert base._codec is None and base.grad_dtype == "fp32"
-
-    was = tracing.enabled()
-    tracing.enable()
-    try:
-        _, loss_f, leaf_f = run()
-        _, loss_q, leaf_q = run(compression="int8")
-    finally:
-        if not was:
-            tracing._enabled = False
-            os.environ.pop("RAY_TPU_ENABLE_TRACING", None)
-    assert abs(loss_q - loss_f) <= 0.02 * abs(loss_f)
-    rel = np.abs(np.asarray(leaf_q) - np.asarray(leaf_f)).max()
-    assert rel < 0.01, rel
-    # bf16 grad narrowing on the one-program sharded path stays close to
-    # fp32 (master accumulation: opt state + params remain fp32)
-    _, loss_b, _ = run(grad_dtype="bf16")
-    assert abs(loss_b - loss_f) <= 0.02 * abs(loss_f)
-    with pytest.raises(ValueError):
-        run(grad_dtype="fp16")
 
 
 # ---------------------------------------------------------------------------

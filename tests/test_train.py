@@ -1,14 +1,15 @@
 """Train layer e2e: JaxTrainer with checkpointing + failure recovery,
-plus the overlapped/cross-replica-sharded train step (PR 12):
+plus the one-program train step and its cross-replica-sharded layout:
 
-- the sharded single-program step is BIT-EXACT in fp32 against the fused
-  step over multiple steps on the 8-device CPU mesh (params AND opt state
-  after all-gather, global-norm clip engaged and included);
+- the sharded step is BIT-EXACT in fp32 against the fused step over
+  multiple steps on the 8-device CPU mesh (params AND opt state after
+  all-gather, global-norm clip engaged and included), with even and with
+  wildly uneven masks;
 - optimizer-state memory per replica is ~1/N of the unsharded state;
+- tracing on or off, `step` runs the same executable and returns the same
+  bits; the phases are named scopes inside the program; NO XLA
+  buffer-donation/alias warnings appear anywhere;
 - bucket-plan boundary cases (giant leaf, many tiny leaves);
-- the traced sharded path emits `train.bucket_allreduce` spans nested
-  under `train.fwd_bwd`, and NO XLA buffer-donation/alias warnings appear
-  anywhere (donation restored on the split path);
 - the bucketed collective tier (AsyncBucketReducer/ShardedBucketOptimizer)
   reduces correctly across ranks and keeps 1/N opt state;
 - JaxTrainer wires grad sync into the train context.
@@ -182,7 +183,7 @@ def _bitwise_equal_trees(a, b, repl):
 def sharded_bundle():
     """One tiny-config bundle on the 8-device mesh, clip LOW enough that
     the global-norm clip actually engages every step — plus the captured
-    warnings from compiling/running every program flavor."""
+    warnings from compiling/running every program of the bundle."""
     import jax
     from ray_tpu.models.transformer import CONFIGS
     from ray_tpu.parallel import TrainStepBundle, create_mesh, make_optimizer
@@ -196,7 +197,7 @@ def sharded_bundle():
     with warnings.catch_warnings(record=True) as wrec:
         warnings.simplefilter("always")
         bundle = TrainStepBundle(cfg, mesh, optimizer_factory=factory,
-                                 shard_update=True, bucket_bytes=64 << 10)
+                                 shard_update=True)
         batch = bundle.make_batch(np.random.default_rng(0), 16, 64)
         runs = {}
         # fused (unsharded) reference, 3 steps
@@ -204,22 +205,13 @@ def sharded_bundle():
         for _ in range(3):
             pf, sf, lf = bundle._fused_step(pf, sf, batch)
         runs["fused"] = (pf, sf, float(lf))
-        # sharded single-program step (the untraced perf path), 3 steps
+        # sharded step (what `step` dispatches with shard_update on)
         ps, ss = bundle.init_sharded(jax.random.PRNGKey(0))
         for _ in range(3):
             ps, ss, ls = bundle.step(ps, ss, batch)
         runs["sharded"] = (ps, ss, float(ls))
-        # split paths (the traced-tier programs), 3 steps each
-        pa, sa = bundle.init(jax.random.PRNGKey(0))
-        for _ in range(3):
-            la, ga = bundle._fwd_bwd(pa, batch)
-            pa, sa = bundle._opt_apply(ga, sa, pa)
-        runs["split"] = (pa, sa, float(la))
-        pb, sb = bundle.init_sharded(jax.random.PRNGKey(0))
-        for _ in range(3):
-            lb, gb = bundle._fwd_bwd_rs(pb, batch)
-            pb, sb = bundle._opt_apply_sharded(gb, sb, pb)
-        runs["split_sharded"] = (pb, sb, float(lb))
+        # the check program (the benchmark's gradient check reads it)
+        runs["fwd_bwd"] = bundle._fwd_bwd(pf, batch)
     return {"bundle": bundle, "batch": batch, "runs": runs,
             "warnings": [str(w.message) for w in wrec]}
 
@@ -235,7 +227,7 @@ def test_sharded_update_bitexact_vs_fused(sharded_bundle):
     pf, sf, lf = sharded_bundle["runs"]["fused"]
     ps, ss, ls = sharded_bundle["runs"]["sharded"]
     # clip engaged: the raw grad norm exceeds the 0.05 threshold
-    _, grads = b._fwd_bwd(pf, sharded_bundle["batch"])
+    _, grads = sharded_bundle["runs"]["fwd_bwd"]
     gnorm = float(np.sqrt(sum(
         float(np.sum(np.square(np.asarray(g, dtype=np.float64))))
         for g in jax.tree_util.tree_leaves(grads))))
@@ -245,20 +237,9 @@ def test_sharded_update_bitexact_vs_fused(sharded_bundle):
     assert lf == ls
 
 
-def test_split_sharded_matches_split_unsharded(sharded_bundle):
-    """The phase-split programs agree with each other bitwise too (the
-    traced tier keeps the same numerics whether the update is sharded)."""
-    b = sharded_bundle["bundle"]
-    pa, sa, _ = sharded_bundle["runs"]["split"]
-    pb, sb, _ = sharded_bundle["runs"]["split_sharded"]
-    assert _bitwise_equal_trees(pa, pb, b.repl) == []
-    assert _bitwise_equal_trees(sa, b.unshard_opt_state(sb), b.repl) == []
-
-
 def test_no_donation_alias_warnings(sharded_bundle):
-    """Donation restored on the split path (grads donated in _opt_apply,
-    params+opt in the sharded flavor): compiling and running every
-    program flavor must produce zero XLA donation/alias warnings."""
+    """Compiling and running every program of the bundle (fused, fused
+    sharded, `_fwd_bwd`) produces zero XLA donation/alias warnings."""
     bad = [w for w in sharded_bundle["warnings"]
            if "donat" in w.lower() or "alias" in w.lower()]
     assert bad == [], f"XLA donation warnings: {bad[:2]}"
@@ -306,82 +287,177 @@ def test_bucket_plan_boundary_cases():
         plan_buckets(meta, bucket_bytes=0)
 
 
-def test_traced_sharded_step_spans(sharded_bundle):
-    """Tracing ON routes the sharded step through the explicit bucketed
-    pipeline: per-bucket reduce programs, each a `train.bucket_allreduce`
-    span nested under `train.fwd_bwd` (what /api/timeline renders)."""
+def _uneven_mask_batch(sharded_bundle):
+    """4 valid tokens on replica 0, 128 on each other replica."""
     import jax
-    from ray_tpu.util import tracing
 
-    b = sharded_bundle["bundle"]
-    batch = sharded_bundle["batch"]
-    ps, ss = b.init_sharded(jax.random.PRNGKey(0))
-    tracing.enable()
-    try:
-        before = len(tracing._buffer)
-        ps, ss, loss = b.step(ps, ss, batch)
-        spans = list(tracing._buffer)[before:]
-    finally:
-        tracing._enabled = False
-        os.environ.pop("RAY_TPU_ENABLE_TRACING", None)
-    names = [s["name"] for s in spans]
-    n_buckets = b.bucket_plan.num_buckets
-    assert n_buckets > 1
-    assert names.count("train.bucket_allreduce") == n_buckets
-    assert names.count("train.fwd_bwd") == 1
-    assert names.count("train.optimizer") == 1
-    fwd_ids = {s["span_id"] for s in spans if s["name"] == "train.fwd_bwd"}
-    assert all(s["parent_id"] in fwd_ids for s in spans
-               if s["name"] == "train.bucket_allreduce")
-    # and the same spans render through the PR 10 timeline path (what
-    # GET /api/timeline serves): complete slices with bucket attrs
-    from ray_tpu.util.tracing import spans_to_chrome_events
-
-    events = spans_to_chrome_events(spans)
-    slices = [e for e in events if e.get("ph") == "X"
-              and e.get("name") == "train.bucket_allreduce"]
-    assert len(slices) == n_buckets
-    assert all("bucket" in (e.get("args") or {}) for e in slices)
-    # the traced (explicit-bucket) step trains the same objective: its
-    # loss matches the untraced sharded step's first-step loss closely
-    # (per-replica local-batch kernels differ from the fused program at
-    # ulp level, so this is allclose, not bitwise — OVERLAP.md)
-    p0, s0 = b.init_sharded(jax.random.PRNGKey(0))
-    _, _, l0 = b.step(p0, s0, batch)
-    assert float(loss) == pytest.approx(float(l0), rel=1e-4)
-
-
-def test_traced_sharded_step_uneven_masks(sharded_bundle):
-    """The explicit bucketed path must weight replicas by their valid-
-    token counts (the fused step's global normalization), not average
-    per-replica means — regression for the mean-of-means bug: with wildly
-    uneven masks across data shards, one traced step still reproduces the
-    untraced sharded step's loss and params to fp32 tolerance."""
-    import jax
-    from ray_tpu.util import tracing
-
-    b = sharded_bundle["bundle"]
     batch = dict(sharded_bundle["batch"])
     mask = np.zeros((16, 64), np.float32)
-    mask[0, :4] = 1.0    # replica 0: 4 valid tokens
-    for row in range(2, 16):
-        mask[row] = 1.0  # replicas 1..7: 128 each
-    batch["mask"] = jax.device_put(mask, b.batch_sharding)
-    p0, s0 = b.init_sharded(jax.random.PRNGKey(0))
-    p0, s0, l_ref = b.step(p0, s0, batch)  # untraced sharded (fused prog)
+    mask[0, :4] = 1.0
+    mask[2:] = 1.0
+    batch["mask"] = jax.device_put(mask, sharded_bundle["bundle"].batch_sharding)
+    return batch
+
+
+@pytest.fixture
+def traced():
+    """Tracing on for one test; off again (and the buffer's new spans
+    handed back) whatever the test does."""
+    from ray_tpu.util import tracing
+
+    was, env = tracing._enabled, os.environ.get("RAY_TPU_ENABLE_TRACING")
     tracing.enable()
+    before = len(tracing._buffer)
     try:
-        p1, s1 = b.init_sharded(jax.random.PRNGKey(0))
-        p1, s1, l_tr = b.step(p1, s1, batch)
+        yield lambda: list(tracing._buffer)[before:]
     finally:
-        tracing._enabled = False
-        os.environ.pop("RAY_TPU_ENABLE_TRACING", None)
-    assert float(l_tr) == pytest.approx(float(l_ref), rel=1e-4)
-    for x, y in zip(jax.tree_util.tree_leaves(p0),
-                    jax.tree_util.tree_leaves(p1)):
-        np.testing.assert_allclose(
-            np.asarray(jax.device_put(x, b.repl)),
-            np.asarray(jax.device_put(y, b.repl)), atol=5e-4, rtol=1e-3)
+        tracing._enabled = was
+        if env is None:
+            os.environ.pop("RAY_TPU_ENABLE_TRACING", None)
+        else:
+            os.environ["RAY_TPU_ENABLE_TRACING"] = env
+
+
+def _one_step(bundle, shard_update, batch):
+    """A fresh bundle-shaped step from PRNGKey(0) on the layout asked for;
+    `bundle.shard_update` is flipped for the call so that ONE set of
+    compiled programs serves both cases."""
+    import jax
+
+    was = bundle.shard_update
+    bundle.shard_update = shard_update
+    try:
+        init = bundle.init_sharded if shard_update else bundle.init
+        p, s = init(jax.random.PRNGKey(0))
+        p, s, loss = bundle.step(p, s, batch)
+        if shard_update:
+            s = bundle.unshard_opt_state(s)
+        return jax.block_until_ready((p, s, loss))
+    finally:
+        bundle.shard_update = was
+
+
+@pytest.mark.parametrize("shard_update", [False, True],
+                         ids=["fused", "fused_sharded"])
+def test_tracing_does_not_select_the_program(sharded_bundle, traced,
+                                             shard_update):
+    """With tracing enabled `step` returns params, opt state and loss
+    bitwise equal to the run with tracing off, compiles no further
+    program, and records one `train.step` span (and no phase span: the
+    phases are scopes inside the program)."""
+    from ray_tpu.util import tracing
+
+    b, batch = sharded_bundle["bundle"], sharded_bundle["batch"]
+    program = b._fused_step_sharded if shard_update else b._fused_step
+    tracing._enabled = False
+    p0, s0, l0 = _one_step(b, shard_update, batch)
+    compiled = program._cache_size()
+    tracing._enabled = True
+    assert tracing.enabled()
+    p1, s1, l1 = _one_step(b, shard_update, batch)
+    assert program._cache_size() == compiled
+    assert _bitwise_equal_trees(p0, p1, b.repl) == []
+    assert _bitwise_equal_trees(s0, s1, b.repl) == []
+    assert float(l0) == float(l1)
+    names = [s["name"] for s in traced() if s["name"].startswith("train.")]
+    assert names == ["train.step"]
+
+
+def test_sharded_step_uneven_masks_bitexact(sharded_bundle):
+    """The loss is normalised by the GLOBAL count of valid tokens, not
+    averaged over per-replica means: with wildly uneven masks across the
+    data shards the sharded step still equals the fused step bitwise."""
+    import jax
+
+    b = sharded_bundle["bundle"]
+    batch = _uneven_mask_batch(sharded_bundle)
+    pf, sf, lf = _one_step(b, False, batch)
+    ps, ss, ls = _one_step(b, True, batch)
+    assert _bitwise_equal_trees(pf, ps, b.repl) == []
+    assert _bitwise_equal_trees(sf, ss, b.repl) == []
+    assert float(lf) == float(ls)
+    # and it IS the global normalisation: replica 0's four tokens weigh
+    # 4 / 1796, not the 1 / 8 a mean of per-replica means would give them
+    p0, _ = b.init(jax.random.PRNGKey(0))
+    mask = np.asarray(batch["mask"])
+    part = {}
+    for name, rows in (("replica0", slice(0, 2)), ("others", slice(2, 16))):
+        m = np.zeros_like(mask)
+        m[rows] = mask[rows]
+        part[name] = float(b.eval_step(
+            p0, {**batch, "mask": jax.device_put(m, b.batch_sharding)}))
+    n0, n = 4.0, float(mask.sum())
+    global_mean = (n0 * part["replica0"] + (n - n0) * part["others"]) / n
+    mean_of_means = (part["replica0"] + 7 * part["others"]) / 8
+    assert float(lf) == pytest.approx(global_mean, rel=1e-5)
+    assert abs(float(lf) - mean_of_means) > 1e-4 * float(lf)
+
+
+def test_check_program_is_the_trained_program(sharded_bundle):
+    """`_fwd_bwd` (what the benchmark's gradient check reads) computes the
+    step's own loss: bitwise `_fused_step`'s on the same params and
+    batch."""
+    import jax
+
+    b, batch = sharded_bundle["bundle"], sharded_bundle["batch"]
+    p, s = b.init(jax.random.PRNGKey(0))
+    loss_check, _ = b._fwd_bwd(p, batch)
+    _, _, loss_step = b._fused_step(p, s, batch)
+    assert float(loss_check) == float(loss_step)
+
+
+def test_phases_are_named_in_the_program(sharded_bundle):
+    """A device trace finds the phases by the scopes the lowered step
+    carries, in both layouts; the check program carries the backward's."""
+    import jax
+
+    b, batch = sharded_bundle["bundle"], sharded_bundle["batch"]
+
+    def abstract(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            tree, shardings)
+
+    p = abstract(b._abstract_params, b.param_shardings)
+    for program, opt_sh in ((b._fused_step, b.opt_shardings),
+                            (b._fused_step_sharded, b.opt_shard_shardings)):
+        text = program.lower(p, abstract(b._abstract_opt, opt_sh),
+                             batch).as_text(debug_info=True)
+        assert "train.fwd_bwd" in text and "train.optimizer" in text
+    text = b._fwd_bwd.lower(p, batch).as_text(debug_info=True)
+    assert "train.fwd_bwd" in text and "train.optimizer" not in text
+
+
+def test_removed_options_are_gone(sharded_bundle):
+    from ray_tpu.parallel import TrainStepBundle
+
+    b = sharded_bundle["bundle"]
+    for option in ({"compression": "int8"}, {"grad_dtype": "bf16"},
+                   {"bucket_bytes": 1 << 20}):
+        with pytest.raises(TypeError):
+            TrainStepBundle(b.cfg, b.mesh, shard_update=True, **option)
+
+
+@pytest.mark.parametrize("program",
+                         ["fused", "fused_sharded", "fwd_bwd", "eval"])
+def test_every_program_of_the_bundle_runs(sharded_bundle, program):
+    """One call of each jitted program on the 8-device CPU mesh: a finite
+    loss (and, where the program returns them, finite gradients)."""
+    import jax
+
+    b, batch = sharded_bundle["bundle"], sharded_bundle["batch"]
+    if program == "fused":
+        loss = b._fused_step(*b.init(jax.random.PRNGKey(1)), batch)[2]
+    elif program == "fused_sharded":
+        loss = b._fused_step_sharded(
+            *b.init_sharded(jax.random.PRNGKey(1)), batch)[2]
+    elif program == "fwd_bwd":
+        loss, grads = b._fwd_bwd(b.init(jax.random.PRNGKey(1))[0], batch)
+        assert all(np.isfinite(np.asarray(g)).all()
+                   for g in jax.tree_util.tree_leaves(grads))
+    else:
+        loss = b.eval_step(b.init(jax.random.PRNGKey(1))[0], batch)
+    assert np.isfinite(float(loss))
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Pipeline-parallel microbenchmark: 1F1B bubble + throughput vs single
-mesh (bench.py-style JSON output; writes PIPE_r*.json at the repo root).
+mesh (one JSON line; writes PIPE_r*.json at the repo root).
 
 Measures, per stage count S (default 2 and 4, M microbatches each) and
 optionally per interleave factor V (``--interleave``):

@@ -98,9 +98,8 @@ def main() -> int:
             f"wire bytes; see collective/QUANT.md). CPU-tier caveat: the "
             f"'wire' here is same-host shared memory (free), so the SPS "
             f"ratio prices the ENCODE overhead only — the byte reduction "
-            f"pays on DCN/ICI-bound multi-host learner groups, where the "
-            f"traced tier runs the jitted quantize->all_to_all->dequant "
-            f"programs over the real interconnect."),
+            f"would have to pay on DCN-bound multi-host learner groups, "
+            f"which no run has measured."),
         "wall_s": round(time.time() - t0, 1),
     }
     blob = json.dumps(result, indent=1)

@@ -1,5 +1,5 @@
 """Weight-plane microbenchmark: plan stats + transfer throughput on the
-8-device virtual CPU mesh (bench.py-style JSON output).
+8-device virtual CPU mesh (one JSON line).
 
 Measures the three flows the weight plane exists for:
 
