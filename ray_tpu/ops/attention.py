@@ -2,12 +2,20 @@
 pure-XLA reference path.
 
 The MXU-friendly hot op of the flagship model. Three pallas kernels, named so
-that a device trace shows them under one key each: ``flash_fwd`` (the
-standard online-softmax flash pattern: one (batch*head, q-block) program,
-fori_loop over k-blocks held in VMEM; saves the row logsumexp),
-``flash_bwd_dq`` and ``flash_bwd_dkv`` (FlashAttention-2 style, softmax
-rebuilt from the saved logsumexp). ``_use_pallas_bwd`` picks the backward:
-the pallas pair at head_dim <= 64, a rematerialised backward through
+that a device trace shows them under one key each, one call a layer and step:
+``flash_fwd`` (online softmax: one (batch*head, q-block) program, a loop over
+the k-blocks of the whole-sequence K/V held in VMEM; saves the row
+logsumexp), ``flash_bwd_dq`` and ``flash_bwd_dkv`` (FlashAttention-2 style,
+softmax rebuilt from the saved logsumexp; dk/dv on the transposed score tile).
+All three share one loop shape: every product takes the operands as they come
+(bfloat16 in the cells) and accumulates in float32, with ``p`` and ``ds``
+rounded to the operand dtype only on their way into a product, as
+``reference_attention`` does; max, sum, ``exp``, logsumexp, delta and the
+accumulators stay float32. The causal mask is built only in the blocks the
+diagonal crosses (a second loop with the same body), the blocks above it are
+never visited, and the row statistics lie along the lanes, (B*H, 1, S).
+``_blocks`` sizes the blocks from the shapes; ``_use_pallas_bwd`` picks the
+backward: the pallas pair at head_dim <= 64, a rematerialised backward through
 ``reference_attention`` at 128 and above.
 
 CI runs the kernels in pallas interpret mode on CPU (SURVEY.md §4 implication:
@@ -19,6 +27,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -44,53 +53,120 @@ def reference_attention(q, k, v, causal: bool = True,
 
 
 # ---------------------------------------------------------------------------
-# pallas flash forward
+# the three pallas kernels: one loop shape, three bodies
 # ---------------------------------------------------------------------------
 
+# a @ b.T as ONE product: both tiles contract their minor dimension, so no
+# tile is transposed on its way to the MXU
+_NT = (((1,), (1,)), ((), ()))
+_NN = (((1,), (0,)), ((), ()))
+_MASKED = -1e30
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
-                      block_k, causal, sm_scale, seq_len):
+
+def _blocks(seq_len: int) -> tuple:
+    """(block_q, block_k) of all three kernels, from the shapes alone: the
+    largest of 512, 256, 128 that divides the sequence (one block below 128).
+
+    A larger block pays a loop iteration's fixed cost (the per-row statistics,
+    the rescaled accumulator) less often and computes more of the masked half
+    of a diagonal block. Chosen by device time on the v5e at the cells' shapes
+    (PERF.md section 6, PR 30: forward / dq / dkv ms a call at
+    ``bf16[4,2048,32,64]``, 128 x 128 6.84 / 6.21 / 5.88, 256 x 256 2.96 / 2.63
+    / 3.50, 512 x 512 1.88 / 1.81 / 2.40, 1024 x 1024 2.10 / 1.99 / 2.63, no
+    unequal pair ahead); head_dim 128 orders the same way, so neither
+    head_dim nor the dtype enters the rule."""
+    if seq_len <= 128:
+        return seq_len, seq_len
+    for block in (512, 256, 128):
+        if seq_len % block == 0:
+            return block, block
+    raise ValueError("flash attention takes at most 128 positions or a "
+                     f"multiple of 128, got {seq_len}")
+
+
+def _dot(a, b, dims=_NN):
+    """Operands as they come (bfloat16 in the cells), float32 out."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scores(a, sm_scale):
+    """A program's score tiles: ``b -> (a @ b.T) * sm_scale`` in float32, for
+    its own block ``a`` and each block ``b`` of its loop. A scale that is a power
+    of two (head_dim 64, 256) goes on ``a`` once, before the loop, exactly
+    whatever ``a``'s dtype: (rows, head_dim) multiplications in place of every
+    tile's. Any other (head_dim 128) would cost ``a`` a rounding there, and
+    multiplies the scores."""
+    if math.frexp(sm_scale)[0] == 0.5:
+        a = (a.astype(jnp.float32) * sm_scale).astype(a.dtype)
+        return lambda b: _dot(a, b, _NT)
+    return lambda b: _dot(a, b, _NT) * sm_scale
+
+
+def _block(i, size, whole):
+    """The i-th block of ``size`` positions of ``whole``, as an index; static
+    when the one block is everything, which is also all Mosaic takes along the
+    lanes below 128 positions."""
     import jax.experimental.pallas as pl
 
-    q = q_ref[0].astype(jnp.float32)  # (block_q, d)
-    q_blk = pl.program_id(1)
-    d = q.shape[-1]
+    if size == whole:
+        return slice(None)
+    return pl.ds(pl.multiple_of(i * size, size), size)
 
-    nk = seq_len // block_k
-    if causal:
-        # only k-blocks up to (and including) the diagonal block
-        upper = jnp.minimum(((q_blk + 1) * block_q + block_k - 1) // block_k, nk)
-    else:
-        upper = nk
 
-    def body(i, carry):
-        acc, m, l = carry
-        k = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -1e30)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + p.sum(axis=-1, keepdims=True)
-        acc_new = acc * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
-        return acc_new, m_new, l_new
+def _hide_future(s, q_start, k_start, q_axis):
+    """The causal mask over one score tile whose queries run along
+    ``q_axis``: only the tiles the diagonal crosses come here."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(q_pos >= k_pos, s, _MASKED)
 
-    acc = jnp.zeros((block_q, d), jnp.float32)
-    m = jnp.full((block_q, 1), -1e30, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, upper, body, (acc, m, l))
+
+def _key_blocks(q_start, block_q, block_k, seq_len, causal):
+    """For the queries [q_start, q_start + block_q): key blocks [0, clear) lie
+    wholly below the diagonal and need no mask, [clear, end) cross it, and
+    the blocks from ``end`` on hold no visible pair."""
+    if not causal:
+        return seq_len // block_k, seq_len // block_k
+    return (q_start + 1) // block_k, (q_start + block_q + block_k - 1) // block_k
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
+                      sm_scale):
+    import jax.experimental.pallas as pl
+
+    block_q, d = q_ref.shape[1:]
+    seq_len = k_ref.shape[1]
+    scores = _scores(q_ref[0], sm_scale)
+    q_start = pl.program_id(1) * block_q
+
+    def step(masked):
+        def body(i, carry):
+            acc, m, l = carry
+            keys = _block(i, block_k, seq_len)
+            k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+            s = scores(k)                                 # (bq, bk) float32
+            if masked:
+                s = _hide_future(s, q_start, i * block_k, 0)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + p.sum(axis=-1, keepdims=True)
+            acc_new = acc * alpha + _dot(p.astype(v.dtype), v)
+            return acc_new, m_new, l_new
+        return body
+
+    carry = (jnp.zeros((block_q, d), jnp.float32),
+             jnp.full((block_q, 1), _MASKED, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32))
+    clear, end = _key_blocks(q_start, block_q, block_k, seq_len, causal)
+    carry = jax.lax.fori_loop(0, clear, step(False), carry)
+    acc, m, l = jax.lax.fori_loop(clear, end, step(True), carry)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    # logsumexp per row: the backward's softmax reconstruction key
-    # (kept (S, 1)-shaped: TPU blocks need last-two dims 8/128-divisible
-    # or full-size, which a trailing singleton satisfies)
-    lse_ref[0] = m + jnp.log(l_safe)
+    # logsumexp per row, the backward's softmax reconstruction key, laid
+    # along the lanes: a (S, 1) array pads every row to 128 lanes, in fast
+    # memory and in HBM (134 MB for 1 MB of statistics at cell 1's shape)
+    lse_ref[0] = (m + jnp.log(l_safe)).T
 
 
 def _to_bh(x):
@@ -103,20 +179,15 @@ def _from_bh(x, B, H):
     return x.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
 
-def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool,
-                    block_q: int = 128, block_k: int = 128):
-    """Returns (o, lse) with o in (B, S, H, D) and lse in (B*H, S)."""
+def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool):
+    """Returns (o, lse) with o in (B, S, H, D) and lse in (B*H, 1, S)."""
     import jax.experimental.pallas as pl
 
     B, S, H, D = q.shape
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    assert S % block_q == 0 and S % block_k == 0, "seq must divide block sizes"
-    sm_scale = 1.0 / (D ** 0.5)
+    block_q, block_k = _blocks(S)
     qt, kt, vt = _to_bh(q), _to_bh(k), _to_bh(v)
-    kernel = functools.partial(
-        _flash_fwd_kernel, block_q=block_q, block_k=block_k, causal=causal,
-        sm_scale=sm_scale, seq_len=S)
+    kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
+                               causal=causal, sm_scale=1.0 / (D ** 0.5))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, S // block_q),
@@ -127,11 +198,11 @@ def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -139,10 +210,10 @@ def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool,
     return _from_bh(out, B, H), lse
 
 
-def flash_attention_fwd(q, k, v, causal: bool = True, block_q: int = 128,
-                        block_k: int = 128, interpret: bool = False):
+def flash_attention_fwd(q, k, v, causal: bool = True,
+                        interpret: bool = False):
     """(B, S, H, D) flash forward via pallas (TPU) / interpret mode (CI)."""
-    return _flash_fwd_impl(q, k, v, causal, interpret, block_q, block_k)[0]
+    return _flash_fwd_impl(q, k, v, causal, interpret)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -152,110 +223,104 @@ def flash_attention_fwd(q, k, v, causal: bool = True, block_q: int = 128,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_q, block_k, causal, sm_scale,
-                         seq_len):
+                         dq_ref, *, block_k, causal, sm_scale):
     import jax.experimental.pallas as pl
 
-    q = q_ref[0].astype(jnp.float32)          # (bq, d)
-    do = do_ref[0].astype(jnp.float32)        # (bq, d)
-    lse = lse_ref[0]                          # (bq, 1)
-    delta = delta_ref[0]                      # (bq, 1)
-    q_blk = pl.program_id(1)
-    nk = seq_len // block_k
-    if causal:
-        upper = jnp.minimum(((q_blk + 1) * block_q + block_k - 1) // block_k,
-                            nk)
-    else:
-        upper = nk
+    block_q, seq_len = q_ref.shape[1], k_ref.shape[1]
+    scores = _scores(q_ref[0], sm_scale)
+    do = do_ref[0]                            # (bq, d)
+    lse = lse_ref[0].T                        # (1, bq) -> (bq, 1)
+    delta = delta_ref[0].T
+    q_start = pl.program_id(1) * block_q
 
-    def body(i, dq_acc):
-        k = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -1e30)
-        p = jnp.exp(s - lse)                              # (bq, bk)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        return dq_acc + jnp.dot(ds, k, preferred_element_type=jnp.float32)
+    def step(masked):
+        def body(i, dq_acc):
+            keys = _block(i, block_k, seq_len)
+            k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+            s = scores(k)                                 # (bq, bk)
+            if masked:
+                s = _hide_future(s, q_start, i * block_k, 0)
+            p = jnp.exp(s - lse)
+            ds = p * (_dot(do, v, _NT) - delta)
+            return dq_acc + _dot(ds.astype(k.dtype), k)
+        return body
 
-    dq = jax.lax.fori_loop(0, upper, body,
-                           jnp.zeros_like(q, dtype=jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    clear, end = _key_blocks(q_start, block_q, block_k, seq_len, causal)
+    dq = jax.lax.fori_loop(0, clear, step(False),
+                           jnp.zeros(q_ref.shape[1:], jnp.float32))
+    dq = jax.lax.fori_loop(clear, end, step(True), dq)
+    # ds went into its product without the softmax scale: once here
+    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q, block_k, causal,
-                          sm_scale, seq_len):
+                          dk_ref, dv_ref, *, block_q, causal, sm_scale):
+    """Works on the TRANSPOSED score tile (keys down, queries across), so
+    that ``p^T do`` and ``ds^T q`` are plain products and the statistics are
+    read as they lie, along the lanes."""
     import jax.experimental.pallas as pl
 
-    k = k_ref[0].astype(jnp.float32)          # (bk, d)
-    v = v_ref[0].astype(jnp.float32)          # (bk, d)
-    k_blk = pl.program_id(1)
+    block_k, seq_len = k_ref.shape[1], q_ref.shape[1]
+    scores = _scores(k_ref[0], sm_scale)
+    v = v_ref[0]                              # (bk, d)
+    k_start = pl.program_id(1) * block_k
+
+    def step(masked):
+        def body(i, carry):
+            dk_acc, dv_acc = carry
+            queries = _block(i, block_q, seq_len)
+            q, do = q_ref[0, queries, :], do_ref[0, queries, :]
+            lse = lse_ref[0, :, queries]                      # (1, bq)
+            delta = delta_ref[0, :, queries]
+            s = scores(q)                                     # (bk, bq)
+            if masked:
+                s = _hide_future(s, i * block_q, k_start, 1)
+            p = jnp.exp(s - lse)
+            dv_acc = dv_acc + _dot(p.astype(do.dtype), do)
+            ds = p * (_dot(v, do, _NT) - delta)
+            dk_acc = dk_acc + _dot(ds.astype(q.dtype), q)
+            return dk_acc, dv_acc
+        return body
+
+    # query blocks [first, clear) cross the diagonal, [clear, nq) lie wholly
+    # below it, the ones before ``first`` hold no visible pair
     nq = seq_len // block_q
-    lower = (k_blk * block_k) // block_q if causal else 0
-
-    def body(i, carry):
-        dk_acc, dv_acc = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), :]
-        delta = delta_ref[0, pl.ds(i * block_q, block_q), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_blk * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -1e30)
-        p = jnp.exp(s - lse)                              # (bq, bk)
-        dv_acc = dv_acc + jnp.dot(p.T, do,
-                                  preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dk_acc = dk_acc + jnp.dot(ds.T, q,
-                                  preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
-
-    dk, dv = jax.lax.fori_loop(
-        lower, nq, body,
-        (jnp.zeros_like(k, dtype=jnp.float32),
-         jnp.zeros_like(v, dtype=jnp.float32)))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    if causal:
+        first = k_start // block_q
+        clear = (k_start + block_k - 1 + block_q - 1) // block_q
+    else:
+        first = clear = 0
+    carry = (jnp.zeros(v.shape, jnp.float32),) * 2
+    carry = jax.lax.fori_loop(first, clear, step(True), carry)
+    dk, dv = jax.lax.fori_loop(clear, nq, step(False), carry)
+    dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def flash_attention_bwd(q, k, v, o, lse, g, causal: bool,
-                        interpret: bool = False, block_q: int = 128,
-                        block_k: int = 128):
+                        interpret: bool = False):
     import jax.experimental.pallas as pl
 
     B, S, H, D = q.shape
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    sm_scale = 1.0 / (D ** 0.5)
+    block_q, block_k = _blocks(S)
     qt, kt, vt = _to_bh(q), _to_bh(k), _to_bh(v)
     dot = _to_bh(g)
-    # delta = rowsum(dO * O): cheap elementwise — plain XLA, not a kernel
-    delta = jnp.sum(dot.astype(jnp.float32)
-                    * _to_bh(o).astype(jnp.float32), axis=-1,
-                    keepdims=True)  # (B*H, S, 1)
-    common = dict(block_q=block_q, block_k=block_k, causal=causal,
-                  sm_scale=sm_scale, seq_len=S)
+    # delta = rowsum(dO * O): cheap elementwise — plain XLA, not a kernel;
+    # reduced where the operands lie, so that only the (B, S, H) sums are
+    # transposed, into lse's layout (B*H, 1, S)
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = delta.transpose(0, 2, 1).reshape(B * H, 1, S)
+    common = dict(causal=causal, sm_scale=1.0 / (D ** 0.5))
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
+        functools.partial(_flash_bwd_dq_kernel, block_k=block_k, **common),
         grid=(B * H, S // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0)),
             pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0)),
             pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
@@ -263,15 +328,15 @@ def flash_attention_bwd(q, k, v, o, lse, g, causal: bool,
         name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse, delta)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **common),
+        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q, **common),
         grid=(B * H, S // block_k),
         in_specs=[
             pl.BlockSpec((1, S, D), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, S, D), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, S, 1), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, S, 1), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, S), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, S), lambda bh, ki: (bh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
@@ -305,9 +370,12 @@ def _use_pallas_bwd(head_dim: int) -> bool:
     """The pallas backward pair is used for head_dim <= 64 by default; at
     128 the backward rematerializes through ``reference_attention``. Both
     backwards compile for the v5e at the ``1b`` shapes
-    (tests/test_chip_compile.py); which is faster at head_dim 128 is not
-    measured, so the rule stands until a chip trace decides it (ROADMAP
-    S1b). Override with RAY_TPU_FLASH_BWD=pallas|reference."""
+    (tests/test_chip_compile.py). The rule is older than its measurement: at
+    ``bf16[2,2048,16,128]`` on the v5e the pallas pair takes 0.45 + 0.60 ms a
+    call where the reference backward takes 7.4 (bare microbenchmark, PR 30;
+    PERF.md section 6). It stands until a ``benchmark`` issue decides it
+    (ROADMAP S3c / D4: ``benchmarks/jobs/train.py`` imports this function).
+    Override with RAY_TPU_FLASH_BWD=pallas|reference."""
     import os
 
     mode = os.environ.get("RAY_TPU_FLASH_BWD", "auto")

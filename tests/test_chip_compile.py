@@ -84,6 +84,91 @@ def test_flash_forward_and_pallas_backward(one_chip, monkeypatch, shape,
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+def _flash_grads(q, k, v):
+    from ray_tpu.ops.attention import flash_attention
+
+    return jax.grad(lambda *a: flash_attention(*a, True).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("shape,backward", [
+    ((4, 2048, 32, 64), True),     # cell 1: b4 x 2048, 32 heads of 64
+    ((2, 2048, 16, 128), False),   # cell 4, one chip's rows (head_dim 128:
+                                   # the backward is reference_attention)
+    ((1, 512, 32, 64), True),      # cell 1's gradient check
+    ((1, 256, 16, 128), False),    # the engine's prefill, cell 3's bucket
+    ((1, 128, 16, 128), False),    # ... and cell 5's: one block
+], ids=["cell1", "cell4-a-chip", "cell1-check", "engine-256", "engine-128"])
+def test_flash_kernels_at_the_cells_own_shapes(one_chip, monkeypatch, shape,
+                                               backward):
+    """Each kernel is one custom call under its own name, at the blocks the
+    kernels choose for the shape."""
+    from ray_tpu.ops.attention import flash_attention
+
+    monkeypatch.delenv("RAY_TPU_FLASH_BWD", raising=False)
+    fn = _flash_grads if backward else (
+        lambda q, k, v: flash_attention(q, k, v, True))
+    text = jax.jit(fn).lower(*_qkv(shape, one_chip)).compile().as_text()
+    names = ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"][0 if backward
+                                                           else 2:]
+    # the instruction each custom call is: %jvp_flash_fwd_.1 = ...
+    made = [line.split(" = ")[0] for line in text.splitlines()
+            if "tpu_custom_call" in line]
+    assert sorted(n for m in made for n in names if n in m) == names
+    assert len(made) == len(names)
+
+
+def test_flash_kernels_fast_memory_at_8k(one_chip, monkeypatch):
+    """``smollm2-1.7b.train-8k``'s shape (b1 x 8192, 32 heads of 64): the
+    kernels keep whole-sequence K/V (and Q/dO in dk/dv) blocks in fast
+    memory, so this is where they stop fitting first. Forward and the pallas
+    backward compile under the compiler's own limit, and what each needs of it
+    is printed (``-s``): the least whole MiB of scoped VMEM it compiles
+    under."""
+    import sys
+
+    from ray_tpu.ops.attention import flash_attention
+
+    mod = sys.modules[flash_attention.__module__]
+    monkeypatch.delenv("RAY_TPU_FLASH_BWD", raising=False)
+    shape = B, S, H, D = (1, 8192, 32, 64)
+    q, k, v = _qkv(shape, one_chip)
+    text = jax.jit(_flash_grads).lower(q, k, v).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+    lse = jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32, sharding=one_chip)
+    alone = {
+        "flash_fwd": (lambda q, k, v: mod._flash_fwd_impl(
+            q, k, v, True, False), (q, k, v)),
+        "flash_bwd_dq": (lambda *a: mod.flash_attention_bwd(*a, True)[0],
+                         (q, k, v, q, lse, q)),
+        "flash_bwd_dkv": (lambda *a: mod.flash_attention_bwd(*a, True)[1:],
+                          (q, k, v, q, lse, q)),
+    }
+    default_mib = 16    # the v5e compiler's scoped limit
+
+    def fits(fn, args, mib):
+        try:
+            jax.jit(fn).lower(*args).compile(compiler_options={
+                "xla_tpu_scoped_vmem_limit_kib": mib * 1024})
+        except Exception as e:
+            assert "vmem" in str(e), e
+            return False
+        return True
+
+    need = {}
+    for name, (fn, args) in alone.items():
+        lo, hi = 0, default_mib     # (does not fit, fits]
+        assert fits(fn, args, hi), name
+        while hi - lo > 1:
+            mid = (lo + hi) // 2
+            lo, hi = (lo, mid) if fits(fn, args, mid) else (mid, hi)
+        need[name] = hi
+    print(f"flash kernels at bf16{list(shape)}: scoped VMEM needed, MiB of "
+          f"{default_mib}: {need}")
+    assert max(need.values()) <= default_mib
+
+
 def _on(tree, sharding):
     return jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
