@@ -52,6 +52,9 @@ class EngineConfig:
     # a sparse deployment's name, and a program that has no expert layer
     # refuses such a deployment where it is described, not in a replica.
     expect_experts: int = 0
+    # width of the latent the cache holds a position (0: per-head K/V pages),
+    # checked against the model as expect_experts is, and for its reason
+    expect_latent_rank: int = 0
 
     def __post_init__(self):
         if self.max_model_len % self.page_size:

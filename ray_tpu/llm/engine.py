@@ -44,15 +44,20 @@ from ``__init__``, so two snapshots subtract):
   ``moe_decode_experts_touched`` (experts that got a row) and
   ``moe_decode_max_load`` (rows of the fullest expert). They come from
   ``KVCache.moe_load``, a few KB whose copy to the host starts at dispatch
-  and is read in ``emit`` after the tokens are back.
+  and is read in ``emit`` after the tokens are back;
+- for a model with a latent cache (0 otherwise), per decode step and a layer:
+  ``mla_decode_live_tokens`` (positions the active slots attend over) and
+  ``mla_decode_read_tokens`` (positions of the pages ``mla_decode`` is given:
+  the live ones rounded up to whole pages).
 
 The same boundaries are spans on the profiler's clock
 (``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
 that owns the engine shows ``ray_tpu/engine.step`` on the host plane and,
 inside it, ``engine.admit``, ``.prefill_dispatch`` (arguments ``bucket``,
-the largest of the phase, and ``admitted``), ``.decode_dispatch`` (argument
+the largest of the phase, and ``admitted``), ``.decode_dispatch`` (arguments
 ``experts``: experts touched per layer in the newest decode step the host
-has read, models with experts only), ``.sample_dispatch``, ``.readback``,
+has read, models with experts only; ``live_tokens``: positions the step
+attends over, models with a latent cache only), ``.sample_dispatch``, ``.readback``,
 ``.emit`` and, around a shape's first use, ``.compile``. With
 ``RAY_TPU_ENABLE_TRACING`` a finished request also leaves ``engine.queued``,
 ``engine.prefill`` and ``engine.decode`` spans (``request_id``) under the
@@ -125,6 +130,11 @@ class JaxLLMEngine:
             raise ValueError(
                 f"the deployment expects {self.ecfg.expect_experts} experts "
                 f"a layer, the model has {self.mcfg.n_experts}")
+        if self.mcfg.kv_latent_rank != self.ecfg.expect_latent_rank:
+            raise ValueError(
+                f"the deployment expects a latent cache of rank "
+                f"{self.ecfg.expect_latent_rank}, the model has "
+                f"{self.mcfg.kv_latent_rank}")
         self.tokenizer = get_tokenizer(config.tokenizer)
         self._mr = model_runner
         self._jax = jax
@@ -172,7 +182,8 @@ class JaxLLMEngine:
             "emit_ms": 0.0, "between_steps_ms": 0.0,
             "queue_wait_ms": 0.0, "ttft_ms": 0.0,
             "moe_decode_layer_steps": 0, "moe_decode_assignments": 0,
-            "moe_decode_experts_touched": 0, "moe_decode_max_load": 0}
+            "moe_decode_experts_touched": 0, "moe_decode_max_load": 0,
+            "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
 
@@ -404,13 +415,19 @@ class JaxLLMEngine:
 
         # 2) one decode step for all active slots
         if decode and self._active.any():
-            with self._phase("decode_dispatch", **self._experts_attr):
+            attrs = dict(self._experts_attr)
+            if self.mcfg.kv_latent_rank:  # positions the step attends over
+                attrs["live_tokens"] = int(
+                    (self._seq_lens[self._active] + 1).sum())
+            with self._phase("decode_dispatch", **attrs):
                 # page-boundary allocation; preempt to waiting on exhaustion
                 for req in [s for s in self._slots if s is not None]:
                     if self._active[req.slot] and not self._ensure_page(req):
                         m["preempted"] += 1
                         self._requeue(req)
                 decoding = bool(self._active.any())
+                if decoding and self.mcfg.kv_latent_rank:
+                    self._count_latent_reads()
                 if decoding:
                     with self._first_use("decode"):
                         logits, self.cache = mr.decode_step(
@@ -432,6 +449,19 @@ class JaxLLMEngine:
                             self._seq_lens[req.slot] += 1
                             self._emit(req, int(toks_np[req.slot]), outputs)
         return outputs
+
+    def _count_latent_reads(self) -> None:
+        """What the decode step about to run attends over (``live``: positions
+        0..seq_len of every active slot) and what it reads for that (``read``:
+        the positions of the pages ``ops/mla.py:live_pages`` lists, an
+        inactive slot's one step over the scratch page included), a layer."""
+        P = self.ecfg.page_size
+        lens = self._seq_lens[self._active].astype(np.int64)
+        live = int((lens + 1).sum())
+        read = int(((lens // P + 1) * P).sum()) \
+            + P * int((~self._active).sum())
+        self.metrics["mla_decode_live_tokens"] += live
+        self.metrics["mla_decode_read_tokens"] += read
 
     def _count_routing(self, load: np.ndarray) -> None:
         """``load`` [expert layers, E]: real rows per expert in one decode
@@ -537,11 +567,16 @@ class JaxLLMEngine:
             "finished": req.finished,
             "finish_reason": req.finish_reason,
             "params": req.params,
-            "k": np.asarray(self.cache.k[:, pages]),
-            "v": np.asarray(self.cache.v[:, pages]),
         }
+        # the cache's page leaves under their own names: "k" and "v", or a
+        # latent model's "rows"
+        for name in self._page_leaves():
+            state[name] = np.asarray(getattr(self.cache, name)[:, pages])
         self.abort_request(request_id)
         return state
+
+    def _page_leaves(self) -> List[str]:
+        return [f for f in self.cache._fields if f != "moe_load"]
 
     def add_request_with_kv(self, state: dict) -> None:
         """Admit a prefilled request directly into a decode slot: allocate
@@ -553,7 +588,8 @@ class JaxLLMEngine:
             # finished during prefill (e.g. max_tokens=1): nothing to decode
             raise ValueError("request already finished at prefill")
         free_slots = [i for i, s in enumerate(self._slots) if s is None]
-        n_pages = state["k"].shape[1]
+        leaves = self._page_leaves()
+        n_pages = state[leaves[0]].shape[1]
         if not free_slots or len(self._free_pages) < n_pages:
             raise RuntimeError("decode engine has no capacity; retry")
         req = _Request(state["request_id"], list(state["prompt_tokens"]),
@@ -564,9 +600,9 @@ class JaxLLMEngine:
         req.slot = free_slots[0]
         req.pages = [self._free_pages.popleft() for _ in range(n_pages)]
         pages = jnp.asarray(np.asarray(req.pages, np.int32))
-        self.cache = self.cache._replace(
-            k=self.cache.k.at[:, pages].set(jnp.asarray(state["k"])),
-            v=self.cache.v.at[:, pages].set(jnp.asarray(state["v"])))
+        self.cache = self.cache._replace(**{
+            name: getattr(self.cache, name).at[:, pages].set(
+                jnp.asarray(state[name])) for name in leaves})
         row = self._block_tables[req.slot]
         row[:] = 0
         row[:n_pages] = req.pages

@@ -32,6 +32,14 @@ NOT the training module's capacity-bound dispatch but ``ops/moe.py``:
 dropless, rows that are padding or belong to an inactive slot reach no
 expert. What routing did in a call comes back beside the pages, as
 ``KVCache.moe_load`` (per expert layer, how many real rows each expert got).
+
+A model with latent attention (``kv_latent_rank``) keeps ``LatentCache``
+instead: one row a position and layer for all heads. Prefill writes the rows
+and attends over keys and values expanded to heads (the flash kernel, 192-wide
+q . k and 128-wide values); decode attends over the rows themselves with the
+up-projection absorbed, through ``ops/mla.py:mla_decode``, which reads only
+the pages that hold live positions. Both programs keep their signatures: the
+cache is whichever tuple ``init_cache`` made, pages first and ``moe_load`` last.
 """
 
 from __future__ import annotations
@@ -54,12 +62,36 @@ class KVCache(NamedTuple):
     moe_load: Optional[jax.Array] = None  # [expert layers, E] int32
 
 
+class LatentCache(NamedTuple):
+    """The cache of a model with latent attention (``kv_latent_rank``): per
+    position and layer ONE row ``c | k_pe | 0`` for all heads, the normalised
+    latent, the rotated key and padding to whole 128-lane tiles (512 + 64 ->
+    640; ``ops/mla.py`` says why), in place of 2 x heads x head_dim."""
+    rows: jax.Array  # [L, NP, P, W]
+    moe_load: Optional[jax.Array] = None
+
+
+def _latent_width(cfg: TransformerConfig) -> int:
+    return -(-(cfg.kv_latent_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def _latent_row(parts, width):
+    """``parts`` side by side along the last axis, zeros up to ``width``."""
+    row = jnp.concatenate(parts, axis=-1)
+    return jnp.pad(row, [(0, 0)] * (row.ndim - 1)
+                   + [(0, width - row.shape[-1])])
+
+
 def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int) -> KVCache:
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     load = None
-    if cfg.n_experts:  # every moe_every-th layer has experts
-        layers = len(range(0, cfg.n_layers, max(cfg.moe_every, 1)))
+    layers = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
+    if layers:
         load = jnp.zeros((layers, cfg.n_experts), jnp.int32)
+    if cfg.kv_latent_rank:
+        return LatentCache(jnp.zeros(
+            (cfg.n_layers, num_pages, page_size, _latent_width(cfg)),
+            cfg.dtype), load)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype),
                    load)
 
@@ -94,8 +126,13 @@ def _ffn(x, lp, cfg, valid, name):
         x.reshape(-1, x.shape[-1]), valid.reshape(-1), p["router"]["kernel"],
         p["gate_proj"], p["up_proj"], p["down_proj"],
         top_k=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
-        name=name)
-    return y.reshape(x.shape), load
+        name=name, router_kind=cfg.router_kind,
+        router_bias=p.get("router_bias"),
+        router_scale=cfg.routed_scaling_factor)
+    y = y.reshape(x.shape)
+    if "shared" in p:  # the experts every row goes through
+        y = y + _mlp(x, p["shared"], cfg.dtype)
+    return y, load
 
 
 def _qkv(x, p, cfg, positions):
@@ -112,6 +149,55 @@ def _qkv(x, p, cfg, positions):
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def _latent_qkv(x, p, cfg, positions):
+    """Latent attention's projections of x [B, S, D]: q_nope [B, S, H, nope],
+    q_pe [B, S, H, rope] rotated, the normalised latent c [B, S, R], the
+    rotated key k_pe [B, S, rope] (one for all heads), and the cache row
+    ``c | k_pe | 0`` [B, S, W]."""
+    dtype = cfg.dtype
+    r, nope = cfg.kv_latent_rank, cfg.qk_nope_head_dim
+    q = jnp.einsum("...d,dhk->...hk", x, p["q_proj"]["kernel"].astype(dtype))
+    a = jnp.einsum("...d,dr->...r", x, p["kv_a_proj"]["kernel"].astype(dtype))
+    c = _rmsnorm(a[..., :r], p["kv_a_norm"]["scale"], cfg.norm_eps)
+    q_pe = _rope(q[..., nope:], positions, cfg.rope_theta)
+    k_pe = _rope(a[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
+    return (q[..., :nope], q_pe, c, k_pe,
+            _latent_row([c, k_pe], _latent_width(cfg)))
+
+
+def _latent_attention_expanded(q_nope, q_pe, c, k_pe, p, cfg):
+    """Prefill's path: keys and values up-projected from the latent to heads,
+    then plain causal attention over 192-wide q . k and 128-wide values."""
+    from ray_tpu.ops.attention import attention as attention_op
+
+    nope = cfg.qk_nope_head_dim
+    kv = jnp.einsum("...r,rhk->...hk", c,
+                    p["kv_b_proj"]["kernel"].astype(cfg.dtype))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe[..., None, :], q_pe.shape)],
+        axis=-1)
+    return attention_op(q, k, kv[..., nope:], causal=True,
+                        impl=cfg.attention_impl)
+
+
+def _latent_attention_absorbed(q_nope, q_pe, rows, work, layer, p, cfg):
+    """Decode's path, the same mathematics with ``kv_b_proj`` absorbed: the
+    query goes up to the latent (``q_lat[h] = q_nope[h] W_k[h]^T``), all heads
+    attend over the cache rows themselves (``ops/mla.py:mla_decode``), and the
+    result comes down through the value half (``out[h] = o_lat[h] W_v[h]``).
+    q_nope [B, H, nope], q_pe [B, H, rope] -> [B, H, v_head_dim]."""
+    from ray_tpu.ops.mla import mla_decode
+
+    r, nope = cfg.kv_latent_rank, cfg.qk_nope_head_dim
+    w = p["kv_b_proj"]["kernel"].astype(cfg.dtype)        # [R, H, nope + v]
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, w[..., :nope])
+    o_lat = mla_decode(
+        _latent_row([q_lat, q_pe], rows.shape[-1]), rows, work, rank=r,
+        layer=layer, sm_scale=1.0 / ((nope + q_pe.shape[-1]) ** 0.5))
+    return jnp.einsum("bhr,rhv->bhv", o_lat, w[..., nope:])
 
 
 # ---------------------------------------------------------------------------
@@ -132,7 +218,7 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
 
     p = params["params"]
     B, S = tokens.shape
-    P = cache.k.shape[2]
+    P = cache[0].shape[2]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     in_prompt = positions < lengths[:, None]
     # padding tokens scatter to scratch page 0
@@ -142,19 +228,24 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
     offset = jnp.where(in_prompt, positions % P, 0)
 
     x = p["embed"].astype(cfg.dtype)[tokens]
-    new_k, new_v = cache.k, cache.v
+    pages = cache[:-1]  # (k, v), or the latent rows alone
     loads = []
     for i in range(cfg.n_layers):
         lp = p[f"layer_{i}"]
         h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps)
-        q, k, v = _qkv(h, lp["attn"], cfg, positions)
-        new_k = new_k.at[i, page, offset].set(k, mode="drop")
-        new_v = new_v.at[i, page, offset].set(v, mode="drop")
-        if cfg.n_kv_heads != cfg.n_heads:
-            rep = cfg.n_heads // cfg.n_kv_heads
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        attn = attention_op(q, k, v, causal=True, impl=cfg.attention_impl)
+        if cfg.kv_latent_rank:
+            *qc, row = _latent_qkv(h, lp["attn"], cfg, positions)
+            pages = (pages[0].at[i, page, offset].set(row, mode="drop"),)
+            attn = _latent_attention_expanded(*qc, lp["attn"], cfg)
+        else:
+            q, k, v = _qkv(h, lp["attn"], cfg, positions)
+            pages = (pages[0].at[i, page, offset].set(k, mode="drop"),
+                     pages[1].at[i, page, offset].set(v, mode="drop"))
+            if cfg.n_kv_heads != cfg.n_heads:
+                rep = cfg.n_heads // cfg.n_kv_heads
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
+            attn = attention_op(q, k, v, causal=True, impl=cfg.attention_impl)
         attn = jnp.einsum("...hk,hkd->...d",
                           attn, lp["attn"]["o_proj"]["kernel"].astype(cfg.dtype))
         h2 = x + attn
@@ -167,8 +258,8 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
     # hidden at the last prompt position only -> [B, d]
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return _head(last, p, cfg), KVCache(new_k, new_v,
-                                        jnp.stack(loads) if loads else None)
+    return _head(last, p, cfg), type(cache)(
+        *pages, jnp.stack(loads) if loads else None)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -209,39 +300,55 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
     """
     p = params["params"]
     B = last_tokens.shape[0]
-    P, KVH, HD = cache.k.shape[2:]
+    P = cache[0].shape[2]
     MP = block_tables.shape[1]
     Lmax = MP * P
-    G = cfg.n_heads // cfg.n_kv_heads
 
     positions = seq_lens[:, None].astype(jnp.int32)  # [B, 1]
     cur_page = jnp.take_along_axis(block_tables, positions // P, axis=1)[:, 0]
     page = jnp.where(active, cur_page, 0)  # [B]; inactive slots -> scratch
     offset = jnp.where(active, seq_lens % P, 0)
-    kv_mask = (jnp.arange(Lmax, dtype=jnp.int32)[None] <= seq_lens[:, None]) \
-        & active[:, None]
-    scale = 1.0 / (HD ** 0.5)
+    if cfg.kv_latent_rank:
+        from ray_tpu.ops.mla import live_pages
+
+        # which pages hold live positions: one list for every layer
+        work = live_pages(seq_lens, active, block_tables, P)
+    else:
+        KVH, HD = cache.k.shape[3:]
+        G = cfg.n_heads // cfg.n_kv_heads
+        kv_mask = (jnp.arange(Lmax, dtype=jnp.int32)[None]
+                   <= seq_lens[:, None]) & active[:, None]
+        scale = 1.0 / (HD ** 0.5)
 
     x = p["embed"].astype(cfg.dtype)[last_tokens[:, None]]  # [B, 1, d]
-    new_k, new_v = cache.k, cache.v
+    pages = cache[:-1]  # (k, v), or the latent rows alone
     loads = []
     for i in range(cfg.n_layers):
         lp = p[f"layer_{i}"]
         h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps)
-        q, k, v = _qkv(h, lp["attn"], cfg, positions)  # q [B,1,H,hd]
-        new_k = new_k.at[i, page, offset].set(k[:, 0], mode="drop")
-        new_v = new_v.at[i, page, offset].set(v[:, 0], mode="drop")
-        # every slot's pages, straight from the 5-D cache: [B, Lmax, KVH, HD]
-        k_all = new_k[i, block_tables].reshape(B, Lmax, KVH, HD)
-        v_all = new_v[i, block_tables].reshape(B, Lmax, KVH, HD)
-        # grouped-query attention without materializing repeated heads
-        qg = q[:, 0].reshape(B, KVH, G, HD)
-        scores = jnp.einsum("bkgd,blkd->bkgl", qg, k_all,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(kv_mask[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bkgl,blkd->bkgd", probs, v_all)
-        attn = attn.reshape(B, 1, cfg.n_heads, HD)
+        if cfg.kv_latent_rank:
+            q_nope, q_pe, _, _, row = _latent_qkv(h, lp["attn"], cfg, positions)
+            pages = (pages[0].at[i, page, offset].set(row[:, 0], mode="drop"),)
+            attn = _latent_attention_absorbed(
+                q_nope[:, 0], q_pe[:, 0], pages[0], work, i, lp["attn"],
+                cfg)[:, None]
+        else:
+            q, k, v = _qkv(h, lp["attn"], cfg, positions)  # q [B,1,H,hd]
+            new_k = pages[0].at[i, page, offset].set(k[:, 0], mode="drop")
+            new_v = pages[1].at[i, page, offset].set(v[:, 0], mode="drop")
+            pages = (new_k, new_v)
+            # every slot's pages, straight from the 5-D cache:
+            # [B, Lmax, KVH, HD]
+            k_all = new_k[i, block_tables].reshape(B, Lmax, KVH, HD)
+            v_all = new_v[i, block_tables].reshape(B, Lmax, KVH, HD)
+            # grouped-query attention without materializing repeated heads
+            qg = q[:, 0].reshape(B, KVH, G, HD)
+            scores = jnp.einsum("bkgd,blkd->bkgl", qg, k_all,
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(kv_mask[:, None, None, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            attn = jnp.einsum("bkgl,blkd->bkgd", probs, v_all)
+            attn = attn.reshape(B, 1, cfg.n_heads, HD)
         attn = jnp.einsum("...hk,hkd->...d",
                           attn, lp["attn"]["o_proj"]["kernel"].astype(cfg.dtype))
         h2 = x + attn
@@ -251,8 +358,8 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
         if load is not None:
             loads.append(load)
 
-    return _head(x[:, 0], p, cfg), KVCache(new_k, new_v,
-                                           jnp.stack(loads) if loads else None)
+    return _head(x[:, 0], p, cfg), type(cache)(
+        *pages, jnp.stack(loads) if loads else None)
 
 
 # ---------------------------------------------------------------------------

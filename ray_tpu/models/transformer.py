@@ -36,6 +36,13 @@ HEAD_DIM = "head_dim"
 MLP = "mlp"
 VOCAB = "vocab"
 
+# the sigmoid router's selection bias is drawn, not zero: at this scale it
+# changes which experts a token gets for about half the tokens (53% at 64
+# experts, top-6, hidden 2048: tests/test_latent_moe.py counts them), so a
+# program that drops the bias cannot pass a comparison, and the experts' loads
+# stay within 0.6-1.6 x their mean
+ROUTER_BIAS_STD = 0.02
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -66,26 +73,76 @@ class TransformerConfig:
     # RMSNorm over the whole q and k projections (all heads together),
     # before the split into heads and RoPE
     qk_norm: bool = False
+    # latent attention (MLA; 0 = per-head K/V projections): keys and values
+    # come from ONE kv_latent_rank-wide normalised latent a position, through
+    # an up-projection per head to qk_nope_head_dim of key and v_head_dim of
+    # value; a qk_rope_head_dim-wide rotated part, one for all heads on the
+    # key side, is appended to q and k. The serving cache holds the latent
+    # and the rotated key, not the heads (llm/model_runner.py).
+    kv_latent_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the first layers that stay dense in a model with experts, and the
+    # width of a dense layer where it is not the experts' d_ff (0 = d_ff)
+    first_k_dense: int = 0
+    d_ff_dense: int = 0
+    # experts every token goes through beside its routed ones: one SwiGLU of
+    # width n_shared_experts * d_ff
+    n_shared_experts: int = 0
+    # "softmax": top-k of the softmax. "sigmoid": top-k of sigmoid scores plus
+    # a per-expert selection bias (a parameter) that chooses and does not
+    # weigh; the chosen scores, renormalised with norm_topk_prob, times
+    # routed_scaling_factor (ops/moe.py:select_experts)
+    router_kind: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # the standard deviations the attention's projections and the MLPs' and
+    # experts' matrices are drawn at; 0 = 0.02 / sqrt(2 layers). What each
+    # part adds to the residual stream goes with its fourth and third power,
+    # so seeded weights that are to show every part of a model in its logits
+    # (a comparison with a reference) state them
+    attn_init_std: float = 0.0
+    mlp_init_std: float = 0.0
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    def init_std(self, part: str) -> float:
+        """``part``: "attn" or "mlp"."""
+        return getattr(self, part + "_init_std") \
+            or 0.02 / np.sqrt(2 * self.n_layers)
+
+    def is_moe_layer(self, i: int) -> bool:
+        return (self.n_experts > 0 and i >= self.first_k_dense
+                and i % max(self.moe_every, 1) == 0)
+
     def num_params(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
-        attn = (
-            d * d  # q
-            + 2 * d * (self.n_kv_heads * self.head_dim)  # k, v
-            + d * d  # o
-            + 2 * d  # norms
-            + (d + self.n_kv_heads * self.head_dim if self.qk_norm else 0)
-        )
-        dense_mlp = 3 * d * f
+        if self.kv_latent_rank:
+            r, rope = self.kv_latent_rank, self.qk_rope_head_dim
+            attn = (
+                d * self.n_heads * (self.qk_nope_head_dim + rope)  # q
+                + d * (r + rope) + r  # latent down-projection and its norm
+                + r * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
+                + self.n_heads * self.v_head_dim * d  # o
+                + 2 * d  # norms
+            )
+        else:
+            attn = (
+                d * d  # q
+                + 2 * d * (self.n_kv_heads * self.head_dim)  # k, v
+                + d * d  # o
+                + 2 * d  # norms
+                + (d + self.n_kv_heads * self.head_dim if self.qk_norm else 0)
+            )
+        dense_mlp = 3 * d * (self.d_ff_dense or f)
+        moe_mlp = (self.n_experts * 3 * d * f + d * self.n_experts
+                   + 3 * d * self.n_shared_experts * f
+                   + (self.n_experts if self.router_kind == "sigmoid" else 0))
         total = 0
         for i in range(self.n_layers):
-            moe = self.n_experts > 0 and i % max(self.moe_every, 1) == 0
-            total += attn + (self.n_experts * 3 * d * f + d * self.n_experts
-                             if moe else dense_mlp)
+            total += attn + (moe_mlp if self.is_moe_layer(i) else dense_mlp)
         return v * d + total + d + (0 if self.tie_embeddings else d * v)
 
 
@@ -149,12 +206,18 @@ class Attention(nn.Module):
         cfg = self.cfg
         B, S, _ = x.shape
         hd = cfg.head_dim
-        dense = lambda feats, axes, name: nn.DenseGeneral(  # noqa: E731
-            features=feats, axis=-1, use_bias=False, dtype=cfg.dtype,
+        dense = lambda feats, axes, name, axis=-1: nn.DenseGeneral(  # noqa: E731
+            features=feats, axis=axis, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name=name,
             kernel_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02 / np.sqrt(2 * cfg.n_layers)), axes),
+                nn.initializers.normal(cfg.init_std("attn")), axes),
         )
+        if cfg.kv_latent_rank:
+            q, k, v = self._latent_qkv(x, positions, dense)
+            out = attention_op(q, k, v, causal=True, impl=cfg.attention_impl,
+                               segment_ids=segment_ids)
+            return dense(cfg.d_model, ("heads", "head_dim", "embed"),
+                         "o_proj", axis=(-2, -1))(out)
         q = dense((cfg.n_heads, hd), ("embed", "heads", "head_dim"), "q_proj")(x)
         k = dense((cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim"), "k_proj")(x)
         v = dense((cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim"), "v_proj")(x)
@@ -176,32 +239,56 @@ class Attention(nn.Module):
             features=cfg.d_model, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="o_proj",
             kernel_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02 / np.sqrt(2 * cfg.n_layers)),
+                nn.initializers.normal(cfg.init_std("attn")),
                 ("heads", "head_dim", "embed")),
         )(out)
         return out
 
+    def _latent_qkv(self, x, positions, dense):
+        """Latent attention, expanded to heads (the serving engine's prefill
+        computes the same; its decode absorbs ``kv_b_proj`` into q and the
+        output instead, ``llm/model_runner.py``): q [B, S, H, nope + rope], k
+        the same width (the rotated part one for all heads), v [B, S, H,
+        v_head_dim]."""
+        cfg = self.cfg
+        H, r = cfg.n_heads, cfg.kv_latent_rank
+        nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        q = dense((H, nope + rope), ("embed", "heads", "head_dim"), "q_proj")(x)
+        a = dense(r + rope, ("embed", None), "kv_a_proj")(x)
+        c = RMSNorm(cfg.norm_eps, cfg.dtype, axis=None,
+                    name="kv_a_norm")(a[..., :r])
+        kv = dense((H, nope + cfg.v_head_dim), (None, "heads", "head_dim"),
+                   "kv_b_proj")(c)
+        q_pe = _rope(q[..., nope:], positions, cfg.rope_theta)
+        k_pe = _rope(a[..., None, r:], positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, q_pe.shape)], axis=-1)
+        return q, k, kv[..., nope:]
+
 
 class MLP(nn.Module):
     cfg: TransformerConfig
+    width: int = 0  # 0 = cfg.d_ff
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
+        d_ff = self.width or cfg.d_ff
         dense = lambda feats, axes, name: nn.DenseGeneral(  # noqa: E731
             features=feats, axis=-1, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name=name,
             kernel_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02 / np.sqrt(2 * cfg.n_layers)), axes),
+                nn.initializers.normal(cfg.init_std("mlp")), axes),
         )
-        gate = dense(cfg.d_ff, ("embed", "mlp"), "gate_proj")(x)
-        up = dense(cfg.d_ff, ("embed", "mlp"), "up_proj")(x)
+        gate = dense(d_ff, ("embed", "mlp"), "gate_proj")(x)
+        up = dense(d_ff, ("embed", "mlp"), "up_proj")(x)
         hidden = nn.silu(gate) * up
         return nn.DenseGeneral(
             features=cfg.d_model, axis=-1, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="down_proj",
             kernel_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02 / np.sqrt(2 * cfg.n_layers)), ("mlp", "embed")),
+                nn.initializers.normal(cfg.init_std("mlp")), ("mlp", "embed")),
         )(hidden)
 
 
@@ -230,6 +317,8 @@ class MoEMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        from ray_tpu.ops.moe import select_experts
+
         cfg = self.cfg
         B, S, D = x.shape
         E, K = cfg.n_experts, cfg.experts_per_token
@@ -251,13 +340,16 @@ class MoEMLP(nn.Module):
                           kernel_init=nn.with_logical_partitioning(
                               nn.initializers.normal(0.02), ("embed", "expert")))
         logits = router(xf.astype(jnp.float32))  # (G, g, E)
-        probs = jax.nn.softmax(logits, axis=-1)
+        bias = None
+        if cfg.router_kind == "sigmoid":
+            bias = self.param("router_bias", nn.with_logical_partitioning(
+                nn.initializers.normal(ROUTER_BIAS_STD), ("expert",)),
+                (E,), jnp.float32)
 
-        # top-k expert choice per token
-        gate_vals, expert_idx = jax.lax.top_k(probs, K)  # (G, g, K)
-        if cfg.norm_topk_prob:
-            gate_vals = gate_vals / jnp.maximum(
-                gate_vals.sum(-1, keepdims=True), 1e-9)
+        # top-k expert choice per token; (G, g, K) and the scores (G, g, E)
+        gate_vals, expert_idx, probs = select_experts(
+            logits, K, cfg.norm_topk_prob, cfg.router_kind, bias,
+            cfg.routed_scaling_factor)
 
         # position of each (token, k) within its expert's capacity buffer,
         # per group; k-slots of a token are ordered before later tokens
@@ -281,7 +373,7 @@ class MoEMLP(nn.Module):
         def stack_param(name, shape, axes):
             return self.param(
                 name, nn.with_logical_partitioning(
-                    nn.initializers.normal(0.02 / np.sqrt(2 * cfg.n_layers)),
+                    nn.initializers.normal(cfg.init_std("mlp")),
                     axes),
                 shape, cfg.param_dtype)
 
@@ -306,7 +398,11 @@ class MoEMLP(nn.Module):
         prob_frac = jnp.mean(probs, axis=(0, 1))
         aux = E * jnp.sum(token_frac * prob_frac)
         self.sow("losses", "moe_aux", aux)
-        return out.reshape(B, S, D)
+        out = out.reshape(B, S, D)
+        if cfg.n_shared_experts:
+            out = out + MLP(cfg, cfg.n_shared_experts * cfg.d_ff,
+                            name="shared")(x)
+        return out
 
 
 class Block(nn.Module):
@@ -320,7 +416,8 @@ class Block(nn.Module):
             RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x), positions,
             segment_ids)
         h = nn.with_logical_constraint(h, ("batch", "seq", "embed"))
-        mlp = MoEMLP(cfg, name="moe") if self.use_moe else MLP(cfg, name="mlp")
+        mlp = MoEMLP(cfg, name="moe") if self.use_moe else MLP(
+            cfg, cfg.d_ff_dense, name="mlp")
         out = h + mlp(RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h))
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
@@ -347,8 +444,7 @@ class Transformer(nn.Module):
             block = nn.remat(Block, prevent_cse=False,
                              policy=jax.checkpoint_policies.nothing_saveable)
         for i in range(cfg.n_layers):
-            use_moe = cfg.n_experts > 0 and i % max(cfg.moe_every, 1) == 0
-            x = block(cfg, use_moe, name=f"layer_{i}")(
+            x = block(cfg, cfg.is_moe_layer(i), name=f"layer_{i}")(
                 x, positions, segment_ids)
         x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
         if cfg.tie_embeddings:

@@ -134,7 +134,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                       sm_scale):
     import jax.experimental.pallas as pl
 
-    block_q, d = q_ref.shape[1:]
+    block_q, d = q_ref.shape[1], v_ref.shape[2]
     seq_len = k_ref.shape[1]
     scores = _scores(q_ref[0], sm_scale)
     q_start = pl.program_id(1) * block_q
@@ -180,32 +180,49 @@ def _from_bh(x, B, H):
 
 
 def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool):
-    """Returns (o, lse) with o in (B, S, H, D) and lse in (B*H, 1, S)."""
+    """Returns (o, lse) with o in (B, S, H, Dv) and lse in (B*H, 1, S). The
+    values may have a head size of their own (latent attention: q . k over
+    192, p . v over 128); the softmax scale is the key head size's."""
     import jax.experimental.pallas as pl
 
     B, S, H, D = q.shape
+    Dv = v.shape[-1]
     block_q, block_k = _blocks(S)
     qt, kt, vt = _to_bh(q), _to_bh(k), _to_bh(v)
     kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
                                causal=causal, sm_scale=1.0 / (D ** 0.5))
+    # the whole-sequence K and V, twice each (the pipeline's two buffers),
+    # as fast memory holds them (the minor dimension in whole 128-lane tiles):
+    # 12.6 MiB at bf16[.., 8192, 192 / 128], which with the blocks and the
+    # score tile is 0.4 MiB past the compiler's own 16 MiB. Only such a shape
+    # gets a limit of its own; every other call is compiled as it was.
+    lanes = lambda d: -(-d // 128) * 128    # noqa: E731
+    held = 2 * S * (lanes(D) + lanes(Dv)) * k.dtype.itemsize
+    params = {}
+    if held > 10 << 20:
+        from jax.experimental.pallas import tpu as pltpu
+
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=held + (16 << 20))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, S // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, S, Dv), lambda bh, qi: (bh, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
+        **params,
     )(qt, kt, vt)
     return _from_bh(out, B, H), lse
 
@@ -358,7 +375,8 @@ def flash_attention(q, k, v, causal: bool = True, interpret: bool = False):
 
 
 def _fa_fwd(q, k, v, causal, interpret):
-    if _use_pallas_bwd(q.shape[-1]):  # head_dim is static at trace time
+    # head_dim is static at trace time; the pallas pair takes one head size
+    if q.shape[-1] == v.shape[-1] and _use_pallas_bwd(q.shape[-1]):
         o, lse = _flash_fwd_impl(q, k, v, causal, interpret)
         return o, (q, k, v, o, lse)
     # reference backward never reads o/lse: don't hold them across bwd
@@ -443,7 +461,7 @@ def attention(q, k, v, causal: bool = True, impl: str = "auto",
             is_tpu()
             and segment_ids is None
             and q.shape[1] % 128 == 0
-            and q.shape[-1] in (64, 128, 256)
+            and q.shape[-1] in (64, 128, 192, 256)
         )
         impl = "flash" if use_flash else "xla"
     if impl == "flash":

@@ -8,7 +8,8 @@ experts, whatever the load; there is no capacity and nothing falls through.
    float32 and at the highest matmul precision (``D x E`` is free, and matmul
    rounding then has no say in which experts a row gets);
 2. ``top_k`` of the probabilities, renormalised to sum to one only with
-   ``norm_topk_prob``;
+   ``norm_topk_prob`` (``select_experts``; its ``sigmoid`` kind chooses by
+   sigmoid scores plus a selection bias and weighs by the scores alone);
 3. rows that are padding or belong to an inactive slot get no expert: their
    assignments sort behind every real one, lie in no group, cost no expert
    compute and are not counted in the load;
@@ -31,7 +32,7 @@ computes what this file computes (``tests/test_moe.py``).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,18 +44,43 @@ from jax.experimental.pallas import tpu as pltpu
 _RHS_BLOCK_ELEMS = 1 << 20
 
 
-def route(x: jax.Array, router: jax.Array, top_k: int,
-          norm_topk_prob: bool) -> Tuple[jax.Array, jax.Array]:
+def select_experts(logits: jax.Array, top_k: int, norm_topk_prob: bool,
+                   kind: str = "softmax", bias: Optional[jax.Array] = None,
+                   scale: float = 1.0):
+    """Router logits [..., E] float32 -> (weights [..., top_k], experts
+    [..., top_k] int32 in descending order of what chose them, scores
+    [..., E]). ``softmax``: the top_k of the softmax, renormalised to sum to
+    one only with ``norm_topk_prob``. ``sigmoid``: the scores are sigmoids;
+    the experts are the top_k of ``scores + bias``, a per-expert selection
+    bias that chooses and does not weigh; the weights are the chosen
+    experts' scores without it, renormalised with ``norm_topk_prob``, times
+    ``scale``."""
+    if kind == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(scores, top_k)
+        if norm_topk_prob:
+            weights = weights / jnp.maximum(
+                weights.sum(-1, keepdims=True), 1e-9)
+    elif kind == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(scores + bias, top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if norm_topk_prob:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        weights = weights * scale
+    else:
+        raise ValueError(f"unknown router kind {kind!r}")
+    return weights, experts.astype(jnp.int32), scores
+
+
+def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk_prob: bool,
+          kind: str = "softmax", bias: Optional[jax.Array] = None,
+          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """x [T, D], router [D, E] -> (weights [T, top_k] float32, experts
-    [T, top_k] int32), the experts of a row in descending probability."""
+    [T, top_k] int32): ``select_experts`` of the float32 logits."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, top_k)
-    if norm_topk_prob:
-        weights = weights / jnp.maximum(
-            weights.sum(-1, keepdims=True), 1e-9)
-    return weights, experts.astype(jnp.int32)
+    return select_experts(logits, top_k, norm_topk_prob, kind, bias, scale)[:2]
 
 
 def _tiles(m: int, k: int, n: int, itemsize: int) -> Tuple[int, int]:
@@ -147,14 +173,17 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
 def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
                  w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
                  top_k: int, norm_topk_prob: bool,
-                 name: str = "moe_gmm") -> Tuple[jax.Array, jax.Array]:
+                 name: str = "moe_gmm", router_kind: str = "softmax",
+                 router_bias: Optional[jax.Array] = None,
+                 router_scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """x [T, D], valid [T] bool, router [D, E], w_gate / w_up [E, D, F],
     w_down [E, F, D] -> (y [T, D] in x's dtype, load [E] int32). ``load`` is
     the number of real rows each expert got; a row that is not valid gives
-    zeros and loads nobody."""
+    zeros and loads nobody. The ``router_*`` arguments are ``route``'s."""
     T, D = x.shape
     E = router.shape[1]
-    weights, experts = route(x, router, top_k, norm_topk_prob)
+    weights, experts = route(x, router, top_k, norm_topk_prob, router_kind,
+                             router_bias, router_scale)
     # an assignment of an invalid row goes to "expert E": behind every group
     flat = jnp.where(valid[:, None], experts, E).reshape(-1)
     load = (flat[:, None] == jnp.arange(E, dtype=jnp.int32)).sum(

@@ -1290,9 +1290,21 @@ class _HttpProxy:
     """aiohttp ingress: POST /<deployment> with a JSON body routes to the
     deployment handle and returns the JSON-serialized response."""
 
+    # A routed request holds a thread from when it is handed to the
+    # deployment's handle until it is answered (``ray_tpu.get`` blocks), so
+    # the pool's size is how many requests the proxy keeps in flight. The
+    # loop's default executor has cpu + 4 threads, 17 on a 13-core host: an
+    # LLM replica with 32 decode slots never saw more than 17 requests and
+    # ran half empty while hundreds waited here (PERF.md section 6, PR 31).
+    ROUTE_THREADS = 256
+
     def __init__(self, port: int):
+        from concurrent.futures import ThreadPoolExecutor
+
         self.port = port
         self._runner = None
+        self._routes = ThreadPoolExecutor(
+            self.ROUTE_THREADS, thread_name_prefix="serve-proxy-route")
 
     async def start(self) -> int:
         import json
@@ -1378,7 +1390,7 @@ class _HttpProxy:
             try:
                 # route off-loop: handle calls block on the core worker
                 result = await loop.run_in_executor(
-                    None, functools.partial(_route, name, body))
+                    self._routes, functools.partial(_route, name, body))
                 return web.json_response({"result": result})
             except Exception as e:
                 return web.json_response({"error": str(e)}, status=500)

@@ -147,8 +147,7 @@ def _build_stage_module(cfg: TransformerConfig, start: int, stop: int,
                     Block, prevent_cse=False,
                     policy=jax.checkpoint_policies.nothing_saveable)
             for i in range(start, stop):
-                use_moe = c.n_experts > 0 and i % max(c.moe_every, 1) == 0
-                x = block(c, use_moe, name=f"layer_{i}")(
+                x = block(c, c.is_moe_layer(i), name=f"layer_{i}")(
                     x, positions, segment_ids)
             if not last:
                 return x

@@ -348,3 +348,103 @@ def test_sharded_update_step_partitions_over_four_chips(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "reduce-scatter" in text and "all-gather" in text
+
+
+# -- the latent model's programs (Moonlight-16B-A3B, the benchmark's file) -------
+
+
+def _lower_latent(one_chip, n_layers):
+    """The engine's programs at the published widths of
+    ``benchmarks/configs/moonlight-16b-a3b.json`` and its job block's geometry
+    (32 slots x 8192, pages of 256), depth cut to ``n_layers`` (the dense
+    layer and the sparse ones after it)."""
+    import json
+
+    import flax.linen as nn
+
+    from benchmarks.jobs import common
+    from benchmarks.registry import REPO
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.llm.config import EngineConfig
+    from ray_tpu.models.transformer import Transformer
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "moonlight-16b-a3b.json")) as f:
+        conf = json.load(f)
+    e = EngineConfig(**conf["job"]["engine"])
+    cfg = dataclasses.replace(
+        common.transformer_config(conf, e.max_model_len), n_layers=n_layers,
+        attention_impl="flash")
+    params = _on(jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))), one_chip)
+    cache = _on(jax.eval_shape(
+        lambda: mr.init_cache(cfg, e.num_pages, e.page_size)), one_chip)
+    B, MP = e.max_num_seqs, e.pages_per_seq
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+
+    def prefill(rows, bucket):
+        return mr.prefill.lower(params, cfg, cache, i32(rows, bucket),
+                                i32(rows), i32(rows, MP))
+
+    return cache, prefill, lambda: mr.decode_step.lower(
+        params, cfg, cache, i32(B), i32(B), i32(B, MP), active)
+
+
+def _live(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes), m.temp_size_in_bytes
+
+
+def test_latent_decode_compiles_with_the_mla_kernel(one_chip):
+    """Decode at 32 slots, the dense layer and one sparse one: one
+    ``mla_decode`` call a layer under the name the trace's metrics read,
+    beside the three grouped matmuls; the latent rows are written in place
+    (one scatter a layer into the whole array, the cache aliased) and no
+    copy of the cache is made for the kernel: the temporaries stay far below
+    one layer of it (336 MB). Depth 2 keeps the compile short: these run
+    beside tests that time themselves."""
+    cache, _, decode = _lower_latent(one_chip, n_layers=2)
+    compiled = decode().compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%(mla_decode\S*) = bf16\[32,16,512\]",
+                              text))) == 2
+    assert len(set(re.findall(r"%(moe_gmm_decode\S*) = bf16\[192,",
+                              text))) == 3
+    assert text.count("tpu_custom_call") == 5
+    live, temp = _live(compiled)
+    L, NP, P, W = cache.rows.shape
+    print(f"latent decode, 2 layers, 32 slots: {live} bytes live, "
+          f"{temp} of temporaries; cache {cache.rows.size * 2}")
+    assert (P, W) == (256, 640)
+    assert temp < NP * P * W * 2 // 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache.rows.size * 2
+
+
+@pytest.mark.parametrize("rows,bucket", [(1, 512), (1, 8192)],
+                         ids=["1x512", "1x8192"])
+def test_latent_prefill_compiles_with_unequal_head_sizes(one_chip, rows,
+                                                         bucket):
+    """The engine's prefill at the mix's least and largest bucket, the dense
+    layer and one sparse one: the flash kernel over 192-wide q . k and
+    128-wide values (at 8192 positions its whole-sequence K/V need a
+    fast-memory limit of their own, ``ops/attention.py``), the three grouped
+    matmuls over rows x 6 assignments. What an execution holds live is
+    printed (``-s``); the full depth and the check's [32, 512] batch are in
+    PERF.md section 4."""
+    _, prefill, _ = _lower_latent(one_chip, n_layers=2)
+    compiled = prefill(rows, bucket).compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(
+        rf"%(flash_fwd\S*) = \(bf16\[{rows * 16},{bucket},128\]", text))) == 2
+    assert len(set(re.findall(
+        rf"%(moe_gmm_prefill\S*) = bf16\[{rows * bucket * 6},", text))) == 3
+    assert text.count("tpu_custom_call") == 5
+    live, temp = _live(compiled)
+    print(f"latent prefill, 2 layers, [{rows}, {bucket}]: {live} bytes live, "
+          f"{temp} of temporaries")
+    assert 0 < live < 16 << 30

@@ -23,7 +23,9 @@ KEYS = {
     "between_steps_ms", "queue_wait_ms", "ttft_ms",
     # what routing did; a dense model's (this one's) stay 0
     "moe_decode_layer_steps", "moe_decode_assignments",
-    "moe_decode_experts_touched", "moe_decode_max_load"}
+    "moe_decode_experts_touched", "moe_decode_max_load",
+    # what a latent cache's decode read; 0 without one
+    "mla_decode_live_tokens", "mla_decode_read_tokens"}
 PHASES = ("admit_ms", "prefill_dispatch_ms", "decode_dispatch_ms",
           "sample_dispatch_ms", "readback_ms", "emit_ms")
 SLOTS, BUCKET = 8, 16

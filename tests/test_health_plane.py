@@ -335,6 +335,7 @@ def test_fork_resets_inherited_obs_buffers():
     from ray_tpu.util.metrics import Counter
 
     task_events.set_enabled(True)
+    task_events.drain()  # what an earlier test of this xdist worker left
     task_events.record("deadbeef", task_events.SUBMITTED, name="fork_probe")
     old_enabled = tracing._enabled
     tracing._enabled = True

@@ -20,6 +20,13 @@ def cluster():
     ray_tpu.shutdown()
 
 
+@pytest.fixture(scope="module")
+def http_port(cluster):
+    """The one HTTP proxy of this module's cluster (a second start of the
+    detached proxy actor would bind its port again)."""
+    return serve.start_http_proxy()
+
+
 def test_function_deployment(cluster):
     @serve.deployment
     def doubler(body):
@@ -127,7 +134,7 @@ def test_replica_restart_on_death(cluster):
     serve.delete("Fragile")
 
 
-def test_http_proxy(cluster):
+def test_http_proxy(cluster, http_port):
     import json
     import urllib.request
 
@@ -136,7 +143,7 @@ def test_http_proxy(cluster):
         return {"echo": body}
 
     serve.run(echo.bind())
-    port = serve.start_http_proxy()
+    port = http_port
     req = urllib.request.Request(
         f"http://127.0.0.1:{port}/echo", data=json.dumps({"hi": 1}).encode(),
         headers={"Content-Type": "application/json"})
@@ -144,6 +151,55 @@ def test_http_proxy(cluster):
         out = json.loads(resp.read())
     assert out["result"]["echo"] == {"hi": 1}
     serve.delete("echo")
+
+
+def test_http_proxy_keeps_more_requests_in_flight_than_the_loops_pool(
+        cluster, http_port):
+    """A routed request holds a proxy thread until it is answered; the
+    loop's default executor has cpu + 4 of them, and an LLM replica with 32
+    decode slots behind it ran half empty. 40 requests whose handler waits
+    until all 40 have arrived: every one is answered, so all were in flight
+    at once."""
+    import asyncio
+    import json
+    import os
+    import threading
+    import urllib.request
+
+    N = 40
+    assert N > min(32, (os.cpu_count() or 1) + 4)
+
+    @serve.deployment(max_ongoing_requests=N)
+    class Gate:
+        def __init__(self):
+            self.n = 0
+
+        async def __call__(self, body):
+            self.n += 1
+            for _ in range(600):
+                if self.n >= body["n"]:
+                    return {"seen": self.n}
+                await asyncio.sleep(0.05)
+            return {"seen": self.n, "gave_up": True}
+
+    serve.run(Gate.bind())
+    port = http_port
+    out = [None] * N
+
+    def ask(i):
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{port}/Gate", data=json.dumps({"n": N}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=90) as resp:
+            out[i] = json.loads(resp.read())["result"]
+
+    threads = [threading.Thread(target=ask, args=(i,)) for i in range(N)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+    assert all(o == {"seen": N} for o in out), out
+    serve.delete("Gate")
 
 
 def test_replica_peak_sampling_under_stats_lock():

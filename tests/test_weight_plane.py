@@ -389,7 +389,12 @@ def test_rolling_serve_weight_update_zero_drops(cluster):
                    for _ in range(4)]
         for t in threads:
             t.start()
-        time.sleep(0.5)
+        # traffic must be flowing BEFORE the update: wait for the first
+        # answers rather than for a fixed time (beside five other xdist
+        # workers the first round trip has taken more than half a second)
+        deadline = time.time() + 60
+        while len(responses) < 4 and time.time() < deadline:
+            time.sleep(0.05)
         v1 = store.publish({"w": np.full(4, 7.0, np.float32)})
         acks = handle.broadcast("update_weights", timeout=120)
         assert acks == [v1] * 3  # every replica applied the update
