@@ -13,27 +13,60 @@ two compiled XLA programs (prefill per length bucket, one decode step):
   prefill each alone, one program call a request on a ``[1, S]`` batch with
   ``S`` its own length bucket (a freed slot is refilled by one request as a
   rule, and a row of padding costs what a real one costs); the calls run back
-  to back and one sampler call reads their first tokens. Then one decode
+  to back and one sampler call samples their first tokens. Then one decode
   step for all active slots.
 
-The engine is synchronous and single-threaded by design — actor wrappers
-(serve_llm.LLMServer) give it an async front end.
+The engine is single-threaded by design (actor wrappers, serve_llm.LLMServer,
+give it an async front end) and reads a step's tokens ONE STEP LATE. A call of
+``step()`` dispatches its programs from what the host knows, starts the copy
+of their tokens to the host, and only then blocks on the tokens of the call
+before it, emits those and returns THAT step's outputs: the device always has
+a step queued behind the one it runs, and the host's admit, emit and the
+caller's way back to ``step()`` cost it nothing. What makes that possible:
+
+- every slot's newest token stays on the device (``_tokens``): the decode
+  step reads the sampler's output of the step before, merged there with a
+  prefill phase's first tokens by one ``[B]`` select;
+- ``seq_lens``, block tables and the active mask do not depend on a token's
+  value: the host advances them at dispatch, and counts a token in flight as
+  generated. A request that ends by ``max_tokens`` or ``max_model_len`` ends
+  at a step the host can count: its slot and pages are free at that dispatch
+  (the device runs programs in order, so whatever is queued later may have
+  them) and the next call admits into the slot;
+- a request that EOS or a stop token ends is seen one step late: its slot has
+  run one more decode step by then, whose token is dropped, never emitted
+  (``dropped_tokens``), and is free for the call after;
+- whoever reads or changes slot state out of that order first reads
+  everything in flight (``_drain``): ``abort_request``, ``export_kv``,
+  ``add_request_with_kv``, ``step(decode=False)`` (which also reads its own
+  tokens before it returns) and a preemption. Outputs read outside a
+  ``step()`` are handed over by the next one; ``has_unfinished()`` is true
+  until they are. A caller whose ``step()`` raised drops what is in flight
+  unread (``discard_in_flight``) before it aborts its requests. ``params``
+  may be swapped between calls: a step already dispatched keeps the tree it
+  was given.
 
 What the engine says about itself (``LLMServer.engine_metrics()`` returns a
 copy of ``engine.metrics``: flat, numeric, only ever growing, every key there
 from ``__init__``, so two snapshots subtract):
 
 - counts: ``steps`` (calls of ``step()`` that ran a program),
-  ``prefill_steps`` (steps that ran a prefill phase), ``decode_steps``,
+  ``overlapped_steps`` (those that did so while an earlier step's tokens were
+  unread: all but the first of a busy stretch), ``dropped_tokens``,
+  ``prefill_steps`` (steps that ran a prefill phase), ``decode_steps`` (a
+  dropped token's step is one),
   ``admitted`` (also the number of prefill program calls),
   ``prefill_tokens`` (real prompt positions) against
   ``prefill_batch_tokens`` (the ``S`` of every prefill call: what the device
   computes), ``generated_tokens``, ``preempted``, ``compiles`` (first use of
-  a prefill bucket or of decode);
+  a prefill bucket or of decode); program counts move at dispatch,
+  ``generated_tokens`` when a token is emitted;
 - host milliseconds (``perf_counter_ns``): ``step_ms`` = ``host_ms`` +
-  ``readback_ms`` (blocked on the device in ``np.asarray(tokens)``); the
-  phases ``admit_ms``, ``prefill_dispatch_ms``, ``decode_dispatch_ms``,
-  ``sample_dispatch_ms``, ``readback_ms``, ``emit_ms`` add up to ``step_ms``;
+  ``readback_ms`` (blocked on the device in ``np.asarray(tokens)``: with a
+  step queued behind the one it waits for, the device's time and not the
+  host's); the phases ``admit_ms``, ``prefill_dispatch_ms``,
+  ``decode_dispatch_ms``, ``sample_dispatch_ms``, ``readback_ms``,
+  ``emit_ms`` add up to ``step_ms``;
   ``between_steps_ms`` is the caller's time from one ``step()`` to the next
   while work was left;
 - per request, summed: ``queue_wait_ms`` (``add_request`` to first
@@ -43,8 +76,9 @@ from ``__init__``, so two snapshots subtract):
   over those, ``moe_decode_assignments`` (active slots x top_k),
   ``moe_decode_experts_touched`` (experts that got a row) and
   ``moe_decode_max_load`` (rows of the fullest expert). They come from
-  ``KVCache.moe_load``, a few KB whose copy to the host starts at dispatch
-  and is read in ``emit`` after the tokens are back;
+  ``KVCache.moe_load``, a few KB copied out of the cache at dispatch (the
+  next dispatch donates the cache) and read in ``emit`` with that step's
+  tokens, a step later;
 - for a model with a latent cache (0 otherwise), per decode step and a layer:
   ``mla_decode_live_tokens`` (positions the active slots attend over) and
   ``mla_decode_read_tokens`` (positions of the pages ``mla_decode`` is given:
@@ -53,12 +87,15 @@ from ``__init__``, so two snapshots subtract):
 The same boundaries are spans on the profiler's clock
 (``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
 that owns the engine shows ``ray_tpu/engine.step`` on the host plane and,
-inside it, ``engine.admit``, ``.prefill_dispatch`` (arguments ``bucket``,
-the largest of the phase, and ``admitted``), ``.decode_dispatch`` (arguments
-``experts``: experts touched per layer in the newest decode step the host
-has read, models with experts only; ``live_tokens``: positions the step
-attends over, models with a latent cache only), ``.sample_dispatch``, ``.readback``,
-``.emit`` and, around a shape's first use, ``.compile``. With
+inside it, in this order, ``engine.admit``, ``.prefill_dispatch`` (arguments
+``bucket``, the largest of the phase, and ``admitted``), ``.sample_dispatch``,
+``.decode_dispatch`` (arguments ``overlapped``: 1 if an earlier step is
+unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
+per layer in the newest decode step the host has read, models with experts
+only; ``live_tokens``: positions the step attends over, models with a latent
+cache only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
+every sampler call of the step before; around a shape's first use,
+``.compile``. With
 ``RAY_TPU_ENABLE_TRACING`` a finished request also leaves ``engine.queued``,
 ``engine.prefill`` and ``engine.decode`` spans (``request_id``) under the
 span that called ``add_request`` (``/api/timeline``). An operator's guide is
@@ -91,6 +128,8 @@ class _Request:
     pages: List[int] = dataclasses.field(default_factory=list)
     finished: bool = False
     finish_reason: Optional[str] = None
+    # tokens the device was asked for and the host has not read yet
+    in_flight: int = 0
     # perf_counter seconds; 0.0 = not yet
     t_added: float = dataclasses.field(default_factory=time.perf_counter)
     t_admitted: float = 0.0
@@ -107,6 +146,14 @@ class _Request:
 
 
 @dataclasses.dataclass
+class _Unread:
+    """One sampler call whose tokens the host has not read."""
+    tokens: Any  # [B] int32 on the device, its copy to the host started
+    rows: List[Tuple[_Request, int]]  # whose token sits at which slot
+    moe_load: Any = None  # a decode step's routing, outside the donated cache
+
+
+@dataclasses.dataclass
 class RequestOutput:
     request_id: str
     token_ids: List[int]
@@ -116,7 +163,9 @@ class RequestOutput:
 
 
 class JaxLLMEngine:
-    """Synchronous continuous-batching engine over the paged-KV model runner."""
+    """Continuous-batching engine over the paged-KV model runner: one
+    ``step()`` dispatches a step and returns the outputs of the one before
+    (module docstring)."""
 
     def __init__(self, config: LLMConfig, params: Any = None, seed: int = 0):
         import jax
@@ -151,8 +200,17 @@ class JaxLLMEngine:
         B, MP = e.max_num_seqs, e.pages_per_seq
         self._block_tables = np.zeros((B, MP), np.int32)
         self._seq_lens = np.zeros(B, np.int32)
-        self._last_tokens = np.zeros(B, np.int32)
         self._active = np.zeros(B, bool)
+        # every slot's newest token, on the device: what the next decode step
+        # reads, whether or not the host has seen it yet
+        self._tokens = jax.numpy.zeros(B, jax.numpy.int32)
+        # sampler calls dispatched and not yet read, oldest first
+        self._unread: collections.deque[_Unread] = collections.deque()
+        # what was emitted since step() last returned
+        self._outputs: List[RequestOutput] = []
+        # how many of the oldest unread sampler calls an EARLIER step()
+        # dispatched: what the call under way hides behind, and reads last
+        self._earlier = 0
         self._temps = np.zeros(B, np.float32)
         self._top_ks = np.zeros(B, np.int32)
         self._top_ps = np.ones(B, np.float32)
@@ -181,6 +239,7 @@ class JaxLLMEngine:
             "decode_dispatch_ms": 0.0, "sample_dispatch_ms": 0.0,
             "emit_ms": 0.0, "between_steps_ms": 0.0,
             "queue_wait_ms": 0.0, "ttft_ms": 0.0,
+            "overlapped_steps": 0, "dropped_tokens": 0,
             "moe_decode_layer_steps": 0, "moe_decode_assignments": 0,
             "moe_decode_experts_touched": 0, "moe_decode_max_load": 0,
             "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0}
@@ -229,6 +288,9 @@ class JaxLLMEngine:
         self._waiting.append(req)
 
     def abort_request(self, request_id: str) -> None:
+        if request_id not in self._requests:
+            return
+        self._drain()  # its last token may be the one in flight
         req = self._requests.pop(request_id, None)
         if req is None:
             return
@@ -241,7 +303,10 @@ class JaxLLMEngine:
                 pass
 
     def has_unfinished(self) -> bool:
-        return bool(self._waiting) or self._active.any()
+        """Is there anything a further ``step()`` would run, read or hand
+        over? True while a dispatched step's tokens are unread."""
+        return bool(self._waiting or self._unread or self._outputs) \
+            or bool(self._active.any())
 
     def num_waiting(self) -> int:
         return len(self._waiting)
@@ -278,13 +343,16 @@ class JaxLLMEngine:
             row[:] = 0
             row[:need] = req.pages
             self._seq_lens[req.slot] = len(req.cache_tokens)
-            p = req.params
-            self._temps[req.slot] = p.temperature
-            self._top_ks[req.slot] = p.top_k
-            self._top_ps[req.slot] = p.top_p
-            self._seeds[req.slot] = -1 if p.seed is None else p.seed
+            self._set_sampling(req)
             admitted.append(req)
         return admitted
+
+    def _set_sampling(self, req: _Request) -> None:
+        p = req.params
+        self._temps[req.slot] = p.temperature
+        self._top_ks[req.slot] = p.top_k
+        self._top_ps[req.slot] = p.top_p
+        self._seeds[req.slot] = -1 if p.seed is None else p.seed
 
     def _prefill_bucket(self, n: int) -> int:
         b = self.ecfg.prefill_bucket_min
@@ -305,24 +373,51 @@ class JaxLLMEngine:
         self._block_tables[req.slot, need - 1] = page
         return True
 
+    def _grow_pages(self) -> None:
+        """Every occupied slot gets the page of its next position; where the
+        pool is empty the request goes back to waiting."""
+        for slot in range(len(self._slots)):
+            req = self._slots[slot]
+            if req is None or self._ensure_page(req):
+                continue
+            if self._unread:
+                # a preempted request is prefilled again from ALL its tokens;
+                # and what is unread may end a request (this one too) and
+                # bring its pages back
+                self._drain()
+                if self._slots[slot] is not req or self._ensure_page(req):
+                    continue
+            self.metrics["preempted"] += 1
+            self._requeue(req)
+
     def _next_rng(self):
         self._rng, sub = self._jax.random.split(self._rng)
         return sub
 
-    def _sample(self, logits) -> np.ndarray:
-        import jax.numpy as jnp
+    def _up(self, a: np.ndarray):
+        """One of the host's own arrays on the device as it reads NOW. The
+        host goes on writing them while the programs that take them are still
+        queued, and a transfer may read the buffer it was given after it
+        returns (the CPU backend aliases it): it gets a copy nobody writes."""
+        return self._jax.numpy.asarray(a.copy())
 
+    def _sample(self, logits):
+        """One sampler call over every slot's row of ``logits``. The tokens
+        stay on the device; their copy to the host starts and nothing waits
+        for it (``_read`` does, a step later)."""
         with self._phase("sample_dispatch"):
+            # a seeded request's position in its stream: the tokens it was
+            # given, read or not
             steps = np.array(
-                [len(s.generated) if s is not None else 0
+                [len(s.generated) + s.in_flight if s is not None else 0
                  for s in self._slots], np.int32)
             toks = self._mr.sample_tokens(
-                logits, self._next_rng(), jnp.asarray(self._temps),
-                jnp.asarray(self._top_ks), jnp.asarray(self._top_ps),
-                jnp.asarray(self._seeds), jnp.asarray(steps),
+                logits, self._next_rng(), self._up(self._temps),
+                self._up(self._top_ks), self._up(self._top_ps),
+                self._up(self._seeds), self._jax.numpy.asarray(steps),
                 max_top_k=self.ecfg.max_top_k)
-        with self._phase("readback"):  # blocks until the device is done
-            return np.asarray(toks)
+            toks.copy_to_host_async()
+        return toks
 
     # -- the step ----------------------------------------------------------
 
@@ -348,38 +443,49 @@ class JaxLLMEngine:
                                 bucket=bucket)
 
     def step(self, decode: bool = True) -> List[RequestOutput]:
-        """One scheduling step. ``decode=False`` runs only the admit+prefill
-        phase — the prefill side of PD disaggregation (reference serving
-        pattern: serving_patterns/prefill_decode/pd_server.py:31)."""
+        """One scheduling step: dispatch this step's programs from what the
+        host knows, THEN block on the tokens of the step before, emit them
+        and return that step's outputs (a request's output arrives one call
+        after the call that computed it; ``has_unfinished()`` stays true
+        until it has). ``decode=False`` runs only the admit+prefill phase and
+        reads its own tokens before it returns: the prefill side of PD
+        disaggregation (reference serving pattern:
+        serving_patterns/prefill_decode/pd_server.py:31)."""
         m = self.metrics
         t0 = time.perf_counter_ns()
         if self._step_ended_ns is not None:
             m["between_steps_ms"] += (t0 - self._step_ended_ns) / 1e6
         readback0 = m["readback_ms"]
         programs0 = m["prefill_steps"] + m["decode_steps"]
+        self._earlier = len(self._unread)
         with tracing.annotate("engine.step"):
-            outputs = self._step(decode)
+            overlapped = self._step(decode)
+        outputs, self._outputs = self._outputs, []
         t1 = time.perf_counter_ns()
         step_ms = (t1 - t0) / 1e6
         if m["prefill_steps"] + m["decode_steps"] > programs0:
             m["steps"] += 1
+            m["overlapped_steps"] += overlapped
         m["step_ms"] += step_ms
         m["host_ms"] += step_ms - (m["readback_ms"] - readback0)
         self._step_ended_ns = t1 if self.has_unfinished() else None
         return outputs
 
-    def _step(self, decode: bool) -> List[RequestOutput]:
+    def _step(self, decode: bool) -> bool:
+        """Returns whether a program was dispatched behind an unread step."""
         import jax.numpy as jnp
 
-        outputs: List[RequestOutput] = []
         mr, m = self._mr, self.metrics
+        overlapped = False
+        if not decode:
+            self._drain()
 
         # 1) admit + prefill: one program call per admitted request, on its
         # own row and its own length bucket, back to back (the donated cache
         # chains them; nothing is read in between). Each call's logits land
         # in the [B, vocab] buffer at the request's slot, so one sampler call
-        # and one blocking read serve the phase however many were admitted,
-        # and no shape depends on that number.
+        # serves the phase however many were admitted, and no shape depends
+        # on that number. Its tokens join the others on the device.
         with self._phase("admit"):
             admitted = self._try_admit()
             now = time.perf_counter()
@@ -388,6 +494,7 @@ class JaxLLMEngine:
                     r.t_admitted = now
                     m["queue_wait_ms"] += (now - r.t_added) * 1e3
         if admitted:
+            overlapped = self._earlier > 0
             slots = [r.slot for r in admitted]
             buckets = [self._prefill_bucket(n) for n in self._seq_lens[slots]]
             with self._phase("prefill_dispatch", bucket=max(buckets),
@@ -399,56 +506,114 @@ class JaxLLMEngine:
                     with self._first_use("prefill", S):
                         logits, self.cache = mr.prefill(
                             self.params, self.mcfg, self.cache,
-                            jnp.asarray(toks), jnp.asarray(self._seq_lens[row]),
-                            jnp.asarray(self._block_tables[row]))
+                            jnp.asarray(toks), self._up(self._seq_lens[row]),
+                            self._up(self._block_tables[row]))
                         self._prefill_logits = mr.place_row(
                             self._prefill_logits, logits, np.int32(r.slot))
-            toks_np = self._sample(self._prefill_logits)
+            firsts = self._sample(self._prefill_logits)
+            self._tokens = mr.select_rows(
+                jnp.asarray(np.isin(np.arange(len(self._slots)), slots)),
+                firsts, self._tokens)
             m["prefill_steps"] += 1
             m["admitted"] += len(admitted)
             m["prefill_tokens"] += int(self._seq_lens[slots].sum())
             m["prefill_batch_tokens"] += sum(buckets)
-            with self._phase("emit"):
-                for r in admitted:
-                    self._active[r.slot] = True
-                    self._emit(r, int(toks_np[r.slot]), outputs)
+            self._active[slots] = True
+            self._sent(firsts, admitted)
 
-        # 2) one decode step for all active slots
+        # 2) one decode step for all active slots, on the tokens the device
+        # holds: the host advances what does not depend on a token's value
         if decode and self._active.any():
-            attrs = dict(self._experts_attr)
+            attrs = dict(self._experts_attr, overlapped=int(self._earlier > 0),
+                         dropped=m["dropped_tokens"])
             if self.mcfg.kv_latent_rank:  # positions the step attends over
                 attrs["live_tokens"] = int(
                     (self._seq_lens[self._active] + 1).sum())
+            load = None
             with self._phase("decode_dispatch", **attrs):
-                # page-boundary allocation; preempt to waiting on exhaustion
-                for req in [s for s in self._slots if s is not None]:
-                    if self._active[req.slot] and not self._ensure_page(req):
-                        m["preempted"] += 1
-                        self._requeue(req)
+                self._grow_pages()
                 decoding = bool(self._active.any())
-                if decoding and self.mcfg.kv_latent_rank:
-                    self._count_latent_reads()
                 if decoding:
+                    overlapped = overlapped or self._earlier > 0
+                    if self.mcfg.kv_latent_rank:
+                        self._count_latent_reads()
                     with self._first_use("decode"):
                         logits, self.cache = mr.decode_step(
-                            self.params, self.mcfg, self.cache,
-                            jnp.asarray(self._last_tokens),
-                            jnp.asarray(self._seq_lens),
-                            jnp.asarray(self._block_tables),
-                            jnp.asarray(self._active))
+                            self.params, self.mcfg, self.cache, self._tokens,
+                            self._up(self._seq_lens),
+                            self._up(self._block_tables),
+                            self._up(self._active))
                     if self.cache.moe_load is not None:
-                        self.cache.moe_load.copy_to_host_async()
+                        # a copy outside the cache: the next call donates
+                        # the cache before the host reads this one's routing
+                        load = jnp.copy(self.cache.moe_load)
+                        load.copy_to_host_async()
             if decoding:
-                toks_np = self._sample(logits)
+                self._tokens = self._sample(logits)
                 m["decode_steps"] += 1
-                with self._phase("emit"):
-                    if self.cache.moe_load is not None:
-                        self._count_routing(np.asarray(self.cache.moe_load))
-                    for req in list(self._slots):
-                        if req is not None and self._active[req.slot]:
-                            self._seq_lens[req.slot] += 1
-                            self._emit(req, int(toks_np[req.slot]), outputs)
-        return outputs
+                self._seq_lens[self._active] += 1
+                self._sent(self._tokens, [
+                    self._slots[i] for i in np.flatnonzero(self._active)],
+                    load)
+
+        # 3) the steps before this one: the device has this call's programs
+        # queued behind them, so the read, the emit and the caller's way back
+        # here cost it nothing
+        while self._unread and (self._earlier or not decode):
+            self._read()
+        return overlapped
+
+    def _sent(self, tokens, reqs: List[_Request], moe_load=None) -> None:
+        """A sampler call is on its way with a token for each of ``reqs``.
+        One that ends by length with it ends at a step the host can count:
+        its slot and pages are free at once (the device runs its programs in
+        order, so whatever is queued behind this call may have them)."""
+        self._unread.append(_Unread(
+            tokens, [(r, r.slot) for r in reqs], moe_load))
+        for r in reqs:
+            r.in_flight += 1
+            if self._ends_by_length(r, len(r.generated) + r.in_flight):
+                self._release(r)
+
+    def _ends_by_length(self, req: _Request, generated: int) -> bool:
+        return (generated >= req.params.max_tokens
+                or len(req.prompt_tokens) + generated
+                >= self.ecfg.max_model_len)
+
+    def _read(self) -> None:
+        """Block on the oldest unread sampler call and emit its tokens."""
+        u = self._unread.popleft()
+        self._earlier = max(self._earlier - 1, 0)
+        with self._phase("readback"):  # blocks until the device is done
+            toks = np.asarray(u.tokens)
+        with self._phase("emit"):
+            if u.moe_load is not None:
+                self._count_routing(np.asarray(u.moe_load))
+            for req, slot in u.rows:
+                req.in_flight -= 1
+                if req.finished:
+                    # a stop token read since the dispatch ended it: the slot
+                    # ran on, for nobody
+                    self.metrics["dropped_tokens"] += 1
+                else:
+                    self._emit(req, int(toks[slot]))
+
+    def _drain(self) -> None:
+        """Read everything in flight: whoever reads or changes slot state
+        from outside a step's own order does this first."""
+        while self._unread:
+            self._read()
+
+    def discard_in_flight(self) -> None:
+        """Forget every dispatched step without reading it: for a caller
+        whose ``step()`` raised, since what is unread may be what failed and a
+        read of it raises again. The tokens are lost; ``abort_request`` then
+        has nothing left to read and cannot raise."""
+        for u in self._unread:
+            for req, _ in u.rows:
+                req.in_flight -= 1
+        self._unread.clear()
+        self._earlier = 0
 
     def _count_latent_reads(self) -> None:
         """What the decode step about to run attends over (``live``: positions
@@ -482,27 +647,23 @@ class JaxLLMEngine:
         self._release(req)
         self._waiting.appendleft(req)
 
-    def _emit(self, req: _Request, token: int, outputs: List[RequestOutput]):
+    def _emit(self, req: _Request, token: int) -> None:
         req.generated.append(token)
-        self._last_tokens[req.slot] = token
         self.metrics["generated_tokens"] += 1
         if not req.t_first_token:
             req.t_first_token = time.perf_counter()
             self.metrics["ttft_ms"] += (req.t_first_token - req.t_added) * 1e3
-        eos = self.tokenizer.eos_token_id
-        total = len(req.prompt_tokens) + len(req.generated)
-        if token == eos or token in req.params.stop_token_ids:
+        if (token == self.tokenizer.eos_token_id
+                or token in req.params.stop_token_ids):
             req.finished, req.finish_reason = True, "stop"
-        elif len(req.generated) >= req.params.max_tokens:
-            req.finished, req.finish_reason = True, "length"
-        elif total >= self.ecfg.max_model_len:
+        elif self._ends_by_length(req, len(req.generated)):
             req.finished, req.finish_reason = True, "length"
         if req.finished:
-            self._release(req)
+            self._release(req)  # nothing left to free if it ended by length
             self._requests.pop(req.request_id, None)
             if tracing.enabled():
                 self._record_request_spans(req)
-        outputs.append(RequestOutput(
+        self._outputs.append(RequestOutput(
             req.request_id, list(req.generated), req.finished,
             req.finish_reason))
 
@@ -536,7 +697,9 @@ class JaxLLMEngine:
         self.add_request(request_id, prompt, params)
         req = self._requests[request_id]
         for _ in range(max_steps):
-            self.step(decode=False)
+            # what the call read of other requests waits for the next step()
+            outs = self.step(decode=False)
+            self._outputs += [o for o in outs if o.request_id != request_id]
             if req.finished or req.generated:
                 break
         else:
@@ -555,6 +718,7 @@ class JaxLLMEngine:
         """Gather a live request's KV pages + scheduling state, releasing
         the request locally. The blob is plain numpy: it ships over the
         object plane (or the device-object plane when replicas colocate)."""
+        self._drain()
         req = self._requests.get(request_id)
         if req is None or req.slot < 0:
             raise KeyError(f"no live request {request_id}")
@@ -587,6 +751,7 @@ class JaxLLMEngine:
         if state.get("finished"):
             # finished during prefill (e.g. max_tokens=1): nothing to decode
             raise ValueError("request already finished at prefill")
+        self._drain()
         free_slots = [i for i, s in enumerate(self._slots) if s is None]
         leaves = self._page_leaves()
         n_pages = state[leaves[0]].shape[1]
@@ -607,12 +772,11 @@ class JaxLLMEngine:
         row[:] = 0
         row[:n_pages] = req.pages
         self._seq_lens[req.slot] = state["seq_len"]
-        self._last_tokens[req.slot] = req.generated[-1]
-        p = req.params
-        self._temps[req.slot] = p.temperature
-        self._top_ks[req.slot] = p.top_k
-        self._top_ps[req.slot] = p.top_p
-        self._seeds[req.slot] = -1 if p.seed is None else p.seed
+        here = np.arange(len(self._slots)) == req.slot
+        self._tokens = self._mr.select_rows(
+            jnp.asarray(here), jnp.asarray(here * np.int32(req.generated[-1])),
+            self._tokens)
+        self._set_sampling(req)
         self._slots[req.slot] = req
         self._active[req.slot] = True
         self._requests[req.request_id] = req

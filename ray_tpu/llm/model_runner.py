@@ -271,6 +271,14 @@ def place_row(buffer: jax.Array, row: jax.Array, index: jax.Array) -> jax.Array:
     return jax.lax.dynamic_update_slice(buffer, row, (index, 0))
 
 
+@jax.jit
+def select_rows(mask: jax.Array, new: jax.Array, old: jax.Array) -> jax.Array:
+    """[B] ``new`` where ``mask``, else ``old``: how the engine puts the
+    tokens a prefill phase sampled (or an imported request's last one) among
+    the decode step's, on the device and at one shape however many they are."""
+    return jnp.where(mask, new, old)
+
+
 def _head(last, p, cfg):
     """Final norm and output head on [B, d] -> float32 logits [B, vocab]."""
     last = _rmsnorm(last, p["final_norm"]["scale"], cfg.norm_eps)

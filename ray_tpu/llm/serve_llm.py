@@ -4,7 +4,11 @@ Reference: llm/_internal/serve/core/server/llm_server.py (LLMServer
 deployment wrapping an engine), build_openai_app (OpenAI-compatible
 ingress). Each replica owns one ``JaxLLMEngine``; requests are enqueued to
 the engine and a single pump task drives ``engine.step()`` while anything is
-unfinished, so concurrent requests continuously batch on the TPU.
+unfinished, so concurrent requests continuously batch on the TPU. A call of
+``step()`` dispatches its programs and returns the outputs of the call before
+it: the pump's hop through the event loop, the answers it builds and the
+engine's next admission all run while the device works, and
+``has_unfinished()`` keeps the pump calling until the last step is read.
 
 Prefix-aware routing (reference: routing_policies/prefix_aware/): the
 ``LLMHandle`` hashes a prompt prefix to prefer a consistent replica, which
@@ -60,7 +64,11 @@ class LLMServer:
                             fut.set_result(result)
                 await asyncio.sleep(0)
         except Exception as e:
-            # fail every pending request rather than hanging its caller
+            # fail every pending request rather than hanging its caller. An
+            # abort reads the step in flight, which may be what raised: it
+            # is dropped unread first, so every slot and page comes back and
+            # this exception is the one raised
+            self.engine.discard_in_flight()
             for rid, fut in list(self._futures.items()):
                 if not fut.done():
                     fut.set_exception(RuntimeError(f"engine step failed: {e}"))
@@ -116,7 +124,8 @@ class LLMServer:
         (default: newest) from the named WeightStore and swap engine params
         between steps. In-flight requests keep decoding — the swap is one
         attribute assignment on the pump's thread boundary, so no request
-        is dropped or restarted. Rolled out across replicas with
+        is dropped or restarted (a step already dispatched keeps the tree it
+        was given). Rolled out across replicas with
         ``handle.broadcast("update_weights", store_name)``."""
         loop = asyncio.get_event_loop()
 

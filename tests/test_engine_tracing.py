@@ -21,6 +21,8 @@ KEYS = {
     "step_ms", "host_ms", "readback_ms", "admit_ms", "prefill_dispatch_ms",
     "decode_dispatch_ms", "sample_dispatch_ms", "emit_ms",
     "between_steps_ms", "queue_wait_ms", "ttft_ms",
+    # steps dispatched behind an unread one; tokens computed for nobody
+    "overlapped_steps", "dropped_tokens",
     # what routing did; a dense model's (this one's) stay 0
     "moe_decode_layer_steps", "moe_decode_assignments",
     "moe_decode_experts_touched", "moe_decode_max_load",
@@ -106,9 +108,14 @@ def test_metrics_complete_numeric_monotone(engine):
     # everything step() does lies inside one phase or another
     assert 0.8 * m["step_ms"] <= phases <= m["step_ms"]
     assert all(m[k] > 0 for k in PHASES)
-    # a call that finds nothing to run is no step
-    assert 0 < m["steps"] == busy < 30
+    # a call that runs no program is no step: the last one of a run only
+    # reads what the one before it dispatched, and an idle one does nothing
+    assert 0 < m["steps"] == busy - 1 < 29
     assert m["steps"] <= m["prefill_steps"] + m["decode_steps"]
+    # every step but the first was dispatched before the one before was read
+    assert m["overlapped_steps"] == m["steps"] - 1
+    # a token computed for a request that EOS had ended is not a generated one
+    assert m["generated_tokens"] <= sum(4 + i for i in range(SLOTS + 2))
     assert m["between_steps_ms"] > 0
     assert m["compiles"] == 2  # one prefill bucket, decode
 
@@ -124,12 +131,23 @@ def test_prefill_counters_equal_the_hand_count(engine):
                            SamplingParams(max_tokens=2 if i == 0 else 12))
     engine.add_request("ninth", _prompt(20), SamplingParams(max_tokens=3))
     ninth = engine._requests["ninth"]
-    engine.step()
+    assert engine.step() == []  # dispatched, nothing read yet
     assert (m["prefill_steps"], m["admitted"]) == (1, 8)
     assert m["prefill_tokens"] == sum(lens) == 60
     assert m["prefill_batch_tokens"] == 8 * BUCKET == 128
+    assert (m["decode_steps"], m["generated_tokens"]) == (1, 0)
     assert ninth.t_admitted == 0.0
     waited = m["queue_wait_ms"]
+    # r0 ends by length with the token of that call's decode step: the host
+    # counts that without the token, so the very next call admits the ninth
+    # into r0's slot, and only then reads r0's two tokens
+    outs = engine.step()
+    assert (ninth.slot, m["admitted"], m["prefill_steps"]) == (0, 9, 2)
+    assert [(o.request_id, len(o.token_ids), o.finished) for o in outs[:1]
+            + outs[8:9]] == [("r0", 1, False), ("r0", 2, True)]
+    # two sampler calls of eight rows read: a token is emitted, or dropped
+    # because its request had ended by EOS (_prompt(7)'s first token is EOS)
+    assert m["generated_tokens"] + m["dropped_tokens"] == 16
     while engine.has_unfinished():
         engine.step()
     assert (m["prefill_steps"], m["admitted"]) == (2, 9)
@@ -265,33 +283,49 @@ def test_spans_ride_the_profiler_annotation(recorder, monkeypatch, enter,
 
 
 def test_engine_spans_nest_in_step_order(engine, recorder):
+    """A call dispatches its programs first and reads afterwards, and what it
+    reads is the call BEFORE it: one ``readback`` and ``emit`` a sampler call,
+    in the order they were dispatched."""
     engine.add_request("a", _prompt(5), SamplingParams(max_tokens=3))
-    engine.step()
+    assert engine.step() == []
     names = [(d, n.removeprefix("ray_tpu/engine.")) for d, n, _ in recorder]
     assert all(n.startswith("ray_tpu/") for _, n, _ in recorder)
-    after_dispatch = [(1, "sample_dispatch"), (1, "readback"), (1, "emit")]
-    assert names == (
-        [(0, "step"), (1, "admit"), (1, "prefill_dispatch"), (2, "compile")]
-        + after_dispatch + [(1, "decode_dispatch"), (2, "compile")]
-        + after_dispatch)
+    assert names == [
+        (0, "step"), (1, "admit"), (1, "prefill_dispatch"), (2, "compile"),
+        (1, "sample_dispatch"), (1, "decode_dispatch"), (2, "compile"),
+        (1, "sample_dispatch")]
     attrs = {n: a for _, n, a in recorder}
     assert attrs["ray_tpu/engine.prefill_dispatch"] == {
         "bucket": BUCKET, "admitted": 1}
     assert attrs["ray_tpu/engine.compile"]["program"] == "decode"
-    # a shape this engine has used is not a compile again
+    assert attrs["ray_tpu/engine.decode_dispatch"] == {
+        "overlapped": 0, "dropped": 0}
+    # a shape this engine has used is not a compile again; the second call
+    # reads the two sampler calls of the first behind its own dispatch
     del recorder[:]
-    engine.step()
+    assert [len(o.token_ids) for o in engine.step()] == [1, 2]
     assert [n for _, n, _ in recorder] == [
         "ray_tpu/engine." + p for p in (
             "step", "admit", "decode_dispatch", "sample_dispatch", "readback",
-            "emit")]
+            "emit", "readback", "emit")]
+    assert recorder[2][2] == {"overlapped": 1, "dropped": 0}
+    # the third token was the second call's: the third call runs nothing
+    del recorder[:]
+    out, = engine.step()
+    assert (len(out.token_ids), out.finish_reason) == (3, "length")
+    assert [n for _, n, _ in recorder] == [
+        "ray_tpu/engine." + p for p in ("step", "admit", "readback", "emit")]
+    assert engine.metrics["steps"] == 2 and not engine.has_unfinished()
 
 
 def test_benchmark_wrappers_still_see_step_and_sample(engine, recorder,
                                                       tmp_path, monkeypatch):
     """The benchmark's ``_annotate_engine`` replaces ``eng.step`` and
     ``eng._sample`` from outside: both stay methods under those names, and
-    its two spans still enclose the engine's own."""
+    its two spans still enclose the engine's own. ``_sample`` dispatches and
+    does not wait, so ``bench/sample_readback`` holds the sampler's dispatch
+    alone, once a sampler call; the blocking read is ``engine.readback``,
+    later and outside it."""
     monkeypatch.syspath_prepend(REPO)
     from benchmarks.jobs import common
     from benchmarks.jobs.serve import BenchLLMServer
@@ -302,11 +336,15 @@ def test_benchmark_wrappers_still_see_step_and_sample(engine, recorder,
     assert len(out.token_ids) == 3 or out.finish_reason == "stop"
     names = [(d, n) for d, n, _ in recorder]
     assert names[:2] == [(0, "bench/engine.step"), (1, "ray_tpu/engine.step")]
-    i = names.index((2, "bench/sample_readback"))
-    assert names[i + 1:i + 3] == [(3, "ray_tpu/engine.sample_dispatch"),
-                                  (3, "ray_tpu/engine.readback")]
+    wraps = [i for i, n in enumerate(names)
+             if n == (2, "bench/sample_readback")]
     steps = engine.metrics["prefill_steps"] + engine.metrics["decode_steps"]
-    assert names.count((2, "bench/sample_readback")) == steps
+    assert len(wraps) == steps
+    for i in wraps:
+        assert names[i + 1] == (3, "ray_tpu/engine.sample_dispatch")
+        assert names[i + 2][0] <= 2
+    reads = [d for d, n in names if n == "ray_tpu/engine.readback"]
+    assert reads == [2] * steps
 
 
 def test_finished_request_leaves_three_spans_under_its_parent(engine,
@@ -340,3 +378,90 @@ def test_finished_request_leaves_three_spans_under_its_parent(engine,
     assert all(s["dur"] >= 0 for s in mine)
     bare = [s for s in spans if s["request_id"] == "bare"]
     assert len(bare) == 3 and all("parent_id" not in s for s in bare)
+
+
+# -- a step's tokens are read one step late ------------------------------------
+
+
+def _pages_conserved(engine):
+    """Every page but the scratch one is free or owned by one live request."""
+    owned = [p for r in engine._slots if r is not None for p in r.pages]
+    pages = sorted(list(engine._free_pages) + owned)
+    return pages == list(range(1, engine.ecfg.num_pages))
+
+
+def test_overlap_needs_a_step_to_hide_behind(engine):
+    """One request of one token: its only step has no predecessor, and the
+    call after it runs nothing; ``has_unfinished()`` holds the caller until
+    that call has read the token."""
+    m = engine.metrics
+    engine.add_request("one", _prompt(5), SamplingParams(max_tokens=1))
+    assert engine.step() == []
+    # the host counted the request out at dispatch: nothing waits, no slot is
+    # active, and the token is still to be read
+    assert not engine._waiting and engine.num_active() == 0
+    assert engine._slots == [None] * SLOTS and _pages_conserved(engine)
+    assert engine.has_unfinished()
+    out, = engine.step()
+    assert (len(out.token_ids), out.finished) == (1, True)
+    assert not engine.has_unfinished() and engine.step() == []
+    assert (m["steps"], m["overlapped_steps"], m["decode_steps"]) == (1, 0, 0)
+
+
+@pytest.mark.parametrize("at", [0, 2], ids=["first-token", "third-token"])
+def test_a_stop_token_is_seen_one_step_late(engine, at):
+    """The slot of a request that a stop token ends has run on when the host
+    reads the token: what it computed since is dropped, never emitted, and
+    the pages go back to the pool once."""
+    sp = SamplingParams(max_tokens=8)
+    free = engine.generate([_prompt(9)], sp)[0].token_ids
+    stop = free[at]
+    assert stop not in free[:at]
+    m = engine.metrics
+    before = dict(m)
+    engine.add_request("s", _prompt(9), SamplingParams(
+        max_tokens=8, stop_token_ids=(stop,)))
+    seen = []
+    while engine.has_unfinished():
+        seen += engine.step()
+        assert _pages_conserved(engine)
+    assert [o.token_ids for o in seen] == [free[:i + 1] for i in range(at + 1)]
+    assert [o.finished for o in seen] == [False] * at + [True]
+    assert seen[-1].finish_reason == "stop"
+    # the token is read behind the dispatch of the next step, and the first
+    # one behind its own phase's decode step too
+    late = 1 if at else 2
+    assert m["dropped_tokens"] - before["dropped_tokens"] == late
+    assert m["generated_tokens"] - before["generated_tokens"] == at + 1
+    # a dropped token's step IS a decode step
+    assert m["decode_steps"] - before["decode_steps"] == at + late
+    assert len(engine._free_pages) == engine.ecfg.num_pages - 1
+
+
+def test_a_full_engine_overlaps_every_step_but_the_first(engine):
+    """Twelve requests over eight slots, answers of different lengths: every
+    call that runs a program does so with the call before unread, and a slot
+    that ends by length is admitted into by the very next call."""
+    m = engine.metrics
+    for i in range(SLOTS + 4):
+        engine.add_request(f"r{i}", _prompt(8 + i),  # none of these meets EOS
+                           SamplingParams(max_tokens=2 + i % 5))
+    done, held = {}, [None]  # held[c]: who has each slot after call c
+    while engine.has_unfinished():
+        for o in engine.step():
+            if o.finished:
+                done[o.request_id] = o
+        held.append([r and r.request_id for r in engine._slots])
+        assert _pages_conserved(engine)
+    assert len(done) == SLOTS + 4
+    assert all(len(done[f"r{i}"].token_ids) == 2 + i % 5
+               for i in range(SLOTS + 4))
+    calls = len(held) - 1
+    assert m["steps"] == calls - 1 and m["overlapped_steps"] == m["steps"] - 1
+    # r0 and r5 end with the second token, which call 1's decode step is
+    # asked for: their slots are free when it returns and r8 and r9 have them
+    # after call 2; r1 and r6 end with call 2's token, r10 and r11 follow
+    # (r10, two tokens again, comes and goes within call 3)
+    assert held[1] == [None, "r1", "r2", "r3", "r4", None, "r6", "r7"]
+    assert held[2] == ["r8", None, "r2", "r3", "r4", "r9", None, "r7"]
+    assert held[3][:2] + held[3][5:7] == ["r8", None, "r9", "r11"]

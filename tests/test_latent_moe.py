@@ -426,6 +426,33 @@ def test_mla_counters_after_a_known_number_of_steps():
     assert m["moe_decode_layer_steps"] == 5 * (LAYERS - 1)
 
 
+def test_routing_is_counted_once_a_step_with_the_next_step_dispatched():
+    """A decode step's ``moe_load`` comes back inside the cache the NEXT
+    dispatch donates, before the host has read it: the engine takes a copy
+    out of that chain, and every step's routing is counted, one step late."""
+    eng = _engine()
+    m = eng.metrics
+    prompts = [[5, 6, 7, 8], [9, 10, 11, 12, 13, 14, 15]]
+    for i, (prompt, most) in enumerate(zip(prompts, (3, 6))):
+        eng.add_request(f"r{i}", prompt, SamplingParams(
+            max_tokens=most, stop_token_ids=()))
+    rows = 0  # slots x steps whose routing the host has read
+    while eng.has_unfinished():
+        eng.step()
+        unread = [u for u in eng._unread if u.moe_load is not None]
+        assert len(unread) == (1 if eng._unread else 0)
+        assert m["moe_decode_layer_steps"] == (
+            (m["decode_steps"] - len(unread)) * (LAYERS - 1))
+        # real rows: every active slot reaches K experts a layer
+        rows = m["moe_decode_assignments"] // (K * (LAYERS - 1))
+        assert m["moe_decode_assignments"] == rows * K * (LAYERS - 1)
+    # five decode steps: both requests in the first two, r1 alone after
+    assert (m["decode_steps"], rows) == (5, 2 + 2 + 1 + 1 + 1)
+    assert m["moe_decode_layer_steps"] == 5 * (LAYERS - 1)
+    assert m["overlapped_steps"] == m["steps"] - 1 == 4
+    assert 1 <= m["moe_decode_max_load"] / m["moe_decode_layer_steps"] <= 2
+
+
 def test_a_deployment_states_its_latent_rank():
     with pytest.raises(ValueError, match="latent cache of rank 0"):
         JaxLLMEngine(LLMConfig(

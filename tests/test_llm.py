@@ -174,6 +174,124 @@ def test_burst_of_four_length_buckets_matches_one_at_a_time(engine, sp):
     assert engine.metrics["prefill_steps"] - phases == 1
 
 
+def _plain_loop(config, params, requests, eos):
+    """The order the engine's tokens are held to, written out with nothing in
+    flight: admit into free slots in arrival order, prefill each alone at its
+    bucket, ONE sampler call, read, emit; one decode step over the active
+    slots, a sampler call, read, emit. ``requests``: (id, prompt, params)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import model_runner as mr
+
+    e, cfg = config.engine_config, config.transformer_config()
+    B, MP = e.max_num_seqs, e.pages_per_seq
+    cache = mr.init_cache(cfg, 1 + B * MP, e.page_size)
+    tables = 1 + np.arange(B * MP, dtype=np.int32).reshape(B, MP)  # fixed
+    rng = jax.random.PRNGKey(0)
+    slots, waiting = [None] * B, list(requests)
+    out = {rid: [] for rid, _, _ in requests}
+    lens, last = np.zeros(B, np.int32), np.zeros(B, np.int32)
+    prefill_logits = np.zeros((B, cfg.vocab_size), np.float32)
+
+    def sample(logits):
+        nonlocal rng
+        rng, sub = jax.random.split(rng)
+        sps = [s[2] if s else SamplingParams() for s in slots]
+        seeds = [-1 if sp.seed is None else sp.seed for sp in sps]
+        steps = [len(out[s[0]]) if s else 0 for s in slots]
+        return np.asarray(mr.sample_tokens(
+            jnp.asarray(logits), sub,
+            jnp.asarray([sp.temperature for sp in sps], jnp.float32),
+            jnp.asarray([sp.top_k for sp in sps], jnp.int32),
+            jnp.asarray([sp.top_p for sp in sps], jnp.float32),
+            jnp.asarray(seeds, jnp.int32), jnp.asarray(steps, jnp.int32),
+            max_top_k=e.max_top_k))
+
+    def emit(i, token):
+        rid, prompt, sp = slots[i]
+        out[rid].append(token)
+        last[i] = token
+        if (token == eos or token in sp.stop_token_ids
+                or len(out[rid]) >= sp.max_tokens
+                or len(prompt) + len(out[rid]) >= e.max_model_len):
+            slots[i] = None
+
+    while waiting or any(slots):
+        admitted = [i for i in range(B) if slots[i] is None][:len(waiting)]
+        for i in admitted:
+            slots[i] = waiting.pop(0)
+            prompt = slots[i][1]
+            S = e.prefill_bucket_min
+            while S < len(prompt):
+                S *= 2
+            toks = np.zeros((1, min(S, e.max_model_len)), np.int32)
+            toks[0, :len(prompt)] = prompt
+            lens[i] = len(prompt)
+            logits, cache = mr.prefill(
+                params, cfg, cache, jnp.asarray(toks),
+                jnp.asarray(lens[i:i + 1]), jnp.asarray(tables[i:i + 1]))
+            prefill_logits[i] = np.asarray(logits[0])
+        if admitted:
+            toks = sample(prefill_logits)
+            for i in admitted:
+                emit(i, int(toks[i]))
+        active = np.array([s is not None for s in slots])
+        if active.any():
+            logits, cache = mr.decode_step(
+                params, cfg, cache, jnp.asarray(last), jnp.asarray(lens),
+                jnp.asarray(tables), jnp.asarray(active))
+            toks = sample(logits)
+            for i in np.flatnonzero(active):
+                lens[i] += 1
+                emit(i, int(toks[i]))
+    return out
+
+
+@pytest.mark.parametrize("sampling", [
+    {}, {"temperature": 0.9, "top_k": 16}], ids=["greedy", "seeded"])
+def test_tokens_equal_the_synchronous_loops(engine, sampling):
+    """Seven requests over four slots, prompts of four length buckets,
+    answers of 3 to 12 tokens, one ended by a stop token: the engine, which
+    reads every token a step late, gives each request token for token what the
+    plain synchronous loop gives it, and nothing after the stop token."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    def requests(stop=()):
+        return [(f"q{i}", list(range(3 + i, 3 + i + n)), SamplingParams(
+            max_tokens=most, stop_token_ids=stop if i == 2 else (),
+            seed=100 + i if sampling else None, **sampling))
+            for i, (n, most) in enumerate(zip((5, 20, 40, 9, 70, 12, 33),
+                                              (6, 12, 9, 3, 5, 10, 4)))]
+
+    cfg = make_config()
+    eos = engine.tokenizer.eos_token_id
+    free = _plain_loop(cfg, engine.params, requests(), eos)["q2"]
+    stop = free[2]
+    assert stop not in free[:2] and len(free) > 3
+    want = _plain_loop(cfg, engine.params, requests((stop,)), eos)
+    assert want["q2"] == free[:3]
+
+    eng = JaxLLMEngine(cfg, params=engine.params, seed=0)
+    for rid, prompt, sp in requests((stop,)):
+        eng.add_request(rid, prompt, sp)
+    got, emitted = {}, {rid: 0 for rid in want}
+    while eng.has_unfinished():
+        for o in eng.step():
+            assert o.request_id not in got  # nothing after its last token
+            emitted[o.request_id] += 1
+            if o.finished:
+                got[o.request_id] = o
+    assert {rid: o.token_ids for rid, o in got.items()} == want
+    assert emitted == {rid: len(toks) for rid, toks in want.items()}
+    assert got["q2"].finish_reason == "stop"
+    m = eng.metrics
+    assert m["generated_tokens"] == sum(map(len, want.values()))
+    assert m["dropped_tokens"] >= 1 and m["overlapped_steps"] == m["steps"] - 1
+    assert m["admitted"] == 7 and not any(eng._slots)
+    assert sorted(eng._free_pages) == list(range(1, eng.ecfg.num_pages))
+
+
 def test_generation_crosses_page_boundaries(engine):
     """Prompt of 14 + 40 new tokens crosses several 16-token pages."""
     prompt = list(range(3, 17))
@@ -231,11 +349,123 @@ def test_preemption_keeps_generated_tokens(engine):
     outs = eng.generate(prompts, SamplingParams(max_tokens=30))
     assert all(o.finished for o in outs)
     assert all(len(o.token_ids) <= 30 for o in outs)
+    # the pool ran dry with a step in flight: the engine read it before it
+    # sent the request back, so the second prefill held every token
+    m = eng.metrics
+    assert m["preempted"] >= 1 and m["overlapped_steps"] >= m["steps"] - 2
+    assert m["prefill_tokens"] > 60 and m["dropped_tokens"] == 0
+    assert sorted(eng._free_pages) == list(range(1, 7))
     # greedy: outputs must match a roomy engine's outputs despite preemption
     roomy = JaxLLMEngine(make_config(max_num_seqs=2, max_model_len=64),
                          params=engine.params, seed=0)
     expect = roomy.generate(prompts, SamplingParams(max_tokens=30))
     assert [o.token_ids for o in outs] == [o.token_ids for o in expect]
+
+
+def _steps_until_unread(eng, calls):
+    """``calls`` steps, ending with a dispatched step whose tokens the host
+    has not read: what each entry point below has to cope with."""
+    outs = []
+    for _ in range(calls):
+        outs += eng.step()
+    assert eng._unread and eng.metrics["overlapped_steps"] == calls - 1
+    return outs
+
+
+def _pages_conserved(eng):
+    owned = [p for r in eng._slots if r is not None for p in r.pages]
+    return sorted(list(eng._free_pages) + owned) == list(
+        range(1, eng.ecfg.num_pages))
+
+
+def test_abort_with_a_step_in_flight(engine):
+    """Aborting reads what is in flight first: the other request loses
+    nothing, the aborted one is never finished, its slot and pages are free."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    sp = SamplingParams(max_tokens=10)
+    keep = list(range(3, 15))
+    alone = engine.generate([keep], sp)[0].token_ids
+    eng = JaxLLMEngine(make_config(), params=engine.params, seed=0)
+    eng.add_request("gone", list(range(20, 50)), sp)
+    eng.add_request("kept", keep, sp)
+    outs = _steps_until_unread(eng, 3)
+    eng.abort_request("gone")
+    assert not eng._unread and "gone" not in eng._requests
+    assert eng._slots[0] is None and _pages_conserved(eng)
+    eng.abort_request("gone")  # unknown by now: nothing happens
+    while eng.has_unfinished():
+        outs += eng.step()
+    gone = [o for o in outs if o.request_id == "gone"]
+    # the three steps dispatched four tokens for it; all were read, none lost
+    assert [len(o.token_ids) for o in gone] == [1, 2, 3, 4]
+    assert not any(o.finished for o in gone)
+    kept = [o for o in outs if o.request_id == "kept"]
+    assert [len(o.token_ids) for o in kept] == list(range(1, 11))
+    assert kept[-1].finished and kept[-1].token_ids == alone
+    assert sorted(eng._free_pages) == list(range(1, eng.ecfg.num_pages))
+
+
+def test_export_and_import_with_steps_in_flight(engine):
+    """``export_kv`` from an engine and ``add_request_with_kv`` into another,
+    each with a step unread: the exported state holds every token dispatched,
+    the importing engine's own request loses none, and both go on to the
+    tokens they get alone."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    sp = SamplingParams(max_tokens=12)
+    moved, stays = list(range(3, 21)), list(range(30, 39))
+    alone = [o.token_ids for o in engine.generate([moved, stays], sp)]
+    a = JaxLLMEngine(make_config(), params=engine.params, seed=0)
+    b = JaxLLMEngine(make_config(), params=engine.params, seed=1)
+    a.add_request("moved", moved, sp)
+    b.add_request("stays", stays, sp)
+    _steps_until_unread(a, 3)
+    outs = _steps_until_unread(b, 2)
+    state = a.export_kv("moved")
+    assert state["generated"] == alone[0][:4] and state["seq_len"] == 18 + 3
+    assert not a._unread and _pages_conserved(a) and not any(a._slots)
+    # what the drain read is handed over by the next step(), then nothing
+    assert [len(o.token_ids) for o in a.step()] == [4]
+    assert not a.has_unfinished()
+    b.add_request_with_kv(state)
+    assert not b._unread and _pages_conserved(b)
+    while b.has_unfinished():
+        outs += b.step()
+    done = {o.request_id: o.token_ids for o in outs if o.finished}
+    assert done == {"moved": alone[0], "stays": alone[1]}
+    assert [len(o.token_ids) for o in outs if o.request_id == "stays"] \
+        == list(range(1, 13))
+    assert sorted(b._free_pages) == list(range(1, b.ecfg.num_pages))
+
+
+def test_prefill_only_with_a_step_in_flight(engine):
+    """The prefill side's entry point on an engine that is decoding: it reads
+    what is in flight, the LAST token of another request among it, and that
+    request's answer is handed over by the next ``step()``, not lost."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    short = list(range(3, 12))
+    alone = engine.generate([short], SamplingParams(max_tokens=3))[0]
+    eng = JaxLLMEngine(make_config(), params=engine.params, seed=0)
+    eng.add_request("short", short, SamplingParams(max_tokens=3))
+    outs = _steps_until_unread(eng, 2)  # its third token is the unread one
+    assert [len(o.token_ids) for o in outs] == [1, 2] and not any(eng._slots)
+    long, sp = list(range(40, 60)), SamplingParams(max_tokens=8)
+    state = eng.prefill_only("p", long, sp)
+    assert len(state["generated"]) == 1 and state["seq_len"] == 20
+    assert not eng._unread and _pages_conserved(eng)
+    assert eng.has_unfinished()  # an answer nobody has been given yet
+    last, = eng.step()
+    assert (last.request_id, last.finished) == ("short", True)
+    assert last.token_ids == alone.token_ids
+    assert not eng.has_unfinished()
+    # and the exported request goes on elsewhere as if it had never moved
+    eng.add_request_with_kv(state)
+    done = []
+    while eng.has_unfinished():
+        done += [o.token_ids for o in eng.step() if o.finished]
+    assert done == [engine.generate([long], sp)[0].token_ids]
 
 
 def test_max_model_len_truncates(engine):
@@ -313,6 +543,69 @@ def test_llm_server_answers_when_model_vocab_exceeds_tokenizer():
 
     with pytest.raises(RuntimeError, match="engine step failed"):
         asyncio.run(ask_broken())
+
+
+def test_llm_server_answers_the_last_request_of_a_burst():
+    """The pump goes on calling ``step()`` while a dispatched step is unread:
+    the last answers of a burst, which only such a call reads, arrive."""
+    import asyncio
+
+    from ray_tpu.llm.serve_llm import LLMServer
+
+    server = LLMServer(make_config())
+
+    async def burst():
+        return await asyncio.wait_for(asyncio.gather(*[
+            server({"prompt": f"burst {i}", "max_tokens": 1 + i % 3})
+            for i in range(7)]), 120)
+
+    outs = asyncio.run(burst())
+    assert [o["usage"]["completion_tokens"] for o in outs] == [
+        len(o["choices"][0]["token_ids"]) for o in outs]
+    assert all(o["choices"][0]["finish_reason"] in ("length", "stop")
+               for o in outs)
+    eng = server.engine
+    assert not eng.has_unfinished() and not server._futures
+    assert eng.metrics["admitted"] == 7 and server._pump_task is None
+
+
+def test_llm_server_releases_every_request_when_a_step_in_flight_fails():
+    """A step whose device computation failed raises where its tokens are
+    read, and what is dispatched behind it is unread still: the pump drops
+    that unread, so every request gets THIS error and gives back its slot and
+    pages (an abort that read it would raise again, inside the handler)."""
+    import asyncio
+
+    from ray_tpu.llm.serve_llm import LLMServer
+
+    class Failed:
+        def __array__(self, *a, **kw):
+            raise RuntimeError("device computation failed")
+
+    server = LLMServer(make_config())
+    eng = server.engine
+    sent, calls = eng._sent, []
+
+    def poisoned(tokens, reqs, moe_load=None):
+        calls.append(len(reqs))
+        sent(Failed() if len(calls) >= 3 else tokens, reqs, moe_load)
+
+    eng._sent = poisoned
+
+    async def burst():
+        return await asyncio.wait_for(asyncio.gather(*[
+            server({"prompt": f"burst {i}", "max_tokens": 10})
+            for i in range(3)], return_exceptions=True), 120)
+
+    outs = asyncio.run(burst())
+    assert len(calls) == 4  # one call was dispatched behind the failed one
+    for o in outs:
+        assert isinstance(o, RuntimeError) and str(o) == (
+            "engine step failed: device computation failed")
+    assert not eng._unread and not eng._requests and not server._futures
+    assert eng._slots == [None] * eng.ecfg.max_num_seqs
+    assert sorted(eng._free_pages) == list(range(1, eng.ecfg.num_pages))
+    assert not eng.has_unfinished() and server._pump_task is None
 
 
 @pytest.mark.isolated
