@@ -55,6 +55,10 @@ class EngineConfig:
     # width of the latent the cache holds a position (0: per-head K/V pages),
     # checked against the model as expect_experts is, and for its reason
     expect_latent_rank: int = 0
+    # layers with recurrent state (a decoder-hybrid-decoder's Mamba layers; 0:
+    # none, every layer keeps pages), checked the same way: such a model also
+    # keeps window rings and recurrent rows by slot beside its pages
+    expect_state_layers: int = 0
 
     def __post_init__(self):
         if self.max_model_len % self.page_size:

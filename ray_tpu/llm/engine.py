@@ -82,7 +82,23 @@ from ``__init__``, so two snapshots subtract):
 - for a model with a latent cache (0 otherwise), per decode step and a layer:
   ``mla_decode_live_tokens`` (positions the active slots attend over) and
   ``mla_decode_read_tokens`` (positions of the pages ``mla_decode`` is given:
-  the live ones rounded up to whole pages).
+  the live ones rounded up to whole pages);
+- for a model that keeps three kinds of state (``layer_kinds``: pages for its
+  one shared layer of keys and values, window rings and recurrent rows by
+  slot; 0 otherwise): ``shared_kv_live_tokens`` and ``shared_kv_read_tokens``
+  (the same two for the shared layer's pages, counted once a decode step, not
+  once a layer that reads them), ``window_live_tokens`` (filled ring entries
+  the active slots attend over, a step and window layer),
+  ``ssm_decode_layer_steps`` (decode steps x layers with recurrent state) and
+  ``prefill_cross_rows`` (rows the cross-decoder computed in prefill: one a
+  call, against ``prefill_batch_tokens`` for the self-decoder).
+
+Such a model's rings and rows need no allocator: a slot owns its own, a
+prefill call overwrites all of them from the prompt (the engine tells it the
+slot), so admission resets them and a preempted request, prefilled again from
+its tokens, gets all three kinds back. Pages are handed out as for any model,
+for one layer. ``export_kv`` / ``add_request_with_kv`` refuse such a model
+by name: a request's state is not a gather of its pages.
 
 The same boundaries are spans on the profiler's clock
 (``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
@@ -92,8 +108,8 @@ inside it, in this order, ``engine.admit``, ``.prefill_dispatch`` (arguments
 ``.decode_dispatch`` (arguments ``overlapped``: 1 if an earlier step is
 unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
 per layer in the newest decode step the host has read, models with experts
-only; ``live_tokens``: positions the step attends over, models with a latent
-cache only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
+only; ``live_tokens``: positions the step attends over through the block
+tables, models with a latent cache or with ``layer_kinds`` only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
 every sampler call of the step before; around a shape's first use,
 ``.compile``. With
 ``RAY_TPU_ENABLE_TRACING`` a finished request also leaves ``engine.queued``,
@@ -184,6 +200,12 @@ class JaxLLMEngine:
                 f"the deployment expects a latent cache of rank "
                 f"{self.ecfg.expect_latent_rank}, the model has "
                 f"{self.mcfg.kv_latent_rank}")
+        kinds = self.mcfg.layer_kinds
+        if kinds.count("mamba") != self.ecfg.expect_state_layers:
+            raise ValueError(
+                f"the deployment expects {self.ecfg.expect_state_layers} "
+                f"layers with recurrent state, the model has "
+                f"{kinds.count('mamba')}")
         self.tokenizer = get_tokenizer(config.tokenizer)
         self._mr = model_runner
         self._jax = jax
@@ -196,7 +218,8 @@ class JaxLLMEngine:
             self.params = self._init_random_params(seed)
 
         e = self.ecfg
-        self.cache = model_runner.init_cache(self.mcfg, e.num_pages, e.page_size)
+        self.cache = model_runner.init_cache(self.mcfg, e.num_pages,
+                                             e.page_size, e.max_num_seqs)
         B, MP = e.max_num_seqs, e.pages_per_seq
         self._block_tables = np.zeros((B, MP), np.int32)
         self._seq_lens = np.zeros(B, np.int32)
@@ -242,7 +265,10 @@ class JaxLLMEngine:
             "overlapped_steps": 0, "dropped_tokens": 0,
             "moe_decode_layer_steps": 0, "moe_decode_assignments": 0,
             "moe_decode_experts_touched": 0, "moe_decode_max_load": 0,
-            "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0}
+            "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0,
+            "shared_kv_live_tokens": 0, "shared_kv_read_tokens": 0,
+            "window_live_tokens": 0, "ssm_decode_layer_steps": 0,
+            "prefill_cross_rows": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
 
@@ -503,11 +529,15 @@ class JaxLLMEngine:
                     row = slice(r.slot, r.slot + 1)
                     toks = np.zeros((1, S), np.int32)
                     toks[0, :self._seq_lens[r.slot]] = r.cache_tokens
+                    # a model that keeps state by slot is told which one
+                    where = (self._up(np.arange(r.slot, r.slot + 1,
+                                                dtype=np.int32)),) \
+                        if self.mcfg.layer_kinds else ()
                     with self._first_use("prefill", S):
                         logits, self.cache = mr.prefill(
                             self.params, self.mcfg, self.cache,
                             jnp.asarray(toks), self._up(self._seq_lens[row]),
-                            self._up(self._block_tables[row]))
+                            self._up(self._block_tables[row]), *where)
                         self._prefill_logits = mr.place_row(
                             self._prefill_logits, logits, np.int32(r.slot))
             firsts = self._sample(self._prefill_logits)
@@ -518,6 +548,8 @@ class JaxLLMEngine:
             m["admitted"] += len(admitted)
             m["prefill_tokens"] += int(self._seq_lens[slots].sum())
             m["prefill_batch_tokens"] += sum(buckets)
+            if self.mcfg.layer_kinds:  # the cross-decoder ran one row a call
+                m["prefill_cross_rows"] += len(admitted)
             self._active[slots] = True
             self._sent(firsts, admitted)
 
@@ -526,7 +558,8 @@ class JaxLLMEngine:
         if decode and self._active.any():
             attrs = dict(self._experts_attr, overlapped=int(self._earlier > 0),
                          dropped=m["dropped_tokens"])
-            if self.mcfg.kv_latent_rank:  # positions the step attends over
+            if self.mcfg.kv_latent_rank or self.mcfg.layer_kinds:
+                # positions the step attends over, through the block tables
                 attrs["live_tokens"] = int(
                     (self._seq_lens[self._active] + 1).sum())
             load = None
@@ -536,7 +569,14 @@ class JaxLLMEngine:
                 if decoding:
                     overlapped = overlapped or self._earlier > 0
                     if self.mcfg.kv_latent_rank:
-                        self._count_latent_reads()
+                        self._count_paged_reads("mla_decode")
+                    elif self.mcfg.layer_kinds:
+                        self._count_paged_reads("shared_kv")
+                        m["window_live_tokens"] += int(np.minimum(
+                            self._seq_lens[self._active] + 1,
+                            self.mcfg.window).sum())
+                        m["ssm_decode_layer_steps"] += \
+                            self.mcfg.layer_kinds.count("mamba")
                     with self._first_use("decode"):
                         logits, self.cache = mr.decode_step(
                             self.params, self.mcfg, self.cache, self._tokens,
@@ -615,18 +655,19 @@ class JaxLLMEngine:
         self._unread.clear()
         self._earlier = 0
 
-    def _count_latent_reads(self) -> None:
+    def _count_paged_reads(self, prefix: str) -> None:
         """What the decode step about to run attends over (``live``: positions
         0..seq_len of every active slot) and what it reads for that (``read``:
         the positions of the pages ``ops/mla.py:live_pages`` lists, an
-        inactive slot's one step over the scratch page included), a layer."""
+        inactive slot's one step over the scratch page included), for ONE
+        layer that reads them, into ``<prefix>_live_tokens`` / ``_read_tokens``."""
         P = self.ecfg.page_size
         lens = self._seq_lens[self._active].astype(np.int64)
         live = int((lens + 1).sum())
         read = int(((lens // P + 1) * P).sum()) \
             + P * int((~self._active).sum())
-        self.metrics["mla_decode_live_tokens"] += live
-        self.metrics["mla_decode_read_tokens"] += read
+        self.metrics[prefix + "_live_tokens"] += live
+        self.metrics[prefix + "_read_tokens"] += read
 
     def _count_routing(self, load: np.ndarray) -> None:
         """``load`` [expert layers, E]: real rows per expert in one decode
@@ -718,6 +759,7 @@ class JaxLLMEngine:
         """Gather a live request's KV pages + scheduling state, releasing
         the request locally. The blob is plain numpy: it ships over the
         object plane (or the device-object plane when replicas colocate)."""
+        self._refuse_state_by_slot("export_kv")
         self._drain()
         req = self._requests.get(request_id)
         if req is None or req.slot < 0:
@@ -742,6 +784,17 @@ class JaxLLMEngine:
     def _page_leaves(self) -> List[str]:
         return [f for f in self.cache._fields if f != "moe_load"]
 
+    def _refuse_state_by_slot(self, what: str) -> None:
+        """A request's state is a gather of its pages only where pages are
+        all of it: a model with window rings and recurrent rows is prefilled
+        where it decodes (its pages could move, its rings and rows have no
+        hand-over yet)."""
+        if self.mcfg.layer_kinds:
+            raise ValueError(
+                f"{what}: model {self.config.model_id!r} keeps window rings "
+                f"and recurrent state by slot beside its pages; prefill / "
+                f"decode disaggregation does not carry them")
+
     def add_request_with_kv(self, state: dict) -> None:
         """Admit a prefilled request directly into a decode slot: allocate
         fresh pages, scatter the imported KV into them, and resume decoding
@@ -751,6 +804,7 @@ class JaxLLMEngine:
         if state.get("finished"):
             # finished during prefill (e.g. max_tokens=1): nothing to decode
             raise ValueError("request already finished at prefill")
+        self._refuse_state_by_slot("add_request_with_kv")
         self._drain()
         free_slots = [i for i, s in enumerate(self._slots) if s is None]
         leaves = self._page_leaves()

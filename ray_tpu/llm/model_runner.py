@@ -40,6 +40,27 @@ q . k and 128-wide values); decode attends over the rows themselves with the
 up-projection absorbed, through ``ops/mla.py:mla_decode``, which reads only
 the pages that hold live positions. Both programs keep their signatures: the
 cache is whichever tuple ``init_cache`` made, pages first and ``moe_load`` last.
+
+A model with ``layer_kinds`` (a decoder-hybrid-decoder: Mamba layers, window
+and full differential attention, gated memory units, cross layers) keeps
+``HybridCache``: THREE kinds of state side by side. Pages, for the one layer
+of keys and values that the full layer writes and every cross layer reads,
+handed out by the engine's block tables as any page is; a ring of ``window``
+positions a slot for each window layer, written at ``position mod window`` and
+masked by how many entries are filled; a recurrent row a slot for each Mamba
+layer (the scan's state in float32 and the convolution's last inputs).
+``prefill`` is told the slot a row fills (``slots``): it runs the
+self-decoder over the prompt (the scan through ``ops/ssm.py``, padding passed
+over with ``dt = 0``; the window through the flash kernel, blocks left of it
+skipped), overwrites all of the slot's rings and rows from the prompt alone
+(which is how a slot is reset at admission and how a preempted request
+comes back) and leaves them at position ``lengths - 1``; then it runs the
+cross-decoder on that ONE position, since those layers write no state and the
+engine reads one row of logits. ``decode_step`` carries the rows forward one
+step, writes each ring at ``position mod window`` and the page row, and
+attends through ``ops/paged_attention.py``: over the rings in the window
+layers, and in the full and the cross layers over the live pages of one work
+list (``ops/mla.py:live_pages``, built once a step).
 """
 
 from __future__ import annotations
@@ -71,6 +92,27 @@ class LatentCache(NamedTuple):
     moe_load: Optional[jax.Array] = None
 
 
+class HybridCache(NamedTuple):
+    """The state of a model with ``layer_kinds`` (a decoder-hybrid-decoder),
+    three kinds side by side. ``pages``: the ONE layer of keys and values
+    that the "full" layer writes and every "cross" layer reads, a row ``k | v``
+    of all heads a position, addressed through the block tables as any page
+    is. ``rings``: per "window" layer and slot the ``window`` newest positions'
+    rows, position ``t`` at entry ``t mod window``. ``ssm``, ``conv``: per
+    "mamba" layer and slot the scan's state (float32, ``inner`` along the
+    lanes as ``ops/ssm.py`` keeps it: [.., N, inner] is whole tiles where [..,
+    inner, N] would pad 16 lanes to 128) and the convolution's last ``ssm_conv
+    - 1`` inputs. Rings and rows belong to a SLOT: prefill
+    overwrites all of a slot's from the prompt alone, which is also how a
+    slot is reset at admission; a slot that is not active computes into its
+    own rows and nobody reads them."""
+    pages: jax.Array  # [NP, P, 2 KVH hd]
+    rings: jax.Array  # [window layers, B, window, 2 KVH hd]
+    ssm: jax.Array    # [mamba layers, B, N, inner] float32
+    conv: jax.Array   # [mamba layers, ssm_conv - 1, B, inner]
+    moe_load: Optional[jax.Array] = None
+
+
 def _latent_width(cfg: TransformerConfig) -> int:
     return -(-(cfg.kv_latent_rank + cfg.qk_rope_head_dim) // 128) * 128
 
@@ -82,7 +124,24 @@ def _latent_row(parts, width):
                    + [(0, width - row.shape[-1])])
 
 
-def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int) -> KVCache:
+def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
+               max_num_seqs: int = 0) -> KVCache:
+    """``max_num_seqs``: the engine's slots, which only a model that keeps
+    state by slot (``HybridCache``) needs."""
+    if cfg.layer_kinds:
+        if not max_num_seqs:
+            raise ValueError("a model with layer_kinds keeps window rings and "
+                             "recurrent rows by slot: init_cache needs "
+                             "max_num_seqs")
+        kinds, row = cfg.layer_kinds, 2 * cfg.n_kv_heads * cfg.head_dim
+        return HybridCache(
+            jnp.zeros((num_pages, page_size, row), cfg.dtype),
+            jnp.zeros((kinds.count("window"), max_num_seqs, cfg.window, row),
+                      cfg.dtype),
+            jnp.zeros((kinds.count("mamba"), max_num_seqs, cfg.ssm_state,
+                       cfg.ssm_inner), jnp.float32),
+            jnp.zeros((kinds.count("mamba"), cfg.ssm_conv - 1, max_num_seqs,
+                       cfg.ssm_inner), cfg.dtype))
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     load = None
     layers = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
@@ -201,6 +260,259 @@ def _latent_attention_absorbed(q_nope, q_pe, rows, work, layer, p, cfg):
 
 
 # ---------------------------------------------------------------------------
+# a model with layer_kinds (models/transformer.py:HybridBlock): its layers'
+# arithmetic over the three kinds of state. The residual stream is float32
+# here (each layer's products take and give cfg.dtype): 64 additions in
+# bfloat16 lose a percent and a half of a 32-layer stream by themselves
+# ---------------------------------------------------------------------------
+
+
+def _dense(x, p, dtype):
+    y = jnp.einsum("...d,df->...f", x.astype(dtype), p["kernel"].astype(dtype))
+    return y + p["bias"].astype(dtype) if "bias" in p else y
+
+
+def _layer_norm(x, n, cfg):
+    """LayerNorm of the float32 stream, handed on in the products' type."""
+    from ray_tpu.models.transformer import layer_norm
+
+    return layer_norm(x, n["scale"], n["bias"], cfg.norm_eps).astype(cfg.dtype)
+
+
+def _memory_unit(h, memory, m, cfg):
+    """A gated memory unit: the handing Mamba layer's scan output at the same
+    position, gated by this layer's own projection of ``h``."""
+    gate = jax.nn.silu(_dense(h, m["in_proj"], cfg.dtype))
+    return _dense(memory.astype(cfg.dtype) * gate, m["out_proj"], cfg.dtype)
+
+
+def _mamba_inputs(a, m, cfg):
+    """The convolved, activated input a [.., I] -> (a, dt, B, C), the scan's
+    operands in float32."""
+    N, R = cfg.ssm_state, cfg.ssm_dt_rank
+    a = jax.nn.silu(a)
+    x = _dense(a, m["x_proj"], jnp.float32)
+    dt = jax.nn.softplus(_dense(x[..., :R], m["dt_proj"], jnp.float32))
+    return a, dt, x[..., R:R + N], x[..., R + N:]
+
+
+def _mamba_output(y, a, z, m, cfg):
+    """The scan's y [.., I] float32 -> (the mixer's output, the memory a
+    gated memory unit reads: y with the D term, before the gate)."""
+    y = y + m["D"] * a.astype(jnp.float32)
+    return _dense(y.astype(cfg.dtype) * jax.nn.silu(z), m["out_proj"],
+                  cfg.dtype), y
+
+
+def _diff_qkv(h, m, cfg):
+    """h [.., D] -> q [.., H, hd] and, for a layer with keys of its own, the
+    cache row ``k | v`` [.., 2 KVH hd] (None for a cross layer)."""
+    H, hd = cfg.n_heads, cfg.head_dim
+    if "Wq" in m:
+        return _dense(h, m["Wq"], cfg.dtype).reshape(*h.shape[:-1], H, hd), None
+    qkv = _dense(h, m["Wqkv"], cfg.dtype)
+    return qkv[..., :H * hd].reshape(*h.shape[:-1], H, hd), qkv[..., H * hd:]
+
+
+def _diff_out(o, m, layer, cfg):
+    """o [.., H, 2 hd], every head's softmax times its value pair -> the
+    mixer's output [.., D]."""
+    from ray_tpu.models.transformer import diff_combine, diff_lambda
+
+    o = diff_combine(o, diff_lambda(m, layer), layer, m["subln"], cfg.norm_eps)
+    return _dense(o.astype(cfg.dtype), m["out_proj"], cfg.dtype)
+
+
+def _row_heads(row, cfg):
+    """A cache row [.., 2 KVH hd] -> keys [.., H, hd] and value pairs [.., H,
+    2 hd] as each query head reads them (prefill's dense attention)."""
+    from ray_tpu.models.transformer import diff_heads
+
+    KVH, hd = cfg.n_kv_heads, cfg.head_dim
+    key_of, value_of = diff_heads(cfg)
+    k = row[..., :KVH * hd].reshape(*row.shape[:-1], KVH, hd)
+    v = row[..., KVH * hd:].reshape(*row.shape[:-1], KVH // 2, 2 * hd)
+    return k[..., key_of, :], v[..., value_of, :]
+
+
+def _grouped_query(q, cfg):
+    """q [B, H, hd] -> [B, G, R, 2 hd] for ``ops/paged_attention.py``: the
+    query heads of a key pair as rows (padded to 16), each scaled and laid
+    where its key lies in the pair's ``k1 | k2``, zeros beside it."""
+    B, H, hd = q.shape
+    G = cfg.n_kv_heads // 2
+    q = (q * (hd ** -0.5)).reshape(B, G, H // G, hd)
+    second = (jnp.arange(H // G) % 2 == 1)[None, None, :, None]
+    zero = jnp.zeros_like(q)
+    q = jnp.concatenate([jnp.where(second, zero, q),
+                         jnp.where(second, q, zero)], axis=-1)
+    return jnp.pad(q, ((0, 0), (0, 0), (0, -(H // G) % 16), (0, 0)))
+
+
+def _paged_diff_attention(q, pages, work, layer, name, cfg):
+    """Decode's attention: q [B, H, hd] against the live rows of ``pages`` [L,
+    NP, P, row] -> [B, H, 2 hd]."""
+    from ray_tpu.ops.paged_attention import paged_gqa_decode
+
+    B, H, _ = q.shape
+    o = paged_gqa_decode(_grouped_query(q, cfg), pages, work, layer=layer,
+                         name=name)
+    G = o.shape[1]
+    return o[:, :, :H // G].reshape(B, H, -1)
+
+
+def _hybrid_head(x, p, cfg):
+    """The final LayerNorm and the tied table on [B, d] -> float32 logits."""
+    x = _layer_norm(x, p["final_norm"], cfg)
+    return jnp.einsum("bd,vd->bv", x, p["embed"].astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
+    from ray_tpu.models.transformer import causal_conv
+    from ray_tpu.ops.attention import attention as attention_op
+    from ray_tpu.ops.ssm import selective_scan
+
+    B, S = tokens.shape
+    P, W, K = cache.pages.shape[1], cfg.window, cfg.ssm_conv
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    in_prompt = positions < lengths[:, None]
+    page_for = jnp.take_along_axis(block_tables, positions // P, axis=1)
+    page = jnp.where(in_prompt, page_for, 0)   # padding -> the scratch page
+    offset = jnp.where(in_prompt, positions % P, 0)
+    last = jnp.maximum(lengths - 1, 0).astype(jnp.int32)[:, None]
+    # ring entry j holds the newest prompt position that is j mod window
+    ring_pos = last - (last - jnp.arange(W, dtype=jnp.int32)[None]) % W  # [B, W]
+    tail_pos = lengths[:, None] - (K - 1) + jnp.arange(K - 1)[None]     # [B, K-1]
+
+    def rows_at(t, pos):
+        """t [B, S, F] at positions pos [B, n]; zeros where pos < 0."""
+        got = jnp.take_along_axis(t, jnp.maximum(pos, 0)[..., None], axis=1)
+        return jnp.where((pos >= 0)[..., None], got, 0)
+
+    pages, rings, ssm, conv = cache[:4]
+    x = p["embed"][tokens].astype(jnp.float32)   # the residual stream: float32
+    memory = shared = None
+    mamba_i = window_i = 0
+    first_cross = min(i for i, k in enumerate(cfg.layer_kinds)
+                      if k in ("gmu", "cross"))
+    for i, kind in enumerate(cfg.layer_kinds):
+        if i == first_cross:
+            # the cross-decoder writes no state and the engine reads one
+            # position's logits: from here on, the last real position alone
+            x = jnp.take_along_axis(x, last[..., None], axis=1)
+            memory = jnp.take_along_axis(memory, last[..., None], axis=1)
+            seen = in_prompt[:, None, None, :]
+        lp = p[f"layer_{i}"]
+        m = lp["mixer"]
+        h = _layer_norm(x, lp["attn_norm"], cfg)
+        if kind == "mamba":
+            with jax.named_scope("ssm.prefill"):
+                az = _dense(h, m["in_proj"], cfg.dtype)
+                raw, z = az[..., :cfg.ssm_inner], az[..., cfg.ssm_inner:]
+                a, dt, Bm, Cm = _mamba_inputs(causal_conv(
+                    raw, m["conv_kernel"].astype(cfg.dtype),
+                    m["conv_bias"].astype(cfg.dtype)), m, cfg)
+                # padding neither advances the state nor enters the tail
+                y, state = selective_scan(
+                    jnp.where(in_prompt[..., None], dt, 0.0), a, Bm, Cm,
+                    -jnp.exp(m["A_log"]))
+                out, memory = _mamba_output(y, a, z, m, cfg)
+                ssm = ssm.at[mamba_i, slots].set(state)
+                # [layer, tap, slot]: the indexed axes come first, [B, K-1, I]
+                conv = conv.at[mamba_i, :, slots].set(rows_at(raw, tail_pos))
+            mamba_i += 1
+        elif kind == "gmu":
+            out = _memory_unit(h, memory, m, cfg)
+        elif kind == "cross":
+            with jax.named_scope("prefill.cross_row"):
+                q, _ = _diff_qkv(h, m, cfg)                # [B, 1, H, hd]
+                k, v = shared
+                scores = jnp.einsum("bqhd,bshd->bhqs", q, k,
+                                    preferred_element_type=jnp.float32)
+                scores = jnp.where(seen, scores * cfg.head_dim ** -0.5, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+                out = _diff_out(jnp.einsum("bhqs,bshd->bqhd", probs, v), m, i,
+                                cfg)
+        else:
+            q, row = _diff_qkv(h, m, cfg)
+            k, v = _row_heads(row, cfg)
+            out = _diff_out(attention_op(
+                q, k, v, causal=True, impl=cfg.attention_impl,
+                window=W if kind == "window" else 0), m, i, cfg)
+            if kind == "window":
+                rings = rings.at[window_i, slots].set(rows_at(row, ring_pos))
+                window_i += 1
+            else:
+                pages = pages.at[page, offset].set(row, mode="drop")
+                shared = (k, v)
+        x = x + out
+        x = x + _mlp(_layer_norm(x, lp["mlp_norm"], cfg), lp["mlp"], cfg.dtype)
+    return _hybrid_head(x[:, 0], p, cfg), HybridCache(pages, rings, ssm, conv)
+
+
+def _hybrid_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
+    from ray_tpu.ops.mla import live_pages
+
+    B = last_tokens.shape[0]
+    P, W = cache.pages.shape[1], cfg.window
+    slot = jnp.arange(B, dtype=jnp.int32)
+    positions = seq_lens.astype(jnp.int32)
+    cur_page = jnp.take_along_axis(block_tables, positions[:, None] // P,
+                                   axis=1)[:, 0]
+    page = jnp.where(active, cur_page, 0)  # inactive slots -> the scratch page
+    offset = jnp.where(active, positions % P, 0)
+    # the pages that hold live positions, once for the full layer and every
+    # cross layer; a ring is its slot's one page, its live entries the filled
+    work = live_pages(positions, active, block_tables, P)
+    ring_work = live_pages(jnp.minimum(positions, W - 1), active,
+                           slot[:, None], W)
+
+    pages, rings, ssm, conv = cache[:4]
+    x = p["embed"][last_tokens].astype(jnp.float32)        # [B, d]
+    memory = None
+    mamba_i = window_i = 0
+    for i, kind in enumerate(cfg.layer_kinds):
+        lp = p[f"layer_{i}"]
+        m = lp["mixer"]
+        h = _layer_norm(x, lp["attn_norm"], cfg)
+        if kind == "mamba":
+            with jax.named_scope("ssm.step"):
+                az = _dense(h, m["in_proj"], cfg.dtype)
+                raw, z = az[..., :cfg.ssm_inner], az[..., cfg.ssm_inner:]
+                taps = jnp.concatenate([conv[mamba_i], raw[None]], axis=0)
+                a, dt, Bm, Cm = _mamba_inputs(
+                    jnp.einsum("kbi,ki->bi", taps,
+                               m["conv_kernel"].astype(cfg.dtype))
+                    + m["conv_bias"].astype(cfg.dtype), m, cfg)
+                state = jnp.exp(dt[:, None] * -jnp.exp(m["A_log"]).T) \
+                    * ssm[mamba_i] + (dt * a)[:, None] * Bm[..., None]
+                out, memory = _mamba_output(
+                    jnp.einsum("bni,bn->bi", state, Cm), a, z, m, cfg)
+                ssm = ssm.at[mamba_i].set(state)
+                conv = conv.at[mamba_i].set(taps[1:])
+            mamba_i += 1
+        elif kind == "gmu":
+            out = _memory_unit(h, memory, m, cfg)
+        else:
+            q, row = _diff_qkv(h, m, cfg)
+            if kind == "window":
+                rings = rings.at[window_i, slot, positions % W].set(row)
+                o = _paged_diff_attention(q, rings, ring_work, window_i,
+                                          "window_gqa_decode", cfg)
+                window_i += 1
+            else:
+                if row is not None:   # the full layer writes the shared row
+                    pages = pages.at[page, offset].set(row, mode="drop")
+                o = _paged_diff_attention(q, pages[None], work, 0,
+                                          "paged_gqa_decode", cfg)
+            out = _diff_out(o, m, i, cfg)
+        x = x + out
+        x = x + _mlp(_layer_norm(x, lp["mlp_norm"], cfg), lp["mlp"], cfg.dtype)
+    return _hybrid_head(x, p, cfg), HybridCache(pages, rings, ssm, conv)
+
+
+# ---------------------------------------------------------------------------
 # prefill
 # ---------------------------------------------------------------------------
 
@@ -208,16 +520,30 @@ def _latent_attention_absorbed(q_nope, q_pe, rows, work, layer, p, cfg):
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2,))
 def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
             tokens: jax.Array, lengths: jax.Array,
-            block_tables: jax.Array) -> Tuple[jax.Array, KVCache]:
+            block_tables: jax.Array, slots: Optional[jax.Array] = None
+            ) -> Tuple[jax.Array, KVCache]:
     """Run the prompt forward, write KV pages, return last-position logits.
 
     tokens: [B, S] padded with PAD after `lengths`; block_tables: [B, MP].
     Returns logits [B, vocab] at position lengths-1 and the updated cache.
+    ``slots`` [B]: the slot each row fills, for a model that keeps state by
+    slot (``HybridCache``); such a model leaves its state at position
+    ``lengths - 1``, not at the padded ``S - 1``, and runs its cross-decoder
+    on that position alone. The engine always gives ``slots`` for such a
+    model. Where none is given, row ``b`` fills slot ``b``: that is the path
+    of the benchmark's check alone (``benchmarks/jobs/serve.py:
+    reference_check`` calls an every-slot ``[max_num_seqs, S]`` batch without
+    it), and goes once the harness calls ``[1, S]`` with a slot.
     """
     from ray_tpu.ops.attention import attention as attention_op
 
     p = params["params"]
     B, S = tokens.shape
+    if cfg.layer_kinds:
+        if slots is None:
+            slots = jnp.arange(B, dtype=jnp.int32)
+        return _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables,
+                               slots)
     P = cache[0].shape[2]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     in_prompt = positions < lengths[:, None]
@@ -307,6 +633,9 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
     the mask is pos <= seq_lens.
     """
     p = params["params"]
+    if cfg.layer_kinds:
+        return _hybrid_decode(p, cfg, cache, last_tokens, seq_lens,
+                              block_tables, active)
     B = last_tokens.shape[0]
     P = cache[0].shape[2]
     MP = block_tables.shape[1]

@@ -103,15 +103,41 @@ class TransformerConfig:
     # (a comparison with a reference) state them
     attn_init_std: float = 0.0
     mlp_init_std: float = 0.0
+    # a decoder-hybrid-decoder (SambaY): each layer's mixer by kind, "mamba"
+    # (a Mamba-1 selective scan), "window" / "full" (differential attention
+    # over the ``window`` newest positions / over all), "gmu" (a gated memory
+    # unit: it gates the scan output of the LAST "mamba" layer at the same
+    # position) or "cross" (a query projection only: differential attention
+    # over the keys and values the "full" layer made). () = attention with
+    # rotary embedding in every layer, as above. A model with kinds has
+    # LayerNorm with bias for RMSNorm, no position embedding, biases on the
+    # attention projections and a tied head (``HybridBlock``).
+    layer_kinds: Tuple[str, ...] = ()
+    window: int = 0
+    ssm_inner: int = 0      # width of the scan and of the gated memory
+    ssm_state: int = 16
+    ssm_conv: int = 4       # taps of the causal depthwise convolution
+    ssm_dt_rank: int = 0
+    # what a Mamba layer's and a memory unit's in_proj and out_proj, and the
+    # scan's x_proj (dt | B | C), are drawn at: with seeded weights the state
+    # shows in the output only where B and C are of order one, which one
+    # deviation for all of them cannot give
+    ssm_proj_init_std: float = 0.0
+    ssm_x_init_std: float = 0.0
+    # the table's (0 = 0.02). Under a pre-norm a layer's gain on the stream
+    # goes with its output over the stream's size: seeded layers that are to
+    # show in the logits without amplifying each other's rounding need a
+    # stream that starts out as large as what they add
+    embed_init_std: float = 0.0
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
     def init_std(self, part: str) -> float:
-        """``part``: "attn" or "mlp"."""
-        return getattr(self, part + "_init_std") \
-            or 0.02 / np.sqrt(2 * self.n_layers)
+        """``part``: "attn", "mlp", "ssm_proj", "ssm_x" or "embed"."""
+        return getattr(self, part + "_init_std") or (
+            0.02 if part == "embed" else 0.02 / np.sqrt(2 * self.n_layers))
 
     def is_moe_layer(self, i: int) -> bool:
         return (self.n_experts > 0 and i >= self.first_k_dense
@@ -119,6 +145,20 @@ class TransformerConfig:
 
     def num_params(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
+        if self.layer_kinds:
+            inner, hd = self.ssm_inner, self.head_dim
+            qkv = (self.n_heads + 2 * self.n_kv_heads) * hd
+            attn = d * d + d + 6 * hd       # o and its bias, lambdas, sub-norm
+            mixer = {
+                "mamba": d * 2 * inner + inner * (
+                    self.ssm_dt_rank + 2 * self.ssm_state)
+                + self.ssm_dt_rank * inner + inner * d
+                + inner * (self.ssm_conv + 3 + self.ssm_state),
+                "gmu": 2 * d * inner,
+                "window": (d + 1) * qkv + attn, "full": (d + 1) * qkv + attn,
+                "cross": (d + 1) * d + attn}
+            return v * d + 2 * d + sum(
+                mixer[kind] + 3 * d * f + 4 * d for kind in self.layer_kinds)
         if self.kv_latent_rank:
             r, rope = self.kv_latent_rank, self.qk_rope_head_dim
             attn = (
@@ -405,6 +445,201 @@ class MoEMLP(nn.Module):
         return out
 
 
+class LayerNorm(nn.Module):
+    """Mean and variance, scale and bias, statistics in float32."""
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        scale = self.param("scale", nn.with_logical_partitioning(
+            nn.initializers.ones, ("embed",)), (d,), jnp.float32)
+        bias = self.param("bias", nn.with_logical_partitioning(
+            nn.initializers.normal(NORM_BIAS_STD), ("embed",)), (d,),
+            jnp.float32)
+        return layer_norm(x, scale, bias, self.eps)
+
+
+def layer_norm(x, scale, bias, eps):
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+    return ((x32 - mean) * jax.lax.rsqrt(var + eps) * scale + bias
+            ).astype(x.dtype)
+
+
+# what a hybrid model's seeded biases, lambda vectors and scan constants are
+# drawn at, chosen so that each shows in the logits (a comparison with a
+# reference that drops one fails): benchmarks/configs/
+# phi-4-mini-flash-reasoning.json, "assumed"
+NORM_BIAS_STD = 0.02
+PROJ_BIAS_STD = 0.02
+LAMBDA_STD = 0.1
+
+
+def lambda_init(layer: int) -> float:
+    """Differential attention's constant part of lambda at layer ``layer``."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * layer))
+
+
+def diff_lambda(p, layer: int):
+    """lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init, float32."""
+    f32 = lambda n: p[n].astype(jnp.float32)   # noqa: E731
+    return (jnp.exp(jnp.sum(f32("lambda_q1") * f32("lambda_k1")))
+            - jnp.exp(jnp.sum(f32("lambda_q2") * f32("lambda_k2")))
+            + lambda_init(layer))
+
+
+def diff_heads(cfg: TransformerConfig):
+    """Differential attention as plain attention over ``n_heads`` heads:
+    query head ``h`` (of pair ``h // 2``) scores against key head ``key_of[h]``
+    and reads the value pair ``value_of[h]``, two value heads side by side."""
+    rep = cfg.n_heads // cfg.n_kv_heads
+    h = np.arange(cfg.n_heads)
+    pair = (h // 2) // rep
+    return 2 * pair + h % 2, pair
+
+
+def diff_combine(o, lam, layer: int, scale, eps):
+    """o [..., H, 2 hd] (every head's softmax times its value pair) -> [..., H
+    hd]: per query pair ``RMSNorm(o1 - lambda o2) * (1 - lambda_init)``."""
+    o = o.astype(jnp.float32)
+    d = o[..., 0::2, :] - lam * o[..., 1::2, :]
+    d = d * jax.lax.rsqrt(jnp.mean(d * d, axis=-1, keepdims=True) + eps)
+    d = d * scale * (1.0 - lambda_init(layer))
+    return d.reshape(*d.shape[:-2], -1)
+
+
+def causal_conv(a, weight, bias):
+    """Depthwise causal convolution along axis 1: a [B, S, I], weight [K, I]
+    (tap K-1 is the position itself)."""
+    K, S = weight.shape[0], a.shape[1]
+    padded = jnp.pad(a, ((0, 0), (K - 1, 0), (0, 0)))
+    return sum(weight[k] * padded[:, k:k + S] for k in range(K)) + bias
+
+
+def _dt_bias_init(key, shape, dtype):
+    """softplus^-1 of step sizes log-uniform in [1e-3, 1e-1] (Mamba's own)."""
+    dt = jnp.exp(jax.random.uniform(key, shape) * np.log(100.0) + np.log(1e-3))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+class HybridMixer(nn.Module):
+    """One layer's mixer of a model with ``layer_kinds``, the TRAINING side:
+    the whole sequence at once, no cache (the serving engine computes the same
+    from the same tree, ``llm/model_runner.py``). Returns (output, memory,
+    shared): ``memory`` is a Mamba layer's scan output, ``shared`` the full
+    layer's (k, v), each handed on unchanged by the layers that make none."""
+
+    cfg: TransformerConfig
+    kind: str
+    layer: int
+
+    def _dense(self, feats, name, std, bias=False, dtype=None):
+        cfg = self.cfg
+        return nn.DenseGeneral(
+            features=feats, use_bias=bias, dtype=dtype or cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name,
+            kernel_init=nn.initializers.normal(std),
+            bias_init=nn.initializers.normal(PROJ_BIAS_STD))
+
+    @nn.compact
+    def __call__(self, h, memory, shared):
+        cfg, kind = self.cfg, self.kind
+        if kind == "mamba":
+            out, memory = self._mamba(h)
+        elif kind == "gmu":
+            gate = nn.silu(self._dense(cfg.ssm_inner, "in_proj",
+                                       cfg.init_std("ssm_proj"))(h))
+            out = self._dense(cfg.d_model, "out_proj",
+                              cfg.init_std("ssm_proj"))(
+                memory.astype(cfg.dtype) * gate)
+        else:
+            out, shared = self._attention(h, shared)
+        return out, memory, shared
+
+    def _mamba(self, h):
+        from ray_tpu.ops.ssm import selective_scan_reference
+
+        cfg = self.cfg
+        inner, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+        az = self._dense(2 * inner, "in_proj", cfg.init_std("ssm_proj"))(h)
+        a, z = az[..., :inner], az[..., inner:]
+        w = self.param("conv_kernel", nn.initializers.normal(
+            cfg.ssm_conv ** -0.5), (cfg.ssm_conv, inner), cfg.param_dtype)
+        b = self.param("conv_bias", nn.initializers.normal(PROJ_BIAS_STD),
+                       (inner,), cfg.param_dtype)
+        a = nn.silu(causal_conv(a, w.astype(cfg.dtype), b.astype(cfg.dtype)))
+        x = self._dense(R + 2 * N, "x_proj", cfg.init_std("ssm_x"),
+                        dtype=jnp.float32)(a)
+        dt = jax.nn.softplus(nn.DenseGeneral(
+            inner, dtype=jnp.float32, param_dtype=jnp.float32, name="dt_proj",
+            kernel_init=nn.initializers.normal(R ** -0.5),
+            bias_init=_dt_bias_init)(x[..., :R]))
+        A_log = self.param(
+            "A_log", lambda *_: jnp.broadcast_to(jnp.log(jnp.arange(
+                1, N + 1, dtype=jnp.float32)), (inner, N)))
+        D = self.param("D", nn.initializers.ones, (inner,), jnp.float32)
+        y, _ = selective_scan_reference(
+            dt, a, x[..., R:R + N], x[..., R + N:], -jnp.exp(A_log),
+            jnp.zeros((h.shape[0], inner, N), jnp.float32))
+        y = y + D * a.astype(jnp.float32)
+        out = self._dense(cfg.d_model, "out_proj", cfg.init_std("ssm_proj"))(
+            y.astype(cfg.dtype) * nn.silu(z))
+        return out, y
+
+    def _attention(self, h, shared):
+        from ray_tpu.ops.attention import reference_attention
+
+        cfg, kind = self.cfg, self.kind
+        H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        std = cfg.init_std("attn")
+        heads = lambda t, n: t.reshape(*t.shape[:-1], n, hd)   # noqa: E731
+        if kind == "cross":
+            q = heads(self._dense(H * hd, "Wq", std, bias=True)(h), H)
+            k, v = shared
+        else:
+            qkv = self._dense((H + 2 * KVH) * hd, "Wqkv", std, bias=True)(h)
+            q = heads(qkv[..., :H * hd], H)
+            k = heads(qkv[..., H * hd:(H + KVH) * hd], KVH)
+            v = heads(qkv[..., (H + KVH) * hd:], KVH)
+            if kind == "full":
+                shared = (k, v)
+        lam = {n: self.param(n, nn.initializers.normal(LAMBDA_STD), (hd,),
+                             jnp.float32)
+               for n in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")}
+        scale = self.param("subln", nn.initializers.ones, (2 * hd,),
+                           jnp.float32)
+        key_of, value_of = diff_heads(cfg)
+        pairs = v.reshape(*v.shape[:-2], KVH // 2, 2 * hd)
+        o = reference_attention(
+            q, k[..., key_of, :], pairs[..., value_of, :], True, None,
+            cfg.window if kind == "window" else 0)
+        o = diff_combine(o, diff_lambda(lam, self.layer), self.layer, scale,
+                         cfg.norm_eps).astype(cfg.dtype)
+        out = self._dense(cfg.d_model, "out_proj", std, bias=True)(o)
+        return out, shared
+
+
+class HybridBlock(nn.Module):
+    cfg: TransformerConfig
+    kind: str
+    layer: int
+
+    @nn.compact
+    def __call__(self, x, memory, shared):
+        cfg = self.cfg
+        out, memory, shared = HybridMixer(
+            cfg, self.kind, self.layer, name="mixer")(
+            LayerNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x), memory,
+            shared)
+        h = x + out
+        h = h + MLP(cfg, name="mlp")(
+            LayerNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h))
+        return h, memory, shared
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     use_moe: bool = False
@@ -435,10 +670,19 @@ class Transformer(nn.Module):
             positions = jnp.broadcast_to(positions, tokens.shape)
         embed = self.param(
             "embed", nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("vocab", "embed")),
+                nn.initializers.normal(cfg.init_std("embed")),
+                ("vocab", "embed")),
             (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
         x = embed.astype(cfg.dtype)[tokens]
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        if cfg.layer_kinds:
+            memory = shared = None
+            for i, kind in enumerate(cfg.layer_kinds):
+                x, memory, shared = HybridBlock(
+                    cfg, kind, i, name=f"layer_{i}")(x, memory, shared)
+            x = LayerNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+            return jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
         block = Block
         if cfg.remat:
             block = nn.remat(Block, prevent_cse=False,

@@ -35,8 +35,10 @@ import jax.numpy as jnp
 
 
 def reference_attention(q, k, v, causal: bool = True,
-                        segment_ids: Optional[jax.Array] = None):
-    """Pure-XLA attention: (B, S, H, D) -> (B, S, H, D), fp32 softmax."""
+                        segment_ids: Optional[jax.Array] = None,
+                        window: int = 0):
+    """Pure-XLA attention: (B, S, H, D) -> (B, S, H, Dv), fp32 softmax. With
+    ``window`` a query sees only the ``window`` newest keys up to its own."""
     d = q.shape[-1]
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -44,6 +46,8 @@ def reference_attention(q, k, v, causal: bool = True,
     S = q.shape[1]
     if causal:
         mask = jnp.tril(jnp.ones((S, S), dtype=bool))
+        if window:
+            mask &= ~jnp.tril(jnp.ones((S, S), dtype=bool), -window)
         scores = jnp.where(mask[None, None], scores, -1e30)
     if segment_ids is not None:
         seg_mask = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
@@ -121,6 +125,24 @@ def _hide_future(s, q_start, k_start, q_axis):
     return jnp.where(q_pos >= k_pos, s, _MASKED)
 
 
+def _hide_outside(s, q_start, k_start, window):
+    """The causal mask and the window's left edge over one score tile
+    (queries down): a query sees the ``window`` newest keys up to its own."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where((q_pos >= k_pos) & (k_pos > q_pos - window), s, _MASKED)
+
+
+def _window_blocks(q_start, block_q, block_k, window):
+    """For the queries [q_start, q_start + block_q) under a window: key blocks
+    before ``first`` lie wholly left of every query's window and are never
+    visited, [first, edge) may hold a pair the window hides, and the blocks
+    from ``edge`` on lie right of the last query's left edge."""
+    first = jnp.maximum(q_start - window + 1, 0) // block_k
+    edge = jnp.maximum(q_start + block_q - window + block_k - 1, 0) // block_k
+    return first, edge
+
+
 def _key_blocks(q_start, block_q, block_k, seq_len, causal):
     """For the queries [q_start, q_start + block_q): key blocks [0, clear) lie
     wholly below the diagonal and need no mask, [clear, end) cross it, and
@@ -131,7 +153,7 @@ def _key_blocks(q_start, block_q, block_k, seq_len, causal):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
-                      sm_scale):
+                      sm_scale, window=0):
     import jax.experimental.pallas as pl
 
     block_q, d = q_ref.shape[1], v_ref.shape[2]
@@ -145,7 +167,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
             keys = _block(i, block_k, seq_len)
             k, v = k_ref[0, keys, :], v_ref[0, keys, :]
             s = scores(k)                                 # (bq, bk) float32
-            if masked:
+            if masked and window:
+                s = _hide_outside(s, q_start, i * block_k, window)
+            elif masked:
                 s = _hide_future(s, q_start, i * block_k, 0)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -159,7 +183,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
              jnp.full((block_q, 1), _MASKED, jnp.float32),
              jnp.zeros((block_q, 1), jnp.float32))
     clear, end = _key_blocks(q_start, block_q, block_k, seq_len, causal)
-    carry = jax.lax.fori_loop(0, clear, step(False), carry)
+    first = 0
+    if window:
+        # the blocks the window's left edge crosses, masked; every row of a
+        # visited block sees a key of it or of an earlier one, so no row's
+        # running max is still the mask's when a later block rescales it
+        first, edge = _window_blocks(q_start, block_q, block_k, window)
+        edge = jnp.minimum(edge, end)
+        carry = jax.lax.fori_loop(first, edge, step(True), carry)
+        first, clear = edge, jnp.maximum(clear, edge)
+    carry = jax.lax.fori_loop(first, clear, step(False), carry)
     acc, m, l = jax.lax.fori_loop(clear, end, step(True), carry)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
@@ -179,10 +212,13 @@ def _from_bh(x, B, H):
     return x.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
 
-def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool):
+def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool, window: int = 0):
     """Returns (o, lse) with o in (B, S, H, Dv) and lse in (B*H, 1, S). The
     values may have a head size of their own (latent attention: q . k over
-    192, p . v over 128); the softmax scale is the key head size's."""
+    192, p . v over 128); the softmax scale is the key head size's. With
+    ``window`` (causal only) a query sees the ``window`` newest keys up to its
+    own, and key blocks left of the window are skipped as the ones above the
+    diagonal are."""
     import jax.experimental.pallas as pl
 
     B, S, H, D = q.shape
@@ -191,6 +227,9 @@ def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool):
     qt, kt, vt = _to_bh(q), _to_bh(k), _to_bh(v)
     kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
                                causal=causal, sm_scale=1.0 / (D ** 0.5))
+    if window:
+        assert causal, "a window is the causal mask's left edge"
+        kernel = functools.partial(kernel, window=window)
     # the whole-sequence K and V, twice each (the pipeline's two buffers),
     # as fast memory holds them (the minor dimension in whole 128-lane tiles):
     # 12.6 MiB at bf16[.., 8192, 192 / 128], which with the blocks and the
@@ -228,9 +267,9 @@ def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool):
 
 
 def flash_attention_fwd(q, k, v, causal: bool = True,
-                        interpret: bool = False):
+                        interpret: bool = False, window: int = 0):
     """(B, S, H, D) flash forward via pallas (TPU) / interpret mode (CI)."""
-    return _flash_fwd_impl(q, k, v, causal, interpret)[0]
+    return _flash_fwd_impl(q, k, v, causal, interpret, window)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -452,8 +491,9 @@ def _flash(q, k, v, causal: bool, interpret: bool):
 
 
 def attention(q, k, v, causal: bool = True, impl: str = "auto",
-              segment_ids: Optional[jax.Array] = None):
-    """Dispatching attention op used by the flagship model."""
+              segment_ids: Optional[jax.Array] = None, window: int = 0):
+    """Dispatching attention op used by the flagship model. ``window``: the
+    forward alone (serving's prefill); the flash kernels' backward has none."""
     if impl == "auto":
         from ray_tpu.utils import is_tpu
 
@@ -464,8 +504,10 @@ def attention(q, k, v, causal: bool = True, impl: str = "auto",
             and q.shape[-1] in (64, 128, 192, 256)
         )
         impl = "flash" if use_flash else "xla"
+    if window and impl in ("flash", "flash_interpret"):
+        return flash_attention_fwd(q, k, v, causal, impl != "flash", window)
     if impl == "flash":
         return _flash(q, k, v, causal, False)
     if impl == "flash_interpret":
         return _flash(q, k, v, causal, True)
-    return reference_attention(q, k, v, causal, segment_ids)
+    return reference_attention(q, k, v, causal, segment_ids, window)

@@ -448,3 +448,97 @@ def test_latent_prefill_compiles_with_unequal_head_sizes(one_chip, rows,
     print(f"latent prefill, 2 layers, [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")
     assert 0 < live < 16 << 30
+
+
+# -- the hybrid model's programs (Phi-4-mini-flash-reasoning, the benchmark's file)
+
+
+def _lower_hybrid(one_chip, n_layers=8):
+    """The engine's programs at the published widths of
+    ``benchmarks/configs/phi-4-mini-flash-reasoning.json`` and its job block's
+    geometry (48 slots x 10240, pages of 512), depth cut to ``n_layers`` with
+    the pattern kept (8: Mamba, window, Mamba, window, the Mamba layer that
+    hands its memory on, full, a gated memory unit, cross)."""
+    import json
+
+    import flax.linen as nn
+
+    from benchmarks.jobs import common
+    from benchmarks.registry import REPO, architecture
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.llm.config import EngineConfig
+    from ray_tpu.models.transformer import Transformer
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        conf = json.load(f)
+    e = EngineConfig(**conf["job"]["engine"])
+    cfg = dataclasses.replace(
+        common.transformer_config(conf, e.max_model_len), n_layers=n_layers,
+        layer_kinds=architecture(conf).layer_kinds(n_layers),
+        attention_impl="flash")
+    params = _on(jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))), one_chip)
+    cache = _on(jax.eval_shape(lambda: mr.init_cache(
+        cfg, e.num_pages, e.page_size, e.max_num_seqs)), one_chip)
+    B, MP = e.max_num_seqs, e.pages_per_seq
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+
+    def prefill(bucket):
+        return mr.prefill.lower(params, cfg, cache, i32(1, bucket), i32(1),
+                                i32(1, MP), i32(1))
+
+    return cache, prefill, lambda: mr.decode_step.lower(
+        params, cfg, cache, i32(B), i32(B), i32(B, MP), active)
+
+
+def test_hybrid_decode_compiles_with_the_paged_kernels(one_chip):
+    """Decode at 48 slots, one period of the pattern: the paged kernel under
+    the two names the trace's metrics read (once for each window layer's
+    rings, once each for the full and the cross layer over the shared pages),
+    every kind of state written in place (the whole cache aliased) and no
+    copy of pages or rings made for a kernel: the temporaries stay far below
+    one window layer's rings (126 MB)."""
+    cache, _, decode = _lower_hybrid(one_chip)
+    compiled = decode().compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%(window_gqa_decode\S*) = bf16\[48,10,16,128\]",
+                              text))) == 2
+    assert len(set(re.findall(r"%(paged_gqa_decode\S*) = bf16\[48,10,16,128\]",
+                              text))) == 2
+    assert text.count("tpu_custom_call") == 4
+    live, temp = _live(compiled)
+    held = sum(x.size * x.dtype.itemsize for x in cache[:4])
+    print(f"hybrid decode, 8 layers, 48 slots: {live} bytes live, {temp} of "
+          f"temporaries; cache {held}")
+    assert cache.pages.shape == (961, 512, 2560)
+    assert cache.rings.shape == (2, 48, 512, 2560)
+    assert cache.ssm.shape == (3, 48, 16, 5120)
+    assert temp < cache.rings.size * 2 // 2 // 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
+@pytest.mark.parametrize("bucket", [256, 8192])
+def test_hybrid_prefill_compiles_with_scan_and_window(one_chip, bucket):
+    """The engine's [1, S] prefill at the mix's least and largest bucket: the
+    scan kernel in each Mamba layer, the flash kernel over 64-wide scores and
+    128-wide values in the window layers (key blocks left of the window
+    skipped) and in the full layer; the cross layer attends from one row and
+    needs none. What an execution holds live is printed (``-s``); the full
+    depth is in PERF.md section 4."""
+    _, prefill, _ = _lower_hybrid(one_chip)
+    compiled = prefill(bucket).compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(
+        rf"%(ssm_scan\S*) = \(f32\[1,{bucket},5120\]", text))) == 3
+    assert len(set(re.findall(
+        rf"%(flash_fwd\S*) = \(bf16\[40,{bucket},128\]", text))) == 3
+    assert text.count("tpu_custom_call") == 6
+    live, temp = _live(compiled)
+    print(f"hybrid prefill, 8 layers, [1, {bucket}]: {live} bytes live, "
+          f"{temp} of temporaries")
+    assert 0 < live < 16 << 30

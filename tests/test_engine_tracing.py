@@ -27,7 +27,11 @@ KEYS = {
     "moe_decode_layer_steps", "moe_decode_assignments",
     "moe_decode_experts_touched", "moe_decode_max_load",
     # what a latent cache's decode read; 0 without one
-    "mla_decode_live_tokens", "mla_decode_read_tokens"}
+    "mla_decode_live_tokens", "mla_decode_read_tokens",
+    # a model with layer_kinds: its shared layer's pages, rings, recurrent
+    # rows and the cross-decoder's prefill rows; 0 without them
+    "shared_kv_live_tokens", "shared_kv_read_tokens", "window_live_tokens",
+    "ssm_decode_layer_steps", "prefill_cross_rows"}
 PHASES = ("admit_ms", "prefill_dispatch_ms", "decode_dispatch_ms",
           "sample_dispatch_ms", "readback_ms", "emit_ms")
 SLOTS, BUCKET = 8, 16
