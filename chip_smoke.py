@@ -134,8 +134,9 @@ def train_loop(cfg: dict) -> None:
         "tokens_per_step": cfg["batch"] * cfg["seq"],
         "losses": losses, "losses_readback_pass": losses_rb,
         "tpu_custom_calls": hlo.count("tpu_custom_call"),
-        "attention_backward": ("pallas" if _use_pallas_bwd(mcfg.head_dim)
-                               else "reference_attention"),
+        "attention_backward": (
+            "pallas" if _use_pallas_bwd(mcfg.head_dim, cfg["seq"])
+            else "reference_attention"),
         "peak_bytes_in_use": after["peak_bytes_in_use"],
     })
 

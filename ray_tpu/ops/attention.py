@@ -15,8 +15,10 @@ accumulators stay float32. The causal mask is built only in the blocks the
 diagonal crosses (a second loop with the same body), the blocks above it are
 never visited, and the row statistics lie along the lanes, (B*H, 1, S).
 ``_blocks`` sizes the blocks from the shapes; ``_use_pallas_bwd`` picks the
-backward: the pallas pair at head_dim <= 64, a rematerialised backward through
-``reference_attention`` at 128 and above.
+backward from the shapes too: the pallas pair wherever its whole-sequence
+blocks fit fast memory (head_dim 64 and 128 up to 12,288 positions in
+bfloat16), a rematerialised backward through ``reference_attention`` for a
+longer sequence and for values with a head size of their own.
 
 CI runs the kernels in pallas interpret mode on CPU (SURVEY.md §4 implication:
 every accelerator feature needs a hardware-free tier).
@@ -77,8 +79,11 @@ def _blocks(seq_len: int) -> tuple:
     (PERF.md section 6, PR 30: forward / dq / dkv ms a call at
     ``bf16[4,2048,32,64]``, 128 x 128 6.84 / 6.21 / 5.88, 256 x 256 2.96 / 2.63
     / 3.50, 512 x 512 1.88 / 1.81 / 2.40, 1024 x 1024 2.10 / 1.99 / 2.63, no
-    unequal pair ahead); head_dim 128 orders the same way, so neither
-    head_dim nor the dtype enters the rule."""
+    unequal pair ahead); head_dim 128 orders the same way (PR 34, the same
+    twelve at ``bf16[2,2048,16,128]``: 1.73 / 1.53 / 1.43, 0.74 / 0.64 / 0.85,
+    0.47 / 0.45 / 0.60, 0.53 / 0.50 / 0.66; 256 x 512 is 0.6% ahead in the
+    forward alone and 16% behind over the three), so neither head_dim nor
+    the dtype enters the rule."""
     if seq_len <= 128:
         return seq_len, seq_len
     for block in (512, 256, 128):
@@ -86,6 +91,11 @@ def _blocks(seq_len: int) -> tuple:
             return block, block
     raise ValueError("flash attention takes at most 128 positions or a "
                      f"multiple of 128, got {seq_len}")
+
+
+def _lanes(d: int) -> int:
+    """A row of ``d`` elements as fast memory holds it: whole 128-lane tiles."""
+    return -(-d // 128) * 128
 
 
 def _dot(a, b, dims=_NN):
@@ -235,8 +245,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool, window: int = 0):
     # 12.6 MiB at bf16[.., 8192, 192 / 128], which with the blocks and the
     # score tile is 0.4 MiB past the compiler's own 16 MiB. Only such a shape
     # gets a limit of its own; every other call is compiled as it was.
-    lanes = lambda d: -(-d // 128) * 128    # noqa: E731
-    held = 2 * S * (lanes(D) + lanes(Dv)) * k.dtype.itemsize
+    held = 2 * S * (_lanes(D) + _lanes(Dv)) * k.dtype.itemsize
     params = {}
     if held > 10 << 20:
         from jax.experimental.pallas import tpu as pltpu
@@ -355,15 +364,33 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def flash_attention_bwd(q, k, v, o, lse, g, causal: bool,
                         interpret: bool = False):
+    """(dq, dk, dv) by the pallas pair, at the blocks ``_blocks`` gives the
+    sequence."""
+    return _flash_bwd_pair(q, k, v, o, lse, g, causal, interpret,
+                           *_blocks(q.shape[1]))
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _flash_bwd_pair(q, k, v, o, lse, g, causal, interpret, block_q, block_k):
+    """Jitted so that the layers of a model share one trace and one lowering
+    of it: a ``pallas_call`` is traced and lowered to Mosaic wherever it is
+    bound, about 0.2 s of host time a kernel on the chip's machine, compile
+    cache or not (the 16 calls of cell 4's step: 3.3 s of every start, PERF.md
+    section 6, PR 34); XLA inlines the call, and the compiled step is the
+    same. Everything the trace reads besides the operands is a static
+    argument, the blocks too: the cache holds one trace for each."""
     import jax.experimental.pallas as pl
 
     B, S, H, D = q.shape
-    block_q, block_k = _blocks(S)
     qt, kt, vt = _to_bh(q), _to_bh(k), _to_bh(v)
     dot = _to_bh(g)
     # delta = rowsum(dO * O): cheap elementwise — plain XLA, not a kernel;
     # reduced where the operands lie, so that only the (B, S, H) sums are
-    # transposed, into lse's layout (B*H, 1, S)
+    # transposed, into lse's layout (B*H, 1, S). O comes rounded to the
+    # operands' dtype: where attention is nearly uniform and dP - delta
+    # cancels, that rounding is what puts a model's q and k gradients 2.5e-2
+    # from the float32 reference where ``reference_attention``'s backward
+    # reads 1.4e-2 (PERF.md section 6, PR 34)
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = delta.transpose(0, 2, 1).reshape(B * H, 1, S)
     common = dict(causal=causal, sm_scale=1.0 / (D ** 0.5))
@@ -414,8 +441,10 @@ def flash_attention(q, k, v, causal: bool = True, interpret: bool = False):
 
 
 def _fa_fwd(q, k, v, causal, interpret):
-    # head_dim is static at trace time; the pallas pair takes one head size
-    if q.shape[-1] == v.shape[-1] and _use_pallas_bwd(q.shape[-1]):
+    # the shapes are static at trace time; the pallas pair has one head size
+    # (latent attention's 192 / 128 forward keeps the reference backward)
+    if q.shape[-1] == v.shape[-1] and _use_pallas_bwd(
+            q.shape[-1], q.shape[1], q.dtype.itemsize):
         o, lse = _flash_fwd_impl(q, k, v, causal, interpret)
         return o, (q, k, v, o, lse)
     # reference backward never reads o/lse: don't hold them across bwd
@@ -423,24 +452,43 @@ def _fa_fwd(q, k, v, causal, interpret):
                                interpret=interpret), (q, k, v, None, None)
 
 
-def _use_pallas_bwd(head_dim: int) -> bool:
-    """The pallas backward pair is used for head_dim <= 64 by default; at
-    128 the backward rematerializes through ``reference_attention``. Both
-    backwards compile for the v5e at the ``1b`` shapes
-    (tests/test_chip_compile.py). The rule is older than its measurement: at
-    ``bf16[2,2048,16,128]`` on the v5e the pallas pair takes 0.45 + 0.60 ms a
-    call where the reference backward takes 7.4 (bare microbenchmark, PR 30;
-    PERF.md section 6). It stands until a ``benchmark`` issue decides it
-    (ROADMAP S3c / D4: ``benchmarks/jobs/train.py`` imports this function).
-    Override with RAY_TPU_FLASH_BWD=pallas|reference."""
-    import os
+# What one whole-sequence operand of the backward pair may take of fast
+# memory, as it lies there (the minor dimension in whole 128-lane tiles), by
+# the bytes of a row: (rows up to, bytes). ``flash_bwd_dq`` holds K and V
+# whole, ``flash_bwd_dkv`` Q and dO, each twice (the pipeline's two buffers):
+# four times this of the compiler's 16 MiB; the blocks, accumulators and
+# score tiles beside them grow with the row, so a wider row leaves less.
+# Found by compiling for the v5e each kernel alone, the pair together and a
+# train step, at 16 to 128 heads (tests/test_chip_compile.py; PERF.md
+# section 6, PR 34); the longest sequence at which all of them compiled / the
+# shortest at which one did not: bfloat16 at head_dim 128 12,800 / 13,312, at
+# 64 12,288 / 13,312, at 256 4,608 / 5,120, at 192 (256 lanes) 4,096 / 5,120;
+# float32 at 128 5,632 / 6,144, at 256 1,536 / 2,048. (Where it stops
+# depends on what surrounds the call: at 2 to 4 heads 16,384 positions of
+# head_dim 128 compile.) So bfloat16 takes the pair to 12,288 positions at
+# head_dim 64 and 128 and to 4,096 at 192 and 256, float32 to 4,096 at 128
+# and to 1,536 at 256.
+_BWD_WHOLE_SEQ_BYTES = ((256, 3 << 20), (512, 2 << 20), (1024, 3 << 19))
 
-    mode = os.environ.get("RAY_TPU_FLASH_BWD", "auto")
-    if mode == "pallas":
-        return True
-    if mode == "reference":
-        return False
-    return head_dim <= 64
+
+def _use_pallas_bwd(head_dim: int, seq_len: int = 0, itemsize: int = 2) -> bool:
+    """Whether the backward of ``flash_attention`` is the pallas pair
+    (``flash_bwd_dq``, ``flash_bwd_dkv``) or ``reference_attention``
+    rematerialised: the pair wherever its whole-sequence blocks fit the
+    compiler's scoped fast memory, a function of the shapes alone. The pair
+    is the faster wherever it compiles (bare on the v5e at
+    ``bf16[2,2048,16,128]``: 0.45 + 0.60 ms a call against 7.4, PR 30; at
+    head_dim 64 it has been the rule since before that), so the reference
+    backward is the fallback for a sequence the pair cannot hold. It costs
+    S x S float32 arrays there (19 GB each at 32 heads of 12,288 positions):
+    past the bound a gradient compiles, and fits the chip only at a few heads.
+
+    With the head size alone (``benchmarks/jobs/train.py`` prints that
+    answer) the answer for a sequence short enough: every head size the
+    kernels are given has one."""
+    row = _lanes(head_dim) * itemsize
+    return any(row <= widest and seq_len * row <= room
+               for widest, room in _BWD_WHOLE_SEQ_BYTES)
 
 
 def _fa_bwd(causal, interpret, res, g):

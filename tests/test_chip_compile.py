@@ -62,18 +62,13 @@ def test_flash_forward_1b_shape(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("shape,bwd_env", [
-    ((8, 1024, 16, 64), None),        # 350m: pallas backward by the rule
-    ((4, 2048, 16, 128), "pallas"),   # 1b: pallas backward when asked for
+@pytest.mark.parametrize("shape", [
+    (8, 1024, 16, 64),     # 350m
+    (4, 2048, 16, 128),    # 1b
 ], ids=["hd64", "hd128"])
-def test_flash_forward_and_pallas_backward(one_chip, monkeypatch, shape,
-                                           bwd_env):
+def test_flash_forward_and_pallas_backward(one_chip, shape):
+    """The pallas backward by the rule, at both head sizes."""
     from ray_tpu.ops.attention import flash_attention
-
-    if bwd_env:
-        monkeypatch.setenv("RAY_TPU_FLASH_BWD", bwd_env)
-    else:
-        monkeypatch.delenv("RAY_TPU_FLASH_BWD", raising=False)
 
     def loss(q, k, v):
         return flash_attention(q, k, v, True).astype(jnp.float32).sum()
@@ -82,6 +77,13 @@ def test_flash_forward_and_pallas_backward(one_chip, monkeypatch, shape,
         *_qkv(shape, one_chip)).compile()
     # the forward, the dq kernel and the dk/dv kernel
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def _custom_calls(text):
+    """The instruction each custom call of a compiled text is:
+    ``%jvp_flash_fwd_.1``."""
+    return [line.split(" = ")[0] for line in text.splitlines()
+            if "tpu_custom_call" in line]
 
 
 def _flash_grads(q, k, v):
@@ -93,45 +95,45 @@ def _flash_grads(q, k, v):
 
 @pytest.mark.parametrize("shape,backward", [
     ((4, 2048, 32, 64), True),     # cell 1: b4 x 2048, 32 heads of 64
-    ((2, 2048, 16, 128), False),   # cell 4, one chip's rows (head_dim 128:
-                                   # the backward is reference_attention)
+    ((2, 2048, 16, 128), True),    # cell 4, one chip's rows (head_dim 128)
     ((1, 512, 32, 64), True),      # cell 1's gradient check
     ((1, 256, 16, 128), False),    # the engine's prefill, cell 3's bucket
     ((1, 128, 16, 128), False),    # ... and cell 5's: one block
 ], ids=["cell1", "cell4-a-chip", "cell1-check", "engine-256", "engine-128"])
-def test_flash_kernels_at_the_cells_own_shapes(one_chip, monkeypatch, shape,
-                                               backward):
+def test_flash_kernels_at_the_cells_own_shapes(one_chip, shape, backward):
     """Each kernel is one custom call under its own name, at the blocks the
     kernels choose for the shape."""
     from ray_tpu.ops.attention import flash_attention
 
-    monkeypatch.delenv("RAY_TPU_FLASH_BWD", raising=False)
     fn = _flash_grads if backward else (
         lambda q, k, v: flash_attention(q, k, v, True))
     text = jax.jit(fn).lower(*_qkv(shape, one_chip)).compile().as_text()
     names = ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"][0 if backward
                                                            else 2:]
-    # the instruction each custom call is: %jvp_flash_fwd_.1 = ...
-    made = [line.split(" = ")[0] for line in text.splitlines()
-            if "tpu_custom_call" in line]
+    made = _custom_calls(text)
     assert sorted(n for m in made for n in names if n in m) == names
     assert len(made) == len(names)
 
 
-def test_flash_kernels_fast_memory_at_8k(one_chip, monkeypatch):
-    """``smollm2-1.7b.train-8k``'s shape (b1 x 8192, 32 heads of 64): the
-    kernels keep whole-sequence K/V (and Q/dO in dk/dv) blocks in fast
-    memory, so this is where they stop fitting first. Forward and the pallas
-    backward compile under the compiler's own limit, and what each needs of it
-    is printed (``-s``): the least whole MiB of scoped VMEM it compiles
-    under."""
+@pytest.mark.parametrize("shape", [(1, 8192, 32, 64), (1, 12288, 16, 128)],
+                         ids=["64-at-8k", "128-at-the-bound"])
+def test_flash_kernels_fast_memory(one_chip, shape):
+    """``smollm2-1.7b.train-8k``'s shape (b1 x 8192, 32 heads of 64), and the
+    longest sequence the backward's rule admits in bfloat16 at head_dim 128
+    (``ops/attention.py:_use_pallas_bwd``; a head of 64 lies in the same 128
+    lanes, and the test below compiles its bound): the kernels keep
+    whole-sequence K/V (and Q/dO in dk/dv) blocks in fast memory. Forward and
+    the pallas backward compile under the compiler's own limit, and what each
+    needs of it is printed (``-s``): the least whole MiB of scoped VMEM it
+    compiles under."""
     import sys
 
     from ray_tpu.ops.attention import flash_attention
 
     mod = sys.modules[flash_attention.__module__]
-    monkeypatch.delenv("RAY_TPU_FLASH_BWD", raising=False)
-    shape = B, S, H, D = (1, 8192, 32, 64)
+    B, S, H, D = shape
+    assert mod._use_pallas_bwd(D, S)
+    assert mod._use_pallas_bwd(D, S + 512) is (S < 12288)
     q, k, v = _qkv(shape, one_chip)
     text = jax.jit(_flash_grads).lower(q, k, v).compile().as_text()
     assert text.count("tpu_custom_call") == 3
@@ -167,6 +169,65 @@ def test_flash_kernels_fast_memory_at_8k(one_chip, monkeypatch):
     print(f"flash kernels at bf16{list(shape)}: scoped VMEM needed, MiB of "
           f"{default_mib}: {need}")
     assert max(need.values()) <= default_mib
+
+
+def _pair(S, H, D, dtype, sharding):
+    """The backward pair alone, compiled for the chip."""
+    import sys
+
+    from ray_tpu.ops.attention import flash_attention
+
+    mod = sys.modules[flash_attention.__module__]
+    x = jax.ShapeDtypeStruct((1, S, H, D), dtype, sharding=sharding)
+    lse = jax.ShapeDtypeStruct((H, 1, S), jnp.float32, sharding=sharding)
+    return jax.jit(lambda *a: mod.flash_attention_bwd(*a, True)).lower(
+        x, x, x, x, lse, x).compile()
+
+
+# (head_dim, dtype, the longest sequence the rule admits, one a little further
+# at which the pair no longer compiles at 16 heads)
+BOUNDS = [(64, jnp.bfloat16, 12288, 16384), (128, jnp.bfloat16, 12288, 13312),
+          (256, jnp.bfloat16, 4096, 5120), (128, jnp.float32, 4096, 6144),
+          (256, jnp.float32, 1536, 2560)]
+
+
+@pytest.mark.parametrize("D,dtype,longest,too_long", BOUNDS, ids=[
+    "64", "128", "256", "128-float32", "256-float32"])
+def test_flash_backward_rule_stops_where_the_pair_still_compiles(
+        one_chip, D, dtype, longest, too_long):
+    """The rule's bound on the sequence, a tier for each width of a row in
+    fast memory: at the longest sequence it admits the pair compiles, with
+    few heads and with many (XLA lays a small operand out otherwise: at 2 to
+    4 heads even 16,384 positions of head_dim 128 compile), and a little
+    further it does not, which is why the bound is there."""
+    import sys
+
+    from ray_tpu.ops.attention import flash_attention
+
+    mod = sys.modules[flash_attention.__module__]
+    itemsize = jnp.dtype(dtype).itemsize
+    assert mod._use_pallas_bwd(D, longest, itemsize)
+    assert not mod._use_pallas_bwd(D, longest + 512, itemsize)
+    for heads in (16, 64):
+        _pair(longest, heads, D, dtype, one_chip)
+    with pytest.raises(Exception, match="vmem"):
+        _pair(too_long, 16, D, dtype, one_chip)
+
+
+def test_flash_backward_past_the_bound_takes_the_fallback(one_chip):
+    """Past the rule's bound a gradient still compiles: at head_dim 128 the
+    next multiple of 512 after 12,288 positions holds the forward kernel
+    alone (the backward is ``reference_attention``'s, S x S arrays in HBM:
+    two heads here; at a cell's 16 to 32 heads they would not fit the
+    chip)."""
+    from ray_tpu.ops.attention import flash_attention
+
+    text = jax.jit(jax.value_and_grad(
+        lambda *a: flash_attention(*a, True).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).lower(
+            *_qkv((1, 12288 + 512, 2, 128), one_chip)).compile().as_text()
+    made = _custom_calls(text)
+    assert len(made) == 1 and "flash_fwd" in made[0]
 
 
 def _on(tree, sharding):

@@ -34,8 +34,8 @@ FLASH_IDS = ["-".join(str(x) for x in c[:3]) + ("" if c[3] is None
 
 def _flash_case(monkeypatch, S, D, blocks, seed):
     """bfloat16 operands as the cells send them, the reference's in float32;
-    the pallas backward at every head size."""
-    monkeypatch.setenv("RAY_TPU_FLASH_BWD", "pallas")
+    the pallas backward at every head size (the rule's own choice at these
+    lengths)."""
     if blocks is not None:
         monkeypatch.setattr(ATTENTION, "_blocks", lambda seq_len: blocks)
     B, H = 1, 2
@@ -119,13 +119,12 @@ def _walk(jaxpr):
 
 
 @pytest.mark.parametrize("D", [64, 128])
-def test_flash_kernels_feed_the_mxu_bfloat16(monkeypatch, D):
+def test_flash_kernels_feed_the_mxu_bfloat16(D):
     """The mechanism, held on the CPU: with bfloat16 inputs each of the three
     kernels is ONE pallas_call under its own name, every product in it takes
     bfloat16 operands and accumulates in float32, and the exponentials and
     whatever a loop carries (the accumulators, the running max and sum) are
     float32."""
-    monkeypatch.setenv("RAY_TPU_FLASH_BWD", "pallas")
     x = jnp.zeros((1, 1024, 2, D), jnp.bfloat16)
 
     def fwd_and_bwd(q, k, v):
@@ -157,6 +156,107 @@ def test_flash_kernels_feed_the_mxu_bfloat16(monkeypatch, D):
             carried = [v.aval for v in e.outvars
                        if jnp.issubdtype(v.aval.dtype, jnp.floating)]
             assert carried and all(a.dtype == jnp.float32 for a in carried)
+
+
+def _kernel_names(jaxpr):
+    """The names of a jaxpr's ``pallas_call``s, nested ones too, as bound."""
+    return [e.params["name"] for e in _walk(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+def _grad_jaxpr(S, D, Dv=None, dtype=jnp.bfloat16):
+    """The jaxpr of a gradient through ``flash_attention`` (traced, never
+    run: a long sequence costs nothing here)."""
+    qk = jnp.zeros((1, S, 2, D), dtype)
+    v = jnp.zeros((1, S, 2, Dv or D), dtype)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, False).astype(jnp.float32).sum()
+
+    return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(qk, qk, v).jaxpr
+
+
+PAIR = ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
+@pytest.mark.parametrize("S,D,Dv,dtype,pair", [
+    (256, 64, 64, jnp.bfloat16, True),
+    (256, 128, 128, jnp.bfloat16, True),
+    (12288, 128, 128, jnp.bfloat16, True),    # the longest the rule admits
+    (12800, 128, 128, jnp.bfloat16, False),   # the next multiple of 512
+    (12288, 64, 64, jnp.bfloat16, True),      # 64 lies in 128 lanes: as 128
+    (12800, 64, 64, jnp.bfloat16, False),
+    (4096, 256, 256, jnp.bfloat16, True),     # a wider row leaves less room
+    (4608, 256, 256, jnp.bfloat16, False),
+    (4096, 128, 128, jnp.float32, True),      # float32 rows are twice as wide
+    (4608, 128, 128, jnp.float32, False),
+    (1536, 256, 256, jnp.float32, True),
+    (2048, 256, 256, jnp.float32, False),
+    (256, 192, 128, jnp.bfloat16, False),     # latent attention's head sizes
+], ids=["64", "128", "128-at-the-bound", "128-over-the-bound",
+        "64-at-the-bound", "64-over-the-bound", "256-at-the-bound",
+        "256-over-the-bound", "128-float32-at-the-bound",
+        "128-float32-over-the-bound", "256-float32-at-the-bound",
+        "256-float32-over-the-bound", "192-with-values-of-128"])
+def test_flash_backward_rule_is_a_function_of_the_shapes(S, D, Dv, dtype,
+                                                         pair):
+    """The pallas pair wherever it takes the shape (one head size, and
+    whole-sequence blocks that fit fast memory:
+    ``tests/test_chip_compile.py`` compiles the bound), else the forward
+    kernel with ``reference_attention``'s backward."""
+    assert sorted(_kernel_names(_grad_jaxpr(S, D, Dv, dtype))) == (
+        PAIR if pair else ["flash_fwd"])
+    if D == Dv:
+        assert ATTENTION._use_pallas_bwd(
+            D, S, jnp.dtype(dtype).itemsize) is pair
+    assert ATTENTION._use_pallas_bwd(D)   # what the benchmark's note prints
+
+
+@pytest.mark.parametrize("blocks", [(256, 128), (128, 256), (128, 128)],
+                         ids=["256x128", "128x256", "128x128"])
+def test_flash_backward_is_traced_at_the_patched_blocks(monkeypatch, blocks):
+    """The jitted backward keys its trace on the blocks too: a trace at the
+    kernels' own blocks, made first, does not stand in for the patched ones
+    (``test_flash_grads_match``'s unequal-block cases share shape, dtype,
+    ``causal`` and ``interpret`` with ``512-64-True``). Read off the grids of
+    the three ``pallas_call``s: a row of programs for each q block (forward,
+    dq) or k block (dkv)."""
+    S, heads = 512, 2
+
+    def grids():
+        return {e.params["name"]: tuple(e.params["grid_mapping"].grid)
+                for e in _walk(_grad_jaxpr(S, 64))
+                if e.primitive.name == "pallas_call"}
+
+    def want(block_q, block_k):
+        return {"flash_fwd": (heads, S // block_q),
+                "flash_bwd_dq": (heads, S // block_q),
+                "flash_bwd_dkv": (heads, S // block_k)}
+
+    assert grids() == want(*ATTENTION._blocks(S))
+    monkeypatch.setattr(ATTENTION, "_blocks", lambda seq_len: blocks)
+    assert grids() == want(*blocks)
+
+
+def test_flash_backward_at_head_dim_128_holds_no_score_array():
+    """What cell 4's step loses: at head_dim 128 the gradient is the three
+    kernels once each, and no float32 array with two sequence-sized
+    dimensions (``reference_attention``'s scores, softmax, dP and dS) is
+    left outside them."""
+    S = 1024
+
+    def square(jaxpr):
+        return [v.aval for e in _walk(jaxpr) for v in e.outvars
+                if getattr(v.aval, "dtype", None) == jnp.float32
+                and list(v.aval.shape).count(S) >= 2]
+
+    jaxpr = _grad_jaxpr(S, 128)
+    assert sorted(_kernel_names(jaxpr)) == PAIR
+    assert not square(jaxpr)
+    # the check can see one: the reference's own backward
+    assert square(jax.make_jaxpr(jax.grad(lambda q: reference_attention(
+        q, q, q).astype(jnp.float32).sum()))(
+            jnp.zeros((1, S, 2, 128), jnp.bfloat16)).jaxpr)
 
 
 def test_ring_attention_matches_reference():
@@ -221,6 +321,42 @@ def test_train_step_dp_fsdp_tp():
         losses.append(float(loss))
     assert all(np.isfinite(losses))
     assert losses[-1] < losses[0]  # memorizing one batch
+
+
+def test_train_step_backward_inside_the_shard_map_matches_xla():
+    """Cell 4's path at toy size: head_dim 128 with repeated K/V heads through
+    ``TrainStepBundle`` on a ``data=2`` mesh, where ``attention`` wraps the
+    kernel in a ``shard_map`` and the pallas backward runs inside the map's
+    transpose on each device's rows. Loss and every gradient leaf against the
+    same step through ``reference_attention``, and the program holds the
+    backward pair."""
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        CONFIGS["tiny"], d_model=256, n_heads=2, n_kv_heads=1,
+        attention_impl="flash_interpret", remat=True)
+    assert cfg.head_dim == 128
+    mesh = create_mesh({"data": 2, "fsdp": 1, "seq": 1, "tensor": 1},
+                       devices=jax.devices()[:2])
+    bundle = TrainStepBundle(cfg, mesh)
+    plain = TrainStepBundle(dataclasses.replace(cfg, attention_impl="xla"),
+                            mesh)
+    params, _ = bundle.init(jax.random.PRNGKey(0))
+    batch = bundle.make_batch(np.random.default_rng(0), batch_size=4,
+                              seq_len=128)
+    names = _kernel_names(jax.make_jaxpr(bundle._fwd_bwd)(params, batch).jaxpr)
+    # under remat the forward runs again inside the backward
+    assert sorted(set(names)) == PAIR
+    assert names.count("flash_bwd_dq") == names.count("flash_bwd_dkv") \
+        == cfg.n_layers
+    loss, grads = bundle._fwd_bwd(params, batch)
+    want_loss, want = plain._fwd_bwd(params, batch)
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-3)
+    for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        # both sides round to bfloat16 at every use; a leaf's distance
+        # relative to its norm, as the cells' gradient check takes it
+        assert np.linalg.norm(got - ref) <= 2e-2 * np.linalg.norm(ref)
 
 
 def test_param_shardings_cover_mesh():
