@@ -69,8 +69,42 @@ from ``__init__``, so two snapshots subtract):
   ``emit_ms`` add up to ``step_ms``;
   ``between_steps_ms`` is the caller's time from one ``step()`` to the next
   while work was left;
+- what each blocking read waited for, over the whole of a window and with no
+  profiler: ``prefill_phase_ms`` and ``decode_phase_ms``, with ``phase_ms``
+  their sum. A read of a prefill phase's first tokens waits behind that
+  phase's ``jit_prefill`` calls and their one sampler call, a read of a
+  decode step's tokens behind ``jit_decode_step`` and its sampler call; each
+  adds the time from the end of the read before it (or from its own
+  dispatch, if that came later: the device had run dry) to the moment its
+  ``np.asarray(tokens)`` returned. While the reads truly block
+  (``readback_ms / phase_ms`` near 1 less the host's share of a step: the
+  device sets the pace) that is the device's time for the phase to a copy's
+  latency. ``prefill_phase_calls`` and ``decode_phase_calls`` count the
+  model's calls those reads waited behind (``admitted`` and ``decode_steps``
+  again, but moving with the read and not at dispatch, so a window's two
+  deltas cover the same calls): ``prefill_phase_ms / prefill_phase_calls``
+  and ``decode_phase_ms / decode_phase_calls`` are a prefill call's and a
+  decode step's time with their share of the sampler. When the host arrives
+  late a read returns at once and the host's time lands on it: a prefill
+  phase shorter than the host's own way from one read to the next (dispatch,
+  emit, the caller's hop: 5-8 ms) reads as that long, and the decode step
+  behind it as much shorter; with ``readback_ms / phase_ms`` near 0 (the
+  device waits for the host; on the CPU backend, which runs a program inside
+  its dispatch, always) the split by kind says nothing. ``phase_ms`` stays
+  the busy time either way;
 - per request, summed: ``queue_wait_ms`` (``add_request`` to first
   admission) and ``ttft_ms`` (``add_request`` to first token);
+- the gaps a request sees between its tokens, each token dated by the moment
+  the read that brought it returned (what a streaming client would see):
+  ``itl_ms`` (their sum), ``itl_tokens`` (their number: every emitted token
+  but a request's first) and the ladder ``itl_over_25ms``, ``_50ms``,
+  ``_100ms``, ``_200ms``, ``_400ms``, ``_800ms`` (gaps strictly longer, so
+  no rung counts more than the one before it). A quantile is bracketed by
+  two rungs: the p-quantile lies at or under the first rung whose count is
+  at most ``(1 - p) x itl_tokens``, and over the rung before it. A decode
+  step alone is 14-21 ms, so a gap over 50 ms was spent behind somebody's
+  prefill phase; a preempted request's gap across its second prefill counts,
+  as its client waited for it;
 - what routing did in decode, for a model with experts (all 0 for a dense
   one): ``moe_decode_layer_steps`` (decode steps x expert layers) and, summed
   over those, ``moe_decode_assignments`` (active slots x top_k),
@@ -88,8 +122,7 @@ from ``__init__``, so two snapshots subtract):
   slot; 0 otherwise): ``shared_kv_live_tokens`` and ``shared_kv_read_tokens``
   (the same two for the shared layer's pages, counted once a decode step, not
   once a layer that reads them), ``window_live_tokens`` (filled ring entries
-  the active slots attend over, a step and window layer),
-  ``ssm_decode_layer_steps`` (decode steps x layers with recurrent state) and
+  the active slots attend over, a step and window layer) and
   ``prefill_cross_rows`` (rows the cross-decoder computed in prefill: one a
   call, against ``prefill_batch_tokens`` for the self-decoder).
 
@@ -110,12 +143,18 @@ unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
 per layer in the newest decode step the host has read, models with experts
 only; ``live_tokens``: positions the step attends over through the block
 tables, models with a latent cache or with ``layer_kinds`` only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
-every sampler call of the step before; around a shape's first use,
-``.compile``. With
+every sampler call of the step before. ``.readback`` names what it waited
+for (arguments ``kind``: ``prefill`` or ``decode``; ``calls``: the prefill
+calls behind it, 1 for a decode step; ``bucket``: the largest of those
+calls' buckets, 0 for a decode step; ``rows``), and its end is the moment
+the phase counters and the token gaps are dated by: in a trace it lies a
+copy's latency after the end of the last ``jit_sample_tokens`` before it on
+the device plane. Around a shape's first use, ``.compile``. With
 ``RAY_TPU_ENABLE_TRACING`` a finished request also leaves ``engine.queued``,
-``engine.prefill`` and ``engine.decode`` spans (``request_id``) under the
-span that called ``add_request`` (``/api/timeline``). An operator's guide is
-in ``ray_tpu/serve/README.md``.
+``engine.prefill`` and ``engine.decode`` spans (``request_id``; the last
+also ``tokens`` and ``max_gap_ms``, the longest gap between two of its
+tokens: WHICH request stalled) under the span that called ``add_request``
+(``/api/timeline``). An operator's guide is in ``ray_tpu/serve/README.md``.
 """
 
 from __future__ import annotations
@@ -132,6 +171,12 @@ import numpy as np
 from ray_tpu.llm.config import EngineConfig, LLMConfig, SamplingParams
 from ray_tpu.llm.tokenizer import get_tokenizer
 from ray_tpu.util import goodput, tracing
+
+# the rungs of the ladder ``itl_over_<n>ms``: a gap between two tokens of a
+# request is counted on every rung it is strictly longer than
+_ITL_RUNGS_MS = (25, 50, 100, 200, 400, 800)
+_ITL_RUNGS_NS = np.array(_ITL_RUNGS_MS, np.int64) * 1_000_000
+_ITL_KEYS = tuple(f"itl_over_{n}ms" for n in _ITL_RUNGS_MS)
 
 
 @dataclasses.dataclass
@@ -150,6 +195,10 @@ class _Request:
     t_added: float = dataclasses.field(default_factory=time.perf_counter)
     t_admitted: float = 0.0
     t_first_token: float = 0.0
+    # perf_counter_ns at which its newest token reached the host (0 = none
+    # yet), and the longest gap between two of its tokens so far
+    t_last_token_ns: int = 0
+    max_gap_ns: int = 0
     # tracing.current_context() of add_request's caller
     trace_ctx: Optional[Tuple[str, str]] = None
 
@@ -166,6 +215,10 @@ class _Unread:
     """One sampler call whose tokens the host has not read."""
     tokens: Any  # [B] int32 on the device, its copy to the host started
     rows: List[Tuple[_Request, int]]  # whose token sits at which slot
+    kind: str  # "prefill" (a phase's first tokens) or "decode" (a step's)
+    calls: int  # model calls it waits behind: prefill calls, or 1 decode step
+    bucket: int  # the largest of the prefill calls' length buckets; 0 = decode
+    sent_ns: int  # perf_counter_ns at its dispatch
     moe_load: Any = None  # a decode step's routing, outside the donated cache
 
 
@@ -251,6 +304,10 @@ class JaxLLMEngine:
         self._compile_watch = goodput.CompileWatch()
         # perf_counter_ns at the end of the last step() that left work
         self._step_ended_ns: Optional[int] = None
+        # perf_counter_ns at the end of the newest phase (_phase), and of the
+        # newest blocking read: where the next read's wait starts
+        self._phase_ended_ns = 0
+        self._read_ended_ns = 0
         # see the module docstring; snapshots are subtracted key by key, so
         # every key is here from the start and none ever decreases
         self.metrics = {
@@ -263,12 +320,14 @@ class JaxLLMEngine:
             "emit_ms": 0.0, "between_steps_ms": 0.0,
             "queue_wait_ms": 0.0, "ttft_ms": 0.0,
             "overlapped_steps": 0, "dropped_tokens": 0,
+            "prefill_phase_ms": 0.0, "decode_phase_ms": 0.0, "phase_ms": 0.0,
+            "prefill_phase_calls": 0, "decode_phase_calls": 0,
+            "itl_ms": 0.0, "itl_tokens": 0, **dict.fromkeys(_ITL_KEYS, 0),
             "moe_decode_layer_steps": 0, "moe_decode_assignments": 0,
             "moe_decode_experts_touched": 0, "moe_decode_max_load": 0,
             "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0,
             "shared_kv_live_tokens": 0, "shared_kv_read_tokens": 0,
-            "window_live_tokens": 0, "ssm_decode_layer_steps": 0,
-            "prefill_cross_rows": 0}
+            "window_live_tokens": 0, "prefill_cross_rows": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
 
@@ -450,13 +509,15 @@ class JaxLLMEngine:
     @contextlib.contextmanager
     def _phase(self, name: str, **attrs):
         """One phase of ``step()``: the span ``engine.<name>`` on the
-        profiler's clock and its host time in ``metrics[<name>_ms]``."""
+        profiler's clock, its host time in ``metrics[<name>_ms]`` and its end
+        in ``_phase_ended_ns``."""
         t0 = time.perf_counter_ns()
         try:
             with tracing.annotate("engine." + name, **attrs):
                 yield
         finally:
-            self.metrics[name + "_ms"] += (time.perf_counter_ns() - t0) / 1e6
+            self._phase_ended_ns = t1 = time.perf_counter_ns()
+            self.metrics[name + "_ms"] += (t1 - t0) / 1e6
 
     def _first_use(self, program: str, bucket: int = 0):
         """Around a model program's call: the span ``engine.compile`` when
@@ -551,7 +612,8 @@ class JaxLLMEngine:
             if self.mcfg.layer_kinds:  # the cross-decoder ran one row a call
                 m["prefill_cross_rows"] += len(admitted)
             self._active[slots] = True
-            self._sent(firsts, admitted)
+            self._sent(firsts, admitted, "prefill", len(admitted),
+                       max(buckets))
 
         # 2) one decode step for all active slots, on the tokens the device
         # holds: the host advances what does not depend on a token's value
@@ -575,8 +637,6 @@ class JaxLLMEngine:
                         m["window_live_tokens"] += int(np.minimum(
                             self._seq_lens[self._active] + 1,
                             self.mcfg.window).sum())
-                        m["ssm_decode_layer_steps"] += \
-                            self.mcfg.layer_kinds.count("mamba")
                     with self._first_use("decode"):
                         logits, self.cache = mr.decode_step(
                             self.params, self.mcfg, self.cache, self._tokens,
@@ -594,7 +654,7 @@ class JaxLLMEngine:
                 self._seq_lens[self._active] += 1
                 self._sent(self._tokens, [
                     self._slots[i] for i in np.flatnonzero(self._active)],
-                    load)
+                    "decode", 1, moe_load=load)
 
         # 3) the steps before this one: the device has this call's programs
         # queued behind them, so the read, the emit and the caller's way back
@@ -603,13 +663,17 @@ class JaxLLMEngine:
             self._read()
         return overlapped
 
-    def _sent(self, tokens, reqs: List[_Request], moe_load=None) -> None:
-        """A sampler call is on its way with a token for each of ``reqs``.
-        One that ends by length with it ends at a step the host can count:
-        its slot and pages are free at once (the device runs its programs in
-        order, so whatever is queued behind this call may have them)."""
+    def _sent(self, tokens, reqs: List[_Request], kind: str, calls: int,
+              bucket: int = 0, moe_load=None) -> None:
+        """A sampler call is on its way with a token for each of ``reqs``,
+        behind ``calls`` prefill calls (the largest at ``bucket``) or one
+        decode step. A request that ends by length with it ends at a step the
+        host can count: its slot and pages are free at once (the device runs
+        its programs in order, so whatever is queued behind this call may
+        have them)."""
         self._unread.append(_Unread(
-            tokens, [(r, r.slot) for r in reqs], moe_load))
+            tokens, [(r, r.slot) for r in reqs], kind, calls, bucket,
+            time.perf_counter_ns(), moe_load))
         for r in reqs:
             r.in_flight += 1
             if self._ends_by_length(r, len(r.generated) + r.in_flight):
@@ -621,22 +685,47 @@ class JaxLLMEngine:
                 >= self.ecfg.max_model_len)
 
     def _read(self) -> None:
-        """Block on the oldest unread sampler call and emit its tokens."""
+        """Block on the oldest unread sampler call, lay the time since the
+        read before it (or since its own dispatch, if that came later) to its
+        kind, and emit its tokens as of the moment the read returned."""
+        m = self.metrics
         u = self._unread.popleft()
         self._earlier = max(self._earlier - 1, 0)
-        with self._phase("readback"):  # blocks until the device is done
-            toks = np.asarray(u.tokens)
+        with self._phase("readback", kind=u.kind, calls=u.calls,
+                         bucket=u.bucket, rows=len(u.rows)):
+            toks = np.asarray(u.tokens)  # blocks until the device is done
+        now = self._phase_ended_ns
+        waited = (now - max(self._read_ended_ns, u.sent_ns)) / 1e6
+        self._read_ended_ns = now
+        m[u.kind + "_phase_ms"] += waited
+        m[u.kind + "_phase_calls"] += u.calls
+        m["phase_ms"] += waited
         with self._phase("emit"):
             if u.moe_load is not None:
                 self._count_routing(np.asarray(u.moe_load))
+            # a stop token read since the dispatch ended a request: its slot
+            # ran on, for nobody
+            live = [req for req, _ in u.rows if not req.finished]
+            m["dropped_tokens"] += len(u.rows) - len(live)
+            self._count_gaps(live, now)
             for req, slot in u.rows:
                 req.in_flight -= 1
-                if req.finished:
-                    # a stop token read since the dispatch ended it: the slot
-                    # ran on, for nobody
-                    self.metrics["dropped_tokens"] += 1
-                else:
-                    self._emit(req, int(toks[slot]))
+                if not req.finished:
+                    self._emit(req, int(toks[slot]), now)
+
+    def _count_gaps(self, reqs: List[_Request], now: int) -> None:
+        """The gap each of ``reqs`` saw before the token that reached the
+        host at ``now`` (perf_counter_ns), into ``itl_ms``, ``itl_tokens``
+        and the ladder. A request's first token has no gap; a preempted
+        request's gap spans its second prefill."""
+        m = self.metrics
+        last = np.array([r.t_last_token_ns for r in reqs], np.int64)
+        gaps = now - last[last > 0]
+        m["itl_tokens"] += len(gaps)
+        m["itl_ms"] += float(gaps.sum()) / 1e6
+        over = (gaps[:, None] > _ITL_RUNGS_NS).sum(axis=0)
+        for key, n in zip(_ITL_KEYS, over.tolist()):
+            m[key] += n
 
     def _drain(self) -> None:
         """Read everything in flight: whoever reads or changes slot state
@@ -688,12 +777,16 @@ class JaxLLMEngine:
         self._release(req)
         self._waiting.appendleft(req)
 
-    def _emit(self, req: _Request, token: int) -> None:
+    def _emit(self, req: _Request, token: int, now: int) -> None:
+        """``token`` reached the host at ``now`` (perf_counter_ns)."""
         req.generated.append(token)
         self.metrics["generated_tokens"] += 1
         if not req.t_first_token:
-            req.t_first_token = time.perf_counter()
+            req.t_first_token = now / 1e9
             self.metrics["ttft_ms"] += (req.t_first_token - req.t_added) * 1e3
+        if req.t_last_token_ns:
+            req.max_gap_ns = max(req.max_gap_ns, now - req.t_last_token_ns)
+        req.t_last_token_ns = now
         if (token == self.tokenizer.eos_token_id
                 or token in req.params.stop_token_ids):
             req.finished, req.finish_reason = True, "stop"
@@ -703,26 +796,29 @@ class JaxLLMEngine:
             self._release(req)  # nothing left to free if it ended by length
             self._requests.pop(req.request_id, None)
             if tracing.enabled():
-                self._record_request_spans(req)
+                self._record_request_spans(req, now / 1e9)
         self._outputs.append(RequestOutput(
             req.request_id, list(req.generated), req.finished,
             req.finish_reason))
 
-    def _record_request_spans(self, req: _Request) -> None:
-        """A finished request's life as three spans in the GCS trace table,
-        children of the span that called ``add_request``."""
-        now = time.perf_counter()
-        wall = time.time() - now  # perf_counter -> the spans' wall clock
+    def _record_request_spans(self, req: _Request, now: float) -> None:
+        """A request that finished at ``now`` (perf_counter seconds) as three
+        spans in the GCS trace table, children of the span that called
+        ``add_request``; ``engine.decode`` says how many tokens it got and
+        the longest gap between two of them."""
+        wall = time.time() - time.perf_counter()  # -> the spans' wall clock
         ids = {}
         if req.trace_ctx is not None:
             ids = {"trace_id": req.trace_ctx[0], "parent_id": req.trace_ctx[1]}
-        for name, start, end in (
-                ("engine.queued", req.t_added, req.t_admitted),
-                ("engine.prefill", req.t_admitted, req.t_first_token),
-                ("engine.decode", req.t_first_token, now)):
+        for name, start, end, attrs in (
+                ("engine.queued", req.t_added, req.t_admitted, {}),
+                ("engine.prefill", req.t_admitted, req.t_first_token, {}),
+                ("engine.decode", req.t_first_token, now,
+                 {"tokens": len(req.generated),
+                  "max_gap_ms": req.max_gap_ns / 1e6})):
             tracing.record_span(name, start + wall, end + wall,
                                 category="llm", request_id=req.request_id,
-                                **ids)
+                                **ids, **attrs)
 
     # -- PD disaggregation (KV page export / import) -----------------------
     # Reference: serving_patterns/prefill_decode/pd_server.py + the vLLM
@@ -815,6 +911,8 @@ class JaxLLMEngine:
                        state["params"], trace_ctx=tracing.current_context())
         # queued and prefilled elsewhere: neither wait is this engine's
         req.t_admitted = req.t_first_token = req.t_added
+        # its client has the first token: the next one's gap starts here
+        req.t_last_token_ns = time.perf_counter_ns()
         req.generated = list(state["generated"])
         req.slot = free_slots[0]
         req.pages = [self._free_pages.popleft() for _ in range(n_pages)]

@@ -8,6 +8,7 @@ import os
 import sys
 import types
 
+import numpy as np
 import pytest
 
 from ray_tpu.llm.config import EngineConfig, LLMConfig, SamplingParams
@@ -23,6 +24,13 @@ KEYS = {
     "between_steps_ms", "queue_wait_ms", "ttft_ms",
     # steps dispatched behind an unread one; tokens computed for nobody
     "overlapped_steps", "dropped_tokens",
+    # what each blocking read waited for, by kind, and both
+    "prefill_phase_ms", "decode_phase_ms", "phase_ms",
+    "prefill_phase_calls", "decode_phase_calls",
+    # the gaps between a request's tokens: sum, number, and how many were
+    # strictly longer than each rung
+    "itl_ms", "itl_tokens", "itl_over_25ms", "itl_over_50ms",
+    "itl_over_100ms", "itl_over_200ms", "itl_over_400ms", "itl_over_800ms",
     # what routing did; a dense model's (this one's) stay 0
     "moe_decode_layer_steps", "moe_decode_assignments",
     "moe_decode_experts_touched", "moe_decode_max_load",
@@ -31,7 +39,8 @@ KEYS = {
     # a model with layer_kinds: its shared layer's pages, rings, recurrent
     # rows and the cross-decoder's prefill rows; 0 without them
     "shared_kv_live_tokens", "shared_kv_read_tokens", "window_live_tokens",
-    "ssm_decode_layer_steps", "prefill_cross_rows"}
+    "prefill_cross_rows"}
+LADDER = tuple(f"itl_over_{n}ms" for n in (25, 50, 100, 200, 400, 800))
 PHASES = ("admit_ms", "prefill_dispatch_ms", "decode_dispatch_ms",
           "sample_dispatch_ms", "readback_ms", "emit_ms")
 SLOTS, BUCKET = 8, 16
@@ -122,6 +131,22 @@ def test_metrics_complete_numeric_monotone(engine):
     assert m["generated_tokens"] <= sum(4 + i for i in range(SLOTS + 2))
     assert m["between_steps_ms"] > 0
     assert m["compiles"] == 2  # one prefill bucket, decode
+    _phases_and_gaps_add_up(m, first_tokens=SLOTS + 2)
+
+
+def _phases_and_gaps_add_up(m, first_tokens):
+    """Every read's wait went to one kind; every emitted token but a
+    request's first saw one gap, counted on the rungs it is longer than."""
+    assert m["prefill_phase_ms"] > 0 and m["decode_phase_ms"] > 0
+    assert m["prefill_phase_ms"] + m["decode_phase_ms"] == pytest.approx(
+        m["phase_ms"])
+    # nothing is left unread: every call dispatched was waited for
+    assert m["prefill_phase_calls"] == m["admitted"]
+    assert m["decode_phase_calls"] == m["decode_steps"]
+    assert m["itl_tokens"] == m["generated_tokens"] - first_tokens > 0
+    rungs = [m["itl_tokens"]] + [m[k] for k in LADDER]
+    assert all(a >= b >= 0 for a, b in zip(rungs, rungs[1:]))
+    assert m["itl_ms"] > 0
 
 
 def test_prefill_counters_equal_the_hand_count(engine):
@@ -351,11 +376,25 @@ def test_benchmark_wrappers_still_see_step_and_sample(engine, recorder,
     assert reads == [2] * steps
 
 
-def test_finished_request_leaves_three_spans_under_its_parent(engine,
-                                                              monkeypatch):
+@pytest.fixture
+def llm_spans(monkeypatch):
+    """``RAY_TPU_ENABLE_TRACING`` for one test; call it for the ``llm`` spans
+    buffered since."""
     monkeypatch.setattr(tracing, "_enabled", None)   # restored afterwards,
     monkeypatch.setenv("RAY_TPU_ENABLE_TRACING", "0")  # with the flag
     tracing.enable()
+
+    def take():
+        with tracing._lock:
+            spans = [s for s in tracing._buffer if s["cat"] == "llm"]
+            tracing._buffer.clear()
+        return spans
+    yield take
+    take()
+
+
+def test_finished_request_leaves_three_spans_under_its_parent(engine,
+                                                              llm_spans):
     trace_id, parent = tracing.new_trace_id(), tracing.new_span_id()
     token = tracing.set_context(trace_id, parent)
     try:
@@ -363,14 +402,9 @@ def test_finished_request_leaves_three_spans_under_its_parent(engine,
     finally:
         tracing.reset_context(token)
     engine.add_request("bare", _prompt(7), SamplingParams(max_tokens=4))
-    try:
-        while engine.has_unfinished():
-            engine.step()
-        with tracing._lock:
-            spans = [s for s in tracing._buffer if s["cat"] == "llm"]
-    finally:
-        with tracing._lock:
-            tracing._buffer.clear()
+    while engine.has_unfinished():
+        engine.step()
+    spans = llm_spans()
     mine = [s for s in spans if s["request_id"] == "traced"]
     assert [s["name"] for s in mine] == [
         "engine.queued", "engine.prefill", "engine.decode"]
@@ -469,3 +503,203 @@ def test_a_full_engine_overlaps_every_step_but_the_first(engine):
     assert held[1] == [None, "r1", "r2", "r3", "r4", None, "r6", "r7"]
     assert held[2] == ["r8", None, "r2", "r3", "r4", "r9", None, "r7"]
     assert held[3][:2] + held[3][5:7] == ["r8", None, "r9", "r11"]
+
+
+# -- what a read waited for, and the gaps a request sees -----------------------
+
+
+def test_readback_names_what_it_waited_for(engine, recorder):
+    """Three requests admitted in one phase, buckets 16, 32 and 16: the read
+    of their first tokens waited behind three prefill calls, the largest at
+    32; the read of the decode step's tokens behind that one step."""
+    for i, n in enumerate((5, 20, 11)):
+        engine.add_request(f"r{i}", _prompt(n), SamplingParams(max_tokens=4))
+    engine.step()
+    assert not [n for _, n, _ in recorder if n.endswith("readback")]
+    engine.step()
+    reads = [a for _, n, a in recorder if n == "ray_tpu/engine.readback"]
+    assert reads == [
+        {"kind": "prefill", "calls": 3, "bucket": 32, "rows": 3},
+        {"kind": "decode", "calls": 1, "bucket": 0, "rows": 3}]
+    m = engine.metrics
+    assert (m["prefill_phase_calls"], m["decode_phase_calls"]) == (3, 1)
+
+
+def test_a_preempted_requests_gap_spans_its_second_prefill(params):
+    """Two slots, pages for one and a half long answers: a request sent back
+    to the queue is prefilled again from all its tokens, and the token that
+    second prefill gives it has a gap like any other, its client waited for
+    it. So every emitted token but a REQUEST's first has one, however many
+    times the request was admitted."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    tight = EngineConfig(max_num_seqs=2, max_model_len=64, page_size=16,
+                         num_pages=7, prefill_bucket_min=BUCKET)
+    eng = JaxLLMEngine(LLMConfig(
+        model_id="tiny", engine_config=tight,
+        model_overrides={"attention_impl": "xla"}), params=params, seed=0)
+    outs = eng.generate([list(range(3, 33)), list(range(40, 70))],
+                        SamplingParams(max_tokens=30))
+    m = eng.metrics
+    assert m["preempted"] >= 1 and m["admitted"] == 2 + m["preempted"]
+    assert m["generated_tokens"] == sum(len(o.token_ids) for o in outs)
+    _phases_and_gaps_add_up(m, first_tokens=2)
+
+
+class _Clock:
+    """Stands in for the engine module's ``time``: it moves only when the
+    device below makes a read wait."""
+
+    def __init__(self):
+        self.ns = 5_000_000_000
+
+    def perf_counter_ns(self):
+        return self.ns
+
+    def perf_counter(self):
+        return self.ns / 1e9
+
+    def time(self):
+        return 1.7e9 + self.ns / 1e9
+
+    def monotonic_ns(self):
+        return self.ns
+
+
+class _Late:
+    """A sampler call's tokens on a device that may still be busy: reading
+    them waits until the call is done."""
+
+    def __init__(self, tokens, clock, ready_ns):
+        self.tokens, self.clock, self.ready_ns = tokens, clock, ready_ns
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        self.clock.ns = max(self.clock.ns, self.ready_ns)
+        return np.asarray(self.tokens)
+
+
+class _Device:
+    """The engine's ``model_runner`` with the device's clock beside it: the
+    real programs compute at once, and each is said to have taken
+    ``cost_ms(program, bucket)`` on a device that runs them one after another
+    and is never ahead of the host's dispatch."""
+
+    def __init__(self, mr, clock, cost_ms):
+        self.mr, self.clock, self.cost_ms = mr, clock, cost_ms
+        self.busy_until = 0
+
+    def __getattr__(self, name):
+        fn = getattr(self.mr, name)
+
+        def call(*args, **kwargs):
+            args = [a.tokens if isinstance(a, _Late) else a for a in args]
+            out = fn(*args, **kwargs)
+            bucket = args[3].shape[1] if name == "prefill" else 0
+            self.busy_until = max(self.busy_until, self.clock.ns) \
+                + int(self.cost_ms(name, bucket) * 1e6)
+            if name == "sample_tokens":
+                return _Late(out, self.clock, self.busy_until)
+            return out
+        return call
+
+
+DECODE_MS, SAMPLE_MS, SHORT_MS = 10, 1, 5
+
+
+@pytest.fixture
+def timed(engine, monkeypatch):
+    """``timed(long_ms)``: the engine on a device whose decode step takes 10
+    ms, a sampler call 1, a prefill call 5 at the smallest bucket and
+    ``long_ms`` at any other, with a host that takes no time at all."""
+    from ray_tpu.llm import engine as engine_module
+
+    clock = _Clock()
+    monkeypatch.setattr(engine_module, "time", clock)
+
+    def build(long_ms):
+        def cost_ms(program, bucket):
+            return {"decode_step": DECODE_MS, "sample_tokens": SAMPLE_MS,
+                    "prefill": SHORT_MS if bucket == BUCKET else long_ms,
+                    }.get(program, 0)
+        engine._mr = _Device(engine._mr, clock, cost_ms)
+        return engine
+    return build
+
+
+def _add(engine, rid, n, max_tokens):
+    from ray_tpu.llm import engine as engine_module
+
+    engine.add_request(rid, _prompt(n), SamplingParams(max_tokens=max_tokens))
+    # the dataclass took the real clock's function when it was defined
+    engine._requests[rid].t_added = engine_module.time.perf_counter()
+
+
+@pytest.mark.parametrize("long_ms,over", [(40, 2), (200, 4), (800, 6)],
+                         ids=["40ms", "200ms", "800ms"])
+def test_a_decoder_waits_behind_anothers_prefill(timed, long_ms, over):
+    """``a`` decodes alone, a token every 11 ms (decode step + sampler).
+    ``b``'s prompt takes the 64 bucket: its prefill call of ``long_ms`` and
+    the phase's sampler call run between two of ``a``'s decode steps, so ONE
+    of ``a``'s gaps is ``long_ms`` + 12 and lands on every rung under it, and
+    the prefill reads waited ``long_ms`` + 1 more than before."""
+    engine = timed(long_ms)
+    m = engine.metrics
+    _add(engine, "a", 5, 12)
+    for _ in range(4):
+        engine.step()
+    a = engine._requests["a"]
+    assert len(a.generated) == 4  # the fourth call's own token is unread
+    before = dict(m)
+    assert before["prefill_phase_ms"] == pytest.approx(SHORT_MS + SAMPLE_MS)
+    assert before["decode_phase_ms"] == pytest.approx(
+        3 * (DECODE_MS + SAMPLE_MS))
+    assert (before["itl_tokens"], before["itl_over_25ms"]) == (3, 0)
+    assert before["itl_ms"] == pytest.approx(3 * (DECODE_MS + SAMPLE_MS))
+    _add(engine, "b", 40, 3)
+    while engine.has_unfinished():
+        engine.step()
+    assert m["prefill_phase_ms"] - before["prefill_phase_ms"] \
+        == pytest.approx(long_ms + SAMPLE_MS)
+    assert m["prefill_phase_ms"] + m["decode_phase_ms"] == pytest.approx(
+        m["phase_ms"])
+    # the device was never idle between the first dispatch and the last read
+    assert m["phase_ms"] == pytest.approx(
+        SHORT_MS + long_ms + (2 + m["decode_steps"]) * SAMPLE_MS
+        + m["decode_steps"] * DECODE_MS)
+    assert a.max_gap_ns == (long_ms + DECODE_MS + 2 * SAMPLE_MS) * 1_000_000
+    assert [m[k] for k in LADDER] == [1] * over + [0] * (6 - over)
+    assert m["itl_tokens"] == m["generated_tokens"] - 2 == 11 + 2
+    assert m["itl_ms"] == pytest.approx(
+        13 * (DECODE_MS + SAMPLE_MS) + long_ms + SAMPLE_MS)
+    # what the reads waited for is what the host was blocked for: it took no
+    # time of its own
+    assert m["readback_ms"] == pytest.approx(m["phase_ms"])
+
+
+def test_decode_span_says_how_many_tokens_and_the_longest_gap(timed,
+                                                              llm_spans):
+    """With ``RAY_TPU_ENABLE_TRACING`` the finished request's
+    ``engine.decode`` span names WHICH request stalled: ``a`` behind ``b``'s
+    300 ms prefill, ``b`` behind nobody's."""
+    engine = timed(300)
+    _add(engine, "a", 5, 8)
+    for _ in range(3):
+        engine.step()
+    _add(engine, "b", 40, 3)
+    while engine.has_unfinished():
+        engine.step()
+    spans = {(s["request_id"], s["name"]): s for s in llm_spans()}
+    assert len(spans) == 6
+    a, b = spans["a", "engine.decode"], spans["b", "engine.decode"]
+    assert (a["tokens"], b["tokens"]) == (8, 3)
+    assert a["max_gap_ms"] == 300 + DECODE_MS + 2 * SAMPLE_MS
+    assert b["max_gap_ms"] == DECODE_MS + SAMPLE_MS
+    # the span ends when the last token reached the host, and lasts as long
+    # as its gaps together
+    assert b["dur"] == pytest.approx(2 * (DECODE_MS + SAMPLE_MS) / 1e3,
+                                     abs=1e-5)  # doubles around 1.7e9 s
+    assert "tokens" not in spans["a", "engine.prefill"]
+    assert "max_gap_ms" not in spans["a", "engine.queued"]

@@ -255,8 +255,6 @@ def test_engine_serves_and_resumes_after_preemption():
     want = [r.token_ids for r in roomy.generate(prompts, sp, decode_text=False)]
     assert roomy.metrics["preempted"] == 0
     assert roomy.metrics["prefill_cross_rows"] == roomy.metrics["admitted"] == 3
-    assert roomy.metrics["ssm_decode_layer_steps"] \
-        == 3 * roomy.metrics["decode_steps"]
     assert roomy.metrics["shared_kv_read_tokens"] \
         >= roomy.metrics["shared_kv_live_tokens"] > 0
     assert 0 < roomy.metrics["window_live_tokens"] \

@@ -586,9 +586,9 @@ def test_llm_server_releases_every_request_when_a_step_in_flight_fails():
     eng = server.engine
     sent, calls = eng._sent, []
 
-    def poisoned(tokens, reqs, moe_load=None):
+    def poisoned(tokens, reqs, *what, **kw):
         calls.append(len(reqs))
-        sent(Failed() if len(calls) >= 3 else tokens, reqs, moe_load)
+        sent(Failed() if len(calls) >= 3 else tokens, reqs, *what, **kw)
 
     eng._sent = poisoned
 
