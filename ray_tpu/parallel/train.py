@@ -6,20 +6,27 @@ of the framework): optax optimizer, bf16 compute / fp32 params, logical
 shardings resolved against the mesh so DP/FSDP/TP/SP all come from the same
 definition.
 
-One step program per optimizer-state layout (see OVERLAP.md next to this
-file; T3 arxiv 2401.16677 + weight-update sharding arxiv 2004.13336), and
-``step()`` dispatches it whether tracing is on or off:
+One step program per layout of the training state (see OVERLAP.md next to
+this file; T3 arxiv 2401.16677 + weight-update sharding arxiv 2004.13336),
+and ``step()`` dispatches it whether tracing is on or off:
 
 - ``_fused_step``: forward, backward, clip, AdamW and apply in ONE jitted
-  program with donated params + opt state.
+  program with donated params + opt state, both on the parameters' logical
+  shardings (``fused_param_shardings``: replicated over ``data``).
 - ``_fused_step_sharded`` (``shard_update=True``; opt-in, needs a mesh
-  ``data`` axis > 1): the same program text with the optimizer state
-  sharded across the data axis — the partitioner turns the grad
-  all-reduce into a reduce-scatter, each replica updates its 1/N slice,
-  and the refreshed params all-gather back, all overlappable by XLA's
-  async collectives. **Bit-exact in fp32** against ``_fused_step``
-  (same-program codegen, pinned-association global-norm clip; asserted in
-  tests/test_train.py).
+  ``data`` axis > 1): the float32 master of every leaf lives with the
+  moments it is updated from, sharded across the data axis
+  (``param_shardings`` names that layout; ``init_sharded`` makes it). The
+  step gathers each leaf ONCE, at its head, in the dtype the model reads
+  it in (``cfg.dtype`` for the kernels, the table and the head: the shard
+  is cast before it travels; norm scales and the like as they are), that
+  one copy serves forward, recomputation and backward, the partitioner
+  turns the grad all-reduce into a reduce-scatter, each replica updates
+  its 1/N slice, and nothing is gathered after the update. **Bit-exact**
+  against ``_fused_step`` in float32 and in bfloat16 (pinned-association
+  global-norm clip; asserted in tests/test_train.py).
+  ``unshard_params`` gathers the float32 tree once, outside the step, for
+  a checkpoint, a publish or an eval that wants it whole.
 
 The phases are ``jax.named_scope``s inside the program
 (``train.fwd_bwd``, ``train.optimizer``), so a device trace shows where
@@ -125,13 +132,50 @@ def make_optimizer(learning_rate: float = 3e-4, weight_decay: float = 0.1,
     )
 
 
+# primitives that hand an operand to an inner jaxpr's invar of the same
+# position, so a use inside them is a use of the operand itself
+_CALLS = frozenset({"jit", "pjit", "remat2", "checkpoint", "closed_call",
+                    "core_call", "custom_jvp_call", "custom_vjp_call"})
+
+
+def _only_cast_to(jaxpr, var, dtype) -> bool:
+    """True when every use of ``var`` in ``jaxpr`` (through the calls of
+    ``_CALLS``) is a ``convert_element_type`` to ``dtype``: casting the
+    leaf before the program then changes no value the program computes."""
+    jax = import_jax()
+
+    if any(out is var for out in jaxpr.outvars):
+        return False
+    used = False
+    for eqn in jaxpr.eqns:
+        at = [i for i, v in enumerate(eqn.invars) if v is var]
+        if not at:
+            continue
+        used = True
+        if eqn.primitive.name == "convert_element_type":
+            if eqn.params["new_dtype"] != dtype:
+                return False
+            continue
+        inner = [getattr(j, "jaxpr", j)
+                 for j in jax.core.jaxprs_in_params(eqn.params)]
+        if (eqn.primitive.name not in _CALLS or len(inner) != 1
+                or len(inner[0].invars) != len(eqn.invars)
+                or not all(_only_cast_to(inner[0], inner[0].invars[i], dtype)
+                           for i in at)):
+            return False
+    return used
+
+
 class TrainStepBundle:
     """Everything a training worker needs: init fn, step fn, shardings.
 
     ``shard_update=True`` (opt-in; requires a mesh ``data`` axis > 1)
     turns on the cross-replica sharded optimizer update — the caller
-    must then hold opt state on the sharded layout (``init_sharded`` /
-    ``shard_opt_state``). ``optimizer_factory(clip_spec_fn)`` lets the
+    must then hold params AND opt state on the sharded layout
+    (``init_sharded``, or ``shard_params`` / ``shard_opt_state``);
+    ``param_shardings`` names the layout ``step`` and ``_fwd_bwd`` take,
+    ``fused_param_shardings`` the one ``init`` / ``_fused_step`` keep.
+    ``optimizer_factory(clip_spec_fn)`` lets the
     caller parameterize the optimizer while still receiving the bundle's
     update shardings for the pinned-association clip (pass ``optimizer=``
     for a fixed transform — bit-parity of the sharded step then depends
@@ -175,19 +219,26 @@ class TrainStepBundle:
         abstract = jax.eval_shape(init_boxed, jax.random.PRNGKey(0))
         logical = nn.get_partition_spec(abstract)
         shardings = logical_to_mesh_sharding(logical, mesh)
-        self.param_shardings, self.opt_shardings = shardings
+        # the fused program's layout: every leaf on its logical sharding,
+        # replicated over ``data``
+        self.fused_param_shardings, self.opt_shardings = shardings
         self.batch_sharding = NamedSharding(mesh, P(("data", "fsdp"), "seq"))
         self.repl = NamedSharding(mesh, P())
         self._abstract_params, self._abstract_opt = nn.unbox(abstract)
 
-        # cross-replica update shardings: each opt-state leaf gains the
-        # "data" axis on its first dim that can absorb it — adam-family
-        # moments mirror a param leaf's shape AND base sharding, scalars
-        # and odd leaves stay on their base sharding (params keep their
-        # logical shardings — they are consumed replicated on data and
-        # re-emitted replicated via the program's all-gather)
+        # cross-replica update shardings: each leaf gains the "data" axis
+        # on its first dim that can absorb it; scalars and odd leaves stay
+        # on their base sharding. Adam-family moments mirror a param
+        # leaf's shape AND base sharding, so the float32 master and its
+        # moments get the same rule and the same dim: the update is local.
         self.opt_shard_shardings = jax.tree_util.tree_map(
             self._update_sharding, self._abstract_opt, self.opt_shardings)
+        # the layout of the parameters ``step`` takes and returns (and
+        # ``_fwd_bwd`` takes): the sharded master when ``shard_update`` is
+        # on, the fused program's layout otherwise
+        self.param_shardings = jax.tree_util.tree_map(
+            self._update_sharding, self._abstract_params,
+            self.fused_param_shardings)
 
         self.init = jax.jit(init_fn, out_shardings=shardings)
         self.init_sharded = jax.jit(
@@ -218,52 +269,96 @@ class TrainStepBundle:
             aux = sum(jax.tree.leaves(cols.get("losses", {})))
             return lm_loss(logits, targets, mask) + cfg.moe_aux_coef * aux
 
+        read_dtypes = self._read_dtypes() if self.shard_update else None
+
+        def gather_for_use(params):
+            """The sharded step's one gather a leaf, ahead of the forward:
+            the shard is cast to the dtype the model reads the leaf in
+            (``gather(cast(x)) == cast(gather(x))`` to the bit) and
+            constrained onto the fused layout, outside the rematerialised
+            blocks, so forward, recomputation and backward share the one
+            copy. The cotangent goes back onto the shard's layout BEFORE
+            it widens: the partitioner reduce-scatters it in the dtype the
+            backward made it in (the plain transpose would ask for a
+            replicated cotangent, an all-reduce)."""
+            def one(x, dtype, held, used):
+                master = x.dtype
+
+                @jax.custom_vjp
+                def gather(x):
+                    return jax.lax.with_sharding_constraint(
+                        x.astype(dtype), used)
+
+                gather.defvjp(
+                    lambda x: (gather(x), None),
+                    lambda _, ct: (jax.lax.with_sharding_constraint(
+                        ct, held).astype(master),))
+                return gather(x)
+
+            return jax.tree_util.tree_map(
+                one, params, read_dtypes, self.param_shardings,
+                self.fused_param_shardings)
+
         # the phases are scopes inside the one program: metadata a device
         # trace carries, nothing the compiler schedules by
-        def fwd_bwd(params, batch):
-            with jax.named_scope("train.fwd_bwd"):
-                return jax.value_and_grad(loss_fn)(
-                    params, batch["tokens"], batch["targets"],
-                    batch.get("mask"))
+        def programs(read=None):
+            """``fwd_bwd`` and ``train_step`` of the loss over
+            ``read(params)`` (the params themselves when None)."""
+            loss_of = loss_fn if read is None else (
+                lambda params, *batch: loss_fn(read(params), *batch))
 
-        def train_step(params, opt_state, batch):
-            import optax
+            def fwd_bwd(params, batch):
+                with jax.named_scope("train.fwd_bwd"):
+                    return jax.value_and_grad(loss_of)(
+                        params, batch["tokens"], batch["targets"],
+                        batch.get("mask"))
 
-            loss, grads = fwd_bwd(params, batch)
-            with jax.named_scope("train.optimizer"):
-                updates, opt_state = self.optimizer.update(
-                    grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-            return params, opt_state, loss
+            def train_step(params, opt_state, batch):
+                import optax
 
+                loss, grads = fwd_bwd(params, batch)
+                with jax.named_scope("train.optimizer"):
+                    updates, opt_state = self.optimizer.update(
+                        grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
+                return params, opt_state, loss
+
+            return fwd_bwd, train_step
+
+        fwd_bwd, train_step = programs()
         batch_shardings = {"tokens": self.batch_sharding,
                            "targets": self.batch_sharding,
                            "mask": self.batch_sharding}
         donate_args = (0, 1) if donate else ()
         self._fused_step = jax.jit(
             train_step,
-            in_shardings=(self.param_shardings, self.opt_shardings,
+            in_shardings=(self.fused_param_shardings, self.opt_shardings,
                           batch_shardings),
-            out_shardings=(self.param_shardings, self.opt_shardings, self.repl),
-            donate_argnums=donate_args,
-        )
-        # the SHARDED step (shard_update on): same program text, opt
-        # state in/out sharded across data — the partitioner emits
-        # reduce-scatter for the grads, shard-local update math, and an
-        # all-gather for the updated params, all overlappable by XLA's
-        # async collectives. Bit-exact vs _fused_step (tests/test_train.py
-        # pins it).
-        self._fused_step_sharded = jax.jit(
-            train_step,
-            in_shardings=(self.param_shardings, self.opt_shard_shardings,
-                          batch_shardings),
-            out_shardings=(self.param_shardings, self.opt_shard_shardings,
+            out_shardings=(self.fused_param_shardings, self.opt_shardings,
                            self.repl),
             donate_argnums=donate_args,
-        ) if self.shard_update else None
+        )
+        # the SHARDED step (shard_update on): the same step over
+        # ``gather_for_use(params)``, with the float32 master and the opt
+        # state in/out sharded across data: one gather a leaf at the head
+        # of the program in the dtype the model reads, a reduce-scatter of
+        # the grads, shard-local update math, and nothing gathered after
+        # it. Bit-exact vs _fused_step (tests/test_train.py pins it).
+        self._fused_step_sharded = None
+        if self.shard_update:
+            fwd_bwd, train_step = programs(gather_for_use)
+            self._fused_step_sharded = jax.jit(
+                train_step,
+                in_shardings=(self.param_shardings, self.opt_shard_shardings,
+                              batch_shardings),
+                out_shardings=(self.param_shardings,
+                               self.opt_shard_shardings, self.repl),
+                donate_argnums=donate_args,
+            )
 
-        # the step's own loss and gradients, for callers that check them
-        # against a reference (the benchmark's gradient check)
+        # the step's own loss and gradients (of the program ``step``
+        # dispatches, on its parameter layout), for callers that check
+        # them against a reference (the benchmark's gradient check)
         self._fwd_bwd = jax.jit(
             fwd_bwd,
             in_shardings=(self.param_shardings, batch_shardings),
@@ -314,6 +409,28 @@ class TrainStepBundle:
                 return NamedSharding(self.mesh, P(*spec))
         return base_sharding
 
+    def _read_dtypes(self):
+        """Per parameter leaf, the dtype the model reads it in:
+        ``cfg.dtype`` where the forward's every use of the leaf is a cast
+        to it (the dense kernels, the embedding table, the head), the
+        leaf's own dtype otherwise (norm scales; routers and the like
+        elsewhere). Read off the forward's jaxpr, so it follows the
+        config's ``dtype`` and whatever layers the config builds."""
+        jax = import_jax()
+        import jax.numpy as jnp
+
+        tokens = jax.ShapeDtypeStruct(
+            (1, min(self.cfg.max_seq_len, 128)), jnp.int32)
+        jaxpr = jax.make_jaxpr(
+            lambda params, tokens: self.model.apply(
+                {"params": params}, tokens, mutable=["losses"])
+        )(self._abstract_params, tokens).jaxpr
+        leaves, treedef = jax.tree_util.tree_flatten(self._abstract_params)
+        dtype = jnp.dtype(self.cfg.dtype)
+        return treedef.unflatten(
+            [dtype if _only_cast_to(jaxpr, var, dtype) else leaf.dtype
+             for var, leaf in zip(jaxpr.invars, leaves)])
+
     def _norm_spec(self, shape: Tuple[int, ...]):
         """Shape-only reduction layout for the sharded clip (must be a
         pure function of shape so every program pins the same
@@ -344,6 +461,27 @@ class TrainStepBundle:
         jax = import_jax()
 
         return jax.device_put(opt_state, self.opt_shardings)
+
+    def shard_params(self, params):
+        """Reshard parameters from the fused layout onto the layout
+        ``step`` takes (adopting a fused-step run or a restored
+        checkpoint)."""
+        jax = import_jax()
+
+        return jax.device_put(params, self.param_shardings)
+
+    def unshard_params(self, params):
+        """Gather the sharded float32 master back onto the fused layout,
+        once and outside the step: what a checkpoint, ``train.publish`` or
+        an eval that wants the whole float32 tree asks for."""
+        jax = import_jax()
+
+        return jax.device_put(params, self.fused_param_shardings)
+
+    def param_bytes_per_replica(self, params) -> int:
+        """Per-device bytes of these parameters (as
+        ``opt_state_bytes_per_replica`` counts the opt state)."""
+        return self.opt_state_bytes_per_replica(params)
 
     def opt_state_bytes_per_replica(self, opt_state) -> int:
         """Per-device bytes of this opt state (sharded leaves count one
@@ -377,9 +515,9 @@ class TrainStepBundle:
 
     def step(self, params, opt_state, batch):
         """One optimization step: ONE fused XLA program — the
-        sharded-update flavor when ``shard_update`` is on (opt state must
-        be on the sharded layout, e.g. from ``init_sharded`` /
-        ``shard_opt_state``), the plain fused program otherwise.
+        sharded-update flavor when ``shard_update`` is on (params and opt
+        state must be on the sharded layout, e.g. from ``init_sharded``,
+        and come back on it), the plain fused program otherwise.
 
         Instrumented without selecting what the device runs: the
         ``ray_tpu.train.step_seconds`` histogram, one ``train.step`` span

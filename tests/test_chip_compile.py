@@ -377,6 +377,13 @@ def test_engine_writes_the_kv_cache_in_place(one_chip, program, sparse):
         - 2 * cache.k.size * cache.k.dtype.itemsize <= load
 
 
+def _sds(tree, shardings):
+    """``tree``'s shapes and dtypes on ``shardings``, leaf by leaf."""
+    return jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        tree, shardings)
+
+
 def test_sharded_update_step_partitions_over_four_chips(topo):
     """The data-parallel sharded-update step on a data=4 mesh of described
     chips, 1b widths (depth cut to 2): the flash kernel must survive the
@@ -393,22 +400,126 @@ def test_sharded_update_step_partitions_over_four_chips(topo):
         cfg, mesh, shard_update=True,
         optimizer_factory=lambda fn: make_optimizer(clip_spec_fn=fn))
 
-    def sds(tree, shardings):
-        return jax.tree_util.tree_map(
-            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
-            tree, shardings)
-
     batch = {k: jax.ShapeDtypeStruct((4, 2048), dt,
                                      sharding=bundle.batch_sharding)
              for k, dt in (("tokens", jnp.int32), ("targets", jnp.int32),
                            ("mask", jnp.float32))}
     compiled = bundle._fused_step_sharded.lower(
-        sds(bundle._abstract_params, bundle.param_shardings),
-        sds(bundle._abstract_opt, bundle.opt_shard_shardings),
+        _sds(bundle._abstract_params, bundle.param_shardings),
+        _sds(bundle._abstract_opt, bundle.opt_shard_shardings),
         batch).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "reduce-scatter" in text and "all-gather" in text
+
+
+# -- what the sharded update sends between chips (cell 4's step, depth 2) --------
+
+
+@pytest.fixture(scope="module")
+def sharded_step_dp4(topo):
+    """Cell 4's own ``TrainStepBundle`` (InternLM2's widths from
+    ``benchmarks/configs/internlm2-1.8b-dp4.json``, its job block's mesh,
+    optimizer and batch) at depth 2, its sharded step compiled for the four
+    described chips: the collectives of the compiled text by the computation
+    that holds them, and the compiler's memory analysis."""
+    import collections
+    import json
+
+    from benchmarks.jobs import common
+    from benchmarks.registry import REPO
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "internlm2-1.8b-dp4.json")) as f:
+        conf = json.load(f)
+    conf["num_hidden_layers"] = 2
+    job = conf["job"]
+    cfg = dataclasses.replace(
+        common.transformer_config(conf, job["seq_len"]),
+        attention_impl="flash")
+    bundle = common.build_bundle(cfg, job, topo.devices[:job["chips"]])
+
+    rows = job["per_chip_batch"] * bundle.dp_size
+    batch = {k: jax.ShapeDtypeStruct((rows, job["seq_len"]), dt,
+                                     sharding=bundle.batch_sharding)
+             for k, dt in (("tokens", jnp.int32), ("targets", jnp.int32),
+                           ("mask", jnp.float32))}
+    compiled = bundle._fused_step_sharded.lower(
+        _sds(bundle._abstract_params, bundle.param_shardings),
+        _sds(bundle._abstract_opt, bundle.opt_shard_shardings),
+        batch).compile()
+    # {(computation, operation, dtype, dims): lines}. A fusion's body
+    # (``fused_computation``) repeats the text of the collective its
+    # ``async_collective_fusion`` holds: count by ENTRY and the latter.
+    found = collections.Counter()
+    where = None
+    for line in compiled.as_text().splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            where = "ENTRY" if head.group(1) else re.sub(
+                r"[.\d]+$", "", head.group(2))
+            continue
+        op = re.match(r"^\s*(?:ROOT )?%\S+ = \(?(\w+)\[([\d,]*)\]\S* "
+                      r"(all-gather|all-reduce)(?:-start)?\(", line)
+        if op:
+            dims = tuple(int(d) for d in op.group(2).split(",") if d)
+            found[(where, op.group(3), op.group(1), dims)] += 1
+    leaves = collections.Counter(
+        tuple(x.shape) for x in jax.tree_util.tree_leaves(
+            bundle._abstract_params) if x.ndim >= 2)
+    return {"bundle": bundle, "found": found, "leaves": leaves,
+            "memory": compiled.memory_analysis(), "batch": batch}
+
+
+def test_sharded_step_gathers_parameters_in_bfloat16(sharded_step_dp4):
+    """(a) Every all-gather whose result has the shape of a parameter
+    matrix carries bfloat16, the dtype the model reads it in; none carries
+    the float32 master."""
+    found, leaves = sharded_step_dp4["found"], sharded_step_dp4["leaves"]
+    dtypes = {dt for (_, op, dt, dims), _ in found.items()
+              if op == "all-gather" and dims in leaves}
+    assert dtypes == {"bf16"}, dtypes
+
+
+def test_sharded_step_gathers_each_parameter_once(sharded_step_dp4):
+    """(b) One gather a leaf and step: a cast left inside the rematerialised
+    blocks gathers three times (forward, recomputation, backward)."""
+    found, leaves = sharded_step_dp4["found"], sharded_step_dp4["leaves"]
+    gathered = {}
+    for (where, op, _, dims), n in found.items():
+        if op == "all-gather" and dims in leaves and \
+                where in ("ENTRY", "async_collective_fusion"):
+            gathered[dims] = gathered.get(dims, 0) + n
+    assert gathered == dict(leaves)
+
+
+def test_sharded_step_keeps_the_gradients_reduce_scatters(sharded_step_dp4):
+    """(c) The gradients stay reduce-scatters (XLA's ``all-reduce-scatter``
+    fusions): no bare all-reduce of a matrix in ENTRY but the head's
+    ``[2048, 92544]``, which the parent has too. A cotangent made
+    replicated before it is sharded shows here as table-sized
+    all-reduces."""
+    found = sharded_step_dp4["found"]
+    bare = {dims for (where, op, _, dims), _ in found.items()
+            if where == "ENTRY" and op == "all-reduce" and len(dims) >= 2}
+    assert bare <= {(2048, 92544)}, bare
+    assert any(where.startswith("all-reduce-scatter")
+               for (where, op, _, _) in found if op == "all-reduce")
+
+
+def test_sharded_step_arguments_hold_a_quarter_of_the_master(sharded_step_dp4):
+    """(d) The program's arguments hold a chip's quarter of the float32
+    master beside its quarter of the moments: three quarters of the
+    parameter tree less than the replicated layout's."""
+    import numpy as np
+
+    b, batch = sharded_step_dp4["bundle"], sharded_step_dp4["batch"]
+    master = sum(int(np.prod(x.shape)) * 4 for x in
+                 jax.tree_util.tree_leaves(b._abstract_params))
+    per_chip_batch = sum(int(np.prod(v.shape)) * 4 for v in batch.values()) // 4
+    replicated = master + b.opt_state_bytes_total() // 4 + per_chip_batch
+    now = sharded_step_dp4["memory"].argument_size_in_bytes
+    assert replicated - now == pytest.approx(0.75 * master, rel=0.01)
 
 
 # -- the latent model's programs (Moonlight-16B-A3B, the benchmark's file) -------

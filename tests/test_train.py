@@ -179,8 +179,7 @@ def _bitwise_equal_trees(a, b, repl):
     return bad
 
 
-@pytest.fixture(scope="module")
-def sharded_bundle():
+def _sharded_bundle(dtype):
     """One tiny-config bundle on the 8-device mesh, clip LOW enough that
     the global-norm clip actually engages every step — plus the captured
     warnings from compiling/running every program of the bundle."""
@@ -188,7 +187,7 @@ def sharded_bundle():
     from ray_tpu.models.transformer import CONFIGS
     from ray_tpu.parallel import TrainStepBundle, create_mesh, make_optimizer
 
-    cfg = dataclasses.replace(CONFIGS["tiny"], max_seq_len=64)
+    cfg = dataclasses.replace(CONFIGS["tiny"], max_seq_len=64, dtype=dtype)
     mesh = create_mesh({"data": DP, "fsdp": 1, "seq": 1, "tensor": 1,
                         "expert": 1})
     factory = lambda spec_fn: make_optimizer(  # noqa: E731
@@ -210,19 +209,41 @@ def sharded_bundle():
         for _ in range(3):
             ps, ss, ls = bundle.step(ps, ss, batch)
         runs["sharded"] = (ps, ss, float(ls))
-        # the check program (the benchmark's gradient check reads it)
-        runs["fwd_bwd"] = bundle._fwd_bwd(pf, batch)
+        # the check program (the benchmark's gradient check reads it), on
+        # the layout `step` takes
+        runs["fwd_bwd"] = bundle._fwd_bwd(bundle.shard_params(pf), batch)
     return {"bundle": bundle, "batch": batch, "runs": runs,
             "warnings": [str(w.message) for w in wrec]}
 
 
-def test_sharded_update_bitexact_vs_fused(sharded_bundle):
+@pytest.fixture(scope="module")
+def sharded_bundle():
+    import jax.numpy as jnp
+
+    return _sharded_bundle(jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def sharded_bundle_bf16():
+    """The dtype cell 4 runs, and the case where the cast moves: the
+    sharded step casts each shard to bfloat16 BEFORE its gather."""
+    import jax.numpy as jnp
+
+    return _sharded_bundle(jnp.bfloat16)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_sharded_update_bitexact_vs_fused(request, dtype):
     """The acceptance contract: the cross-replica sharded-update step
-    reproduces the fused step bit-for-bit in fp32 over 3 steps — params
-    AND optimizer state after all-gather, with the global-norm clip (low
-    threshold, so it engages) computed from shard-local sqnorms."""
+    reproduces the fused step bit-for-bit over 3 steps — params (through
+    `unshard_params`) AND optimizer state after all-gather, with the
+    global-norm clip (low threshold, so it engages) computed from
+    shard-local sqnorms. In float32, and in bfloat16, where the gathered
+    copy is the cast one."""
     import jax
 
+    sharded_bundle = request.getfixturevalue(
+        "sharded_bundle" if dtype == "float32" else "sharded_bundle_bf16")
     b = sharded_bundle["bundle"]
     pf, sf, lf = sharded_bundle["runs"]["fused"]
     ps, ss, ls = sharded_bundle["runs"]["sharded"]
@@ -232,17 +253,84 @@ def test_sharded_update_bitexact_vs_fused(sharded_bundle):
         float(np.sum(np.square(np.asarray(g, dtype=np.float64))))
         for g in jax.tree_util.tree_leaves(grads))))
     assert gnorm > 0.05, "test misconfigured: clip never engages"
-    assert _bitwise_equal_trees(pf, ps, b.repl) == []
+    assert _bitwise_equal_trees(pf, b.unshard_params(ps), b.repl) == []
     assert _bitwise_equal_trees(sf, b.unshard_opt_state(ss), b.repl) == []
     assert lf == ls
+    assert {str(x.dtype) for x in jax.tree_util.tree_leaves(ps)} == {"float32"}
 
 
-def test_no_donation_alias_warnings(sharded_bundle):
+def test_no_donation_alias_warnings(sharded_bundle, sharded_bundle_bf16):
     """Compiling and running every program of the bundle (fused, fused
     sharded, `_fwd_bwd`) produces zero XLA donation/alias warnings."""
-    bad = [w for w in sharded_bundle["warnings"]
+    bad = [w for w in (sharded_bundle["warnings"]
+                       + sharded_bundle_bf16["warnings"])
            if "donat" in w.lower() or "alias" in w.lower()]
     assert bad == [], f"XLA donation warnings: {bad[:2]}"
+
+
+def test_sharded_param_state_is_1_over_n(sharded_bundle):
+    """Between steps a replica holds its 1/N of the float32 master of
+    every leaf the update rule can shard (the same rule and dim as the
+    leaf's moments), not a replicated tree."""
+    import jax
+
+    b = sharded_bundle["bundle"]
+    ps, ss, _ = sharded_bundle["runs"]["sharded"]
+    pf, _, _ = sharded_bundle["runs"]["fused"]
+    per = b.param_bytes_per_replica(ps)
+    total = b.param_bytes_per_replica(pf)
+    assert per == pytest.approx(total / DP, rel=0.05), (per, total)
+    mu = ss[1][0].mu
+    assert jax.tree_util.tree_map(lambda x: x.sharding.spec, ps) == \
+        jax.tree_util.tree_map(lambda x: x.sharding.spec, mu)
+
+
+def test_harness_contract_one_params_tree(sharded_bundle):
+    """What the benchmark's harness does with ONE params tree: `_fwd_bwd`
+    and `step` take exactly what `init_sharded` returns, `param_shardings`
+    names that layout (a committed array that disagreed with a program's
+    `in_shardings` would raise), and the fused program's layout has a name
+    of its own."""
+    import jax
+
+    b, batch = sharded_bundle["bundle"], sharded_bundle["batch"]
+    p, s = b.init_sharded(jax.random.PRNGKey(2))
+    is_sharding = lambda x: hasattr(x, "spec")  # noqa: E731
+    named = jax.tree_util.tree_leaves(b.param_shardings, is_leaf=is_sharding)
+    held = [x.sharding for x in jax.tree_util.tree_leaves(p)]
+    assert all(h.is_equivalent_to(n, x.ndim) for h, n, x in
+               zip(held, named, jax.tree_util.tree_leaves(p)))
+    loss, grads = b._fwd_bwd(p, batch)
+    # the gradient arrives on the shards the update reads
+    assert jax.tree_util.tree_map(lambda g: g.sharding.spec, grads) == \
+        jax.tree_util.tree_map(lambda x: x.sharding.spec, p)
+    p1, s1, loss_step = b.step(p, s, batch)
+    assert float(loss) == float(loss_step)
+    assert [x.sharding for x in jax.tree_util.tree_leaves(p1)] == held
+    fused = jax.tree_util.tree_leaves(b.fused_param_shardings,
+                                      is_leaf=is_sharding)
+    assert any("data" in str(n.spec) for n in named)
+    assert not any("data" in str(f.spec) for f in fused)
+    with pytest.raises(ValueError, match="[Ss]harding"):
+        b._fwd_bwd(b.init(jax.random.PRNGKey(2))[0], batch)
+
+
+def test_sharded_step_reads_leaves_in_the_models_dtype(sharded_bundle,
+                                                       sharded_bundle_bf16):
+    """The gathered copy has the dtype the model reads the leaf in: with
+    `dtype=bfloat16` every leaf the forward only ever casts (kernels,
+    table, head) is bfloat16, the norm scales stay float32; with
+    `dtype=float32` there is nothing to cast."""
+    import jax
+
+    reads = sharded_bundle_bf16["bundle"]._read_dtypes()
+    flat = {jax.tree_util.keystr(k): str(v) for k, v in
+            jax.tree_util.tree_leaves_with_path(reads)}
+    assert flat and all(
+        v == ("float32" if "scale" in k else "bfloat16")
+        for k, v in flat.items()), flat
+    assert {str(v) for v in jax.tree_util.tree_leaves(
+        sharded_bundle["bundle"]._read_dtypes())} == {"float32"}
 
 
 def test_sharded_opt_state_memory_is_1_over_n(sharded_bundle):
@@ -401,7 +489,7 @@ def test_check_program_is_the_trained_program(sharded_bundle):
 
     b, batch = sharded_bundle["bundle"], sharded_bundle["batch"]
     p, s = b.init(jax.random.PRNGKey(0))
-    loss_check, _ = b._fwd_bwd(p, batch)
+    loss_check, _ = b._fwd_bwd(b.shard_params(p), batch)
     _, _, loss_step = b._fused_step(p, s, batch)
     assert float(loss_check) == float(loss_step)
 
@@ -419,9 +507,12 @@ def test_phases_are_named_in_the_program(sharded_bundle):
             tree, shardings)
 
     p = abstract(b._abstract_params, b.param_shardings)
-    for program, opt_sh in ((b._fused_step, b.opt_shardings),
-                            (b._fused_step_sharded, b.opt_shard_shardings)):
-        text = program.lower(p, abstract(b._abstract_opt, opt_sh),
+    for program, p_sh, opt_sh in (
+            (b._fused_step, b.fused_param_shardings, b.opt_shardings),
+            (b._fused_step_sharded, b.param_shardings,
+             b.opt_shard_shardings)):
+        text = program.lower(abstract(b._abstract_params, p_sh),
+                             abstract(b._abstract_opt, opt_sh),
                              batch).as_text(debug_info=True)
         assert "train.fwd_bwd" in text and "train.optimizer" in text
     text = b._fwd_bwd.lower(p, batch).as_text(debug_info=True)
@@ -452,7 +543,8 @@ def test_every_program_of_the_bundle_runs(sharded_bundle, program):
         loss = b._fused_step_sharded(
             *b.init_sharded(jax.random.PRNGKey(1)), batch)[2]
     elif program == "fwd_bwd":
-        loss, grads = b._fwd_bwd(b.init(jax.random.PRNGKey(1))[0], batch)
+        loss, grads = b._fwd_bwd(
+            b.init_sharded(jax.random.PRNGKey(1))[0], batch)
         assert all(np.isfinite(np.asarray(g)).all()
                    for g in jax.tree_util.tree_leaves(grads))
     else:
