@@ -52,6 +52,10 @@ class EngineConfig:
     # a sparse deployment's name, and a program that has no expert layer
     # refuses such a deployment where it is described, not in a replica.
     expect_experts: int = 0
+    # the router's outputs where the deployment is one rank of an
+    # expert-parallel group and holds only ``expect_experts`` of them (0: it
+    # holds them all), checked against the model the same way
+    expect_routed_experts: int = 0
     # width of the latent the cache holds a position (0: per-head K/V pages),
     # checked against the model as expect_experts is, and for its reason
     expect_latent_rank: int = 0

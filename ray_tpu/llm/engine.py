@@ -107,9 +107,12 @@ from ``__init__``, so two snapshots subtract):
   as its client waited for it;
 - what routing did in decode, for a model with experts (all 0 for a dense
   one): ``moe_decode_layer_steps`` (decode steps x expert layers) and, summed
-  over those, ``moe_decode_assignments`` (active slots x top_k),
-  ``moe_decode_experts_touched`` (experts that got a row) and
-  ``moe_decode_max_load`` (rows of the fullest expert). They come from
+  over those, ``moe_decode_routed_assignments`` (active slots x top_k:
+  wherever they fell), ``moe_decode_assignments`` (those that fell on an
+  expert HELD here: all of them, unless the model is one rank of an
+  expert-parallel deployment, ``TransformerConfig.experts_held``),
+  ``moe_decode_experts_touched`` (held experts that got a row) and
+  ``moe_decode_max_load`` (rows of the fullest held expert). They come from
   ``KVCache.moe_load``, a few KB copied out of the cache at dispatch (the
   next dispatch donates the cache) and read in ``emit`` with that step's
   tokens, a step later;
@@ -117,14 +120,15 @@ from ``__init__``, so two snapshots subtract):
   ``mla_decode_live_tokens`` (positions the active slots attend over) and
   ``mla_decode_read_tokens`` (positions of the pages ``mla_decode`` is given:
   the live ones rounded up to whole pages);
-- for a model that keeps three kinds of state (``layer_kinds``: pages for its
-  one shared layer of keys and values, window rings and recurrent rows by
-  slot; 0 otherwise): ``shared_kv_live_tokens`` and ``shared_kv_read_tokens``
-  (the same two for the shared layer's pages, counted once a decode step, not
-  once a layer that reads them), ``window_live_tokens`` (filled ring entries
-  the active slots attend over, a step and window layer) and
-  ``prefill_cross_rows`` (rows the cross-decoder computed in prefill: one a
-  call, against ``prefill_batch_tokens`` for the self-decoder).
+- for a model that keeps state by kind of layer (``layer_kinds``: pages for
+  its "full" layers, window rings and, in a decoder-hybrid-decoder, recurrent
+  rows by slot; 0 otherwise): ``shared_kv_live_tokens`` and
+  ``shared_kv_read_tokens`` (the same two for a paged layer, counted once a
+  decode step, not once a layer that reads pages), ``window_live_tokens``
+  (filled ring entries the active slots attend over, a step and window layer)
+  and, for a decoder-hybrid-decoder alone, ``prefill_cross_rows`` (rows the
+  cross-decoder computed in prefill: one a call, against
+  ``prefill_batch_tokens`` for the self-decoder).
 
 Such a model's rings and rows need no allocator: a slot owns its own, a
 prefill call overwrites all of them from the prompt (the engine tells it the
@@ -140,8 +144,8 @@ inside it, in this order, ``engine.admit``, ``.prefill_dispatch`` (arguments
 ``bucket``, the largest of the phase, and ``admitted``), ``.sample_dispatch``,
 ``.decode_dispatch`` (arguments ``overlapped``: 1 if an earlier step is
 unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
-per layer in the newest decode step the host has read, models with experts
-only; ``live_tokens``: positions the step attends over through the block
+per layer in the newest decode step the host has read, and ``held``: that
+step's assignments per layer to experts held here, models with experts only; ``live_tokens``: positions the step attends over through the block
 tables, models with a latent cache or with ``layer_kinds`` only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
 every sampler call of the step before. ``.readback`` names what it waited
 for (arguments ``kind``: ``prefill`` or ``decode``; ``calls``: the prefill
@@ -244,10 +248,13 @@ class JaxLLMEngine:
         self.config = config
         self.ecfg: EngineConfig = config.engine_config
         self.mcfg = config.transformer_config()
-        if self.mcfg.n_experts != self.ecfg.expect_experts:
+        expect = (self.ecfg.expect_experts, self.ecfg.expect_routed_experts
+                  or self.ecfg.expect_experts)
+        if (self.mcfg.n_experts_held, self.mcfg.n_experts) != expect:
             raise ValueError(
-                f"the deployment expects {self.ecfg.expect_experts} experts "
-                f"a layer, the model has {self.mcfg.n_experts}")
+                f"the deployment expects {expect[0]} experts a layer of "
+                f"{expect[1]} routed, the model holds "
+                f"{self.mcfg.n_experts_held} of {self.mcfg.n_experts}")
         if self.mcfg.kv_latent_rank != self.ecfg.expect_latent_rank:
             raise ValueError(
                 f"the deployment expects a latent cache of rank "
@@ -291,6 +298,8 @@ class JaxLLMEngine:
         self._top_ks = np.zeros(B, np.int32)
         self._top_ps = np.ones(B, np.float32)
         self._seeds = np.full(B, -1, np.int32)  # -1 = engine-global stream
+        # _up's kept copies: name -> (the host's array as sent, the device's)
+        self._kept: Dict[str, tuple] = {}
         # where a prefill phase gathers its calls' logits, by slot, for the
         # one sampler call; rows of slots not admitted in a phase are stale
         # and their samples unread
@@ -325,6 +334,7 @@ class JaxLLMEngine:
             "itl_ms": 0.0, "itl_tokens": 0, **dict.fromkeys(_ITL_KEYS, 0),
             "moe_decode_layer_steps": 0, "moe_decode_assignments": 0,
             "moe_decode_experts_touched": 0, "moe_decode_max_load": 0,
+            "moe_decode_routed_assignments": 0,
             "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0,
             "shared_kv_live_tokens": 0, "shared_kv_read_tokens": 0,
             "window_live_tokens": 0, "prefill_cross_rows": 0}
@@ -476,15 +486,28 @@ class JaxLLMEngine:
             self._requeue(req)
 
     def _next_rng(self):
-        self._rng, sub = self._jax.random.split(self._rng)
+        self._rng, sub = self._mr.split_key(self._rng)
         return sub
 
-    def _up(self, a: np.ndarray):
+    def _up(self, a: np.ndarray, keep: str = ""):
         """One of the host's own arrays on the device as it reads NOW. The
         host goes on writing them while the programs that take them are still
         queued, and a transfer may read the buffer it was given after it
-        returns (the CPU backend aliases it): it gets a copy nobody writes."""
-        return self._jax.numpy.asarray(a.copy())
+        returns (the CPU backend aliases it): it gets a copy nobody writes.
+        Under ``keep`` the device's copy is kept by that name and handed out
+        again while the host's array reads the same: the block tables, the
+        active mask and the sampling parameters change with an admission, a
+        release or a new page, not with a step, and a transfer costs the
+        host more than the comparison."""
+        if keep:
+            kept = self._kept.get(keep)
+            if kept is not None and np.array_equal(kept[0], a):
+                return kept[1]
+        host = a.copy()
+        dev = self._jax.numpy.asarray(host)
+        if keep:
+            self._kept[keep] = (host, dev)
+        return dev
 
     def _sample(self, logits):
         """One sampler call over every slot's row of ``logits``. The tokens
@@ -492,14 +515,17 @@ class JaxLLMEngine:
         for it (``_read`` does, a step later)."""
         with self._phase("sample_dispatch"):
             # a seeded request's position in its stream: the tokens it was
-            # given, read or not
-            steps = np.array(
-                [len(s.generated) + s.in_flight if s is not None else 0
-                 for s in self._slots], np.int32)
+            # given, read or not (the engine's own stream takes no position)
+            steps = np.zeros(len(self._slots), np.int32)
+            for i in np.flatnonzero(self._seeds >= 0):
+                s = self._slots[i]
+                if s is not None:
+                    steps[i] = len(s.generated) + s.in_flight
             toks = self._mr.sample_tokens(
-                logits, self._next_rng(), self._up(self._temps),
-                self._up(self._top_ks), self._up(self._top_ps),
-                self._up(self._seeds), self._jax.numpy.asarray(steps),
+                logits, self._next_rng(), self._up(self._temps, "temps"),
+                self._up(self._top_ks, "top_ks"),
+                self._up(self._top_ps, "top_ps"),
+                self._up(self._seeds, "seeds"), self._up(steps, "steps"),
                 max_top_k=self.ecfg.max_top_k)
             toks.copy_to_host_async()
         return toks
@@ -609,7 +635,7 @@ class JaxLLMEngine:
             m["admitted"] += len(admitted)
             m["prefill_tokens"] += int(self._seq_lens[slots].sum())
             m["prefill_batch_tokens"] += sum(buckets)
-            if self.mcfg.layer_kinds:  # the cross-decoder ran one row a call
+            if self.mcfg.sambay:  # the cross-decoder ran one row a call
                 m["prefill_cross_rows"] += len(admitted)
             self._active[slots] = True
             self._sent(firsts, admitted, "prefill", len(admitted),
@@ -641,8 +667,8 @@ class JaxLLMEngine:
                         logits, self.cache = mr.decode_step(
                             self.params, self.mcfg, self.cache, self._tokens,
                             self._up(self._seq_lens),
-                            self._up(self._block_tables),
-                            self._up(self._active))
+                            self._up(self._block_tables, "tables"),
+                            self._up(self._active, "active"))
                     if self.cache.moe_load is not None:
                         # a copy outside the cache: the next call donates
                         # the cache before the host reads this one's routing
@@ -702,7 +728,7 @@ class JaxLLMEngine:
         m["phase_ms"] += waited
         with self._phase("emit"):
             if u.moe_load is not None:
-                self._count_routing(np.asarray(u.moe_load))
+                self._count_routing(np.asarray(u.moe_load), len(u.rows))
             # a stop token read since the dispatch ended a request: its slot
             # ran on, for nobody
             live = [req for req, _ in u.rows if not req.finished]
@@ -758,16 +784,20 @@ class JaxLLMEngine:
         self.metrics[prefix + "_live_tokens"] += live
         self.metrics[prefix + "_read_tokens"] += read
 
-    def _count_routing(self, load: np.ndarray) -> None:
-        """``load`` [expert layers, E]: real rows per expert in one decode
-        step."""
+    def _count_routing(self, load: np.ndarray, rows: int) -> None:
+        """``load`` [expert layers, E]: real rows per expert HELD here in one
+        decode step of ``rows`` real rows."""
         m = self.metrics
         touched = int((load > 0).sum())
+        held = int(load.sum())
         m["moe_decode_layer_steps"] += load.shape[0]
-        m["moe_decode_assignments"] += int(load.sum())
+        m["moe_decode_assignments"] += held
+        m["moe_decode_routed_assignments"] += (
+            rows * self.mcfg.experts_per_token * load.shape[0])
         m["moe_decode_experts_touched"] += touched
         m["moe_decode_max_load"] += int(load.max(axis=1).sum())
-        self._experts_attr = {"experts": touched / load.shape[0]}
+        self._experts_attr = {"experts": touched / load.shape[0],
+                              "held": held / load.shape[0]}
 
     def _requeue(self, req: _Request) -> None:
         """Preempt a running request back to the waiting queue; its KV is

@@ -41,26 +41,37 @@ up-projection absorbed, through ``ops/mla.py:mla_decode``, which reads only
 the pages that hold live positions. Both programs keep their signatures: the
 cache is whichever tuple ``init_cache`` made, pages first and ``moe_load`` last.
 
-A model with ``layer_kinds`` (a decoder-hybrid-decoder: Mamba layers, window
-and full differential attention, gated memory units, cross layers) keeps
-``HybridCache``: THREE kinds of state side by side. Pages, for the one layer
-of keys and values that the full layer writes and every cross layer reads,
-handed out by the engine's block tables as any page is; a ring of ``window``
-positions a slot for each window layer, written at ``position mod window`` and
-masked by how many entries are filled; a recurrent row a slot for each Mamba
-layer (the scan's state in float32 and the convolution's last inputs).
-``prefill`` is told the slot a row fills (``slots``): it runs the
-self-decoder over the prompt (the scan through ``ops/ssm.py``, padding passed
-over with ``dt = 0``; the window through the flash kernel, blocks left of it
-skipped), overwrites all of the slot's rings and rows from the prompt alone
-(which is how a slot is reset at admission and how a preempted request
-comes back) and leaves them at position ``lengths - 1``; then it runs the
-cross-decoder on that ONE position, since those layers write no state and the
-engine reads one row of logits. ``decode_step`` carries the rows forward one
-step, writes each ring at ``position mod window`` and the page row, and
-attends through ``ops/paged_attention.py``: over the rings in the window
-layers, and in the full and the cross layers over the live pages of one work
-list (``ops/mla.py:live_pages``, built once a step).
+A model with ``layer_kinds`` keeps ``HybridCache``: state by KIND of layer,
+side by side, under ONE definition of where each kind lies (``_prompt_index``,
+``_write_rings``, ``_decode_index``, ``_ring_blocks``). Pages, for each "full"
+layer's keys and values, handed out by the engine's block tables as any page
+is; a ring of ``window`` positions a slot for each "window" layer, written at
+``position mod window`` and masked by how many entries are filled; and, in a
+decoder-hybrid-decoder, a recurrent row a slot for each Mamba layer (the scan's
+state in float32 and the convolution's last inputs). ``prefill`` is told the
+slot a row fills (``slots``), overwrites the slot's rings (and rows) from the
+prompt alone (which is how a slot is reset at admission and how a preempted
+request comes back) and leaves them at position ``lengths - 1``.
+``decode_step`` writes each ring at ``position mod window`` and the page row,
+and attends through ``ops/paged_attention.py``: over the rings' filled blocks
+in the window layers and over the live pages in the full ones, two work lists
+(``ops/mla.py:live_pages``) built once a step; nothing is gathered over a
+slot's whole length. Two kinds of block use it (``TransformerConfig.block``):
+
+- "sambay" (Mamba layers, window and full DIFFERENTIAL attention, gated memory
+  units, cross layers; LayerNorm, no position embedding): one paged layer,
+  which the full layer writes and every cross layer reads. Its prefill runs
+  the self-decoder over the prompt (the scan through ``ops/ssm.py``, padding
+  passed over with ``dt = 0``; the window through the flash kernel, blocks left
+  of it skipped) and the cross-decoder on the ONE last position, since those
+  layers write no state and the engine reads one row of logits;
+- "rms" (the RMSNorm block of every other model, with plain grouped-query
+  heads): pages for each full layer, rings for each window layer, keys
+  rotated before they are written where the kind rotates
+  (``TransformerConfig.rope_kinds``), and whatever of a per-head q/k norm, an
+  attention gate, sandwich norms, a scaled embedding and experts (all of them,
+  or the share ``experts_held`` of an expert-parallel rank) the config asks
+  for.
 """
 
 from __future__ import annotations
@@ -93,23 +104,25 @@ class LatentCache(NamedTuple):
 
 
 class HybridCache(NamedTuple):
-    """The state of a model with ``layer_kinds`` (a decoder-hybrid-decoder),
-    three kinds side by side. ``pages``: the ONE layer of keys and values
-    that the "full" layer writes and every "cross" layer reads, a row ``k | v``
-    of all heads a position, addressed through the block tables as any page
-    is. ``rings``: per "window" layer and slot the ``window`` newest positions'
-    rows, position ``t`` at entry ``t mod window``. ``ssm``, ``conv``: per
-    "mamba" layer and slot the scan's state (float32, ``inner`` along the
-    lanes as ``ops/ssm.py`` keeps it: [.., N, inner] is whole tiles where [..,
-    inner, N] would pad 16 lanes to 128) and the convolution's last ``ssm_conv
-    - 1`` inputs. Rings and rows belong to a SLOT: prefill
-    overwrites all of a slot's from the prompt alone, which is also how a
-    slot is reset at admission; a slot that is not active computes into its
-    own rows and nobody reads them."""
-    pages: jax.Array  # [NP, P, 2 KVH hd]
+    """The state of a model with ``layer_kinds``: up to three kinds side by
+    side, ONE definition for every such model. ``pages``: a layer of keys and
+    values for each "full" layer, a row ``k | v`` of all heads a position,
+    addressed through the block tables as any page is (a decoder-hybrid-decoder
+    has one such layer, which its "cross" layers read too). ``rings``: per
+    "window" layer and slot the ``window`` newest positions' rows, position
+    ``t`` at entry ``t mod window`` (keys as attention reads them: rotated, in
+    a model that rotates). ``ssm``, ``conv``: per "mamba" layer and slot the
+    scan's state (float32, ``inner`` along the lanes as ``ops/ssm.py`` keeps
+    it: [.., N, inner] is whole tiles where [.., inner, N] would pad 16 lanes
+    to 128) and the convolution's last ``ssm_conv - 1`` inputs; None in a model
+    without such layers. Rings and rows belong to a SLOT: prefill overwrites
+    all of a slot's from the prompt alone, which is also how a slot is reset
+    at admission; a slot that is not active computes into its own rows and
+    nobody reads them."""
+    pages: jax.Array  # [full layers, NP, P, 2 KVH hd]
     rings: jax.Array  # [window layers, B, window, 2 KVH hd]
-    ssm: jax.Array    # [mamba layers, B, N, inner] float32
-    conv: jax.Array   # [mamba layers, ssm_conv - 1, B, inner]
+    ssm: Optional[jax.Array] = None   # [mamba layers, B, N, inner] float32
+    conv: Optional[jax.Array] = None  # [mamba layers, ssm_conv - 1, B, inner]
     moe_load: Optional[jax.Array] = None
 
 
@@ -128,25 +141,27 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
                max_num_seqs: int = 0) -> KVCache:
     """``max_num_seqs``: the engine's slots, which only a model that keeps
     state by slot (``HybridCache``) needs."""
+    load = None
+    layers = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
+    if layers:
+        load = jnp.zeros((layers, cfg.n_experts_held), jnp.int32)
     if cfg.layer_kinds:
         if not max_num_seqs:
             raise ValueError("a model with layer_kinds keeps window rings and "
                              "recurrent rows by slot: init_cache needs "
                              "max_num_seqs")
         kinds, row = cfg.layer_kinds, 2 * cfg.n_kv_heads * cfg.head_dim
+        mamba = kinds.count("mamba")
         return HybridCache(
-            jnp.zeros((num_pages, page_size, row), cfg.dtype),
+            jnp.zeros((kinds.count("full"), num_pages, page_size, row),
+                      cfg.dtype),
             jnp.zeros((kinds.count("window"), max_num_seqs, cfg.window, row),
                       cfg.dtype),
-            jnp.zeros((kinds.count("mamba"), max_num_seqs, cfg.ssm_state,
-                       cfg.ssm_inner), jnp.float32),
-            jnp.zeros((kinds.count("mamba"), cfg.ssm_conv - 1, max_num_seqs,
-                       cfg.ssm_inner), cfg.dtype))
+            jnp.zeros((mamba, max_num_seqs, cfg.ssm_state, cfg.ssm_inner),
+                      jnp.float32) if mamba else None,
+            jnp.zeros((mamba, cfg.ssm_conv - 1, max_num_seqs, cfg.ssm_inner),
+                      cfg.dtype) if mamba else None, load)
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    load = None
-    layers = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
-    if layers:
-        load = jnp.zeros((layers, cfg.n_experts), jnp.int32)
     if cfg.kv_latent_rank:
         return LatentCache(jnp.zeros(
             (cfg.n_layers, num_pages, page_size, _latent_width(cfg)),
@@ -187,14 +202,16 @@ def _ffn(x, lp, cfg, valid, name):
         top_k=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
         name=name, router_kind=cfg.router_kind,
         router_bias=p.get("router_bias"),
-        router_scale=cfg.routed_scaling_factor)
+        router_scale=cfg.routed_scaling_factor,
+        held=tuple(cfg.experts_held) or None)
     y = y.reshape(x.shape)
     if "shared" in p:  # the experts every row goes through
-        y = y + _mlp(x, p["shared"], cfg.dtype)
+        with jax.named_scope("moe.shared"):
+            y = y + _mlp(x, p["shared"], cfg.dtype)
     return y, load
 
 
-def _qkv(x, p, cfg, positions):
+def _qkv(x, p, cfg, positions, rotate=True):
     dtype = cfg.dtype
     q = jnp.einsum("...d,dhk->...hk", x, p["q_proj"]["kernel"].astype(dtype))
     k = jnp.einsum("...d,dhk->...hk", x, p["k_proj"]["kernel"].astype(dtype))
@@ -205,8 +222,12 @@ def _qkv(x, p, cfg, positions):
             return _rmsnorm(flat, scale, cfg.norm_eps).reshape(t.shape)
         q = whole(q, p["q_norm"]["scale"])
         k = whole(k, p["k_norm"]["scale"])
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    if cfg.qk_head_norm:  # head by head, one scale for all of them
+        q = _rmsnorm(q, p["q_norm"]["scale"], cfg.norm_eps)
+        k = _rmsnorm(k, p["k_norm"]["scale"], cfg.norm_eps)
+    if rotate:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -336,22 +357,29 @@ def _row_heads(row, cfg):
 
 
 def _grouped_query(q, cfg):
-    """q [B, H, hd] -> [B, G, R, 2 hd] for ``ops/paged_attention.py``: the
-    query heads of a key pair as rows (padded to 16), each scaled and laid
-    where its key lies in the pair's ``k1 | k2``, zeros beside it."""
+    """q [B, H, hd] -> [B, G, R, W] for ``ops/paged_attention.py``, scaled:
+    the query heads of a group as rows (padded to 16). Plain heads: a group
+    is one key head, ``W = hd``. Differential heads: a group is a key PAIR,
+    each query laid where its key lies in the pair's ``k1 | k2`` (``W = 2
+    hd``), zeros beside it."""
     B, H, hd = q.shape
-    G = cfg.n_kv_heads // 2
-    q = (q * (hd ** -0.5)).reshape(B, G, H // G, hd)
-    second = (jnp.arange(H // G) % 2 == 1)[None, None, :, None]
-    zero = jnp.zeros_like(q)
-    q = jnp.concatenate([jnp.where(second, zero, q),
-                         jnp.where(second, q, zero)], axis=-1)
+    q = q * (hd ** -0.5)
+    if cfg.sambay:
+        G = cfg.n_kv_heads // 2
+        q = q.reshape(B, G, H // G, hd)
+        second = (jnp.arange(H // G) % 2 == 1)[None, None, :, None]
+        zero = jnp.zeros_like(q)
+        q = jnp.concatenate([jnp.where(second, zero, q),
+                             jnp.where(second, q, zero)], axis=-1)
+    else:
+        G = cfg.n_kv_heads
+        q = q.reshape(B, G, H // G, hd)
     return jnp.pad(q, ((0, 0), (0, 0), (0, -(H // G) % 16), (0, 0)))
 
 
-def _paged_diff_attention(q, pages, work, layer, name, cfg):
+def _paged_attention(q, pages, work, layer, name, cfg):
     """Decode's attention: q [B, H, hd] against the live rows of ``pages`` [L,
-    NP, P, row] -> [B, H, 2 hd]."""
+    NP, P, row] -> [B, H, W] (``W``: ``_grouped_query``'s)."""
     from ray_tpu.ops.paged_attention import paged_gqa_decode
 
     B, H, _ = q.shape
@@ -359,6 +387,85 @@ def _paged_diff_attention(q, pages, work, layer, name, cfg):
                          name=name)
     G = o.shape[1]
     return o[:, :, :H // G].reshape(B, H, -1)
+
+
+# -- where a model with layer_kinds keeps what: ONE definition of the page and
+# ring arithmetic for every such model ---------------------------------------
+
+
+def _rows_at(t, pos):
+    """t [B, S, F] at positions pos [B, n]; zeros where pos < 0."""
+    got = jnp.take_along_axis(t, jnp.maximum(pos, 0)[..., None], axis=1)
+    return jnp.where((pos >= 0)[..., None], got, 0)
+
+
+def _write_rings(rings, layer, slots, row, ring_pos):
+    """A prefill call's rows ``row`` [B, S, F] into the rings of ``slots``. A
+    bucket no longer than the window cannot wrap: position t is entry t, and
+    the entries past the prompt are left as they are, since a decode step
+    counts a ring's filled entries and writes an entry before it first reads
+    it. A longer bucket gathers each entry's newest position."""
+    S, W = row.shape[1], rings.shape[2]
+    if S <= W:
+        return rings.at[layer, slots, :S].set(row)
+    return rings.at[layer, slots].set(_rows_at(row, ring_pos))
+
+
+def _prompt_index(cfg, cache, S, lengths, block_tables):
+    """Where a prefill call's ``[B, S]`` positions go: ``in_prompt`` [B, S],
+    the ``page`` and ``offset`` of each (padding -> the scratch page), ``last``
+    [B, 1] the last real position, and ``ring_pos`` [B, window]: ring entry j
+    holds the newest prompt position that is j mod window (< 0: none)."""
+    B = lengths.shape[0]
+    P, W = cache.pages.shape[2], cfg.window
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    in_prompt = positions < lengths[:, None]
+    page_for = jnp.take_along_axis(block_tables, positions // P, axis=1)
+    page = jnp.where(in_prompt, page_for, 0)
+    offset = jnp.where(in_prompt, positions % P, 0)
+    last = jnp.maximum(lengths - 1, 0).astype(jnp.int32)[:, None]
+    ring_pos = last - (last - jnp.arange(W, dtype=jnp.int32)[None]) % W
+    return positions, in_prompt, page, offset, last, ring_pos
+
+
+def _ring_block(cfg, page_size):
+    """The block a ring is read in: a page's positions where the window is
+    whole pages (a block of ``paged_gqa_decode`` then has a page's size
+    whichever it reads), else the whole ring."""
+    return page_size if cfg.window % page_size == 0 else cfg.window
+
+
+def _decode_index(cfg, cache, seq_lens, block_tables, active):
+    """A decode step's row: ``slot`` [B], ``positions`` [B], the ``page`` and
+    ``offset`` it is written at (inactive slots -> the scratch page), and the
+    kernel's two work lists (``ops/mla.py:live_pages``, built once a step):
+    the pages that hold live positions, and the ring blocks that hold filled
+    entries (a ring is its slot's own run of blocks, its live entries the
+    filled ones, in whatever order it holds them)."""
+    from ray_tpu.ops.mla import live_pages
+
+    B = seq_lens.shape[0]
+    P, W = cache.pages.shape[2], cfg.window
+    slot = jnp.arange(B, dtype=jnp.int32)
+    positions = seq_lens.astype(jnp.int32)
+    cur_page = jnp.take_along_axis(block_tables, positions[:, None] // P,
+                                   axis=1)[:, 0]
+    page = jnp.where(active, cur_page, 0)
+    offset = jnp.where(active, positions % P, 0)
+    work = live_pages(positions, active, block_tables, P)
+    blocks = W // _ring_block(cfg, P)
+    ring_tables = slot[:, None] * blocks + jnp.arange(
+        blocks, dtype=jnp.int32)[None]
+    ring_work = live_pages(jnp.minimum(positions, W - 1), active, ring_tables,
+                           W // blocks)
+    return slot, positions, page, offset, work, ring_work
+
+
+def _ring_blocks(rings, cfg, page_size):
+    """rings [layers, B, window, row] as the kernel reads them: [layers, B x
+    blocks, block, row], the same bytes."""
+    return rings.reshape(rings.shape[0], -1, _ring_block(cfg, page_size),
+                         rings.shape[-1])
 
 
 def _hybrid_head(x, p, cfg):
@@ -374,21 +481,10 @@ def _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
     from ray_tpu.ops.ssm import selective_scan
 
     B, S = tokens.shape
-    P, W, K = cache.pages.shape[1], cfg.window, cfg.ssm_conv
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    in_prompt = positions < lengths[:, None]
-    page_for = jnp.take_along_axis(block_tables, positions // P, axis=1)
-    page = jnp.where(in_prompt, page_for, 0)   # padding -> the scratch page
-    offset = jnp.where(in_prompt, positions % P, 0)
-    last = jnp.maximum(lengths - 1, 0).astype(jnp.int32)[:, None]
-    # ring entry j holds the newest prompt position that is j mod window
-    ring_pos = last - (last - jnp.arange(W, dtype=jnp.int32)[None]) % W  # [B, W]
+    W, K = cfg.window, cfg.ssm_conv
+    _, in_prompt, page, offset, last, ring_pos = _prompt_index(
+        cfg, cache, S, lengths, block_tables)
     tail_pos = lengths[:, None] - (K - 1) + jnp.arange(K - 1)[None]     # [B, K-1]
-
-    def rows_at(t, pos):
-        """t [B, S, F] at positions pos [B, n]; zeros where pos < 0."""
-        got = jnp.take_along_axis(t, jnp.maximum(pos, 0)[..., None], axis=1)
-        return jnp.where((pos >= 0)[..., None], got, 0)
 
     pages, rings, ssm, conv = cache[:4]
     x = p["embed"][tokens].astype(jnp.float32)   # the residual stream: float32
@@ -420,7 +516,7 @@ def _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
                 out, memory = _mamba_output(y, a, z, m, cfg)
                 ssm = ssm.at[mamba_i, slots].set(state)
                 # [layer, tap, slot]: the indexed axes come first, [B, K-1, I]
-                conv = conv.at[mamba_i, :, slots].set(rows_at(raw, tail_pos))
+                conv = conv.at[mamba_i, :, slots].set(_rows_at(raw, tail_pos))
             mamba_i += 1
         elif kind == "gmu":
             out = _memory_unit(h, memory, m, cfg)
@@ -441,10 +537,10 @@ def _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
                 q, k, v, causal=True, impl=cfg.attention_impl,
                 window=W if kind == "window" else 0), m, i, cfg)
             if kind == "window":
-                rings = rings.at[window_i, slots].set(rows_at(row, ring_pos))
+                rings = _write_rings(rings, window_i, slots, row, ring_pos)
                 window_i += 1
             else:
-                pages = pages.at[page, offset].set(row, mode="drop")
+                pages = pages.at[0, page, offset].set(row, mode="drop")
                 shared = (k, v)
         x = x + out
         x = x + _mlp(_layer_norm(x, lp["mlp_norm"], cfg), lp["mlp"], cfg.dtype)
@@ -452,21 +548,10 @@ def _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
 
 
 def _hybrid_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
-    from ray_tpu.ops.mla import live_pages
-
-    B = last_tokens.shape[0]
-    P, W = cache.pages.shape[1], cfg.window
-    slot = jnp.arange(B, dtype=jnp.int32)
-    positions = seq_lens.astype(jnp.int32)
-    cur_page = jnp.take_along_axis(block_tables, positions[:, None] // P,
-                                   axis=1)[:, 0]
-    page = jnp.where(active, cur_page, 0)  # inactive slots -> the scratch page
-    offset = jnp.where(active, positions % P, 0)
-    # the pages that hold live positions, once for the full layer and every
-    # cross layer; a ring is its slot's one page, its live entries the filled
-    work = live_pages(positions, active, block_tables, P)
-    ring_work = live_pages(jnp.minimum(positions, W - 1), active,
-                           slot[:, None], W)
+    P, W = cache.pages.shape[2], cfg.window
+    # one work list for the full layer and every cross layer, one for the rings
+    slot, positions, page, offset, work, ring_work = _decode_index(
+        cfg, cache, seq_lens, block_tables, active)
 
     pages, rings, ssm, conv = cache[:4]
     x = p["embed"][last_tokens].astype(jnp.float32)        # [B, d]
@@ -498,18 +583,128 @@ def _hybrid_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
             q, row = _diff_qkv(h, m, cfg)
             if kind == "window":
                 rings = rings.at[window_i, slot, positions % W].set(row)
-                o = _paged_diff_attention(q, rings, ring_work, window_i,
-                                          "window_gqa_decode", cfg)
+                o = _paged_attention(q, _ring_blocks(rings, cfg, P), ring_work,
+                                     window_i, "window_gqa_decode", cfg)
                 window_i += 1
             else:
                 if row is not None:   # the full layer writes the shared row
-                    pages = pages.at[page, offset].set(row, mode="drop")
-                o = _paged_diff_attention(q, pages[None], work, 0,
-                                          "paged_gqa_decode", cfg)
+                    pages = pages.at[0, page, offset].set(row, mode="drop")
+                o = _paged_attention(q, pages, work, 0, "paged_gqa_decode",
+                                     cfg)
             out = _diff_out(o, m, i, cfg)
         x = x + out
         x = x + _mlp(_layer_norm(x, lp["mlp_norm"], cfg), lp["mlp"], cfg.dtype)
     return _hybrid_head(x, p, cfg), HybridCache(pages, rings, ssm, conv)
+
+
+# ---------------------------------------------------------------------------
+# an RMSNorm block with layer_kinds (models/transformer.py:Block with a kind):
+# plain grouped-query heads, rotated or not by kind, pages for each "full"
+# layer and a ring for each "window" layer; with the per-head q/k norm, the
+# attention gate, the sandwich norms and the experts its config asks for. The
+# residual stream is float32, as the hybrid's: a normalised sublayer adds a
+# whole unit to it, which bfloat16 would round at every layer
+# ---------------------------------------------------------------------------
+
+
+def _kind_attn_inputs(x, lp, cfg, positions, kind):
+    """The float32 stream x [B, S, D] -> the layer's normalised input h, q
+    [B, S, H, hd] and the cache row ``k | v`` [B, S, 2 KVH hd]."""
+    h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps).astype(cfg.dtype)
+    q, k, v = _qkv(h, lp["attn"], cfg, positions, kind in cfg.rope_kinds)
+    flat = lambda t: t.reshape(*t.shape[:-2], -1)   # noqa: E731
+    return h, q, jnp.concatenate([flat(k), flat(v)], axis=-1)
+
+
+def _kind_block_rest(x, h, o, lp, cfg, valid, name):
+    """The block after its attention ``o`` [B, S, H, hd]: the gate, o_proj,
+    the residual (a norm on the way out under ``sandwich_norm``), the MLP or
+    the experts likewise. Returns (x, load)."""
+    f32 = jnp.float32
+    a = lp["attn"]
+    if cfg.attn_gate:
+        with jax.named_scope("attn.gate"):
+            o = o * jax.nn.sigmoid(jnp.einsum(
+                "...d,dhk->...hk", h, a["gate_proj"]["kernel"].astype(cfg.dtype)))
+    o = jnp.einsum("...hk,hkd->...d", o,
+                   a["o_proj"]["kernel"].astype(cfg.dtype)).astype(f32)
+    if cfg.sandwich_norm:
+        o = _rmsnorm(o, lp["post_attn_norm"]["scale"], cfg.norm_eps)
+    x = x + o
+    m = _rmsnorm(x, lp["mlp_norm"]["scale"], cfg.norm_eps).astype(cfg.dtype)
+    y, load = _ffn(m, lp, cfg, valid, name)
+    y = y.astype(f32)
+    if cfg.sandwich_norm:
+        y = _rmsnorm(y, lp["post_mlp_norm"]["scale"], cfg.norm_eps)
+    return x + y, load
+
+
+def _embed(p, cfg, tokens):
+    return p["embed"][tokens].astype(jnp.float32) * cfg.embed_scale
+
+
+def _kinds_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
+    from ray_tpu.ops.attention import attention as attention_op
+
+    B, S = tokens.shape
+    rep = cfg.n_heads // cfg.n_kv_heads
+    positions, in_prompt, page, offset, last, ring_pos = _prompt_index(
+        cfg, cache, S, lengths, block_tables)
+    pages, rings = cache[:2]
+    x = _embed(p, cfg, tokens)
+    loads = []
+    full_i = window_i = 0
+    for i, kind in enumerate(cfg.layer_kinds):
+        lp = p[f"layer_{i}"]
+        h, q, row = _kind_attn_inputs(x, lp, cfg, positions, kind)
+        if kind == "window":
+            rings = _write_rings(rings, window_i, slots, row, ring_pos)
+            window_i += 1
+        else:
+            pages = pages.at[full_i, page, offset].set(row, mode="drop")
+            full_i += 1
+        k, v = (t.reshape(B, S, cfg.n_kv_heads, -1)
+                for t in jnp.split(row, 2, axis=-1))
+        o = attention_op(q, jnp.repeat(k, rep, axis=2),
+                         jnp.repeat(v, rep, axis=2), causal=True,
+                         impl=cfg.attention_impl,
+                         window=cfg.window if kind == "window" else 0)
+        x, load = _kind_block_rest(x, h, o, lp, cfg, in_prompt,
+                                   "moe_gmm_prefill")
+        if load is not None:
+            loads.append(load)
+    x = jnp.take_along_axis(x, last[..., None], axis=1)[:, 0]
+    return _head(x, p, cfg), HybridCache(
+        pages, rings, None, None, jnp.stack(loads) if loads else None)
+
+
+def _kinds_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
+    P, W = cache.pages.shape[2], cfg.window
+    slot, positions, page, offset, work, ring_work = _decode_index(
+        cfg, cache, seq_lens, block_tables, active)
+    pages, rings = cache[:2]
+    x = _embed(p, cfg, last_tokens[:, None])                  # [B, 1, d]
+    loads = []
+    full_i = window_i = 0
+    for i, kind in enumerate(cfg.layer_kinds):
+        lp = p[f"layer_{i}"]
+        h, q, row = _kind_attn_inputs(x, lp, cfg, positions[:, None], kind)
+        if kind == "window":
+            rings = rings.at[window_i, slot, positions % W].set(row[:, 0])
+            o = _paged_attention(q[:, 0], _ring_blocks(rings, cfg, P),
+                                 ring_work, window_i, "window_gqa_decode", cfg)
+            window_i += 1
+        else:
+            pages = pages.at[full_i, page, offset].set(row[:, 0], mode="drop")
+            o = _paged_attention(q[:, 0], pages, work, full_i,
+                                 "paged_gqa_decode", cfg)
+            full_i += 1
+        x, load = _kind_block_rest(x, h, o[:, None], lp, cfg, active[:, None],
+                                   "moe_gmm_decode")
+        if load is not None:
+            loads.append(load)
+    return _head(x[:, 0], p, cfg), HybridCache(
+        pages, rings, None, None, jnp.stack(loads) if loads else None)
 
 
 # ---------------------------------------------------------------------------
@@ -542,8 +737,8 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
     if cfg.layer_kinds:
         if slots is None:
             slots = jnp.arange(B, dtype=jnp.int32)
-        return _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables,
-                               slots)
+        fn = _hybrid_prefill if cfg.sambay else _kinds_prefill
+        return fn(p, cfg, cache, tokens, lengths, block_tables, slots)
     P = cache[0].shape[2]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     in_prompt = positions < lengths[:, None]
@@ -598,6 +793,15 @@ def place_row(buffer: jax.Array, row: jax.Array, index: jax.Array) -> jax.Array:
 
 
 @jax.jit
+def split_key(rng: jax.Array):
+    """``jax.random.split(rng)`` as one program, the two keys apart: the
+    engine's stream and the key of one sampler call (eagerly it is a split
+    and two slices, each a dispatch of its own, once a step)."""
+    stream, key = jax.random.split(rng)
+    return stream, key
+
+
+@jax.jit
 def select_rows(mask: jax.Array, new: jax.Array, old: jax.Array) -> jax.Array:
     """[B] ``new`` where ``mask``, else ``old``: how the engine puts the
     tokens a prefill phase sampled (or an imported request's last one) among
@@ -607,7 +811,8 @@ def select_rows(mask: jax.Array, new: jax.Array, old: jax.Array) -> jax.Array:
 
 def _head(last, p, cfg):
     """Final norm and output head on [B, d] -> float32 logits [B, vocab]."""
-    last = _rmsnorm(last, p["final_norm"]["scale"], cfg.norm_eps)
+    last = _rmsnorm(last, p["final_norm"]["scale"], cfg.norm_eps
+                    ).astype(cfg.dtype)
     if cfg.tie_embeddings:
         logits = jnp.einsum("bd,vd->bv", last, p["embed"].astype(cfg.dtype))
     else:
@@ -634,8 +839,8 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
     """
     p = params["params"]
     if cfg.layer_kinds:
-        return _hybrid_decode(p, cfg, cache, last_tokens, seq_lens,
-                              block_tables, active)
+        fn = _hybrid_decode if cfg.sambay else _kinds_decode
+        return fn(p, cfg, cache, last_tokens, seq_lens, block_tables, active)
     B = last_tokens.shape[0]
     P = cache[0].shape[2]
     MP = block_tables.shape[1]

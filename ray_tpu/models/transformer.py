@@ -109,10 +109,17 @@ class TransformerConfig:
     # unit: it gates the scan output of the LAST "mamba" layer at the same
     # position) or "cross" (a query projection only: differential attention
     # over the keys and values the "full" layer made). () = attention with
-    # rotary embedding in every layer, as above. A model with kinds has
-    # LayerNorm with bias for RMSNorm, no position embedding, biases on the
-    # attention projections and a tied head (``HybridBlock``).
+    # rotary embedding in every layer, as above. A kind names a layer's MIXER
+    # and the state it keeps in serving ("window": a ring of ``window``
+    # positions a slot; "full": pages), and nothing more: what else a block
+    # is, ``block`` says. "sambay": LayerNorm with bias for RMSNorm, no
+    # position embedding, differential heads, biases on the attention
+    # projections and a tied head (``HybridBlock``). "rms": the RMSNorm
+    # block of every model without kinds (``Block``), plain grouped-query
+    # heads under the causal mask ("full") or the window's ("window"); only
+    # "window" and "full" are kinds of such a block.
     layer_kinds: Tuple[str, ...] = ()
+    block: str = "sambay"
     window: int = 0
     ssm_inner: int = 0      # width of the scan and of the gated memory
     ssm_state: int = 16
@@ -129,13 +136,52 @@ class TransformerConfig:
     # show in the logits without amplifying each other's rounding need a
     # stream that starts out as large as what they add
     embed_init_std: float = 0.0
+    # head geometry: the width of a head where it is not d_model // n_heads
+    # (q and o are then d_model x n_heads * head_size)
+    head_size: int = 0
+    # RMSNorm over head_dim of every q and k head (one scale for all heads),
+    # before RoPE; ``qk_norm`` above is over the whole projection instead
+    qk_head_norm: bool = False
+    # a sigmoid gate on the attention's output, head by head and lane by
+    # lane, from the layer's own input: o_proj((attention) * sigmoid(h W_g))
+    attn_gate: bool = False
+    # four norms a block: each of attention and MLP is normalised going in
+    # AND coming out, x + norm(f(norm(x)))
+    sandwich_norm: bool = False
+    # the table's rows times this (a muP model: sqrt(d_model))
+    embed_scale: float = 1.0
+    # the kinds of layer that rotate q and k; a kind left out has no position
+    # embedding at all. Without ``layer_kinds`` every layer rotates
+    rope_kinds: Tuple[str, ...] = ("window", "full")
+    # one rank of an expert-parallel deployment: (first, count), the routed
+    # experts this program holds. The router keeps its ``n_experts`` outputs
+    # and top-k; the expert matrices are ``count`` deep and an assignment to
+    # an expert held elsewhere adds nothing here. () = all of them
+    experts_held: Tuple[int, ...] = ()
+    # what the routed experts' matrices are drawn at where it is not the
+    # MLPs' (0 = mlp_init_std): the shared expert stays at the MLPs'
+    expert_init_std: float = 0.0
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def sambay(self) -> bool:
+        """Layers by kind in a decoder-hybrid-decoder's block."""
+        return bool(self.layer_kinds) and self.block == "sambay"
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
+    def layer_kind(self, i: int) -> str:
+        return self.layer_kinds[i] if self.layer_kinds else "full"
 
     def init_std(self, part: str) -> float:
-        """``part``: "attn", "mlp", "ssm_proj", "ssm_x" or "embed"."""
+        """``part``: "attn", "mlp", "expert", "ssm_proj", "ssm_x" or "embed"."""
+        if part == "expert" and not self.expert_init_std:
+            part = "mlp"
         return getattr(self, part + "_init_std") or (
             0.02 if part == "embed" else 0.02 / np.sqrt(2 * self.n_layers))
 
@@ -145,7 +191,7 @@ class TransformerConfig:
 
     def num_params(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
-        if self.layer_kinds:
+        if self.sambay:
             inner, hd = self.ssm_inner, self.head_dim
             qkv = (self.n_heads + 2 * self.n_kv_heads) * hd
             attn = d * d + d + 6 * hd       # o and its bias, lambdas, sub-norm
@@ -169,15 +215,17 @@ class TransformerConfig:
                 + 2 * d  # norms
             )
         else:
+            q = d * self.n_heads * self.head_dim
             attn = (
-                d * d  # q
+                q * (3 if self.attn_gate else 2)  # q, o and the gate
                 + 2 * d * (self.n_kv_heads * self.head_dim)  # k, v
-                + d * d  # o
-                + 2 * d  # norms
-                + (d + self.n_kv_heads * self.head_dim if self.qk_norm else 0)
+                + (4 if self.sandwich_norm else 2) * d  # norms
+                + ((self.n_heads + self.n_kv_heads) * self.head_dim
+                   if self.qk_norm else 0)
+                + (2 * self.head_dim if self.qk_head_norm else 0)
             )
         dense_mlp = 3 * d * (self.d_ff_dense or f)
-        moe_mlp = (self.n_experts * 3 * d * f + d * self.n_experts
+        moe_mlp = (self.n_experts_held * 3 * d * f + d * self.n_experts
                    + 3 * d * self.n_shared_experts * f
                    + (self.n_experts if self.router_kind == "sigmoid" else 0))
         total = 0
@@ -240,6 +288,7 @@ class RMSNorm(nn.Module):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    kind: str = "full"  # "window": a query sees its cfg.window newest keys
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None):
@@ -267,14 +316,23 @@ class Attention(nn.Module):
                 return RMSNorm(cfg.norm_eps, cfg.dtype, axis=None,
                                name=name)(flat).reshape(t.shape)
             q, k = whole(q, "q_norm"), whole(k, "k_norm")
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        if cfg.qk_head_norm:  # head by head, one scale for all of them
+            q = RMSNorm(cfg.norm_eps, cfg.dtype, axis=None, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, cfg.dtype, axis=None, name="k_norm")(k)
+        if not cfg.layer_kinds or self.kind in cfg.rope_kinds:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         if cfg.n_kv_heads != cfg.n_heads:
             rep = cfg.n_heads // cfg.n_kv_heads
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
         out = attention_op(q, k, v, causal=True, impl=cfg.attention_impl,
-                           segment_ids=segment_ids)
+                           segment_ids=segment_ids,
+                           window=cfg.window if self.kind == "window" else 0)
+        if cfg.attn_gate:
+            out = out * nn.sigmoid(dense(
+                (cfg.n_heads, hd), ("embed", "heads", "head_dim"),
+                "gate_proj")(x))
         out = nn.DenseGeneral(
             features=cfg.d_model, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="o_proj",
@@ -406,6 +464,11 @@ class MoEMLP(nn.Module):
         combine = (eh * ph
                    * (gate_vals * keep)[..., None, None].astype(cfg.dtype)).sum(2)
 
+        # one rank of an expert-parallel deployment computes its own experts'
+        # part: what the others would add is not here
+        first, held = cfg.experts_held or (0, E)
+        dispatch = dispatch[:, :, first:first + held]
+        combine = combine[:, :, first:first + held]
         expert_in = jnp.einsum("gnec,gnd->gecd", dispatch, xf)
         expert_in = nn.with_logical_constraint(
             expert_in, (None, "expert", None, "embed"))
@@ -413,15 +476,15 @@ class MoEMLP(nn.Module):
         def stack_param(name, shape, axes):
             return self.param(
                 name, nn.with_logical_partitioning(
-                    nn.initializers.normal(cfg.init_std("mlp")),
+                    nn.initializers.normal(cfg.init_std("expert")),
                     axes),
                 shape, cfg.param_dtype)
 
-        w_gate = stack_param("gate_proj", (E, D, cfg.d_ff),
+        w_gate = stack_param("gate_proj", (held, D, cfg.d_ff),
                              ("expert", "embed", "mlp"))
-        w_up = stack_param("up_proj", (E, D, cfg.d_ff),
+        w_up = stack_param("up_proj", (held, D, cfg.d_ff),
                            ("expert", "embed", "mlp"))
-        w_down = stack_param("down_proj", (E, cfg.d_ff, D),
+        w_down = stack_param("down_proj", (held, cfg.d_ff, D),
                              ("expert", "mlp", "embed"))
         h = (nn.silu(jnp.einsum("gecd,edf->gecf", expert_in,
                                 w_gate.astype(cfg.dtype)))
@@ -643,17 +706,20 @@ class HybridBlock(nn.Module):
 class Block(nn.Module):
     cfg: TransformerConfig
     use_moe: bool = False
+    kind: str = "full"
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None):
         cfg = self.cfg
-        h = x + Attention(cfg, name="attn")(
-            RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x), positions,
-            segment_ids)
+        norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)  # noqa: E731
+        a = Attention(cfg, self.kind, name="attn")(
+            norm("attn_norm")(x), positions, segment_ids)
+        h = x + (norm("post_attn_norm")(a) if cfg.sandwich_norm else a)
         h = nn.with_logical_constraint(h, ("batch", "seq", "embed"))
         mlp = MoEMLP(cfg, name="moe") if self.use_moe else MLP(
             cfg, cfg.d_ff_dense, name="mlp")
-        out = h + mlp(RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h))
+        y = mlp(norm("mlp_norm")(h))
+        out = h + (norm("post_mlp_norm")(y) if cfg.sandwich_norm else y)
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
@@ -674,8 +740,10 @@ class Transformer(nn.Module):
                 ("vocab", "embed")),
             (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
         x = embed.astype(cfg.dtype)[tokens]
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-        if cfg.layer_kinds:
+        if cfg.sambay:
             memory = shared = None
             for i, kind in enumerate(cfg.layer_kinds):
                 x, memory, shared = HybridBlock(
@@ -688,8 +756,8 @@ class Transformer(nn.Module):
             block = nn.remat(Block, prevent_cse=False,
                              policy=jax.checkpoint_policies.nothing_saveable)
         for i in range(cfg.n_layers):
-            x = block(cfg, cfg.is_moe_layer(i), name=f"layer_{i}")(
-                x, positions, segment_ids)
+            x = block(cfg, cfg.is_moe_layer(i), cfg.layer_kind(i),
+                      name=f"layer_{i}")(x, positions, segment_ids)
         x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
         if cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype))

@@ -18,6 +18,13 @@ experts, whatever the load; there is no capacity and nothing falls through.
    three products of ``down(silu(gate(x)) * up(x))``;
 5. the weighted sum over a row's experts, back in row order.
 
+One rank of an expert-parallel deployment is told which experts it holds
+(``held = (first, count)``): it routes over ALL the router's outputs, as every
+rank does, and computes the part of the result its own ``count`` experts
+give. An assignment to an expert held elsewhere sorts behind every group as
+an invalid row's does: it lies in no group, costs no row tile and adds
+nothing; there is no stand-in for the other ranks and no exchange.
+
 ``grouped_matmul`` is a Pallas kernel: for each (group, row tile) pair that
 holds a real row it multiplies the tile by that group's matrix, so a step
 streams the touched experts' weights once and nothing else. ``name`` is the
@@ -175,17 +182,29 @@ def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
                  top_k: int, norm_topk_prob: bool,
                  name: str = "moe_gmm", router_kind: str = "softmax",
                  router_bias: Optional[jax.Array] = None,
-                 router_scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
-    """x [T, D], valid [T] bool, router [D, E], w_gate / w_up [E, D, F],
+                 router_scale: float = 1.0,
+                 held: Optional[Tuple[int, int]] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """x [T, D], valid [T] bool, router [D, R], w_gate / w_up [E, D, F],
     w_down [E, F, D] -> (y [T, D] in x's dtype, load [E] int32). ``load`` is
     the number of real rows each expert got; a row that is not valid gives
-    zeros and loads nobody. The ``router_*`` arguments are ``route``'s."""
+    zeros and loads nobody. The ``router_*`` arguments are ``route``'s.
+    ``held = (first, E)``: the matrices are those of experts ``first .. first
+    + E`` of the router's ``R``; without it ``E`` is ``R`` and all are here."""
     T, D = x.shape
-    E = router.shape[1]
+    E = w_gate.shape[0]
     weights, experts = route(x, router, top_k, norm_topk_prob, router_kind,
                              router_bias, router_scale)
-    # an assignment of an invalid row goes to "expert E": behind every group
-    flat = jnp.where(valid[:, None], experts, E).reshape(-1)
+    if held is not None:
+        assert held[1] == E and held[0] + E <= router.shape[1], (held, E)
+        experts = experts - held[0]
+        valid = valid[:, None] & (experts >= 0) & (experts < E)
+    else:
+        assert E == router.shape[1], (E, router.shape)
+        valid = jnp.broadcast_to(valid[:, None], experts.shape)
+    # an assignment of an invalid row, or to an expert that is not here, goes
+    # to "expert E": behind every group
+    flat = jnp.where(valid, experts, E).reshape(-1)
     load = (flat[:, None] == jnp.arange(E, dtype=jnp.int32)).sum(
         0, dtype=jnp.int32)
     M = T * top_k
@@ -202,5 +221,5 @@ def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
     where = jnp.zeros(padded, jnp.int32).at[order].set(
         jnp.arange(padded, dtype=jnp.int32))[:M].reshape(T, top_k)
     picked = out[where].astype(jnp.float32)           # [T, top_k, D]
-    y = jnp.where(valid[:, None, None], picked * weights[..., None], 0.0)
+    y = jnp.where(valid[..., None], picked * weights[..., None], 0.0)
     return y.sum(1).astype(x.dtype), load

@@ -687,7 +687,7 @@ def test_hybrid_decode_compiles_with_the_paged_kernels(one_chip):
     held = sum(x.size * x.dtype.itemsize for x in cache[:4])
     print(f"hybrid decode, 8 layers, 48 slots: {live} bytes live, {temp} of "
           f"temporaries; cache {held}")
-    assert cache.pages.shape == (961, 512, 2560)
+    assert cache.pages.shape == (1, 961, 512, 2560)
     assert cache.rings.shape == (2, 48, 512, 2560)
     assert cache.ssm.shape == (3, 48, 16, 5120)
     assert temp < cache.rings.size * 2 // 2 // 4
@@ -714,3 +714,95 @@ def test_hybrid_prefill_compiles_with_scan_and_window(one_chip, bucket):
     print(f"hybrid prefill, 8 layers, [1, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")
     assert 0 < live < 16 << 30
+
+
+# -- one rank of Trinity-Large-Preview (afmoe, the benchmark's file) ----------------
+
+
+def _lower_afmoe(one_chip):
+    """The engine's programs at the published widths and the whole cut of
+    ``benchmarks/configs/trinity-large-preview.json`` (5 layers, 32 of 256
+    experts held, 32 slots x 16896, pages of 512)."""
+    import json
+
+    import flax.linen as nn
+
+    from benchmarks.jobs import common
+    from benchmarks.registry import REPO
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.llm.config import EngineConfig
+    from ray_tpu.models.transformer import Transformer
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "trinity-large-preview.json")) as f:
+        conf = json.load(f)
+    e = EngineConfig(**conf["job"]["engine"])
+    cfg = dataclasses.replace(
+        common.transformer_config(conf, e.max_model_len),
+        attention_impl="flash")
+    params = _on(jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))), one_chip)
+    cache = _on(jax.eval_shape(lambda: mr.init_cache(
+        cfg, e.num_pages, e.page_size, e.max_num_seqs)), one_chip)
+    B, MP = e.max_num_seqs, e.pages_per_seq
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+
+    def prefill(rows, bucket):
+        slots = (i32(1),) if rows == 1 else ()    # the check gives none
+        return mr.prefill.lower(params, cfg, cache, i32(rows, bucket),
+                                i32(rows), i32(rows, MP), *slots)
+
+    return cache, prefill, lambda: mr.decode_step.lower(
+        params, cfg, cache, i32(B), i32(B), i32(B, MP), active)
+
+
+def test_afmoe_decode_reads_rings_and_live_pages_and_nothing_else(one_chip):
+    """Decode at 32 slots x 16,896: the paged kernel over the rings of the four
+    sliding layers (8 blocks of 512 a ring) and over the one full layer's live
+    pages, plain heads (8 groups of 6 query rows padded to 16, 128 lanes);
+    three grouped matmuls in each of the four expert layers over the 32 held
+    experts; the cache written in place, and NO array a slot's whole length
+    long: nothing is gathered over ``Lmax``."""
+    cache, _, decode = _lower_afmoe(one_chip)
+    compiled = decode().compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%(window_gqa_decode\S*) = bf16\[32,8,16,128\]",
+                              text))) == 4
+    assert len(set(re.findall(r"%(paged_gqa_decode\S*) = bf16\[32,8,16,128\]",
+                              text))) == 1
+    assert len(set(re.findall(r"%(moe_gmm_decode\S*) = bf16\[128,3072\]",
+                              text))) == 12
+    assert text.count("tpu_custom_call") == 17
+    assert cache.pages.shape == (1, 1057, 512, 2048)
+    assert cache.rings.shape == (4, 32, 4096, 2048)
+    assert cache.moe_load.shape == (4, 32) and cache.ssm is None
+    assert not re.search(r"\[32,(16896|33,512),", text)
+    live, temp = _live(compiled)
+    held = sum(x.size * x.dtype.itemsize for x in cache[:2])
+    print(f"afmoe decode, 32 slots: {live} bytes live, {temp} of "
+          f"temporaries; cache {held}")
+    assert temp < 64 << 20
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
+@pytest.mark.parametrize("rows,bucket", [(1, 16384), (32, 256)])
+def test_afmoe_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
+    """The engine's largest call, ``[1, 16384]``, and the benchmark check's
+    every-slot ``[32, 256]`` call, beside 8.65 GB of weights and 4.36 GB of
+    pages and rings: five flash calls (the window's in four of them) and
+    twelve grouped matmuls, under the chip's 15.75 GiB. What an execution
+    holds live is printed (``-s``) and stands in PERF.md section 4."""
+    _, prefill, _ = _lower_afmoe(one_chip)
+    compiled = prefill(rows, bucket).compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(
+        rf"%(flash_fwd\S*) = \(bf16\[{rows * 48},{bucket},128\]", text))) == 5
+    assert text.count("tpu_custom_call") == 17
+    live, temp = _live(compiled)
+    print(f"afmoe prefill [{rows}, {bucket}]: {live} bytes live, "
+          f"{temp} of temporaries")
+    assert 0 < live < int(15.5 * 2 ** 30)

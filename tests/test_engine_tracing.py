@@ -34,6 +34,7 @@ KEYS = {
     # what routing did; a dense model's (this one's) stay 0
     "moe_decode_layer_steps", "moe_decode_assignments",
     "moe_decode_experts_touched", "moe_decode_max_load",
+    "moe_decode_routed_assignments",
     # what a latent cache's decode read; 0 without one
     "mla_decode_live_tokens", "mla_decode_read_tokens",
     # a model with layer_kinds: its shared layer's pages, rings, recurrent

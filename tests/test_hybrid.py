@@ -236,7 +236,7 @@ def test_prefill_state_is_the_last_real_position(engine):
     (la, a), (lb, b) = run(16), run(32)
     assert _rel(lb, la) < 1e-5
     # page 0 is scratch: the padded bucket's padding lands there
-    for x, y in zip((a.pages[1:],) + a[1:4], (b.pages[1:],) + b[1:4]):
+    for x, y in zip((a.pages[:, 1:],) + a[1:4], (b.pages[:, 1:],) + b[1:4]):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
                                    atol=1e-5)
     assert float(jnp.abs(a.ssm[:, 1]).max()) > 0        # the slot asked for
@@ -346,7 +346,7 @@ def test_paged_decode_matches_masked_reference():
                               d_model=H * hd)
     work = live_pages(jnp.asarray(seq_lens), jnp.asarray(active),
                       jnp.asarray(tables), P)
-    got = mr._paged_diff_attention(q, pages, work, 1, "paged_gqa_decode", cfg)
+    got = mr._paged_attention(q, pages, work, 1, "paged_gqa_decode", cfg)
     assert got.shape == (B, H, W)
     for b in range(B):
         live = seq_lens[b] + 1 if active[b] else 0

@@ -322,6 +322,48 @@ def test_per_request_seed_batch_independent(engine):
     assert mixed[0].token_ids == alone
 
 
+@pytest.mark.parametrize("name", ["tables", "active", "temps", "top_ks",
+                                  "top_ps", "seeds"])
+def test_kept_upload_follows_the_host_array(name):
+    """What a decode step and its sampler take from the host beside the
+    lengths is sent once and handed out again while the host's array reads
+    the same; a write to the host's array sends a new copy, and no write
+    reaches the copy a queued program was given."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    eng = JaxLLMEngine(make_config(), seed=0)
+    host = {"tables": eng._block_tables, "active": eng._active,
+            "temps": eng._temps, "top_ks": eng._top_ks,
+            "top_ps": eng._top_ps, "seeds": eng._seeds}[name]
+    first = eng._up(host, name)
+    before = host.copy()
+    assert eng._up(host, name) is first
+    host.flat[0] = 1 if host.flat[0] != 1 else 0
+    second = eng._up(host, name)
+    assert second is not first
+    np.testing.assert_array_equal(np.asarray(first), before)
+    np.testing.assert_array_equal(np.asarray(second), host)
+    assert eng._up(host, name) is second
+    assert eng._up(host) is not second      # unnamed: a transfer every time
+
+
+def test_kept_uploads_change_with_admission_not_with_a_step(engine):
+    """Over a run of decode steps with nothing admitted, released or grown a
+    page, only the lengths travel: every kept copy is the same object."""
+    engine.add_request("kept", list(range(3, 9)), SamplingParams(max_tokens=9))
+    engine.step()
+    engine.step()                            # prefill, then the first decode
+    kept = {k: v[1] for k, v in engine._kept.items()}
+    assert set(kept) == {"tables", "active", "temps", "top_ks", "top_ps",
+                         "seeds", "steps"}
+    engine.step()
+    engine.step()
+    assert all(engine._kept[k][1] is v for k, v in kept.items())
+    while engine.has_unfinished():
+        engine.step()
+    assert not engine._active.any()
+
+
 def test_capacity_rejection():
     """A request that can never fit the page pool raises instead of
     livelocking admission (num_pages too small for prompt+max_tokens)."""
