@@ -53,6 +53,11 @@ from ``__init__``, so two snapshots subtract):
 - counts: ``steps`` (calls of ``step()`` that ran a program),
   ``overlapped_steps`` (those that did so while an earlier step's tokens were
   unread: all but the first of a busy stretch), ``dropped_tokens``,
+  ``sample_calls`` (calls of the sampler program, ``jit_sample_tokens``: one
+  a decode step and one a prefill phase) and ``sample_greedy_calls`` (those
+  with no positive temperature in any slot, counted from the host's own
+  ``_temps`` by the rule the program applies to its copy on the device: it
+  then takes its ``argmax`` branch and computes no shortlist),
   ``prefill_steps`` (steps that ran a prefill phase), ``decode_steps`` (a
   dropped token's step is one),
   ``admitted`` (also the number of prefill program calls),
@@ -141,9 +146,10 @@ The same boundaries are spans on the profiler's clock
 (``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
 that owns the engine shows ``ray_tpu/engine.step`` on the host plane and,
 inside it, in this order, ``engine.admit``, ``.prefill_dispatch`` (arguments
-``bucket``, the largest of the phase, and ``admitted``), ``.sample_dispatch``,
-``.decode_dispatch`` (arguments ``overlapped``: 1 if an earlier step is
-unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
+``bucket``, the largest of the phase, and ``admitted``), ``.sample_dispatch``
+(argument ``greedy``: no slot samples, the program takes its ``argmax``
+branch), ``.decode_dispatch`` (arguments ``overlapped``: 1 if an earlier step
+is unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
 per layer in the newest decode step the host has read, and ``held``: that
 step's assignments per layer to experts held here, models with experts only; ``live_tokens``: positions the step attends over through the block
 tables, models with a latent cache or with ``layer_kinds`` only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
@@ -329,6 +335,7 @@ class JaxLLMEngine:
             "emit_ms": 0.0, "between_steps_ms": 0.0,
             "queue_wait_ms": 0.0, "ttft_ms": 0.0,
             "overlapped_steps": 0, "dropped_tokens": 0,
+            "sample_calls": 0, "sample_greedy_calls": 0,
             "prefill_phase_ms": 0.0, "decode_phase_ms": 0.0, "phase_ms": 0.0,
             "prefill_phase_calls": 0, "decode_phase_calls": 0,
             "itl_ms": 0.0, "itl_tokens": 0, **dict.fromkeys(_ITL_KEYS, 0),
@@ -419,6 +426,9 @@ class JaxLLMEngine:
             self._slots[req.slot] = None
             self._seq_lens[req.slot] = 0
             self._block_tables[req.slot, :] = 0
+            # an empty slot samples nothing: left at a positive temperature
+            # it would hold every later batch on the sampler's shortlist
+            self._temps[req.slot] = 0.0
             req.slot = -1
 
     def _try_admit(self) -> List[_Request]:
@@ -513,7 +523,12 @@ class JaxLLMEngine:
         """One sampler call over every slot's row of ``logits``. The tokens
         stay on the device; their copy to the host starts and nothing waits
         for it (``_read`` does, a step later)."""
-        with self._phase("sample_dispatch"):
+        # the rule ``sample_tokens`` applies on the device to the same array:
+        # with no positive temperature it takes its argmax branch
+        greedy = not (self._temps > 0).any()
+        self.metrics["sample_calls"] += 1
+        self.metrics["sample_greedy_calls"] += greedy
+        with self._phase("sample_dispatch", greedy=greedy):
             # a seeded request's position in its stream: the tokens it was
             # given, read or not (the engine's own stream takes no position)
             steps = np.zeros(len(self._slots), np.int32)

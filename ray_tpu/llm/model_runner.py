@@ -917,30 +917,50 @@ def sample_tokens(logits: jax.Array, rng: jax.Array, temps: jax.Array,
     """Per-slot sampling: greedy when temp==0, else temp/top-k/top-p over a
     static top-``max_top_k`` shortlist (keeps the program shape static).
 
+    One program with two branches, taken on the device from ``temps``
+    (``jax.lax.cond``: no host read, no second program):
+
+    - no slot has a positive temperature: ``argmax`` over the vocabulary and
+      nothing else. No shortlist, softmax, cumulative sum or key is computed,
+      since no row would read them;
+    - any slot samples: the shortlist (``jax.lax.top_k`` over the whole
+      vocabulary), temperature, top-k and top-p masks over it, one key a
+      slot and a categorical draw; the greedy rows of such a batch still take
+      the same ``argmax``.
+
+    A row's token is the same whichever branch its batch takes.
+
     ``seeds[b] >= 0`` gives that slot its own reproducible stream
     (PRNGKey(seed) folded with the slot's step count), independent of batch
     composition; ``seeds[b] < 0`` draws from the engine-global stream."""
     B, V = logits.shape
     K = min(max_top_k, V)
-    greedy = jnp.argmax(logits, axis=-1)
 
-    vals, idx = jax.lax.top_k(logits, K)  # [B, K] descending
-    safe_t = jnp.maximum(temps, 1e-6)[:, None]
-    scaled = vals / safe_t
-    ranks = jnp.arange(K, dtype=jnp.int32)[None]
-    k_lim = jnp.where(top_ks <= 0, K, jnp.minimum(top_ks, K))[:, None]
-    mask = ranks < k_lim
-    probs = jax.nn.softmax(jnp.where(mask, scaled, -1e30), axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    # keep tokens whose cumulative prob before them is < top_p
-    mask = mask & ((cum - probs) < top_ps[:, None])
-    final = jnp.where(mask, scaled, -1e30)
+    def greedy_path():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    global_keys = jax.random.split(rng, B)
-    seeded_keys = jax.vmap(
-        lambda s, st: jax.random.fold_in(jax.random.PRNGKey(s), st)
-    )(jnp.maximum(seeds, 0).astype(jnp.uint32), steps.astype(jnp.uint32))
-    keys = jnp.where((seeds >= 0)[:, None], seeded_keys, global_keys)
-    sampled_pos = jax.vmap(jax.random.categorical)(keys, final)
-    sampled = jnp.take_along_axis(idx, sampled_pos[:, None], axis=1)[:, 0]
-    return jnp.where(temps <= 0, greedy, sampled).astype(jnp.int32)
+    def shortlist_path():
+        greedy = jnp.argmax(logits, axis=-1)
+
+        vals, idx = jax.lax.top_k(logits, K)  # [B, K] descending
+        safe_t = jnp.maximum(temps, 1e-6)[:, None]
+        scaled = vals / safe_t
+        ranks = jnp.arange(K, dtype=jnp.int32)[None]
+        k_lim = jnp.where(top_ks <= 0, K, jnp.minimum(top_ks, K))[:, None]
+        mask = ranks < k_lim
+        probs = jax.nn.softmax(jnp.where(mask, scaled, -1e30), axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        # keep tokens whose cumulative prob before them is < top_p
+        mask = mask & ((cum - probs) < top_ps[:, None])
+        final = jnp.where(mask, scaled, -1e30)
+
+        global_keys = jax.random.split(rng, B)
+        seeded_keys = jax.vmap(
+            lambda s, st: jax.random.fold_in(jax.random.PRNGKey(s), st)
+        )(jnp.maximum(seeds, 0).astype(jnp.uint32), steps.astype(jnp.uint32))
+        keys = jnp.where((seeds >= 0)[:, None], seeded_keys, global_keys)
+        sampled_pos = jax.vmap(jax.random.categorical)(keys, final)
+        sampled = jnp.take_along_axis(idx, sampled_pos[:, None], axis=1)[:, 0]
+        return jnp.where(temps <= 0, greedy, sampled).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(temps > 0), shortlist_path, greedy_path)

@@ -24,6 +24,8 @@ KEYS = {
     "between_steps_ms", "queue_wait_ms", "ttft_ms",
     # steps dispatched behind an unread one; tokens computed for nobody
     "overlapped_steps", "dropped_tokens",
+    # sampler calls, and those in which no slot had a positive temperature
+    "sample_calls", "sample_greedy_calls",
     # what each blocking read waited for, by kind, and both
     "prefill_phase_ms", "decode_phase_ms", "phase_ms",
     "prefill_phase_calls", "decode_phase_calls",
@@ -128,6 +130,9 @@ def test_metrics_complete_numeric_monotone(engine):
     assert m["steps"] <= m["prefill_steps"] + m["decode_steps"]
     # every step but the first was dispatched before the one before was read
     assert m["overlapped_steps"] == m["steps"] - 1
+    # one sampler call a prefill phase and one a decode step; nobody sampled
+    assert m["sample_calls"] == m["prefill_steps"] + m["decode_steps"]
+    assert m["sample_greedy_calls"] == m["sample_calls"]
     # a token computed for a request that EOS had ended is not a generated one
     assert m["generated_tokens"] <= sum(4 + i for i in range(SLOTS + 2))
     assert m["between_steps_ms"] > 0
@@ -330,6 +335,7 @@ def test_engine_spans_nest_in_step_order(engine, recorder):
     assert attrs["ray_tpu/engine.compile"]["program"] == "decode"
     assert attrs["ray_tpu/engine.decode_dispatch"] == {
         "overlapped": 0, "dropped": 0}
+    assert attrs["ray_tpu/engine.sample_dispatch"] == {"greedy": True}
     # a shape this engine has used is not a compile again; the second call
     # reads the two sampler calls of the first behind its own dispatch
     del recorder[:]

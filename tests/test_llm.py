@@ -3,6 +3,8 @@ hardware-free tier). Covers: paged-KV decode vs. the training forward,
 continuous batching determinism, page-boundary growth, serve + data
 integration."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -320,6 +322,243 @@ def test_per_request_seed_batch_independent(engine):
     e2 = JaxLLMEngine(make_config(), params=engine.params, seed=999)
     mixed = e2.generate(["seeded prompt", "other a", "other b"], sp)
     assert mixed[0].token_ids == alone
+
+
+def _shortlist_sampler():
+    """``sample_tokens`` as it stood before it branched, kept plain as the
+    oracle: the shortlist, the masks, the keys and the draw for EVERY batch,
+    and a select between the draw and the argmax at the end."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.jit, static_argnames=("max_top_k",))
+    def sample(logits, rng, temps, top_ks, top_ps, seeds, steps,
+               max_top_k=64):
+        B, V = logits.shape
+        K = min(max_top_k, V)
+        greedy = jnp.argmax(logits, axis=-1)
+        vals, idx = jax.lax.top_k(logits, K)
+        safe_t = jnp.maximum(temps, 1e-6)[:, None]
+        scaled = vals / safe_t
+        ranks = jnp.arange(K, dtype=jnp.int32)[None]
+        k_lim = jnp.where(top_ks <= 0, K, jnp.minimum(top_ks, K))[:, None]
+        mask = ranks < k_lim
+        probs = jax.nn.softmax(jnp.where(mask, scaled, -1e30), axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        mask = mask & ((cum - probs) < top_ps[:, None])
+        final = jnp.where(mask, scaled, -1e30)
+        global_keys = jax.random.split(rng, B)
+        seeded_keys = jax.vmap(
+            lambda s, st: jax.random.fold_in(jax.random.PRNGKey(s), st)
+        )(jnp.maximum(seeds, 0).astype(jnp.uint32), steps.astype(jnp.uint32))
+        keys = jnp.where((seeds >= 0)[:, None], seeded_keys, global_keys)
+        sampled_pos = jax.vmap(jax.random.categorical)(keys, final)
+        sampled = jnp.take_along_axis(idx, sampled_pos[:, None], axis=1)[:, 0]
+        return jnp.where(temps <= 0, greedy, sampled).astype(jnp.int32)
+    return sample
+
+
+# vocabulary, then a slot's temperature, top_k, top_p, seed (-1: the engine's
+# stream) and position in its stream
+_SAMPLER_BATCHES = {
+    "all-greedy": (300, [(0.0, 0, 1.0, -1, 0), (0.0, 5, 0.9, 11, 3),
+                         (0.0, 0, 0.5, -1, 0), (0.0, 100, 1.0, 4, 2),
+                         (0.0, 0, 1.0, -1, 0), (0.0, 64, 0.3, 7, 9)]),
+    "all-greedy-vocab-under-the-shortlist": (
+        40, [(0.0, 0, 1.0, -1, 0), (0.0, 3, 0.9, 2, 1)]),
+    "mixed-greedy-seeded-unseeded": (
+        300, [(0.0, 0, 1.0, -1, 0), (0.7, 5, 0.9, 11, 3),
+              (1.3, 0, 0.5, -1, 0), (0.0, 3, 1.0, 4, 2),
+              (0.9, 64, 1.0, 7, 9), (2.0, 100, 0.3, -1, 0)]),
+    "mixed-one-slot-samples": (
+        300, [(0.0, 0, 1.0, -1, 0), (0.0, 0, 1.0, 3, 5), (0.0, 8, 0.8, -1, 0),
+              (1.0, 8, 0.8, -1, 0), (0.0, 0, 1.0, -1, 0)]),
+    "mixed-vocab-under-the-shortlist": (
+        40, [(0.0, 0, 1.0, -1, 0), (1.1, 3, 0.9, 2, 1), (0.6, 0, 0.7, -1, 0)]),
+    "all-sample": (300, [(0.8, 16, 1.0, 5, 0), (1.0, 0, 0.9, -1, 0),
+                         (1.5, 4, 0.6, 6, 12)]),
+}
+
+
+def _check_batch_against_the_old_formula(vocab, slots):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import model_runner as mr
+
+    old = _shortlist_sampler()
+    temps, top_ks, top_ps, seeds, steps = zip(*slots)
+    sampling = tuple(jnp.asarray(a, dt) for a, dt in (
+        (temps, jnp.float32), (top_ks, jnp.int32), (top_ps, jnp.float32),
+        (seeds, jnp.int32), (steps, jnp.int32)))
+    drawn = False
+    for trial in range(6):
+        logits = jax.random.normal(jax.random.PRNGKey(100 + trial),
+                                   (len(slots), vocab), jnp.float32)
+        rng = jax.random.PRNGKey(trial)
+        got = np.asarray(mr.sample_tokens(logits, rng, *sampling))
+        np.testing.assert_array_equal(
+            got, np.asarray(old(logits, rng, *sampling)))
+        assert got.dtype == np.int32
+        best = np.asarray(logits).argmax(-1)
+        cold = np.asarray(temps) <= 0
+        np.testing.assert_array_equal(got[cold], best[cold])
+        drawn |= bool((got != best).any())
+    # the slots that sample do draw: not every token of theirs is the argmax
+    assert drawn == (max(temps) > 0)
+
+
+def _run(eng, arrivals):
+    """Steps ``eng`` to the end, adding ``arrivals[n]`` = (id, prompt,
+    params) before step ``n``. Returns the tokens by request and, for every
+    sampler call, whether the engine counted it greedy."""
+    got, greedy = {}, []
+    calls = (eng.metrics["sample_calls"], eng.metrics["sample_greedy_calls"])
+    n = 0
+    while eng.has_unfinished() or n <= max(arrivals):
+        for rid, prompt, sp in arrivals.get(n, ()):
+            eng.add_request(rid, prompt, sp)
+        for o in eng.step():
+            if o.finished:
+                got[o.request_id] = o.token_ids
+        now = (eng.metrics["sample_calls"], eng.metrics["sample_greedy_calls"])
+        g = now[1] - calls[1]
+        greedy += [True] * g + [False] * (now[0] - calls[0] - g)
+        calls, n = now, n + 1
+    return got, greedy
+
+
+def _check_neighbours_do_not_move_a_request(engine):
+    """A greedy request that starts alone (the argmax branch), is joined by a
+    seeded one and then by one on the engine's own stream (the shortlist
+    branch) and outlives both (the argmax branch again) gets the tokens it
+    gets alone; so does the seeded one, whoever sits beside it."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    g = ("g", list(range(3, 12)), SamplingParams(max_tokens=20))
+    s = ("s", list(range(20, 31)), SamplingParams(
+        max_tokens=6, temperature=0.9, top_k=16, top_p=0.95, seed=5))
+    n = ("n", list(range(40, 47)), SamplingParams(
+        max_tokens=3, temperature=1.3))
+    alone = {}
+    for r in (g, s):
+        eng = JaxLLMEngine(make_config(), params=engine.params, seed=1)
+        alone.update(_run(eng, {0: [r]})[0])
+    assert len(alone["g"]) == 20 and len(alone["s"]) == 6
+    assert alone["s"] != _run(
+        JaxLLMEngine(make_config(), params=engine.params, seed=1),
+        {0: [("s", s[1], SamplingParams(max_tokens=6))]})[0]["s"]
+
+    eng = JaxLLMEngine(make_config(), params=engine.params, seed=2)
+    got, greedy = _run(eng, {0: [g], 3: [s], 5: [n]})
+    assert got["g"] == alone["g"] and got["s"] == alone["s"]
+    assert len(got["n"]) == 3
+    # alone, then beside the two that sample, then alone again
+    flips = [a != b for a, b in zip(greedy, greedy[1:])]
+    assert greedy[0] and greedy[-1] and flips.count(True) == 2
+    assert 3 <= greedy.index(False) and greedy[::-1].index(False) >= 3
+
+
+def _check_one_conditional_holds_the_shortlist(engine):
+    """The compiled program branches once, on the device, and the shortlist
+    is computed inside a branch: the entry computation holds no top-k."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import model_runner as mr
+
+    B, V = 4, 300
+    text = mr.sample_tokens.lower(
+        jnp.zeros((B, V)), jax.random.PRNGKey(0), jnp.zeros(B),
+        jnp.zeros(B, jnp.int32), jnp.ones(B), jnp.full(B, -1, jnp.int32),
+        jnp.zeros(B, jnp.int32)).compile().as_text()
+    assert text.startswith("HloModule jit_sample_tokens")
+    conds = re.findall(
+        r" conditional\(.*branch_computations=\{([^}]*)\}", text)
+    assert len(conds) == 1 and text.count(" conditional(") == 1
+    branches = [b.strip().lstrip("%") for b in conds[0].split(",")]
+    assert len(branches) == 2
+    # a computation's text, from its header line to its closing brace
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.S | re.M)}
+    entry = re.search(r"^ENTRY %([\w.\-]+) ", text, re.M).group(1)
+    holds = [name for name, body in bodies.items() if "TopK" in body]
+    assert holds and set(holds) <= set(branches) and entry not in holds
+    # the other branch is the argmax alone: no key, no draw, no shortlist
+    other, = set(branches) - set(holds)
+    assert len(bodies[other].splitlines()) <= 6
+    assert not re.search(r"while|TopK|exponential|u32\[", bodies[other])
+
+
+def _check_counters_and_span(engine, monkeypatch):
+    """A greedy run counts every sampler call greedy, a run with one request
+    that samples counts the calls it sat through as not, and the span says
+    the same of each call."""
+    import jax
+
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    spans = []
+
+    class Recorder(contextlib.nullcontext):
+        def __init__(self, name, **attrs):
+            super().__init__()
+            if name == "ray_tpu/engine.sample_dispatch":
+                spans.append(attrs)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    eng = JaxLLMEngine(make_config(), params=engine.params, seed=0)
+    m = eng.metrics
+    assert (m["sample_calls"], m["sample_greedy_calls"]) == (0, 0)
+    reqs = [(f"q{i}", list(range(3 + i, 9 + 2 * i)), SamplingParams(
+        max_tokens=5 + i)) for i in range(3)]
+    _, greedy = _run(eng, {0: reqs})
+    assert all(greedy) and len(greedy) == m["sample_calls"] > 5
+    assert m["sample_calls"] == m["sample_greedy_calls"] \
+        == m["prefill_steps"] + m["decode_steps"]
+    assert spans == [{"greedy": True}] * m["sample_calls"]
+
+    before = dict(m)
+    del spans[:]
+    hot = ("hot", list(range(50, 58)), SamplingParams(
+        max_tokens=4, temperature=0.7))
+    _, greedy = _run(eng, {0: [
+        ("a", *reqs[0][1:]), hot,
+        ("b", reqs[2][1], SamplingParams(max_tokens=12))]})
+    calls = m["sample_calls"] - before["sample_calls"]
+    cold = m["sample_greedy_calls"] - before["sample_greedy_calls"]
+    # the one prefill phase and the decode steps "hot" sat in were not greedy;
+    # the slot it left is greedy again without anybody taking it
+    assert greedy[:4] == [False] * 4 and all(greedy[5:]) and greedy[-1]
+    assert 0 < cold < calls == len(greedy)
+    assert spans == [{"greedy": g} for g in greedy]
+    assert all(type(s["greedy"]) is bool for s in spans)
+    assert not (eng._temps > 0).any()
+
+
+@pytest.mark.parametrize("case", [
+    *_SAMPLER_BATCHES, "neighbours-do-not-move-a-request",
+    "one-conditional-holds-the-shortlist", "counters-and-span"])
+def test_sampler_takes_its_shortlist_only_when_a_slot_samples(
+        engine, monkeypatch, case):
+    """``sample_tokens`` computes its shortlist, masks, keys and draw only
+    for a batch in which some slot samples, and every row's token is what
+    the formula it replaced gives: an all-greedy batch is the argmax; a mixed
+    batch equals the old program bit for bit; through the engine a request's
+    tokens do not move as its batch changes branch; the program is one
+    conditional; and the engine counts which branch each call took."""
+    if case in _SAMPLER_BATCHES:
+        _check_batch_against_the_old_formula(*_SAMPLER_BATCHES[case])
+    elif case == "neighbours-do-not-move-a-request":
+        _check_neighbours_do_not_move_a_request(engine)
+    elif case == "one-conditional-holds-the-shortlist":
+        _check_one_conditional_holds_the_shortlist(engine)
+    else:
+        _check_counters_and_span(engine, monkeypatch)
 
 
 @pytest.mark.parametrize("name", ["tables", "active", "temps", "top_ks",
