@@ -127,7 +127,9 @@ from ``__init__``, so two snapshots subtract):
   the live ones rounded up to whole pages);
 - for a model that keeps state by kind of layer (``layer_kinds``: pages for
   its "full" layers, window rings and, in a decoder-hybrid-decoder, recurrent
-  rows by slot; 0 otherwise): ``shared_kv_live_tokens`` and
+  rows by slot, or a gated short convolution's two rows by slot, which the
+  program keeps and the host has nothing to count for; 0 otherwise):
+  ``shared_kv_live_tokens`` and
   ``shared_kv_read_tokens`` (the same two for a paged layer, counted once a
   decode step, not once a layer that reads pages), ``window_live_tokens``
   (filled ring entries the active slots attend over, a step and window layer)
@@ -267,11 +269,16 @@ class JaxLLMEngine:
                 f"{self.ecfg.expect_latent_rank}, the model has "
                 f"{self.mcfg.kv_latent_rank}")
         kinds = self.mcfg.layer_kinds
-        if kinds.count("mamba") != self.ecfg.expect_state_layers:
+        state_layers = kinds.count("mamba") + kinds.count("conv")
+        if state_layers != self.ecfg.expect_state_layers:
             raise ValueError(
                 f"the deployment expects {self.ecfg.expect_state_layers} "
-                f"layers with recurrent state, the model has "
-                f"{kinds.count('mamba')}")
+                f"layers with recurrent state, the model has {state_layers}")
+        if self.mcfg.conv_taps != self.ecfg.expect_conv_taps:
+            raise ValueError(
+                f"the deployment expects short convolutions of "
+                f"{self.ecfg.expect_conv_taps} taps, the model's have "
+                f"{self.mcfg.conv_taps}")
         self.tokenizer = get_tokenizer(config.tokenizer)
         self._mr = model_runner
         self._jax = jax

@@ -71,12 +71,19 @@ slot's whole length. Two kinds of block use it (``TransformerConfig.block``):
   (``TransformerConfig.rope_kinds``), and whatever of a per-head q/k norm, an
   attention gate, sandwich norms, a scaled embedding and experts (all of them,
   or the share ``experts_held`` of an expert-parallel rank) the config asks
-  for.
+  for. A "conv" layer of such a block (a gated short convolution,
+  ``models/transformer.py:ShortConv``) has no attention at all: its state is
+  the last ``conv_taps - 1`` gated inputs ``B * z`` a slot, in
+  ``HybridCache.conv``. Prefill convolves the bucket and leaves the rows of
+  positions ``lengths - conv_taps + 1 .. lengths - 1`` (zeros where the
+  prompt is shorter than that; padding behind the prompt never enters them);
+  a decode step convolves the rows with the new input and shifts them by one.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
@@ -114,15 +121,19 @@ class HybridCache(NamedTuple):
     a model that rotates). ``ssm``, ``conv``: per "mamba" layer and slot the
     scan's state (float32, ``inner`` along the lanes as ``ops/ssm.py`` keeps
     it: [.., N, inner] is whole tiles where [.., inner, N] would pad 16 lanes
-    to 128) and the convolution's last ``ssm_conv - 1`` inputs; None in a model
-    without such layers. Rings and rows belong to a SLOT: prefill overwrites
-    all of a slot's from the prompt alone, which is also how a slot is reset
-    at admission; a slot that is not active computes into its own rows and
-    nobody reads them."""
+    to 128) and the convolution's last ``ssm_conv - 1`` inputs. ``conv`` alone,
+    ``ssm`` None: per "conv" layer (a gated short convolution) and slot the
+    last ``conv_taps - 1`` gated inputs ``B * z``, oldest first, ``d_model``
+    wide. A kind the model lacks has None. Rings and rows belong to a SLOT:
+    prefill overwrites all of a slot's from the prompt alone, which is also
+    how a slot is reset at admission; a slot that is not active computes into
+    its own rows and nobody reads them."""
     pages: jax.Array  # [full layers, NP, P, 2 KVH hd]
-    rings: jax.Array  # [window layers, B, window, 2 KVH hd]
+    rings: Optional[jax.Array]  # [window layers, B, window, 2 KVH hd]
     ssm: Optional[jax.Array] = None   # [mamba layers, B, N, inner] float32
-    conv: Optional[jax.Array] = None  # [mamba layers, ssm_conv - 1, B, inner]
+    # [mamba layers, ssm_conv - 1, B, inner] or [conv layers, conv_taps - 1,
+    # B, d_model]
+    conv: Optional[jax.Array] = None
     moe_load: Optional[jax.Array] = None
 
 
@@ -151,16 +162,21 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
                              "recurrent rows by slot: init_cache needs "
                              "max_num_seqs")
         kinds, row = cfg.layer_kinds, 2 * cfg.n_kv_heads * cfg.head_dim
-        mamba = kinds.count("mamba")
+        window, mamba = kinds.count("window"), kinds.count("mamba")
+        rows = None
+        if mamba:
+            rows = (mamba, cfg.ssm_conv - 1, max_num_seqs, cfg.ssm_inner)
+        elif "conv" in kinds:
+            rows = (kinds.count("conv"), cfg.conv_taps - 1, max_num_seqs,
+                    cfg.d_model)
         return HybridCache(
             jnp.zeros((kinds.count("full"), num_pages, page_size, row),
                       cfg.dtype),
-            jnp.zeros((kinds.count("window"), max_num_seqs, cfg.window, row),
-                      cfg.dtype),
+            jnp.zeros((window, max_num_seqs, cfg.window, row), cfg.dtype)
+            if window else None,
             jnp.zeros((mamba, max_num_seqs, cfg.ssm_state, cfg.ssm_inner),
                       jnp.float32) if mamba else None,
-            jnp.zeros((mamba, cfg.ssm_conv - 1, max_num_seqs, cfg.ssm_inner),
-                      cfg.dtype) if mamba else None, load)
+            jnp.zeros(rows, cfg.dtype) if rows else None, load)
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     if cfg.kv_latent_rank:
         return LatentCache(jnp.zeros(
@@ -203,6 +219,7 @@ def _ffn(x, lp, cfg, valid, name):
         name=name, router_kind=cfg.router_kind,
         router_bias=p.get("router_bias"),
         router_scale=cfg.routed_scaling_factor,
+        router_norm_eps=cfg.router_norm_eps,
         held=tuple(cfg.experts_held) or None)
     y = y.reshape(x.shape)
     if "shared" in p:  # the experts every row goes through
@@ -356,12 +373,20 @@ def _row_heads(row, cfg):
     return k[..., key_of, :], v[..., value_of, :]
 
 
+def _keys_per_group(cfg):
+    """Plain key heads a group of ``ops/paged_attention.py``: as many as lie
+    in one 128-lane tile of a page's row (two of 64 lanes, one of 128)."""
+    return math.gcd(max(128 // cfg.head_dim, 1), cfg.n_kv_heads)
+
+
 def _grouped_query(q, cfg):
     """q [B, H, hd] -> [B, G, R, W] for ``ops/paged_attention.py``, scaled:
-    the query heads of a group as rows (padded to 16). Plain heads: a group
-    is one key head, ``W = hd``. Differential heads: a group is a key PAIR,
-    each query laid where its key lies in the pair's ``k1 | k2`` (``W = 2
-    hd``), zeros beside it."""
+    the query heads of a group as rows (padded to 16). Differential heads: a
+    group is a key PAIR, each query laid where its key lies in the pair's ``k1
+    | k2`` (``W = 2 hd``), zeros beside it. Plain heads: a group is the
+    ``_keys_per_group`` key heads of one tile, ``k1 | .. | kn``, the queries
+    of key ``j`` laid at its ``hd`` lanes with zeros beside them (``W = n
+    hd``); at ``head_dim`` 128 that is one key head and ``W = hd``."""
     B, H, hd = q.shape
     q = q * (hd ** -0.5)
     if cfg.sambay:
@@ -372,21 +397,31 @@ def _grouped_query(q, cfg):
         q = jnp.concatenate([jnp.where(second, zero, q),
                              jnp.where(second, q, zero)], axis=-1)
     else:
-        G = cfg.n_kv_heads
-        q = q.reshape(B, G, H // G, hd)
+        n = _keys_per_group(cfg)
+        G = cfg.n_kv_heads // n
+        q = q.reshape(B, G, n, H // cfg.n_kv_heads, hd)
+        q = jnp.concatenate([jnp.pad(q[:, :, j], (
+            (0, 0), (0, 0), (0, 0), (j * hd, (n - 1 - j) * hd)))
+            for j in range(n)], axis=2)
     return jnp.pad(q, ((0, 0), (0, 0), (0, -(H // G) % 16), (0, 0)))
 
 
 def _paged_attention(q, pages, work, layer, name, cfg):
     """Decode's attention: q [B, H, hd] against the live rows of ``pages`` [L,
-    NP, P, row] -> [B, H, W] (``W``: ``_grouped_query``'s)."""
+    NP, P, row] -> [B, H, W]: differential heads keep the pair's ``2 hd``
+    lanes, a plain head takes its own key's ``hd`` of its group's."""
     from ray_tpu.ops.paged_attention import paged_gqa_decode
 
-    B, H, _ = q.shape
+    B, H, hd = q.shape
     o = paged_gqa_decode(_grouped_query(q, cfg), pages, work, layer=layer,
                          name=name)
     G = o.shape[1]
-    return o[:, :, :H // G].reshape(B, H, -1)
+    o = o[:, :, :H // G]
+    n = 1 if cfg.sambay else _keys_per_group(cfg)
+    if n > 1:   # rows of key j hold their own result at key j's lanes
+        o = o.reshape(B, G, n, H // G // n, n, hd)
+        o = jnp.stack([o[:, :, j, :, j] for j in range(n)], axis=2)
+    return o.reshape(B, H, -1)
 
 
 # -- where a model with layer_kinds keeps what: ONE definition of the page and
@@ -415,7 +450,8 @@ def _prompt_index(cfg, cache, S, lengths, block_tables):
     """Where a prefill call's ``[B, S]`` positions go: ``in_prompt`` [B, S],
     the ``page`` and ``offset`` of each (padding -> the scratch page), ``last``
     [B, 1] the last real position, and ``ring_pos`` [B, window]: ring entry j
-    holds the newest prompt position that is j mod window (< 0: none)."""
+    holds the newest prompt position that is j mod window (< 0: none; None in
+    a model without rings)."""
     B = lengths.shape[0]
     P, W = cache.pages.shape[2], cfg.window
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -424,7 +460,9 @@ def _prompt_index(cfg, cache, S, lengths, block_tables):
     page = jnp.where(in_prompt, page_for, 0)
     offset = jnp.where(in_prompt, positions % P, 0)
     last = jnp.maximum(lengths - 1, 0).astype(jnp.int32)[:, None]
-    ring_pos = last - (last - jnp.arange(W, dtype=jnp.int32)[None]) % W
+    ring_pos = None
+    if cache.rings is not None:
+        ring_pos = last - (last - jnp.arange(W, dtype=jnp.int32)[None]) % W
     return positions, in_prompt, page, offset, last, ring_pos
 
 
@@ -441,7 +479,8 @@ def _decode_index(cfg, cache, seq_lens, block_tables, active):
     kernel's two work lists (``ops/mla.py:live_pages``, built once a step):
     the pages that hold live positions, and the ring blocks that hold filled
     entries (a ring is its slot's own run of blocks, its live entries the
-    filled ones, in whatever order it holds them)."""
+    filled ones, in whatever order it holds them; None in a model without
+    rings)."""
     from ray_tpu.ops.mla import live_pages
 
     B = seq_lens.shape[0]
@@ -453,6 +492,8 @@ def _decode_index(cfg, cache, seq_lens, block_tables, active):
     page = jnp.where(active, cur_page, 0)
     offset = jnp.where(active, positions % P, 0)
     work = live_pages(positions, active, block_tables, P)
+    if cache.rings is None:
+        return slot, positions, page, offset, work, None
     blocks = W // _ring_block(cfg, P)
     ring_tables = slot[:, None] * blocks + jnp.arange(
         blocks, dtype=jnp.int32)[None]
@@ -600,8 +641,9 @@ def _hybrid_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
 # ---------------------------------------------------------------------------
 # an RMSNorm block with layer_kinds (models/transformer.py:Block with a kind):
 # plain grouped-query heads, rotated or not by kind, pages for each "full"
-# layer and a ring for each "window" layer; with the per-head q/k norm, the
-# attention gate, the sandwich norms and the experts its config asks for. The
+# layer, a ring for each "window" layer and conv_taps - 1 rows a slot for each
+# "conv" layer (no heads at all); with the per-head q/k norm, the attention
+# gate, the sandwich norms and the experts its config asks for. The
 # residual stream is float32, as the hybrid's: a normalised sublayer adds a
 # whole unit to it, which bfloat16 would round at every layer
 # ---------------------------------------------------------------------------
@@ -616,18 +658,65 @@ def _kind_attn_inputs(x, lp, cfg, positions, kind):
     return h, q, jnp.concatenate([flat(k), flat(v)], axis=-1)
 
 
-def _kind_block_rest(x, h, o, lp, cfg, valid, name):
-    """The block after its attention ``o`` [B, S, H, hd]: the gate, o_proj,
-    the residual (a norm on the way out under ``sandwich_norm``), the MLP or
-    the experts likewise. Returns (x, load)."""
-    f32 = jnp.float32
+def _kind_attn_out(h, o, lp, cfg):
+    """The attention ``o`` [B, S, H, hd] of a layer with input ``h`` -> what
+    the mixer adds to the stream, float32: the gate, then o_proj."""
     a = lp["attn"]
     if cfg.attn_gate:
         with jax.named_scope("attn.gate"):
             o = o * jax.nn.sigmoid(jnp.einsum(
                 "...d,dhk->...hk", h, a["gate_proj"]["kernel"].astype(cfg.dtype)))
-    o = jnp.einsum("...hk,hkd->...d", o,
-                   a["o_proj"]["kernel"].astype(cfg.dtype)).astype(f32)
+    return jnp.einsum("...hk,hkd->...d", o,
+                      a["o_proj"]["kernel"].astype(cfg.dtype)
+                      ).astype(jnp.float32)
+
+
+def _conv_gates(x, lp, cfg):
+    """A "conv" layer's ``in_proj`` on the normalised stream x [.., D]: the
+    gated input ``s = B * z`` that is convolved (and kept) and the gate ``C``
+    on the convolution's output."""
+    h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps).astype(cfg.dtype)
+    b, c, z = jnp.split(_dense(h, lp["conv"]["in_proj"], cfg.dtype), 3, axis=-1)
+    return b * z, c
+
+
+def _conv_out(c, y, lp, cfg):
+    return _dense(c * y, lp["conv"]["out_proj"], cfg.dtype).astype(jnp.float32)
+
+
+def _conv_prefill(x, lp, cfg, conv, layer, slots, lengths):
+    """A "conv" layer over a prefill call's x [B, S, D]: what the mixer adds
+    to the stream, and ``conv`` with the rows of ``slots`` left at the
+    prompt's last ``conv_taps - 1`` positions (zeros where it has none)."""
+    from ray_tpu.models.transformer import causal_conv
+
+    tail = cfg.conv_taps - 1
+    tail_pos = lengths[:, None] - tail + jnp.arange(tail)[None]
+    with jax.named_scope("conv.prefill"):
+        s, c = _conv_gates(x, lp, cfg)
+        o = _conv_out(c, causal_conv(
+            s, lp["conv"]["conv_kernel"].astype(cfg.dtype), 0), lp, cfg)
+        # [layer, tap, slot]: the indexed axes come first, [B, K-1, D]
+        return o, conv.at[layer, :, slots].set(_rows_at(s, tail_pos))
+
+
+def _conv_step(x, lp, cfg, conv, layer):
+    """A "conv" layer over a decode step's x [B, 1, D]: the kept rows and the
+    new input under the taps, and the rows shifted by one."""
+    with jax.named_scope("conv.step"):
+        s, c = _conv_gates(x[:, 0], lp, cfg)
+        taps = jnp.concatenate([conv[layer], s[None]], axis=0)
+        o = _conv_out(c, jnp.einsum(
+            "kbd,kd->bd", taps, lp["conv"]["conv_kernel"].astype(cfg.dtype)),
+            lp, cfg)
+        return o[:, None], conv.at[layer].set(taps[1:])
+
+
+def _kind_block_rest(x, o, lp, cfg, valid, name):
+    """The block after its mixer's output ``o`` [B, S, D] float32: the
+    residual (a norm on the way out under ``sandwich_norm``), the MLP or the
+    experts likewise. Returns (x, load)."""
+    f32 = jnp.float32
     if cfg.sandwich_norm:
         o = _rmsnorm(o, lp["post_attn_norm"]["scale"], cfg.norm_eps)
     x = x + o
@@ -650,61 +739,72 @@ def _kinds_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
     rep = cfg.n_heads // cfg.n_kv_heads
     positions, in_prompt, page, offset, last, ring_pos = _prompt_index(
         cfg, cache, S, lengths, block_tables)
-    pages, rings = cache[:2]
+    pages, rings, _, conv = cache[:4]
     x = _embed(p, cfg, tokens)
     loads = []
-    full_i = window_i = 0
+    full_i = window_i = conv_i = 0
     for i, kind in enumerate(cfg.layer_kinds):
         lp = p[f"layer_{i}"]
-        h, q, row = _kind_attn_inputs(x, lp, cfg, positions, kind)
-        if kind == "window":
-            rings = _write_rings(rings, window_i, slots, row, ring_pos)
-            window_i += 1
+        if kind == "conv":
+            o, conv = _conv_prefill(x, lp, cfg, conv, conv_i, slots, lengths)
+            conv_i += 1
         else:
-            pages = pages.at[full_i, page, offset].set(row, mode="drop")
-            full_i += 1
-        k, v = (t.reshape(B, S, cfg.n_kv_heads, -1)
-                for t in jnp.split(row, 2, axis=-1))
-        o = attention_op(q, jnp.repeat(k, rep, axis=2),
-                         jnp.repeat(v, rep, axis=2), causal=True,
-                         impl=cfg.attention_impl,
-                         window=cfg.window if kind == "window" else 0)
-        x, load = _kind_block_rest(x, h, o, lp, cfg, in_prompt,
+            h, q, row = _kind_attn_inputs(x, lp, cfg, positions, kind)
+            if kind == "window":
+                rings = _write_rings(rings, window_i, slots, row, ring_pos)
+                window_i += 1
+            else:
+                pages = pages.at[full_i, page, offset].set(row, mode="drop")
+                full_i += 1
+            k, v = (t.reshape(B, S, cfg.n_kv_heads, -1)
+                    for t in jnp.split(row, 2, axis=-1))
+            o = _kind_attn_out(h, attention_op(
+                q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+                causal=True, impl=cfg.attention_impl,
+                window=cfg.window if kind == "window" else 0), lp, cfg)
+        x, load = _kind_block_rest(x, o, lp, cfg, in_prompt,
                                    "moe_gmm_prefill")
         if load is not None:
             loads.append(load)
     x = jnp.take_along_axis(x, last[..., None], axis=1)[:, 0]
     return _head(x, p, cfg), HybridCache(
-        pages, rings, None, None, jnp.stack(loads) if loads else None)
+        pages, rings, None, conv, jnp.stack(loads) if loads else None)
 
 
 def _kinds_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
     P, W = cache.pages.shape[2], cfg.window
     slot, positions, page, offset, work, ring_work = _decode_index(
         cfg, cache, seq_lens, block_tables, active)
-    pages, rings = cache[:2]
+    pages, rings, _, conv = cache[:4]
     x = _embed(p, cfg, last_tokens[:, None])                  # [B, 1, d]
     loads = []
-    full_i = window_i = 0
+    full_i = window_i = conv_i = 0
     for i, kind in enumerate(cfg.layer_kinds):
         lp = p[f"layer_{i}"]
-        h, q, row = _kind_attn_inputs(x, lp, cfg, positions[:, None], kind)
-        if kind == "window":
-            rings = rings.at[window_i, slot, positions % W].set(row[:, 0])
-            o = _paged_attention(q[:, 0], _ring_blocks(rings, cfg, P),
-                                 ring_work, window_i, "window_gqa_decode", cfg)
-            window_i += 1
+        if kind == "conv":
+            o, conv = _conv_step(x, lp, cfg, conv, conv_i)
+            conv_i += 1
         else:
-            pages = pages.at[full_i, page, offset].set(row[:, 0], mode="drop")
-            o = _paged_attention(q[:, 0], pages, work, full_i,
-                                 "paged_gqa_decode", cfg)
-            full_i += 1
-        x, load = _kind_block_rest(x, h, o[:, None], lp, cfg, active[:, None],
+            h, q, row = _kind_attn_inputs(x, lp, cfg, positions[:, None], kind)
+            if kind == "window":
+                rings = rings.at[window_i, slot, positions % W].set(row[:, 0])
+                o = _paged_attention(q[:, 0], _ring_blocks(rings, cfg, P),
+                                     ring_work, window_i, "window_gqa_decode",
+                                     cfg)
+                window_i += 1
+            else:
+                pages = pages.at[full_i, page, offset].set(row[:, 0],
+                                                           mode="drop")
+                o = _paged_attention(q[:, 0], pages, work, full_i,
+                                     "paged_gqa_decode", cfg)
+                full_i += 1
+            o = _kind_attn_out(h, o[:, None], lp, cfg)
+        x, load = _kind_block_rest(x, o, lp, cfg, active[:, None],
                                    "moe_gmm_decode")
         if load is not None:
             loads.append(load)
     return _head(x[:, 0], p, cfg), HybridCache(
-        pages, rings, None, None, jnp.stack(loads) if loads else None)
+        pages, rings, None, conv, jnp.stack(loads) if loads else None)
 
 
 # ---------------------------------------------------------------------------
@@ -814,7 +914,8 @@ def _head(last, p, cfg):
     last = _rmsnorm(last, p["final_norm"]["scale"], cfg.norm_eps
                     ).astype(cfg.dtype)
     if cfg.tie_embeddings:
-        logits = jnp.einsum("bd,vd->bv", last, p["embed"].astype(cfg.dtype))
+        logits = jnp.einsum("bd,vd->bv", last, p["embed"].astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
     else:
         logits = jnp.einsum("bd,dv->bv", last, p["lm_head"].astype(cfg.dtype),
                             preferred_element_type=jnp.float32)

@@ -93,9 +93,11 @@ class TransformerConfig:
     # "softmax": top-k of the softmax. "sigmoid": top-k of sigmoid scores plus
     # a per-expert selection bias (a parameter) that chooses and does not
     # weigh; the chosen scores, renormalised with norm_topk_prob, times
-    # routed_scaling_factor (ops/moe.py:select_experts)
+    # routed_scaling_factor (ops/moe.py:select_experts). ``router_norm_eps`` is
+    # what the sigmoid kind adds to the chosen scores' sum before it divides
     router_kind: str = "softmax"
     routed_scaling_factor: float = 1.0
+    router_norm_eps: float = 1e-20
     # the standard deviations the attention's projections and the MLPs' and
     # experts' matrices are drawn at; 0 = 0.02 / sqrt(2 layers). What each
     # part adds to the residual stream goes with its fourth and third power,
@@ -117,7 +119,9 @@ class TransformerConfig:
     # projections and a tied head (``HybridBlock``). "rms": the RMSNorm
     # block of every model without kinds (``Block``), plain grouped-query
     # heads under the causal mask ("full") or the window's ("window"); only
-    # "window" and "full" are kinds of such a block.
+    # "window", "full" and "conv" are kinds of such a block. "conv" is a gated
+    # short convolution (``ShortConv``): no attention, no position embedding,
+    # and in serving ``conv_taps - 1`` rows a slot in place of pages or a ring.
     layer_kinds: Tuple[str, ...] = ()
     block: str = "sambay"
     window: int = 0
@@ -125,6 +129,12 @@ class TransformerConfig:
     ssm_state: int = 16
     ssm_conv: int = 4       # taps of the causal depthwise convolution
     ssm_dt_rank: int = 0
+    # taps a channel of a "conv" layer's causal depthwise convolution (0: the
+    # model has no such layer), and what its in_proj and out_proj are drawn
+    # at (0 = the attention's): its output goes with their FOURTH power, the
+    # attention's with the second, so one deviation cannot size both
+    conv_taps: int = 0
+    conv_init_std: float = 0.0
     # what a Mamba layer's and a memory unit's in_proj and out_proj, and the
     # scan's x_proj (dt | B | C), are drawn at: with seeded weights the state
     # shows in the output only where B and C are of order one, which one
@@ -179,9 +189,12 @@ class TransformerConfig:
         return self.layer_kinds[i] if self.layer_kinds else "full"
 
     def init_std(self, part: str) -> float:
-        """``part``: "attn", "mlp", "expert", "ssm_proj", "ssm_x" or "embed"."""
+        """``part``: "attn", "conv", "mlp", "expert", "ssm_proj", "ssm_x" or
+        "embed"."""
         if part == "expert" and not self.expert_init_std:
             part = "mlp"
+        if part == "conv" and not self.conv_init_std:
+            part = "attn"
         return getattr(self, part + "_init_std") or (
             0.02 if part == "embed" else 0.02 / np.sqrt(2 * self.n_layers))
 
@@ -224,13 +237,16 @@ class TransformerConfig:
                    if self.qk_norm else 0)
                 + (2 * self.head_dim if self.qk_head_norm else 0)
             )
+        # in_proj (B | C | z), out_proj, the taps and the block's two norms
+        conv = 4 * d * d + self.conv_taps * d + 2 * d
         dense_mlp = 3 * d * (self.d_ff_dense or f)
         moe_mlp = (self.n_experts_held * 3 * d * f + d * self.n_experts
                    + 3 * d * self.n_shared_experts * f
                    + (self.n_experts if self.router_kind == "sigmoid" else 0))
         total = 0
         for i in range(self.n_layers):
-            total += attn + (moe_mlp if self.is_moe_layer(i) else dense_mlp)
+            total += conv if self.layer_kind(i) == "conv" else attn
+            total += moe_mlp if self.is_moe_layer(i) else dense_mlp
         return v * d + total + d + (0 if self.tie_embeddings else d * v)
 
 
@@ -365,6 +381,35 @@ class Attention(nn.Module):
         return q, k, kv[..., nope:]
 
 
+class ShortConv(nn.Module):
+    """A "conv" layer's mixer, a gated short convolution: ``B | C | z =
+    in_proj(h)``, ``s = B * z``, a causal depthwise convolution of
+    ``cfg.conv_taps`` taps a channel over ``s`` (zeros before the first
+    position; tap ``conv_taps - 1`` is the position itself), ``out_proj(C *
+    conv(s))``. No bias, no activation, no position embedding. The TRAINING
+    side: the whole sequence at once (packed sequences are not kept apart:
+    no ``segment_ids``); the serving engine keeps the last ``conv_taps - 1``
+    rows of ``s`` a slot (``llm/model_runner.py``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.cfg
+        d = cfg.d_model
+        dense = lambda feats, axes, name: nn.DenseGeneral(  # noqa: E731
+            features=feats, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.normal(cfg.init_std("conv")), axes))
+        b, c, z = jnp.split(dense(3 * d, ("embed", "mlp"), "in_proj")(h), 3,
+                            axis=-1)
+        w = self.param("conv_kernel", nn.initializers.normal(
+            cfg.conv_taps ** -0.5), (cfg.conv_taps, d), cfg.param_dtype)
+        y = c * causal_conv(b * z, w.astype(cfg.dtype), 0)
+        return dense(d, ("mlp", "embed"), "out_proj")(y)
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
     width: int = 0  # 0 = cfg.d_ff
@@ -447,7 +492,7 @@ class MoEMLP(nn.Module):
         # top-k expert choice per token; (G, g, K) and the scores (G, g, E)
         gate_vals, expert_idx, probs = select_experts(
             logits, K, cfg.norm_topk_prob, cfg.router_kind, bias,
-            cfg.routed_scaling_factor)
+            cfg.routed_scaling_factor, cfg.router_norm_eps)
 
         # position of each (token, k) within its expert's capacity buffer,
         # per group; k-slots of a token are ordered before later tokens
@@ -712,8 +757,11 @@ class Block(nn.Module):
     def __call__(self, x, positions, segment_ids=None):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)  # noqa: E731
-        a = Attention(cfg, self.kind, name="attn")(
-            norm("attn_norm")(x), positions, segment_ids)
+        if self.kind == "conv":
+            a = ShortConv(cfg, name="conv")(norm("attn_norm")(x))
+        else:
+            a = Attention(cfg, self.kind, name="attn")(
+                norm("attn_norm")(x), positions, segment_ids)
         h = x + (norm("post_attn_norm")(a) if cfg.sandwich_norm else a)
         h = nn.with_logical_constraint(h, ("batch", "seq", "embed"))
         mlp = MoEMLP(cfg, name="moe") if self.use_moe else MLP(

@@ -53,14 +53,15 @@ _RHS_BLOCK_ELEMS = 1 << 20
 
 def select_experts(logits: jax.Array, top_k: int, norm_topk_prob: bool,
                    kind: str = "softmax", bias: Optional[jax.Array] = None,
-                   scale: float = 1.0):
+                   scale: float = 1.0, norm_eps: float = 1e-20):
     """Router logits [..., E] float32 -> (weights [..., top_k], experts
     [..., top_k] int32 in descending order of what chose them, scores
     [..., E]). ``softmax``: the top_k of the softmax, renormalised to sum to
     one only with ``norm_topk_prob``. ``sigmoid``: the scores are sigmoids;
     the experts are the top_k of ``scores + bias``, a per-expert selection
     bias that chooses and does not weigh; the weights are the chosen
-    experts' scores without it, renormalised with ``norm_topk_prob``, times
+    experts' scores without it, renormalised with ``norm_topk_prob`` (divided
+    by their sum plus ``norm_eps``: published routers differ in it), times
     ``scale``."""
     if kind == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
@@ -73,7 +74,7 @@ def select_experts(logits: jax.Array, top_k: int, norm_topk_prob: bool,
         _, experts = jax.lax.top_k(scores + bias, top_k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         if norm_topk_prob:
-            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+            weights = weights / (weights.sum(-1, keepdims=True) + norm_eps)
         weights = weights * scale
     else:
         raise ValueError(f"unknown router kind {kind!r}")
@@ -82,12 +83,14 @@ def select_experts(logits: jax.Array, top_k: int, norm_topk_prob: bool,
 
 def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk_prob: bool,
           kind: str = "softmax", bias: Optional[jax.Array] = None,
-          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+          scale: float = 1.0, norm_eps: float = 1e-20
+          ) -> Tuple[jax.Array, jax.Array]:
     """x [T, D], router [D, E] -> (weights [T, top_k] float32, experts
     [T, top_k] int32): ``select_experts`` of the float32 logits."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    return select_experts(logits, top_k, norm_topk_prob, kind, bias, scale)[:2]
+    return select_experts(logits, top_k, norm_topk_prob, kind, bias, scale,
+                          norm_eps)[:2]
 
 
 def _tiles(m: int, k: int, n: int, itemsize: int) -> Tuple[int, int]:
@@ -182,7 +185,7 @@ def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
                  top_k: int, norm_topk_prob: bool,
                  name: str = "moe_gmm", router_kind: str = "softmax",
                  router_bias: Optional[jax.Array] = None,
-                 router_scale: float = 1.0,
+                 router_scale: float = 1.0, router_norm_eps: float = 1e-20,
                  held: Optional[Tuple[int, int]] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     """x [T, D], valid [T] bool, router [D, R], w_gate / w_up [E, D, F],
@@ -194,7 +197,7 @@ def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
     T, D = x.shape
     E = w_gate.shape[0]
     weights, experts = route(x, router, top_k, norm_topk_prob, router_kind,
-                             router_bias, router_scale)
+                             router_bias, router_scale, router_norm_eps)
     if held is not None:
         assert held[1] == E and held[0] + E <= router.shape[1], (held, E)
         experts = experts - held[0]

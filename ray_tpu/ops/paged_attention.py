@@ -10,7 +10,15 @@ rows ``2 hd`` wide with zeros where the other key lies (``llm/model_runner.py``)
 so one product ``rows . (k1 | k2)^T`` gives every head its own ``hd``-wide score
 and one product ``p . (v1 | v2)`` its ``2 hd``-wide value: at head_dim 64 both
 operands are whole 128-lane tiles read where they lie in the page, for each
-group in turn. Row statistics (max, sum), scores and accumulators are float32;
+group in turn. PLAIN heads of 64 lanes are laid out the same way, for the
+same reason: two key heads lie in one 128-lane tile of the row, so a group is
+the two of them, the queries of the first key laid at lanes 0-63 and those of
+the second at 64-127 with zeros beside them, and each head takes its own key's
+half of ``p . (v1 | v2)`` (``llm/model_runner.py:_grouped_query``,
+``_paged_attention``); a group a key head would slice half a tile out of the
+page (it lowers too; PERF.md section 6, PR 40 has both timed). At head_dim 128
+a group is one key head and nothing is laid beside it. Row statistics (max,
+sum), scores and accumulators are float32;
 ``p`` is rounded to the cache's type on its way into ``p . v``, as the flash
 kernels round theirs; the softmax scale is the caller's, on the query.
 
@@ -114,10 +122,12 @@ def _paged_gqa_decode(q, pages, slot_of, page_of, starts, lengths, used, *,
 def paged_gqa_decode(q: jax.Array, pages: jax.Array,
                      work: Tuple[jax.Array, ...], *, layer: int,
                      name: str = "paged_gqa_decode") -> jax.Array:
-    """q [B, G, R, 2 hd] (group by group, a row a query head, scaled, zeros
-    where the group's other key lies), the cache ``pages`` [L, NP, P, 2 G 2 hd]
-    (keys of every group, then values), ``work`` from ``ops/mla.py:live_pages``
-    -> [B, G, R, 2 hd] in q's type: for each slot and row the softmax over the
+    """q [B, G, R, W] (group by group, a row a query head, scaled, zeros where
+    the group's other keys lie; ``W``: the lanes of a group's keys, 2 hd for a
+    differential pair or two plain heads of 64, hd for one plain head of 128),
+    the cache ``pages`` [L, NP, P, 2 G W] (keys of every group, then values),
+    ``work`` from ``ops/mla.py:live_pages`` -> [B, G, R, W] in q's type: for
+    each slot and row the softmax over the
     slot's live positions of ``q . k``, times the group's values. ``layer``
     (static) is the layer of ``pages`` read; none is sliced out. ``name`` is
     the kernel's name in a device trace."""

@@ -719,10 +719,11 @@ def test_hybrid_prefill_compiles_with_scan_and_window(one_chip, bucket):
 # -- one rank of Trinity-Large-Preview (afmoe, the benchmark's file) ----------------
 
 
-def _lower_afmoe(one_chip):
-    """The engine's programs at the published widths and the whole cut of
-    ``benchmarks/configs/trinity-large-preview.json`` (5 layers, 32 of 256
-    experts held, 32 slots x 16896, pages of 512)."""
+def _lower_rms_kinds(one_chip, name="trinity-large-preview"):
+    """The engine's programs at the published widths and the whole cut of a
+    configuration file with an ``"rms"`` block by kind: ``benchmarks/configs/
+    trinity-large-preview.json`` (5 layers, 32 of 256 experts held, 32 slots x
+    16896, pages of 512) unless another is named."""
     import json
 
     import flax.linen as nn
@@ -734,7 +735,7 @@ def _lower_afmoe(one_chip):
     from ray_tpu.models.transformer import Transformer
 
     with open(os.path.join(REPO, "benchmarks", "configs",
-                           "trinity-large-preview.json")) as f:
+                           name + ".json")) as f:
         conf = json.load(f)
     e = EngineConfig(**conf["job"]["engine"])
     cfg = dataclasses.replace(
@@ -767,7 +768,7 @@ def test_afmoe_decode_reads_rings_and_live_pages_and_nothing_else(one_chip):
     three grouped matmuls in each of the four expert layers over the 32 held
     experts; the cache written in place, and NO array a slot's whole length
     long: nothing is gathered over ``Lmax``."""
-    cache, _, decode = _lower_afmoe(one_chip)
+    cache, _, decode = _lower_rms_kinds(one_chip)
     compiled = decode().compile()
     text = compiled.as_text()
     assert len(set(re.findall(r"%(window_gqa_decode\S*) = bf16\[32,8,16,128\]",
@@ -796,7 +797,7 @@ def test_afmoe_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
     pages and rings: five flash calls (the window's in four of them) and
     twelve grouped matmuls, under the chip's 15.75 GiB. What an execution
     holds live is printed (``-s``) and stands in PERF.md section 4."""
-    _, prefill, _ = _lower_afmoe(one_chip)
+    _, prefill, _ = _lower_rms_kinds(one_chip)
     compiled = prefill(rows, bucket).compile()
     text = compiled.as_text()
     assert len(set(re.findall(
@@ -806,3 +807,58 @@ def test_afmoe_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
     print(f"afmoe prefill [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")
     assert 0 < live < int(15.5 * 2 ** 30)
+
+
+# -- LFM2-8B-A1B (lfm2_moe, the benchmark's file) ------------------------------------
+
+
+def test_lfm2_decode_shifts_rows_and_reads_live_pages(one_chip):
+    """Decode at 128 slots x 2,560: the paged kernel over the three attention
+    layers' live pages with two plain 64-lane key heads to a group (4 groups
+    of 8 query rows padded to 16, 128 lanes: whole tiles of a page's row);
+    three grouped matmuls in each of the twelve sparse layers over 512
+    assignments (two row tiles of 256); the eleven convolutions' rows shifted
+    in the cache, which is written in place; nothing gathered over a slot's
+    whole length."""
+    cache, _, decode = _lower_rms_kinds(one_chip, "lfm2-8b-a1b")
+    compiled = decode().compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%(paged_gqa_decode\S*) = bf16\[128,4,16,128\]",
+                              text))) == 3
+    assert len(set(re.findall(r"%(moe_gmm_decode\S*) = bf16\[512,(?:1792|2048)\]",
+                              text))) == 36
+    assert text.count("tpu_custom_call") == 39
+    assert cache.pages.shape == (3, 1281, 256, 1024)
+    assert cache.conv.shape == (11, 2, 128, 2048)
+    assert cache.moe_load.shape == (12, 32)
+    assert cache.rings is None and cache.ssm is None
+    assert not re.search(r"\[128,(2560|10,256),", text)
+    live, temp = _live(compiled)
+    held = sum(x.size * x.dtype.itemsize for x in (cache.pages, cache.conv))
+    print(f"lfm2 decode, 128 slots: {live} bytes live, {temp} of "
+          f"temporaries; cache {held}")
+    assert temp < 256 << 20
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
+@pytest.mark.parametrize("rows,bucket", [(1, 128), (1, 2048), (128, 256)])
+def test_lfm2_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
+    """The least and the largest bucket the mix reaches as the engine calls
+    them, ``[1, S]`` with a slot (the three between hold 11.40, 11.42 and
+    11.47 GB: compiled once, AOT, PR 40; this file is the suite's longest),
+    and the benchmark check's every-slot ``[128, 256]`` call (32,768 rows x
+    top-4 = 131,072 sorted rows in each expert layer), beside 9.33 GB of
+    weights and 2.03 GB of pages and rows: three flash calls and 36 grouped
+    matmuls, under the chip's 15.75 GiB. What an execution holds live is
+    printed (``-s``) and stands in PERF.md section 4."""
+    _, prefill, _ = _lower_rms_kinds(one_chip, "lfm2-8b-a1b")
+    compiled = prefill(rows, bucket).compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(
+        rf"%(flash_fwd\S*) = \(bf16\[{rows * 32},{bucket},64\]", text))) == 3
+    assert text.count("tpu_custom_call") == 39
+    live, temp = _live(compiled)
+    print(f"lfm2 prefill [{rows}, {bucket}]: {live} bytes live, "
+          f"{temp} of temporaries")
+    assert 0 < live < int(15.5 * 2 ** 30)
+
