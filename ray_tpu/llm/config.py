@@ -43,8 +43,11 @@ class EngineConfig:
     page_size: int = 16             # tokens per KV page
     num_pages: Optional[int] = None  # default: enough for all slots + scratch
     max_top_k: int = 64             # static top-k width compiled into sampler
-    # a prompt is prefilled alone, padded to the smallest power-of-two
-    # multiple of this that holds it (one compiled program a bucket)
+    # a prompt is padded to the smallest power-of-two multiple of this that
+    # holds it: its length bucket, one compiled program a bucket for a request
+    # prefilled alone and one more for each number of rows (2, 4) that
+    # requests admitted in one step share a call at (llm/engine.py:
+    # prefill_groups)
     prefill_bucket_min: int = 32
     # routed experts per layer of the model this deployment serves (0: a
     # dense model). The engine refuses to start on a model with another

@@ -10,11 +10,18 @@ two compiled XLA programs (prefill per length bucket, one decode step):
 - pages: a free list of KV pages; sequences allocate pages on demand as they
   cross page boundaries (admission blocks when no pages are free);
 - scheduling per ``step()``: admit waiting requests into free slots and
-  prefill each alone, one program call a request on a ``[1, S]`` batch with
-  ``S`` its own length bucket (a freed slot is refilled by one request as a
-  rule, and a row of padding costs what a real one costs); the calls run back
-  to back and one sampler call samples their first tokens. Then one decode
-  step for all active slots.
+  prefill them, one row a request: a request admitted alone is one program
+  call on a ``[1, S]`` batch with ``S`` its own length bucket, and the
+  requests of a step that admitted several share calls of two or four rows at
+  the longest member's bucket (``prefill_groups``: every held weight is then
+  read once a call and not once a request, which in a sparse model is most of
+  a short prompt's call); the calls run back to back and one sampler call
+  samples their first tokens. Then one decode step for all active slots.
+  Nothing waits for a partner: WHEN a request is admitted is as it was. A
+  shape of several rows is made off the serving path from its bucket's first
+  use on (``llm/prefill_shapes.py``: traced in a process of its own, compiled
+  in threads), and a group forms only at a shape that is ready: until then
+  its requests run as one-row calls.
 
 The engine is single-threaded by design (actor wrappers, serve_llm.LLMServer,
 give it an async front end) and reads a step's tokens ONE STEP LATE. A call of
@@ -60,12 +67,17 @@ from ``__init__``, so two snapshots subtract):
   then takes its ``argmax`` branch and computes no shortlist),
   ``prefill_steps`` (steps that ran a prefill phase), ``decode_steps`` (a
   dropped token's step is one),
-  ``admitted`` (also the number of prefill program calls),
+  ``admitted`` (requests, each one row of a prefill call) against
+  ``prefill_calls`` (prefill program calls: fewer, where rows shared one),
   ``prefill_tokens`` (real prompt positions) against
-  ``prefill_batch_tokens`` (the ``S`` of every prefill call: what the device
-  computes), ``generated_tokens``, ``preempted``, ``compiles`` (first use of
-  a prefill bucket or of decode); program counts move at dispatch,
-  ``generated_tokens`` when a token is emitted;
+  ``prefill_batch_tokens`` (the ``R x S`` of every prefill call, padding rows
+  included: what the device computes), ``generated_tokens``, ``preempted``,
+  ``compiles`` (first use of a prefill bucket or of decode: what the serving
+  path waited for), ``prefill_shapes_wanted`` (shapes of several rows asked
+  for off it, at their bucket's first use) and ``prefill_shapes_ready`` (those
+  compiled: fewer for good where one could not be made, which
+  ``prefill_shapes.RowShapes.failed`` and the log explain); program counts
+  move at dispatch, ``generated_tokens`` when a token is emitted;
 - host milliseconds (``perf_counter_ns``): ``step_ms`` = ``host_ms`` +
   ``readback_ms`` (blocked on the device in ``np.asarray(tokens)``: with a
   step queued behind the one it waits for, the device's time and not the
@@ -85,11 +97,12 @@ from ``__init__``, so two snapshots subtract):
   (``readback_ms / phase_ms`` near 1 less the host's share of a step: the
   device sets the pace) that is the device's time for the phase to a copy's
   latency. ``prefill_phase_calls`` and ``decode_phase_calls`` count the
-  model's calls those reads waited behind (``admitted`` and ``decode_steps``
-  again, but moving with the read and not at dispatch, so a window's two
-  deltas cover the same calls): ``prefill_phase_ms / prefill_phase_calls``
-  and ``decode_phase_ms / decode_phase_calls`` are a prefill call's and a
-  decode step's time with their share of the sampler. When the host arrives
+  model's calls those reads waited behind (``prefill_calls`` and
+  ``decode_steps`` again, but moving with the read and not at dispatch, so a
+  window's two deltas cover the same calls): ``prefill_phase_ms /
+  prefill_phase_calls`` and ``decode_phase_ms / decode_phase_calls`` are a
+  prefill call's (of however many rows) and a decode step's time with their
+  share of the sampler. When the host arrives
   late a read returns at once and the host's time lands on it: a prefill
   phase shorter than the host's own way from one read to the next (dispatch,
   emit, the caller's hop: 5-8 ms) reads as that long, and the decode step
@@ -134,7 +147,7 @@ from ``__init__``, so two snapshots subtract):
   decode step, not once a layer that reads pages), ``window_live_tokens``
   (filled ring entries the active slots attend over, a step and window layer)
   and, for a decoder-hybrid-decoder alone, ``prefill_cross_rows`` (rows the
-  cross-decoder computed in prefill: one a call, against
+  cross-decoder computed in prefill: one a row of a call, against
   ``prefill_batch_tokens`` for the self-decoder).
 
 Such a model's rings and rows need no allocator: a slot owns its own, a
@@ -148,7 +161,8 @@ The same boundaries are spans on the profiler's clock
 (``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
 that owns the engine shows ``ray_tpu/engine.step`` on the host plane and,
 inside it, in this order, ``engine.admit``, ``.prefill_dispatch`` (arguments
-``bucket``, the largest of the phase, and ``admitted``), ``.sample_dispatch``
+``bucket``, the largest of the phase, ``admitted``, ``calls`` and ``rows``:
+the calls' rows, padding included), ``.sample_dispatch``
 (argument ``greedy``: no slot samples, the program takes its ``argmax``
 branch), ``.decode_dispatch`` (arguments ``overlapped``: 1 if an earlier step
 is unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
@@ -158,10 +172,12 @@ tables, models with a latent cache or with ``layer_kinds`` only), ``.sample_disp
 every sampler call of the step before. ``.readback`` names what it waited
 for (arguments ``kind``: ``prefill`` or ``decode``; ``calls``: the prefill
 calls behind it, 1 for a decode step; ``bucket``: the largest of those
-calls' buckets, 0 for a decode step; ``rows``), and its end is the moment
+calls' buckets, 0 for a decode step; ``rows``: the requests whose token it
+brings), and its end is the moment
 the phase counters and the token gaps are dated by: in a trace it lies a
 copy's latency after the end of the last ``jit_sample_tokens`` before it on
-the device plane. Around a shape's first use, ``.compile``. With
+the device plane. Around a shape's first use on the serving path,
+``.compile``. With
 ``RAY_TPU_ENABLE_TRACING`` a finished request also leaves ``engine.queued``,
 ``engine.prefill`` and ``engine.decode`` spans (``request_id``; the last
 also ``tokens`` and ``max_gap_ms``, the longest gap between two of its
@@ -174,12 +190,14 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import math
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ray_tpu.llm import prefill_shapes
 from ray_tpu.llm.config import EngineConfig, LLMConfig, SamplingParams
 from ray_tpu.llm.tokenizer import get_tokenizer
 from ray_tpu.util import goodput, tracing
@@ -189,6 +207,71 @@ from ray_tpu.util import goodput, tracing
 _ITL_RUNGS_MS = (25, 50, 100, 200, 400, 800)
 _ITL_RUNGS_NS = np.array(_ITL_RUNGS_MS, np.int64) * 1_000_000
 _ITL_KEYS = tuple(f"itl_over_{n}ms" for n in _ITL_RUNGS_MS)
+
+# rows of a prefill call that holds more than one request: a group of three
+# takes the four with one row of padding
+_ROW_BUCKETS = (2, 4)
+# the positions a call may pad beyond its requests' own length buckets for
+# every call it saves. A call costs a fixed part (every held weight read
+# once) and a part that goes with its padded positions; sharing saves the
+# first and pays the second for what it pads. On the chip a one-row call of
+# cell 9's model takes 12.8 / 15.3 / 18.5 / 26.7 / 39.6 ms at 128 / 256 / 512
+# / 1,024 / 2,048 positions (tests/test_chip_lfm2.py, PR 41): 10.5 ms fixed,
+# 0.013-0.016 ms a position, so 700-800 padded positions cost what one shared
+# call saves. The same division of the ledger's call times (PR 40) by the
+# weights' bytes gives about 600 in cell 3, 800-850 in cells 6 and 8 and 270
+# in cell 7: a dense model stands at the chip's 240 operations a byte, a
+# sparse one above it by its total over its active parameters. 512 keeps a
+# margin in every cell but 7, whose traffic forms no groups. A fraction of S
+# instead would let a 4,096 bucket take a 2,048 along: 2,048 positions padded
+# for 800 saved.
+_PAD_TOKENS = 512
+
+
+def row_buckets(S: int, cap: int) -> List[int]:
+    """The rows a call at length bucket ``S`` may have beside one: two while
+    ``2 x S`` padded tokens fit ``cap``, four while ``4 x S`` do and three
+    requests with a row of padding keep within ``_PAD_TOKENS`` a call saved
+    (four requests of one bucket over that are two calls of two)."""
+    # R rows hold at least R // 2 + 1 requests (fewer take the row bucket
+    # below), which saves R // 2 calls and leaves R // 2 - 1 rows of padding
+    return [R for R in _ROW_BUCKETS
+            if R * S <= cap and (R // 2 - 1) * S <= _PAD_TOKENS * (R // 2)]
+
+
+def prefill_groups(buckets: Sequence[int], cap: int,
+                   ready: Callable[[int, int], bool] = lambda R, S: True
+                   ) -> List[Tuple[int, int, List[int]]]:
+    """Which prefill calls the requests admitted in one step form. ``buckets``:
+    each request's own length bucket, in admission order. Returns ``(R, S,
+    members)`` a call: its rows, its length bucket, and which requests (as
+    indices into ``buckets``) fill its first ``len(members)`` rows; every
+    request is in exactly one. The longest request left leads a call at its
+    own bucket ``S`` and takes the next longest with it, as many as (1) ``R``,
+    their number rounded up to a row bucket of 2 or 4, is one of
+    ``row_buckets(S, cap)``: ``R x S`` padded tokens do not pass ``cap``, the
+    largest one-row call the deployment runs anyway (what must fit the chip);
+    (2) the call pads no more than ``_PAD_TOKENS`` positions beyond its
+    members' own buckets for every call it saves (a padded position computes
+    for nothing, and enough of them cost what the shared read of the weights
+    saves); (3) ``[R, S]`` is a shape that is ``ready``. A request with no
+    such partner is the ``[1, S]`` call it always was."""
+    left = sorted(range(len(buckets)), key=lambda i: -buckets[i])
+    calls = []
+    while left:
+        S = buckets[left[0]]
+        rows, n = 1, 1
+        allowed = row_buckets(S, cap)
+        for take in range(min(_ROW_BUCKETS[-1], len(left)), 1, -1):
+            R = min(R for R in _ROW_BUCKETS if R >= take)
+            padded = R * S - sum(buckets[i] for i in left[:take])
+            if (R in allowed and padded <= _PAD_TOKENS * (take - 1)
+                    and ready(R, S)):
+                rows, n = R, take
+                break
+        calls.append((rows, S, left[:n]))
+        left = left[n:]
+    return calls
 
 
 @dataclasses.dataclass
@@ -228,7 +311,9 @@ class _Unread:
     tokens: Any  # [B] int32 on the device, its copy to the host started
     rows: List[Tuple[_Request, int]]  # whose token sits at which slot
     kind: str  # "prefill" (a phase's first tokens) or "decode" (a step's)
-    calls: int  # model calls it waits behind: prefill calls, or 1 decode step
+    # model calls it waits behind: prefill calls (of one or more rows each),
+    # or 1 decode step
+    calls: int
     bucket: int  # the largest of the prefill calls' length buckets; 0 = decode
     sent_ns: int  # perf_counter_ns at its dispatch
     moe_load: Any = None  # a decode step's routing, outside the donated cache
@@ -291,15 +376,26 @@ class JaxLLMEngine:
             self.params = self._init_random_params(seed)
 
         e = self.ecfg
-        self.cache = model_runner.init_cache(self.mcfg, e.num_pages,
-                                             e.page_size, e.max_num_seqs)
+        # what the programs hand round (the cache, the logits buffer, the
+        # newest tokens) is committed to its device from the start: a program
+        # of several rows hands its outputs back committed
+        # (llm/prefill_shapes.py), jit keys its programs by that, and none
+        # may meet this state both ways (the second would be a compile in the
+        # middle of serving)
+        def pinned(tree):
+            return jax.tree.map(lambda x: jax.device_put(x, x.sharding), tree)
+
+        self.cache = pinned(model_runner.init_cache(
+            self.mcfg, e.num_pages, e.page_size, e.max_num_seqs))
         B, MP = e.max_num_seqs, e.pages_per_seq
+        # the most padded tokens a call of several rows may hold
+        self._group_cap = self._prefill_bucket(e.max_model_len)
         self._block_tables = np.zeros((B, MP), np.int32)
         self._seq_lens = np.zeros(B, np.int32)
         self._active = np.zeros(B, bool)
         # every slot's newest token, on the device: what the next decode step
         # reads, whether or not the host has seen it yet
-        self._tokens = jax.numpy.zeros(B, jax.numpy.int32)
+        self._tokens = pinned(jax.numpy.zeros(B, jax.numpy.int32))
         # sampler calls dispatched and not yet read, oldest first
         self._unread: collections.deque[_Unread] = collections.deque()
         # what was emitted since step() last returned
@@ -316,8 +412,12 @@ class JaxLLMEngine:
         # where a prefill phase gathers its calls' logits, by slot, for the
         # one sampler call; rows of slots not admitted in a phase are stale
         # and their samples unread
-        self._prefill_logits = jax.numpy.asarray(
-            np.zeros((B, self.mcfg.vocab_size), np.float32))
+        self._prefill_logits = pinned(jax.numpy.asarray(
+            np.zeros((B, self.mcfg.vocab_size), np.float32)))
+        # calls of several rows: their programs, made off the serving path
+        self._row_shapes = prefill_shapes.RowShapes(
+            self.mcfg, self.params, self.cache, self._prefill_logits, MP,
+            self._row_shape_ready)
         self._slots: List[Optional[_Request]] = [None] * B
         self._free_pages = collections.deque(range(1, e.num_pages))
         self._waiting: collections.deque[_Request] = collections.deque()
@@ -335,7 +435,8 @@ class JaxLLMEngine:
         self.metrics = {
             "prefill_tokens": 0, "decode_steps": 0, "generated_tokens": 0,
             "preempted": 0, "steps": 0, "prefill_steps": 0, "admitted": 0,
-            "prefill_batch_tokens": 0, "compiles": 0,
+            "prefill_calls": 0, "prefill_batch_tokens": 0, "compiles": 0,
+            "prefill_shapes_wanted": 0, "prefill_shapes_ready": 0,
             "step_ms": 0.0, "host_ms": 0.0, "readback_ms": 0.0,
             "admit_ms": 0.0, "prefill_dispatch_ms": 0.0,
             "decode_dispatch_ms": 0.0, "sample_dispatch_ms": 0.0,
@@ -567,15 +668,29 @@ class JaxLLMEngine:
             self._phase_ended_ns = t1 = time.perf_counter_ns()
             self.metrics[name + "_ms"] += (t1 - t0) / 1e6
 
+    @contextlib.contextmanager
     def _first_use(self, program: str, bucket: int = 0):
         """Around a model program's call: the span ``engine.compile`` when
         this engine has not yet called it at this shape (jit traces and
-        compiles, or reads its cache, before it returns)."""
+        compiles, or reads its cache, before it returns). A prefill bucket's
+        first use also asks for the bucket's calls of several rows, which
+        are made off the serving path (``prefill_shapes.RowShapes``)."""
         if self._compile_watch.observe(program, (bucket,)) is None:
-            return contextlib.nullcontext()
+            yield
+            return
         self.metrics["compiles"] += 1
-        return tracing.annotate("engine.compile", program=program,
-                                bucket=bucket)
+        with tracing.annotate("engine.compile", program=program,
+                              bucket=bucket):
+            yield
+        if program == "prefill":
+            shapes = [(R, bucket)
+                      for R in row_buckets(bucket, self._group_cap)]
+            self.metrics["prefill_shapes_wanted"] += len(shapes)
+            self._row_shapes.want(shapes)
+
+    def _row_shape_ready(self) -> None:
+        # from RowShapes' threads, one at a time; they write this key alone
+        self.metrics["prefill_shapes_ready"] += 1
 
     def step(self, decode: bool = True) -> List[RequestOutput]:
         """One scheduling step: dispatch this step's programs from what the
@@ -615,12 +730,13 @@ class JaxLLMEngine:
         if not decode:
             self._drain()
 
-        # 1) admit + prefill: one program call per admitted request, on its
-        # own row and its own length bucket, back to back (the donated cache
-        # chains them; nothing is read in between). Each call's logits land
-        # in the [B, vocab] buffer at the request's slot, so one sampler call
-        # serves the phase however many were admitted, and no shape depends
-        # on that number. Its tokens join the others on the device.
+        # 1) admit + prefill: the admitted requests one row each, alone at
+        # their own length bucket or several to a call at the longest's
+        # (prefill_groups), the calls back to back (the donated cache chains
+        # them; nothing is read in between). Each call's logits land in the
+        # [B, vocab] buffer at its requests' slots, so one sampler call serves
+        # the phase however many were admitted. Its tokens join the others on
+        # the device.
         with self._phase("admit"):
             admitted = self._try_admit()
             now = time.perf_counter()
@@ -631,37 +747,29 @@ class JaxLLMEngine:
         if admitted:
             overlapped = self._earlier > 0
             slots = [r.slot for r in admitted]
-            buckets = [self._prefill_bucket(n) for n in self._seq_lens[slots]]
-            with self._phase("prefill_dispatch", bucket=max(buckets),
-                             admitted=len(admitted)):
-                for r, S in zip(admitted, buckets):
-                    row = slice(r.slot, r.slot + 1)
-                    toks = np.zeros((1, S), np.int32)
-                    toks[0, :self._seq_lens[r.slot]] = r.cache_tokens
-                    # a model that keeps state by slot is told which one
-                    where = (self._up(np.arange(r.slot, r.slot + 1,
-                                                dtype=np.int32)),) \
-                        if self.mcfg.layer_kinds else ()
-                    with self._first_use("prefill", S):
-                        logits, self.cache = mr.prefill(
-                            self.params, self.mcfg, self.cache,
-                            jnp.asarray(toks), self._up(self._seq_lens[row]),
-                            self._up(self._block_tables[row]), *where)
-                        self._prefill_logits = mr.place_row(
-                            self._prefill_logits, logits, np.int32(r.slot))
+            calls = prefill_groups(
+                [self._prefill_bucket(n) for n in self._seq_lens[slots]],
+                self._group_cap, lambda R, S: (R, S) in self._row_shapes.ready)
+            rows = sum(R for R, _, _ in calls)
+            bucket = max(S for _, S, _ in calls)
+            with self._phase("prefill_dispatch", bucket=bucket,
+                             admitted=len(admitted), calls=len(calls),
+                             rows=rows):
+                for R, S, members in calls:
+                    self._prefill_call(R, S, [slots[i] for i in members])
             firsts = self._sample(self._prefill_logits)
             self._tokens = mr.select_rows(
                 jnp.asarray(np.isin(np.arange(len(self._slots)), slots)),
                 firsts, self._tokens)
             m["prefill_steps"] += 1
             m["admitted"] += len(admitted)
+            m["prefill_calls"] += len(calls)
             m["prefill_tokens"] += int(self._seq_lens[slots].sum())
-            m["prefill_batch_tokens"] += sum(buckets)
-            if self.mcfg.sambay:  # the cross-decoder ran one row a call
-                m["prefill_cross_rows"] += len(admitted)
+            m["prefill_batch_tokens"] += sum(R * S for R, S, _ in calls)
+            if self.mcfg.sambay:  # the cross-decoder ran one row a row of a call
+                m["prefill_cross_rows"] += rows
             self._active[slots] = True
-            self._sent(firsts, admitted, "prefill", len(admitted),
-                       max(buckets))
+            self._sent(firsts, admitted, "prefill", len(calls), bucket)
 
         # 2) one decode step for all active slots, on the tokens the device
         # holds: the host advances what does not depend on a token's value
@@ -710,6 +818,40 @@ class JaxLLMEngine:
         while self._unread and (self._earlier or not decode):
             self._read()
         return overlapped
+
+    def _prefill_call(self, R: int, S: int, slots: List[int]) -> None:
+        """One prefill program call: the requests in ``slots`` in its first
+        rows, padding rows behind them (length 0, a block table of zeros and
+        the slot past the last, so that whatever they write is dropped or
+        lands on the scratch page), the logits of the real rows into
+        ``_prefill_logits`` at their slots."""
+        import jax.numpy as jnp
+
+        mr, n = self._mr, len(slots)
+        toks = np.zeros((R, S), np.int32)
+        lens = np.zeros(R, np.int32)
+        tables = np.zeros((R, self._block_tables.shape[1]), np.int32)
+        where = np.full(R, len(self._slots), np.int32)
+        lens[:n], tables[:n], where[:n] = (
+            self._seq_lens[slots], self._block_tables[slots], slots)
+        for i, slot in enumerate(slots):
+            toks[i, :lens[i]] = self._slots[slot].cache_tokens
+        where = jnp.asarray(where)
+        # a model that keeps state by slot is told which ones
+        told = (where,) if self.mcfg.layer_kinds else ()
+        if R == 1:  # jit's own, compiled at the bucket's first use
+            prefill = functools.partial(mr.prefill, self.params, self.mcfg)
+            place, first_use = mr.place_rows, self._first_use("prefill", S)
+        else:  # made off the serving path, the model compiled in
+            prefill = functools.partial(self._row_shapes.ready[(R, S)],
+                                        self.params)
+            place = self._row_shapes.place[R]
+            first_use = contextlib.nullcontext()
+        with first_use:
+            logits, self.cache = prefill(
+                self.cache, jnp.asarray(toks), jnp.asarray(lens),
+                jnp.asarray(tables), *told)
+            self._prefill_logits = place(self._prefill_logits, logits, where)
 
     def _sent(self, tokens, reqs: List[_Request], kind: str, calls: int,
               bucket: int = 0, moe_load=None) -> None:

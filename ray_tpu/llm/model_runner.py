@@ -15,8 +15,9 @@ Layout:
 Both programs take the cache donated and write it in place: per layer, one
 scatter for K and one for V whose operand is the whole 5-D array and whose
 indices are (layer, page, offset): B rows in decode, and in prefill the S
-positions of each row it is given (the engine gives it one admitted request
-a call; the batch axis is generic).
+positions of each row it is given (the engine gives it the requests admitted
+in one step, one row each, alone or in groups of two to four padded to one
+length bucket; a row of length 0 is padding and writes the scratch page).
 Nothing slices a layer out or writes one back, so a step's cache traffic is
 the rows it writes, not the cache. Decode then gathers each slot's pages from
 the same array by (layer, block_tables) into a [B, Lmax] view and runs
@@ -825,10 +826,14 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
     slot (``HybridCache``); such a model leaves its state at position
     ``lengths - 1``, not at the padded ``S - 1``, and runs its cross-decoder
     on that position alone. The engine always gives ``slots`` for such a
-    model. Where none is given, row ``b`` fills slot ``b``: that is the path
-    of the benchmark's check alone (``benchmarks/jobs/serve.py:
-    reference_check`` calls an every-slot ``[max_num_seqs, S]`` batch without
-    it), and goes once the harness calls ``[1, S]`` with a slot.
+    model: one row an admitted request, and for a padding row (length 0, a
+    block table of zeros) the slot past the last, whose writes the scatters
+    drop. Such a row reaches no expert, and what it writes by position lands
+    on the scratch page, in every kind of model. Where no ``slots`` is
+    given, row ``b`` fills slot ``b``: that is the path of the benchmark's
+    check alone (``benchmarks/jobs/serve.py:reference_check`` calls an
+    every-slot ``[max_num_seqs, S]`` batch without it), and goes once the
+    harness calls ``[1, S]`` with a slot.
     """
     from ray_tpu.ops.attention import attention as attention_op
 
@@ -884,12 +889,14 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def place_row(buffer: jax.Array, row: jax.Array, index: jax.Array) -> jax.Array:
-    """buffer [B, V] with ``row`` [1, V] written at row ``index``, a traced
-    scalar: one program whichever slot, so the engine can gather the logits
-    of one-row prefill calls by slot without a shape that depends on how
-    many there were."""
-    return jax.lax.dynamic_update_slice(buffer, row, (index, 0))
+def place_rows(buffer: jax.Array, rows: jax.Array, slots: jax.Array
+               ) -> jax.Array:
+    """buffer [B, V] with ``rows`` [R, V] written at the rows ``slots`` [R],
+    traced: one program a number of rows whichever slots, so the engine can
+    gather the logits of a phase's prefill calls by slot without a shape that
+    depends on how many requests it admitted. A slot past the last (a padding
+    row's) is dropped."""
+    return buffer.at[slots].set(rows, mode="drop")
 
 
 @jax.jit

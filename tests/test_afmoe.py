@@ -382,6 +382,33 @@ def test_engine_serves_and_counts_the_share():
     assert m["prefill_cross_rows"] == 0
 
 
+@pytest.mark.parametrize("lens,rows,bucket", [((11, 5, 14), 4, 16),
+                                              ((19, 9), 2, 32)])
+def test_burst_admitted_in_one_step_shares_a_prefill_call(lens, rows, bucket):
+    """Requests admitted in one step are rows of ONE prefill call: each row's
+    pages by its block table, its rings by its slot, and the tokens those the
+    same requests generate one a step."""
+    import prefill_rows
+
+    eng = _engine()
+    rng = np.random.default_rng(5)
+    d = prefill_rows.burst_equals_one_a_step(
+        eng, [rng.integers(0, VOCAB, n).tolist() for n in lens])
+    assert (d["prefill_calls"], d["prefill_batch_tokens"]) == (1, rows * bucket)
+
+
+@pytest.mark.parametrize("model", ["afmoe", "hybrid"])
+def test_padding_row_changes_no_page_ring_row_by_slot_or_load(engine, model):
+    """Through ``_kinds_prefill`` and through the decoder-hybrid-decoder's
+    ``_hybrid_prefill`` (recurrent rows by slot beside the rings)."""
+    import prefill_rows
+
+    eng = engine if model == "afmoe" else _hybrid_engine()
+    rng = np.random.default_rng(6)
+    prefill_rows.padding_rows_write_nothing(
+        eng, rng.integers(0, eng.mcfg.vocab_size, 7).tolist())
+
+
 def test_engine_refuses_what_it_cannot_do(engine):
     with pytest.raises(ValueError, match="holds 4 of 16"):
         _engine(expect_experts=RANKS * HELD)

@@ -724,6 +724,30 @@ def _lower_rms_kinds(one_chip, name="trinity-large-preview"):
     configuration file with an ``"rms"`` block by kind: ``benchmarks/configs/
     trinity-large-preview.json`` (5 layers, 32 of 256 experts held, 32 slots x
     16896, pages of 512) unless another is named."""
+    e, cfg, params, cache = _rms_kinds(one_chip, name, "flash")
+    B, MP = e.max_num_seqs, e.pages_per_seq
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+
+    def prefill(rows, bucket):
+        slots = (i32(1),) if rows == 1 else ()    # the check gives none
+        return mr.prefill.lower(params, cfg, cache, i32(rows, bucket),
+                                i32(rows), i32(rows, MP), *slots)
+
+    from ray_tpu.llm import model_runner as mr
+    return cache, prefill, lambda: mr.decode_step.lower(
+        params, cfg, cache, i32(B), i32(B), i32(B, MP), active)
+
+
+def _rms_kinds(one_chip, name, attention_impl):
+    """``benchmarks/configs/<name>.json`` as the engine holds it: its
+    geometry, the model, and the shapes of its parameters and cache on
+    ``one_chip``. ``attention_impl``: ``"auto"`` is what the cell runs (the
+    flash kernel where the program is traced for the chip), ``"flash"`` what a
+    lowering in this process, which has no chip, must be told."""
     import json
 
     import flax.linen as nn
@@ -740,25 +764,12 @@ def _lower_rms_kinds(one_chip, name="trinity-large-preview"):
     e = EngineConfig(**conf["job"]["engine"])
     cfg = dataclasses.replace(
         common.transformer_config(conf, e.max_model_len),
-        attention_impl="flash")
+        attention_impl=attention_impl)
     params = _on(jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))), one_chip)
     cache = _on(jax.eval_shape(lambda: mr.init_cache(
         cfg, e.num_pages, e.page_size, e.max_num_seqs)), one_chip)
-    B, MP = e.max_num_seqs, e.pages_per_seq
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
-
-    def prefill(rows, bucket):
-        slots = (i32(1),) if rows == 1 else ()    # the check gives none
-        return mr.prefill.lower(params, cfg, cache, i32(rows, bucket),
-                                i32(rows), i32(rows, MP), *slots)
-
-    return cache, prefill, lambda: mr.decode_step.lower(
-        params, cfg, cache, i32(B), i32(B), i32(B, MP), active)
+    return e, cfg, params, cache
 
 
 def test_afmoe_decode_reads_rings_and_live_pages_and_nothing_else(one_chip):

@@ -19,6 +19,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KEYS = {
     "prefill_tokens", "decode_steps", "generated_tokens", "preempted",
     "steps", "prefill_steps", "admitted", "prefill_batch_tokens", "compiles",
+    # prefill program calls (of one row or several), and the shapes of
+    # several rows compiled off the serving path
+    "prefill_calls", "prefill_shapes_wanted", "prefill_shapes_ready",
     "step_ms", "host_ms", "readback_ms", "admit_ms", "prefill_dispatch_ms",
     "decode_dispatch_ms", "sample_dispatch_ms", "emit_ms",
     "between_steps_ms", "queue_wait_ms", "ttft_ms",
@@ -147,7 +150,7 @@ def _phases_and_gaps_add_up(m, first_tokens):
     assert m["prefill_phase_ms"] + m["decode_phase_ms"] == pytest.approx(
         m["phase_ms"])
     # nothing is left unread: every call dispatched was waited for
-    assert m["prefill_phase_calls"] == m["admitted"]
+    assert m["prefill_phase_calls"] == m["prefill_calls"] <= m["admitted"]
     assert m["decode_phase_calls"] == m["decode_steps"]
     assert m["itl_tokens"] == m["generated_tokens"] - first_tokens > 0
     rungs = [m["itl_tokens"]] + [m[k] for k in LADDER]
@@ -246,10 +249,13 @@ def test_one_row_calls_leave_what_the_padded_batch_leaves(engine):
 
 def test_no_shape_depends_on_how_many_were_admitted(engine, monkeypatch):
     """The benchmark warms one request per reachable length bucket, one at a
-    time, and nothing may compile in its window: after that warm-up, steps
-    that admit 1, 3 and all eight requests at once, of mixed buckets, compile
-    nothing, by the engine's own count and by the benchmark's (JAX's compile
-    events: any program or eager operation at a shape not yet run)."""
+    time, and nothing may compile in its window: that warm-up's first
+    request also asks for every bucket's calls of two and of four rows, off
+    the serving path, and once they are made (the benchmark's reference check
+    and lead-in give it the time) steps that admit 1, 3 and all eight requests at
+    once, of mixed buckets, alone or sharing calls, compile nothing, by the
+    engine's own count and by the benchmark's (JAX's compile events: any
+    program or eager operation at a shape not yet run)."""
     monkeypatch.syspath_prepend(REPO)
     from benchmarks.jobs.common import CompileCounter
 
@@ -257,6 +263,9 @@ def test_no_shape_depends_on_how_many_were_admitted(engine, monkeypatch):
     for n in (10, 20, 40, 100):  # the four buckets of 16..128
         engine.generate([_prompt(n)], SamplingParams(max_tokens=3))
     assert m["compiles"] == 5  # four prefill buckets, decode
+    assert engine._row_shapes.wait(300)
+    # [2, 16], [4, 16], [2, 32], [4, 32], [2, 64]: 128 padded tokens at most
+    assert m["prefill_shapes_wanted"] == m["prefill_shapes_ready"] == 5
     events = CompileCounter()
     for i, burst in enumerate([(7,), (12, 33, 90), (5, 17, 70, 9, 30, 101, 16,
                                                    64)]):
@@ -269,7 +278,8 @@ def test_no_shape_depends_on_how_many_were_admitted(engine, monkeypatch):
         assert m["prefill_steps"] - calls == 1
         while engine.has_unfinished():
             engine.step()
-    assert m["compiles"] == 5
+    assert m["compiles"] == 5 and m["prefill_shapes_ready"] == 5
+    assert m["prefill_calls"] == 4 + 1 + 2 + 4 < m["admitted"] == 4 + 12
     assert events.count == 0
 
 
@@ -331,7 +341,7 @@ def test_engine_spans_nest_in_step_order(engine, recorder):
         (1, "sample_dispatch")]
     attrs = {n: a for _, n, a in recorder}
     assert attrs["ray_tpu/engine.prefill_dispatch"] == {
-        "bucket": BUCKET, "admitted": 1}
+        "bucket": BUCKET, "admitted": 1, "calls": 1, "rows": 1}
     assert attrs["ray_tpu/engine.compile"]["program"] == "decode"
     assert attrs["ray_tpu/engine.decode_dispatch"] == {
         "overlapped": 0, "dropped": 0}

@@ -426,6 +426,30 @@ def test_mla_counters_after_a_known_number_of_steps():
     assert m["moe_decode_layer_steps"] == 5 * (LAYERS - 1)
 
 
+@pytest.mark.parametrize("lens,rows,bucket", [((11, 5, 14), 4, 16),
+                                              ((19, 9), 2, 32)])
+def test_burst_admitted_in_one_step_shares_a_prefill_call(lens, rows, bucket):
+    """Requests admitted in one step are rows of ONE prefill call, each row's
+    latent rows written through its own block table, its tokens' experts
+    chosen row by row: the tokens are those the same requests generate one a
+    step."""
+    import prefill_rows
+
+    eng = _engine()
+    rng = np.random.default_rng(5)
+    d = prefill_rows.burst_equals_one_a_step(
+        eng, [rng.integers(0, VOCAB, n).tolist() for n in lens])
+    assert (d["prefill_calls"], d["prefill_batch_tokens"]) == (1, rows * bucket)
+
+
+def test_padding_row_changes_no_latent_row_or_load(engine):
+    import prefill_rows
+
+    rng = np.random.default_rng(6)
+    prefill_rows.padding_rows_write_nothing(
+        engine, rng.integers(0, VOCAB, 7).tolist())
+
+
 def test_routing_is_counted_once_a_step_with_the_next_step_dispatched():
     """A decode step's ``moe_load`` comes back inside the cache the NEXT
     dispatch donates, before the host has read it: the engine takes a copy

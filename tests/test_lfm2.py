@@ -329,6 +329,31 @@ def test_engine_serves_admits_two_at_once_and_preempts():
     assert m["prefill_cross_rows"] == 0
 
 
+@pytest.mark.parametrize("lens,rows,bucket", [((11, 2, 14), 4, 16),
+                                              ((20, 9), 2, 32)])
+def test_burst_admitted_in_one_step_shares_a_prefill_call(lens, rows, bucket):
+    """Requests admitted in one step are rows of ONE prefill call (three in a
+    call of four with a padding row; a 32 bucket and a 16 bucket at 32): each
+    row's pages by its block table, its two rows by its slot, and the tokens
+    those the same requests generate one a step."""
+    import prefill_rows
+
+    eng = _engine(num_pages=None)   # room for all of them at once
+    rng = np.random.default_rng(5)
+    d = prefill_rows.burst_equals_one_a_step(
+        eng, [rng.integers(0, VOCAB, n).tolist() for n in lens])
+    assert (d["prefill_calls"], d["prefill_batch_tokens"]) == (1, rows * bucket)
+    assert d["preempted"] == 0
+
+
+def test_padding_row_changes_no_page_row_by_slot_or_load(engine):
+    import prefill_rows
+
+    rng = np.random.default_rng(6)
+    prefill_rows.padding_rows_write_nothing(
+        engine, rng.integers(0, VOCAB, 7).tolist())
+
+
 def test_engine_refuses_what_it_cannot_do(engine):
     with pytest.raises(ValueError, match="the model has 5"):
         _engine(expect_state_layers=0)

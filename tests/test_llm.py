@@ -163,17 +163,240 @@ def test_continuous_batching_matches_sequential(engine):
 ], ids=["greedy", "seeded"])
 def test_burst_of_four_length_buckets_matches_one_at_a_time(engine, sp):
     """Prompts of 9, 25, 50 and 100 tokens (buckets 16 to 128) admitted in
-    one step are each prefilled alone at their own bucket: the burst gives
-    every prompt the tokens it gets when served alone, and the device
-    computed 16 + 32 + 64 + 128 positions for it, not 4 x 128."""
+    one step: the burst gives every prompt the tokens it gets when served
+    alone. Under a cap of 128 padded tokens the longest is a call of its own,
+    the 64 and 32 buckets share a ``[2, 64]`` call, and the shortest, whose
+    bucket is a quarter of 64 but finds no row left under the cap, is the
+    ``[1, 16]`` call it always was: the device computed 128 + 128 + 16
+    positions in three calls, not 4 x 128 in one or in four."""
     prompts = [list(range(3, 3 + n)) for n in (9, 25, 50, 100)]
     singles = [engine.generate([p], sp)[0].token_ids for p in prompts]
-    computed = engine.metrics["prefill_batch_tokens"]
-    phases = engine.metrics["prefill_steps"]
+    assert engine._row_shapes.wait(300)
+    before = dict(engine.metrics)
     burst = engine.generate(prompts, sp)
     assert [o.token_ids for o in burst] == singles
-    assert engine.metrics["prefill_batch_tokens"] - computed == 240
-    assert engine.metrics["prefill_steps"] - phases == 1
+    d = {k: engine.metrics[k] - v for k, v in before.items()}
+    assert d["prefill_batch_tokens"] == 128 + 2 * 64 + 16
+    assert (d["prefill_steps"], d["admitted"], d["prefill_calls"]) == (1, 4, 3)
+
+
+# -- several admitted requests' rows in one prefill call (ISSUE 41) ----------
+
+
+@pytest.mark.parametrize("buckets,cap,ready,want", [
+    # one admitted request is the [1, S] call of today
+    ([256], 2560, None, [(1, 256, [0])]),
+    ([512, 512], 2560, None, [(2, 512, [0, 1])]),
+    # S is the longest member's bucket; the order of admission is kept
+    ([512, 1024], 2560, None, [(2, 1024, [1, 0])]),
+    # more than 512 positions of padding for the one call saved: apart
+    ([256, 1024], 2560, None, [(1, 1024, [1]), (1, 256, [0])]),
+    ([4096, 2048], 16896, None, [(1, 4096, [0]), (1, 2048, [1])]),
+    # R x S never passes the cap
+    ([2048, 512], 2560, None, [(1, 2048, [0]), (1, 512, [1])]),
+    ([1024] * 3, 2560, None, [(2, 1024, [0, 1]), (1, 1024, [2])]),
+    # three take the row bucket of four, with one row of padding, while
+    # that row is within 512 positions for each of the two calls saved
+    ([512] * 3, 2560, None, [(4, 512, [0, 1, 2])]),
+    ([1024] * 3, 16896, None, [(4, 1024, [0, 1, 2])]),
+    ([4096] * 3, 16896, None, [(2, 4096, [0, 1]), (1, 4096, [2])]),
+    ([2048] * 4, 16896, None, [(2, 2048, [0, 1]), (2, 2048, [2, 3])]),
+    ([256] * 5, 2560, None, [(4, 256, [0, 1, 2, 3]), (1, 256, [4])]),
+    ([128, 512, 256, 512, 128], 2560, None,
+     [(4, 512, [1, 3, 2, 0]), (1, 128, [4])]),
+    # a group forms only at a shape that is ready
+    ([512] * 3, 2560, {(2, 512)}, [(2, 512, [0, 1]), (1, 512, [2])]),
+    ([512] * 2, 2560, {(4, 512)}, [(1, 512, [0]), (1, 512, [1])]),
+    ([512] * 3, 2560, set(), [(1, 512, [0]), (1, 512, [1]), (1, 512, [2])]),
+    ([], 2560, None, []),
+])
+def test_prefill_groups_by_hand(buckets, cap, ready, want):
+    from ray_tpu.llm.engine import prefill_groups
+
+    usable = (lambda R, S: True) if ready is None else (
+        lambda R, S: (R, S) in ready)
+    assert prefill_groups(buckets, cap, usable) == want
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_prefill_groups_place_every_request_once_within_the_limits(seed):
+    """Random admitted sets against the rule's own promises: every request in
+    exactly one call, its first rows; ``R`` 1, 2 or 4 and more than half
+    filled; no group over the cap or at a shape that is not ready; ``S`` the
+    longest member's bucket; no more than 512 positions of padding beyond the
+    members' own buckets for every call saved."""
+    from ray_tpu.llm.engine import prefill_groups, row_buckets
+
+    rng = np.random.default_rng(seed)
+    sizes = [128, 256, 512, 1024, 2048, 4096]
+    grouped = 0
+    for _ in range(200):
+        buckets = rng.choice(sizes, rng.integers(1, 10)).tolist()
+        cap = int(rng.choice([2048, 2560, 4096, 8192, 16896]))
+        unready = {(int(rng.choice([2, 4])), int(rng.choice(sizes)))
+                   for _ in range(rng.integers(0, 4))}
+        calls = prefill_groups(buckets, cap, lambda R, S: (R, S) not in unready)
+        placed = sorted(i for _, _, members in calls for i in members)
+        assert placed == list(range(len(buckets)))
+        for R, S, members in calls:
+            own = [buckets[i] for i in members]
+            assert R in (1, 2, 4) and R // 2 < len(members) <= R
+            assert S == max(own)
+            if R > 1:
+                assert R * S <= cap and R in row_buckets(S, cap)
+                assert (R, S) not in unready
+                assert R * S - sum(own) <= 512 * (len(own) - 1)
+                grouped += 1
+    assert grouped > 100
+
+
+def test_padding_row_changes_no_page_and_no_logits_row(engine):
+    import prefill_rows
+
+    prefill_rows.padding_rows_write_nothing(engine, list(range(5, 26)))
+
+
+def test_counters_after_a_known_burst():
+    """Six prompts into six slots in one step, after each bucket was used
+    once: 16, 16, 16 and 32, 32 and 64 under a cap of 128. The longest takes
+    one 32 along (``[2, 64]`` fits the cap; four rows of 64 do not), then the
+    other 32 leads the three 16s in ``[4, 32]``."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    eng = JaxLLMEngine(make_config(max_num_seqs=6), seed=0)
+    sp = SamplingParams(max_tokens=3)
+    for n in (9, 25, 50):
+        eng.generate([list(range(3, 3 + n))], sp)
+    assert eng._row_shapes.wait(300)
+    m = eng.metrics
+    # (2, S) and (4, S) of every bucket used, while R x S <= 128: asked for
+    # at the bucket's first use, none failed
+    assert m["prefill_shapes_wanted"] == m["prefill_shapes_ready"] == 5
+    assert m["compiles"] == 4 and not eng._row_shapes.failed
+    before = dict(m)
+    lens = (5, 9, 14, 20, 30, 40)
+    for i, n in enumerate(lens):
+        eng.add_request(f"r{i}", list(range(3, 3 + n)), sp)
+    assert eng.step() == []
+    d = {k: m[k] - v for k, v in before.items()}
+    assert (d["prefill_steps"], d["admitted"], d["prefill_calls"]) == (1, 6, 2)
+    assert d["prefill_tokens"] == sum(lens)
+    assert d["prefill_batch_tokens"] == 2 * 64 + 4 * 32
+    assert d["prefill_phase_calls"] == 0   # moves with the read
+    while eng.has_unfinished():
+        eng.step()
+    d = {k: m[k] - v for k, v in before.items()}
+    assert d["prefill_phase_calls"] == d["prefill_calls"] == 2
+    assert d["compiles"] == d["prefill_shapes_ready"] == 0
+    assert d["generated_tokens"] == 6 * 3
+
+
+@pytest.mark.parametrize("platform,kernels", [("cpu", []),
+                                              ("tpu", ["flash_fwd"])])
+def test_exporting_process_chooses_as_the_platform_it_lowers_for(platform,
+                                                                 kernels):
+    """The process that traces the programs of several rows is held to the
+    CPU whatever the serving process runs on. What is chosen at trace time
+    (``attention_impl="auto"``, every serve cell's, takes the flash kernel on
+    the chip alone) has to come out as the serving process's own ``[1, S]``
+    trace has it: a program exported for the chip holds ``flash_fwd``."""
+    import re
+
+    from jax import export
+
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    eng = JaxLLMEngine(LLMConfig(
+        model_id="tiny", engine_config=EngineConfig(
+            max_num_seqs=2, max_model_len=256, prefill_bucket_min=128),
+        model_overrides={"n_heads": 1, "n_kv_heads": 1, "max_seq_len": 256}),
+        seed=0)
+    assert (eng.mcfg.attention_impl, eng.mcfg.head_dim) == ("auto", 64)
+    shapes = eng._row_shapes
+    shapes._platform = platform
+    blob, = shapes._export([(2, 128)])
+    text = export.deserialize(blob).mlir_module()
+    assert sorted(set(re.findall(r'kernel_name = "(\w+)"', text))) == kernels
+
+
+@pytest.mark.parametrize("broken", ["one", "all"])
+def test_a_shape_that_cannot_be_made_is_named_and_never_used(
+        engine, broken, monkeypatch, caplog):
+    """A program of several rows that the exporting process or the compiler
+    refuses (no room on the device, say) is never ready: it stands in
+    ``failed`` with the reason, the log names it, ``prefill_shapes_ready``
+    stays under ``prefill_shapes_wanted``, the other shapes are made all the
+    same, and the requests that would have shared it go as the one-row calls
+    they always were."""
+    from ray_tpu.llm import prefill_shapes
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    eng = JaxLLMEngine(make_config(), params=engine.params, seed=0)
+    if broken == "one":
+        export = prefill_shapes.RowShapes._export
+
+        def refused(self, shapes):
+            for shape, blob in zip(shapes, export(self, shapes)):
+                yield RuntimeError("RESOURCE_EXHAUSTED: no room") \
+                    if shape == (2, 32) else blob
+        monkeypatch.setattr(prefill_shapes.RowShapes, "_export", refused)
+    else:  # the exporting process cannot lower for it: an error a shape
+        eng._row_shapes._platform = "no-such-platform"
+    sp = SamplingParams(max_tokens=3)
+    prompts = [list(range(3, 28)), list(range(40, 60))]  # both of bucket 32
+    alone = [eng.generate([p], sp)[0].token_ids for p in prompts]
+    with caplog.at_level("WARNING", logger=prefill_shapes.__name__):
+        assert eng._row_shapes.wait(300)
+    m, made = eng.metrics, eng._row_shapes
+    lost = 1 if broken == "one" else 2  # of the one bucket's [2, 32], [4, 32]
+    assert m["prefill_shapes_wanted"] == 2 == m["prefill_shapes_ready"] + lost
+    assert len(made.failed) == lost and (2, 32) in made.failed
+    assert (2, 32) not in made.ready and len(made.ready) == 2 - lost
+    assert f"{2 - lost} of 2 ready" in caplog.text
+    assert "[2, 32] not made" in caplog.text
+    if broken == "one":
+        assert "RESOURCE_EXHAUSTED" in made.failed[2, 32]
+    before = m["prefill_calls"]
+    assert [o.token_ids for o in eng.generate(prompts, sp)] == alone
+    assert m["prefill_calls"] - before == 2
+
+
+def test_preempted_request_is_prefilled_again_beside_a_new_one(engine):
+    """Two long requests run the pool dry; the one sent back waits at the head
+    of the queue with a new request behind it, and the step that frees the
+    pages admits both: one ``[2, 64]`` call holds the preempted request's
+    prompt + generated tokens and the newcomer's prompt. Greedy tokens are a
+    roomy engine's."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    sp = SamplingParams(max_tokens=30)
+    prompts = {"a": list(range(3, 33)), "b": list(range(40, 70)),
+               "new": list(range(80, 97))}
+    roomy = JaxLLMEngine(make_config(max_num_seqs=3), params=engine.params,
+                         seed=0)
+    expect = {k: roomy.generate([p], sp)[0].token_ids
+              for k, p in prompts.items()}
+    eng = JaxLLMEngine(make_config(max_num_seqs=3, num_pages=7),
+                       params=engine.params, seed=0)
+    for n in (10, 20, 40):   # each bucket once: its shapes of several rows
+        eng.generate([list(range(3, 3 + n))], SamplingParams(max_tokens=2))
+    assert eng._row_shapes.wait(300)
+    m = eng.metrics
+    eng.add_request("a", prompts["a"], sp)
+    eng.add_request("b", prompts["b"], sp)
+    got, shared = {}, []
+    while eng.has_unfinished():
+        if m["preempted"] == 1 and "new" in prompts:
+            eng.add_request("new", prompts.pop("new"), sp)
+        before = (m["admitted"], m["prefill_calls"])
+        for o in eng.step():
+            if o.finished:
+                got[o.request_id] = o.token_ids
+        if m["admitted"] - before[0] == 2 and m["preempted"]:
+            shared.append(m["prefill_calls"] - before[1])
+    assert m["preempted"] == 1 and shared == [1]
+    assert got == expect
+    assert sorted(eng._free_pages) == list(range(1, 7))
 
 
 def _plain_loop(config, params, requests, eos):
