@@ -16,7 +16,24 @@ two compiled XLA programs (prefill per length bucket, one decode step):
   the longest member's bucket (``prefill_groups``: every held weight is then
   read once a call and not once a request, which in a sparse model is most of
   a short prompt's call); the calls run back to back and one sampler call
-  samples their first tokens. Then one decode step for all active slots.
+  samples their first tokens. Then one decode step for all active slots:
+  a program of its own (``jit_decode_step``) in a step that admitted nobody,
+  and, where the model's prefill program can carry one
+  (``model_runner.rides``: the models of ``layer_kinds`` under the "rms"
+  block), the decode rows of a step that admitted somebody RIDE the phase's
+  first prefill call. That call runs each side's mixer on its own rows and
+  everything else (experts, head) once over both, so a sparse model streams
+  its experts once in such a step and not twice; the slots that were active
+  before the admission get their token from it, one sampler call samples
+  theirs and the admitted requests' first, no ``decode_step`` is dispatched,
+  and the requests admitted in the step take their second token in the next.
+  A shape has one prefill program, of one row or of several: the carrying one
+  where the call's own rows do not dwarf the step's (``_RIDE_ROWS``), called
+  with nobody marked active where nothing rides, and the plain one elsewhere.
+  A phase's carrying calls go first, so its first call takes the step; a
+  ``[1, S]`` whose carrying program the compiler finds no room for is the
+  plain program from then on (``plain_buckets`` says why), and a phase with no
+  carrying call runs ``decode_step`` after it.
   Nothing waits for a partner: WHEN a request is admitted is as it was. A
   shape of several rows is made off the serving path from its bucket's first
   use on (``llm/prefill_shapes.py``: traced in a process of its own, compiled
@@ -61,12 +78,18 @@ from ``__init__``, so two snapshots subtract):
   ``overlapped_steps`` (those that did so while an earlier step's tokens were
   unread: all but the first of a busy stretch), ``dropped_tokens``,
   ``sample_calls`` (calls of the sampler program, ``jit_sample_tokens``: one
-  a decode step and one a prefill phase) and ``sample_greedy_calls`` (those
+  a prefill phase and one a decode step that rode none) and
+  ``sample_greedy_calls`` (those
   with no positive temperature in any slot, counted from the host's own
   ``_temps`` by the rule the program applies to its copy on the device: it
   then takes its ``argmax`` branch and computes no shortlist),
   ``prefill_steps`` (steps that ran a prefill phase), ``decode_steps`` (a
-  dropped token's step is one),
+  dropped token's step is one; so is a step whose decode rows rode a
+  prefill call) and, of those, ``riding_steps`` (the steps that did: no
+  ``jit_decode_step``, no sampler call and no read of their own, so
+  ``sample_calls == prefill_steps + decode_steps - riding_steps``);
+  ``generated_tokens``, ``itl_*`` and the ``*_live_tokens`` below count a
+  riding step's decode rows as any decode step's,
   ``admitted`` (requests, each one row of a prefill call) against
   ``prefill_calls`` (prefill program calls: fewer, where rows shared one),
   ``prefill_tokens`` (real prompt positions) against
@@ -90,7 +113,12 @@ from ``__init__``, so two snapshots subtract):
   profiler: ``prefill_phase_ms`` and ``decode_phase_ms``, with ``phase_ms``
   their sum. A read of a prefill phase's first tokens waits behind that
   phase's ``jit_prefill`` calls and their one sampler call, a read of a
-  decode step's tokens behind ``jit_decode_step`` and its sampler call; each
+  decode step's tokens behind ``jit_decode_step`` and its sampler call; a
+  phase that carried a decode step is read once, as a prefill phase (it IS
+  ``jit_prefill`` calls: ``prefill_phase_ms`` then holds that decode step's
+  attention and its rows' share of everything else, ``prefill_phase_ms /
+  phase_ms`` is no longer "the share lost to prefill", and
+  ``decode_phase_calls`` is ``decode_steps - riding_steps``); each
   adds the time from the end of the read before it (or from its own
   dispatch, if that came later: the device had run dry) to the moment its
   ``np.asarray(tokens)`` returned. While the reads truly block
@@ -123,8 +151,10 @@ from ``__init__``, so two snapshots subtract):
   step alone is 14-21 ms, so a gap over 50 ms was spent behind somebody's
   prefill phase; a preempted request's gap across its second prefill counts,
   as its client waited for it;
-- what routing did in decode, for a model with experts (all 0 for a dense
-  one): ``moe_decode_layer_steps`` (decode steps x expert layers) and, summed
+- what routing did in the ``jit_decode_step`` calls, for a model with experts
+  (all 0 for a dense one; a carrying prefill call's ``moe_load`` mixes prompt
+  and decode rows and is not read): ``moe_decode_layer_steps`` (those calls x
+  expert layers) and, summed
   over those, ``moe_decode_routed_assignments`` (active slots x top_k:
   wherever they fell), ``moe_decode_assignments`` (those that fell on an
   expert HELD here: all of them, unless the model is one rank of an
@@ -161,8 +191,9 @@ The same boundaries are spans on the profiler's clock
 (``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
 that owns the engine shows ``ray_tpu/engine.step`` on the host plane and,
 inside it, in this order, ``engine.admit``, ``.prefill_dispatch`` (arguments
-``bucket``, the largest of the phase, ``admitted``, ``calls`` and ``rows``:
-the calls' rows, padding included), ``.sample_dispatch``
+``bucket``, the largest of the phase, ``admitted``, ``calls``, ``rows``:
+the calls' rows, padding included, and ``riding``: the decoding slots whose
+step the phase is to carry, 0 where none), ``.sample_dispatch``
 (argument ``greedy``: no slot samples, the program takes its ``argmax``
 branch), ``.decode_dispatch`` (arguments ``overlapped``: 1 if an earlier step
 is unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
@@ -173,7 +204,7 @@ every sampler call of the step before. ``.readback`` names what it waited
 for (arguments ``kind``: ``prefill`` or ``decode``; ``calls``: the prefill
 calls behind it, 1 for a decode step; ``bucket``: the largest of those
 calls' buckets, 0 for a decode step; ``rows``: the requests whose token it
-brings), and its end is the moment
+brings, a carried step's among a phase's), and its end is the moment
 the phase counters and the token gaps are dated by: in a trace it lies a
 copy's latency after the end of the last ``jit_sample_tokens`` before it on
 the device plane. Around a shape's first use on the serving path,
@@ -191,6 +222,7 @@ import collections
 import contextlib
 import dataclasses
 import functools
+import logging
 import math
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -201,6 +233,8 @@ from ray_tpu.llm import prefill_shapes
 from ray_tpu.llm.config import EngineConfig, LLMConfig, SamplingParams
 from ray_tpu.llm.tokenizer import get_tokenizer
 from ray_tpu.util import goodput, tracing
+
+logger = logging.getLogger(__name__)
 
 # the rungs of the ladder ``itl_over_<n>ms``: a gap between two tokens of a
 # request is counted on every rung it is strictly longer than
@@ -226,6 +260,20 @@ _ROW_BUCKETS = (2, 4)
 # instead would let a 4,096 bucket take a 2,048 along: 2,048 positions padded
 # for 800 saved.
 _PAD_TOKENS = 512
+
+# A prefill call carries the decode step of its phase (``model_runner.prefill``'s
+# ``riders``) where its own rows, ``R x S``, are at most this many a slot. What
+# a carried step saves is fixed (its read of the experts, the dense weights and
+# the head); what it adds grows with the call (both sides' rows are laid end to
+# end and taken apart around every mixer) and every carrying program traces a
+# step side of its own at its first use, on the serving path. On the chip (PR
+# 42, PERF.md section 6): cell 9 (128 slots, calls of 128-2,048 positions in
+# 14-44 ms, a step of 16.4) gains 11-12 ms a carried step at every shape; cell
+# 8 (32 slots, calls of 256-16,384 positions, 75 ms in the mean, a step of 7.7)
+# paid 6 ms a carried step for the 7.7 saved and 2 s of start-up a bucket, seven
+# buckets. 16 is every shape of cell 9's (2,048 positions at 128 slots) and the
+# two smallest buckets of cell 8's (512 at 32).
+_RIDE_ROWS = 16
 
 
 def row_buckets(S: int, cap: int) -> List[int]:
@@ -414,10 +462,14 @@ class JaxLLMEngine:
         # and their samples unread
         self._prefill_logits = pinned(jax.numpy.asarray(
             np.zeros((B, self.mcfg.vocab_size), np.float32)))
+        # can a prefill call of this model carry a decode step's rows, and
+        # the [1, S] buckets where the compiler refused that program (why)
+        self._rides = model_runner.rides(self.mcfg)
+        self.plain_buckets: Dict[int, str] = {}
         # calls of several rows: their programs, made off the serving path
         self._row_shapes = prefill_shapes.RowShapes(
             self.mcfg, self.params, self.cache, self._prefill_logits, MP,
-            self._row_shape_ready)
+            self._row_shape_ready, self._carries)
         self._slots: List[Optional[_Request]] = [None] * B
         self._free_pages = collections.deque(range(1, e.num_pages))
         self._waiting: collections.deque[_Request] = collections.deque()
@@ -433,7 +485,8 @@ class JaxLLMEngine:
         # see the module docstring; snapshots are subtracted key by key, so
         # every key is here from the start and none ever decreases
         self.metrics = {
-            "prefill_tokens": 0, "decode_steps": 0, "generated_tokens": 0,
+            "prefill_tokens": 0, "decode_steps": 0, "riding_steps": 0,
+            "generated_tokens": 0,
             "preempted": 0, "steps": 0, "prefill_steps": 0, "admitted": 0,
             "prefill_calls": 0, "prefill_batch_tokens": 0, "compiles": 0,
             "prefill_shapes_wanted": 0, "prefill_shapes_ready": 0,
@@ -587,9 +640,12 @@ class JaxLLMEngine:
         return True
 
     def _grow_pages(self) -> None:
-        """Every occupied slot gets the page of its next position; where the
-        pool is empty the request goes back to waiting."""
-        for slot in range(len(self._slots)):
+        """Every active slot gets the page of its next position; where the
+        pool is empty the request goes back to waiting. (A slot admitted in
+        this step is active once its prefill call is dispatched: before a
+        decode step of its own, not before the call its first token rides
+        with the others' next.)"""
+        for slot in np.flatnonzero(self._active):
             req = self._slots[slot]
             if req is None or self._ensure_page(req):
                 continue
@@ -676,12 +732,12 @@ class JaxLLMEngine:
         first use also asks for the bucket's calls of several rows, which
         are made off the serving path (``prefill_shapes.RowShapes``)."""
         if self._compile_watch.observe(program, (bucket,)) is None:
-            yield
+            yield False
             return
         self.metrics["compiles"] += 1
         with tracing.annotate("engine.compile", program=program,
                               bucket=bucket):
-            yield
+            yield True
         if program == "prefill":
             shapes = [(R, bucket)
                       for R in row_buckets(bucket, self._group_cap)]
@@ -736,7 +792,13 @@ class JaxLLMEngine:
         # them; nothing is read in between). Each call's logits land in the
         # [B, vocab] buffer at its requests' slots, so one sampler call serves
         # the phase however many were admitted. Its tokens join the others on
-        # the device.
+        # the device. Where the phase's first call carries a decode step's
+        # rows (_carries: such calls are put first), the slots that were
+        # decoding before this admission ride it: its [B, vocab] of decode
+        # logits IS the buffer the phase's prompts' rows are placed in, the
+        # one sampler call samples both, and 2) is not run in this step (the
+        # requests admitted here take their second token in the next).
+        calls, ride = [], False
         with self._phase("admit"):
             admitted = self._try_admit()
             now = time.perf_counter()
@@ -744,23 +806,47 @@ class JaxLLMEngine:
                 if not r.t_admitted:  # not a re-admission after preemption
                     r.t_admitted = now
                     m["queue_wait_ms"] += (now - r.t_added) * 1e3
+            if admitted:
+                slots = [r.slot for r in admitted]
+                calls = prefill_groups(
+                    [self._prefill_bucket(n) for n in self._seq_lens[slots]],
+                    self._group_cap,
+                    lambda R, S: (R, S) in self._row_shapes.ready)
+                # a call that carries goes first: it takes the step
+                calls.sort(key=lambda c: not self._carries(c[0], c[1]))
+                if (decode and self._active.any()
+                        and self._carries(*calls[0][:2])):
+                    # the decoding slots' next pages, before their step is
+                    # dispatched: may read what is in flight, may preempt
+                    self._grow_pages()
+                    ride = bool(self._active.any())
+        riders: List[_Request] = []
         if admitted:
             overlapped = self._earlier > 0
-            slots = [r.slot for r in admitted]
-            calls = prefill_groups(
-                [self._prefill_bucket(n) for n in self._seq_lens[slots]],
-                self._group_cap, lambda R, S: (R, S) in self._row_shapes.ready)
             rows = sum(R for R, _, _ in calls)
             bucket = max(S for _, S, _ in calls)
             with self._phase("prefill_dispatch", bucket=bucket,
                              admitted=len(admitted), calls=len(calls),
-                             rows=rows):
-                for R, S, members in calls:
-                    self._prefill_call(R, S, [slots[i] for i in members])
+                             rows=rows,
+                             riding=int(self._active.sum()) if ride else 0):
+                for n, (R, S, members) in enumerate(calls):
+                    if self._prefill_call(R, S, [slots[i] for i in members],
+                                          carry=ride and n == 0):
+                        riders = [self._slots[i]
+                                  for i in np.flatnonzero(self._active)]
             firsts = self._sample(self._prefill_logits)
-            self._tokens = mr.select_rows(
-                jnp.asarray(np.isin(np.arange(len(self._slots)), slots)),
-                firsts, self._tokens)
+            if riders:
+                # every row a slot will read is new: a decode step's, or an
+                # admitted request's first
+                self._tokens = firsts
+                self._count_decode_reads()
+                m["decode_steps"] += 1
+                m["riding_steps"] += 1
+                self._seq_lens[self._active] += 1
+            else:
+                self._tokens = mr.select_rows(
+                    jnp.asarray(np.isin(np.arange(len(self._slots)), slots)),
+                    firsts, self._tokens)
             m["prefill_steps"] += 1
             m["admitted"] += len(admitted)
             m["prefill_calls"] += len(calls)
@@ -769,11 +855,12 @@ class JaxLLMEngine:
             if self.mcfg.sambay:  # the cross-decoder ran one row a row of a call
                 m["prefill_cross_rows"] += rows
             self._active[slots] = True
-            self._sent(firsts, admitted, "prefill", len(calls), bucket)
+            self._sent(firsts, admitted + riders, "prefill", len(calls),
+                       bucket)
 
         # 2) one decode step for all active slots, on the tokens the device
         # holds: the host advances what does not depend on a token's value
-        if decode and self._active.any():
+        if decode and not riders and self._active.any():
             attrs = dict(self._experts_attr, overlapped=int(self._earlier > 0),
                          dropped=m["dropped_tokens"])
             if self.mcfg.kv_latent_rank or self.mcfg.layer_kinds:
@@ -786,19 +873,11 @@ class JaxLLMEngine:
                 decoding = bool(self._active.any())
                 if decoding:
                     overlapped = overlapped or self._earlier > 0
-                    if self.mcfg.kv_latent_rank:
-                        self._count_paged_reads("mla_decode")
-                    elif self.mcfg.layer_kinds:
-                        self._count_paged_reads("shared_kv")
-                        m["window_live_tokens"] += int(np.minimum(
-                            self._seq_lens[self._active] + 1,
-                            self.mcfg.window).sum())
+                    self._count_decode_reads()
                     with self._first_use("decode"):
                         logits, self.cache = mr.decode_step(
-                            self.params, self.mcfg, self.cache, self._tokens,
-                            self._up(self._seq_lens),
-                            self._up(self._block_tables, "tables"),
-                            self._up(self._active, "active"))
+                            self.params, self.mcfg, self.cache,
+                            *self._decode_rows(self._active))
                     if self.cache.moe_load is not None:
                         # a copy outside the cache: the next call donates
                         # the cache before the host reads this one's routing
@@ -819,12 +898,32 @@ class JaxLLMEngine:
             self._read()
         return overlapped
 
-    def _prefill_call(self, R: int, S: int, slots: List[int]) -> None:
+    def _carries(self, R: int, S: int) -> bool:
+        """Is the prefill program at ``[R, S]`` the one that carries a decode
+        step? Where the model's can (``model_runner.rides``), the call's own
+        rows do not dwarf the step's (``_RIDE_ROWS``) and the compiler took
+        the program (``plain_buckets``; a shape of several rows it refused is
+        never ready, so never asked about)."""
+        return (self._rides and R * S <= _RIDE_ROWS * len(self._slots)
+                and (R > 1 or S not in self.plain_buckets))
+
+    def _prefill_call(self, R: int, S: int, slots: List[int],
+                      carry: bool = False) -> bool:
         """One prefill program call: the requests in ``slots`` in its first
         rows, padding rows behind them (length 0, a block table of zeros and
         the slot past the last, so that whatever they write is dropped or
         lands on the scratch page), the logits of the real rows into
-        ``_prefill_logits`` at their slots."""
+        ``_prefill_logits`` at their slots. ``carry``: the decoding slots'
+        step rides this call, the first of its phase, if its program carries
+        one: then (True is returned and) the step's ``[B, vocab]`` logits
+        become the buffer. A program that carries a step is called so whether
+        or not one rides (with no slot marked active its decode side attends
+        over the scratch page and reaches no expert): a shape has ONE prefill
+        program. Which shapes carry, ``_carries`` says; a ``[1, S]`` whose
+        carrying program the compiler finds no room for at its first use is
+        the plain one from then on (``plain_buckets``), and its phase runs
+        ``decode_step`` after it."""
+        import jax
         import jax.numpy as jnp
 
         mr, n = self._mr, len(slots)
@@ -837,8 +936,14 @@ class JaxLLMEngine:
         for i, slot in enumerate(slots):
             toks[i, :lens[i]] = self._slots[slot].cache_tokens
         where = jnp.asarray(where)
-        # a model that keeps state by slot is told which ones
-        told = (where,) if self.mcfg.layer_kinds else ()
+        rows = [jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(tables)]
+        if self.mcfg.layer_kinds:  # a model that keeps state by slot is told
+            rows.append(where)
+        carries = self._carries(R, S)
+        carry = carry and carries
+        if carries:
+            rows.append(self._decode_rows(
+                self._active if carry else np.zeros_like(self._active)))
         if R == 1:  # jit's own, compiled at the bucket's first use
             prefill = functools.partial(mr.prefill, self.params, self.mcfg)
             place, first_use = mr.place_rows, self._first_use("prefill", S)
@@ -846,12 +951,46 @@ class JaxLLMEngine:
             prefill = functools.partial(self._row_shapes.ready[(R, S)],
                                         self.params)
             place = self._row_shapes.place[R]
-            first_use = contextlib.nullcontext()
-        with first_use:
-            logits, self.cache = prefill(
-                self.cache, jnp.asarray(toks), jnp.asarray(lens),
-                jnp.asarray(tables), *told)
-            self._prefill_logits = place(self._prefill_logits, logits, where)
+            first_use = contextlib.nullcontext(False)
+        with first_use as first:
+            try:
+                logits, cache = prefill(self.cache, *rows)
+            except jax.errors.JaxRuntimeError as e:
+                # the compiler's own refusal, before anything was donated
+                if not (first and carries and "RESOURCE_EXHAUSTED" in str(e)
+                        ) or self.cache[0].is_deleted():
+                    raise
+                self.plain_buckets[S] = str(e)
+                logger.warning(
+                    "prefill [1, %d] carries no decode step: %s", S,
+                    self.plain_buckets[S][:300])
+                carries = carry = False
+                logits, cache = prefill(self.cache, *rows[:-1])
+            self.cache, buffer = cache, self._prefill_logits
+            if carries:
+                logits, step_logits = logits
+                if carry:
+                    buffer = step_logits
+            self._prefill_logits = place(buffer, logits, where)
+        return carry
+
+    def _decode_rows(self, active: np.ndarray) -> tuple:
+        """The operands of a decode step after the cache, for the slots
+        ``active`` marks: ``decode_step``'s own, or a prefill call's
+        ``riders``."""
+        return (self._tokens, self._up(self._seq_lens),
+                self._up(self._block_tables, "tables"),
+                self._up(active, "active" if active.any() else "idle"))
+
+    def _count_decode_reads(self) -> None:
+        """What the decode step being dispatched (alone, or riding a prefill
+        call) attends over, into the counters of the model's kind."""
+        if self.mcfg.kv_latent_rank:
+            self._count_paged_reads("mla_decode")
+        elif self.mcfg.layer_kinds:
+            self._count_paged_reads("shared_kv")
+            self.metrics["window_live_tokens"] += int(np.minimum(
+                self._seq_lens[self._active] + 1, self.mcfg.window).sum())
 
     def _sent(self, tokens, reqs: List[_Request], kind: str, calls: int,
               bucket: int = 0, moe_load=None) -> None:

@@ -79,6 +79,12 @@ slot's whole length. Two kinds of block use it (``TransformerConfig.block``):
   positions ``lengths - conv_taps + 1 .. lengths - 1`` (zeros where the
   prompt is shorter than that; padding behind the prompt never enters them);
   a decode step convolves the rows with the new input and shifts them by one.
+  Both of this block's programs are ONE per-layer composition
+  (``_kinds_forward``: a prompt side, a step side, or both), so ``prefill``
+  can carry a decode step's rows beside its prompts (``riders``): each side's
+  mixer on its own rows and slots, then the residual, the norms, the MLP or
+  the experts and the head once over all rows, every held weight read once
+  for the call and the step (``llm/engine.py`` says when it asks for that).
 """
 
 from __future__ import annotations
@@ -685,36 +691,37 @@ def _conv_out(c, y, lp, cfg):
     return _dense(c * y, lp["conv"]["out_proj"], cfg.dtype).astype(jnp.float32)
 
 
-def _conv_prefill(x, lp, cfg, conv, layer, slots, lengths):
-    """A "conv" layer over a prefill call's x [B, S, D]: what the mixer adds
-    to the stream, and ``conv`` with the rows of ``slots`` left at the
-    prompt's last ``conv_taps - 1`` positions (zeros where it has none)."""
+def _conv_prefill(s, lp, cfg, conv, layer, slots, lengths):
+    """A "conv" layer's taps over a prefill call's gated inputs s [R, S, D],
+    and ``conv`` with the rows of ``slots`` left at the prompt's last
+    ``conv_taps - 1`` positions (zeros where it has none)."""
     from ray_tpu.models.transformer import causal_conv
 
     tail = cfg.conv_taps - 1
     tail_pos = lengths[:, None] - tail + jnp.arange(tail)[None]
     with jax.named_scope("conv.prefill"):
-        s, c = _conv_gates(x, lp, cfg)
-        o = _conv_out(c, causal_conv(
-            s, lp["conv"]["conv_kernel"].astype(cfg.dtype), 0), lp, cfg)
-        # [layer, tap, slot]: the indexed axes come first, [B, K-1, D]
-        return o, conv.at[layer, :, slots].set(_rows_at(s, tail_pos))
+        y = causal_conv(s, lp["conv"]["conv_kernel"].astype(cfg.dtype), 0)
+        # [layer, tap, slot]: the indexed axes come first, [R, K-1, D]
+        return y, conv.at[layer, :, slots].set(_rows_at(s, tail_pos))
 
 
-def _conv_step(x, lp, cfg, conv, layer):
-    """A "conv" layer over a decode step's x [B, 1, D]: the kept rows and the
-    new input under the taps, and the rows shifted by one."""
+def _conv_step(s, lp, cfg, conv, layer, keep=None):
+    """A "conv" layer's taps over a decode step's gated inputs s [B, 1, D]
+    and the kept rows, and the rows shifted by one: every slot's, or with
+    ``keep`` [B] those of the slots it marks alone (a prefill call's prompts
+    beside this step have just written others)."""
     with jax.named_scope("conv.step"):
-        s, c = _conv_gates(x[:, 0], lp, cfg)
-        taps = jnp.concatenate([conv[layer], s[None]], axis=0)
-        o = _conv_out(c, jnp.einsum(
-            "kbd,kd->bd", taps, lp["conv"]["conv_kernel"].astype(cfg.dtype)),
-            lp, cfg)
-        return o[:, None], conv.at[layer].set(taps[1:])
+        taps = jnp.concatenate([conv[layer], s[:, 0][None]], axis=0)
+        y = jnp.einsum("kbd,kd->bd", taps,
+                       lp["conv"]["conv_kernel"].astype(cfg.dtype))
+        rows = taps[1:]
+        if keep is not None:
+            rows = jnp.where(keep[None, :, None], rows, conv[layer])
+        return y[:, None], conv.at[layer].set(rows)
 
 
 def _kind_block_rest(x, o, lp, cfg, valid, name):
-    """The block after its mixer's output ``o`` [B, S, D] float32: the
+    """The block after its mixer's output ``o`` [.., D] float32: the
     residual (a norm on the way out under ``sandwich_norm``), the MLP or the
     experts likewise. Returns (x, load)."""
     f32 = jnp.float32
@@ -733,79 +740,151 @@ def _embed(p, cfg, tokens):
     return p["embed"][tokens].astype(jnp.float32) * cfg.embed_scale
 
 
-def _kinds_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
+def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
+    """Layer ``at`` of its ``kind`` proper, over a prefill call's rows: the
+    gated inputs ``row`` [R, S, D] under a "conv" layer's taps, or the queries
+    ``q`` [R, S, H, hd] over the call's own keys and values ``row`` [R, S, 2
+    KVH hd] (the flash kernel). Returns what comes out, [R, S, D] or [R, S, H,
+    hd], and ``kept`` (the kind's pages, rings or conv rows) with the
+    prompts' state written."""
     from ray_tpu.ops.attention import attention as attention_op
 
-    B, S = tokens.shape
+    if kind == "conv":
+        return _conv_prefill(row, lp, cfg, kept, at, slots, lengths)
+    R, S = row.shape[:2]
     rep = cfg.n_heads // cfg.n_kv_heads
-    positions, in_prompt, page, offset, last, ring_pos = _prompt_index(
-        cfg, cache, S, lengths, block_tables)
-    pages, rings, _, conv = cache[:4]
-    x = _embed(p, cfg, tokens)
+    _, _, page, offset, _, ring_pos = index
+    if kind == "window":
+        kept = _write_rings(kept, at, slots, row, ring_pos)
+    else:
+        kept = kept.at[at, page, offset].set(row, mode="drop")
+    k, v = (t.reshape(R, S, cfg.n_kv_heads, -1)
+            for t in jnp.split(row, 2, axis=-1))
+    return attention_op(
+        q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+        causal=True, impl=cfg.attention_impl,
+        window=cfg.window if kind == "window" else 0), kept
+
+
+def _step_mixer(cfg, index, page_size, keep, op, kind, at, lp, kept, q, row):
+    """Layer ``at`` of its ``kind`` proper, over a decode step's rows: the
+    gated inputs ``row`` [B, 1, D] and the kept rows under the taps, or each
+    slot's new ``row`` [B, 1, 2 KVH hd] into ``kept`` and its query ``q`` [B,
+    1, H, hd] over what the slot holds there, through the kernel
+    ``<paged|window>_gqa_<op>``."""
+    if kind == "conv":
+        return _conv_step(row, lp, cfg, kept, at, keep)
+    slot, positions, page, offset, work, ring_work = index
+    if kind == "window":
+        # a slot past the last (beside a prompt: one that is not active) is
+        # dropped, as _write_rings drops a padding row's
+        kept = kept.at[at, slot, positions % cfg.window].set(row[:, 0])
+        o = _paged_attention(q[:, 0], _ring_blocks(kept, cfg, page_size),
+                             ring_work, at, "window_gqa_" + op, cfg)
+    else:
+        kept = kept.at[at, page, offset].set(row[:, 0], mode="drop")
+        o = _paged_attention(q[:, 0], kept, work, at, "paged_gqa_" + op, cfg)
+    return o[:, None], kept
+
+
+def rides(cfg: TransformerConfig) -> bool:
+    """Can a decode step's rows ride this model's prefill call (``prefill``'s
+    ``riders``)? Where both programs are ``_kinds_forward``."""
+    return bool(cfg.layer_kinds) and not cfg.sambay
+
+
+def _kinds_forward(p, cfg, cache, prompt=None, step=None):
+    """The layers of an "rms" block with ``layer_kinds`` over a prefill
+    call's rows (``prompt``: tokens [R, S], lengths, block_tables, slots), over
+    a decode step's (``step``: last_tokens [B], seq_lens, block_tables,
+    active), or over both in one program: the decode rows RIDE the prefill
+    call. Everything that works row by row (norms, projections, gates, the
+    residual, the MLP or the experts, the head) runs ONCE over all the rows
+    laid end to end, [1, R S + B, D]: every held weight is read once, and the
+    experts sort both sides' rows together. Only a layer's mixer proper runs
+    a side at a time, on its own rows and its own part of the cache, the
+    prompts' first (``_prompt_mixer``: flash attention or the convolution
+    over the bucket; ``_step_mixer``: the paged kernel or the taps over the
+    kept rows): the slots a call fills and the slots that decode are
+    disjoint, so are their pages, rings and conv rows, and a slot that is not
+    active writes nothing beside a prompt (alone it computes into its own rows
+    and nobody reads them). Returns the logits of each side it was given, [R,
+    vocab] and [B, vocab] (a pair where both), and the cache; the routing it
+    leaves in ``moe_load`` is that of all its rows."""
+    kept = {"full": cache.pages, "window": cache.rings, "conv": cache.conv}
+    xs, positions, valid, mixers = [], [], [], []
+    if prompt is not None:
+        tokens, lengths, tables, slots = prompt
+        index = _prompt_index(cfg, cache, tokens.shape[1], lengths, tables)
+        xs.append(_embed(p, cfg, tokens))
+        positions.append(index[0])
+        valid.append(index[1])
+        last = index[4]
+        mixers.append(functools.partial(_prompt_mixer, cfg, index, slots,
+                                        lengths))
+    if step is not None:
+        last_tokens, seq_lens, tables, active = step
+        index = _decode_index(cfg, cache, seq_lens, tables, active)
+        xs.append(_embed(p, cfg, last_tokens[:, None]))
+        positions.append(index[1][:, None])
+        valid.append(active[:, None])
+        keep = None
+        if prompt is not None:
+            keep = active
+            index = (jnp.where(active, index[0], active.shape[0]), *index[1:])
+        mixers.append(functools.partial(
+            _step_mixer, cfg, index, cache.pages.shape[2], keep,
+            "decode" if prompt is None else "riding"))
+    shapes = [x.shape[:2] for x in xs]
+    x, positions, valid = map(_end_to_end, (xs, positions, valid))
+    name = "moe_gmm_decode" if prompt is None else "moe_gmm_prefill"
     loads = []
-    full_i = window_i = conv_i = 0
     for i, kind in enumerate(cfg.layer_kinds):
-        lp = p[f"layer_{i}"]
+        lp, at = p[f"layer_{i}"], cfg.layer_kinds[:i].count(kind)
         if kind == "conv":
-            o, conv = _conv_prefill(x, lp, cfg, conv, conv_i, slots, lengths)
-            conv_i += 1
+            q, (row, gate) = None, _conv_gates(x, lp, cfg)
         else:
             h, q, row = _kind_attn_inputs(x, lp, cfg, positions, kind)
-            if kind == "window":
-                rings = _write_rings(rings, window_i, slots, row, ring_pos)
-                window_i += 1
-            else:
-                pages = pages.at[full_i, page, offset].set(row, mode="drop")
-                full_i += 1
-            k, v = (t.reshape(B, S, cfg.n_kv_heads, -1)
-                    for t in jnp.split(row, 2, axis=-1))
-            o = _kind_attn_out(h, attention_op(
-                q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
-                causal=True, impl=cfg.attention_impl,
-                window=cfg.window if kind == "window" else 0), lp, cfg)
-        x, load = _kind_block_rest(x, o, lp, cfg, in_prompt,
-                                   "moe_gmm_prefill")
+        outs = []
+        for mixer, q_, row_ in zip(mixers, _apart(q, shapes),
+                                   _apart(row, shapes)):
+            o, kept[kind] = mixer(kind, at, lp, kept[kind], q_, row_)
+            outs.append(o)
+        o = _end_to_end(outs)
+        o = _conv_out(gate, o, lp, cfg) if kind == "conv" \
+            else _kind_attn_out(h, o, lp, cfg)
+        x, load = _kind_block_rest(x, o, lp, cfg, valid, name)
         if load is not None:
             loads.append(load)
-    x = jnp.take_along_axis(x, last[..., None], axis=1)[:, 0]
-    return _head(x, p, cfg), HybridCache(
-        pages, rings, None, conv, jnp.stack(loads) if loads else None)
+    sides = _apart(x, shapes)
+    if prompt is not None:  # each prompt's last real position
+        sides[0] = jnp.take_along_axis(sides[0], last[..., None], axis=1)
+    logits = _head(jnp.concatenate([t[:, 0] for t in sides]), p, cfg)
+    if len(sides) == 2:
+        R = shapes[0][0]
+        logits = logits[:R], logits[R:]
+    return logits, HybridCache(
+        kept["full"], kept["window"], None, kept["conv"],
+        jnp.stack(loads) if loads else None)
 
 
-def _kinds_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
-    P, W = cache.pages.shape[2], cfg.window
-    slot, positions, page, offset, work, ring_work = _decode_index(
-        cfg, cache, seq_lens, block_tables, active)
-    pages, rings, _, conv = cache[:4]
-    x = _embed(p, cfg, last_tokens[:, None])                  # [B, 1, d]
-    loads = []
-    full_i = window_i = conv_i = 0
-    for i, kind in enumerate(cfg.layer_kinds):
-        lp = p[f"layer_{i}"]
-        if kind == "conv":
-            o, conv = _conv_step(x, lp, cfg, conv, conv_i)
-            conv_i += 1
-        else:
-            h, q, row = _kind_attn_inputs(x, lp, cfg, positions[:, None], kind)
-            if kind == "window":
-                rings = rings.at[window_i, slot, positions % W].set(row[:, 0])
-                o = _paged_attention(q[:, 0], _ring_blocks(rings, cfg, P),
-                                     ring_work, window_i, "window_gqa_decode",
-                                     cfg)
-                window_i += 1
-            else:
-                pages = pages.at[full_i, page, offset].set(row[:, 0],
-                                                           mode="drop")
-                o = _paged_attention(q[:, 0], pages, work, full_i,
-                                     "paged_gqa_decode", cfg)
-                full_i += 1
-            o = _kind_attn_out(h, o[:, None], lp, cfg)
-        x, load = _kind_block_rest(x, o, lp, cfg, active[:, None],
-                                   "moe_gmm_decode")
-        if load is not None:
-            loads.append(load)
-    return _head(x[:, 0], p, cfg), HybridCache(
-        pages, rings, None, conv, jnp.stack(loads) if loads else None)
+def _end_to_end(sides):
+    """One or two sides' rows ([R, S, ..] and [B, 1, ..]) as ONE array: a side
+    alone as it is, two end to end, [1, R S + B, ..]."""
+    if len(sides) == 1:
+        return sides[0]
+    return jnp.concatenate(
+        [t.reshape(1, -1, *t.shape[2:]) for t in sides], axis=1)
+
+
+def _apart(x, shapes):
+    """``_end_to_end``'s inverse: the sides of ``x`` (None: of nothing), of
+    ``shapes`` (R, S) and (B, 1)."""
+    if x is None or len(shapes) == 1:
+        return [x] * len(shapes)
+    (R, S), (B, _) = shapes
+    return [x[:, :R * S].reshape(R, S, *x.shape[2:]),
+            x[:, R * S:].reshape(B, 1, *x.shape[2:])]
 
 
 # ---------------------------------------------------------------------------
@@ -816,7 +895,8 @@ def _kinds_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2,))
 def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
             tokens: jax.Array, lengths: jax.Array,
-            block_tables: jax.Array, slots: Optional[jax.Array] = None
+            block_tables: jax.Array, slots: Optional[jax.Array] = None,
+            riders: Optional[Tuple[jax.Array, ...]] = None
             ) -> Tuple[jax.Array, KVCache]:
     """Run the prompt forward, write KV pages, return last-position logits.
 
@@ -834,16 +914,27 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
     check alone (``benchmarks/jobs/serve.py:reference_check`` calls an
     every-slot ``[max_num_seqs, S]`` batch without it), and goes once the
     harness calls ``[1, S]`` with a slot.
+
+    ``riders``: the operands of ``decode_step`` after the cache (last_tokens,
+    seq_lens, block_tables, active, all ``[max_num_seqs, ..]``), for a model
+    that ``rides``: the call then runs that decode step too, on slots none of
+    its rows fills, and returns ``((logits [B, vocab], the step's logits
+    [max_num_seqs, vocab]), cache)``: what the prompts and the step would have
+    computed one after the other, with every held weight read once.
     """
     from ray_tpu.ops.attention import attention as attention_op
 
+    if riders is not None and not rides(cfg):
+        raise ValueError("no decode rows ride this model's prefill call")
     p = params["params"]
     B, S = tokens.shape
     if cfg.layer_kinds:
         if slots is None:
             slots = jnp.arange(B, dtype=jnp.int32)
-        fn = _hybrid_prefill if cfg.sambay else _kinds_prefill
-        return fn(p, cfg, cache, tokens, lengths, block_tables, slots)
+        rows = (tokens, lengths, block_tables, slots)
+        if cfg.sambay:
+            return _hybrid_prefill(p, cfg, cache, *rows)
+        return _kinds_forward(p, cfg, cache, rows, riders)
     P = cache[0].shape[2]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     in_prompt = positions < lengths[:, None]
@@ -947,8 +1038,10 @@ def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
     """
     p = params["params"]
     if cfg.layer_kinds:
-        fn = _hybrid_decode if cfg.sambay else _kinds_decode
-        return fn(p, cfg, cache, last_tokens, seq_lens, block_tables, active)
+        rows = (last_tokens, seq_lens, block_tables, active)
+        if cfg.sambay:
+            return _hybrid_decode(p, cfg, cache, *rows)
+        return _kinds_forward(p, cfg, cache, step=rows)
     B = last_tokens.shape[0]
     P = cache[0].shape[2]
     MP = block_tables.shape[1]

@@ -82,8 +82,9 @@ class RowShapes:
     fill from the threads it starts."""
 
     def __init__(self, cfg, params, cache, logits, pages_per_seq: int,
-                 on_ready: Callable[[], None]):
-        self._cfg, self._on_ready = cfg, on_ready
+                 on_ready: Callable[[], None],
+                 carries: Callable[[int, int], bool] = lambda R, S: False):
+        self._cfg, self._on_ready, self._carries = cfg, on_ready, carries
         # shapes alone: the serving path goes on donating the arrays
         self._params, self._cache = _shapes(params), _shapes(cache, True)
         self._logits, self._mp = _shapes(logits, True), pages_per_seq
@@ -98,8 +99,13 @@ class RowShapes:
 
     def _rows(self, R: int, S: int) -> tuple:
         """The arguments of ``prefill`` at ``[R, S]`` after the cache; a model
-        that keeps state by slot is told the slots."""
+        that keeps state by slot is told the slots, and a shape that
+        ``carries`` a decode step takes that step's operands (``riders``)."""
         told = (_ints(R),) if self._cfg.layer_kinds else ()
+        if self._carries(R, S):
+            B = self._logits.shape[0]
+            told += ((_ints(B), _ints(B), _ints(B, self._mp),
+                      jax.ShapeDtypeStruct((B,), np.bool_)),)
         return (_ints(R, S), _ints(R), _ints(R, self._mp), *told)
 
     def want(self, shapes: Sequence[Tuple[int, int]]) -> None:

@@ -373,7 +373,8 @@ def test_engine_serves_and_counts_the_share():
             assert t == int(np.argmax(_reference(eng, toks)[-1]))
             toks.append(t)
     m = eng.metrics
-    assert m["moe_decode_layer_steps"] == 4 * m["decode_steps"]
+    assert m["moe_decode_layer_steps"] == 4 * (
+        m["decode_steps"] - m["riding_steps"])
     assert m["moe_decode_routed_assignments"] == 2 * 4 * (
         m["generated_tokens"] - m["admitted"])
     assert 0 < m["moe_decode_assignments"] < m["moe_decode_routed_assignments"]
@@ -397,9 +398,114 @@ def test_burst_admitted_in_one_step_shares_a_prefill_call(lens, rows, bucket):
     assert (d["prefill_calls"], d["prefill_batch_tokens"]) == (1, rows * bucket)
 
 
+def test_decode_rows_ride_a_prefill_call(engine):
+    """``prefill`` with ``riders`` is the call and then ``decode_step``: the
+    prompt's logits, the step's logits, the pages and the rings of the slot
+    that decodes and of the slot that is filled again, beside a padding row
+    and a slot that is not active (whose state it leaves as it found it)."""
+    import prefill_rows
+
+    prefill_rows.riders_equal_a_step_after_the_call(
+        engine, np.random.default_rng(7), TOL)
+    with pytest.raises(ValueError, match="no decode rows ride"):
+        mr.prefill(engine.params, dataclasses.replace(
+            engine.mcfg, layer_kinds=()), engine.cache, None, None, None,
+            riders=())
+
+
+def test_riding_calls_match_reference():
+    """Three requests through the calls the engine makes when it admits
+    beside decoding slots: the second's ``[1, 16]`` call carries the first's
+    third step, the third's ``[1, 32]`` (past the window, so its rings wrap in
+    the call) the other two's, ``decode_step`` in between and after:
+    every position's logits against the reference (what the chip test runs at
+    the published widths)."""
+    import prefill_rows
+
+    eng = _engine()
+    rng = np.random.default_rng(11)
+    seqs = {1: (rng.integers(0, VOCAB, 11 + 7), 11, 3),
+            0: (rng.integers(0, VOCAB, 2 + 6), 2, 9),
+            2: (rng.integers(0, VOCAB, 19 + 4), 19, 20)}
+    got = prefill_rows.teacher_forced_riding(eng, seqs, gap=2)
+    for slot, (toks, n, _) in seqs.items():
+        assert got[slot].shape == (len(toks) - n + 1, VOCAB)
+        assert _rel(got[slot], _reference(eng, toks)[n - 1:]) < TOL, slot
+
+
+def test_staggered_requests_ride_and_get_the_tokens_they_get_alone():
+    """Requests admitted while others decode: the decoding slots' step rides
+    the admitted request's prefill call (one program, one sampler call, one
+    read), every request's greedy tokens are those it gets alone, and the
+    routing counters move with the decode-only steps alone."""
+    import prefill_rows
+
+    eng = _engine()
+    rng = np.random.default_rng(8)
+    d = prefill_rows.staggered_equal_alone(
+        eng, [rng.integers(0, VOCAB, n).tolist() for n in (11, 5, 14)])
+    layers = eng.cache.moe_load.shape[0]
+    assert d["moe_decode_layer_steps"] == layers * (
+        d["decode_steps"] - d["riding_steps"])
+    assert d["shared_kv_live_tokens"] > 0
+
+
+@pytest.mark.parametrize("slots,decoding,burst,calls", [
+    # [2, 32] then [1, 16]: both carry, the first takes the step
+    (5, (9,), (20, 25, 5), 2),
+    # [1, 64] is a plain program at 3 slots and [1, 16] carries: it goes first
+    (3, (9,), (40, 5), 2),
+    # [4, 16] of three beside two that decode
+    (5, (9, 3), (11, 12, 5), 1)])
+def test_a_burst_beside_decoding_slots_gets_the_tokens_it_gets_alone(
+        slots, decoding, burst, calls):
+    """Several requests admitted in ONE step while slots decode: the phase's
+    first call carries the step, the rows of its other calls land in the same
+    buffer, one sampler call serves all, and every request's tokens are those
+    it gets alone, whichever of its calls' programs carry."""
+    import prefill_rows
+
+    eng = _engine(max_num_seqs=slots)
+    rng = np.random.default_rng(12)
+    d = prefill_rows.admitted_beside_decoders_equal_alone(
+        eng, *([rng.integers(0, VOCAB, n).tolist() for n in lens]
+               for lens in (decoding, burst)))
+    assert d["prefill_calls"] == calls
+    assert (d["prefill_steps"], d["decode_steps"], d["riding_steps"],
+            d["sample_calls"]) == (1, 1, 1, 1)
+
+
+def test_nothing_rides_where_nothing_decodes_or_no_decode_is_asked():
+    """A step that admits with no slot active runs the same prefill program
+    with nobody marked active and then ``decode_step``, as before;
+    ``step(decode=False)`` and ``prefill_only`` carry nobody either, and
+    leave the decoding slots where they were."""
+    eng = _engine()
+    rng = np.random.default_rng(9)
+    sp = SamplingParams(max_tokens=5)
+    eng.add_request("a", rng.integers(0, VOCAB, 6).tolist(), sp)
+    eng.step()
+    m = dict(eng.metrics)
+    assert (m["prefill_steps"], m["decode_steps"], m["riding_steps"]) == (1, 1, 0)
+    assert m["sample_calls"] == 2
+    first = eng.prefill_only("b", rng.integers(0, VOCAB, 9).tolist(),
+                             SamplingParams(max_tokens=1))
+    assert first["finished"] and len(first["generated"]) == 1
+    eng.add_request("c", rng.integers(0, VOCAB, 3).tolist(), sp)
+    eng.step(decode=False)
+    d = {k: eng.metrics[k] - v for k, v in m.items()}
+    assert (d["prefill_steps"], d["decode_steps"], d["riding_steps"]) == (2, 0, 0)
+    done = {}
+    while eng.has_unfinished():
+        done.update((o.request_id, o) for o in eng.step() if o.finished)
+    assert sorted(done) == ["a", "c"]
+    assert all(len(o.token_ids) == 5 for o in done.values())
+    assert not eng.plain_buckets
+
+
 @pytest.mark.parametrize("model", ["afmoe", "hybrid"])
 def test_padding_row_changes_no_page_ring_row_by_slot_or_load(engine, model):
-    """Through ``_kinds_prefill`` and through the decoder-hybrid-decoder's
+    """Through ``_kinds_forward`` and through the decoder-hybrid-decoder's
     ``_hybrid_prefill`` (recurrent rows by slot beside the rings)."""
     import prefill_rows
 

@@ -10,7 +10,12 @@ call with a slot that the engine times. This does: 4,096 + 512 + 8 positions
 of ``benchmarks/configs/trinity-large-preview.json`` through the engine's
 ``[1, 8192]`` prefill with a slot that is not the first and pages that are not
 the first (the rings wrap in prefill: 512 positions overwrite the oldest), then
-eight 32-slot decode steps through rings and ten live pages, logits against
+300 + 8 through ``[1, 512]`` into another slot, each call the program the
+engine calls since PR 42: the plain one at 8,192 positions, whose rows dwarf
+the 32 slots' step, and at 512 the one that CARRIES the decode step of
+whoever decodes by then (``prefill``'s ``riders``: the first request's third
+step; ``tests/prefill_rows.py:teacher_forced_riding``), 32-slot decode steps
+before and after through rings and ten live pages, logits against
 ``benchmarks/architectures/afmoe.py:forward`` in float32; and the same against
 references that lack the attention gate, the rotary embedding of the sliding
 layers, or the window, each of which has to FAIL the cell's tolerance (a
@@ -36,6 +41,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 3e-2                     # the cell's: benchmarks/jobs/serve.py
 PROMPT, STEPS, SEED, SLOT, FIRST_PAGE = 4096 + 512, 8, 3141592653, 5, 7
+SECOND = (300, 9, 40)          # prompt, slot, first page
 
 
 def main() -> dict:
@@ -65,34 +71,24 @@ def main() -> dict:
         model_id="tiny", seed=SEED % 2 ** 32, engine_config=e,
         model_overrides=arch.program_overrides(conf, e.max_model_len)),
         seed=SEED % 2 ** 32)
-    mcfg, mr = eng.mcfg, eng._mr
+    mcfg = eng.mcfg
     note("engine up on", jax.devices()[0].device_kind)
-    B, MP, total = e.max_num_seqs, e.pages_per_seq, PROMPT + STEPS
-    toks = np.random.default_rng(SEED).integers(
-        0, mcfg.vocab_size, total, dtype=np.int32)
+    from prefill_rows import teacher_forced_riding
+
+    total = PROMPT + STEPS
+    rng = np.random.default_rng(SEED)
+    toks = rng.integers(0, mcfg.vocab_size, total, dtype=np.int32)
     S = eng._prefill_bucket(PROMPT)
     need = -(-total // e.page_size)
-    tables = np.zeros((B, MP), np.int32)
-    tables[SLOT, :need] = np.arange(FIRST_PAGE, FIRST_PAGE + need)
-    batch = np.zeros((1, S), np.int32)
-    batch[0, :PROMPT] = toks[:PROMPT]
-    # the engine's own call: one admitted request, [1, S], told its slot
-    logits, eng.cache = mr.prefill(
-        eng.params, mcfg, eng.cache, jnp.asarray(batch),
-        jnp.asarray([PROMPT], jnp.int32), jnp.asarray(tables[SLOT:SLOT + 1]),
-        jnp.asarray([SLOT], jnp.int32))
-    got = [np.asarray(logits[0])]
-    active = np.zeros(B, bool)
-    active[SLOT] = True
-    last, seq_lens = np.zeros(B, np.int32), np.zeros(B, np.int32)
-    for i in range(STEPS):
-        last[SLOT], seq_lens[SLOT] = toks[PROMPT + i], PROMPT + i
-        logits, eng.cache = mr.decode_step(
-            eng.params, mcfg, eng.cache, jnp.asarray(last),
-            jnp.asarray(seq_lens), jnp.asarray(tables), jnp.asarray(active))
-        got.append(np.asarray(logits[SLOT]))
-    got = np.stack(got)
-    note(f"prefill [1, {S}] and {STEPS} decode steps done")
+    toks2 = rng.integers(0, mcfg.vocab_size, SECOND[0] + STEPS, dtype=np.int32)
+    # the engine's own calls: [1, S] told its slot; the second, at 512,
+    # carries the first request's third step
+    both = teacher_forced_riding(eng, {
+        SLOT: (toks, PROMPT, FIRST_PAGE),
+        SECOND[1]: (toks2, SECOND[0], SECOND[2])}, gap=2)
+    got, got2 = both[SLOT], both[SECOND[1]]
+    note(f"prefill [1, {S}], [1, 512] carrying a step, and the decode steps "
+         f"of both done")
 
     def reference(**change):
         rcfg = dict(arch.reference_cfg(conf), **change)
@@ -111,15 +107,18 @@ def main() -> dict:
     out = {"device": jax.devices()[0].device_kind, "bucket": S,
            "positions": total, "pages": need, "tol": TOL,
            "finite": bool(np.isfinite(got).all()),
-           "rel_err": rel(got, np.asarray(reference()(p, tj)))}
-    note("reference", out["rel_err"])
+           "rel_err": rel(got, np.asarray(reference()(p, tj))),
+           "rel_err_second": rel(got2, np.asarray(
+               reference()(p, jnp.asarray(toks2))))}
+    note("reference", out["rel_err"], "second request", out["rel_err_second"])
     spoiled = {"no_gate": {"attention_gate": False},
                "no_rope_in_sliding": {"rotated": ()},
                "no_window": {"sliding_window": 0}}
     for name, change in spoiled.items():
         out[name] = rel(got, np.asarray(reference(**change)(p, tj)))
         note(name, out[name])
-    out["ok"] = bool(out["finite"] and out["rel_err"] < TOL
+    out["ok"] = bool(out["finite"] and np.isfinite(got2).all()
+                     and out["rel_err"] < TOL and out["rel_err_second"] < TOL
                      and all(out[k] > TOL for k in spoiled))
     print(json.dumps(out), flush=True)
     return out

@@ -732,14 +732,23 @@ def _lower_rms_kinds(one_chip, name="trinity-large-preview"):
 
     active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
 
+    step = (i32(B), i32(B), i32(B, MP), active)
+
     def prefill(rows, bucket):
-        slots = (i32(1),) if rows == 1 else ()    # the check gives none
+        # the engine's call: told its slot and, where the call's rows do not
+        # dwarf the step's, carrying a decode step's rows (PR 42:
+        # ``engine._carries``); the benchmark's check gives neither
+        from ray_tpu.llm.engine import _RIDE_ROWS
+
+        told = (i32(1),) if rows == 1 else ()
+        if told and bucket <= _RIDE_ROWS * B:
+            told += (step,)
         return mr.prefill.lower(params, cfg, cache, i32(rows, bucket),
-                                i32(rows), i32(rows, MP), *slots)
+                                i32(rows), i32(rows, MP), *told)
 
     from ray_tpu.llm import model_runner as mr
     return cache, prefill, lambda: mr.decode_step.lower(
-        params, cfg, cache, i32(B), i32(B), i32(B, MP), active)
+        params, cfg, cache, *step)
 
 
 def _rms_kinds(one_chip, name, attention_impl):
@@ -801,19 +810,24 @@ def test_afmoe_decode_reads_rings_and_live_pages_and_nothing_else(one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes >= held
 
 
-@pytest.mark.parametrize("rows,bucket", [(1, 16384), (32, 256)])
+@pytest.mark.parametrize("rows,bucket", [(1, 16384), (32, 256), (1, 512)])
 def test_afmoe_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
-    """The engine's largest call, ``[1, 16384]``, and the benchmark check's
-    every-slot ``[32, 256]`` call, beside 8.65 GB of weights and 4.36 GB of
-    pages and rings: five flash calls (the window's in four of them) and
-    twelve grouped matmuls, under the chip's 15.75 GiB. What an execution
-    holds live is printed (``-s``) and stands in PERF.md section 4."""
+    """The engine's largest call, ``[1, 16384]``, the benchmark check's
+    every-slot ``[32, 256]`` call, and the largest of the engine's calls that
+    carry the 32 slots' decode step beside the prompt, ``[1, 512]`` (the paged
+    kernel over four rings and one layer of pages: PR 42), beside 8.65 GB of
+    weights and 4.36 GB of pages and rings: five flash calls (the window's in
+    four of them) and twelve grouped matmuls, under the chip's 15.75 GiB.
+    What an execution holds live is printed (``-s``) and stands in PERF.md
+    section 4."""
     _, prefill, _ = _lower_rms_kinds(one_chip)
     compiled = prefill(rows, bucket).compile()
     text = compiled.as_text()
     assert len(set(re.findall(
         rf"%(flash_fwd\S*) = \(bf16\[{rows * 48},{bucket},128\]", text))) == 5
-    assert text.count("tpu_custom_call") == 17
+    riding = len(set(re.findall(r"%((?:window|paged)_gqa_riding\S*) = ", text)))
+    assert riding == (5 if (rows, bucket) == (1, 512) else 0)
+    assert text.count("tpu_custom_call") == 17 + riding
     live, temp = _live(compiled)
     print(f"afmoe prefill [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")
@@ -860,14 +874,18 @@ def test_lfm2_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
     and the benchmark check's every-slot ``[128, 256]`` call (32,768 rows x
     top-4 = 131,072 sorted rows in each expert layer), beside 9.33 GB of
     weights and 2.03 GB of pages and rows: three flash calls and 36 grouped
-    matmuls, under the chip's 15.75 GiB. What an execution holds live is
-    printed (``-s``) and stands in PERF.md section 4."""
+    matmuls (over the prompt's rows and, in the engine's call, the 128 decode
+    rows it carries since PR 42, whose attention is the paged kernel's in the
+    three attention layers), under the chip's 15.75 GiB. What an execution
+    holds live is printed (``-s``) and stands in PERF.md section 4."""
     _, prefill, _ = _lower_rms_kinds(one_chip, "lfm2-8b-a1b")
     compiled = prefill(rows, bucket).compile()
     text = compiled.as_text()
     assert len(set(re.findall(
         rf"%(flash_fwd\S*) = \(bf16\[{rows * 32},{bucket},64\]", text))) == 3
-    assert text.count("tpu_custom_call") == 39
+    riding = len(set(re.findall(r"%(paged_gqa_riding\S*) = ", text)))
+    assert riding == (3 if rows == 1 else 0)
+    assert text.count("tpu_custom_call") == 39 + riding
     live, temp = _live(compiled)
     print(f"lfm2 prefill [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")

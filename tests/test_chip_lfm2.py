@@ -8,11 +8,16 @@ S]`` call with a slot that the engine times, never gives a slot a prompt
 shorter than the convolution's taps and never decodes two requests beside each
 other. This does: 600 + 8 positions of ``benchmarks/configs/lfm2-8b-a1b.json``
 through the engine's ``[1, 1024]`` prefill into a slot that is not the first,
-on pages that are not the first (three of them), and a 2-token prompt through
+on pages that are not the first (three of them), a 2-token prompt through
 ``[1, 128]`` into another slot (one row of its state is the prompt's first
-position, the other its second; nothing before them), then eight 128-slot
-decode steps for both at once through the convolutions' rows and the live
-pages, logits against ``benchmarks/architectures/lfm2_moe.py:forward`` in
+position, the other its second; nothing before them) and 300 + 8 through ``[1,
+512]`` into a third, each call the program the engine calls since PR 42: the
+one that CARRIES the decode step of whoever decodes by then (``prefill``'s
+``riders``: nobody for the first call, the first request's second step in the
+second call, both others' steps in the third;
+``tests/prefill_rows.py:teacher_forced_riding``), 128-slot decode steps in
+between and after through the convolutions' rows and the live pages, every
+request's eight tokens fed; logits against ``benchmarks/architectures/lfm2_moe.py:forward`` in
 float32; and the same against references with the taps reversed, without the
 output gate, with the kept rows a position late or with another
 ``rope_theta``, each of which has to FAIL the cell's tolerance; what a
@@ -29,11 +34,12 @@ the one-row calls it replaces: two prompts (750 and 600 tokens) through ``[2,
 each row told its slot, against the same prompts one a ``[1, S]`` call into
 the same slots and pages: logits, the pages and the convolutions' two rows by
 slot agree to the cell's tolerance, the padding row (length 0, the slot past the last) writes nothing
-but the scratch page. It also prints what the issue rests on: a call's time
+but the scratch page. It also prints what the issues rest on: a call's time
 by shape (``[1, S]``, ``[2, S]``, ``[4, S]``: the fixed part every held weight
-costs once a call, and the part that goes with the padded tokens) and how
-long a shape takes to be ready off the serving path. ``--grouped`` runs that
-part alone.
+costs once a call, and the part that goes with the padded tokens), how
+long a shape takes to be ready off the serving path, and (PR 42) what a
+decode step of 127 slots costs alone and riding a ``[1, 1024]`` call.
+``--grouped`` runs that part alone.
 
 It needs the chip (9.3 GB of weights), so under ``tests/conftest.py`` (which
 holds JAX to the CPU) the test only starts this file as a process of its own
@@ -54,7 +60,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 3e-2                     # the cell's: benchmarks/jobs/serve.py
 STEPS, SEED = 8, 2718281828
 # (prompt, slot, first page)
-LONG, SHORT = (600, 5, 7), (2, 77, 40)
+LONG, SHORT, THIRD = (600, 5, 7), (2, 77, 40), (300, 30, 90)
 
 
 def grouped(eng, note) -> dict:
@@ -70,6 +76,10 @@ def grouped(eng, note) -> dict:
     B, MP = e.max_num_seqs, e.pages_per_seq
     rng = np.random.default_rng(SEED + 1)
     out = {}
+    # this model's prefill programs carry a decode step at every shape of
+    # the engine's (``eng._carries``): nobody's here
+    idle = (jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
+            jnp.zeros((B, MP), jnp.int32), jnp.zeros(B, bool))
 
     # the programs as the engine gets them: all traced in one process of
     # their own, compiled in threads (``llm/prefill_shapes.py``); the engine's
@@ -103,7 +113,7 @@ def grouped(eng, note) -> dict:
         note(f"[{R}, {S}] kernels", dict(got), "jit's own", dict(want))
         return got == want and got["flash_fwd"] == 3
 
-    def call(fn, rows, S, R=None):
+    def call(fn, rows, S, R=None, riders=idle):
         """``rows``: (tokens, slot, first page) each; padding up to ``R``."""
         R = R or len(rows)
         toks = np.zeros((R, S), np.int32)
@@ -116,7 +126,11 @@ def grouped(eng, note) -> dict:
             tables[i, :need] = np.arange(first, first + need)
         args = (jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(tables),
                 jnp.asarray(slots))
-        logits, eng.cache = fn(eng.params, mcfg, eng.cache, *args)
+        if eng._carries(R, S):  # the shape's program carries a step's rows
+            (logits, _), eng.cache = fn(eng.params, mcfg, eng.cache, *args,
+                                        riders)
+        else:  # past the engine's own shapes: the plain program
+            logits, eng.cache = fn(eng.params, mcfg, eng.cache, *args)
         return logits
 
     def state(rows):
@@ -129,11 +143,11 @@ def grouped(eng, note) -> dict:
                           np.float32)
         return pages, conv
 
-    def timed(fn, rows, S, R=None, n=5):
-        call(fn, rows, S, R).block_until_ready()
+    def timed(fn, rows, S, R=None, n=5, riders=idle):
+        call(fn, rows, S, R, riders).block_until_ready()
         t0 = time.perf_counter()
         for _ in range(n):
-            logits = call(fn, rows, S, R)
+            logits = call(fn, rows, S, R, riders)
         logits.block_until_ready()
         return (time.perf_counter() - t0) / n * 1e3
 
@@ -190,6 +204,31 @@ def grouped(eng, note) -> dict:
                      20 + j, 100 + 8 * j) for j in range(R)]
             out[f"ms_full_{R}x{S}"] = round(timed(fn, rows, S), 2)
             note(f"[{R}, {S}] full rows: {out[f'ms_full_{R}x{S}']} ms a call")
+    # a decode step alone and riding a call: every slot but the one the call
+    # fills at 700 positions (three live pages of its own ten)
+    own = 1 + np.arange(B * MP, dtype=np.int32).reshape(B, MP)
+    full = (jnp.zeros(B, jnp.int32), jnp.full(B, 700, jnp.int32),
+            jnp.asarray(own), jnp.asarray(np.arange(B) > 0))
+    row = [(rng.integers(0, mcfg.vocab_size, 1000, dtype=np.int32), 0, 1)]
+
+    def decode_ms(n=10):
+        def run():
+            logits, eng.cache = mr.decode_step(eng.params, mcfg, eng.cache,
+                                               *full)
+            return logits
+        run().block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(n):
+            logits = run()
+        logits.block_until_ready()
+        return (time.perf_counter() - t0) / n * 1e3
+
+    out["ms_riding"] = {
+        "call_1x1024_nobody_riding": round(timed(mr.prefill, row, 1024), 2),
+        "call_1x1024_127_riding": round(
+            timed(mr.prefill, row, 1024, riders=full), 2),
+        "decode_step_127_alone": round(decode_ms(), 2)}
+    note("a [1, 1024] call and a 127-slot decode step:", out["ms_riding"])
     stats = jax.devices()[0].memory_stats() or {}
     out["peak_gb"] = round(stats.get("peak_bytes_in_use", 0) / 1e9, 3)
     out["limit_gb"] = round(stats.get("bytes_limit", 0) / 1e9, 3)
@@ -231,37 +270,16 @@ def main(only_grouped: bool = False) -> dict:
         out = grouped(eng, note)
         print(json.dumps(out), flush=True)
         return out
-    B, MP = e.max_num_seqs, e.pages_per_seq
+    from prefill_rows import teacher_forced_riding
+
     rng = np.random.default_rng(seed)
-    tables = np.zeros((B, MP), np.int32)
-    active = np.zeros(B, bool)
-    seqs, got = {}, {}
-    for prompt, slot, first in (LONG, SHORT):
-        toks = rng.integers(0, mcfg.vocab_size, prompt + STEPS, dtype=np.int32)
-        S = eng._prefill_bucket(prompt)
-        need = -(-len(toks) // e.page_size)
-        tables[slot, :need] = np.arange(first, first + need)
-        batch = np.zeros((1, S), np.int32)
-        batch[0, :prompt] = toks[:prompt]
-        # the engine's own call: one admitted request, [1, S], told its slot
-        logits, eng.cache = mr.prefill(
-            eng.params, mcfg, eng.cache, jnp.asarray(batch),
-            jnp.asarray([prompt], jnp.int32),
-            jnp.asarray(tables[slot:slot + 1]), jnp.asarray([slot], jnp.int32))
-        seqs[slot], got[slot] = (toks, prompt), [np.asarray(logits[0])]
-        active[slot] = True
-        note(f"prefill [1, {S}] of {prompt} tokens into slot {slot}")
-    for i in range(STEPS):
-        last, seq_lens = np.zeros(B, np.int32), np.zeros(B, np.int32)
-        for slot, (toks, prompt) in seqs.items():
-            last[slot], seq_lens[slot] = toks[prompt + i], prompt + i
-        logits, eng.cache = mr.decode_step(
-            eng.params, mcfg, eng.cache, jnp.asarray(last),
-            jnp.asarray(seq_lens), jnp.asarray(tables), jnp.asarray(active))
-        for slot in seqs:
-            got[slot].append(np.asarray(logits[slot]))
-    got = {slot: np.stack(v) for slot, v in got.items()}
-    note(f"{STEPS} decode steps of both requests done")
+    seqs = {slot: (rng.integers(0, mcfg.vocab_size, prompt + STEPS,
+                                dtype=np.int32), prompt, first)
+            for prompt, slot, first in (LONG, SHORT, THIRD)}
+    # the engine's own calls: [1, S] told its slot, carrying the others' step
+    got = teacher_forced_riding(eng, seqs, gap=1)
+    note(f"three requests, {STEPS} tokens each: [1, 1024] carrying nobody, "
+         f"[1, 128] one step, [1, 512] two; decode steps between and after")
 
     def reference(drop_bias=False, **change):
         rcfg = dict(arch.reference_cfg(conf), **change)
@@ -289,11 +307,14 @@ def main(only_grouped: bool = False) -> dict:
                "state_a_row_late": {"state_lag": 1},
                "rope_theta_1e4": {"rope_theta": 10000},
                "no_expert_bias": {"drop_bias": True}}       # printed only
-    for name, (prompt, slot, _) in (("long", LONG), ("short", SHORT)):
+    for name, (prompt, slot, _) in (("long", LONG), ("short", SHORT),
+                                    ("third", THIRD)):
         tj = jnp.asarray(seqs[slot][0])
         out[f"rel_err_{name}"] = rel(got[slot], np.asarray(reference()(p, tj)))
         note(name, "reference", out[f"rel_err_{name}"])
         for what, change in spoiled.items():
+            if name == "third":
+                break        # held to the reference; the others show the rest
             if name == "short" and what == "rope_theta_1e4":
                 continue     # ten positions: the two thetas hardly differ
             key = f"{what}_{name}"
@@ -301,7 +322,7 @@ def main(only_grouped: bool = False) -> dict:
             note(name, what, out[key])
     out["ok"] = bool(
         out["finite"] and out["rel_err_long"] < TOL
-        and out["rel_err_short"] < TOL
+        and out["rel_err_short"] < TOL and out["rel_err_third"] < TOL
         and all(v > TOL for k, v in out.items()
                 if k.endswith(("_long", "_short"))
                 and not k.startswith(("rel_", "no_expert_bias"))))

@@ -18,6 +18,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 KEYS = {
     "prefill_tokens", "decode_steps", "generated_tokens", "preempted",
+    # decode steps whose rows rode a prefill call; a dense model's stay 0
+    "riding_steps",
     "steps", "prefill_steps", "admitted", "prefill_batch_tokens", "compiles",
     # prefill program calls (of one row or several), and the shapes of
     # several rows compiled off the serving path
@@ -135,6 +137,7 @@ def test_metrics_complete_numeric_monotone(engine):
     assert m["overlapped_steps"] == m["steps"] - 1
     # one sampler call a prefill phase and one a decode step; nobody sampled
     assert m["sample_calls"] == m["prefill_steps"] + m["decode_steps"]
+    assert m["riding_steps"] == 0  # this model's prefill call carries none
     assert m["sample_greedy_calls"] == m["sample_calls"]
     # a token computed for a request that EOS had ended is not a generated one
     assert m["generated_tokens"] <= sum(4 + i for i in range(SLOTS + 2))
@@ -341,7 +344,7 @@ def test_engine_spans_nest_in_step_order(engine, recorder):
         (1, "sample_dispatch")]
     attrs = {n: a for _, n, a in recorder}
     assert attrs["ray_tpu/engine.prefill_dispatch"] == {
-        "bucket": BUCKET, "admitted": 1, "calls": 1, "rows": 1}
+        "bucket": BUCKET, "admitted": 1, "calls": 1, "rows": 1, "riding": 0}
     assert attrs["ray_tpu/engine.compile"]["program"] == "decode"
     assert attrs["ray_tpu/engine.decode_dispatch"] == {
         "overlapped": 0, "dropped": 0}

@@ -323,7 +323,8 @@ def test_engine_serves_admits_two_at_once_and_preempts():
         assert out.token_ids == np.argmax(want, axis=-1).tolist()
     m = eng.metrics
     assert m["preempted"] >= 1 and m["admitted"] > m["prefill_steps"]
-    assert m["moe_decode_layer_steps"] == 5 * m["decode_steps"]
+    assert m["moe_decode_layer_steps"] == 5 * (
+        m["decode_steps"] - m["riding_steps"])
     assert m["moe_decode_assignments"] == m["moe_decode_routed_assignments"]
     assert m["shared_kv_live_tokens"] > 0 == m["window_live_tokens"]
     assert m["prefill_cross_rows"] == 0
@@ -344,6 +345,172 @@ def test_burst_admitted_in_one_step_shares_a_prefill_call(lens, rows, bucket):
         eng, [rng.integers(0, VOCAB, n).tolist() for n in lens])
     assert (d["prefill_calls"], d["prefill_batch_tokens"]) == (1, rows * bucket)
     assert d["preempted"] == 0
+
+
+def test_decode_rows_ride_a_prefill_call(engine):
+    """``prefill`` with ``riders`` is the call and then ``decode_step``: the
+    prompt's logits, the step's logits, the pages and the conv rows of the slot
+    that decodes and of the slot that is filled again, beside a padding row
+    and a slot that is not active (whose state it leaves as it found it)."""
+    import prefill_rows
+
+    prefill_rows.riders_equal_a_step_after_the_call(
+        engine, np.random.default_rng(7), TOL)
+    with pytest.raises(ValueError, match="no decode rows ride"):
+        mr.prefill(engine.params, dataclasses.replace(
+            engine.mcfg, layer_kinds=()), engine.cache, None, None, None,
+            riders=())
+
+
+def test_riding_calls_match_reference():
+    """Three requests through the calls the engine makes when it admits
+    beside decoding slots: the second's ``[1, 16]`` call carries the first's
+    third step, the third's ``[1, 32]`` (a slot used before, pages in the
+    middle of the pool) the other two's, ``decode_step`` in between and after:
+    every position's logits against the reference (what the chip test runs at
+    the published widths)."""
+    import prefill_rows
+
+    eng = _engine(num_pages=40)
+    rng = np.random.default_rng(11)
+    seqs = {1: (rng.integers(0, VOCAB, 11 + 7), 11, 3),
+            0: (rng.integers(0, VOCAB, 2 + 6), 2, 9),
+            2: (rng.integers(0, VOCAB, 19 + 4), 19, 20)}
+    got = prefill_rows.teacher_forced_riding(eng, seqs, gap=2)
+    for slot, (toks, n, _) in seqs.items():
+        assert got[slot].shape == (len(toks) - n + 1, VOCAB)
+        assert _rel(got[slot], _reference(eng, toks)[n - 1:]) < TOL, slot
+
+
+def test_staggered_requests_ride_and_get_the_tokens_they_get_alone():
+    """Requests admitted while others decode: the decoding slots' step rides
+    the admitted request's prefill call (one program, one sampler call, one
+    read), every request's greedy tokens are those it gets alone, and the
+    routing counters move with the decode-only steps alone."""
+    import prefill_rows
+
+    eng = _engine(num_pages=None)
+    rng = np.random.default_rng(8)
+    d = prefill_rows.staggered_equal_alone(
+        eng, [rng.integers(0, VOCAB, n).tolist() for n in (11, 5, 14)])
+    layers = eng.cache.moe_load.shape[0]
+    assert d["moe_decode_layer_steps"] == layers * (
+        d["decode_steps"] - d["riding_steps"])
+    assert d["shared_kv_live_tokens"] > 0
+
+
+@pytest.mark.parametrize("slots,decoding,burst,calls", [
+    # [2, 32] then [1, 16]: both carry, the first takes the step
+    (5, (9,), (20, 25, 5), 2),
+    # [1, 64] is a plain program at 3 slots and [1, 16] carries: it goes first
+    (3, (9,), (40, 5), 2),
+    # [4, 16] of three beside two that decode
+    (5, (9, 3), (11, 12, 5), 1)])
+def test_a_burst_beside_decoding_slots_gets_the_tokens_it_gets_alone(
+        slots, decoding, burst, calls):
+    """Several requests admitted in ONE step while slots decode: the phase's
+    first call carries the step, the rows of its other calls land in the same
+    buffer, one sampler call serves all, and every request's tokens are those
+    it gets alone, whichever of its calls' programs carry."""
+    import prefill_rows
+
+    eng = _engine(num_pages=None, max_num_seqs=slots)
+    rng = np.random.default_rng(12)
+    d = prefill_rows.admitted_beside_decoders_equal_alone(
+        eng, *([rng.integers(0, VOCAB, n).tolist() for n in lens]
+               for lens in (decoding, burst)))
+    assert d["prefill_calls"] == calls
+    assert (d["prefill_steps"], d["decode_steps"], d["riding_steps"],
+            d["sample_calls"]) == (1, 1, 1, 1)
+
+
+def test_nothing_rides_where_nothing_decodes_or_no_decode_is_asked():
+    """A step that admits with no slot active runs the same prefill program
+    with nobody marked active and then ``decode_step``, as before;
+    ``step(decode=False)`` and ``prefill_only`` carry nobody either, and
+    leave the decoding slots where they were."""
+    eng = _engine(num_pages=None)
+    rng = np.random.default_rng(9)
+    sp = SamplingParams(max_tokens=5)
+    eng.add_request("a", rng.integers(0, VOCAB, 6).tolist(), sp)
+    eng.step()
+    m = dict(eng.metrics)
+    assert (m["prefill_steps"], m["decode_steps"], m["riding_steps"]) == (1, 1, 0)
+    assert m["sample_calls"] == 2
+    first = eng.prefill_only("b", rng.integers(0, VOCAB, 9).tolist(),
+                             SamplingParams(max_tokens=1))
+    assert first["finished"] and len(first["generated"]) == 1
+    eng.add_request("c", rng.integers(0, VOCAB, 3).tolist(), sp)
+    eng.step(decode=False)
+    d = {k: eng.metrics[k] - v for k, v in m.items()}
+    assert (d["prefill_steps"], d["decode_steps"], d["riding_steps"]) == (2, 0, 0)
+    done = {}
+    while eng.has_unfinished():
+        done.update((o.request_id, o) for o in eng.step() if o.finished)
+    assert sorted(done) == ["a", "c"]
+    assert all(len(o.token_ids) == 5 for o in done.values())
+    assert not eng.plain_buckets
+
+
+class _NoRoomAt32:
+    """``model_runner`` with a compiler that finds no room for the ``[1, 32]``
+    prefill program that carries a decode step (or fails on it with
+    ``error``)."""
+
+    def __init__(self, mr, error=None):
+        self._mr = mr
+        self._error = error or jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: ran out of memory in hbm")
+
+    def __getattr__(self, name):
+        return getattr(self._mr, name)
+
+    def prefill(self, params, cfg, cache, tokens, *rows):
+        if tokens.shape == (1, 32) and len(rows) == 4:
+            raise self._error
+        return self._mr.prefill(params, cfg, cache, tokens, *rows)
+
+
+def test_a_bucket_the_compiler_refuses_stays_the_plain_program():
+    """The ``[1, 32]`` program with decode rows does not compile: the bucket's
+    first use falls back to the plain program, the engine says why
+    (``plain_buckets``), a step that admits at that bucket runs
+    ``decode_step`` after the call as before, the 16 bucket goes on carrying
+    the others' step, and every token is the one served alone."""
+    import prefill_rows
+
+    eng = _engine(num_pages=None)
+    eng._mr = _NoRoomAt32(eng._mr)
+    rng = np.random.default_rng(10)
+    prompts = [rng.integers(0, VOCAB, n).tolist() for n in (11, 20, 5)]
+    sp = SamplingParams(max_tokens=6)
+    alone = [eng.generate([p], sp, decode_text=False)[0].token_ids
+             for p in prompts]
+    assert list(eng.plain_buckets) == [32]
+    assert "RESOURCE_EXHAUSTED" in eng.plain_buckets[32]
+    before, got = dict(eng.metrics), {}
+    for i, p in enumerate(prompts):  # one admitted a step, the others decoding
+        eng.add_request(f"r{i}", p, sp)
+        got.update((o.request_id, o.token_ids) for o in eng.step() if o.finished)
+    while eng.has_unfinished():
+        got.update((o.request_id, o.token_ids) for o in eng.step() if o.finished)
+    assert [got[f"r{i}"] for i in range(3)] == alone
+    d = {k: eng.metrics[k] - v for k, v in before.items()}
+    # the 5-token prompt's call carried a step; the 20-token prompt's did not
+    assert (d["prefill_steps"], d["riding_steps"], d["compiles"]) == (3, 1, 0)
+    assert d["sample_calls"] == 3 + d["decode_steps"] - 1
+
+
+def test_an_error_that_is_no_refusal_of_the_compiler_is_raised():
+    """Only the compiler's own "no room" makes a bucket plain: any other
+    error of the carrying program's first use is the caller's to see."""
+    eng = _engine(num_pages=None)
+    eng._mr = _NoRoomAt32(eng._mr, TypeError("a bug in the program"))
+    rng = np.random.default_rng(10)
+    with pytest.raises(TypeError, match="a bug in the program"):
+        eng.generate([rng.integers(0, VOCAB, 20).tolist()],
+                     SamplingParams(max_tokens=2), decode_text=False)
+    assert not eng.plain_buckets
 
 
 def test_padding_row_changes_no_page_row_by_slot_or_load(engine):
