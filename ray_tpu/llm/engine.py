@@ -161,7 +161,7 @@ from ``__init__``, so two snapshots subtract):
   expert-parallel deployment, ``TransformerConfig.experts_held``),
   ``moe_decode_experts_touched`` (held experts that got a row) and
   ``moe_decode_max_load`` (rows of the fullest held expert). They come from
-  ``KVCache.moe_load``, a few KB copied out of the cache at dispatch (the
+  ``Cache.moe_load``, a few KB copied out of the cache at dispatch (the
   next dispatch donates the cache) and read in ``emit`` with that step's
   tokens, a step later;
 - for a model with a latent cache (0 otherwise), per decode step and a layer:
@@ -958,7 +958,7 @@ class JaxLLMEngine:
             except jax.errors.JaxRuntimeError as e:
                 # the compiler's own refusal, before anything was donated
                 if not (first and carries and "RESOURCE_EXHAUSTED" in str(e)
-                        ) or self.cache[0].is_deleted():
+                        ) or jax.tree.leaves(self.cache)[0].is_deleted():
                     raise
                 self.plain_buckets[S] = str(e)
                 logger.warning(
@@ -1211,7 +1211,9 @@ class JaxLLMEngine:
         return state
 
     def _page_leaves(self) -> List[str]:
-        return [f for f in self.cache._fields if f != "moe_load"]
+        """The leaves this model's block tables address."""
+        return [name for name in self._mr.PAGE_LEAVES
+                if getattr(self.cache, name) is not None]
 
     def _refuse_state_by_slot(self, what: str) -> None:
         """A request's state is a gather of its pages only where pages are

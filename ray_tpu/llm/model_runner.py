@@ -7,22 +7,78 @@ own pages through a block table — but implements it as pure-jnp programs so
 every prefill bucket and the decode step are each ONE compiled XLA program
 with static shapes (no dynamic shapes, no host sync inside the step).
 
-Layout:
-- ``k_pages``/``v_pages``: [n_layers, num_pages, page_size, n_kv_heads, hd]
-- ``block_tables``:        [max_num_seqs, pages_per_seq] int32 page ids
-- page 0 is scratch: masked-out writes (padding, inactive slots) land there.
+ONE cache type (``Cache``) holds every model's state by KIND of layer, side
+by side, a kind the model lacks None; ``block_tables`` [max_num_seqs,
+pages_per_seq] int32 hands out the pages of whichever leaves are paged
+(``PAGE_LEAVES``), and page 0 is scratch: masked-out writes (padding,
+inactive slots) land there. Both programs take the cache donated and write it
+in place: per layer one scatter a leaf whose operand is the whole array and
+whose indices are (layer, page, offset), B rows in decode, and in prefill the
+S positions of each row it is given (the engine gives it the requests
+admitted in one step, one row each, alone or in groups of two to four padded
+to one length bucket; a row of length 0 is padding and writes the scratch
+page). Nothing slices a layer out or writes one back, so a step's cache
+traffic is the rows it writes, not the cache.
 
-Both programs take the cache donated and write it in place: per layer, one
-scatter for K and one for V whose operand is the whole 5-D array and whose
-indices are (layer, page, offset): B rows in decode, and in prefill the S
-positions of each row it is given (the engine gives it the requests admitted
-in one step, one row each, alone or in groups of two to four padded to one
-length bucket; a row of length 0 is padding and writes the scratch page).
-Nothing slices a layer out or writes one back, so a step's cache traffic is
-the rows it writes, not the cache. Decode then gathers each slot's pages from
-the same array by (layer, block_tables) into a [B, Lmax] view and runs
-grouped-query attention against it. A pallas paged-attention kernel that
-reads only live pages can swap in underneath without changing the layout.
+ONE per-layer composition (``_forward``) is both programs of every model but
+the decoder-hybrid-decoder: a prompt side, a step side, or both. It walks the
+layers' kinds (``_kinds``), asks the kind for its inputs (``_attn_inputs``,
+``_conv_gates``), hands each side's rows to the kind's mixer
+(``_prompt_mixer``, ``_step_mixer``) and runs the residual, the norms, the MLP
+or the experts and the head once over all rows, so ``prefill`` can carry a
+decode step's rows beside its prompts (``riders``; ``rides`` says for which
+models, ``llm/engine.py`` when): every held weight read once for the call and
+the step. The kinds, and where each keeps what:
+
+- "dense", every layer of a model with neither ``layer_kinds`` nor a latent
+  rank: ``k`` / ``v`` [n_layers, num_pages, page_size, n_kv_heads, hd]. Prefill
+  attends over the call's own keys and values (the flash kernel); decode
+  gathers each slot's pages by (layer, block_tables) into a [B, Lmax] view and
+  runs grouped-query attention against it under a mask;
+- "latent", every layer of a model with ``kv_latent_rank``: ``rows``, one row
+  a position and layer for all heads. Prefill attends over keys and values
+  expanded to heads (the flash kernel, 192-wide q . k and 128-wide values);
+  decode attends over the rows themselves with the up-projection absorbed,
+  through ``ops/mla.py:mla_decode``, which reads only the pages that hold
+  live positions;
+- "full", "window", "conv" of a model with ``layer_kinds``, under ONE
+  definition of where each lies (``_prompt_index``, ``_write_rings``,
+  ``_decode_index``, ``_ring_blocks``): ``pages`` for each "full" layer's keys
+  and values, handed out by the block tables as any page is; in ``rings`` a
+  ring of ``window`` positions a slot for each "window" layer, written at
+  ``position mod window`` and masked by how many entries are filled; in
+  ``conv`` the last ``conv_taps - 1`` gated inputs ``B * z`` a slot for each
+  "conv" layer (a gated short convolution,
+  ``models/transformer.py:ShortConv``: no attention at all). Keys are rotated
+  before they are written where the kind rotates
+  (``TransformerConfig.rope_kinds``). Decode attends through
+  ``ops/paged_attention.py``: over the rings' filled blocks in the window
+  layers and over the live pages in the full ones, two work lists
+  (``ops/mla.py:live_pages``) built once a step; nothing is gathered over a
+  slot's whole length. Prefill convolves the bucket and leaves the conv rows
+  of positions ``lengths - conv_taps + 1 .. lengths - 1`` (zeros where the
+  prompt is shorter than that; padding behind the prompt never enters them);
+  a decode step convolves the rows with the new input and shifts them by one.
+
+``prefill`` is told the slot a row fills (``slots``), overwrites the slot's
+rings and rows from the prompt alone (which is how a slot is reset at
+admission and how a preempted request comes back) and leaves them at position
+``lengths - 1``. Whatever of a per-head q/k norm, an attention gate, sandwich
+norms, a scaled embedding and experts (all of them, or the share
+``experts_held`` of an expert-parallel rank) the config asks for is a field
+the block reads.
+
+The decoder-hybrid-decoder ("sambay": Mamba layers, window and full
+DIFFERENTIAL attention, gated memory units, cross layers; LayerNorm, no
+position embedding) is a composition of its own (``_hybrid_prefill``,
+``_hybrid_decode``) over the same cache and the same index: one paged layer,
+which the full layer writes and every cross layer reads, rings, and in
+``ssm`` / ``conv`` a recurrent row a slot for each Mamba layer (the scan's
+state in float32 and the convolution's last inputs). Its prefill runs the
+self-decoder over the prompt (the scan through ``ops/ssm.py``, padding passed
+over with ``dt = 0``; the window through the flash kernel, blocks left of it
+skipped) and the cross-decoder on the ONE last position, since those layers
+write no state and the engine reads one row of logits.
 
 Weights come from ``ray_tpu.models.transformer.Transformer`` — this module
 reads the same param pytree (checkpoint-compatible with training). The dense
@@ -32,59 +88,7 @@ must stay the arithmetic of ``models/transformer.py``; a layer with experts
 NOT the training module's capacity-bound dispatch but ``ops/moe.py``:
 dropless, rows that are padding or belong to an inactive slot reach no
 expert. What routing did in a call comes back beside the pages, as
-``KVCache.moe_load`` (per expert layer, how many real rows each expert got).
-
-A model with latent attention (``kv_latent_rank``) keeps ``LatentCache``
-instead: one row a position and layer for all heads. Prefill writes the rows
-and attends over keys and values expanded to heads (the flash kernel, 192-wide
-q . k and 128-wide values); decode attends over the rows themselves with the
-up-projection absorbed, through ``ops/mla.py:mla_decode``, which reads only
-the pages that hold live positions. Both programs keep their signatures: the
-cache is whichever tuple ``init_cache`` made, pages first and ``moe_load`` last.
-
-A model with ``layer_kinds`` keeps ``HybridCache``: state by KIND of layer,
-side by side, under ONE definition of where each kind lies (``_prompt_index``,
-``_write_rings``, ``_decode_index``, ``_ring_blocks``). Pages, for each "full"
-layer's keys and values, handed out by the engine's block tables as any page
-is; a ring of ``window`` positions a slot for each "window" layer, written at
-``position mod window`` and masked by how many entries are filled; and, in a
-decoder-hybrid-decoder, a recurrent row a slot for each Mamba layer (the scan's
-state in float32 and the convolution's last inputs). ``prefill`` is told the
-slot a row fills (``slots``), overwrites the slot's rings (and rows) from the
-prompt alone (which is how a slot is reset at admission and how a preempted
-request comes back) and leaves them at position ``lengths - 1``.
-``decode_step`` writes each ring at ``position mod window`` and the page row,
-and attends through ``ops/paged_attention.py``: over the rings' filled blocks
-in the window layers and over the live pages in the full ones, two work lists
-(``ops/mla.py:live_pages``) built once a step; nothing is gathered over a
-slot's whole length. Two kinds of block use it (``TransformerConfig.block``):
-
-- "sambay" (Mamba layers, window and full DIFFERENTIAL attention, gated memory
-  units, cross layers; LayerNorm, no position embedding): one paged layer,
-  which the full layer writes and every cross layer reads. Its prefill runs
-  the self-decoder over the prompt (the scan through ``ops/ssm.py``, padding
-  passed over with ``dt = 0``; the window through the flash kernel, blocks left
-  of it skipped) and the cross-decoder on the ONE last position, since those
-  layers write no state and the engine reads one row of logits;
-- "rms" (the RMSNorm block of every other model, with plain grouped-query
-  heads): pages for each full layer, rings for each window layer, keys
-  rotated before they are written where the kind rotates
-  (``TransformerConfig.rope_kinds``), and whatever of a per-head q/k norm, an
-  attention gate, sandwich norms, a scaled embedding and experts (all of them,
-  or the share ``experts_held`` of an expert-parallel rank) the config asks
-  for. A "conv" layer of such a block (a gated short convolution,
-  ``models/transformer.py:ShortConv``) has no attention at all: its state is
-  the last ``conv_taps - 1`` gated inputs ``B * z`` a slot, in
-  ``HybridCache.conv``. Prefill convolves the bucket and leaves the rows of
-  positions ``lengths - conv_taps + 1 .. lengths - 1`` (zeros where the
-  prompt is shorter than that; padding behind the prompt never enters them);
-  a decode step convolves the rows with the new input and shifts them by one.
-  Both of this block's programs are ONE per-layer composition
-  (``_kinds_forward``: a prompt side, a step side, or both), so ``prefill``
-  can carry a decode step's rows beside its prompts (``riders``): each side's
-  mixer on its own rows and slots, then the residual, the norms, the MLP or
-  the experts and the head once over all rows, every held weight read once
-  for the call and the step (``llm/engine.py`` says when it asks for that).
+``Cache.moe_load`` (per expert layer, how many real rows each expert got).
 """
 
 from __future__ import annotations
@@ -99,49 +103,54 @@ import jax.numpy as jnp
 from ray_tpu.models.transformer import TransformerConfig, _rope
 
 
-class KVCache(NamedTuple):
-    """What a program takes donated and hands back: the pages, and for a
-    model with experts what the call's routing did (a dense model has no
-    such leaf, so nothing is added to its programs)."""
-    k: jax.Array  # [L, NP, P, KVH, HD]
-    v: jax.Array
-    moe_load: Optional[jax.Array] = None  # [expert layers, E] int32
+class Cache(NamedTuple):
+    """What a program takes donated and hands back: the model's state by KIND
+    of layer, side by side, ONE type for every model; a kind the model lacks
+    is None and adds nothing to its programs.
 
-
-class LatentCache(NamedTuple):
-    """The cache of a model with latent attention (``kv_latent_rank``): per
-    position and layer ONE row ``c | k_pe | 0`` for all heads, the normalised
-    latent, the rotated key and padding to whole 128-lane tiles (512 + 64 ->
-    640; ``ops/mla.py`` says why), in place of 2 x heads x head_dim."""
-    rows: jax.Array  # [L, NP, P, W]
-    moe_load: Optional[jax.Array] = None
-
-
-class HybridCache(NamedTuple):
-    """The state of a model with ``layer_kinds``: up to three kinds side by
-    side, ONE definition for every such model. ``pages``: a layer of keys and
-    values for each "full" layer, a row ``k | v`` of all heads a position,
-    addressed through the block tables as any page is (a decoder-hybrid-decoder
-    has one such layer, which its "cross" layers read too). ``rings``: per
-    "window" layer and slot the ``window`` newest positions' rows, position
-    ``t`` at entry ``t mod window`` (keys as attention reads them: rotated, in
-    a model that rotates). ``ssm``, ``conv``: per "mamba" layer and slot the
-    scan's state (float32, ``inner`` along the lanes as ``ops/ssm.py`` keeps
-    it: [.., N, inner] is whole tiles where [.., inner, N] would pad 16 lanes
-    to 128) and the convolution's last ``ssm_conv - 1`` inputs. ``conv`` alone,
-    ``ssm`` None: per "conv" layer (a gated short convolution) and slot the
-    last ``conv_taps - 1`` gated inputs ``B * z``, oldest first, ``d_model``
-    wide. A kind the model lacks has None. Rings and rows belong to a SLOT:
-    prefill overwrites all of a slot's from the prompt alone, which is also
-    how a slot is reset at admission; a slot that is not active computes into
-    its own rows and nobody reads them."""
-    pages: jax.Array  # [full layers, NP, P, 2 KVH hd]
-    rings: Optional[jax.Array]  # [window layers, B, window, 2 KVH hd]
+    ``k``, ``v``: per "dense" layer (every layer of a model with neither
+    ``layer_kinds`` nor a latent rank) the keys and the values by head, pages
+    addressed through the block tables. ``rows``: per "latent" layer
+    (``kv_latent_rank``) and position ONE row ``c | k_pe | 0`` for all heads,
+    the normalised latent, the rotated key and padding to whole 128-lane
+    tiles (512 + 64 -> 640; ``ops/mla.py`` says why), in place of 2 x heads x
+    head_dim. ``pages``: a layer of keys and values for each "full" layer of
+    a model with ``layer_kinds``, a row ``k | v`` of all heads a position,
+    addressed through the block tables as any page is (a
+    decoder-hybrid-decoder has one such layer, which its "cross" layers read
+    too). ``rings``: per "window" layer and slot the ``window`` newest
+    positions' rows, position ``t`` at entry ``t mod window`` (keys as
+    attention reads them: rotated, in a model that rotates). ``ssm``,
+    ``conv``: per "mamba" layer and slot the scan's state (float32, ``inner``
+    along the lanes as ``ops/ssm.py`` keeps it: [.., N, inner] is whole tiles
+    where [.., inner, N] would pad 16 lanes to 128) and the convolution's last
+    ``ssm_conv - 1`` inputs. ``conv`` alone, ``ssm`` None: per "conv" layer (a
+    gated short convolution) and slot the last ``conv_taps - 1`` gated inputs
+    ``B * z``, oldest first, ``d_model`` wide. ``moe_load``: for a model with
+    experts, what the call's routing did. Rings and rows by slot belong to a
+    SLOT: prefill overwrites all of a slot's from the prompt alone, which is
+    also how a slot is reset at admission; a slot that is not active computes
+    into its own rows and nobody reads them."""
+    k: Optional[jax.Array] = None  # [L, NP, P, KVH, HD]
+    v: Optional[jax.Array] = None
+    rows: Optional[jax.Array] = None  # [L, NP, P, W]
+    pages: Optional[jax.Array] = None  # [full layers, NP, P, 2 KVH hd]
+    rings: Optional[jax.Array] = None  # [window layers, B, window, 2 KVH hd]
     ssm: Optional[jax.Array] = None   # [mamba layers, B, N, inner] float32
     # [mamba layers, ssm_conv - 1, B, inner] or [conv layers, conv_taps - 1,
     # B, d_model]
     conv: Optional[jax.Array] = None
-    moe_load: Optional[jax.Array] = None
+    moe_load: Optional[jax.Array] = None  # [expert layers, E] int32
+
+
+# the leaves that block tables address: what a request's pages are gathered
+# from and scattered into when it moves between engines
+PAGE_LEAVES = ("k", "v", "rows", "pages")
+
+
+def _page_size(cache: Cache) -> int:
+    return next(getattr(cache, name) for name in PAGE_LEAVES
+                if getattr(cache, name) is not None).shape[2]
 
 
 def _latent_width(cfg: TransformerConfig) -> int:
@@ -156,9 +165,9 @@ def _latent_row(parts, width):
 
 
 def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
-               max_num_seqs: int = 0) -> KVCache:
+               max_num_seqs: int = 0) -> Cache:
     """``max_num_seqs``: the engine's slots, which only a model that keeps
-    state by slot (``HybridCache``) needs."""
+    state by slot (``layer_kinds``) needs."""
     load = None
     layers = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
     if layers:
@@ -176,21 +185,21 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
         elif "conv" in kinds:
             rows = (kinds.count("conv"), cfg.conv_taps - 1, max_num_seqs,
                     cfg.d_model)
-        return HybridCache(
-            jnp.zeros((kinds.count("full"), num_pages, page_size, row),
-                      cfg.dtype),
-            jnp.zeros((window, max_num_seqs, cfg.window, row), cfg.dtype)
+        return Cache(
+            pages=jnp.zeros((kinds.count("full"), num_pages, page_size, row),
+                            cfg.dtype),
+            rings=jnp.zeros((window, max_num_seqs, cfg.window, row), cfg.dtype)
             if window else None,
-            jnp.zeros((mamba, max_num_seqs, cfg.ssm_state, cfg.ssm_inner),
-                      jnp.float32) if mamba else None,
-            jnp.zeros(rows, cfg.dtype) if rows else None, load)
+            ssm=jnp.zeros((mamba, max_num_seqs, cfg.ssm_state, cfg.ssm_inner),
+                          jnp.float32) if mamba else None,
+            conv=jnp.zeros(rows, cfg.dtype) if rows else None, moe_load=load)
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     if cfg.kv_latent_rank:
-        return LatentCache(jnp.zeros(
+        return Cache(rows=jnp.zeros(
             (cfg.n_layers, num_pages, page_size, _latent_width(cfg)),
-            cfg.dtype), load)
-    return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype),
-                   load)
+            cfg.dtype), moe_load=load)
+    return Cache(k=jnp.zeros(shape, cfg.dtype), v=jnp.zeros(shape, cfg.dtype),
+                 moe_load=load)
 
 
 # ---------------------------------------------------------------------------
@@ -460,7 +469,7 @@ def _prompt_index(cfg, cache, S, lengths, block_tables):
     holds the newest prompt position that is j mod window (< 0: none; None in
     a model without rings)."""
     B = lengths.shape[0]
-    P, W = cache.pages.shape[2], cfg.window
+    P, W = _page_size(cache), cfg.window
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     in_prompt = positions < lengths[:, None]
     page_for = jnp.take_along_axis(block_tables, positions // P, axis=1)
@@ -482,31 +491,38 @@ def _ring_block(cfg, page_size):
 
 def _decode_index(cfg, cache, seq_lens, block_tables, active):
     """A decode step's row: ``slot`` [B], ``positions`` [B], the ``page`` and
-    ``offset`` it is written at (inactive slots -> the scratch page), and the
-    kernel's two work lists (``ops/mla.py:live_pages``, built once a step):
-    the pages that hold live positions, and the ring blocks that hold filled
-    entries (a ring is its slot's own run of blocks, its live entries the
-    filled ones, in whatever order it holds them; None in a model without
-    rings)."""
+    ``offset`` it is written at (inactive slots -> the scratch page), and what
+    its attention reads, built once a step. For the paged kernels two work
+    lists (``ops/mla.py:live_pages``): the pages that hold live positions, and
+    the ring blocks that hold filled entries (a ring is its slot's own run of
+    blocks, its live entries the filled ones, in whatever order it holds them;
+    None in a model without rings). For the "dense" kind, which gathers every
+    page of a slot, the block tables and the mask of the live positions among
+    their ``Lmax``. Last, the positions as the tables took them, [B, 1]."""
     from ray_tpu.ops.mla import live_pages
 
     B = seq_lens.shape[0]
-    P, W = cache.pages.shape[2], cfg.window
+    P, W = _page_size(cache), cfg.window
     slot = jnp.arange(B, dtype=jnp.int32)
     positions = seq_lens.astype(jnp.int32)
-    cur_page = jnp.take_along_axis(block_tables, positions[:, None] // P,
-                                   axis=1)[:, 0]
+    at = positions[:, None]
+    cur_page = jnp.take_along_axis(block_tables, at // P, axis=1)[:, 0]
     page = jnp.where(active, cur_page, 0)
     offset = jnp.where(active, positions % P, 0)
-    work = live_pages(positions, active, block_tables, P)
+    if cache.k is not None:
+        Lmax = block_tables.shape[1] * P
+        work = block_tables, (jnp.arange(Lmax, dtype=jnp.int32)[None]
+                              <= seq_lens[:, None]) & active[:, None]
+    else:
+        work = live_pages(positions, active, block_tables, P)
     if cache.rings is None:
-        return slot, positions, page, offset, work, None
+        return slot, positions, page, offset, work, None, at
     blocks = W // _ring_block(cfg, P)
     ring_tables = slot[:, None] * blocks + jnp.arange(
         blocks, dtype=jnp.int32)[None]
     ring_work = live_pages(jnp.minimum(positions, W - 1), active, ring_tables,
                            W // blocks)
-    return slot, positions, page, offset, work, ring_work
+    return slot, positions, page, offset, work, ring_work, at
 
 
 def _ring_blocks(rings, cfg, page_size):
@@ -534,7 +550,7 @@ def _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
         cfg, cache, S, lengths, block_tables)
     tail_pos = lengths[:, None] - (K - 1) + jnp.arange(K - 1)[None]     # [B, K-1]
 
-    pages, rings, ssm, conv = cache[:4]
+    pages, rings, ssm, conv = cache.pages, cache.rings, cache.ssm, cache.conv
     x = p["embed"][tokens].astype(jnp.float32)   # the residual stream: float32
     memory = shared = None
     mamba_i = window_i = 0
@@ -592,16 +608,17 @@ def _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
                 shared = (k, v)
         x = x + out
         x = x + _mlp(_layer_norm(x, lp["mlp_norm"], cfg), lp["mlp"], cfg.dtype)
-    return _hybrid_head(x[:, 0], p, cfg), HybridCache(pages, rings, ssm, conv)
+    return _hybrid_head(x[:, 0], p, cfg), Cache(
+        pages=pages, rings=rings, ssm=ssm, conv=conv)
 
 
 def _hybrid_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
     P, W = cache.pages.shape[2], cfg.window
     # one work list for the full layer and every cross layer, one for the rings
-    slot, positions, page, offset, work, ring_work = _decode_index(
+    slot, positions, page, offset, work, ring_work, _ = _decode_index(
         cfg, cache, seq_lens, block_tables, active)
 
-    pages, rings, ssm, conv = cache[:4]
+    pages, rings, ssm, conv = cache.pages, cache.rings, cache.ssm, cache.conv
     x = p["embed"][last_tokens].astype(jnp.float32)        # [B, d]
     memory = None
     mamba_i = window_i = 0
@@ -642,53 +659,88 @@ def _hybrid_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
             out = _diff_out(o, m, i, cfg)
         x = x + out
         x = x + _mlp(_layer_norm(x, lp["mlp_norm"], cfg), lp["mlp"], cfg.dtype)
-    return _hybrid_head(x, p, cfg), HybridCache(pages, rings, ssm, conv)
+    return _hybrid_head(x, p, cfg), Cache(
+        pages=pages, rings=rings, ssm=ssm, conv=conv)
 
 
 # ---------------------------------------------------------------------------
-# an RMSNorm block with layer_kinds (models/transformer.py:Block with a kind):
-# plain grouped-query heads, rotated or not by kind, pages for each "full"
-# layer, a ring for each "window" layer and conv_taps - 1 rows a slot for each
-# "conv" layer (no heads at all); with the per-head q/k norm, the attention
-# gate, the sandwich norms and the experts its config asks for. The
-# residual stream is float32, as the hybrid's: a normalised sublayer adds a
-# whole unit to it, which bfloat16 would round at every layer
+# the RMSNorm block (models/transformer.py:Block), every model's but the
+# decoder-hybrid-decoder's: ONE per-layer composition over the layer's KIND.
+# "dense" (keys and values by head, by layer number) or "latent" (one row a
+# position) in every layer of a model without layer_kinds; with them, plain
+# grouped-query heads, rotated or not by kind, pages for each "full" layer, a
+# ring for each "window" layer and conv_taps - 1 rows a slot for each "conv"
+# layer (no heads at all); with the per-head q/k norm, the attention gate,
+# the sandwich norms and the experts the config asks for. ``_embed`` decides
+# the residual stream's type
 # ---------------------------------------------------------------------------
 
 
-def _kind_attn_inputs(x, lp, cfg, positions, kind):
-    """The float32 stream x [B, S, D] -> the layer's normalised input h, q
-    [B, S, H, hd] and the cache row ``k | v`` [B, S, 2 KVH hd]."""
-    h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps).astype(cfg.dtype)
+def _kinds(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """Each layer's kind as this module keeps its state. A model without
+    ``layer_kinds`` is ``n_layers`` layers of ONE kind; not "full": that is
+    ``k | v`` rows in ``pages`` under the paged kernel, here."""
+    return cfg.layer_kinds or (
+        ("latent" if cfg.kv_latent_rank else "dense",) * cfg.n_layers)
+
+
+def _embed(p, cfg, tokens):
+    """The residual stream at its start, of tokens [R, S] or a step's [B] (->
+    [B, 1, D]), and with it the stream's type for the whole block. With
+    ``layer_kinds`` float32 (each layer's products take and give
+    ``cfg.dtype``): a normalised sublayer adds a whole unit to it, which
+    bfloat16 would round at every layer. Without, ``cfg.dtype``, the table
+    cast before it is read."""
+    table = p["embed"] if cfg.layer_kinds else p["embed"].astype(cfg.dtype)
+    x = table[tokens if tokens.ndim == 2 else tokens[:, None]]
+    return x.astype(jnp.float32) * cfg.embed_scale if cfg.layer_kinds else x
+
+
+def _normed(x, norm, cfg):
+    """The stream under an RMSNorm, in the products' type."""
+    return _rmsnorm(x, norm["scale"], cfg.norm_eps).astype(cfg.dtype)
+
+
+def _attn_inputs(x, lp, cfg, positions, kind):
+    """The stream x [B, S, D] -> the layer's normalised input h, its queries
+    and what it keeps of these positions. "full", "window": q [B, S, H, hd]
+    and the cache row ``k | v`` [B, S, 2 KVH hd]; "dense": q and (k, v) by
+    head; "latent": (q_nope, q_pe, c, k_pe) and the row ``c | k_pe | 0``."""
+    h = _normed(x, lp["attn_norm"], cfg)
+    if kind == "latent":
+        *q, row = _latent_qkv(h, lp["attn"], cfg, positions)
+        return h, q, row
+    if kind == "dense":
+        q, k, v = _qkv(h, lp["attn"], cfg, positions)
+        return h, q, (k, v)
     q, k, v = _qkv(h, lp["attn"], cfg, positions, kind in cfg.rope_kinds)
     flat = lambda t: t.reshape(*t.shape[:-2], -1)   # noqa: E731
     return h, q, jnp.concatenate([flat(k), flat(v)], axis=-1)
 
 
-def _kind_attn_out(h, o, lp, cfg):
+def _attn_out(h, o, lp, cfg):
     """The attention ``o`` [B, S, H, hd] of a layer with input ``h`` -> what
-    the mixer adds to the stream, float32: the gate, then o_proj."""
+    the mixer adds to the stream: the gate, then o_proj."""
     a = lp["attn"]
     if cfg.attn_gate:
         with jax.named_scope("attn.gate"):
             o = o * jax.nn.sigmoid(jnp.einsum(
                 "...d,dhk->...hk", h, a["gate_proj"]["kernel"].astype(cfg.dtype)))
     return jnp.einsum("...hk,hkd->...d", o,
-                      a["o_proj"]["kernel"].astype(cfg.dtype)
-                      ).astype(jnp.float32)
+                      a["o_proj"]["kernel"].astype(cfg.dtype))
 
 
 def _conv_gates(x, lp, cfg):
     """A "conv" layer's ``in_proj`` on the normalised stream x [.., D]: the
     gated input ``s = B * z`` that is convolved (and kept) and the gate ``C``
     on the convolution's output."""
-    h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps).astype(cfg.dtype)
+    h = _normed(x, lp["attn_norm"], cfg)
     b, c, z = jnp.split(_dense(h, lp["conv"]["in_proj"], cfg.dtype), 3, axis=-1)
     return b * z, c
 
 
 def _conv_out(c, y, lp, cfg):
-    return _dense(c * y, lp["conv"]["out_proj"], cfg.dtype).astype(jnp.float32)
+    return _dense(c * y, lp["conv"]["out_proj"], cfg.dtype)
 
 
 def _conv_prefill(s, lp, cfg, conv, layer, slots, lengths):
@@ -720,40 +772,49 @@ def _conv_step(s, lp, cfg, conv, layer, keep=None):
         return y[:, None], conv.at[layer].set(rows)
 
 
-def _kind_block_rest(x, o, lp, cfg, valid, name):
-    """The block after its mixer's output ``o`` [.., D] float32: the
-    residual (a norm on the way out under ``sandwich_norm``), the MLP or the
-    experts likewise. Returns (x, load)."""
-    f32 = jnp.float32
+def _block_rest(x, o, lp, cfg, valid, name):
+    """The block after its mixer's output ``o`` [.., D], in the stream's
+    type: the residual (a norm on the way out under ``sandwich_norm``), the
+    MLP or the experts likewise. Returns (x, load)."""
+    o = o.astype(x.dtype)
     if cfg.sandwich_norm:
         o = _rmsnorm(o, lp["post_attn_norm"]["scale"], cfg.norm_eps)
     x = x + o
-    m = _rmsnorm(x, lp["mlp_norm"]["scale"], cfg.norm_eps).astype(cfg.dtype)
-    y, load = _ffn(m, lp, cfg, valid, name)
-    y = y.astype(f32)
+    # a plain step's mask comes as the slots' [B] (``_forward``)
+    y, load = _ffn(_normed(x, lp["mlp_norm"], cfg), lp, cfg,
+                   valid[:, None] if valid.ndim == 1 else valid, name)
+    y = y.astype(x.dtype)
     if cfg.sandwich_norm:
         y = _rmsnorm(y, lp["post_mlp_norm"]["scale"], cfg.norm_eps)
     return x + y, load
 
 
-def _embed(p, cfg, tokens):
-    return p["embed"][tokens].astype(jnp.float32) * cfg.embed_scale
-
-
 def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
     """Layer ``at`` of its ``kind`` proper, over a prefill call's rows: the
     gated inputs ``row`` [R, S, D] under a "conv" layer's taps, or the queries
-    ``q`` [R, S, H, hd] over the call's own keys and values ``row`` [R, S, 2
-    KVH hd] (the flash kernel). Returns what comes out, [R, S, D] or [R, S, H,
+    ``q`` over the call's own keys and values (the flash kernel; a "latent"
+    layer's expanded to heads). Returns what comes out, [R, S, D] or [R, S, H,
     hd], and ``kept`` (the kind's pages, rings or conv rows) with the
     prompts' state written."""
     from ray_tpu.ops.attention import attention as attention_op
 
     if kind == "conv":
         return _conv_prefill(row, lp, cfg, kept, at, slots, lengths)
-    R, S = row.shape[:2]
-    rep = cfg.n_heads // cfg.n_kv_heads
     _, _, page, offset, _, ring_pos = index
+    if kind == "latent":
+        kept = kept.at[at, page, offset].set(row, mode="drop")
+        return _latent_attention_expanded(*q, lp["attn"], cfg), kept
+    rep = cfg.n_heads // cfg.n_kv_heads
+    if kind == "dense":
+        k, v = row
+        kept = (kept[0].at[at, page, offset].set(k, mode="drop"),
+                kept[1].at[at, page, offset].set(v, mode="drop"))
+        if rep != 1:
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        return attention_op(q, k, v, causal=True,
+                            impl=cfg.attention_impl), kept
+    R, S = row.shape[:2]
     if kind == "window":
         kept = _write_rings(kept, at, slots, row, ring_pos)
     else:
@@ -769,13 +830,20 @@ def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
 def _step_mixer(cfg, index, page_size, keep, op, kind, at, lp, kept, q, row):
     """Layer ``at`` of its ``kind`` proper, over a decode step's rows: the
     gated inputs ``row`` [B, 1, D] and the kept rows under the taps, or each
-    slot's new ``row`` [B, 1, 2 KVH hd] into ``kept`` and its query ``q`` [B,
-    1, H, hd] over what the slot holds there, through the kernel
-    ``<paged|window>_gqa_<op>``."""
+    slot's new row into ``kept`` and its query over what the slot holds
+    there: through the kernel ``<paged|window>_gqa_<op>``, through
+    ``mla_decode`` over the latent rows, or ("dense") over a gather of the
+    slot's every page."""
     if kind == "conv":
         return _conv_step(row, lp, cfg, kept, at, keep)
-    slot, positions, page, offset, work, ring_work = index
-    if kind == "window":
+    slot, positions, page, offset, work, ring_work, _ = index
+    if kind == "latent":
+        kept = kept.at[at, page, offset].set(row[:, 0], mode="drop")
+        o = _latent_attention_absorbed(q[0][:, 0], q[1][:, 0], kept, work, at,
+                                       lp["attn"], cfg)
+    elif kind == "dense":
+        return _gather_attention(cfg, page, offset, work, at, kept, q, *row)
+    elif kind == "window":
         # a slot past the last (beside a prompt: one that is not active) is
         # dropped, as _write_rings drops a padding row's
         kept = kept.at[at, slot, positions % cfg.window].set(row[:, 0])
@@ -787,31 +855,64 @@ def _step_mixer(cfg, index, page_size, keep, op, kind, at, lp, kept, q, row):
     return o[:, None], kept
 
 
+def _gather_attention(cfg, page, offset, work, layer, kept, q, k, v):
+    """The "dense" kind's decode step: the new keys and values k, v [B, 1,
+    KVH, HD] into ``kept`` (the 5-D k and v), every slot's pages gathered
+    straight from them, [B, Lmax, KVH, HD], and grouped-query attention over
+    that under the mask, without materializing repeated heads."""
+    block_tables, kv_mask = work
+    B, Lmax = kv_mask.shape
+    KVH, HD = kept[0].shape[3:]
+    new_k = kept[0].at[layer, page, offset].set(k[:, 0], mode="drop")
+    new_v = kept[1].at[layer, page, offset].set(v[:, 0], mode="drop")
+    k_all = new_k[layer, block_tables].reshape(B, Lmax, KVH, HD)
+    v_all = new_v[layer, block_tables].reshape(B, Lmax, KVH, HD)
+    qg = q[:, 0].reshape(B, KVH, cfg.n_heads // cfg.n_kv_heads, HD)
+    scores = jnp.einsum("bkgd,blkd->bkgl", qg, k_all,
+                        preferred_element_type=jnp.float32) * (
+                            1.0 / (HD ** 0.5))
+    scores = jnp.where(kv_mask[:, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+    attn = jnp.einsum("bkgl,blkd->bkgd", probs, v_all)
+    return attn.reshape(B, 1, cfg.n_heads, HD), (new_k, new_v)
+
+
 def rides(cfg: TransformerConfig) -> bool:
-    """Can a decode step's rows ride this model's prefill call (``prefill``'s
-    ``riders``)? Where both programs are ``_kinds_forward``."""
+    """May a decode step's rows ride this model's prefill call (``prefill``'s
+    ``riders``)? A policy: both programs of every model but the
+    decoder-hybrid-decoder are ``_forward``, the "dense" and "latent" kinds'
+    mixers have not been run with both sides yet."""
     return bool(cfg.layer_kinds) and not cfg.sambay
 
 
-def _kinds_forward(p, cfg, cache, prompt=None, step=None):
-    """The layers of an "rms" block with ``layer_kinds`` over a prefill
-    call's rows (``prompt``: tokens [R, S], lengths, block_tables, slots), over
-    a decode step's (``step``: last_tokens [B], seq_lens, block_tables,
-    active), or over both in one program: the decode rows RIDE the prefill
-    call. Everything that works row by row (norms, projections, gates, the
-    residual, the MLP or the experts, the head) runs ONCE over all the rows
-    laid end to end, [1, R S + B, D]: every held weight is read once, and the
-    experts sort both sides' rows together. Only a layer's mixer proper runs
-    a side at a time, on its own rows and its own part of the cache, the
-    prompts' first (``_prompt_mixer``: flash attention or the convolution
-    over the bucket; ``_step_mixer``: the paged kernel or the taps over the
-    kept rows): the slots a call fills and the slots that decode are
-    disjoint, so are their pages, rings and conv rows, and a slot that is not
-    active writes nothing beside a prompt (alone it computes into its own rows
-    and nobody reads them). Returns the logits of each side it was given, [R,
-    vocab] and [B, vocab] (a pair where both), and the cache; the routing it
-    leaves in ``moe_load`` is that of all its rows."""
-    kept = {"full": cache.pages, "window": cache.rings, "conv": cache.conv}
+def _forward(p, cfg, cache, prompt=None, step=None):
+    """The layers of an "rms" block over a prefill call's rows (``prompt``:
+    tokens [R, S], lengths, block_tables, slots), over a decode step's
+    (``step``: last_tokens [B], seq_lens, block_tables, active), or over both
+    in one program: the decode rows RIDE the prefill call. Everything that
+    works row by row (norms, projections, gates, the residual, the MLP or the
+    experts, the head) runs ONCE over all the rows laid end to end, [1, R S +
+    B, D]: every held weight is read once, and the experts sort both sides'
+    rows together. Only a layer's mixer proper runs a side at a time, on its
+    own rows and its own part of the cache, the prompts' first
+    (``_prompt_mixer``: flash attention or the convolution over the bucket;
+    ``_step_mixer``: the paged kernel, the gather or the taps over the kept
+    rows): the slots a call fills and the slots that decode are disjoint, so
+    are their pages, rings and conv rows, and a slot that is not active
+    writes nothing beside a prompt (alone it computes into its own rows, or
+    the scratch page, and nobody reads them). Returns the logits of each side
+    it was given, [R, vocab] and [B, vocab] (a pair where both), and the
+    cache; the routing it leaves in ``moe_load`` is that of all its rows.
+
+    A model without ``layer_kinds`` had a loop of its own in each program
+    before it took this one. What is marked ``plain`` here (a step's
+    positions expanded once, its mask once a layer, a prompt's last position
+    found after the layers) keeps those programs' text as it was, reshape
+    for reshape, as ``_embed`` keeps their stream: no arithmetic hangs on it,
+    and the next change to those programs folds it."""
+    kinds, plain = _kinds(cfg), not cfg.layer_kinds
+    kept = {"dense": (cache.k, cache.v), "latent": cache.rows,
+            "full": cache.pages, "window": cache.rings, "conv": cache.conv}
     xs, positions, valid, mixers = [], [], [], []
     if prompt is not None:
         tokens, lengths, tables, slots = prompt
@@ -825,26 +926,27 @@ def _kinds_forward(p, cfg, cache, prompt=None, step=None):
     if step is not None:
         last_tokens, seq_lens, tables, active = step
         index = _decode_index(cfg, cache, seq_lens, tables, active)
-        xs.append(_embed(p, cfg, last_tokens[:, None]))
-        positions.append(index[1][:, None])
-        valid.append(active[:, None])
+        xs.append(_embed(p, cfg, last_tokens))
+        _, at, *_, at_tables = index
+        positions.append(at_tables if plain else at[:, None])
+        valid.append(active if plain else active[:, None])
         keep = None
         if prompt is not None:
             keep = active
             index = (jnp.where(active, index[0], active.shape[0]), *index[1:])
         mixers.append(functools.partial(
-            _step_mixer, cfg, index, cache.pages.shape[2], keep,
+            _step_mixer, cfg, index, _page_size(cache), keep,
             "decode" if prompt is None else "riding"))
     shapes = [x.shape[:2] for x in xs]
     x, positions, valid = map(_end_to_end, (xs, positions, valid))
     name = "moe_gmm_decode" if prompt is None else "moe_gmm_prefill"
     loads = []
-    for i, kind in enumerate(cfg.layer_kinds):
-        lp, at = p[f"layer_{i}"], cfg.layer_kinds[:i].count(kind)
+    for i, kind in enumerate(kinds):
+        lp, at = p[f"layer_{i}"], kinds[:i].count(kind)
         if kind == "conv":
             q, (row, gate) = None, _conv_gates(x, lp, cfg)
         else:
-            h, q, row = _kind_attn_inputs(x, lp, cfg, positions, kind)
+            h, q, row = _attn_inputs(x, lp, cfg, positions, kind)
         outs = []
         for mixer, q_, row_ in zip(mixers, _apart(q, shapes),
                                    _apart(row, shapes)):
@@ -852,20 +954,24 @@ def _kinds_forward(p, cfg, cache, prompt=None, step=None):
             outs.append(o)
         o = _end_to_end(outs)
         o = _conv_out(gate, o, lp, cfg) if kind == "conv" \
-            else _kind_attn_out(h, o, lp, cfg)
-        x, load = _kind_block_rest(x, o, lp, cfg, valid, name)
+            else _attn_out(h, o, lp, cfg)
+        x, load = _block_rest(x, o, lp, cfg, valid, name)
         if load is not None:
             loads.append(load)
     sides = _apart(x, shapes)
     if prompt is not None:  # each prompt's last real position
-        sides[0] = jnp.take_along_axis(sides[0], last[..., None], axis=1)
+        at = jnp.maximum(lengths - 1, 0)[:, None, None] if plain \
+            else last[..., None]
+        sides[0] = jnp.take_along_axis(sides[0], at, axis=1)
     logits = _head(jnp.concatenate([t[:, 0] for t in sides]), p, cfg)
     if len(sides) == 2:
         R = shapes[0][0]
         logits = logits[:R], logits[R:]
-    return logits, HybridCache(
-        kept["full"], kept["window"], None, kept["conv"],
-        jnp.stack(loads) if loads else None)
+    k, v = kept["dense"]
+    return logits, Cache(
+        k=k, v=v, rows=kept["latent"], pages=kept["full"],
+        rings=kept["window"], conv=kept["conv"],
+        moe_load=jnp.stack(loads) if loads else None)
 
 
 def _end_to_end(sides):
@@ -893,17 +999,17 @@ def _apart(x, shapes):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2,))
-def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
+def prefill(params: Any, cfg: TransformerConfig, cache: Cache,
             tokens: jax.Array, lengths: jax.Array,
             block_tables: jax.Array, slots: Optional[jax.Array] = None,
             riders: Optional[Tuple[jax.Array, ...]] = None
-            ) -> Tuple[jax.Array, KVCache]:
+            ) -> Tuple[jax.Array, Cache]:
     """Run the prompt forward, write KV pages, return last-position logits.
 
     tokens: [B, S] padded with PAD after `lengths`; block_tables: [B, MP].
     Returns logits [B, vocab] at position lengths-1 and the updated cache.
     ``slots`` [B]: the slot each row fills, for a model that keeps state by
-    slot (``HybridCache``); such a model leaves its state at position
+    slot (``layer_kinds``); such a model leaves its state at position
     ``lengths - 1``, not at the padded ``S - 1``, and runs its cross-decoder
     on that position alone. The engine always gives ``slots`` for such a
     model: one row an admitted request, and for a padding row (length 0, a
@@ -922,61 +1028,14 @@ def prefill(params: Any, cfg: TransformerConfig, cache: KVCache,
     [max_num_seqs, vocab]), cache)``: what the prompts and the step would have
     computed one after the other, with every held weight read once.
     """
-    from ray_tpu.ops.attention import attention as attention_op
-
     if riders is not None and not rides(cfg):
         raise ValueError("no decode rows ride this model's prefill call")
-    p = params["params"]
-    B, S = tokens.shape
-    if cfg.layer_kinds:
-        if slots is None:
-            slots = jnp.arange(B, dtype=jnp.int32)
-        rows = (tokens, lengths, block_tables, slots)
-        if cfg.sambay:
-            return _hybrid_prefill(p, cfg, cache, *rows)
-        return _kinds_forward(p, cfg, cache, rows, riders)
-    P = cache[0].shape[2]
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    in_prompt = positions < lengths[:, None]
-    # padding tokens scatter to scratch page 0
-    page_for = jnp.take_along_axis(
-        block_tables, (positions // P).astype(jnp.int32), axis=1)
-    page = jnp.where(in_prompt, page_for, 0)
-    offset = jnp.where(in_prompt, positions % P, 0)
-
-    x = p["embed"].astype(cfg.dtype)[tokens]
-    pages = cache[:-1]  # (k, v), or the latent rows alone
-    loads = []
-    for i in range(cfg.n_layers):
-        lp = p[f"layer_{i}"]
-        h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps)
-        if cfg.kv_latent_rank:
-            *qc, row = _latent_qkv(h, lp["attn"], cfg, positions)
-            pages = (pages[0].at[i, page, offset].set(row, mode="drop"),)
-            attn = _latent_attention_expanded(*qc, lp["attn"], cfg)
-        else:
-            q, k, v = _qkv(h, lp["attn"], cfg, positions)
-            pages = (pages[0].at[i, page, offset].set(k, mode="drop"),
-                     pages[1].at[i, page, offset].set(v, mode="drop"))
-            if cfg.n_kv_heads != cfg.n_heads:
-                rep = cfg.n_heads // cfg.n_kv_heads
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
-            attn = attention_op(q, k, v, causal=True, impl=cfg.attention_impl)
-        attn = jnp.einsum("...hk,hkd->...d",
-                          attn, lp["attn"]["o_proj"]["kernel"].astype(cfg.dtype))
-        h2 = x + attn
-        y, load = _ffn(_rmsnorm(h2, lp["mlp_norm"]["scale"], cfg.norm_eps),
-                       lp, cfg, in_prompt, "moe_gmm_prefill")
-        x = h2 + y
-        if load is not None:
-            loads.append(load)
-
-    # hidden at the last prompt position only -> [B, d]
-    last = jnp.take_along_axis(
-        x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return _head(last, p, cfg), type(cache)(
-        *pages, jnp.stack(loads) if loads else None)
+    if slots is None:
+        slots = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    rows = (tokens, lengths, block_tables, slots)
+    if cfg.sambay:
+        return _hybrid_prefill(params["params"], cfg, cache, *rows)
+    return _forward(params["params"], cfg, cache, rows, riders)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -1026,83 +1085,20 @@ def _head(last, p, cfg):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2,))
-def decode_step(params: Any, cfg: TransformerConfig, cache: KVCache,
+def decode_step(params: Any, cfg: TransformerConfig, cache: Cache,
                 last_tokens: jax.Array, seq_lens: jax.Array,
                 block_tables: jax.Array, active: jax.Array
-                ) -> Tuple[jax.Array, KVCache]:
+                ) -> Tuple[jax.Array, Cache]:
     """One batched decode step over all slots: [B] tokens -> [B, vocab].
 
     Inactive slots compute garbage into scratch page 0 and reach no expert.
     The new token's KV is written at position seq_lens before attention, so
     the mask is pos <= seq_lens.
     """
-    p = params["params"]
-    if cfg.layer_kinds:
-        rows = (last_tokens, seq_lens, block_tables, active)
-        if cfg.sambay:
-            return _hybrid_decode(p, cfg, cache, *rows)
-        return _kinds_forward(p, cfg, cache, step=rows)
-    B = last_tokens.shape[0]
-    P = cache[0].shape[2]
-    MP = block_tables.shape[1]
-    Lmax = MP * P
-
-    positions = seq_lens[:, None].astype(jnp.int32)  # [B, 1]
-    cur_page = jnp.take_along_axis(block_tables, positions // P, axis=1)[:, 0]
-    page = jnp.where(active, cur_page, 0)  # [B]; inactive slots -> scratch
-    offset = jnp.where(active, seq_lens % P, 0)
-    if cfg.kv_latent_rank:
-        from ray_tpu.ops.mla import live_pages
-
-        # which pages hold live positions: one list for every layer
-        work = live_pages(seq_lens, active, block_tables, P)
-    else:
-        KVH, HD = cache.k.shape[3:]
-        G = cfg.n_heads // cfg.n_kv_heads
-        kv_mask = (jnp.arange(Lmax, dtype=jnp.int32)[None]
-                   <= seq_lens[:, None]) & active[:, None]
-        scale = 1.0 / (HD ** 0.5)
-
-    x = p["embed"].astype(cfg.dtype)[last_tokens[:, None]]  # [B, 1, d]
-    pages = cache[:-1]  # (k, v), or the latent rows alone
-    loads = []
-    for i in range(cfg.n_layers):
-        lp = p[f"layer_{i}"]
-        h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.norm_eps)
-        if cfg.kv_latent_rank:
-            q_nope, q_pe, _, _, row = _latent_qkv(h, lp["attn"], cfg, positions)
-            pages = (pages[0].at[i, page, offset].set(row[:, 0], mode="drop"),)
-            attn = _latent_attention_absorbed(
-                q_nope[:, 0], q_pe[:, 0], pages[0], work, i, lp["attn"],
-                cfg)[:, None]
-        else:
-            q, k, v = _qkv(h, lp["attn"], cfg, positions)  # q [B,1,H,hd]
-            new_k = pages[0].at[i, page, offset].set(k[:, 0], mode="drop")
-            new_v = pages[1].at[i, page, offset].set(v[:, 0], mode="drop")
-            pages = (new_k, new_v)
-            # every slot's pages, straight from the 5-D cache:
-            # [B, Lmax, KVH, HD]
-            k_all = new_k[i, block_tables].reshape(B, Lmax, KVH, HD)
-            v_all = new_v[i, block_tables].reshape(B, Lmax, KVH, HD)
-            # grouped-query attention without materializing repeated heads
-            qg = q[:, 0].reshape(B, KVH, G, HD)
-            scores = jnp.einsum("bkgd,blkd->bkgl", qg, k_all,
-                                preferred_element_type=jnp.float32) * scale
-            scores = jnp.where(kv_mask[:, None, None, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            attn = jnp.einsum("bkgl,blkd->bkgd", probs, v_all)
-            attn = attn.reshape(B, 1, cfg.n_heads, HD)
-        attn = jnp.einsum("...hk,hkd->...d",
-                          attn, lp["attn"]["o_proj"]["kernel"].astype(cfg.dtype))
-        h2 = x + attn
-        y, load = _ffn(_rmsnorm(h2, lp["mlp_norm"]["scale"], cfg.norm_eps),
-                       lp, cfg, active[:, None], "moe_gmm_decode")
-        x = h2 + y
-        if load is not None:
-            loads.append(load)
-
-    return _head(x[:, 0], p, cfg), type(cache)(
-        *pages, jnp.stack(loads) if loads else None)
+    rows = (last_tokens, seq_lens, block_tables, active)
+    if cfg.sambay:
+        return _hybrid_decode(params["params"], cfg, cache, *rows)
+    return _forward(params["params"], cfg, cache, step=rows)
 
 
 # ---------------------------------------------------------------------------

@@ -53,10 +53,8 @@ from ray_tpu.llm import model_runner
 
 logger = logging.getLogger(__name__)
 
-for _cache in (model_runner.KVCache, model_runner.LatentCache,
-               model_runner.HybridCache):
-    export.register_namedtuple_serialization(
-        _cache, serialized_name=f"ray_tpu.llm.{_cache.__name__}")
+export.register_namedtuple_serialization(
+    model_runner.Cache, serialized_name="ray_tpu.llm.Cache")
 
 # programs compiled at a time: a cold compile is 15-20 s of one core, and the
 # cores are the serving path's too

@@ -22,6 +22,11 @@ _LEAVES = {"k": (1, "page"), "v": (1, "page"), "rows": (1, "page"),
            "conv": (2, "slot")}
 
 
+def held(cache) -> set:
+    """The names of the leaves a model's cache holds (the rest are None)."""
+    return {name for name, leaf in cache._asdict().items() if leaf is not None}
+
+
 def kernels(compiled) -> collections.Counter:
     """The Pallas calls of an executable for the chip, by kernel name."""
     return collections.Counter(re.findall(

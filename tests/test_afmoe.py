@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import prefill_rows
 from benchmarks.architectures import afmoe as ref
 from benchmarks.registry import REPO, Cell
 from ray_tpu.llm import LLMConfig
@@ -98,7 +99,7 @@ def _engine_logits(eng, seqs, prompt_lens, steps, bucket=16, slots=None,
     tables = np.zeros((B, MP), np.int32)
     active = np.zeros(B, bool)
     cache = mr.init_cache(cfg, e.num_pages, e.page_size, B)
-    assert isinstance(cache, mr.HybridCache)
+    assert prefill_rows.held(cache) == {"pages", "rings", "moe_load"}
     got, page = {}, first_page
     for s, toks, n in zip(slots, seqs, prompt_lens):
         need = -(-len(toks) // e.page_size)
@@ -389,8 +390,6 @@ def test_burst_admitted_in_one_step_shares_a_prefill_call(lens, rows, bucket):
     """Requests admitted in one step are rows of ONE prefill call: each row's
     pages by its block table, its rings by its slot, and the tokens those the
     same requests generate one a step."""
-    import prefill_rows
-
     eng = _engine()
     rng = np.random.default_rng(5)
     d = prefill_rows.burst_equals_one_a_step(
@@ -403,8 +402,6 @@ def test_decode_rows_ride_a_prefill_call(engine):
     prompt's logits, the step's logits, the pages and the rings of the slot
     that decodes and of the slot that is filled again, beside a padding row
     and a slot that is not active (whose state it leaves as it found it)."""
-    import prefill_rows
-
     prefill_rows.riders_equal_a_step_after_the_call(
         engine, np.random.default_rng(7), TOL)
     with pytest.raises(ValueError, match="no decode rows ride"):
@@ -420,8 +417,6 @@ def test_riding_calls_match_reference():
     the call) the other two's, ``decode_step`` in between and after:
     every position's logits against the reference (what the chip test runs at
     the published widths)."""
-    import prefill_rows
-
     eng = _engine()
     rng = np.random.default_rng(11)
     seqs = {1: (rng.integers(0, VOCAB, 11 + 7), 11, 3),
@@ -438,8 +433,6 @@ def test_staggered_requests_ride_and_get_the_tokens_they_get_alone():
     the admitted request's prefill call (one program, one sampler call, one
     read), every request's greedy tokens are those it gets alone, and the
     routing counters move with the decode-only steps alone."""
-    import prefill_rows
-
     eng = _engine()
     rng = np.random.default_rng(8)
     d = prefill_rows.staggered_equal_alone(
@@ -463,8 +456,6 @@ def test_a_burst_beside_decoding_slots_gets_the_tokens_it_gets_alone(
     first call carries the step, the rows of its other calls land in the same
     buffer, one sampler call serves all, and every request's tokens are those
     it gets alone, whichever of its calls' programs carry."""
-    import prefill_rows
-
     eng = _engine(max_num_seqs=slots)
     rng = np.random.default_rng(12)
     d = prefill_rows.admitted_beside_decoders_equal_alone(
@@ -505,10 +496,8 @@ def test_nothing_rides_where_nothing_decodes_or_no_decode_is_asked():
 
 @pytest.mark.parametrize("model", ["afmoe", "hybrid"])
 def test_padding_row_changes_no_page_ring_row_by_slot_or_load(engine, model):
-    """Through ``_kinds_forward`` and through the decoder-hybrid-decoder's
+    """Through ``_forward`` and through the decoder-hybrid-decoder's
     ``_hybrid_prefill`` (recurrent rows by slot beside the rings)."""
-    import prefill_rows
-
     eng = engine if model == "afmoe" else _hybrid_engine()
     rng = np.random.default_rng(6)
     prefill_rows.padding_rows_write_nothing(

@@ -684,7 +684,8 @@ def test_hybrid_decode_compiles_with_the_paged_kernels(one_chip):
                               text))) == 2
     assert text.count("tpu_custom_call") == 4
     live, temp = _live(compiled)
-    held = sum(x.size * x.dtype.itemsize for x in cache[:4])
+    held = sum(x.size * x.dtype.itemsize for x in (
+        cache.pages, cache.rings, cache.ssm, cache.conv))
     print(f"hybrid decode, 8 layers, 48 slots: {live} bytes live, {temp} of "
           f"temporaries; cache {held}")
     assert cache.pages.shape == (1, 961, 512, 2560)
@@ -803,7 +804,8 @@ def test_afmoe_decode_reads_rings_and_live_pages_and_nothing_else(one_chip):
     assert cache.moe_load.shape == (4, 32) and cache.ssm is None
     assert not re.search(r"\[32,(16896|33,512),", text)
     live, temp = _live(compiled)
-    held = sum(x.size * x.dtype.itemsize for x in cache[:2])
+    held = sum(x.size * x.dtype.itemsize
+               for x in (cache.pages, cache.rings))
     print(f"afmoe decode, 32 slots: {live} bytes live, {temp} of "
           f"temporaries; cache {held}")
     assert temp < 64 << 20

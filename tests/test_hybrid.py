@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import prefill_rows
 from benchmarks.architectures import phi4flash as ref
 from benchmarks.registry import REPO, Cell
 from ray_tpu.llm import LLMConfig
@@ -90,7 +91,7 @@ def _engine_logits(eng, seqs, prompt_lens, steps, bucket=16, slots=None):
     tables = np.zeros((B, MP), np.int32)
     active = np.zeros(B, bool)
     cache = mr.init_cache(cfg, e.num_pages, e.page_size, B)
-    assert isinstance(cache, mr.HybridCache)
+    assert prefill_rows.held(cache) == {"pages", "rings", "ssm", "conv"}
     got, page = {}, 1
     for s, toks, n in zip(slots, seqs, prompt_lens):
         need = -(-len(toks) // e.page_size)
@@ -236,7 +237,8 @@ def test_prefill_state_is_the_last_real_position(engine):
     (la, a), (lb, b) = run(16), run(32)
     assert _rel(lb, la) < 1e-5
     # page 0 is scratch: the padded bucket's padding lands there
-    for x, y in zip((a.pages[:, 1:],) + a[1:4], (b.pages[:, 1:],) + b[1:4]):
+    for x, y in zip((a.pages[:, 1:], a.rings, a.ssm, a.conv),
+                    (b.pages[:, 1:], b.rings, b.ssm, b.conv)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
                                    atol=1e-5)
     assert float(jnp.abs(a.ssm[:, 1]).max()) > 0        # the slot asked for

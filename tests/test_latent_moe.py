@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import prefill_rows
 from benchmarks.architectures import deepseek_v3 as ref
 from benchmarks.registry import REPO, Cell
 from ray_tpu.llm import LLMConfig
@@ -109,7 +110,7 @@ def _engine_logits(eng, seqs, prompt_lens, steps):
         lens[s] = n
         active[s] = True
     cache = mr.init_cache(cfg, e.num_pages, e.page_size)
-    assert isinstance(cache, mr.LatentCache)
+    assert prefill_rows.held(cache) == {"rows", "moe_load"}
     logits, cache = mr.prefill(eng.params, cfg, cache, jnp.asarray(batch),
                                jnp.asarray(lens), jnp.asarray(tables))
     got = {s: [np.asarray(logits[s])] for s in range(len(seqs))}
@@ -401,7 +402,7 @@ def test_config_defaults_select_nothing_new():
     moe = dataclasses.replace(cfg, n_experts=4, moe_every=2, n_layers=4)
     assert [moe.is_moe_layer(i) for i in range(4)] == [True, False, True, False]
     assert not any(cfg.is_moe_layer(i) for i in range(8))
-    assert isinstance(mr.init_cache(cfg, 3, 8), mr.KVCache)
+    assert prefill_rows.held(mr.init_cache(cfg, 3, 8)) == {"k", "v"}
 
 
 # -- the engine's accounting ------------------------------------------------------------
@@ -433,8 +434,6 @@ def test_burst_admitted_in_one_step_shares_a_prefill_call(lens, rows, bucket):
     latent rows written through its own block table, its tokens' experts
     chosen row by row: the tokens are those the same requests generate one a
     step."""
-    import prefill_rows
-
     eng = _engine()
     rng = np.random.default_rng(5)
     d = prefill_rows.burst_equals_one_a_step(
@@ -443,8 +442,6 @@ def test_burst_admitted_in_one_step_shares_a_prefill_call(lens, rows, bucket):
 
 
 def test_padding_row_changes_no_latent_row_or_load(engine):
-    import prefill_rows
-
     rng = np.random.default_rng(6)
     prefill_rows.padding_rows_write_nothing(
         engine, rng.integers(0, VOCAB, 7).tolist())

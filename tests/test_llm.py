@@ -116,7 +116,7 @@ def test_cache_rows_land_at_their_pages_and_nothing_else_moves(n_kv_heads):
     fed = rng.integers(1, cfg.vocab_size, (4, S + steps)).astype(np.int32)
     shape = (cfg.n_layers, NP, P, n_kv_heads, cfg.head_dim)
     before = [rng.standard_normal(shape).astype(np.float32) for _ in "kv"]
-    cache = mr.KVCache(*(jnp.asarray(a) for a in before))
+    cache = mr.Cache(*(jnp.asarray(a) for a in before))  # k, v
 
     prompt = np.where(np.arange(S)[None] < lengths[:, None], fed[:, :S], 0)
     _, cache = mr.prefill(params, cfg, cache, jnp.asarray(prompt),
@@ -132,7 +132,10 @@ def test_cache_rows_land_at_their_pages_and_nothing_else_moves(n_kv_heads):
             seqs[b].append(last[b])
     assert [len(s) for s in seqs] == [10, 7, 12, 0]  # 7 and 12 crossed a page
 
-    got = [np.asarray(a) for a in cache]
+    import prefill_rows
+
+    assert prefill_rows.held(cache) == {"k", "v"}
+    got = [np.asarray(cache.k), np.asarray(cache.v)]
     untouched = np.ones(shape[:3], bool)
     untouched[:, 0] = False  # scratch page: masked writes land there
     for b in np.flatnonzero(active):

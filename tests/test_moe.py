@@ -241,7 +241,7 @@ def test_a_spoiled_reference_fails(engine, run, spoil):
 
 
 def test_the_programs_report_what_routing_did(run):
-    """``KVCache.moe_load``: per expert layer, the real rows of each expert;
+    """``Cache.moe_load``: per expert layer, the real rows of each expert;
     padding and the inactive slot are not in it."""
     _, _, loads = run
     assert loads[0].shape == (2, E)

@@ -66,26 +66,35 @@ def test_fifo_within_class():
 
 def test_priority_upgrade():
     """A queued background pull upgraded by a hot requester is admitted
-    ahead of mid-priority arrivals."""
+    ahead of mid-priority arrivals. Ordered by what the queue holds, not by
+    sleeps: the slot is held until the upgrade has been made, and the upgrade
+    is made once both others are parked behind it."""
 
     async def main():
         q = PullQueue(slots=1)
         order = []
+        holding, upgraded = asyncio.Event(), asyncio.Event()
 
-        async def pull(oid, prio):
+        async def pull(oid, prio, until=None):
             q.request(oid, prio)
             assert await q.admit(oid)
             order.append(oid)
-            await asyncio.sleep(0.02)
+            if until is not None:
+                holding.set()
+                await until.wait()
             q.release(oid)
 
-        hold = asyncio.ensure_future(pull(b"hold", PRIO_ARG))
-        await asyncio.sleep(0.01)
+        hold = asyncio.ensure_future(pull(b"hold", PRIO_ARG, until=upgraded))
+        await holding.wait()  # occupies the slot
         bg = asyncio.ensure_future(pull(b"bg", PRIO_BACKGROUND))
         mid = asyncio.ensure_future(pull(b"mid", PRIO_ARG))
-        await asyncio.sleep(0.01)
+        while q.stats()["queued_by_prio"] != {PRIO_BACKGROUND: 1, PRIO_ARG: 1}:
+            await asyncio.sleep(0)  # until both wait for the slot
+        assert q.stats()["in_flight"] == 1 and order == [b"hold"]
         q.request(b"bg", PRIO_GET)  # upgrade: a get now needs it
-        await asyncio.gather(hold, bg, mid)
+        assert q.stats()["queued_by_prio"] == {PRIO_GET: 1, PRIO_ARG: 1}
+        upgraded.set()
+        await asyncio.wait_for(asyncio.gather(hold, bg, mid), 30)
         assert order == [b"hold", b"bg", b"mid"], order
 
     _run(main())

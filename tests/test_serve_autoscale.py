@@ -394,8 +394,19 @@ def test_prefix_routing_policy_sticks_and_survives_scaling(cluster):
 
     handle = serve.run(KV.bind(), name="kv").options(
         routing_policy="prefix")
+    # the state the spread below means: the handle routes over BOTH replicas
+    # (a handle that has seen one yet sends every key there), and the keys
+    # are enough that a ring of random replica ids cannot put them all on
+    # one (six keys did, one run in 32)
+    deadline = time.monotonic() + 120.0
+    while time.monotonic() < deadline:
+        handle._refresh(force=True)
+        if len(handle._replicas) == 2:
+            break
+        time.sleep(0.2)
+    assert len(handle._replicas) == 2
     prompts = [{"prompt": f"conversation-{i}: tell me more"}
-               for i in range(6)]
+               for i in range(24)]
     first = [ray_tpu.get(handle.remote(p), timeout=120) for p in prompts]
     for _ in range(3):  # repeats stay on their replica
         again = [ray_tpu.get(handle.remote(p), timeout=60)
