@@ -63,14 +63,18 @@ class EngineConfig:
     # checked against the model as expect_experts is, and for its reason
     expect_latent_rank: int = 0
     # layers with recurrent state (a decoder-hybrid-decoder's Mamba layers, a
-    # model's gated short convolutions; 0: none, every layer keeps pages or a
-    # ring), checked the same way: such a model keeps rows by slot beside its
+    # model's gated short convolutions or Mamba-2 layers; 0: none, every layer
+    # keeps pages or a ring), checked the same way: such a model keeps rows by slot beside its
     # pages
     expect_state_layers: int = 0
     # taps of the model's gated short convolutions (0: it has none): such a
     # layer keeps ``taps - 1`` rows a slot, so like expect_latent_rank this is
     # the size of what the cache holds, checked against the model the same way
     expect_conv_taps: int = 0
+    # heads of the model's Mamba-2 layers (0: it has none): such a layer
+    # keeps a matrix state a head and slot, the largest thing the cache holds
+    # a slot, checked against the model the same way
+    expect_ssm_heads: int = 0
 
     def __post_init__(self):
         if self.max_model_len % self.page_size:

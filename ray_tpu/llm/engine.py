@@ -178,7 +178,11 @@ from ``__init__``, so two snapshots subtract):
   (filled ring entries the active slots attend over, a step and window layer)
   and, for a decoder-hybrid-decoder alone, ``prefill_cross_rows`` (rows the
   cross-decoder computed in prefill: one a row of a call, against
-  ``prefill_batch_tokens`` for the self-decoder).
+  ``prefill_batch_tokens`` for the self-decoder). For a model with Mamba-2
+  layers (``"mamba2"``), per decode step (riding ones too) and such layer:
+  ``ssd_step_slots`` (the slots whose state the step reads and writes: all
+  of them, ``ops/ssd.py:ssd_step`` walks every slot) and
+  ``ssd_step_live_slots`` (those of them that decode).
 
 Such a model's rings and rows need no allocator: a slot owns its own, a
 prefill call overwrites all of them from the prompt (the engine tells it the
@@ -199,7 +203,9 @@ branch), ``.decode_dispatch`` (arguments ``overlapped``: 1 if an earlier step
 is unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
 per layer in the newest decode step the host has read, and ``held``: that
 step's assignments per layer to experts held here, models with experts only; ``live_tokens``: positions the step attends over through the block
-tables, models with a latent cache or with ``layer_kinds`` only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
+tables, models with a latent cache or with ``layer_kinds`` only;
+``state_slots``: the decoding slots, whose matrix states the step moves, models
+with Mamba-2 layers only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
 every sampler call of the step before. ``.readback`` names what it waited
 for (arguments ``kind``: ``prefill`` or ``decode``; ``calls``: the prefill
 calls behind it, 1 for a decode step; ``bucket``: the largest of those
@@ -402,7 +408,9 @@ class JaxLLMEngine:
                 f"{self.ecfg.expect_latent_rank}, the model has "
                 f"{self.mcfg.kv_latent_rank}")
         kinds = self.mcfg.layer_kinds
-        state_layers = kinds.count("mamba") + kinds.count("conv")
+        self._ssd_layers = kinds.count("mamba2")
+        state_layers = (kinds.count("mamba") + kinds.count("conv")
+                        + self._ssd_layers)
         if state_layers != self.ecfg.expect_state_layers:
             raise ValueError(
                 f"the deployment expects {self.ecfg.expect_state_layers} "
@@ -412,6 +420,11 @@ class JaxLLMEngine:
                 f"the deployment expects short convolutions of "
                 f"{self.ecfg.expect_conv_taps} taps, the model's have "
                 f"{self.mcfg.conv_taps}")
+        if self.mcfg.ssm_heads != self.ecfg.expect_ssm_heads:
+            raise ValueError(
+                f"the deployment expects Mamba-2 layers of "
+                f"{self.ecfg.expect_ssm_heads} heads, the model's have "
+                f"{self.mcfg.ssm_heads}")
         self.tokenizer = get_tokenizer(config.tokenizer)
         self._mr = model_runner
         self._jax = jax
@@ -505,7 +518,8 @@ class JaxLLMEngine:
             "moe_decode_routed_assignments": 0,
             "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0,
             "shared_kv_live_tokens": 0, "shared_kv_read_tokens": 0,
-            "window_live_tokens": 0, "prefill_cross_rows": 0}
+            "window_live_tokens": 0, "prefill_cross_rows": 0,
+            "ssd_step_slots": 0, "ssd_step_live_slots": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
 
@@ -867,6 +881,8 @@ class JaxLLMEngine:
                 # positions the step attends over, through the block tables
                 attrs["live_tokens"] = int(
                     (self._seq_lens[self._active] + 1).sum())
+            if self._ssd_layers:  # live slots whose state the step moves
+                attrs["state_slots"] = int(self._active.sum())
             load = None
             with self._phase("decode_dispatch", **attrs):
                 self._grow_pages()
@@ -991,6 +1007,11 @@ class JaxLLMEngine:
             self._count_paged_reads("shared_kv")
             self.metrics["window_live_tokens"] += int(np.minimum(
                 self._seq_lens[self._active] + 1, self.mcfg.window).sum())
+            # a step moves every slot's state in each Mamba-2 layer
+            self.metrics["ssd_step_slots"] += (
+                self._ssd_layers * len(self._slots))
+            self.metrics["ssd_step_live_slots"] += (
+                self._ssd_layers * int(self._active.sum()))
 
     def _sent(self, tokens, reqs: List[_Request], kind: str, calls: int,
               bucket: int = 0, moe_load=None) -> None:

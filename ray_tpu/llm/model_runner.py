@@ -23,7 +23,7 @@ traffic is the rows it writes, not the cache.
 ONE per-layer composition (``_forward``) is both programs of every model but
 the decoder-hybrid-decoder: a prompt side, a step side, or both. It walks the
 layers' kinds (``_kinds``), asks the kind for its inputs (``_attn_inputs``,
-``_conv_gates``), hands each side's rows to the kind's mixer
+``_conv_gates``, ``_mamba2_inputs``), hands each side's rows to the kind's mixer
 (``_prompt_mixer``, ``_step_mixer``) and runs the residual, the norms, the MLP
 or the experts and the head once over all rows, so ``prefill`` can carry a
 decode step's rows beside its prompts (``riders``; ``rides`` says for which
@@ -58,15 +58,31 @@ the step. The kinds, and where each keeps what:
   slot's whole length. Prefill convolves the bucket and leaves the conv rows
   of positions ``lengths - conv_taps + 1 .. lengths - 1`` (zeros where the
   prompt is shorter than that; padding behind the prompt never enters them);
-  a decode step convolves the rows with the new input and shifts them by one.
+  a decode step convolves the rows with the new input and shifts them by one;
+- "mamba2" of such a model (a Mamba-2 mixer, ``models/transformer.py:Mamba2``):
+  per layer and slot in ``ssm`` the matrix state of every head, float32, laid
+  ``[state, heads x head size]`` as ``ops/ssd.py`` keeps it (4.19 MB a slot
+  and layer at 128 heads of 64 and a state of 128: the largest thing a slot
+  holds), and in ``conv`` the last ``ssm_conv - 1`` rows of the convolution's
+  input ``x | B | C``. Prefill runs the chunked scan over the bucket
+  (``ssd_scan``, padding passed over with ``dt = 0``) and WRITES the slot's
+  state and tail from the prompt alone, which is how a slot is reset at
+  admission, reused, or given back to a preempted request; a decode step
+  convolves the tail with the new input, steps every slot's state once, in
+  place (``ssd_step``; beside a prompt ``ssd_riding`` with ``keep``), and
+  shifts the tail. One product (``in_proj``) makes ``z | xBC | dt`` for all
+  rows, the gated norm and ``out_proj`` run once over all rows.
 
 ``prefill`` is told the slot a row fills (``slots``), overwrites the slot's
 rings and rows from the prompt alone (which is how a slot is reset at
 admission and how a preempted request comes back) and leaves them at position
 ``lengths - 1``. Whatever of a per-head q/k norm, an attention gate, sandwich
-norms, a scaled embedding and experts (all of them, or the share
+norms, a scaled embedding, a multiplier on what a sublayer adds to the stream
+(``residual_scale``), a softmax scale of its own (``attn_scale``: the queries
+are scaled before the kernels, which divide by sqrt(head_dim)), a multiplier on
+the logits (``logit_scale``) and experts (all of them, or the share
 ``experts_held`` of an expert-parallel rank) the config asks for is a field
-the block reads.
+the block reads; at 1.0 / 0 the multipliers trace nothing.
 
 The decoder-hybrid-decoder ("sambay": Mamba layers, window and full
 DIFFERENTIAL attention, gated memory units, cross layers; LayerNorm, no
@@ -126,7 +142,11 @@ class Cache(NamedTuple):
     where [.., inner, N] would pad 16 lanes to 128) and the convolution's last
     ``ssm_conv - 1`` inputs. ``conv`` alone, ``ssm`` None: per "conv" layer (a
     gated short convolution) and slot the last ``conv_taps - 1`` gated inputs
-    ``B * z``, oldest first, ``d_model`` wide. ``moe_load``: for a model with
+    ``B * z``, oldest first, ``d_model`` wide. ``ssm`` and ``conv`` of a model
+    with "mamba2" layers: per such layer and slot every head's matrix state,
+    float32, [N, heads x head size] (``ops/ssd.py``'s layout: the channels
+    along the lanes), and the last ``ssm_conv - 1`` rows of the convolution's
+    input ``x | B | C``, ``ssm_inner + 2 ssm_state`` wide. ``moe_load``: for a model with
     experts, what the call's routing did. Rings and rows by slot belong to a
     SLOT: prefill overwrites all of a slot's from the prompt alone, which is
     also how a slot is reset at admission; a slot that is not active computes
@@ -137,8 +157,8 @@ class Cache(NamedTuple):
     pages: Optional[jax.Array] = None  # [full layers, NP, P, 2 KVH hd]
     rings: Optional[jax.Array] = None  # [window layers, B, window, 2 KVH hd]
     ssm: Optional[jax.Array] = None   # [mamba layers, B, N, inner] float32
-    # [mamba layers, ssm_conv - 1, B, inner] or [conv layers, conv_taps - 1,
-    # B, d_model]
+    # [mamba layers, ssm_conv - 1, B, inner (+ 2 N: "mamba2")] or [conv
+    # layers, conv_taps - 1, B, d_model]
     conv: Optional[jax.Array] = None
     moe_load: Optional[jax.Array] = None  # [expert layers, E] int32
 
@@ -178,10 +198,12 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
                              "recurrent rows by slot: init_cache needs "
                              "max_num_seqs")
         kinds, row = cfg.layer_kinds, 2 * cfg.n_kv_heads * cfg.head_dim
-        window, mamba = kinds.count("window"), kinds.count("mamba")
+        window = kinds.count("window")
+        mamba = kinds.count("mamba") + kinds.count("mamba2")
         rows = None
-        if mamba:
-            rows = (mamba, cfg.ssm_conv - 1, max_num_seqs, cfg.ssm_inner)
+        if mamba:  # a "mamba2" layer convolves x | B | C, a "mamba" layer x
+            rows = (mamba, cfg.ssm_conv - 1, max_num_seqs, cfg.ssm_inner
+                    + ("mamba2" in kinds) * 2 * cfg.ssm_state)
         elif "conv" in kinds:
             rows = (kinds.count("conv"), cfg.conv_taps - 1, max_num_seqs,
                     cfg.d_model)
@@ -261,6 +283,8 @@ def _qkv(x, p, cfg, positions, rotate=True):
     if rotate:
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
+    if cfg.attn_scale:  # every kernel and gather divides by sqrt(head_dim)
+        q = q * jnp.asarray(cfg.attn_scale * cfg.head_dim ** 0.5, q.dtype)
     return q, k, v
 
 
@@ -669,9 +693,10 @@ def _hybrid_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
 # "dense" (keys and values by head, by layer number) or "latent" (one row a
 # position) in every layer of a model without layer_kinds; with them, plain
 # grouped-query heads, rotated or not by kind, pages for each "full" layer, a
-# ring for each "window" layer and conv_taps - 1 rows a slot for each "conv"
-# layer (no heads at all); with the per-head q/k norm, the attention gate,
-# the sandwich norms and the experts the config asks for. ``_embed`` decides
+# ring for each "window" layer, conv_taps - 1 rows a slot for each "conv"
+# layer (no heads at all) and a matrix state with a convolution tail a slot
+# for each "mamba2" layer; with the per-head q/k norm, the attention gate,
+# the sandwich norms, the multipliers and the experts the config asks for. ``_embed`` decides
 # the residual stream's type
 # ---------------------------------------------------------------------------
 
@@ -772,6 +797,92 @@ def _conv_step(s, lp, cfg, conv, layer, keep=None):
         return y[:, None], conv.at[layer].set(rows)
 
 
+def _mamba2_inputs(x, lp, cfg):
+    """A "mamba2" layer's ``in_proj`` on the normalised stream x [.., D], ONE
+    product for all rows: the step sizes before their bias ``dt`` [.., H],
+    the convolution's input ``x | B | C`` (what a slot keeps the tail of) and
+    the gate ``z`` on the recurrence's output."""
+    h = _normed(x, lp["attn_norm"], cfg)
+    z, xbc, dt = jnp.split(
+        _dense(h, lp["mamba"]["in_proj"], cfg.dtype),
+        [cfg.ssm_inner, 2 * cfg.ssm_inner + 2 * cfg.ssm_state], axis=-1)
+    return dt, (xbc, z)
+
+
+def _mamba2_operands(a, dt, m, cfg):
+    """The convolved input a [.., I + 2N] and the raw step sizes dt [.., H]
+    -> what the recurrence takes: (dt after bias and softplus in float32, x,
+    B, C, A [H])."""
+    a = jax.nn.silu(a)
+    x, Bm, Cm = jnp.split(a, [cfg.ssm_inner, cfg.ssm_inner + cfg.ssm_state],
+                          axis=-1)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + m["dt_bias"])
+    return dt, x, Bm, Cm, -jnp.exp(m["A_log"])
+
+
+def _mamba2_skip(y, x, m, cfg):
+    """y [.., I] float32 with the skip ``D[h] x`` of each head."""
+    return y + jnp.repeat(m["D"], cfg.ssm_inner // cfg.ssm_heads) \
+        * x.astype(jnp.float32)
+
+
+def _mamba2_prefill(xbc, dt, lp, cfg, kept, layer, slots, lengths, in_prompt):
+    """A "mamba2" layer's recurrence over a prefill call's rows (xbc [R, S, I
+    + 2N], dt [R, S, H]) from a zero state, and ``kept`` (the scan's states,
+    the convolution's tails) with the rows of ``slots`` left at the prompts'
+    last position: the state after it and the ``ssm_conv - 1`` inputs before
+    the next (zeros where the prompt has none). Padding behind a prompt
+    neither advances the state (``dt = 0``) nor enters the tail."""
+    from ray_tpu.models.transformer import causal_conv
+    from ray_tpu.ops.ssd import ssd_scan
+
+    ssm, conv = kept
+    m, tail = lp["mamba"], cfg.ssm_conv - 1
+    tail_pos = lengths[:, None] - tail + jnp.arange(tail)[None]
+    with jax.named_scope("ssd.prefill"):
+        dt, x, Bm, Cm, A = _mamba2_operands(causal_conv(
+            xbc, m["conv_kernel"].astype(cfg.dtype),
+            m["conv_bias"].astype(cfg.dtype)), dt, m, cfg)
+        y, state = ssd_scan(jnp.where(in_prompt[..., None], dt, 0.0), x, Bm,
+                            Cm, A)
+        # [layer, tap, slot]: the indexed axes come first, [R, K-1, I + 2N]
+        return _mamba2_skip(y, x, m, cfg), (
+            ssm.at[layer, slots].set(state),
+            conv.at[layer, :, slots].set(_rows_at(xbc, tail_pos)))
+
+
+def _mamba2_step(xbc, dt, lp, cfg, kept, layer, keep, op):
+    """A "mamba2" layer's recurrence over a decode step's rows (xbc [B, 1, I
+    + 2N], dt [B, 1, H]): the taps over the kept tail and the new input, one
+    step of every slot's state in place (``ops/ssd.py:ssd_step``), the tail
+    shifted by one; with ``keep`` [B] only the slots it marks move."""
+    from ray_tpu.ops.ssd import ssd_step
+
+    ssm, conv = kept
+    m = lp["mamba"]
+    with jax.named_scope("ssd.step"):
+        taps = jnp.concatenate([conv[layer], xbc[:, 0][None]], axis=0)
+        dt, x, Bm, Cm, A = _mamba2_operands(
+            jnp.einsum("kbc,kc->bc", taps, m["conv_kernel"].astype(cfg.dtype))
+            + m["conv_bias"].astype(cfg.dtype), dt[:, 0], m, cfg)
+        y, ssm = ssd_step(ssm, layer, dt, x, Bm, Cm, A, keep,
+                          name="ssd_step" if op == "decode" else "ssd_" + op)
+        rows = taps[1:]
+        if keep is not None:
+            rows = jnp.where(keep[None, :, None], rows, conv[layer])
+        return _mamba2_skip(y, x, m, cfg)[:, None], (ssm, conv.at[layer].set(rows))
+
+
+def _mamba2_out(z, y, lp, cfg):
+    """The gated norm over all ``ssm_inner`` channels of the recurrence's y
+    [.., I] float32, then ``out_proj``."""
+    m = lp["mamba"]
+    with jax.named_scope("ssd.gate_norm"):
+        y = _rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)),
+                     m["norm"]["scale"], cfg.norm_eps).astype(cfg.dtype)
+    return _dense(y, m["out_proj"], cfg.dtype)
+
+
 def _block_rest(x, o, lp, cfg, valid, name):
     """The block after its mixer's output ``o`` [.., D], in the stream's
     type: the residual (a norm on the way out under ``sandwich_norm``), the
@@ -779,14 +890,15 @@ def _block_rest(x, o, lp, cfg, valid, name):
     o = o.astype(x.dtype)
     if cfg.sandwich_norm:
         o = _rmsnorm(o, lp["post_attn_norm"]["scale"], cfg.norm_eps)
-    x = x + o
+    r = cfg.residual_scale  # what a sublayer adds goes in times this
+    x = x + (o if r == 1.0 else o * r)
     # a plain step's mask comes as the slots' [B] (``_forward``)
     y, load = _ffn(_normed(x, lp["mlp_norm"], cfg), lp, cfg,
                    valid[:, None] if valid.ndim == 1 else valid, name)
     y = y.astype(x.dtype)
     if cfg.sandwich_norm:
         y = _rmsnorm(y, lp["post_mlp_norm"]["scale"], cfg.norm_eps)
-    return x + y, load
+    return x + (y if r == 1.0 else y * r), load
 
 
 def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
@@ -800,6 +912,9 @@ def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
 
     if kind == "conv":
         return _conv_prefill(row, lp, cfg, kept, at, slots, lengths)
+    if kind == "mamba2":
+        return _mamba2_prefill(row, q, lp, cfg, kept, at, slots, lengths,
+                               index[1])
     _, _, page, offset, _, ring_pos = index
     if kind == "latent":
         kept = kept.at[at, page, offset].set(row, mode="drop")
@@ -836,6 +951,8 @@ def _step_mixer(cfg, index, page_size, keep, op, kind, at, lp, kept, q, row):
     slot's every page."""
     if kind == "conv":
         return _conv_step(row, lp, cfg, kept, at, keep)
+    if kind == "mamba2":
+        return _mamba2_step(row, q, lp, cfg, kept, at, keep, op)
     slot, positions, page, offset, work, ring_work, _ = index
     if kind == "latent":
         kept = kept.at[at, page, offset].set(row[:, 0], mode="drop")
@@ -912,7 +1029,8 @@ def _forward(p, cfg, cache, prompt=None, step=None):
     and the next change to those programs folds it."""
     kinds, plain = _kinds(cfg), not cfg.layer_kinds
     kept = {"dense": (cache.k, cache.v), "latent": cache.rows,
-            "full": cache.pages, "window": cache.rings, "conv": cache.conv}
+            "full": cache.pages, "window": cache.rings, "conv": cache.conv,
+            "mamba2": (cache.ssm, cache.conv)}
     xs, positions, valid, mixers = [], [], [], []
     if prompt is not None:
         tokens, lengths, tables, slots = prompt
@@ -945,6 +1063,8 @@ def _forward(p, cfg, cache, prompt=None, step=None):
         lp, at = p[f"layer_{i}"], kinds[:i].count(kind)
         if kind == "conv":
             q, (row, gate) = None, _conv_gates(x, lp, cfg)
+        elif kind == "mamba2":  # "q": the step sizes, split as queries are
+            q, (row, gate) = _mamba2_inputs(x, lp, cfg)
         else:
             h, q, row = _attn_inputs(x, lp, cfg, positions, kind)
         outs = []
@@ -953,8 +1073,12 @@ def _forward(p, cfg, cache, prompt=None, step=None):
             o, kept[kind] = mixer(kind, at, lp, kept[kind], q_, row_)
             outs.append(o)
         o = _end_to_end(outs)
-        o = _conv_out(gate, o, lp, cfg) if kind == "conv" \
-            else _attn_out(h, o, lp, cfg)
+        if kind == "conv":
+            o = _conv_out(gate, o, lp, cfg)
+        elif kind == "mamba2":
+            o = _mamba2_out(gate, o, lp, cfg)
+        else:
+            o = _attn_out(h, o, lp, cfg)
         x, load = _block_rest(x, o, lp, cfg, valid, name)
         if load is not None:
             loads.append(load)
@@ -968,9 +1092,10 @@ def _forward(p, cfg, cache, prompt=None, step=None):
         R = shapes[0][0]
         logits = logits[:R], logits[R:]
     k, v = kept["dense"]
+    ssm, conv = kept["mamba2"] if "mamba2" in kinds else (None, kept["conv"])
     return logits, Cache(
         k=k, v=v, rows=kept["latent"], pages=kept["full"],
-        rings=kept["window"], conv=kept["conv"],
+        rings=kept["window"], ssm=ssm, conv=conv,
         moe_load=jnp.stack(loads) if loads else None)
 
 
@@ -1076,7 +1201,8 @@ def _head(last, p, cfg):
     else:
         logits = jnp.einsum("bd,dv->bv", last, p["lm_head"].astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
-    return logits.astype(jnp.float32)
+    logits = logits.astype(jnp.float32)
+    return logits if cfg.logit_scale == 1.0 else logits * cfg.logit_scale
 
 
 # ---------------------------------------------------------------------------

@@ -119,9 +119,13 @@ class TransformerConfig:
     # projections and a tied head (``HybridBlock``). "rms": the RMSNorm
     # block of every model without kinds (``Block``), plain grouped-query
     # heads under the causal mask ("full") or the window's ("window"); only
-    # "window", "full" and "conv" are kinds of such a block. "conv" is a gated
-    # short convolution (``ShortConv``): no attention, no position embedding,
-    # and in serving ``conv_taps - 1`` rows a slot in place of pages or a ring.
+    # "window", "full", "conv" and "mamba2" are kinds of such a block. "conv" is
+    # a gated short convolution (``ShortConv``): no attention, no position
+    # embedding, and in serving ``conv_taps - 1`` rows a slot in place of pages
+    # or a ring. "mamba2" is a Mamba-2 mixer (``Mamba2``): ``ssm_heads`` heads
+    # of ``ssm_inner / ssm_heads`` channels, a matrix state [head size,
+    # ssm_state] a head, ``ssm_conv`` taps; in serving the state and the
+    # convolution's last ``ssm_conv - 1`` inputs a slot.
     layer_kinds: Tuple[str, ...] = ()
     block: str = "sambay"
     window: int = 0
@@ -129,6 +133,9 @@ class TransformerConfig:
     ssm_state: int = 16
     ssm_conv: int = 4       # taps of the causal depthwise convolution
     ssm_dt_rank: int = 0
+    # heads of a "mamba2" layer (0: the model has none); B and C are shared by
+    # all of them (one group)
+    ssm_heads: int = 0
     # taps a channel of a "conv" layer's causal depthwise convolution (0: the
     # model has no such layer), and what its in_proj and out_proj are drawn
     # at (0 = the attention's): its output goes with their FOURTH power, the
@@ -171,6 +178,12 @@ class TransformerConfig:
     # what the routed experts' matrices are drawn at where it is not the
     # MLPs' (0 = mlp_init_std): the shared expert stays at the MLPs'
     expert_init_std: float = 0.0
+    # what a sublayer's output is multiplied by on its way into the stream,
+    # the softmax scale where it is not 1 / sqrt(head_dim) (0 = that), and
+    # what the logits are multiplied by
+    residual_scale: float = 1.0
+    attn_scale: float = 0.0
+    logit_scale: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -239,13 +252,20 @@ class TransformerConfig:
             )
         # in_proj (B | C | z), out_proj, the taps and the block's two norms
         conv = 4 * d * d + self.conv_taps * d + 2 * d
+        # in_proj (z | xBC | dt), out_proj, the taps and their bias, dt_bias,
+        # A_log and D a head, the gated norm and the block's two norms
+        inner, xbc = self.ssm_inner, self.ssm_inner + 2 * self.ssm_state
+        mamba2 = (d * (inner + xbc + self.ssm_heads) + inner * d
+                  + xbc * (self.ssm_conv + 1) + 3 * self.ssm_heads + inner
+                  + 2 * d)
+        mixer = {"conv": conv, "mamba2": mamba2}
         dense_mlp = 3 * d * (self.d_ff_dense or f)
         moe_mlp = (self.n_experts_held * 3 * d * f + d * self.n_experts
                    + 3 * d * self.n_shared_experts * f
                    + (self.n_experts if self.router_kind == "sigmoid" else 0))
         total = 0
         for i in range(self.n_layers):
-            total += conv if self.layer_kind(i) == "conv" else attn
+            total += mixer.get(self.layer_kind(i), attn)
             total += moe_mlp if self.is_moe_layer(i) else dense_mlp
         return v * d + total + d + (0 if self.tie_embeddings else d * v)
 
@@ -342,6 +362,8 @@ class Attention(nn.Module):
             rep = cfg.n_heads // cfg.n_kv_heads
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
+        if cfg.attn_scale:  # the kernels divide by sqrt(head_dim)
+            q = q * jnp.asarray(cfg.attn_scale * hd ** 0.5, q.dtype)
         out = attention_op(q, k, v, causal=True, impl=cfg.attention_impl,
                            segment_ids=segment_ids,
                            window=cfg.window if self.kind == "window" else 0)
@@ -408,6 +430,54 @@ class ShortConv(nn.Module):
             cfg.conv_taps ** -0.5), (cfg.conv_taps, d), cfg.param_dtype)
         y = c * causal_conv(b * z, w.astype(cfg.dtype), 0)
         return dense(d, ("mlp", "embed"), "out_proj")(y)
+
+
+class Mamba2(nn.Module):
+    """A "mamba2" layer's mixer: ``z | xBC | dt = in_proj(h)`` (widths
+    ``ssm_inner``, ``ssm_inner + 2 ssm_state``, ``ssm_heads``), ``xBC =
+    silu(causal_conv(xBC) + conv_bias)`` split into ``x | B | C``, ``dt =
+    softplus(dt + dt_bias)``, the recurrence of ``ops/ssd.py`` with ``A =
+    -exp(A_log)`` a head, ``y = y + D x`` a head, the gated norm ``RMSNorm(y *
+    silu(z))`` over all ``ssm_inner`` channels, ``out_proj``. No bias but the
+    convolution's, no position embedding. The TRAINING side: the whole
+    sequence at once through ``ssd_reference`` (packed sequences are not kept
+    apart); the serving engine keeps the state and the last ``ssm_conv - 1``
+    rows of the pre-convolution ``xBC`` a slot (``llm/model_runner.py``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h):
+        from ray_tpu.ops.ssd import ssd_reference
+
+        cfg = self.cfg
+        inner, N, H = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads
+        dense = lambda feats, axes, name: nn.DenseGeneral(  # noqa: E731
+            features=feats, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.normal(cfg.init_std("ssm_proj")), axes))
+        zxd = dense(2 * inner + 2 * N + H, ("embed", "mlp"), "in_proj")(h)
+        z, xbc, dt = jnp.split(zxd, [inner, 2 * inner + 2 * N], axis=-1)
+        w = self.param("conv_kernel", nn.initializers.normal(
+            cfg.ssm_conv ** -0.5), (cfg.ssm_conv, inner + 2 * N),
+            cfg.param_dtype)
+        b = self.param("conv_bias", nn.initializers.normal(CONV_BIAS_STD),
+                       (inner + 2 * N,), cfg.param_dtype)
+        xbc = nn.silu(causal_conv(xbc, w.astype(cfg.dtype),
+                                  b.astype(cfg.dtype)))
+        x, Bm, Cm = jnp.split(xbc, [inner, inner + N], axis=-1)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (H,), jnp.float32)
+        A_log = self.param("A_log", lambda key, shape: jnp.log(
+            jax.random.uniform(key, shape, minval=1.0, maxval=16.0)), (H,))
+        D = self.param("D", nn.initializers.ones, (H,), jnp.float32)
+        y, _ = ssd_reference(
+            jax.nn.softplus(dt.astype(jnp.float32) + dt_bias), x, Bm, Cm,
+            -jnp.exp(A_log))
+        y = y + jnp.repeat(D, inner // H) * x.astype(jnp.float32)
+        y = RMSNorm(cfg.norm_eps, cfg.dtype, axis=None, name="norm")(
+            y * nn.silu(z.astype(jnp.float32)))
+        return dense(cfg.d_model, ("mlp", "embed"), "out_proj")(y)
 
 
 class MLP(nn.Module):
@@ -584,6 +654,10 @@ def layer_norm(x, scale, bias, eps):
 NORM_BIAS_STD = 0.02
 PROJ_BIAS_STD = 0.02
 LAMBDA_STD = 0.1
+# a "mamba2" layer's convolution bias: the deviation of the uniform range a
+# depthwise Conv1d of four taps is born with (+-0.5), so that it shows beside
+# a convolution's output of order one
+CONV_BIAS_STD = 0.29
 
 
 def lambda_init(layer: int) -> float:
@@ -759,15 +833,20 @@ class Block(nn.Module):
         norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)  # noqa: E731
         if self.kind == "conv":
             a = ShortConv(cfg, name="conv")(norm("attn_norm")(x))
+        elif self.kind == "mamba2":
+            a = Mamba2(cfg, name="mamba")(norm("attn_norm")(x))
         else:
             a = Attention(cfg, self.kind, name="attn")(
                 norm("attn_norm")(x), positions, segment_ids)
-        h = x + (norm("post_attn_norm")(a) if cfg.sandwich_norm else a)
+        # what a sublayer adds to the stream, under the model's multiplier
+        into = lambda t: (t if cfg.residual_scale == 1.0 else   # noqa: E731
+                          t * jnp.asarray(cfg.residual_scale, t.dtype))
+        h = x + into(norm("post_attn_norm")(a) if cfg.sandwich_norm else a)
         h = nn.with_logical_constraint(h, ("batch", "seq", "embed"))
         mlp = MoEMLP(cfg, name="moe") if self.use_moe else MLP(
             cfg, cfg.d_ff_dense, name="mlp")
         y = mlp(norm("mlp_norm")(h))
-        out = h + (norm("post_mlp_norm")(y) if cfg.sandwich_norm else y)
+        out = h + into(norm("post_mlp_norm")(y) if cfg.sandwich_norm else y)
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
@@ -816,6 +895,8 @@ class Transformer(nn.Module):
                 (cfg.d_model, cfg.vocab_size), cfg.param_dtype)
             logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype),
                                 preferred_element_type=jnp.float32)
+        if cfg.logit_scale != 1.0:
+            logits = logits * jnp.asarray(cfg.logit_scale, logits.dtype)
         return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
 
 

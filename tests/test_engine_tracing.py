@@ -47,7 +47,10 @@ KEYS = {
     # a model with layer_kinds: its shared layer's pages, rings, recurrent
     # rows and the cross-decoder's prefill rows; 0 without them
     "shared_kv_live_tokens", "shared_kv_read_tokens", "window_live_tokens",
-    "prefill_cross_rows"}
+    "prefill_cross_rows",
+    # the slots whose Mamba-2 state a decode step moves, and those of them
+    # that decode; 0 without such layers
+    "ssd_step_slots", "ssd_step_live_slots"}
 LADDER = tuple(f"itl_over_{n}ms" for n in (25, 50, 100, 200, 400, 800))
 PHASES = ("admit_ms", "prefill_dispatch_ms", "decode_dispatch_ms",
           "sample_dispatch_ms", "readback_ms", "emit_ms")
