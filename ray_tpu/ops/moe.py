@@ -14,9 +14,12 @@ experts, whatever the load; there is no capacity and nothing falls through.
    assignments sort behind every real one, lie in no group, cost no expert
    compute and are not counted in the load;
 4. the ``T x top_k`` assignments sorted by expert (static shape whatever is
-   real), the per-expert group sizes, and a grouped matmul for each of the
+   real), the rows gathered into that order (XLA's gather, at its bytes'
+   rate), and a grouped matmul by the per-expert group sizes for each of the
    three products of ``down(silu(gate(x)) * up(x))``;
-5. the weighted sum over a row's experts, back in row order.
+5. the weighted float32 sum over a row's experts, back in row order: a decode
+   step's few hundred assignments by one gather of ``[T, top_k, D]``, a
+   prefill call's thousands choice by choice (``_sum_by_choice``).
 
 One rank of an expert-parallel deployment is told which experts it holds
 (``held = (first, count)``): it routes over ALL the router's outputs, as every
@@ -27,13 +30,10 @@ nothing; there is no stand-in for the other ranks and no exchange.
 
 ``grouped_matmul`` is a Pallas kernel: for each (group, row tile) pair that
 holds a real row it multiplies the tile by that group's matrix, so a step
-streams the touched experts' weights once and nothing else. ``name`` is the
-kernel's name in a device trace (the engine passes ``moe_gmm_decode`` and
-``moe_gmm_prefill``). Off the TPU the same kernel runs in interpret mode.
-
-The training module ``models/transformer.py:MoEMLP`` keeps its own one-hot
-dispatch with a capacity (ROADMAP S5); at a capacity that drops nothing it
-computes what this file computes (``tests/test_moe.py``).
+streams the touched experts' weights once and nothing else; rows no group
+owns are never written and hold whatever lay there (step 5 masks them).
+``name`` names it in a device trace (``moe_gmm_decode``, ``moe_gmm_prefill``);
+off the TPU it runs in interpret mode. Training's ``MoEMLP`` dispatches itself.
 """
 
 from __future__ import annotations
@@ -95,9 +95,9 @@ def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk_prob: bool,
 
 def _tiles(m: int, k: int, n: int, itemsize: int) -> Tuple[int, int]:
     """(rows of a tile, columns of a weight block). Rows: a decode step's
-    assignments fit one 128-row tile; above that 256, where a block's
-    product takes about as long as its 2 MiB take to arrive. Columns: the
-    whole contraction axis stays in one block, so no accumulator is kept."""
+    assignments fit one 128-row tile; above that 256, where a block's product
+    takes about as long as its 2 MiB take to arrive. Columns: the contraction
+    axis whole, no accumulator. (Which way back: ``_BY_CHOICE_MIN``, below.)"""
     sublane = 8 * 4 // itemsize
     tm = 256 if m >= 256 else -(-m // sublane) * sublane
     tn = n
@@ -223,6 +223,49 @@ def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
     # assignment -> its sorted row, then the weighted sum over a row's experts
     where = jnp.zeros(padded, jnp.int32).at[order].set(
         jnp.arange(padded, dtype=jnp.int32))[:M].reshape(T, top_k)
+    if M >= _BY_CHOICE_MIN:
+        return _sum_by_choice(out, where, weights, valid).astype(x.dtype), load
     picked = out[where].astype(jnp.float32)           # [T, top_k, D]
     y = jnp.where(valid[..., None], picked * weights[..., None], 0.0)
     return y.sum(1).astype(x.dtype), load
+
+
+# The least ``T x top_k`` whose way back is ``_sum_by_choice``. Every decode
+# step of the sparse serve cells lies under it (128, 192, 128, 512 and 400
+# assignments) and keeps the one gather above; every prefill call lies at or
+# over it (the least are 1,024: ``[1, 128]`` at top-8 and a ``[1, 128]``
+# call of 128 riding slots at top-4). The chip's sweep (TPU v5e, PERF.md
+# section 6, PR 46): at 1,024 assignments the layer takes 1.307 ms this way
+# and 1.318 the other, at 2,048 1.331 against 1.397, at 40,960 (hidden 4096,
+# top-10) 7.44 against 10.64; at 512 the two ways back are 10 and 28 us of a
+# layer of 1.1 ms, nothing a step could show, so the decode programs stay
+# what they were. (It stands here and not beside ``_tiles`` so that the lines
+# above keep their numbers: a kernel's body carries them into a program.)
+_BY_CHOICE_MIN = 1024
+
+
+@jax.jit
+def _sum_by_choice(out: jax.Array, where: jax.Array, weights: jax.Array,
+                   valid: jax.Array) -> jax.Array:
+    """out [padded, D] sorted rows, where / weights / valid [T, top_k] ->
+    [T, D] float32: ``weights[t, k] * out[where[t, k]]`` summed over a row's
+    valid choices, k ascending. The rows are gathered choice by choice,
+    ``[top_k, T, D]`` in out's dtype, which is ``[padded, D]`` as the gather
+    wrote it and the sum's operand as it lies: whole ``[T, D]`` tiles, read
+    once, upcast, weighted and added in registers. Gathered row by row,
+    ``[T, top_k, D]``, the same values are laid out again in float32 around
+    a ``top_k`` padded to sixteen sublanes before one sum reads them back: at
+    hidden 4096 and top-10 three passes and 6.2 ms where this takes 3.0, of
+    which 2.5 are the gather (a row of a tiled ``[padded, D]`` is 32 pieces
+    of 512 bytes). A choice nobody here took reads its own sorted row, past
+    every group, whatever lies there: pointing all of them at one row made
+    the gather slower (2.65 ms), and the select drops what they read.
+    Jitted so that a model's layers share one trace and one lowering of a
+    shape (as ``ops/attention.py:_flash_bwd_pair`` is): XLA inlines the call."""
+    picked = out[where.T]
+    y = None
+    for k in range(where.shape[1]):
+        term = jnp.where(valid[:, k, None], picked[k].astype(jnp.float32)
+                         * weights[:, k, None], 0.0)
+        y = term if y is None else y + term
+    return y

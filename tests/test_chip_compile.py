@@ -86,6 +86,13 @@ def _custom_calls(text):
             if "tpu_custom_call" in line]
 
 
+def _float32_rows_a_choice(text, top_k, hidden):
+    """The float32 ``[T, top_k, hidden]`` values of a compiled program: what
+    the expert layer's way back laid out before ``ops/moe.py`` summed a prefill
+    call's rows choice by choice (PR 46); a prefill program holds none."""
+    return re.findall(rf"= f32\[\d+,{top_k},{hidden}\]", text)
+
+
 def _flash_grads(q, k, v):
     from ray_tpu.ops.attention import flash_attention
 
@@ -305,6 +312,7 @@ def test_expert_layer_programs_compile_at_published_widths(one_chip):
     assert prefill.count("tpu_custom_call") == 8
     assert len(set(re.findall(r"%(moe_gmm_prefill\S*) = bf16\[16384,",
                               prefill))) == 6
+    assert not _float32_rows_a_choice(prefill, 8, 2048)
 
 
 # the dense serve cell's widths (InternLM2-1.8B) where they differ from "1b"
@@ -616,6 +624,7 @@ def test_latent_prefill_compiles_with_unequal_head_sizes(one_chip, rows,
     assert len(set(re.findall(
         rf"%(moe_gmm_prefill\S*) = bf16\[{rows * bucket * 6},", text))) == 3
     assert text.count("tpu_custom_call") == 5
+    assert not _float32_rows_a_choice(text, 6, 2048)
     live, temp = _live(compiled)
     print(f"latent prefill, 2 layers, [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")
@@ -830,6 +839,8 @@ def test_afmoe_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
     riding = len(set(re.findall(r"%((?:window|paged)_gqa_riding\S*) = ", text)))
     assert riding == (5 if (rows, bucket) == (1, 512) else 0)
     assert text.count("tpu_custom_call") == 17 + riding
+    assert len(set(re.findall(r"%(moe_gmm_prefill\S*) = bf16\[", text))) == 12
+    assert not _float32_rows_a_choice(text, 4, 3072)
     live, temp = _live(compiled)
     print(f"afmoe prefill [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")
@@ -888,6 +899,8 @@ def test_lfm2_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
     riding = len(set(re.findall(r"%(paged_gqa_riding\S*) = ", text)))
     assert riding == (3 if rows == 1 else 0)
     assert text.count("tpu_custom_call") == 39 + riding
+    assert len(set(re.findall(r"%(moe_gmm_prefill\S*) = bf16\[", text))) == 36
+    assert not _float32_rows_a_choice(text, 4, 2048)
     live, temp = _live(compiled)
     print(f"lfm2 prefill [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")
@@ -927,7 +940,7 @@ def test_granite_decode_steps_every_state_in_place(one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes >= held
 
 
-@pytest.mark.parametrize("rows,bucket", [(1, 256), (40, 256)])
+@pytest.mark.parametrize("rows,bucket", [(1, 256), (40, 256), (1, 4096)])
 def test_granite_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
     """The least bucket as the engine calls it, ``[1, 256]`` with a slot,
     CARRYING the 40 slots' decode step (``ssd_riding`` in nine layers,
@@ -935,8 +948,9 @@ def test_granite_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
     256]`` call, the largest program of the cell (10,240 rows x top-10), beside
     9.51 GB of weights and 2.29 GB of state, tails and pages: the chunked scan
     in nine layers, one flash call, thirty grouped matmuls, under the chip's
-    15.75 GiB. ``[1, 1024]`` and ``[1, 4096]`` hold 12.34 and 13.80 GB
-    (compiled once, AOT, PR 45: PERF.md section 4)."""
+    15.75 GiB; and the largest bucket, ``[1, 4096]`` (40,960 sorted rows a
+    layer; too long to carry a step), which held 13.80 GB while the way back
+    laid a float32 ``[4096, 10, 4096]`` out and holds 12.67 since PR 46."""
     _, prefill, _ = _lower_rms_kinds(one_chip, "granite-4.0-h-small")
     compiled = prefill(rows, bucket).compile()
     text = compiled.as_text()
@@ -945,8 +959,10 @@ def test_granite_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
     assert len(set(re.findall(
         rf"%(flash_fwd\S*) = \(bf16\[{rows * 32},{bucket},128\]", text))) == 1
     riding = len(set(re.findall(r"%((?:ssd|paged_gqa)_riding\S*) = ", text)))
-    assert riding == (10 if rows == 1 else 0)
+    assert riding == (10 if (rows, bucket) == (1, 256) else 0)
     assert text.count("tpu_custom_call") == 40 + riding
+    assert len(set(re.findall(r"%(moe_gmm_prefill\S*) = bf16\[", text))) == 30
+    assert not _float32_rows_a_choice(text, 10, 4096)
     live, temp = _live(compiled)
     print(f"granite prefill [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")

@@ -10,6 +10,7 @@ not admit a missing q/k norm, renormalised router weights or another eps.
 """
 
 import dataclasses
+import re
 
 import flax.linen as nn
 import jax
@@ -23,6 +24,7 @@ from ray_tpu.llm import model_runner as mr
 from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.engine import JaxLLMEngine
 from ray_tpu.models.transformer import MoEMLP, TransformerConfig
+from ray_tpu.ops import moe
 from ray_tpu.ops.moe import expert_layer, route
 
 TOL = 1e-4
@@ -37,15 +39,19 @@ def _weights(seed, scale=0.1):
             jax.random.normal(ks[3], (E, F, D)) * scale)
 
 
-def _dense_loop(x, valid, router, w_gate, w_up, w_down, norm):
+def _dense_loop(x, valid, router, w_gate, w_up, w_down, norm, first=0):
     """Every expert on every row, weighted by the row's router weight for it
-    (0 where the row did not choose it or is not valid)."""
+    (0 where the row did not choose it or is not valid), in float32 whatever
+    the inputs are. The matrices are those of experts ``first .. first +
+    len(w_gate)`` of the router's outputs: all of them unless told."""
+    f32 = lambda a: a.astype(jnp.float32)
     weights, experts = route(x, router, K, norm)
+    x = f32(x)
     y = jnp.zeros_like(x)
-    for e in range(router.shape[1]):
-        w = jnp.where(experts == e, weights, 0.0).sum(-1) * valid
-        y = y + w[:, None] * (
-            (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e])
+    for e in range(w_gate.shape[0]):
+        w = jnp.where(experts == first + e, weights, 0.0).sum(-1) * valid
+        y = y + w[:, None] * ((jax.nn.silu(x @ f32(w_gate[e]))
+                               * (x @ f32(w_up[e]))) @ f32(w_down[e]))
     return y
 
 
@@ -98,6 +104,144 @@ def test_norm_topk_prob_true_and_false_differ():
     assert _rel(plain, renorm) > 0.1
     w, _ = route(x, router, K, True)
     assert np.allclose(np.asarray(w.sum(-1)), 1.0, atol=1e-6)
+
+
+# -- (a2) at prefill sizes the way back sums choice by choice --------------------
+
+LEAST = moe._BY_CHOICE_MIN // K      # rows of the smallest call that does
+
+
+def _case(name):
+    """(x, valid, router, held) of a named call; the experts are seed 0's."""
+    rows, routed, held = LEAST + 88, E, None
+    if name == "half_elsewhere":
+        routed, held = 2 * E, (E // 2, E)
+    elif name == "seven_eighths_elsewhere":
+        routed, held = 8 * E, (3 * E, E)
+    elif name == "one_row_under":
+        rows = LEAST - 1
+    elif name == "at_the_least":
+        rows = LEAST
+    x = jax.random.normal(jax.random.PRNGKey(11), (rows, D))
+    valid = jax.random.bernoulli(jax.random.PRNGKey(12), 0.7, (rows,))
+    router = jax.random.normal(jax.random.PRNGKey(13), (D, routed))
+    if name == "padding_and_an_inactive_slot":
+        # a bucket's padding behind the prompt and every row of one slot
+        valid = (jnp.arange(rows) < rows - 70) & ~(
+            (jnp.arange(rows) >= 128) & (jnp.arange(rows) < 256))
+    elif name == "one_expert":
+        router = jnp.zeros((D, E)).at[:, 3].set(1.0).at[:, 5].set(0.5)
+        x, valid = jnp.abs(x), jnp.ones(rows, bool)
+    elif name == "nothing_valid":
+        valid = jnp.zeros(rows, bool)
+    return x, valid, router, held
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, TOL), (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name", [
+    "all_here", "half_elsewhere", "seven_eighths_elsewhere",
+    "padding_and_an_inactive_slot", "one_expert", "nothing_valid",
+    "one_row_under", "at_the_least"])
+def test_prefill_sizes_match_a_dense_masked_loop(name, dtype, tol):
+    x, valid, router, held = _case(name)
+    x = x.astype(dtype)
+    experts = [w.astype(dtype) for w in _weights(0)[1:]]
+    assert (x.shape[0] * K >= moe._BY_CHOICE_MIN) == (name != "one_row_under")
+    y, load = expert_layer(x, valid, router, *experts, top_k=K,
+                           norm_topk_prob=False, held=held)
+    assert y.dtype == dtype and y.shape == x.shape
+    first = held[0] if held else 0
+    want = _dense_loop(x, valid, router, *experts, False, first=first)
+    _, chosen = route(x, router, K, False)
+    chosen = np.asarray(chosen)[np.asarray(valid)].ravel() - first
+    here = chosen[(chosen >= 0) & (chosen < E)]
+    assert np.array_equal(np.asarray(load), np.bincount(here, minlength=E))
+    assert not np.asarray(y, np.float32)[~np.asarray(valid)].any()
+    if name == "nothing_valid":
+        assert not np.asarray(y, np.float32).any() and not int(load.sum())
+        return
+    if name == "one_expert":
+        assert list(np.asarray(load)) == [0, 0, 0, len(x), 0, len(x), 0, 0]
+    if held:   # the share of the routed choices that fell on this rank
+        share = len(here) / len(chosen)
+        assert abs(share - E / router.shape[1]) < 0.05
+    assert _rel(y.astype(jnp.float32), want) < tol
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6), (jnp.bfloat16, 2e-3)],
+                         ids=["float32", "bfloat16"])
+def test_the_two_ways_back_agree_to_rounding(monkeypatch, dtype, tol):
+    """The same call summed the decode step's way (one gather of float32
+    ``[T, top_k, D]``) and the prefill call's (choice by choice): the same
+    terms in float32, so they differ by the order of a row's additions and
+    by whether a compiler contracts a product and a sum, and in nothing the
+    activations' last bit does not cover."""
+    x, valid, router, held = _case("half_elsewhere")
+    experts = [w.astype(dtype) for w in _weights(0)[1:]]
+    args = (x.astype(dtype), valid, router, *experts)
+    new, load = expert_layer(*args, top_k=K, norm_topk_prob=True, held=held)
+    monkeypatch.setattr(moe, "_BY_CHOICE_MIN", 1 << 30)
+    old, load_old = expert_layer(*args, top_k=K, norm_topk_prob=True, held=held)
+    assert np.array_equal(np.asarray(load), np.asarray(load_old))
+    assert _rel(new.astype(jnp.float32), old.astype(jnp.float32)) < tol
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_a_row_reads_the_same_alone_and_beside_other_rows(dtype):
+    """Rows 100-139 of a call whose other rows are all padding, and of the
+    same call with every row real: bit for bit the same (the equalities
+    between a request served alone and beside others rest on it)."""
+    x, _, router, held = _case("half_elsewhere")
+    experts = [w.astype(dtype) for w in _weights(0)[1:]]
+    mine = (jnp.arange(len(x)) >= 100) & (jnp.arange(len(x)) < 140)
+    kw = dict(top_k=K, norm_topk_prob=True, held=held)
+    alone, _ = expert_layer(x.astype(dtype), mine, router, *experts, **kw)
+    beside, _ = expert_layer(x.astype(dtype), jnp.ones(len(x), bool), router,
+                             *experts, **kw)
+    alone, beside = (np.asarray(a, np.float32) for a in (alone, beside))
+    assert alone[100:140].any()
+    assert np.array_equal(alone[100:140], beside[100:140])
+
+
+def _lowered(rows, top_k, experts=16):
+    """StableHLO of ``expert_layer`` over bfloat16 rows, and the sizes of its
+    rank-3 float32 values."""
+    f = lambda *a: expert_layer(*a, top_k=top_k, norm_topk_prob=False)
+    sds = jax.ShapeDtypeStruct
+    text = jax.jit(f).lower(
+        sds((rows, D), jnp.bfloat16), sds((rows,), jnp.bool_),
+        sds((D, experts), jnp.float32), sds((experts, D, F), jnp.bfloat16),
+        sds((experts, D, F), jnp.bfloat16),
+        sds((experts, F, D), jnp.bfloat16)).as_text()
+    sizes = {tuple(int(n) for n in dims) for dims in
+             re.findall(r"tensor<(\d+)x(\d+)x(\d+)xf32>", text)}
+    return text, sizes
+
+
+@pytest.mark.parametrize("rows,top_k", [(16, 8), (32, 6), (40, 10), (128, 4)],
+                         ids=["128", "192", "400", "512"])
+def test_a_decode_steps_assignments_go_back_by_one_gather(rows, top_k):
+    """The sparse serve cells' decode steps (16 slots x top-8, 32 x 6, 40 x
+    10, 128 x 4; 32 x 4 is the first again): the shape rule leaves them the
+    ``[T, top_k, D]`` float32 gather and sum they had."""
+    assert rows * top_k < moe._BY_CHOICE_MIN
+    _, sizes = _lowered(rows, top_k)
+    assert (rows, top_k, D) in sizes
+
+
+@pytest.mark.parametrize("rows,top_k", [(128, 8), (512, 2), (296, 10)],
+                         ids=["1024", "1024_top2", "2960"])
+def test_a_prefill_calls_assignments_hold_no_float32_row_a_choice(rows, top_k):
+    """At and over the least size no float32 value has a row for every
+    (token, choice): the rows come back in the activations' type, ``[top_k,
+    T, D]``, and the float32 sum is ``[T, D]``."""
+    assert rows * top_k >= moe._BY_CHOICE_MIN
+    text, sizes = _lowered(rows, top_k)
+    assert not [s for s in sizes if s[0] * s[1] * s[2] >= rows * top_k * D]
+    assert f"tensor<{top_k}x{rows}x{D}xbf16>" in text
+    assert f"tensor<{rows}x{D}xf32>" in text
 
 
 # -- (c) the training module at a capacity that drops nothing ------------------
