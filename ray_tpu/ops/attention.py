@@ -3,21 +3,33 @@ pure-XLA reference path.
 
 The MXU-friendly hot op of the flagship model. Three pallas kernels, named so
 that a device trace shows them under one key each, one call a layer and step:
-``flash_fwd`` (online softmax: one (batch*head, q-block) program, a loop over
-the k-blocks of the whole-sequence K/V held in VMEM; saves the row
-logsumexp), ``flash_bwd_dq`` and ``flash_bwd_dkv`` (FlashAttention-2 style,
-softmax rebuilt from the saved logsumexp; dk/dv on the transposed score tile).
+``flash_fwd`` (online softmax: one (head, q-block) program, a loop over the
+k-blocks of the whole-sequence K/V held in VMEM; saves the row logsumexp),
+``flash_bwd_dq`` and ``flash_bwd_dkv`` (FlashAttention-2 style, softmax
+rebuilt from the saved logsumexp; dk/dv on the transposed score tile).
 All three share one loop shape: every product takes the operands as they come
 (bfloat16 in the cells) and accumulates in float32, with ``p`` and ``ds``
 rounded to the operand dtype only on their way into a product, as
 ``reference_attention`` does; max, sum, ``exp``, logsumexp, delta and the
 accumulators stay float32. The causal mask is built only in the blocks the
 diagonal crosses (a second loop with the same body), the blocks above it are
-never visited, and the row statistics lie along the lanes, (B*H, 1, S).
+never visited, and the row statistics lie along the lanes, a row a head.
+
+Two layouts, chosen by what the call can see. A DIFFERENTIATED call (a train
+step: ``_fa_fwd``, ``_flash_bwd_pair``) of an even count of 64-lane heads
+(``_heads_a_program``) hands the kernels the operands as the projections
+produce them, ``(B, S, H, D)`` seen as ``[B, S, H x D]``: a program finds its
+128-lane tile through the ``BlockSpec`` index maps (``_tile_specs``), owns the
+two heads of it (``_each_head``), and writes o, dq, dk, dv the same way, so
+nothing is transposed before or after any of the three calls. Every other
+call, the forward-only ones of the serving engine's prefill among them (a
+window, values with a head size of their own) and every call of heads of 128
+lanes or more, brings a head's rows together first, ``(B*H, S, D)``, and takes
+them apart after.
 ``_blocks`` sizes the blocks from the shapes; ``_use_pallas_bwd`` picks the
 backward from the shapes too: the pallas pair wherever its whole-sequence
-blocks fit fast memory (head_dim 64 and 128 up to 12,288 positions in
-bfloat16), a rematerialised backward through ``reference_attention`` for a
+blocks fit fast memory (bfloat16: head_dim 128 up to 12,288 positions, 64 up
+to 10,240), a rematerialised backward through ``reference_attention`` for a
 longer sequence and for values with a head size of their own.
 
 CI runs the kernels in pallas interpret mode on CPU (SURVEY.md §4 implication:
@@ -83,7 +95,12 @@ def _blocks(seq_len: int) -> tuple:
     twelve at ``bf16[2,2048,16,128]``: 1.73 / 1.53 / 1.43, 0.74 / 0.64 / 0.85,
     0.47 / 0.45 / 0.60, 0.53 / 0.50 / 0.66; 256 x 512 is 0.6% ahead in the
     forward alone and 16% behind over the three), so neither head_dim nor
-    the dtype enters the rule."""
+    the dtype enters the rule. Swept again for the program of two 64-lane
+    heads that reads the projections' layout (PERF.md section 6, PR 47;
+    ``bf16[4,2048,32,64]``: 4.14 / 5.13 / 4.63, 1.99 / 2.01 / 2.19, **1.84 /
+    1.69 / 2.13**, 1,024 x 1,024 past fast memory with two heads' score tiles;
+    of six unequal pairs 256 x 512 is 1.7% ahead in the forward alone and 3.8%
+    behind over the three): the order stands, and so does the rule."""
     if seq_len <= 128:
         return seq_len, seq_len
     for block in (512, 256, 128):
@@ -114,6 +131,46 @@ def _scores(a, sm_scale):
         a = (a.astype(jnp.float32) * sm_scale).astype(a.dtype)
         return lambda b: _dot(a, b, _NT)
     return lambda b: _dot(a, b, _NT) * sm_scale
+
+
+def _heads_a_program(n_heads: int, head_dim: int) -> int:
+    """2 where a differentiated call reads the projections' own layout ``[B,
+    S, H x D]``: a block's minor dimension is whole 128-lane tiles, so one
+    program owns the TWO 64-lane heads of a tile, and an even count of them
+    tiles a row. 1 everywhere else: a head's rows are brought together first
+    (``_to_bh``). A head of 128 lanes could be read where it lies too, and is
+    not: in cell 4 (16 heads of 128) that step read 171.36 ms against 167.41
+    (PERF.md section 6, PR 47: blocks strided across the row cost the kernels
+    0.70 ms a step, and a head of whole tiles transposes cheaply as it is)."""
+    return 2 if head_dim == 64 and n_heads % 2 == 0 else 1
+
+
+def _first_head(width):
+    """The lanes of a tile's first head, ``(1, width)``."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) < width // 2
+
+
+def _each_head(x, heads):
+    """A block ``(rows, heads x D)`` as one operand a head. Two 64-lane heads
+    lie side by side in a tile: each gets the block with zeros in the other's
+    lanes, so a product that contracts all 128 lanes is that head's own and no
+    lane is sliced (``ops/paged_attention.py`` pairs its key heads the same
+    way). On a 128-wide MXU the padded product costs the passes the 64-wide
+    one costs."""
+    if heads == 1:
+        return [x]
+    first, zero = _first_head(x.shape[1]), jnp.zeros_like(x)
+    return [jnp.where(first, x, zero), jnp.where(first, zero, x)]
+
+
+def _side_by_side(parts, width):
+    """The heads' results back in one tile ``(rows, width)``: of each head's
+    product the lanes that are its own (the others hold its product with the
+    other head's operand, which nobody reads), of its ``(rows, 1)`` statistic
+    a copy in each of them."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.where(_first_head(width), *parts)
 
 
 def _block(i, size, whole):
@@ -163,12 +220,17 @@ def _key_blocks(q_start, block_q, block_k, seq_len, causal):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
-                      sm_scale, window=0):
+                      sm_scale, window=0, heads=1):
+    """``heads`` 2: the program's blocks are one 128-lane tile of two 64-lane
+    heads (``_each_head``), each with a softmax of its own: the statistics
+    are lists, one entry a head, the accumulator is the one tile of ``o``
+    (a whole tile a head read 4 ms a step slower in cell 1: PERF.md section
+    6, PR 47), and ``lse_ref`` holds a row a head."""
     import jax.experimental.pallas as pl
 
     block_q, d = q_ref.shape[1], v_ref.shape[2]
     seq_len = k_ref.shape[1]
-    scores = _scores(q_ref[0], sm_scale)
+    scores = [_scores(q, sm_scale) for q in _each_head(q_ref[0], heads)]
     q_start = pl.program_id(1) * block_q
 
     def step(masked):
@@ -176,22 +238,25 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
             acc, m, l = carry
             keys = _block(i, block_k, seq_len)
             k, v = k_ref[0, keys, :], v_ref[0, keys, :]
-            s = scores(k)                                 # (bq, bk) float32
-            if masked and window:
-                s = _hide_outside(s, q_start, i * block_k, window)
-            elif masked:
-                s = _hide_future(s, q_start, i * block_k, 0)
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l_new = l * alpha + p.sum(axis=-1, keepdims=True)
-            acc_new = acc * alpha + _dot(p.astype(v.dtype), v)
+            m_new, p, alpha, l_new = [], [], [], []
+            for h in range(heads):
+                s = scores[h](k)                          # (bq, bk) float32
+                if masked and window:
+                    s = _hide_outside(s, q_start, i * block_k, window)
+                elif masked:
+                    s = _hide_future(s, q_start, i * block_k, 0)
+                m_new.append(jnp.maximum(m[h], s.max(axis=-1, keepdims=True)))
+                p.append(jnp.exp(s - m_new[h]))
+                alpha.append(jnp.exp(m[h] - m_new[h]))
+                l_new.append(l[h] * alpha[h] + p[h].sum(axis=-1, keepdims=True))
+            acc_new = acc * _side_by_side(alpha, d) + _side_by_side(
+                [_dot(p_h.astype(v.dtype), v) for p_h in p], d)
             return acc_new, m_new, l_new
         return body
 
     carry = (jnp.zeros((block_q, d), jnp.float32),
-             jnp.full((block_q, 1), _MASKED, jnp.float32),
-             jnp.zeros((block_q, 1), jnp.float32))
+             [jnp.full((block_q, 1), _MASKED, jnp.float32)] * heads,
+             [jnp.zeros((block_q, 1), jnp.float32)] * heads)
     clear, end = _key_blocks(q_start, block_q, block_k, seq_len, causal)
     first = 0
     if window:
@@ -204,12 +269,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         first, clear = edge, jnp.maximum(clear, edge)
     carry = jax.lax.fori_loop(first, clear, step(False), carry)
     acc, m, l = jax.lax.fori_loop(clear, end, step(True), carry)
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+    l_safe = [jnp.maximum(l_h, 1e-30) for l_h in l]
+    o_ref[0] = (acc / _side_by_side(l_safe, d)).astype(o_ref.dtype)
     # logsumexp per row, the backward's softmax reconstruction key, laid
     # along the lanes: a (S, 1) array pads every row to 128 lanes, in fast
     # memory and in HBM (134 MB for 1 MB of statistics at cell 1's shape)
-    lse_ref[0] = (m + jnp.log(l_safe)).T
+    for h in range(heads):
+        lse_ref[0, h:h + 1] = (m[h] + jnp.log(l_safe[h])).T
 
 
 def _to_bh(x):
@@ -220,6 +286,22 @@ def _to_bh(x):
 def _from_bh(x, B, H):
     BH, S, D = x.shape
     return x.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+
+
+def _whole_seq_params(S, D, Dv, itemsize, room=10 << 20):
+    """``pallas_call`` arguments for the forward's whole-sequence K and V,
+    twice each (the pipeline's two buffers), as fast memory holds them (the
+    minor dimension in whole 128-lane tiles): 12.6 MiB at bf16[.., 8192, 192 /
+    128], which with the blocks and the score tile is 0.4 MiB past the
+    compiler's own 16 MiB. Only such a shape gets a limit of its own (more
+    than ``room`` held); every other call is compiled as it was."""
+    held = 2 * S * (_lanes(D) + _lanes(Dv)) * itemsize
+    if held <= room:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=held + (16 << 20))}
 
 
 def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool, window: int = 0):
@@ -240,18 +322,6 @@ def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool, window: int = 0):
     if window:
         assert causal, "a window is the causal mask's left edge"
         kernel = functools.partial(kernel, window=window)
-    # the whole-sequence K and V, twice each (the pipeline's two buffers),
-    # as fast memory holds them (the minor dimension in whole 128-lane tiles):
-    # 12.6 MiB at bf16[.., 8192, 192 / 128], which with the blocks and the
-    # score tile is 0.4 MiB past the compiler's own 16 MiB. Only such a shape
-    # gets a limit of its own; every other call is compiled as it was.
-    held = 2 * S * (_lanes(D) + _lanes(Dv)) * k.dtype.itemsize
-    params = {}
-    if held > 10 << 20:
-        from jax.experimental.pallas import tpu as pltpu
-
-        params["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=held + (16 << 20))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, S // block_q),
@@ -270,7 +340,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool, window: int = 0):
         ],
         interpret=interpret,
         name="flash_fwd",
-        **params,
+        **_whole_seq_params(S, D, Dv, k.dtype.itemsize),
     )(qt, kt, vt)
     return _from_bh(out, B, H), lse
 
@@ -288,26 +358,29 @@ def flash_attention_fwd(q, k, v, causal: bool = True,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_k, causal, sm_scale):
+                         dq_ref, *, block_k, causal, sm_scale, heads):
     import jax.experimental.pallas as pl
 
     block_q, seq_len = q_ref.shape[1], k_ref.shape[1]
-    scores = _scores(q_ref[0], sm_scale)
-    do = do_ref[0]                            # (bq, d)
-    lse = lse_ref[0].T                        # (1, bq) -> (bq, 1)
-    delta = delta_ref[0].T
+    scores = [_scores(q, sm_scale) for q in _each_head(q_ref[0], heads)]
+    do = _each_head(do_ref[0], heads)         # (bq, heads x d) each
+    lse = [lse_ref[0, h:h + 1].T for h in range(heads)]   # (1, bq) -> (bq, 1)
+    delta = [delta_ref[0, h:h + 1].T for h in range(heads)]
     q_start = pl.program_id(1) * block_q
 
     def step(masked):
         def body(i, dq_acc):
             keys = _block(i, block_k, seq_len)
             k, v = k_ref[0, keys, :], v_ref[0, keys, :]
-            s = scores(k)                                 # (bq, bk)
-            if masked:
-                s = _hide_future(s, q_start, i * block_k, 0)
-            p = jnp.exp(s - lse)
-            ds = p * (_dot(do, v, _NT) - delta)
-            return dq_acc + _dot(ds.astype(k.dtype), k)
+            dq = []
+            for h in range(heads):
+                s = scores[h](k)                              # (bq, bk)
+                if masked:
+                    s = _hide_future(s, q_start, i * block_k, 0)
+                p = jnp.exp(s - lse[h])
+                ds = p * (_dot(do[h], v, _NT) - delta[h])
+                dq.append(_dot(ds.astype(k.dtype), k))
+            return dq_acc + _side_by_side(dq, k.shape[1])
         return body
 
     clear, end = _key_blocks(q_start, block_q, block_k, seq_len, causal)
@@ -319,15 +392,15 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q, causal, sm_scale):
+                          dk_ref, dv_ref, *, block_q, causal, sm_scale, heads):
     """Works on the TRANSPOSED score tile (keys down, queries across), so
     that ``p^T do`` and ``ds^T q`` are plain products and the statistics are
     read as they lie, along the lanes."""
     import jax.experimental.pallas as pl
 
     block_k, seq_len = k_ref.shape[1], q_ref.shape[1]
-    scores = _scores(k_ref[0], sm_scale)
-    v = v_ref[0]                              # (bk, d)
+    scores = [_scores(k, sm_scale) for k in _each_head(k_ref[0], heads)]
+    v = _each_head(v_ref[0], heads)           # (bk, heads x d) each
     k_start = pl.program_id(1) * block_k
 
     def step(masked):
@@ -335,15 +408,20 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dk_acc, dv_acc = carry
             queries = _block(i, block_q, seq_len)
             q, do = q_ref[0, queries, :], do_ref[0, queries, :]
-            lse = lse_ref[0, :, queries]                      # (1, bq)
-            delta = delta_ref[0, :, queries]
-            s = scores(q)                                     # (bk, bq)
-            if masked:
-                s = _hide_future(s, i * block_q, k_start, 1)
-            p = jnp.exp(s - lse)
-            dv_acc = dv_acc + _dot(p.astype(do.dtype), do)
-            ds = p * (_dot(v, do, _NT) - delta)
-            dk_acc = dk_acc + _dot(ds.astype(q.dtype), q)
+            delta, p = [], []
+            for h in range(heads):
+                lse = lse_ref[0, h:h + 1, queries]                # (1, bq)
+                delta.append(delta_ref[0, h:h + 1, queries])
+                s = scores[h](q)                                  # (bk, bq)
+                if masked:
+                    s = _hide_future(s, i * block_q, k_start, 1)
+                p.append(jnp.exp(s - lse))
+            dv_acc = dv_acc + _side_by_side(
+                [_dot(p_h.astype(do.dtype), do) for p_h in p], q.shape[1])
+            ds = [p[h] * (_dot(v[h], do, _NT) - delta[h])
+                  for h in range(heads)]
+            dk_acc = dk_acc + _side_by_side(
+                [_dot(ds_h.astype(q.dtype), q) for ds_h in ds], q.shape[1])
             return dk_acc, dv_acc
         return body
 
@@ -355,17 +433,83 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         clear = (k_start + block_k - 1 + block_q - 1) // block_q
     else:
         first = clear = 0
-    carry = (jnp.zeros(v.shape, jnp.float32),) * 2
+    carry = (jnp.zeros(k_ref.shape[1:], jnp.float32),) * 2
     carry = jax.lax.fori_loop(first, clear, step(True), carry)
     dk, dv = jax.lax.fori_loop(clear, nq, step(False), carry)
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+# ---------------------------------------------------------------------------
+# a differentiated call: the three kernels on the projections' own layout
+# ---------------------------------------------------------------------------
+
+
+def _tile_specs(tiles, width, heads, seq_len):
+    """The ``BlockSpec``s of a program (p, i) over operands ``[N, S, tiles x
+    width]`` and statistics ``(N x tiles, heads, S)``: program p owns lane
+    block ``p % tiles`` (a tile of two heads) of row ``p // tiles``, or, with
+    one lane block a row, row p (one head's rows, brought together);
+    ``rows(n)`` is its i-th block of n positions, ``rows()`` the whole
+    sequence (fetched once a p: the index does not move with i), ``stats``
+    the same for the statistics. In ``[B, S, H x D]`` a head is addressed
+    where the projections left it."""
+    import jax.experimental.pallas as pl
+
+    def at(n):   # a block of n positions moves with i, the whole sequence not
+        return (lambda i: 0) if n is None else (lambda i: i)
+
+    def rows(n=None):
+        shape, j = (1, n or seq_len, width), at(n)
+        if tiles == 1:
+            return pl.BlockSpec(shape, lambda p, i: (p, j(i), 0))
+        return pl.BlockSpec(shape, lambda p, i: (p // tiles, j(i), p % tiles))
+
+    def stats(n=None):
+        j = at(n)
+        return pl.BlockSpec((1, heads, n or seq_len),
+                            lambda p, i: (p, 0, j(i)))
+
+    return rows, stats
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _flash_fwd_two_heads(q, k, v, causal, interpret, block_q, block_k):
+    """The forward of a differentiated call of two heads a program
+    (``_heads_a_program``), on ``[B, S, H x D]`` as it lies: (o, lse), o in
+    (B, S, H, D) and lse in ``(B*H/2, 2, S)``, a row a head of each program.
+    Jitted for the reason ``_flash_bwd_pair`` is."""
+    import jax.experimental.pallas as pl
+
+    B, S, H, D = q.shape
+    heads, tiles = 2, H // 2
+    rows, stats = _tile_specs(tiles, heads * D, heads, S)
+    out, lse = pl.pallas_call(
+        functools.partial(_flash_fwd_kernel, block_k=block_k, causal=causal,
+                          sm_scale=1.0 / (D ** 0.5), heads=heads),
+        grid=(B * tiles, S // block_q),
+        in_specs=[rows(block_q), rows(), rows()],
+        out_specs=[rows(block_q), stats(block_q)],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
+            jax.ShapeDtypeStruct((B * tiles, heads, S), jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_fwd",
+        # two heads' score tiles lie beside K and V: a limit of its own from
+        # 8 MiB held (AOT, PR 47: 10,240 positions, 10 MiB, do not compile
+        # under the compiler's 16)
+        **_whole_seq_params(S, heads * D, heads * D, k.dtype.itemsize,
+                            room=8 << 20),
+    )(*(x.reshape(B, S, H * D) for x in (q, k, v)))
+    return out.reshape(q.shape), lse
+
+
 def flash_attention_bwd(q, k, v, o, lse, g, causal: bool,
                         interpret: bool = False):
     """(dq, dk, dv) by the pallas pair, at the blocks ``_blocks`` gives the
-    sequence."""
+    sequence; ``lse`` as the forward of the same shapes left it
+    (``_fa_fwd``)."""
     return _flash_bwd_pair(q, k, v, o, lse, g, causal, interpret,
                            *_blocks(q.shape[1]))
 
@@ -378,61 +522,63 @@ def _flash_bwd_pair(q, k, v, o, lse, g, causal, interpret, block_q, block_k):
     cache or not (the 16 calls of cell 4's step: 3.3 s of every start, PERF.md
     section 6, PR 34); XLA inlines the call, and the compiled step is the
     same. Everything the trace reads besides the operands is a static
-    argument, the blocks too: the cache holds one trace for each."""
+    argument, the blocks too: the cache holds one trace for each.
+
+    With two 64-lane heads to a program (``_heads_a_program``) q, k, v, dO
+    are read and dq, dk, dv written as ``[B, S, H x D]``, the layout the
+    projections produce and their gradients consume: no operand and no
+    result is transposed here (PR 47; until then eleven whole-array
+    transposes a layer stood around the three calls, 17.8 ms of cell 1's 281
+    ms step with their converts; what is left is XLA's: it keeps q, k, v and
+    their cotangents around RoPE with the positions along the lanes and
+    copies each once, a dense ``[B, S, H x D]`` array, 5.1 ms a step).
+    Elsewhere a head's rows are brought together first, ``(B*H, S, D)``, and
+    the same calls see one head a program, one lane block a row."""
     import jax.experimental.pallas as pl
 
     B, S, H, D = q.shape
-    qt, kt, vt = _to_bh(q), _to_bh(k), _to_bh(v)
-    dot = _to_bh(g)
+    heads = _heads_a_program(H, D)
+    if heads == 2:
+        tiles = H // 2
+        lay, back = (lambda x: x.reshape(B, S, H * D),
+                     lambda x: x.reshape(B, S, H, D))
+    else:
+        tiles = 1
+        lay, back = _to_bh, lambda x: _from_bh(x, B, H)
+    qt, kt, vt, dot = (lay(x) for x in (q, k, v, g))
     # delta = rowsum(dO * O): cheap elementwise — plain XLA, not a kernel;
     # reduced where the operands lie, so that only the (B, S, H) sums are
-    # transposed, into lse's layout (B*H, 1, S). O comes rounded to the
-    # operands' dtype: where attention is nearly uniform and dP - delta
+    # transposed, into lse's layout (B*H/heads, heads, S). O comes rounded to
+    # the operands' dtype: where attention is nearly uniform and dP - delta
     # cancels, that rounding is what puts a model's q and k gradients 2.5e-2
     # from the float32 reference where ``reference_attention``'s backward
     # reads 1.4e-2 (PERF.md section 6, PR 34)
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = delta.transpose(0, 2, 1).reshape(B * H, 1, S)
-    common = dict(causal=causal, sm_scale=1.0 / (D ** 0.5))
+    delta = delta.transpose(0, 2, 1).reshape(lse.shape)
+    rows, stats = _tile_specs(tiles, heads * D, heads, S)
+    common = dict(causal=causal, sm_scale=1.0 / (D ** 0.5), heads=heads)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_k=block_k, **common),
-        grid=(B * H, S // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+        grid=(qt.shape[0] * tiles, S // block_q),
+        in_specs=[rows(block_q), rows(), rows(), rows(block_q),
+                  stats(block_q), stats(block_q)],
+        out_specs=rows(block_q),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
         name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q, **common),
-        grid=(B * H, S // block_k),
-        in_specs=[
-            pl.BlockSpec((1, S, D), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, S, D), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, S), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, S), lambda bh, ki: (bh, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, S, D), v.dtype),
-        ],
+        grid=(qt.shape[0] * tiles, S // block_k),
+        in_specs=[rows(), rows(block_k), rows(block_k), rows(),
+                  stats(), stats()],
+        out_specs=[rows(block_k), rows(block_k)],
+        out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, v.dtype)],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse, delta)
-    return (_from_bh(dq, B, H), _from_bh(dk, B, H), _from_bh(dv, B, H))
+    return back(dq), back(dk), back(dv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -443,9 +589,13 @@ def flash_attention(q, k, v, causal: bool = True, interpret: bool = False):
 def _fa_fwd(q, k, v, causal, interpret):
     # the shapes are static at trace time; the pallas pair has one head size
     # (latent attention's 192 / 128 forward keeps the reference backward)
-    if q.shape[-1] == v.shape[-1] and _use_pallas_bwd(
-            q.shape[-1], q.shape[1], q.dtype.itemsize):
-        o, lse = _flash_fwd_impl(q, k, v, causal, interpret)
+    B, S, H, D = q.shape
+    if D == v.shape[-1] and _use_pallas_bwd(D, S, q.dtype.itemsize):
+        if _heads_a_program(H, D) == 2:
+            o, lse = _flash_fwd_two_heads(q, k, v, causal, interpret,
+                                          *_blocks(S))
+        else:
+            o, lse = _flash_fwd_impl(q, k, v, causal, interpret)
         return o, (q, k, v, o, lse)
     # reference backward never reads o/lse: don't hold them across bwd
     return flash_attention_fwd(q, k, v, causal=causal,
@@ -466,9 +616,13 @@ def _fa_fwd(q, k, v, causal, interpret):
 # float32 at 128 5,632 / 6,144, at 256 1,536 / 2,048. (Where it stops
 # depends on what surrounds the call: at 2 to 4 heads 16,384 positions of
 # head_dim 128 compile.) So bfloat16 takes the pair to 12,288 positions at
-# head_dim 64 and 128 and to 4,096 at 192 and 256, float32 to 4,096 at 128
-# and to 1,536 at 256.
+# head_dim 128 and to 4,096 at 192 and 256, float32 to 4,096 at 128 and to
+# 1,536 at 256. A program of two 64-lane heads (``_heads_a_program``, PR 47)
+# holds two heads' score tiles beside the same blocks, 2 MiB more of the 16,
+# half a MiB off each operand: bfloat16 at head_dim 64 10,240 / 10,752 (12,288
+# / 13,312 while a program held one head), float32 4,608 and more.
 _BWD_WHOLE_SEQ_BYTES = ((256, 3 << 20), (512, 2 << 20), (1024, 3 << 19))
+_TWO_HEADS_BYTES = 1 << 19
 
 
 def _use_pallas_bwd(head_dim: int, seq_len: int = 0, itemsize: int = 2) -> bool:
@@ -487,7 +641,8 @@ def _use_pallas_bwd(head_dim: int, seq_len: int = 0, itemsize: int = 2) -> bool:
     answer) the answer for a sequence short enough: every head size the
     kernels are given has one."""
     row = _lanes(head_dim) * itemsize
-    return any(row <= widest and seq_len * row <= room
+    spare = _TWO_HEADS_BYTES if head_dim == 64 else 0
+    return any(row <= widest and seq_len * row <= room - spare
                for widest, room in _BWD_WHOLE_SEQ_BYTES)
 
 
@@ -517,7 +672,11 @@ def partitioned_over(mesh, batch_axes, head_axes):
     automatically partitioned"), so inside this context ``attention`` runs
     the flash kernel under a ``shard_map``: each device runs it on its own
     batch rows (``batch_axes``) and heads (``head_axes``) of the
-    (B, S, H, D) operands, always over the whole sequence."""
+    (B, S, H, D) operands, always over the whole sequence. The spec is over
+    (B, S, H, D) whatever layout the kernels read: a differentiated call
+    sees its device's ``(B', S, H', D)`` as ``[B', S, H' x D]`` INSIDE the
+    mapped function (``_flash_fwd_two_heads``, ``_flash_bwd_pair``), by the
+    device's own head count."""
     from jax.sharding import PartitionSpec as P
 
     token = _PARTITION.set((mesh, P(batch_axes, None, head_axes, None)))

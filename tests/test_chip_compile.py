@@ -127,8 +127,8 @@ def test_flash_kernels_at_the_cells_own_shapes(one_chip, shape, backward):
 def test_flash_kernels_fast_memory(one_chip, shape):
     """``smollm2-1.7b.train-8k``'s shape (b1 x 8192, 32 heads of 64), and the
     longest sequence the backward's rule admits in bfloat16 at head_dim 128
-    (``ops/attention.py:_use_pallas_bwd``; a head of 64 lies in the same 128
-    lanes, and the test below compiles its bound): the kernels keep
+    (``ops/attention.py:_use_pallas_bwd``; two heads of 64 share a program's
+    128 lanes, and the test below compiles their bound): the kernels keep
     whole-sequence K/V (and Q/dO in dk/dv) blocks in fast memory. Forward and
     the pallas backward compile under the compiler's own limit, and what each
     needs of it is printed (``-s``): the least whole MiB of scoped VMEM it
@@ -145,10 +145,17 @@ def test_flash_kernels_fast_memory(one_chip, shape):
     text = jax.jit(_flash_grads).lower(q, k, v).compile().as_text()
     assert text.count("tpu_custom_call") == 3
 
-    lse = jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32, sharding=one_chip)
+    # the entries a differentiated call takes: at head_dim 64 the
+    # projections' own layout [B, S, H x D], two heads and two rows of
+    # statistics to a program (PR 47)
+    heads = mod._heads_a_program(H, D)
+    lse = jax.ShapeDtypeStruct((B * H // heads, heads, S), jnp.float32,
+                               sharding=one_chip)
+    forward = (lambda *a: mod._flash_fwd_two_heads(
+        *a, True, False, *mod._blocks(S))) if heads == 2 else (
+            lambda *a: mod._flash_fwd_impl(*a, True, False))
     alone = {
-        "flash_fwd": (lambda q, k, v: mod._flash_fwd_impl(
-            q, k, v, True, False), (q, k, v)),
+        "flash_fwd": (forward, (q, k, v)),
         "flash_bwd_dq": (lambda *a: mod.flash_attention_bwd(*a, True)[0],
                          (q, k, v, q, lse, q)),
         "flash_bwd_dkv": (lambda *a: mod.flash_attention_bwd(*a, True)[1:],
@@ -186,14 +193,16 @@ def _pair(S, H, D, dtype, sharding):
 
     mod = sys.modules[flash_attention.__module__]
     x = jax.ShapeDtypeStruct((1, S, H, D), dtype, sharding=sharding)
-    lse = jax.ShapeDtypeStruct((H, 1, S), jnp.float32, sharding=sharding)
+    heads = mod._heads_a_program(H, D)
+    lse = jax.ShapeDtypeStruct((H // heads, heads, S), jnp.float32,
+                               sharding=sharding)
     return jax.jit(lambda *a: mod.flash_attention_bwd(*a, True)).lower(
         x, x, x, x, lse, x).compile()
 
 
 # (head_dim, dtype, the longest sequence the rule admits, one a little further
 # at which the pair no longer compiles at 16 heads)
-BOUNDS = [(64, jnp.bfloat16, 12288, 16384), (128, jnp.bfloat16, 12288, 13312),
+BOUNDS = [(64, jnp.bfloat16, 10240, 10752), (128, jnp.bfloat16, 12288, 13312),
           (256, jnp.bfloat16, 4096, 5120), (128, jnp.float32, 4096, 6144),
           (256, jnp.float32, 1536, 2560)]
 
@@ -235,6 +244,44 @@ def test_flash_backward_past_the_bound_takes_the_fallback(one_chip):
             *_qkv((1, 12288 + 512, 2, 128), one_chip)).compile().as_text()
     made = _custom_calls(text)
     assert len(made) == 1 and "flash_fwd" in made[0]
+
+
+def test_train_step_hands_the_flash_kernels_the_projections_layout(topo):
+    """Cell 1's own train step (``benchmarks/configs/smollm2-1.7b.json``: 32
+    heads of 64, b4 x 2048; depth 2), compiled for the described chip: three
+    Mosaic calls a layer under their names, each on ``[4, 2048, 32 x 64]`` as
+    the projections produce it, and no array in the ``(B*H, S, D)`` layout
+    anywhere in the step: nothing brings a head's rows together (PR 47; the
+    parent's step had 64 copies and 16 converts of such arrays in 8 layers).
+    XLA still keeps q, k, v and their cotangents in a layout of its own around
+    RoPE (positions along the lanes) and copies once between that and a call:
+    those copies are dense ``[4, 2048, 2048]`` arrays and are counted here,
+    at most one for each array the calls read or write."""
+    layers = 2
+    bundle, cfg, batch = _cell_bundle(topo, "smollm2-1.7b", layers)
+    rows, S = batch["tokens"].shape
+    text = bundle._fused_step.lower(
+        _sds(bundle._abstract_params, bundle.param_shardings),
+        _sds(bundle._abstract_opt, bundle.opt_shardings),
+        batch).compile().as_text()
+    H, D = cfg.n_heads, cfg.head_dim
+    assert (rows, S, H, D) == (4, 2048, 32, 64)
+    made = _custom_calls(text)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(name in m for m in made) == layers, (name, made)
+    assert len(made) == 3 * layers
+    flat = rf"bf16\[{rows},{S},{H * D}\]"
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            assert re.search(rf"= \(?{flat}", line), line[:200]
+    gathered = re.findall(
+        rf"\[(?:{rows},{H},{S},{D}|{rows * H},{S},{D})\]", text)
+    assert not gathered, sorted(set(gathered))
+    # q, k, v, o, dO, dq, dk, dv: XLA's own layout to the call's or back
+    # (56 in cell 1's 8 layers, none of them for o; 16 at this depth)
+    entry = text[text.index("ENTRY "):]
+    copies = re.findall(rf"= \w+\[{rows},{S},{H * D}\]\S* copy\(", entry)
+    assert len(copies) <= 8 * layers, len(copies)
 
 
 def _on(tree, sharding):
@@ -392,6 +439,33 @@ def _sds(tree, shardings):
         tree, shardings)
 
 
+def _cell_bundle(topo, name, layers):
+    """A train cell's own ``TrainStepBundle`` (the widths of
+    ``benchmarks/configs/<name>.json``, its job block's mesh, optimizer and
+    batch) at depth ``layers`` on the described chips, with the flash kernels
+    a TPU backend would choose: (bundle, model configuration, batch)."""
+    import json
+
+    from benchmarks.jobs import common
+    from benchmarks.registry import REPO
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           name + ".json")) as f:
+        conf = json.load(f)
+    conf["num_hidden_layers"] = layers
+    job = conf["job"]
+    cfg = dataclasses.replace(
+        common.transformer_config(conf, job["seq_len"]),
+        attention_impl="flash")
+    bundle = common.build_bundle(cfg, job, topo.devices[:job["chips"]])
+    rows = job["per_chip_batch"] * bundle.dp_size
+    batch = {k: jax.ShapeDtypeStruct((rows, job["seq_len"]), dt,
+                                     sharding=bundle.batch_sharding)
+             for k, dt in (("tokens", jnp.int32), ("targets", jnp.int32),
+                           ("mask", jnp.float32))}
+    return bundle, cfg, batch
+
+
 def test_sharded_update_step_partitions_over_four_chips(topo):
     """The data-parallel sharded-update step on a data=4 mesh of described
     chips, 1b widths (depth cut to 2): the flash kernel must survive the
@@ -432,26 +506,8 @@ def sharded_step_dp4(topo):
     described chips: the collectives of the compiled text by the computation
     that holds them, and the compiler's memory analysis."""
     import collections
-    import json
 
-    from benchmarks.jobs import common
-    from benchmarks.registry import REPO
-
-    with open(os.path.join(REPO, "benchmarks", "configs",
-                           "internlm2-1.8b-dp4.json")) as f:
-        conf = json.load(f)
-    conf["num_hidden_layers"] = 2
-    job = conf["job"]
-    cfg = dataclasses.replace(
-        common.transformer_config(conf, job["seq_len"]),
-        attention_impl="flash")
-    bundle = common.build_bundle(cfg, job, topo.devices[:job["chips"]])
-
-    rows = job["per_chip_batch"] * bundle.dp_size
-    batch = {k: jax.ShapeDtypeStruct((rows, job["seq_len"]), dt,
-                                     sharding=bundle.batch_sharding)
-             for k, dt in (("tokens", jnp.int32), ("targets", jnp.int32),
-                           ("mask", jnp.float32))}
+    bundle, _, batch = _cell_bundle(topo, "internlm2-1.8b-dp4", 2)
     compiled = bundle._fused_step_sharded.lower(
         _sds(bundle._abstract_params, bundle.param_shardings),
         _sds(bundle._abstract_opt, bundle.opt_shard_shardings),
