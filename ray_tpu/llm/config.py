@@ -63,7 +63,7 @@ class EngineConfig:
     # checked against the model as expect_experts is, and for its reason
     expect_latent_rank: int = 0
     # layers with recurrent state (a decoder-hybrid-decoder's Mamba layers, a
-    # model's gated short convolutions or Mamba-2 layers; 0: none, every layer
+    # model's gated short convolutions, Mamba-2 or delta-rule layers; 0: none, every layer
     # keeps pages or a ring), checked the same way: such a model keeps rows by slot beside its
     # pages
     expect_state_layers: int = 0
@@ -75,6 +75,10 @@ class EngineConfig:
     # keeps a matrix state a head and slot, the largest thing the cache holds
     # a slot, checked against the model the same way
     expect_ssm_heads: int = 0
+    # heads of the model's delta-rule linear-attention layers (0: it has
+    # none): such a layer keeps a float32 matrix state a head and slot, checked
+    # against the model the same way
+    expect_kda_heads: int = 0
 
     def __post_init__(self):
         if self.max_model_len % self.page_size:

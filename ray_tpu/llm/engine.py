@@ -182,7 +182,13 @@ from ``__init__``, so two snapshots subtract):
   layers (``"mamba2"``), per decode step (riding ones too) and such layer:
   ``ssd_step_slots`` (the slots whose state the step reads and writes: all
   of them, ``ops/ssd.py:ssd_step`` walks every slot) and
-  ``ssd_step_live_slots`` (those of them that decode).
+  ``ssd_step_live_slots`` (those of them that decode). For a model with
+  delta-rule layers (``"kda"``) the same two under ``kda_step_slots`` /
+  ``kda_step_live_slots`` (``ops/kda.py:kda_step`` walks every slot too), and
+  per prefill call and such layer ``kda_prefill_positions``: the real
+  positions the call ran through ``kda_scan`` (against ``prefill_batch_tokens``
+  times the layers: what it walked, padding included). Its latent layers
+  count under ``mla_decode_*`` as a latent model's do.
 
 Such a model's rings and rows need no allocator: a slot owns its own, a
 prefill call overwrites all of them from the prompt (the engine tells it the
@@ -205,7 +211,7 @@ per layer in the newest decode step the host has read, and ``held``: that
 step's assignments per layer to experts held here, models with experts only; ``live_tokens``: positions the step attends over through the block
 tables, models with a latent cache or with ``layer_kinds`` only;
 ``state_slots``: the decoding slots, whose matrix states the step moves, models
-with Mamba-2 layers only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
+with Mamba-2 or delta-rule layers only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
 every sampler call of the step before. ``.readback`` names what it waited
 for (arguments ``kind``: ``prefill`` or ``decode``; ``calls``: the prefill
 calls behind it, 1 for a decode step; ``bucket``: the largest of those
@@ -409,8 +415,9 @@ class JaxLLMEngine:
                 f"{self.mcfg.kv_latent_rank}")
         kinds = self.mcfg.layer_kinds
         self._ssd_layers = kinds.count("mamba2")
+        self._kda_layers = kinds.count("kda")
         state_layers = (kinds.count("mamba") + kinds.count("conv")
-                        + self._ssd_layers)
+                        + self._ssd_layers + self._kda_layers)
         if state_layers != self.ecfg.expect_state_layers:
             raise ValueError(
                 f"the deployment expects {self.ecfg.expect_state_layers} "
@@ -425,6 +432,11 @@ class JaxLLMEngine:
                 f"the deployment expects Mamba-2 layers of "
                 f"{self.ecfg.expect_ssm_heads} heads, the model's have "
                 f"{self.mcfg.ssm_heads}")
+        if self.mcfg.kda_heads != self.ecfg.expect_kda_heads:
+            raise ValueError(
+                f"the deployment expects delta-rule layers of "
+                f"{self.ecfg.expect_kda_heads} heads, the model's have "
+                f"{self.mcfg.kda_heads}")
         self.tokenizer = get_tokenizer(config.tokenizer)
         self._mr = model_runner
         self._jax = jax
@@ -519,7 +531,9 @@ class JaxLLMEngine:
             "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0,
             "shared_kv_live_tokens": 0, "shared_kv_read_tokens": 0,
             "window_live_tokens": 0, "prefill_cross_rows": 0,
-            "ssd_step_slots": 0, "ssd_step_live_slots": 0}
+            "ssd_step_slots": 0, "ssd_step_live_slots": 0,
+            "kda_step_slots": 0, "kda_step_live_slots": 0,
+            "kda_prefill_positions": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
 
@@ -865,6 +879,8 @@ class JaxLLMEngine:
             m["admitted"] += len(admitted)
             m["prefill_calls"] += len(calls)
             m["prefill_tokens"] += int(self._seq_lens[slots].sum())
+            m["kda_prefill_positions"] += (
+                self._kda_layers * int(self._seq_lens[slots].sum()))
             m["prefill_batch_tokens"] += sum(R * S for R, S, _ in calls)
             if self.mcfg.sambay:  # the cross-decoder ran one row a row of a call
                 m["prefill_cross_rows"] += rows
@@ -881,7 +897,8 @@ class JaxLLMEngine:
                 # positions the step attends over, through the block tables
                 attrs["live_tokens"] = int(
                     (self._seq_lens[self._active] + 1).sum())
-            if self._ssd_layers:  # live slots whose state the step moves
+            if self._ssd_layers or self._kda_layers:
+                # live slots whose state the step moves
                 attrs["state_slots"] = int(self._active.sum())
             load = None
             with self._phase("decode_dispatch", **attrs):
@@ -1007,11 +1024,12 @@ class JaxLLMEngine:
             self._count_paged_reads("shared_kv")
             self.metrics["window_live_tokens"] += int(np.minimum(
                 self._seq_lens[self._active] + 1, self.mcfg.window).sum())
-            # a step moves every slot's state in each Mamba-2 layer
-            self.metrics["ssd_step_slots"] += (
-                self._ssd_layers * len(self._slots))
-            self.metrics["ssd_step_live_slots"] += (
-                self._ssd_layers * int(self._active.sum()))
+        # a step moves every slot's state in each Mamba-2 or delta-rule layer
+        for name, layers in (("ssd", self._ssd_layers),
+                             ("kda", self._kda_layers)):
+            self.metrics[name + "_step_slots"] += layers * len(self._slots)
+            self.metrics[name + "_step_live_slots"] += (
+                layers * int(self._active.sum()))
 
     def _sent(self, tokens, reqs: List[_Request], kind: str, calls: int,
               bucket: int = 0, moe_load=None) -> None:

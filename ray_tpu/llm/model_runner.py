@@ -23,7 +23,7 @@ traffic is the rows it writes, not the cache.
 ONE per-layer composition (``_forward``) is both programs of every model but
 the decoder-hybrid-decoder: a prompt side, a step side, or both. It walks the
 layers' kinds (``_kinds``), asks the kind for its inputs (``_attn_inputs``,
-``_conv_gates``, ``_mamba2_inputs``), hands each side's rows to the kind's mixer
+``_conv_gates``, ``_mamba2_inputs``, ``_kda_inputs``), hands each side's rows to the kind's mixer
 (``_prompt_mixer``, ``_step_mixer``) and runs the residual, the norms, the MLP
 or the experts and the head once over all rows, so ``prefill`` can carry a
 decode step's rows beside its prompts (``riders``; ``rides`` says for which
@@ -35,7 +35,11 @@ the step. The kinds, and where each keeps what:
   attends over the call's own keys and values (the flash kernel); decode
   gathers each slot's pages by (layer, block_tables) into a [B, Lmax] view and
   runs grouped-query attention against it under a mask;
-- "latent", every layer of a model with ``kv_latent_rank``: ``rows``, one row
+- "latent", every layer of a model with ``kv_latent_rank`` and no
+  ``layer_kinds``, or ONE kind among others of a model with them (``rows``
+  then holds as many layers as the model has latent ones, a layer found by
+  its rank among them; the rotated part is rotated only where ``rope_kinds``
+  names "latent", else its lanes are plain ones): ``rows``, one row
   a position and layer for all heads. Prefill attends over keys and values
   expanded to heads (the flash kernel, 192-wide q . k and 128-wide values);
   decode attends over the rows themselves with the up-projection absorbed,
@@ -71,7 +75,21 @@ the step. The kinds, and where each keeps what:
   convolves the tail with the new input, steps every slot's state once, in
   place (``ssd_step``; beside a prompt ``ssd_riding`` with ``keep``), and
   shifts the tail. One product (``in_proj``) makes ``z | xBC | dt`` for all
-  rows, the gated norm and ``out_proj`` run once over all rows.
+  rows, the gated norm and ``out_proj`` run once over all rows;
+- "kda" of such a model (delta-rule linear attention with a decay per key
+  lane, ``models/transformer.py:KDA``): per layer and slot in ``ssm`` the
+  matrix state of every head, float32, laid ``[heads, key lanes, value
+  lanes]`` as ``ops/kda.py`` keeps it (2.1 MB a slot and layer at 32 heads of
+  128 x 128), and in ``conv`` the last ``kda_conv - 1`` rows of the three
+  convolutions' input ``q | k | v``. Prefill runs the chunked delta rule
+  over the bucket (``kda_scan``, padding passed over with ``g = 0, beta =
+  0``) and WRITES the slot's state and tail from the prompt alone, which is
+  how a slot is reset at admission, reused, or given back to a preempted
+  request; a decode step convolves the tail with the new input, steps every
+  slot's state once, in place (``kda_step``; beside a prompt ``kda_riding``
+  with ``keep``), and shifts the tail. One product (``qkv_proj``) makes ``q |
+  k | v`` and one the low-rank gates' inner halves and ``beta`` for all
+  rows; the output norm, its gate and ``o_proj`` run once over all rows.
 
 ``prefill`` is told the slot a row fills (``slots``), overwrites the slot's
 rings and rows from the prompt alone (which is how a slot is reset at
@@ -146,19 +164,25 @@ class Cache(NamedTuple):
     with "mamba2" layers: per such layer and slot every head's matrix state,
     float32, [N, heads x head size] (``ops/ssd.py``'s layout: the channels
     along the lanes), and the last ``ssm_conv - 1`` rows of the convolution's
-    input ``x | B | C``, ``ssm_inner + 2 ssm_state`` wide. ``moe_load``: for a model with
+    input ``x | B | C``, ``ssm_inner + 2 ssm_state`` wide. Of a model with
+    "kda" layers: per such layer and slot every head's matrix state, float32,
+    [heads, key lanes, value lanes] (``ops/kda.py``'s layout), and the last
+    ``kda_conv - 1`` rows of the convolutions' input ``q | k | v``, ``3 x
+    heads x head dim`` wide; its "latent" layers' rows lie in ``rows``, which
+    then has as many layers as the model has latent ones. ``moe_load``: for a model with
     experts, what the call's routing did. Rings and rows by slot belong to a
     SLOT: prefill overwrites all of a slot's from the prompt alone, which is
     also how a slot is reset at admission; a slot that is not active computes
     into its own rows and nobody reads them."""
     k: Optional[jax.Array] = None  # [L, NP, P, KVH, HD]
     v: Optional[jax.Array] = None
-    rows: Optional[jax.Array] = None  # [L, NP, P, W]
+    rows: Optional[jax.Array] = None  # [L or latent layers, NP, P, W]
     pages: Optional[jax.Array] = None  # [full layers, NP, P, 2 KVH hd]
     rings: Optional[jax.Array] = None  # [window layers, B, window, 2 KVH hd]
-    ssm: Optional[jax.Array] = None   # [mamba layers, B, N, inner] float32
-    # [mamba layers, ssm_conv - 1, B, inner (+ 2 N: "mamba2")] or [conv
-    # layers, conv_taps - 1, B, d_model]
+    # [mamba layers, B, N, inner] or [kda layers, B, H, K, K], float32
+    ssm: Optional[jax.Array] = None
+    # [mamba layers, ssm_conv - 1, B, inner (+ 2 N: "mamba2")], [conv layers,
+    # conv_taps - 1, B, d_model] or [kda layers, kda_conv - 1, B, 3 H K]
     conv: Optional[jax.Array] = None
     moe_load: Optional[jax.Array] = None  # [expert layers, E] int32
 
@@ -198,22 +222,29 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
                              "recurrent rows by slot: init_cache needs "
                              "max_num_seqs")
         kinds, row = cfg.layer_kinds, 2 * cfg.n_kv_heads * cfg.head_dim
-        window = kinds.count("window")
+        window, full = kinds.count("window"), kinds.count("full")
+        latent, kda = kinds.count("latent"), kinds.count("kda")
         mamba = kinds.count("mamba") + kinds.count("mamba2")
-        rows = None
+        rows = state = None
         if mamba:  # a "mamba2" layer convolves x | B | C, a "mamba" layer x
             rows = (mamba, cfg.ssm_conv - 1, max_num_seqs, cfg.ssm_inner
                     + ("mamba2" in kinds) * 2 * cfg.ssm_state)
+            state = (mamba, max_num_seqs, cfg.ssm_state, cfg.ssm_inner)
         elif "conv" in kinds:
             rows = (kinds.count("conv"), cfg.conv_taps - 1, max_num_seqs,
                     cfg.d_model)
+        elif kda:
+            H, K = cfg.kda_heads, cfg.kda_head_dim
+            rows = (kda, cfg.kda_conv - 1, max_num_seqs, 3 * H * K)
+            state = (kda, max_num_seqs, H, K, K)
         return Cache(
-            pages=jnp.zeros((kinds.count("full"), num_pages, page_size, row),
-                            cfg.dtype),
+            rows=jnp.zeros((latent, num_pages, page_size, _latent_width(cfg)),
+                           cfg.dtype) if latent else None,
+            pages=jnp.zeros((full, num_pages, page_size, row), cfg.dtype)
+            if full or not latent else None,
             rings=jnp.zeros((window, max_num_seqs, cfg.window, row), cfg.dtype)
             if window else None,
-            ssm=jnp.zeros((mamba, max_num_seqs, cfg.ssm_state, cfg.ssm_inner),
-                          jnp.float32) if mamba else None,
+            ssm=jnp.zeros(state, jnp.float32) if state else None,
             conv=jnp.zeros(rows, cfg.dtype) if rows else None, moe_load=load)
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     if cfg.kv_latent_rank:
@@ -292,14 +323,19 @@ def _latent_qkv(x, p, cfg, positions):
     """Latent attention's projections of x [B, S, D]: q_nope [B, S, H, nope],
     q_pe [B, S, H, rope] rotated, the normalised latent c [B, S, R], the
     rotated key k_pe [B, S, rope] (one for all heads), and the cache row
-    ``c | k_pe | 0`` [B, S, W]."""
+    ``c | k_pe | 0`` [B, S, W]. As a kind of a model with ``layer_kinds``
+    that does not name "latent" in ``rope_kinds`` nothing is rotated: q_pe and
+    k_pe are the same lanes, plain."""
     dtype = cfg.dtype
     r, nope = cfg.kv_latent_rank, cfg.qk_nope_head_dim
     q = jnp.einsum("...d,dhk->...hk", x, p["q_proj"]["kernel"].astype(dtype))
     a = jnp.einsum("...d,dr->...r", x, p["kv_a_proj"]["kernel"].astype(dtype))
     c = _rmsnorm(a[..., :r], p["kv_a_norm"]["scale"], cfg.norm_eps)
-    q_pe = _rope(q[..., nope:], positions, cfg.rope_theta)
-    k_pe = _rope(a[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
+    if cfg.layer_kinds and "latent" not in cfg.rope_kinds:
+        q_pe, k_pe = q[..., nope:], a[..., r:]
+    else:
+        q_pe = _rope(q[..., nope:], positions, cfg.rope_theta)
+        k_pe = _rope(a[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
     return (q[..., :nope], q_pe, c, k_pe,
             _latent_row([c, k_pe], _latent_width(cfg)))
 
@@ -694,8 +730,9 @@ def _hybrid_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
 # position) in every layer of a model without layer_kinds; with them, plain
 # grouped-query heads, rotated or not by kind, pages for each "full" layer, a
 # ring for each "window" layer, conv_taps - 1 rows a slot for each "conv"
-# layer (no heads at all) and a matrix state with a convolution tail a slot
-# for each "mamba2" layer; with the per-head q/k norm, the attention gate,
+# layer (no heads at all), a matrix state with a convolution tail a slot
+# for each "mamba2" and each "kda" layer and one row a position for each
+# "latent" layer; with the per-head q/k norm, the attention gate,
 # the sandwich norms, the multipliers and the experts the config asks for. ``_embed`` decides
 # the residual stream's type
 # ---------------------------------------------------------------------------
@@ -883,6 +920,98 @@ def _mamba2_out(z, y, lp, cfg):
     return _dense(y, m["out_proj"], cfg.dtype)
 
 
+def _kda_inputs(x, lp, cfg):
+    """A "kda" layer's projections of the normalised stream x [.., D], for
+    all rows: ONE product for ``q | k | v`` before their convolutions (what a
+    slot keeps the tail of), one for the inner halves of the two low-rank
+    gates and ``beta``. Returns (the decay gate's ``f`` [.., H K] and ``beta``
+    [.., H] float32, side by side), (``q | k | v``, the output gate [.., H K])."""
+    m, r = lp["kda"], cfg.kda_gate_rank
+    h = _normed(x, lp["attn_norm"], cfg)
+    with jax.named_scope("kda.in_proj"):
+        qkv = _dense(h, m["qkv_proj"], cfg.dtype)
+    with jax.named_scope("kda.gates"):
+        inner = jnp.einsum("...d,df->...f", h, jnp.concatenate(
+            [m[n]["kernel"] for n in ("f_a", "g_a", "b_proj")],
+            axis=-1).astype(cfg.dtype))
+        f = _dense(inner[..., :r], m["f_b"], cfg.dtype)
+        gate = _dense(inner[..., r:2 * r], m["g_b"], cfg.dtype)
+        beta = jax.nn.sigmoid(inner[..., 2 * r:].astype(jnp.float32))
+    return jnp.concatenate([f.astype(jnp.float32), beta], axis=-1), (qkv, gate)
+
+
+def _kda_operands(a, fb, m, cfg):
+    """The convolved ``q | k | v`` a [.., 3 H K] and ``f | beta`` fb [.., H K
+    + H] -> what the recurrence takes: q, k (unit length a head, q times
+    ``K^-0.5``), v [.., H, K], the log-decay g [.., H, K] and beta [.., H],
+    both float32."""
+    from ray_tpu.models.transformer import kda_log_decay, kda_qk_norm
+
+    H, K = cfg.kda_heads, cfg.kda_head_dim
+    q, k, v = (t.reshape(*t.shape[:-1], H, K)
+               for t in jnp.split(jax.nn.silu(a), 3, axis=-1))
+    q, k = kda_qk_norm(q, k)
+    g = kda_log_decay(fb[..., :H * K], m["dt_bias"], m["A_log"])
+    return q, k, v, g, fb[..., H * K:]
+
+
+def _kda_prefill(qkv, fb, lp, cfg, kept, layer, slots, lengths, in_prompt):
+    """A "kda" layer's recurrence over a prefill call's rows (qkv [R, S, 3 H
+    K], fb [R, S, H K + H]) from a zero state, and ``kept`` (the states, the
+    convolutions' tails) with the rows of ``slots`` left at the prompts' last
+    position: the state after it and the ``kda_conv - 1`` inputs before the
+    next (zeros where the prompt has none). Padding behind a prompt neither
+    moves the state (``g = 0, beta = 0``) nor enters the tail."""
+    from ray_tpu.models.transformer import causal_conv
+    from ray_tpu.ops.kda import kda_scan
+
+    ssm, conv = kept
+    m, tail = lp["kda"], cfg.kda_conv - 1
+    tail_pos = lengths[:, None] - tail + jnp.arange(tail)[None]
+    with jax.named_scope("kda.conv"):
+        q, k, v, g, beta = _kda_operands(causal_conv(
+            qkv, m["conv_kernel"].astype(cfg.dtype), 0), fb, m, cfg)
+        g = jnp.where(in_prompt[..., None, None], g, 0.0)
+        beta = jnp.where(in_prompt[..., None], beta, 0.0)
+    o, state = kda_scan(q, k, v, g, beta)
+    # [layer, tap, slot]: the indexed axes come first, [R, K-1, 3 H K]
+    return o, (ssm.at[layer, slots].set(state),
+               conv.at[layer, :, slots].set(_rows_at(qkv, tail_pos)))
+
+
+def _kda_step(qkv, fb, lp, cfg, kept, layer, keep, op):
+    """A "kda" layer's recurrence over a decode step's rows (qkv [B, 1, 3 H
+    K], fb [B, 1, H K + H]): the taps over the kept tail and the new input,
+    one step of every slot's state in place (``ops/kda.py:kda_step``), the
+    tail shifted by one; with ``keep`` [B] only the slots it marks move."""
+    from ray_tpu.ops.kda import kda_step
+
+    ssm, conv = kept
+    m = lp["kda"]
+    with jax.named_scope("kda.conv"):
+        taps = jnp.concatenate([conv[layer], qkv[:, 0][None]], axis=0)
+        q, k, v, g, beta = _kda_operands(
+            jnp.einsum("kbc,kc->bc", taps, m["conv_kernel"].astype(cfg.dtype)),
+            fb[:, 0], m, cfg)
+    o, ssm = kda_step(ssm, layer, q, k, v, g, beta, keep,
+                      name="kda_step" if op == "decode" else "kda_" + op)
+    rows = taps[1:]
+    if keep is not None:
+        rows = jnp.where(keep[None, :, None], rows, conv[layer])
+    return o[:, None], (ssm, conv.at[layer].set(rows))
+
+
+def _kda_out(gate, o, lp, cfg):
+    """The recurrence's o [.., H, K] float32 under the output norm (one scale
+    for all heads) and the sigmoid of the low-rank ``gate`` [.., H K], then
+    ``o_proj``."""
+    m = lp["kda"]
+    with jax.named_scope("kda.out"):
+        o = _rmsnorm(o, m["o_norm"]["scale"], cfg.norm_eps) * jax.nn.sigmoid(
+            gate.astype(jnp.float32).reshape(o.shape))
+        return _dense(o.reshape(*o.shape[:-2], -1), m["o_proj"], cfg.dtype)
+
+
 def _block_rest(x, o, lp, cfg, valid, name):
     """The block after its mixer's output ``o`` [.., D], in the stream's
     type: the residual (a norm on the way out under ``sandwich_norm``), the
@@ -915,6 +1044,9 @@ def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
     if kind == "mamba2":
         return _mamba2_prefill(row, q, lp, cfg, kept, at, slots, lengths,
                                index[1])
+    if kind == "kda":
+        return _kda_prefill(row, q, lp, cfg, kept, at, slots, lengths,
+                            index[1])
     _, _, page, offset, _, ring_pos = index
     if kind == "latent":
         kept = kept.at[at, page, offset].set(row, mode="drop")
@@ -953,6 +1085,8 @@ def _step_mixer(cfg, index, page_size, keep, op, kind, at, lp, kept, q, row):
         return _conv_step(row, lp, cfg, kept, at, keep)
     if kind == "mamba2":
         return _mamba2_step(row, q, lp, cfg, kept, at, keep, op)
+    if kind == "kda":
+        return _kda_step(row, q, lp, cfg, kept, at, keep, op)
     slot, positions, page, offset, work, ring_work, _ = index
     if kind == "latent":
         kept = kept.at[at, page, offset].set(row[:, 0], mode="drop")
@@ -997,8 +1131,9 @@ def _gather_attention(cfg, page, offset, work, layer, kept, q, k, v):
 def rides(cfg: TransformerConfig) -> bool:
     """May a decode step's rows ride this model's prefill call (``prefill``'s
     ``riders``)? A policy: both programs of every model but the
-    decoder-hybrid-decoder are ``_forward``, the "dense" and "latent" kinds'
-    mixers have not been run with both sides yet."""
+    decoder-hybrid-decoder are ``_forward``; the "dense" kind's mixer has not
+    been run with both sides yet, the "latent" kind's only as one kind of a
+    model with ``layer_kinds``."""
     return bool(cfg.layer_kinds) and not cfg.sambay
 
 
@@ -1030,7 +1165,7 @@ def _forward(p, cfg, cache, prompt=None, step=None):
     kinds, plain = _kinds(cfg), not cfg.layer_kinds
     kept = {"dense": (cache.k, cache.v), "latent": cache.rows,
             "full": cache.pages, "window": cache.rings, "conv": cache.conv,
-            "mamba2": (cache.ssm, cache.conv)}
+            "mamba2": (cache.ssm, cache.conv), "kda": (cache.ssm, cache.conv)}
     xs, positions, valid, mixers = [], [], [], []
     if prompt is not None:
         tokens, lengths, tables, slots = prompt
@@ -1065,6 +1200,8 @@ def _forward(p, cfg, cache, prompt=None, step=None):
             q, (row, gate) = None, _conv_gates(x, lp, cfg)
         elif kind == "mamba2":  # "q": the step sizes, split as queries are
             q, (row, gate) = _mamba2_inputs(x, lp, cfg)
+        elif kind == "kda":  # "q": the decay gate and beta, split likewise
+            q, (row, gate) = _kda_inputs(x, lp, cfg)
         else:
             h, q, row = _attn_inputs(x, lp, cfg, positions, kind)
         outs = []
@@ -1077,6 +1214,8 @@ def _forward(p, cfg, cache, prompt=None, step=None):
             o = _conv_out(gate, o, lp, cfg)
         elif kind == "mamba2":
             o = _mamba2_out(gate, o, lp, cfg)
+        elif kind == "kda":
+            o = _kda_out(gate, o, lp, cfg)
         else:
             o = _attn_out(h, o, lp, cfg)
         x, load = _block_rest(x, o, lp, cfg, valid, name)
@@ -1092,7 +1231,8 @@ def _forward(p, cfg, cache, prompt=None, step=None):
         R = shapes[0][0]
         logits = logits[:R], logits[R:]
     k, v = kept["dense"]
-    ssm, conv = kept["mamba2"] if "mamba2" in kinds else (None, kept["conv"])
+    state = next((kind for kind in ("mamba2", "kda") if kind in kinds), "")
+    ssm, conv = kept[state] if state else (None, kept["conv"])
     return logits, Cache(
         k=k, v=v, rows=kept["latent"], pages=kept["full"],
         rings=kept["window"], ssm=ssm, conv=conv,
@@ -1109,10 +1249,13 @@ def _end_to_end(sides):
 
 
 def _apart(x, shapes):
-    """``_end_to_end``'s inverse: the sides of ``x`` (None: of nothing), of
+    """``_end_to_end``'s inverse: the sides of ``x`` (None: of nothing; a
+    list, as the "latent" kind's queries are: of each of its arrays), of
     ``shapes`` (R, S) and (B, 1)."""
     if x is None or len(shapes) == 1:
         return [x] * len(shapes)
+    if isinstance(x, (list, tuple)):
+        return [list(side) for side in zip(*(_apart(t, shapes) for t in x))]
     (R, S), (B, _) = shapes
     return [x[:, :R * S].reshape(R, S, *x.shape[2:]),
             x[:, R * S:].reshape(B, 1, *x.shape[2:])]

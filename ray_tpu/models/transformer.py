@@ -119,13 +119,21 @@ class TransformerConfig:
     # projections and a tied head (``HybridBlock``). "rms": the RMSNorm
     # block of every model without kinds (``Block``), plain grouped-query
     # heads under the causal mask ("full") or the window's ("window"); only
-    # "window", "full", "conv" and "mamba2" are kinds of such a block. "conv" is
+    # "window", "full", "conv", "mamba2", "kda" and "latent" are kinds of such a
+    # block. "conv" is
     # a gated short convolution (``ShortConv``): no attention, no position
     # embedding, and in serving ``conv_taps - 1`` rows a slot in place of pages
     # or a ring. "mamba2" is a Mamba-2 mixer (``Mamba2``): ``ssm_heads`` heads
     # of ``ssm_inner / ssm_heads`` channels, a matrix state [head size,
     # ssm_state] a head, ``ssm_conv`` taps; in serving the state and the
-    # convolution's last ``ssm_conv - 1`` inputs a slot.
+    # convolution's last ``ssm_conv - 1`` inputs a slot. "kda" is a delta-rule
+    # linear-attention mixer with a decay per key lane (``KDA``): ``kda_heads``
+    # heads of ``kda_head_dim`` key and value lanes, a matrix state [head
+    # dim, head dim] a head, ``kda_conv`` taps on each of q, k and v; in
+    # serving the state and the three convolutions' last ``kda_conv - 1``
+    # inputs a slot. "latent" is latent attention (``kv_latent_rank``) as ONE
+    # kind among others: one row a position in each such layer, rotated only
+    # where ``rope_kinds`` names it.
     layer_kinds: Tuple[str, ...] = ()
     block: str = "sambay"
     window: int = 0
@@ -136,6 +144,15 @@ class TransformerConfig:
     # heads of a "mamba2" layer (0: the model has none); B and C are shared by
     # all of them (one group)
     ssm_heads: int = 0
+    # a "kda" layer's heads (0: the model has none), the key and value lanes
+    # of one, the taps of its three causal depthwise convolutions, the inner
+    # width of its two low-rank gates (the decay's and the output's), and what
+    # its projections are drawn at (0 = the attention's)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_gate_rank: int = 0
+    kda_init_std: float = 0.0
     # taps a channel of a "conv" layer's causal depthwise convolution (0: the
     # model has no such layer), and what its in_proj and out_proj are drawn
     # at (0 = the attention's): its output goes with their FOURTH power, the
@@ -202,11 +219,11 @@ class TransformerConfig:
         return self.layer_kinds[i] if self.layer_kinds else "full"
 
     def init_std(self, part: str) -> float:
-        """``part``: "attn", "conv", "mlp", "expert", "ssm_proj", "ssm_x" or
-        "embed"."""
+        """``part``: "attn", "conv", "kda", "mlp", "expert", "ssm_proj",
+        "ssm_x" or "embed"."""
         if part == "expert" and not self.expert_init_std:
             part = "mlp"
-        if part == "conv" and not self.conv_init_std:
+        if part in ("conv", "kda") and not getattr(self, part + "_init_std"):
             part = "attn"
         return getattr(self, part + "_init_std") or (
             0.02 if part == "embed" else 0.02 / np.sqrt(2 * self.n_layers))
@@ -231,25 +248,26 @@ class TransformerConfig:
                 "cross": (d + 1) * d + attn}
             return v * d + 2 * d + sum(
                 mixer[kind] + 3 * d * f + 4 * d for kind in self.layer_kinds)
+        q = d * self.n_heads * self.head_dim
+        attn = latent = (
+            q * (3 if self.attn_gate else 2)  # q, o and the gate
+            + 2 * d * (self.n_kv_heads * self.head_dim)  # k, v
+            + (4 if self.sandwich_norm else 2) * d  # norms
+            + ((self.n_heads + self.n_kv_heads) * self.head_dim
+               if self.qk_norm else 0)
+            + (2 * self.head_dim if self.qk_head_norm else 0)
+        )
         if self.kv_latent_rank:
             r, rope = self.kv_latent_rank, self.qk_rope_head_dim
-            attn = (
+            latent = (
                 d * self.n_heads * (self.qk_nope_head_dim + rope)  # q
                 + d * (r + rope) + r  # latent down-projection and its norm
                 + r * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
                 + self.n_heads * self.v_head_dim * d  # o
                 + 2 * d  # norms
             )
-        else:
-            q = d * self.n_heads * self.head_dim
-            attn = (
-                q * (3 if self.attn_gate else 2)  # q, o and the gate
-                + 2 * d * (self.n_kv_heads * self.head_dim)  # k, v
-                + (4 if self.sandwich_norm else 2) * d  # norms
-                + ((self.n_heads + self.n_kv_heads) * self.head_dim
-                   if self.qk_norm else 0)
-                + (2 * self.head_dim if self.qk_head_norm else 0)
-            )
+            if not self.layer_kinds:  # every layer is one
+                attn = latent
         # in_proj (B | C | z), out_proj, the taps and the block's two norms
         conv = 4 * d * d + self.conv_taps * d + 2 * d
         # in_proj (z | xBC | dt), out_proj, the taps and their bias, dt_bias,
@@ -258,7 +276,14 @@ class TransformerConfig:
         mamba2 = (d * (inner + xbc + self.ssm_heads) + inner * d
                   + xbc * (self.ssm_conv + 1) + 3 * self.ssm_heads + inner
                   + 2 * d)
-        mixer = {"conv": conv, "mamba2": mamba2}
+        # q | k | v and o, the three convolutions' taps, the decay's low-rank
+        # pair with dt_bias and A_log, beta's projection, the output gate's
+        # pair, the output norm and the block's two norms
+        wide, r = self.kda_heads * self.kda_head_dim, self.kda_gate_rank
+        kda = (4 * d * wide + 3 * wide * self.kda_conv
+               + 2 * (d * r + r * wide) + wide + self.kda_heads
+               + d * self.kda_heads + self.kda_head_dim + 2 * d)
+        mixer = {"conv": conv, "mamba2": mamba2, "kda": kda, "latent": latent}
         dense_mlp = 3 * d * (self.d_ff_dense or f)
         moe_mlp = (self.n_experts_held * 3 * d * f + d * self.n_experts
                    + 3 * d * self.n_shared_experts * f
@@ -337,7 +362,8 @@ class Attention(nn.Module):
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.normal(cfg.init_std("attn")), axes),
         )
-        if cfg.kv_latent_rank:
+        if cfg.kv_latent_rank and (not cfg.layer_kinds
+                                   or self.kind == "latent"):
             q, k, v = self._latent_qkv(x, positions, dense)
             out = attention_op(q, k, v, causal=True, impl=cfg.attention_impl,
                                segment_ids=segment_ids)
@@ -384,8 +410,9 @@ class Attention(nn.Module):
         """Latent attention, expanded to heads (the serving engine's prefill
         computes the same; its decode absorbs ``kv_b_proj`` into q and the
         output instead, ``llm/model_runner.py``): q [B, S, H, nope + rope], k
-        the same width (the rotated part one for all heads), v [B, S, H,
-        v_head_dim]."""
+        the same width (the rotated part one for all heads; as a kind of a
+        model with ``layer_kinds`` rotated only where ``rope_kinds`` names
+        "latent", else plain lanes), v [B, S, H, v_head_dim]."""
         cfg = self.cfg
         H, r = cfg.n_heads, cfg.kv_latent_rank
         nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -395,8 +422,10 @@ class Attention(nn.Module):
                     name="kv_a_norm")(a[..., :r])
         kv = dense((H, nope + cfg.v_head_dim), (None, "heads", "head_dim"),
                    "kv_b_proj")(c)
-        q_pe = _rope(q[..., nope:], positions, cfg.rope_theta)
-        k_pe = _rope(a[..., None, r:], positions, cfg.rope_theta)
+        q_pe, k_pe = q[..., nope:], a[..., None, r:]
+        if not cfg.layer_kinds or "latent" in cfg.rope_kinds:
+            q_pe = _rope(q_pe, positions, cfg.rope_theta)
+            k_pe = _rope(k_pe, positions, cfg.rope_theta)
         q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_pe, q_pe.shape)], axis=-1)
@@ -478,6 +507,77 @@ class Mamba2(nn.Module):
         y = RMSNorm(cfg.norm_eps, cfg.dtype, axis=None, name="norm")(
             y * nn.silu(z.astype(jnp.float32)))
         return dense(cfg.d_model, ("mlp", "embed"), "out_proj")(y)
+
+
+class KDA(nn.Module):
+    """A "kda" layer's mixer, delta-rule linear attention with a decay per
+    key lane (Kimi Linear): ``q | k | v = qkv_proj(h)``, each through its own
+    causal depthwise convolution of ``kda_conv`` taps (zeros before the first
+    position, no bias) and a silu; ``q = q / |q| * head_dim^-0.5`` and ``k = k
+    / |k|`` a head; the log-decay ``g = -exp(A_log[head]) * softplus(f_b(f_a(h))
+    + dt_bias)`` a head and key lane; ``beta = sigmoid(b_proj(h))`` a head;
+    the recurrence of ``ops/kda.py``; ``RMSNorm(o) * sigmoid(g_b(g_a(h)))``
+    over each head's lanes (one scale for all heads), ``o_proj``. No bias, no
+    position embedding. The TRAINING side: the whole sequence at once through
+    ``kda_reference`` (packed sequences are not kept apart); the serving engine
+    keeps the state and the last ``kda_conv - 1`` rows of the pre-convolution
+    ``q | k | v`` a slot (``llm/model_runner.py``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h):
+        from ray_tpu.ops.kda import kda_reference
+
+        cfg = self.cfg
+        H, K, r = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
+        dense = lambda feats, axes, name: nn.DenseGeneral(  # noqa: E731
+            features=feats, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.normal(cfg.init_std("kda")), axes))
+        w = self.param("conv_kernel", nn.initializers.normal(
+            cfg.kda_conv ** -0.5), (cfg.kda_conv, 3 * H * K), cfg.param_dtype)
+        qkv = nn.silu(causal_conv(
+            dense(3 * H * K, ("embed", "mlp"), "qkv_proj")(h),
+            w.astype(cfg.dtype), 0))
+        q, k, v = (t.reshape(*t.shape[:-1], H, K)
+                   for t in jnp.split(qkv, 3, axis=-1))
+        q, k = kda_qk_norm(q, k)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (H * K,), jnp.float32)
+        A_log = self.param("A_log", lambda key, shape: jnp.log(
+            jax.random.uniform(key, shape, minval=1.0, maxval=16.0)), (H,))
+        g = kda_log_decay(
+            dense(H * K, (None, "mlp"), "f_b")(
+                dense(r, ("embed", None), "f_a")(h)), dt_bias, A_log)
+        beta = nn.sigmoid(dense(H, ("embed", None), "b_proj")(h)
+                          .astype(jnp.float32))
+        o, _ = kda_reference(q, k, v, g, beta)
+        gate = dense(H * K, (None, "mlp"), "g_b")(
+            dense(r, ("embed", None), "g_a")(h))
+        o = RMSNorm(cfg.norm_eps, cfg.dtype, axis=None, name="o_norm")(o) \
+            * nn.sigmoid(gate.reshape(o.shape))
+        return dense(cfg.d_model, ("mlp", "embed"), "o_proj")(
+            o.reshape(*o.shape[:-2], H * K))
+
+
+def kda_qk_norm(q, k):
+    """q, k [.., H, K] -> ``q / |q| * K^-0.5`` and ``k / |k|`` a head, the
+    norms in float32, handed on in the type they came in."""
+    def unit(t):
+        t32 = t.astype(jnp.float32)
+        return t32 * jax.lax.rsqrt(
+            jnp.sum(t32 * t32, axis=-1, keepdims=True) + 1e-6)
+    return ((unit(q) * q.shape[-1] ** -0.5).astype(q.dtype),
+            unit(k).astype(k.dtype))
+
+
+def kda_log_decay(f, dt_bias, A_log):
+    """The decay gate's projection f [.., H x K] -> the log-decay g [.., H, K]
+    float32: ``-exp(A_log[head]) * softplus(f + dt_bias)``."""
+    H = A_log.shape[0]
+    step = jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
+    return -jnp.exp(A_log)[:, None] * step.reshape(*f.shape[:-1], H, -1)
 
 
 class MLP(nn.Module):
@@ -835,6 +935,8 @@ class Block(nn.Module):
             a = ShortConv(cfg, name="conv")(norm("attn_norm")(x))
         elif self.kind == "mamba2":
             a = Mamba2(cfg, name="mamba")(norm("attn_norm")(x))
+        elif self.kind == "kda":
+            a = KDA(cfg, name="kda")(norm("attn_norm")(x))
         else:
             a = Attention(cfg, self.kind, name="attn")(
                 norm("attn_norm")(x), positions, segment_ids)

@@ -50,7 +50,10 @@ KEYS = {
     "prefill_cross_rows",
     # the slots whose Mamba-2 state a decode step moves, and those of them
     # that decode; 0 without such layers
-    "ssd_step_slots", "ssd_step_live_slots"}
+    "ssd_step_slots", "ssd_step_live_slots",
+    # the same two for delta-rule layers, and the real positions a prefill
+    # call ran through their chunked scan; 0 without such layers
+    "kda_step_slots", "kda_step_live_slots", "kda_prefill_positions"}
 LADDER = tuple(f"itl_over_{n}ms" for n in (25, 50, 100, 200, 400, 800))
 PHASES = ("admit_ms", "prefill_dispatch_ms", "decode_dispatch_ms",
           "sample_dispatch_ms", "readback_ms", "emit_ms")

@@ -653,7 +653,7 @@ def test_the_cells_metrics_read_a_window_as_data(cell):
     raised."""
     listed = {m["name"]: m for m in cell.per_layer()}
     for name in NEW_METRICS:
-        assert listed[name]["workloads"] == [CELL]
+        assert CELL in listed[name]["workloads"]   # in the list: later cells join
         assert listed[name]["moves"] == "serve_tokens_per_s"
         assert set(cell.reader(name)) == {"reduce", "args"}
     assert {"moe_gmm_decode_roofline", "moe.expert_dev_ms", "moe.max_load",
