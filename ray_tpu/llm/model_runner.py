@@ -82,14 +82,22 @@ the step. The kinds, and where each keeps what:
   lanes]`` as ``ops/kda.py`` keeps it (2.1 MB a slot and layer at 32 heads of
   128 x 128), and in ``conv`` the last ``kda_conv - 1`` rows of the three
   convolutions' input ``q | k | v``. Prefill runs the chunked delta rule
-  over the bucket (``kda_scan``, padding passed over with ``g = 0, beta =
-  0``) and WRITES the slot's state and tail from the prompt alone, which is
-  how a slot is reset at admission, reused, or given back to a preempted
-  request; a decode step convolves the tail with the new input, steps every
-  slot's state once, in place (``kda_step``; beside a prompt ``kda_riding``
-  with ``keep``), and shifts the tail. One product (``qkv_proj``) makes ``q |
-  k | v`` and one the low-rank gates' inner halves and ``beta`` for all
-  rows; the output norm, its gate and ``o_proj`` run once over all rows.
+  over the bucket (``kda_scan`` through ``ops/kda.py:kda_prefill``, padding
+  passed over from ``lengths`` on) and WRITES the slot's state and tail from
+  the prompt alone, which is how a slot is reset at admission, reused, or
+  given back to a preempted request; a decode step convolves the tail with
+  the new input, steps every slot's state once, in place (``kda_step``;
+  beside a prompt ``kda_riding`` with ``keep``), and shifts the tail. One
+  product (``qkv_proj``) makes ``q | k | v`` and one the low-rank gates'
+  inner halves and ``beta`` for all rows, and ``o_proj`` runs once over all
+  rows; what lies between is each side's own. The prompt side: the
+  convolutions with the silu behind them stay XLA's (one fusion over ``[R,
+  S, 3 H K]``), then ONE kernel takes that array, ``f``, beta and the output
+  gate as their products left them and does the l2 norms, the log-decay,
+  beta's folds, the recurrence, the head's output norm and the gate in its
+  tile, and writes ``o`` in the products' type as ``o_proj`` reads it. The
+  step side (``[B, 1]`` rows) does the same arithmetic in XLA around
+  ``kda_step`` (``_kda_operands`` before it, the norm and gate after).
 
 ``prefill`` is told the slot a row fills (``slots``), overwrites the slot's
 rings and rows from the prompt alone (which is how a slot is reset at
@@ -924,8 +932,9 @@ def _kda_inputs(x, lp, cfg):
     """A "kda" layer's projections of the normalised stream x [.., D], for
     all rows: ONE product for ``q | k | v`` before their convolutions (what a
     slot keeps the tail of), one for the inner halves of the two low-rank
-    gates and ``beta``. Returns (the decay gate's ``f`` [.., H K] and ``beta``
-    [.., H] float32, side by side), (``q | k | v``, the output gate [.., H K])."""
+    gates and ``beta``. Returns (the decay gate's ``f`` [.., H K], ``beta``
+    [.., H] float32, the output gate [.., H K]: each as its product left it),
+    ``q | k | v``."""
     m, r = lp["kda"], cfg.kda_gate_rank
     h = _normed(x, lp["attn_norm"], cfg)
     with jax.named_scope("kda.in_proj"):
@@ -937,79 +946,77 @@ def _kda_inputs(x, lp, cfg):
         f = _dense(inner[..., :r], m["f_b"], cfg.dtype)
         gate = _dense(inner[..., r:2 * r], m["g_b"], cfg.dtype)
         beta = jax.nn.sigmoid(inner[..., 2 * r:].astype(jnp.float32))
-    return jnp.concatenate([f.astype(jnp.float32), beta], axis=-1), (qkv, gate)
+    return (f, beta, gate), qkv
 
 
-def _kda_operands(a, fb, m, cfg):
-    """The convolved ``q | k | v`` a [.., 3 H K] and ``f | beta`` fb [.., H K
-    + H] -> what the recurrence takes: q, k (unit length a head, q times
-    ``K^-0.5``), v [.., H, K], the log-decay g [.., H, K] and beta [.., H],
-    both float32."""
+def _kda_operands(a, f, m, cfg):
+    """The convolved ``q | k | v`` a [.., 3 H K] and the decay gate's ``f``
+    [.., H K] -> what the recurrence takes: q, k (unit length a head, q times
+    ``K^-0.5``), v [.., H, K] and the log-decay g [.., H, K] float32. In XLA:
+    a decode step's rows and the tests'; a prefill call's are
+    ``ops/kda.py:kda_prefill``'s."""
     from ray_tpu.models.transformer import kda_log_decay, kda_qk_norm
 
     H, K = cfg.kda_heads, cfg.kda_head_dim
     q, k, v = (t.reshape(*t.shape[:-1], H, K)
                for t in jnp.split(jax.nn.silu(a), 3, axis=-1))
     q, k = kda_qk_norm(q, k)
-    g = kda_log_decay(fb[..., :H * K], m["dt_bias"], m["A_log"])
-    return q, k, v, g, fb[..., H * K:]
+    return q, k, v, kda_log_decay(f, m["dt_bias"], m["A_log"])
 
 
-def _kda_prefill(qkv, fb, lp, cfg, kept, layer, slots, lengths, in_prompt):
+def _kda_prefill(qkv, gates, lp, cfg, kept, layer, slots, lengths):
     """A "kda" layer's recurrence over a prefill call's rows (qkv [R, S, 3 H
-    K], fb [R, S, H K + H]) from a zero state, and ``kept`` (the states, the
-    convolutions' tails) with the rows of ``slots`` left at the prompts' last
-    position: the state after it and the ``kda_conv - 1`` inputs before the
-    next (zeros where the prompt has none). Padding behind a prompt neither
-    moves the state (``g = 0, beta = 0``) nor enters the tail."""
+    K], gates: f, beta and the output gate of those rows) from a zero state,
+    and ``kept`` (the states, the convolutions' tails) with the rows of
+    ``slots`` left at the prompts' last position: the state after it and the
+    ``kda_conv - 1`` inputs before the next (zeros where the prompt has
+    none). Between the convolutions (the silu fused behind them) and
+    ``o_proj`` there is ONE kernel: the norms of q and k, the log-decay, beta
+    and the output's norm and gate happen in its tile
+    (``ops/kda.py:kda_prefill``). Padding behind a prompt neither moves the
+    state (the kernel passes over positions from ``lengths`` on) nor enters
+    the tail."""
     from ray_tpu.models.transformer import causal_conv
-    from ray_tpu.ops.kda import kda_scan
+    from ray_tpu.ops.kda import kda_prefill
 
     ssm, conv = kept
     m, tail = lp["kda"], cfg.kda_conv - 1
     tail_pos = lengths[:, None] - tail + jnp.arange(tail)[None]
     with jax.named_scope("kda.conv"):
-        q, k, v, g, beta = _kda_operands(causal_conv(
-            qkv, m["conv_kernel"].astype(cfg.dtype), 0), fb, m, cfg)
-        g = jnp.where(in_prompt[..., None, None], g, 0.0)
-        beta = jnp.where(in_prompt[..., None], beta, 0.0)
-    o, state = kda_scan(q, k, v, g, beta)
+        a = jax.nn.silu(causal_conv(qkv, m["conv_kernel"].astype(cfg.dtype), 0))
+    o, state = kda_prefill(a, *gates, m["dt_bias"], m["A_log"],
+                           m["o_norm"]["scale"], lengths, eps=cfg.norm_eps)
     # [layer, tap, slot]: the indexed axes come first, [R, K-1, 3 H K]
     return o, (ssm.at[layer, slots].set(state),
                conv.at[layer, :, slots].set(_rows_at(qkv, tail_pos)))
 
 
-def _kda_step(qkv, fb, lp, cfg, kept, layer, keep, op):
+def _kda_step(qkv, gates, lp, cfg, kept, layer, keep, op):
     """A "kda" layer's recurrence over a decode step's rows (qkv [B, 1, 3 H
-    K], fb [B, 1, H K + H]): the taps over the kept tail and the new input,
-    one step of every slot's state in place (``ops/kda.py:kda_step``), the
-    tail shifted by one; with ``keep`` [B] only the slots it marks move."""
+    K], gates: f, beta and the output gate of those rows): the taps over the
+    kept tail and the new input, one step of every slot's state in place
+    (``ops/kda.py:kda_step``), the tail shifted by one, the output's norm and
+    gate; with ``keep`` [B] only the slots it marks move."""
     from ray_tpu.ops.kda import kda_step
 
     ssm, conv = kept
     m = lp["kda"]
+    f, beta, gate = gates
     with jax.named_scope("kda.conv"):
         taps = jnp.concatenate([conv[layer], qkv[:, 0][None]], axis=0)
-        q, k, v, g, beta = _kda_operands(
+        q, k, v, g = _kda_operands(
             jnp.einsum("kbc,kc->bc", taps, m["conv_kernel"].astype(cfg.dtype)),
-            fb[:, 0], m, cfg)
-    o, ssm = kda_step(ssm, layer, q, k, v, g, beta, keep,
+            f[:, 0], m, cfg)
+    o, ssm = kda_step(ssm, layer, q, k, v, g, beta[:, 0], keep,
                       name="kda_step" if op == "decode" else "kda_" + op)
+    with jax.named_scope("kda.out"):  # [B, 1, H K], what o_proj reads
+        o = _rmsnorm(o, m["o_norm"]["scale"], cfg.norm_eps) * jax.nn.sigmoid(
+            gate.astype(jnp.float32).reshape(o.shape))
+        o = o.astype(cfg.dtype).reshape(gate.shape)
     rows = taps[1:]
     if keep is not None:
         rows = jnp.where(keep[None, :, None], rows, conv[layer])
-    return o[:, None], (ssm, conv.at[layer].set(rows))
-
-
-def _kda_out(gate, o, lp, cfg):
-    """The recurrence's o [.., H, K] float32 under the output norm (one scale
-    for all heads) and the sigmoid of the low-rank ``gate`` [.., H K], then
-    ``o_proj``."""
-    m = lp["kda"]
-    with jax.named_scope("kda.out"):
-        o = _rmsnorm(o, m["o_norm"]["scale"], cfg.norm_eps) * jax.nn.sigmoid(
-            gate.astype(jnp.float32).reshape(o.shape))
-        return _dense(o.reshape(*o.shape[:-2], -1), m["o_proj"], cfg.dtype)
+    return o, (ssm, conv.at[layer].set(rows))
 
 
 def _block_rest(x, o, lp, cfg, valid, name):
@@ -1045,8 +1052,7 @@ def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
         return _mamba2_prefill(row, q, lp, cfg, kept, at, slots, lengths,
                                index[1])
     if kind == "kda":
-        return _kda_prefill(row, q, lp, cfg, kept, at, slots, lengths,
-                            index[1])
+        return _kda_prefill(row, q, lp, cfg, kept, at, slots, lengths)
     _, _, page, offset, _, ring_pos = index
     if kind == "latent":
         kept = kept.at[at, page, offset].set(row, mode="drop")
@@ -1200,8 +1206,8 @@ def _forward(p, cfg, cache, prompt=None, step=None):
             q, (row, gate) = None, _conv_gates(x, lp, cfg)
         elif kind == "mamba2":  # "q": the step sizes, split as queries are
             q, (row, gate) = _mamba2_inputs(x, lp, cfg)
-        elif kind == "kda":  # "q": the decay gate and beta, split likewise
-            q, (row, gate) = _kda_inputs(x, lp, cfg)
+        elif kind == "kda":  # "q": the two gates and beta, split likewise
+            q, row = _kda_inputs(x, lp, cfg)
         else:
             h, q, row = _attn_inputs(x, lp, cfg, positions, kind)
         outs = []
@@ -1214,8 +1220,8 @@ def _forward(p, cfg, cache, prompt=None, step=None):
             o = _conv_out(gate, o, lp, cfg)
         elif kind == "mamba2":
             o = _mamba2_out(gate, o, lp, cfg)
-        elif kind == "kda":
-            o = _kda_out(gate, o, lp, cfg)
+        elif kind == "kda":  # each side's norm and gate are its mixer's
+            o = _dense(o, lp["kda"]["o_proj"], cfg.dtype)
         else:
             o = _attn_out(h, o, lp, cfg)
         x, load = _block_rest(x, o, lp, cfg, valid, name)

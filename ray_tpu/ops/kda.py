@@ -16,7 +16,8 @@ state is 2.1 MB a slot and layer.
 ``kda_reference`` is the recurrence as it reads, one position a ``lax.scan``
 step, float32: what both kernels are held to.
 
-``kda_scan`` (Pallas, name ``kda_scan``) is the chunked evaluation of the SAME
+``kda_scan`` (Pallas, name ``kda_scan``; ``kda_prefill`` below is how the
+engine calls it) is the chunked evaluation of the SAME
 recurrence over a prefill call's rows, from a zero state: the grid is (row,
 head, chunk of ``CHUNK`` positions), a head's chunks follow each other and its
 state stays in fast memory. Over a chunk with ``G_t = sum_{u <= t} g_u`` and
@@ -46,6 +47,29 @@ precision in a float32 one, which is how the tests hold the kernel to the
 reference at 1e-5). A position with ``g = 0`` and ``beta = 0`` leaves the
 state as it was: that is how padding behind a prompt is passed over, so the
 state that comes back is the one after ``lengths - 1``.
+
+``kda_prefill`` is the SAME ``pallas_call`` (one kernel body, one name) handed
+a layer's arrays as its products left them, and is what a prefill call runs:
+everything between the convolutions and ``o_proj`` that is local to a position
+and a head happens in the kernel's [chunk, 128 lanes] tile, so XLA makes no
+pass over ``[S, 4096..12288]`` there and no float32 ``[S, 4096]`` is ever
+written. It takes ``q | k | v`` after the convolutions and the silu as ONE
+array [R, S, 3 H K] through three block specs (a head's lanes at ``h``, ``H +
+h``, ``2 H + h``: no split), the decay gate's ``f`` and the output gate [R, S,
+H K] in the stored type, beta [R, S, H] float32 (the head's column by mask and
+reduce), ``dt_bias``, ``A_log``, the output norm's scale and the prompts'
+lengths (scalar prefetch). Prologue, float32: ``q / |q| K^-0.5``, ``k / |k|``,
+``g = -exp(A_log[h]) softplus(f + dt_bias)``, ``g = 0`` and ``beta = 0`` from
+``lengths[r]`` on, ``beta k``, ``beta v``; q, k and ``beta k`` stay float32
+where the recurrence uses them so and are cast where it casts them (they are
+no longer rounded to the stored type on the way in), ``beta v`` enters its
+product in the stored type as before. Epilogue: the head's RMSNorm of the
+float32 ``o``, the sigmoid of the gate, the cast to the stored type, written
+[R, S, H V] lane-dense as ``o_proj`` reads it. ``kda_scan`` on prepared
+operands is the same body with neither (decided by what it is handed, at
+trace time) and writes ``o`` float32: what the tests hold to
+``kda_reference``. The step grows by about a tenth for it
+(PERF.md section 6, PR 50).
 
 ``CHUNK`` is 128 where the family's own kernels take 64: the solve's cost a
 position grows with the chunk, the state's products shrink with it, and on
@@ -114,17 +138,63 @@ def kda_reference(q, k, v, g, beta, s0=None):
 # -- the chunked form over a prefill call's rows ---------------------------------
 
 
-def _scan_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, sT_ref, s_ref, *,
-                 sub):
+def _raw_operands(len_ref, q_ref, k_ref, v_ref, f_ref, beta_ref, bias_ref,
+                  alog_ref):
+    """The prologue of a grid step that is handed a layer's arrays as the
+    convolution and the gates' products left them: a head's [C, K] lanes of
+    ``q | k | v`` (after the silu) and of the decay gate's ``f``, every head's
+    beta [C, H], the head's ``dt_bias`` [1, K], ``A_log`` [1, H] and the row's
+    length. Returns what the recurrence takes, float32: q and k of unit
+    length (q times ``K^-0.5``), ``beta k``, ``beta v`` (in the stored type,
+    as the products take it) and the log-decay; no decay and no update behind
+    the prompt's end."""
+    f32 = jnp.float32
+    C, K = q_ref.shape
+    r, h, chunk = (pl.program_id(axis) for axis in range(3))
+
+    def unit(ref):
+        t = ref[...].astype(f32)
+        return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+    def own(t):          # the head's column of t [.., H]: mask and reduce
+        lane = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+        return jnp.sum(jnp.where(lane == h, t, 0.0), axis=-1, keepdims=True)
+
+    def in_prompt(shape):
+        at = chunk * C + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        return at < len_ref[r]
+
+    q, k = unit(q_ref) * K ** -0.5, unit(k_ref)
+    g = own(-jnp.exp(alog_ref[...].astype(f32))) * jax.nn.softplus(
+        f_ref[...].astype(f32) + bias_ref[...].astype(f32))
+    g = jnp.where(in_prompt((C, K)), g, 0.0)
+    beta = jnp.where(in_prompt((C, 1)), own(beta_ref[...].astype(f32)), 0.0)
+    vb = (v_ref[...].astype(f32) * beta).astype(v_ref.dtype)
+    return q, k, k * beta, vb, g
+
+
+def _scan_kernel(*refs, sub, eps):
+    """``refs``: the prepared operands (q, k, beta k, beta v, g) or a layer's
+    own arrays with the output gate and the output norm's scale behind them
+    (``_raw_operands``; then ``o`` leaves normalised, gated and in the stored
+    type), the two results, the carried state."""
+    *operands, o_ref, sT_ref, s_ref = refs
     chunk = pl.program_id(2)
 
     @pl.when(chunk == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    C, K = q_ref.shape
-    kind = q_ref.dtype                    # what the large products multiply in
     f32 = jnp.float32
+    raw = len(operands) != 5
+    if raw:
+        *operands, gate_ref, scale_ref = operands
+        q, k, kb, vb, g = _raw_operands(*operands)
+    else:
+        q, k, kb = (ref[...].astype(f32) for ref in operands[:3])
+        vb, g = operands[3][...], operands[4][...]
+    C, K = q.shape
+    kind = vb.dtype                       # what the large products multiply in
     exact = functools.partial(jax.lax.dot_general, precision=_HIGHEST,
                               preferred_element_type=f32)
     mxu = exact if kind == f32 else functools.partial(
@@ -136,8 +206,7 @@ def _scan_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, sT_ref, s_ref, *,
     same = (row >> shift) == (col >> shift)      # within a diagonal block
 
     # the log-decay summed from the chunk's first position on
-    G = exact((row >= col).astype(f32), g_ref[...], _NN)         # [C, K]
-    q, k, kb = (r[...].astype(f32) for r in (q_ref, k_ref, kb_ref))
+    G = exact((row >= col).astype(f32), g, _NN)                  # [C, K]
 
     # what position t reads of position s, rows of ``sub`` positions at a time:
     # n for the update (beta k), p for the output (q)
@@ -196,10 +265,14 @@ def _scan_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, sT_ref, s_ref, *,
     s0 = s_ref[...]
     state = s0.astype(kind)
     w = mxu(A, (kb * eG).astype(kind), _NN)                      # [C, K]
-    u = mxu(A, vb_ref[...], _NN) - mxu(w.astype(kind), state, _NN)
+    u = mxu(A, vb, _NN) - mxu(w.astype(kind), state, _NN)
     u = u.astype(kind)                                           # [C, V]
-    o_ref[...] = mxu((q * eG).astype(kind), state, _NN) \
-        + mxu(P.astype(kind), u, _NN)
+    o = mxu((q * eG).astype(kind), state, _NN) + mxu(P.astype(kind), u, _NN)
+    if raw:     # the head's norm, the output gate, the type o_proj takes
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+        o = o * scale_ref[...].astype(f32) * jax.nn.sigmoid(
+            gate_ref[...].astype(f32))
+    o_ref[...] = o.astype(o_ref.dtype)
     last = G[C - 1:C]                                            # [1, K]
     # diag(e^last) S: the row as a column, through the diagonal's mask
     lane = jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)
@@ -214,49 +287,100 @@ def _scan_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, sT_ref, s_ref, *,
         sT_ref[...] = s_ref[...]
 
 
-def _kda_scan(q, k, kb, vb, g, *, H, T, sub, interpret):
-    R, S, _ = q.shape
-    K, V = q.shape[-1] // H, vb.shape[-1] // H
-    keys = pl.BlockSpec((None, T, K), lambda r, h, t: (r, t, h))
-    values = pl.BlockSpec((None, T, V), lambda r, h, t: (r, t, h))
-    return pl.pallas_call(
-        functools.partial(_scan_kernel, sub=sub),
-        grid=(R, H, S // T),
-        in_specs=[keys, keys, keys, values, keys],
-        out_specs=[values,
-                   pl.BlockSpec((None, None, K, V), lambda r, h, t: (r, h, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((R, S, H * V), jnp.float32),
-                   jax.ShapeDtypeStruct((R, H, K, V), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="kda_scan",
-    )(q, k, kb, vb, g)
-
-
-def kda_scan(q, k, v, g, beta, *, chunk: int = CHUNK):
-    """``kda_reference`` from a zero state through the chunked kernel: q, k
-    [R, S, H, K], v [R, S, H, V], g [R, S, H, K] (<= 0; 0 on padding), beta
-    [R, S, H] (0 on padding) -> (o [R, S, H, V] float32, the state after the
-    last position [R, H, K, V] float32). ``S`` is a power of two up to
-    ``chunk`` or a multiple of it."""
-    R, S, H, _ = q.shape
+def _tile(S, chunk):
+    """The positions a grid step takes of a bucket of ``S``."""
     T = chunk if S % chunk == 0 else S
     sub = min(SUB, T)
     if T % sub or sub & (sub - 1):
         raise ValueError(f"kda_scan takes a power of two up to {chunk} "
                          f"positions or a multiple of {chunk}, got {S}")
+    return T
+
+
+def _head_lanes(T, K, first=0):
+    """A head's ``K`` lanes of ``T`` positions of an array [R, S, n H K], the
+    heads' lanes from head ``first`` on."""
+    return pl.BlockSpec((None, T, K), lambda r, h, t, *_: (r, t, first + h))
+
+
+def _kda_scan(*operands, specs, prefetch, dims, T, eps, o_dtype):
+    """The one ``pallas_call``: ``operands`` under ``specs`` (the first
+    ``prefetch`` of them scalars), ``dims`` = (R, S, H, K, V)."""
+    R, S, H, K, V = dims
+
+    def call(*operands, interpret):
+        return pl.pallas_call(
+            functools.partial(_scan_kernel, sub=min(SUB, T), eps=eps),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=prefetch,
+                grid=(R, H, S // T),
+                in_specs=specs,
+                out_specs=[_head_lanes(T, V),
+                           pl.BlockSpec((None, None, K, V),
+                                        lambda r, h, t, *_: (r, h, 0, 0))],
+                scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct((R, S, H * V), o_dtype),
+                       jax.ShapeDtypeStruct((R, H, K, V), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="kda_scan",
+        )(*operands)
+
+    return jax.lax.platform_dependent(
+        *operands, tpu=functools.partial(call, interpret=False),
+        default=functools.partial(call, interpret=True))
+
+
+def kda_scan(q, k, v, g, beta, *, chunk: int = CHUNK):
+    """``kda_reference`` from a zero state through the chunked kernel, on
+    prepared operands: q, k [R, S, H, K], v [R, S, H, V], g [R, S, H, K] (<=
+    0; 0 on padding), beta [R, S, H] (0 on padding) -> (o [R, S, H, V]
+    float32, the state after the last position [R, H, K, V] float32). ``S``
+    is a power of two up to ``chunk`` or a multiple of it."""
+    R, S, H, K = q.shape
+    V = v.shape[-1]
+    T = _tile(S, chunk)
     b = beta.astype(jnp.float32)[..., None]
     scaled = lambda t: (t.astype(jnp.float32) * b).astype(t.dtype)  # noqa: E731
     flat = lambda t: t.reshape(R, S, -1)   # noqa: E731
-    o, state = jax.lax.platform_dependent(
+    o, state = _kda_scan(
         flat(q), flat(k), flat(scaled(k)), flat(scaled(v)),
         flat(g.astype(jnp.float32)),
-        tpu=functools.partial(_kda_scan, H=H, T=T, sub=sub, interpret=False),
-        default=functools.partial(_kda_scan, H=H, T=T, sub=sub,
-                                  interpret=True))
-    return o.reshape(R, S, H, -1), state
+        specs=[_head_lanes(T, K)] * 3 + [_head_lanes(T, V), _head_lanes(T, K)],
+        prefetch=0, dims=(R, S, H, K, V), T=T, eps=None, o_dtype=jnp.float32)
+    return o.reshape(R, S, H, V), state
+
+
+def kda_prefill(qkv, f, beta, gate, dt_bias, A_log, o_scale, lengths, *,
+                eps: float, chunk: int = CHUNK):
+    """A layer's recurrence over a prefill call's rows from a zero state with
+    everything that is local to a position and a head done in the kernel's
+    tile, on the arrays as the layer's products left them: ``q | k | v`` after
+    the convolutions and the silu qkv [R, S, 3 H K] (one array, read through
+    three views), the decay gate's f [R, S, H K] and the output gate [R, S, H
+    K] in the stored type, beta [R, S, H] float32, ``dt_bias`` [H K], ``A_log``
+    [H], the output norm's scale ``o_scale`` [K], the prompts' lengths [R].
+    Returns (o [R, S, H K] in the stored type: under the head's RMSNorm
+    (``eps``) and the gate's sigmoid, what ``o_proj`` reads, and whatever lies
+    behind a prompt's end is nobody's; the state after ``lengths - 1`` [R, H,
+    K, K] float32). ``S`` as ``kda_scan`` takes it."""
+    R, S, _ = qkv.shape
+    H = A_log.shape[0]
+    K = f.shape[-1] // H
+    T = _tile(S, chunk)
+    head = _head_lanes(T, K)
+    whole = lambda n: pl.BlockSpec(   # noqa: E731
+        (1, n), lambda r, h, t, *_: (0, 0))
+    return _kda_scan(
+        lengths.astype(jnp.int32), qkv, qkv, qkv, f, beta,
+        dt_bias.reshape(1, -1), A_log.reshape(1, -1), gate,
+        o_scale.reshape(1, -1),
+        specs=[head, _head_lanes(T, K, H), _head_lanes(T, K, 2 * H), head,
+               pl.BlockSpec((None, T, H), lambda r, h, t, *_: (r, t, 0)),
+               pl.BlockSpec((1, K), lambda r, h, t, *_: (0, h)), whole(H),
+               head, whole(K)],
+        prefetch=1, dims=(R, S, H, K, K), T=T, eps=eps, o_dtype=qkv.dtype)
 
 
 # -- one decode step of one layer, every slot, in place --------------------------

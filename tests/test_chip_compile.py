@@ -1067,12 +1067,18 @@ def test_kimi_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
     and the largest bucket, ``[1, 16384]``, beside 7.54 GB of weights and 3.6
     GB of state, tails and latent rows: the chunked delta rule in six layers,
     two flash calls over 192-wide q . k, twenty-one grouped matmuls, under the
-    chip's 15.75 GiB."""
+    chip's 15.75 GiB. The kernel writes ``o`` normalised, gated and in the
+    stored type, ``[rows, bucket, 4096]`` as ``o_proj`` reads it, and no
+    float32 array of a prompt's positions by all heads' lanes, flat or by
+    head (what the layer's elementwise passes wrote while XLA made them:
+    the float32 ``f``, ``g`` and ``o``), is anybody's result."""
     _, prefill, _ = _lower_rms_kinds(one_chip, "kimi-linear-48b-a3b")
     compiled = prefill(rows, bucket).compile()
     text = compiled.as_text()
     assert len(set(re.findall(
-        rf"%(kda_scan\S*) = \(f32\[{rows},{bucket},4096\]", text))) == 6
+        rf"%(kda_scan\S*) = \(bf16\[{rows},{bucket},4096\]", text))) == 6
+    assert not re.findall(
+        rf"= \(?f32\[{rows},{bucket},(?:4096|32,128)\]", text)
     assert len(set(re.findall(r"%(flash_fwd\S*) = ", text))) == 2
     riding = len(set(re.findall(r"%(kda_riding\S*) = ", text)))
     assert riding == (6 if (rows, bucket) == (1, 512) else 0)

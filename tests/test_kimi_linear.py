@@ -202,6 +202,76 @@ def test_scan_kernel_in_bfloat16_is_one_rounding_a_product():
     assert _rel(o, want_o) < 2e-2 and _rel(s, want_s) < 2e-2
 
 
+def _layer_arrays(R, S, H, K, seed, extreme, dtype):
+    """What a "kda" layer hands its prefill kernel: the convolutions' output
+    ``q | k | v`` before the silu, the decay gate's ``f``, beta, the output
+    gate (the products' in ``dtype``) and the layer's own constants as they
+    are seeded (``A`` up to 16, steps log-uniform in [1e-3, 1e-1]);
+    ``extreme``: every lane at the fastest decay those give."""
+    key = jax.random.split(jax.random.PRNGKey(seed), 7)
+    a = jax.random.normal(key[0], (R, S, 3 * H * K))
+    gate = jax.random.normal(key[1], (R, S, H * K))
+    beta = jax.nn.sigmoid(jax.random.normal(key[2], (R, S, H)))
+    dt = jnp.exp(jax.random.uniform(key[3], (H * K,)) * np.log(100.0)
+                 + np.log(1e-3))
+    m = {"dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+         "A_log": jnp.log(jax.random.uniform(key[4], (H,), minval=1.0,
+                                             maxval=16.0)),
+         "o_norm": {"scale": 1.0 + 0.2 * jax.random.normal(key[5], (K,))}}
+    f = jax.random.normal(key[6], (R, S, H * K))
+    if extreme:  # softplus(f + dt_bias) = 0.24 under A = 16
+        m["A_log"] = jnp.full((H,), np.log(16.0))
+        f = jnp.full_like(f, np.log(np.expm1(-EXTREME / 16))) - m["dt_bias"]
+    return a.astype(dtype), f.astype(dtype), beta, gate.astype(dtype), m
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("R,S,chunk,lengths,extreme", [
+    (1, 64, 16, None, False), (1, 64, 64, None, False),
+    (2, 32, 16, [32, 13], False), (3, 16, 64, [2, 16, 0], False),
+    (1, 8, 64, [5], False), (1, 128, 64, None, True),
+    (2, 512, kda.CHUNK, [400, 270], False),  # one call, two prompts
+], ids=["edges", "series", "padding", "short-and-empty", "one-block",
+        "extreme-decay", "two-rows"])
+def test_prefill_kernel_is_the_layer_between_conv_and_o_proj(
+        R, S, chunk, lengths, extreme, dtype, tol):
+    """``kda_prefill`` on a layer's arrays as its products left them against
+    the composition it replaces, in float32 on the same values: the
+    operands made in XLA (``_kda_operands``), the recurrence as it reads with
+    no decay and no update behind a prompt's end, the head's norm and the
+    gate. In float32 to float32's own rounding; in bfloat16 within what
+    rounding a product's operands once costs, o in the stored type as
+    ``o_proj`` takes it. The state is the one after ``lengths - 1``; o behind
+    a prompt's end is nobody's."""
+    import types
+
+    H, K, eps = 2, 16, 1e-5
+    cfg = types.SimpleNamespace(kda_heads=H, kda_head_dim=K)
+    a, f, beta, gate, m = _layer_arrays(R, S, H, K, S + R, extreme, dtype)
+    n = jnp.asarray(lengths or [S] * R, jnp.int32)
+    real = jnp.arange(S)[None, :] < n[:, None]
+    wide = lambda t: t.astype(jnp.float32)   # noqa: E731
+    q, k, v, g = mr._kda_operands(wide(a), wide(f), m, cfg)
+    want_o, want_s = kda.kda_reference(
+        q, k, v, jnp.where(real[..., None, None], g, 0.0),
+        jnp.where(real[..., None], beta, 0.0))
+    want_o = mr._rmsnorm(want_o, m["o_norm"]["scale"], eps) * jax.nn.sigmoid(
+        wide(gate).reshape(want_o.shape))
+    o, s = jax.jit(lambda *t: kda.kda_prefill(*t, eps=eps, chunk=chunk))(
+        jax.nn.silu(a), f, beta, gate, m["dt_bias"], m["A_log"],
+        m["o_norm"]["scale"], n)
+    assert o.dtype == dtype and o.shape == (R, S, H * K)
+    assert s.dtype == jnp.float32 and np.isfinite(np.asarray(s)).all()
+    live = real[..., None]
+    assert _rel(jnp.where(live, wide(o), 0),
+                jnp.where(live, want_o.reshape(o.shape), 0)) < tol
+    assert _rel(s, want_s) < tol
+    if lengths and 0 in lengths:   # a padding row leaves a zero state
+        assert not np.asarray(s[lengths.index(0)]).any()
+
+
 def test_step_kernel_steps_one_layer_in_place():
     """One position for every slot on ONE layer of the leaf: to float32's
     own rounding the recurrence's step, a slot that is not kept to the bit
