@@ -130,7 +130,13 @@ from ``__init__``, so two snapshots subtract):
   window's two deltas cover the same calls): ``prefill_phase_ms /
   prefill_phase_calls`` and ``decode_phase_ms / decode_phase_calls`` are a
   prefill call's (of however many rows) and a decode step's time with their
-  share of the sampler. When the host arrives
+  share of the sampler, and ``prefill_phase_positions`` /
+  ``prefill_phase_real_positions`` are ``prefill_batch_tokens`` and
+  ``prefill_tokens`` again by the same rule: ``prefill_phase_positions /
+  prefill_phase_ms`` is what a padded position of prefill costs over every
+  call of a window, whatever buckets it drew. ``generated_tokens /
+  phase_ms`` is the tokens a busy millisecond brings: both move at a read,
+  so no request's end and no window's edge is in it. When the host arrives
   late a read returns at once and the host's time lands on it: a prefill
   phase shorter than the host's own way from one read to the next (dispatch,
   emit, the caller's hop: 5-8 ms) reads as that long, and the decode step
@@ -138,6 +144,29 @@ from ``__init__``, so two snapshots subtract):
   device waits for the host; on the CPU backend, which runs a program inside
   its dispatch, always) the split by kind says nothing. ``phase_ms`` stays
   the busy time either way;
+- reads that stalled: a read that waited over ``_STALL_MS`` (1,000 ms: nearly
+  three times the longest program of any benchmark cell) for EACH call it stood
+  behind adds one to ``stalled_reads`` and its wait to ``stalled_read_ms``,
+  and the engine logs one line (``stalled read: kind= calls= bucket= rows=``,
+  the wait, the time since its dispatch, and whether ``compiles`` moved
+  since: a shape's first use on the serving path makes the read behind it
+  wait for the compile). ``stalled_read_ms / phase_ms`` is 0 in a sound run;
+- every slot's row of every decode step (riding ones too), by what filled or
+  emptied it, counted at the step's dispatch: ``slot_steps`` (``max_num_seqs``
+  a step) = ``slot_steps_live`` (rows that decode; a row whose request a
+  stop token, read a step late, had ended is among them and
+  ``dropped_tokens`` says how many, so once everything is read
+  ``slot_steps_live == generated_tokens + dropped_tokens - admitted``) +
+  ``slot_steps_prefilling`` (in a step whose decode rows ride a prefill
+  call, the slots that went to a request in that very step: the call runs
+  their prompts, their first decode row is the next step's; 0 where nothing
+  rides) + ``slot_steps_starved`` (empty, and the admission before the step
+  left nobody waiting: whoever offers the load set this step's batch, not
+  the engine) + ``slot_steps_page_blocked`` (empty though somebody waited:
+  the queue's head lacked pages, or ``_grow_pages`` found the pool empty and
+  sent a decoding request back to the queue). Nothing else empties a row: a
+  slot freed by length is free at its last dispatch and the next call admits
+  into it;
 - per request, summed: ``queue_wait_ms`` (``add_request`` to first
   admission) and ``ttft_ms`` (``add_request`` to first token);
 - the gaps a request sees between its tokens, each token dated by the moment
@@ -184,11 +213,10 @@ from ``__init__``, so two snapshots subtract):
   of them, ``ops/ssd.py:ssd_step`` walks every slot) and
   ``ssd_step_live_slots`` (those of them that decode). For a model with
   delta-rule layers (``"kda"``) the same two under ``kda_step_slots`` /
-  ``kda_step_live_slots`` (``ops/kda.py:kda_step`` walks every slot too), and
-  per prefill call and such layer ``kda_prefill_positions``: the real
-  positions the call ran through ``kda_scan`` (against ``prefill_batch_tokens``
-  times the layers: what it walked, padding included). Its latent layers
-  count under ``mla_decode_*`` as a latent model's do.
+  ``kda_step_live_slots`` (``ops/kda.py:kda_step`` walks every slot too);
+  what ``kda_scan`` walks in prefill is ``prefill_batch_tokens`` a layer, the
+  real positions among them ``prefill_tokens``. Its latent layers count
+  under ``mla_decode_*`` as a latent model's do.
 
 Such a model's rings and rows need no allocator: a slot owns its own, a
 prefill call overwrites all of them from the prompt (the engine tells it the
@@ -200,13 +228,18 @@ by name: a request's state is not a gather of its pages.
 The same boundaries are spans on the profiler's clock
 (``util.tracing.annotate``): a ``jax.profiler`` trace taken in the process
 that owns the engine shows ``ray_tpu/engine.step`` on the host plane and,
-inside it, in this order, ``engine.admit``, ``.prefill_dispatch`` (arguments
+inside it, in this order, ``engine.admit`` (arguments, all as the admission
+left them: ``waiting``, the queue's length; ``free_slots``; ``free_pages``;
+``stopped``: why it admitted no more, ``queue``: nobody waited, ``slots``:
+none was free, ``pages``: the queue's head lacked pages, or a slot that
+decoded was sent back to the queue for one: why a row of this step's batch
+runs empty, step by step), ``.prefill_dispatch`` (arguments
 ``bucket``, the largest of the phase, ``admitted``, ``calls``, ``rows``:
 the calls' rows, padding included, and ``riding``: the decoding slots whose
 step the phase is to carry, 0 where none), ``.sample_dispatch``
 (argument ``greedy``: no slot samples, the program takes its ``argmax``
 branch), ``.decode_dispatch`` (arguments ``overlapped``: 1 if an earlier step
-is unread, ``dropped``: ``dropped_tokens`` so far; ``experts``: experts touched
+is unread; ``experts``: experts touched
 per layer in the newest decode step the host has read, and ``held``: that
 step's assignments per layer to experts held here, models with experts only; ``live_tokens``: positions the step attends over through the block
 tables, models with a latent cache or with ``layer_kinds`` only;
@@ -253,6 +286,10 @@ logger = logging.getLogger(__name__)
 _ITL_RUNGS_MS = (25, 50, 100, 200, 400, 800)
 _ITL_RUNGS_NS = np.array(_ITL_RUNGS_MS, np.int64) * 1_000_000
 _ITL_KEYS = tuple(f"itl_over_{n}ms" for n in _ITL_RUNGS_MS)
+# a read stalled if it waited longer than this for EACH model call it stood
+# behind: the longest program of any benchmark cell, a ``[1, 16384]`` prefill
+# call, takes 359 ms on the chip (PERF.md section 6, PR 50)
+_STALL_MS = 1000
 
 # rows of a prefill call that holds more than one request: a group of three
 # takes the four with one row of padding
@@ -377,6 +414,11 @@ class _Unread:
     bucket: int  # the largest of the prefill calls' length buckets; 0 = decode
     sent_ns: int  # perf_counter_ns at its dispatch
     moe_load: Any = None  # a decode step's routing, outside the donated cache
+    # a prefill phase's positions, as the device computes them (the ``R x S``
+    # of its calls) and as the prompts hold them; 0 for a decode step
+    positions: int = 0
+    real_positions: int = 0
+    compiles: int = 0  # ``metrics["compiles"]`` at its dispatch
 
 
 @dataclasses.dataclass
@@ -498,6 +540,9 @@ class JaxLLMEngine:
         self._slots: List[Optional[_Request]] = [None] * B
         self._free_pages = collections.deque(range(1, e.num_pages))
         self._waiting: collections.deque[_Request] = collections.deque()
+        # why the newest admission stopped: "queue", "slots" or "pages"
+        # (_try_admit; a preemption afterwards makes it "pages")
+        self._stopped = "queue"
         self._requests: Dict[str, _Request] = {}
         self._rng = jax.random.PRNGKey(seed)
         self._compile_watch = goodput.CompileWatch()
@@ -524,6 +569,10 @@ class JaxLLMEngine:
             "sample_calls": 0, "sample_greedy_calls": 0,
             "prefill_phase_ms": 0.0, "decode_phase_ms": 0.0, "phase_ms": 0.0,
             "prefill_phase_calls": 0, "decode_phase_calls": 0,
+            "prefill_phase_positions": 0, "prefill_phase_real_positions": 0,
+            "stalled_reads": 0, "stalled_read_ms": 0.0,
+            "slot_steps": 0, "slot_steps_live": 0, "slot_steps_starved": 0,
+            "slot_steps_page_blocked": 0, "slot_steps_prefilling": 0,
             "itl_ms": 0.0, "itl_tokens": 0, **dict.fromkeys(_ITL_KEYS, 0),
             "moe_decode_layer_steps": 0, "moe_decode_assignments": 0,
             "moe_decode_experts_touched": 0, "moe_decode_max_load": 0,
@@ -532,8 +581,7 @@ class JaxLLMEngine:
             "shared_kv_live_tokens": 0, "shared_kv_read_tokens": 0,
             "window_live_tokens": 0, "prefill_cross_rows": 0,
             "ssd_step_slots": 0, "ssd_step_live_slots": 0,
-            "kda_step_slots": 0, "kda_step_live_slots": 0,
-            "kda_prefill_positions": 0}
+            "kda_step_slots": 0, "kda_step_live_slots": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
 
@@ -620,7 +668,11 @@ class JaxLLMEngine:
             self._temps[req.slot] = 0.0
             req.slot = -1
 
-    def _try_admit(self) -> List[_Request]:
+    def _try_admit(self) -> Tuple[List[_Request], int]:
+        """Waiting requests into free slots, oldest first, until nobody
+        waits (``_stopped`` = "queue"), no slot is free ("slots") or the
+        queue's head lacks pages ("pages"). Returns the admitted requests
+        and how many slots are still free."""
         admitted = []
         free_slots = [i for i, s in enumerate(self._slots) if s is None]
         while self._waiting and free_slots:
@@ -628,6 +680,7 @@ class JaxLLMEngine:
             need = max(1, math.ceil(len(req.cache_tokens)
                                     / self.ecfg.page_size))
             if len(self._free_pages) < need:
+                self._stopped = "pages"
                 break
             self._waiting.popleft()
             req.slot = free_slots.pop(0)
@@ -639,7 +692,9 @@ class JaxLLMEngine:
             self._seq_lens[req.slot] = len(req.cache_tokens)
             self._set_sampling(req)
             admitted.append(req)
-        return admitted
+        else:
+            self._stopped = "slots" if self._waiting else "queue"
+        return admitted, len(free_slots)
 
     def _set_sampling(self, req: _Request) -> None:
         p = req.params
@@ -686,6 +741,7 @@ class JaxLLMEngine:
                     continue
             self.metrics["preempted"] += 1
             self._requeue(req)
+            self._stopped = "pages"  # the pool sets this step's batch now
 
     def _next_rng(self):
         self._rng, sub = self._mr.split_key(self._rng)
@@ -742,12 +798,13 @@ class JaxLLMEngine:
     @contextlib.contextmanager
     def _phase(self, name: str, **attrs):
         """One phase of ``step()``: the span ``engine.<name>`` on the
-        profiler's clock, its host time in ``metrics[<name>_ms]`` and its end
-        in ``_phase_ended_ns``."""
+        profiler's clock (yielded: what a phase learns on its way it adds
+        with ``set_metadata``), its host time in ``metrics[<name>_ms]`` and
+        its end in ``_phase_ended_ns``."""
         t0 = time.perf_counter_ns()
         try:
-            with tracing.annotate("engine." + name, **attrs):
-                yield
+            with tracing.annotate("engine." + name, **attrs) as span:
+                yield span
         finally:
             self._phase_ended_ns = t1 = time.perf_counter_ns()
             self.metrics[name + "_ms"] += (t1 - t0) / 1e6
@@ -827,8 +884,8 @@ class JaxLLMEngine:
         # one sampler call samples both, and 2) is not run in this step (the
         # requests admitted here take their second token in the next).
         calls, ride = [], False
-        with self._phase("admit"):
-            admitted = self._try_admit()
+        with self._phase("admit") as span:
+            admitted, free_slots = self._try_admit()
             now = time.perf_counter()
             for r in admitted:
                 if not r.t_admitted:  # not a re-admission after preemption
@@ -848,6 +905,10 @@ class JaxLLMEngine:
                     # dispatched: may read what is in flight, may preempt
                     self._grow_pages()
                     ride = bool(self._active.any())
+            # why a row of this step's batch runs empty, on the device's clock
+            span.set_metadata(
+                waiting=len(self._waiting), free_slots=free_slots,
+                free_pages=len(self._free_pages), stopped=self._stopped)
         riders: List[_Request] = []
         if admitted:
             overlapped = self._earlier > 0
@@ -868,6 +929,7 @@ class JaxLLMEngine:
                 # admitted request's first
                 self._tokens = firsts
                 self._count_decode_reads()
+                self._count_slot_steps(prefilling=len(admitted))
                 m["decode_steps"] += 1
                 m["riding_steps"] += 1
                 self._seq_lens[self._active] += 1
@@ -875,24 +937,23 @@ class JaxLLMEngine:
                 self._tokens = mr.select_rows(
                     jnp.asarray(np.isin(np.arange(len(self._slots)), slots)),
                     firsts, self._tokens)
+            real = int(self._seq_lens[slots].sum())
+            positions = sum(R * S for R, S, _ in calls)
             m["prefill_steps"] += 1
             m["admitted"] += len(admitted)
             m["prefill_calls"] += len(calls)
-            m["prefill_tokens"] += int(self._seq_lens[slots].sum())
-            m["kda_prefill_positions"] += (
-                self._kda_layers * int(self._seq_lens[slots].sum()))
-            m["prefill_batch_tokens"] += sum(R * S for R, S, _ in calls)
+            m["prefill_tokens"] += real
+            m["prefill_batch_tokens"] += positions
             if self.mcfg.sambay:  # the cross-decoder ran one row a row of a call
                 m["prefill_cross_rows"] += rows
             self._active[slots] = True
             self._sent(firsts, admitted + riders, "prefill", len(calls),
-                       bucket)
+                       bucket, positions=positions, real_positions=real)
 
         # 2) one decode step for all active slots, on the tokens the device
         # holds: the host advances what does not depend on a token's value
         if decode and not riders and self._active.any():
-            attrs = dict(self._experts_attr, overlapped=int(self._earlier > 0),
-                         dropped=m["dropped_tokens"])
+            attrs = dict(self._experts_attr, overlapped=int(self._earlier > 0))
             if self.mcfg.kv_latent_rank or self.mcfg.layer_kinds:
                 # positions the step attends over, through the block tables
                 attrs["live_tokens"] = int(
@@ -907,6 +968,7 @@ class JaxLLMEngine:
                 if decoding:
                     overlapped = overlapped or self._earlier > 0
                     self._count_decode_reads()
+                    self._count_slot_steps()
                     with self._first_use("decode"):
                         logits, self.cache = mr.decode_step(
                             self.params, self.mcfg, self.cache,
@@ -1031,17 +1093,41 @@ class JaxLLMEngine:
             self.metrics[name + "_step_live_slots"] += (
                 layers * int(self._active.sum()))
 
+    def _count_slot_steps(self, prefilling: int = 0) -> None:
+        """Every slot's row of the decode step being dispatched (alone, or
+        riding a prefill call) by its state: it decodes (``live``: a token
+        that a stop token, read a step late, has already ended is among them,
+        ``dropped_tokens`` says how many), or its slot went in this very step
+        to one of ``prefilling`` requests whose prompt the carrying call runs
+        (their first decode row is the next step's), or it is empty: because
+        the admission before it left nobody waiting (``starved``: whoever
+        offers the load set this step's batch), else because the page pool
+        did (``page_blocked``: the queue's head lacked pages, or every slot
+        was held and one was emptied after the admission, which only
+        ``_grow_pages`` does, on an empty pool)."""
+        m = self.metrics
+        slots, live = len(self._slots), int(self._active.sum())
+        empty = ("slot_steps_starved" if self._stopped == "queue"
+                 else "slot_steps_page_blocked")
+        m["slot_steps"] += slots
+        m["slot_steps_live"] += live
+        m["slot_steps_prefilling"] += prefilling
+        m[empty] += slots - live - prefilling
+
     def _sent(self, tokens, reqs: List[_Request], kind: str, calls: int,
-              bucket: int = 0, moe_load=None) -> None:
+              bucket: int = 0, moe_load=None, positions: int = 0,
+              real_positions: int = 0) -> None:
         """A sampler call is on its way with a token for each of ``reqs``,
-        behind ``calls`` prefill calls (the largest at ``bucket``) or one
+        behind ``calls`` prefill calls (the largest at ``bucket``, over
+        ``positions`` padded and ``real_positions`` real positions) or one
         decode step. A request that ends by length with it ends at a step the
         host can count: its slot and pages are free at once (the device runs
         its programs in order, so whatever is queued behind this call may
         have them)."""
         self._unread.append(_Unread(
             tokens, [(r, r.slot) for r in reqs], kind, calls, bucket,
-            time.perf_counter_ns(), moe_load))
+            time.perf_counter_ns(), moe_load, positions=positions,
+            real_positions=real_positions, compiles=self.metrics["compiles"]))
         for r in reqs:
             r.in_flight += 1
             if self._ends_by_length(r, len(r.generated) + r.in_flight):
@@ -1067,7 +1153,17 @@ class JaxLLMEngine:
         self._read_ended_ns = now
         m[u.kind + "_phase_ms"] += waited
         m[u.kind + "_phase_calls"] += u.calls
+        m["prefill_phase_positions"] += u.positions  # 0 behind a decode step
+        m["prefill_phase_real_positions"] += u.real_positions
         m["phase_ms"] += waited
+        if waited > _STALL_MS * u.calls:
+            m["stalled_reads"] += 1
+            m["stalled_read_ms"] += waited
+            logger.warning(
+                "stalled read: kind=%s calls=%d bucket=%d rows=%d waited "
+                "%.0f ms, %.0f ms after its dispatch; compiled since: %s",
+                u.kind, u.calls, u.bucket, len(u.rows), waited,
+                (now - u.sent_ns) / 1e6, m["compiles"] > u.compiles)
         with self._phase("emit"):
             if u.moe_load is not None:
                 self._count_routing(np.asarray(u.moe_load), len(u.rows))

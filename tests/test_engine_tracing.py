@@ -6,6 +6,7 @@ read here is a host time of the CPU backend and is compared with nothing."""
 import contextlib
 import os
 import sys
+import threading
 import types
 
 import numpy as np
@@ -34,6 +35,15 @@ KEYS = {
     # what each blocking read waited for, by kind, and both
     "prefill_phase_ms", "decode_phase_ms", "phase_ms",
     "prefill_phase_calls", "decode_phase_calls",
+    # the positions of the prefill calls those reads waited behind, padded and
+    # real: ``prefill_batch_tokens`` and ``prefill_tokens`` again, but moving
+    # with the read as ``prefill_phase_ms`` does
+    "prefill_phase_positions", "prefill_phase_real_positions",
+    # reads that waited over a second for each call they stood behind
+    "stalled_reads", "stalled_read_ms",
+    # every slot's row of every decode step, by what filled or emptied it
+    "slot_steps", "slot_steps_live", "slot_steps_starved",
+    "slot_steps_page_blocked", "slot_steps_prefilling",
     # the gaps between a request's tokens: sum, number, and how many were
     # strictly longer than each rung
     "itl_ms", "itl_tokens", "itl_over_25ms", "itl_over_50ms",
@@ -51,9 +61,8 @@ KEYS = {
     # the slots whose Mamba-2 state a decode step moves, and those of them
     # that decode; 0 without such layers
     "ssd_step_slots", "ssd_step_live_slots",
-    # the same two for delta-rule layers, and the real positions a prefill
-    # call ran through their chunked scan; 0 without such layers
-    "kda_step_slots", "kda_step_live_slots", "kda_prefill_positions"}
+    # the same two for delta-rule layers; 0 without such layers
+    "kda_step_slots", "kda_step_live_slots"}
 LADDER = tuple(f"itl_over_{n}ms" for n in (25, 50, 100, 200, 400, 800))
 PHASES = ("admit_ms", "prefill_dispatch_ms", "decode_dispatch_ms",
           "sample_dispatch_ms", "readback_ms", "emit_ms")
@@ -104,6 +113,9 @@ def recorder(monkeypatch):
             depth[0] += 1
             return self
 
+        def set_metadata(self, **attrs):  # what a span learns on its way
+            self.attrs.update(attrs)
+
         def __exit__(self, *exc):
             depth[0] -= 1
             return False
@@ -152,7 +164,7 @@ def test_metrics_complete_numeric_monotone(engine):
     _phases_and_gaps_add_up(m, first_tokens=SLOTS + 2)
 
 
-def _phases_and_gaps_add_up(m, first_tokens):
+def _phases_and_gaps_add_up(m, first_tokens, slots=SLOTS):
     """Every read's wait went to one kind; every emitted token but a
     request's first saw one gap, counted on the rungs it is longer than."""
     assert m["prefill_phase_ms"] > 0 and m["decode_phase_ms"] > 0
@@ -161,10 +173,107 @@ def _phases_and_gaps_add_up(m, first_tokens):
     # nothing is left unread: every call dispatched was waited for
     assert m["prefill_phase_calls"] == m["prefill_calls"] <= m["admitted"]
     assert m["decode_phase_calls"] == m["decode_steps"]
+    # so the positions dated by the read are those counted at dispatch
+    assert m["prefill_phase_positions"] == m["prefill_batch_tokens"]
+    assert m["prefill_phase_real_positions"] == m["prefill_tokens"]
+    _slot_account_closes(m, slots)
     assert m["itl_tokens"] == m["generated_tokens"] - first_tokens > 0
     rungs = [m["itl_tokens"]] + [m[k] for k in LADDER]
     assert all(a >= b >= 0 for a, b in zip(rungs, rungs[1:]))
     assert m["itl_ms"] > 0
+
+
+def _slot_account_closes(m, slots=SLOTS):
+    """Every row of every decode step is in exactly one state."""
+    assert m["slot_steps"] == slots * m["decode_steps"]
+    assert m["slot_steps"] == (
+        m["slot_steps_live"] + m["slot_steps_starved"]
+        + m["slot_steps_page_blocked"] + m["slot_steps_prefilling"])
+    # a row that decodes brings a token, emitted or dropped
+    assert m["slot_steps_live"] == (
+        m["generated_tokens"] + m["dropped_tokens"] - m["admitted"])
+
+
+def _small(params, model_overrides=None, **engine):
+    """Four slots on pages of 16 positions."""
+    from ray_tpu.llm.engine import JaxLLMEngine
+
+    geometry = dict(max_num_seqs=4, max_model_len=64, page_size=16,
+                    prefill_bucket_min=BUCKET)
+    return JaxLLMEngine(LLMConfig(
+        model_id="tiny", engine_config=EngineConfig(**geometry, **engine),
+        model_overrides=model_overrides or {"attention_impl": "xla"}),
+        params=params, seed=0)
+
+
+def _starved(params):
+    """Three requests of four tokens on four slots: every step decodes three
+    rows and the fourth is empty because nobody was offered."""
+    eng = _small(params)
+    for i in range(3):
+        eng.add_request(f"r{i}", _prompt(8 + i), SamplingParams(max_tokens=4))
+    return eng, [], dict(decode_steps=3, live=9, starved=3), [
+        dict(waiting=0, free_slots=1, free_pages=16 - 3, stopped="queue")]
+
+
+def _page_blocked(params):
+    """Four usable pages: ``a`` and ``b``, two pages each, take them all, and
+    ``c`` waits at the head of the queue beside two free slots until both have
+    ended; it then decodes alone, and nobody waits."""
+    eng = _small(params, num_pages=5)
+    for rid in "ab":
+        eng.add_request(rid, _prompt(20), SamplingParams(max_tokens=4))
+    eng.add_request("c", _prompt(20), SamplingParams(max_tokens=3))
+    return eng, [], dict(decode_steps=5, live=3 * 2 + 2 * 1,
+                         page_blocked=3 * 2, starved=2 * 3), [
+        dict(waiting=1, free_slots=2, free_pages=0, stopped="pages")] * 3 + [
+        dict(waiting=0, free_slots=3, free_pages=2, stopped="queue")]
+
+
+def _riding(params):
+    """A model whose prefill call carries a decode step. ``a`` decodes alone
+    for two steps; the step that admits ``b`` rides ``b``'s prefill call: of
+    its four rows ``a``'s decodes, ``b``'s slot is being prefilled (its first
+    decode row is the next step's) and two are empty."""
+    import jax.numpy as jnp
+
+    eng = _small(None, expect_state_layers=1, expect_conv_taps=3,
+                 model_overrides=dict(
+                     n_layers=2, layer_kinds=("conv", "full"), block="rms",
+                     rope_kinds=("full",), conv_taps=3, dtype=jnp.float32))
+    eng.add_request("a", _prompt(5), SamplingParams(max_tokens=6))
+    late = [(2, "b", _prompt(9), SamplingParams(max_tokens=3))]
+    return eng, late, dict(decode_steps=5, riding_steps=1, live=5 + 2,
+                           prefilling=1, starved=3 + 3 + 2 + 2 + 2), [
+        dict(waiting=0, free_slots=3, free_pages=16 - 1, stopped="queue")]
+
+
+@pytest.mark.parametrize("scene", [_starved, _page_blocked, _riding],
+                         ids=["starved", "page-blocked", "riding"])
+def test_every_row_of_a_decode_step_is_in_one_state(params, recorder, scene):
+    """``slot_steps`` by hand: a row decodes, or its slot went in this very
+    step to a request whose prompt the carrying call runs, or it is empty,
+    because nobody waited or because the queue's head lacked pages; and
+    ``engine.admit`` says which, step by step."""
+    eng, late, want, admits = scene(params)
+    calls, done = 0, {}
+    while eng.has_unfinished() or late:
+        for _, rid, prompt, sp in [x for x in late if x[0] == calls]:
+            eng.add_request(rid, prompt, sp)
+        late = [x for x in late if x[0] != calls]
+        done.update((o.request_id, o) for o in eng.step() if o.finished)
+        calls += 1
+    # none met a stop token: the hand count holds
+    assert {o.finish_reason for o in done.values()} == {"length"}
+    m = eng.metrics
+    got = {k: m[k] for k in ("decode_steps", "riding_steps")}
+    got.update((k, m["slot_steps_" + k])
+               for k in ("live", "starved", "page_blocked", "prefilling"))
+    assert got == dict(dict.fromkeys(got, 0), **want)
+    assert m["dropped_tokens"] == 0
+    _slot_account_closes(m, slots=4)
+    seen = [a for _, n, a in recorder if n == "ray_tpu/engine.admit"]
+    assert seen[:len(admits)] == admits
 
 
 def test_prefill_counters_equal_the_hand_count(engine):
@@ -275,6 +384,11 @@ def test_no_shape_depends_on_how_many_were_admitted(engine, monkeypatch):
     assert engine._row_shapes.wait(300)
     # [2, 16], [4, 16], [2, 32], [4, 32], [2, 64]: 128 padded tokens at most
     assert m["prefill_shapes_wanted"] == m["prefill_shapes_ready"] == 5
+    # the counter hears the whole process: what the engines of the tests
+    # before this one asked for off their serving paths is made first
+    for maker in threading.enumerate():
+        if maker.name == "prefill-shapes":
+            maker.join(300)
     events = CompileCounter()
     for i, burst in enumerate([(7,), (12, 33, 90), (5, 17, 70, 9, 30, 101, 16,
                                                    64)]):
@@ -349,11 +463,14 @@ def test_engine_spans_nest_in_step_order(engine, recorder):
         (1, "sample_dispatch"), (1, "decode_dispatch"), (2, "compile"),
         (1, "sample_dispatch")]
     attrs = {n: a for _, n, a in recorder}
+    # why the step's other seven rows run empty: nobody else was offered
+    assert attrs["ray_tpu/engine.admit"] == {
+        "waiting": 0, "free_slots": SLOTS - 1, "stopped": "queue",
+        "free_pages": engine.ecfg.num_pages - 2}
     assert attrs["ray_tpu/engine.prefill_dispatch"] == {
         "bucket": BUCKET, "admitted": 1, "calls": 1, "rows": 1, "riding": 0}
     assert attrs["ray_tpu/engine.compile"]["program"] == "decode"
-    assert attrs["ray_tpu/engine.decode_dispatch"] == {
-        "overlapped": 0, "dropped": 0}
+    assert attrs["ray_tpu/engine.decode_dispatch"] == {"overlapped": 0}
     assert attrs["ray_tpu/engine.sample_dispatch"] == {"greedy": True}
     # a shape this engine has used is not a compile again; the second call
     # reads the two sampler calls of the first behind its own dispatch
@@ -363,7 +480,8 @@ def test_engine_spans_nest_in_step_order(engine, recorder):
         "ray_tpu/engine." + p for p in (
             "step", "admit", "decode_dispatch", "sample_dispatch", "readback",
             "emit", "readback", "emit")]
-    assert recorder[2][2] == {"overlapped": 1, "dropped": 0}
+    assert recorder[1][2]["stopped"] == "queue"
+    assert recorder[2][2] == {"overlapped": 1}
     # the third token was the second call's: the third call runs nothing
     del recorder[:]
     out, = engine.step()
@@ -569,7 +687,11 @@ def test_a_preempted_requests_gap_spans_its_second_prefill(params):
     m = eng.metrics
     assert m["preempted"] >= 1 and m["admitted"] == 2 + m["preempted"]
     assert m["generated_tokens"] == sum(len(o.token_ids) for o in outs)
-    _phases_and_gaps_add_up(m, first_tokens=2)
+    _phases_and_gaps_add_up(m, first_tokens=2, slots=2)
+    # a preempted request waits for pages, not for a slot: from its
+    # preemption to its second admission the pool set the batch
+    assert m["slot_steps_page_blocked"] >= 1
+    assert m["slot_steps_prefilling"] == 0  # nothing rides in a dense model
 
 
 class _Clock:
@@ -663,14 +785,19 @@ def _add(engine, rid, n, max_tokens):
     engine._requests[rid].t_added = engine_module.time.perf_counter()
 
 
-@pytest.mark.parametrize("long_ms,over", [(40, 2), (200, 4), (800, 6)],
-                         ids=["40ms", "200ms", "800ms"])
-def test_a_decoder_waits_behind_anothers_prefill(timed, long_ms, over):
+@pytest.mark.parametrize("long_ms,over", [(40, 2), (200, 4), (800, 6),
+                                          (999, 6), (3500, 6)],
+                         ids=["40ms", "200ms", "800ms", "999ms", "stalled"])
+def test_a_decoder_waits_behind_anothers_prefill(timed, caplog, long_ms, over):
     """``a`` decodes alone, a token every 11 ms (decode step + sampler).
     ``b``'s prompt takes the 64 bucket: its prefill call of ``long_ms`` and
     the phase's sampler call run between two of ``a``'s decode steps, so ONE
     of ``a``'s gaps is ``long_ms`` + 12 and lands on every rung under it, and
-    the prefill reads waited ``long_ms`` + 1 more than before."""
+    the prefill reads waited ``long_ms`` + 1 more than before. A read that
+    waited over a second for its one call stalled: it is counted with its
+    wait and the engine says so in one line; 999 + 1 ms behind a call and its
+    sampler call is the longest wait that is none."""
+    caplog.set_level("WARNING", logger="ray_tpu.llm.engine")
     engine = timed(long_ms)
     m = engine.metrics
     _add(engine, "a", 5, 12)
@@ -703,6 +830,18 @@ def test_a_decoder_waits_behind_anothers_prefill(timed, long_ms, over):
     # what the reads waited for is what the host was blocked for: it took no
     # time of its own
     assert m["readback_ms"] == pytest.approx(m["phase_ms"])
+    stalled = [r.getMessage() for r in caplog.records
+               if r.getMessage().startswith("stalled read")]
+    if long_ms + SAMPLE_MS <= 1000:
+        assert (m["stalled_reads"], m["stalled_read_ms"], stalled) == (0, 0, [])
+    else:
+        assert (m["stalled_reads"], m["stalled_read_ms"]) == (
+            1, pytest.approx(long_ms + SAMPLE_MS))
+        # b's phase was dispatched behind a's fourth decode step
+        assert stalled == [
+            f"stalled read: kind=prefill calls=1 bucket=64 rows=1 waited "
+            f"{long_ms + SAMPLE_MS} ms, {long_ms + DECODE_MS + 2 * SAMPLE_MS} "
+            f"ms after its dispatch; compiled since: False"]
 
 
 def test_decode_span_says_how_many_tokens_and_the_longest_gap(timed,

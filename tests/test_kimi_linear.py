@@ -494,7 +494,6 @@ def test_engine_serves_preempts_and_counts_the_states_it_moves():
     assert m["preempted"] >= 1
     assert m["kda_step_slots"] == 3 * 3 * m["decode_steps"]
     assert 0 < m["kda_step_live_slots"] <= m["kda_step_slots"]
-    assert m["kda_prefill_positions"] == 3 * m["prefill_tokens"] > 0
     assert m["ssd_step_slots"] == 0 and m["shared_kv_live_tokens"] == 0
     assert 0 < m["mla_decode_live_tokens"] <= m["mla_decode_read_tokens"]
     assert m["moe_decode_assignments"] < m["moe_decode_routed_assignments"]

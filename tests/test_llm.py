@@ -732,9 +732,12 @@ def _check_counters_and_span(engine, monkeypatch):
 
     class Recorder(contextlib.nullcontext):
         def __init__(self, name, **attrs):
-            super().__init__()
+            super().__init__(self)
             if name == "ray_tpu/engine.sample_dispatch":
                 spans.append(attrs)
+
+        def set_metadata(self, **attrs):  # engine.admit's, learnt on its way
+            pass
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
     eng = JaxLLMEngine(make_config(), params=engine.params, seed=0)
