@@ -213,10 +213,13 @@ from ``__init__``, so two snapshots subtract):
   of them, ``ops/ssd.py:ssd_step`` walks every slot) and
   ``ssd_step_live_slots`` (those of them that decode). For a model with
   delta-rule layers (``"kda"``) the same two under ``kda_step_slots`` /
-  ``kda_step_live_slots`` (``ops/kda.py:kda_step`` walks every slot too);
-  what ``kda_scan`` walks in prefill is ``prefill_batch_tokens`` a layer, the
-  real positions among them ``prefill_tokens``. Its latent layers count
-  under ``mla_decode_*`` as a latent model's do.
+  ``kda_step_live_slots`` (``ops/kda.py:kda_step`` walks every slot too)
+  and, per prefill call, ``kda_scan_chunks`` (the chunks of ``CHUNK``
+  positions ``kda_scan``'s grid has a head and such layer: the call's ``R x
+  S / CHUNK``) and ``kda_scan_chunks_skipped`` (those of them that lie
+  wholly behind their row's length, a padding row's all: the kernel passes
+  over them). Its latent layers count under ``mla_decode_*`` as a latent
+  model's do.
 
 Such a model's rings and rows need no allocator: a slot owns its own, a
 prefill call overwrites all of them from the prompt (the engine tells it the
@@ -581,7 +584,8 @@ class JaxLLMEngine:
             "shared_kv_live_tokens": 0, "shared_kv_read_tokens": 0,
             "window_live_tokens": 0, "prefill_cross_rows": 0,
             "ssd_step_slots": 0, "ssd_step_live_slots": 0,
-            "kda_step_slots": 0, "kda_step_live_slots": 0}
+            "kda_step_slots": 0, "kda_step_live_slots": 0,
+            "kda_scan_chunks": 0, "kda_scan_chunks_skipped": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
 
@@ -1032,6 +1036,12 @@ class JaxLLMEngine:
             toks[i, :lens[i]] = self._slots[slot].cache_tokens
         where = jnp.asarray(where)
         rows = [jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(tables)]
+        if self._kda_layers:  # the grid steps of kda_scan, and those it skips
+            from ray_tpu.ops.kda import scan_chunks
+
+            chunks, skipped = scan_chunks(S, lens)
+            self.metrics["kda_scan_chunks"] += chunks
+            self.metrics["kda_scan_chunks_skipped"] += skipped
         if self.mcfg.layer_kinds:  # a model that keeps state by slot is told
             rows.append(where)
         carries = self._carries(R, S)

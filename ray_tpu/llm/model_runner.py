@@ -83,7 +83,8 @@ the step. The kinds, and where each keeps what:
   128 x 128), and in ``conv`` the last ``kda_conv - 1`` rows of the three
   convolutions' input ``q | k | v``. Prefill runs the chunked delta rule
   over the bucket (``kda_scan`` through ``ops/kda.py:kda_prefill``, padding
-  passed over from ``lengths`` on) and WRITES the slot's state and tail from
+  passed over from ``lengths`` on, by the whole chunk where a chunk holds
+  nothing else) and WRITES the slot's state and tail from
   the prompt alone, which is how a slot is reset at admission, reused, or
   given back to a preempted request; a decode step convolves the tail with
   the new input, steps every slot's state once, in place (``kda_step``;
@@ -974,8 +975,12 @@ def _kda_prefill(qkv, gates, lp, cfg, kept, layer, slots, lengths):
     ``o_proj`` there is ONE kernel: the norms of q and k, the log-decay, beta
     and the output's norm and gate happen in its tile
     (``ops/kda.py:kda_prefill``). Padding behind a prompt neither moves the
-    state (the kernel passes over positions from ``lengths`` on) nor enters
-    the tail."""
+    state nor enters the tail: the kernel does nothing for a chunk that lies
+    wholly behind ``lengths`` (it reads none of these arrays there, leaves
+    the state alone and writes zeros to ``o``) and forces no decay and no
+    update from ``lengths`` on inside the chunk that holds the end, where
+    ``o`` behind the end is nobody's but finite. The padded rows of ``o`` go
+    on through ``o_proj`` and the experts like any row."""
     from ray_tpu.models.transformer import causal_conv
     from ray_tpu.ops.kda import kda_prefill
 

@@ -22,7 +22,12 @@ step beside a prompt, no slot used twice. This does, on
   ``[1, 512]``, its state and tail overwritten from the prompt alone) beside
   a 130-token request in another;
 - two prompts (400 and 270) through ONE ``[2, 512]`` call told its slots,
-  then four decode steps of both.
+  then four decode steps of both;
+- a 100-token prompt through a ``[1, 16384]`` call, the cell's longest
+  bucket: it ends in the first of 128 chunks, ``kda_scan`` passes over the
+  other 127 in every head and layer, and the zeros it leaves behind the
+  prompt's end go through ``o_proj``, the experts and the latent layers like
+  any row; then four decode steps from the state it handed back.
 
 Every position's logits against ``benchmarks/architectures/
 kimi_linear.py:forward`` in float32 at the highest matmul precision.
@@ -60,6 +65,7 @@ CONFIG = os.path.join(REPO, "benchmarks", "configs", "kimi-linear-48b-a3b.json")
 LONG, MIDDLE, SHORT = (6512, 5, 7), (700, 40, 100), (2, 33, 30)
 AGAIN, FOURTH = (300, 5, 140), (130, 12, 150)
 PAIR = ((400, 20, 160), (270, 21, 170))
+FIRST_CHUNK, LONGEST = ((100, 50, 190),), 16384
 SPOILED = {"no_beta": {"without": ("beta",)},
            "no_decay": {"without": ("decay",)},
            "no_conv": {"without": ("conv",)},
@@ -154,42 +160,50 @@ def main() -> dict:
         out[what] = rel(long_got, reference(**change)(long_toks))
         note("long", what, out[what])
 
+    def one_call(rows, S, name):
+        """The prompts of ``rows`` through ONE ``[len(rows), S]`` call told
+        its slots, then four decode steps of them: rel_err_<name>_<row>."""
+        nonlocal finite
+        toks2 = {slot: draw(prompt) for prompt, slot, _ in rows}
+        R = len(rows)
+        batch, lens = np.zeros((R, S), np.int32), np.zeros(R, np.int32)
+        tables = np.zeros((B, MP), np.int32)
+        for i, (prompt, slot, page) in enumerate(rows):
+            batch[i, :prompt], lens[i] = toks2[slot][:prompt], prompt
+            need = -(-(prompt + STEPS) // e.page_size)
+            tables[slot, :need] = np.arange(page, page + need)
+        slots = np.asarray([slot for _, slot, _ in rows], np.int32)
+        args = (jnp.asarray(batch), jnp.asarray(lens),
+                jnp.asarray(tables[slots]), jnp.asarray(slots))
+        call = mr.prefill.lower(eng.params, mcfg, eng.cache, *args).compile()
+        out[f"kernels_{R}x{S}"] = dict(kernels(call))
+        logits, eng.cache = mr.prefill(eng.params, mcfg, eng.cache, *args)
+        got = {slot: [np.asarray(logits[i])] for i, slot in enumerate(slots)}
+        last, seq_lens = np.zeros(B, np.int32), np.zeros(B, np.int32)
+        active = np.zeros(B, bool)
+        active[slots] = True
+        for step in range(4):
+            for prompt, slot, _ in rows:
+                last[slot] = toks2[slot][prompt + step]
+                seq_lens[slot] = prompt + step
+            logits, eng.cache = mr.decode_step(
+                eng.params, mcfg, eng.cache, jnp.asarray(last),
+                jnp.asarray(seq_lens), jnp.asarray(tables),
+                jnp.asarray(active))
+            for slot in slots:
+                got[slot].append(np.asarray(logits[slot]))
+        for i, (prompt, slot, _) in enumerate(rows):
+            # the reference's last STEPS + 1 rows are positions prompt - 1 ..
+            # prompt + STEPS - 1: the first five are the call's and the steps'
+            g, w = np.stack(got[slot]), want(toks2[slot])[:5]
+            out[f"rel_err_{name}_{i}"] = rel(g, w)
+            finite = finite and bool(np.isfinite(g).all())
+            note(f"[{R}, {S}] row", i, out[f"rel_err_{name}_{i}"])
+
     # (3) two prompts through ONE [2, 512] call told its slots, four steps
-    S = eng._prefill_bucket(max(prompt for prompt, _, _ in PAIR))
-    toks2 = {slot: draw(prompt) for prompt, slot, _ in PAIR}
-    batch, lens = np.zeros((2, S), np.int32), np.zeros(2, np.int32)
-    tables = np.zeros((B, MP), np.int32)
-    for i, (prompt, slot, page) in enumerate(PAIR):
-        batch[i, :prompt], lens[i] = toks2[slot][:prompt], prompt
-        need = -(-(prompt + STEPS) // e.page_size)
-        tables[slot, :need] = np.arange(page, page + need)
-    slots = np.asarray([slot for _, slot, _ in PAIR], np.int32)
-    call = mr.prefill.lower(eng.params, mcfg, eng.cache, jnp.asarray(batch),
-                            jnp.asarray(lens), jnp.asarray(tables[slots]),
-                            jnp.asarray(slots)).compile()
-    out["kernels_2x512"] = dict(kernels(call))
-    logits, eng.cache = mr.prefill(
-        eng.params, mcfg, eng.cache, jnp.asarray(batch), jnp.asarray(lens),
-        jnp.asarray(tables[slots]), jnp.asarray(slots))
-    pair = {slot: [np.asarray(logits[i])] for i, slot in enumerate(slots)}
-    last, seq_lens = np.zeros(B, np.int32), np.zeros(B, np.int32)
-    active = np.zeros(B, bool)
-    active[slots] = True
-    for step in range(4):
-        for prompt, slot, _ in PAIR:
-            last[slot], seq_lens[slot] = toks2[slot][prompt + step], prompt + step
-        logits, eng.cache = mr.decode_step(
-            eng.params, mcfg, eng.cache, jnp.asarray(last),
-            jnp.asarray(seq_lens), jnp.asarray(tables), jnp.asarray(active))
-        for slot in slots:
-            pair[slot].append(np.asarray(logits[slot]))
-    for i, (prompt, slot, _) in enumerate(PAIR):
-        # the reference's last STEPS + 1 rows are positions prompt - 1 ..
-        # prompt + STEPS - 1: the first five are the call's and the steps'
-        g, w = np.stack(pair[slot]), want(toks2[slot])[:5]
-        out[f"rel_err_pair_{i}"] = rel(g, w)
-        finite = finite and bool(np.isfinite(g).all())
-        note(f"[2, {S}] row", i, out[f"rel_err_pair_{i}"])
+    one_call(PAIR, eng._prefill_bucket(max(p for p, _, _ in PAIR)), "pair")
+    # (4) a prompt that ends in the first chunk of the longest bucket
+    one_call(FIRST_CHUNK, LONGEST, "first_chunk")
     stats = jax.devices()[0].memory_stats() or {}
     out["peak_gb"] = round(stats.get("peak_bytes_in_use", 0) / 1e9, 3)
     out["finite"] = finite

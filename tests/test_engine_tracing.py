@@ -61,8 +61,10 @@ KEYS = {
     # the slots whose Mamba-2 state a decode step moves, and those of them
     # that decode; 0 without such layers
     "ssd_step_slots", "ssd_step_live_slots",
-    # the same two for delta-rule layers; 0 without such layers
-    "kda_step_slots", "kda_step_live_slots"}
+    # the same two for delta-rule layers, and the chunks their prefill
+    # kernel's grid has and passes over; 0 without such layers
+    "kda_step_slots", "kda_step_live_slots",
+    "kda_scan_chunks", "kda_scan_chunks_skipped"}
 LADDER = tuple(f"itl_over_{n}ms" for n in (25, 50, 100, 200, 400, 800))
 PHASES = ("admit_ms", "prefill_dispatch_ms", "decode_dispatch_ms",
           "sample_dispatch_ms", "readback_ms", "emit_ms")
@@ -157,6 +159,8 @@ def test_metrics_complete_numeric_monotone(engine):
     assert m["sample_calls"] == m["prefill_steps"] + m["decode_steps"]
     assert m["riding_steps"] == 0  # this model's prefill call carries none
     assert m["sample_greedy_calls"] == m["sample_calls"]
+    # no delta-rule layer: nothing for a kda_scan to walk or pass over
+    assert m["kda_scan_chunks"] == m["kda_scan_chunks_skipped"] == 0
     # a token computed for a request that EOS had ended is not a generated one
     assert m["generated_tokens"] <= sum(4 + i for i in range(SLOTS + 2))
     assert m["between_steps_ms"] > 0

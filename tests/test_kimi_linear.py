@@ -148,6 +148,11 @@ def _pages(first, positions):
 EXTREME = -16 * 0.24
 
 
+# rows of a bucket of eight chunks of 16: the prompt ends in the first chunk,
+# in a middle one, in the last; a padding row
+ENDS = [5, 60, 121, 0]
+
+
 def _operands(R, S, H, K, seed, lengths=None, g=None, dtype=jnp.float32):
     key = jax.random.split(jax.random.PRNGKey(seed), 5)
     unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
@@ -173,8 +178,11 @@ def _operands(R, S, H, K, seed, lengths=None, g=None, dtype=jnp.float32):
     (3, 16, 64, [2, 16, 0], None),  # shorter than the taps; a padding row
     (1, 8, 64, [5], None),          # a bucket shorter than a sub-chunk
     (1, 128, 64, None, EXTREME),    # every lane at the extreme, whole chunks
+    # eight chunks: rows that end in the first, a middle and the last, a
+    # padding row (on prepared operands nothing is passed over)
+    (4, 128, 16, ENDS, None),
 ], ids=["edges", "series", "padding", "short-and-empty", "one-block",
-        "extreme-decay"])
+        "extreme-decay", "ends-by-chunk"])
 def test_scan_kernel_matches_the_recurrence(R, S, chunk, lengths, g):
     """o at every real position and the state after ``lengths - 1``, float32
     against float32: no exponent leaves float32's range (the extreme decay
@@ -233,10 +241,15 @@ def _layer_arrays(R, S, H, K, seed, extreme, dtype):
     (2, 32, 16, [32, 13], False), (3, 16, 64, [2, 16, 0], False),
     (1, 8, 64, [5], False), (1, 128, 64, None, True),
     (2, 512, kda.CHUNK, [400, 270], False),  # one call, two prompts
+    (4, 128, 16, ENDS, False),      # eight chunks, most of them passed over
 ], ids=["edges", "series", "padding", "short-and-empty", "one-block",
-        "extreme-decay", "two-rows"])
+        "extreme-decay", "two-rows", "ends-by-chunk"])
 def test_prefill_kernel_is_the_layer_between_conv_and_o_proj(
         R, S, chunk, lengths, extreme, dtype, tol):
+    _prefill_is_the_layer(R, S, chunk, lengths, extreme, dtype, tol, H=2)
+
+
+def _prefill_is_the_layer(R, S, chunk, lengths, extreme, dtype, tol, H):
     """``kda_prefill`` on a layer's arrays as its products left them against
     the composition it replaces, in float32 on the same values: the
     operands made in XLA (``_kda_operands``), the recurrence as it reads with
@@ -244,10 +257,11 @@ def test_prefill_kernel_is_the_layer_between_conv_and_o_proj(
     gate. In float32 to float32's own rounding; in bfloat16 within what
     rounding a product's operands once costs, o in the stored type as
     ``o_proj`` takes it. The state is the one after ``lengths - 1``; o behind
-    a prompt's end is nobody's."""
+    a prompt's end is nobody's but finite, and zero in the chunks that lie
+    wholly behind it: those the kernel passes over, and reads nothing of."""
     import types
 
-    H, K, eps = 2, 16, 1e-5
+    K, eps = 16, 1e-5
     cfg = types.SimpleNamespace(kda_heads=H, kda_head_dim=K)
     a, f, beta, gate, m = _layer_arrays(R, S, H, K, S + R, extreme, dtype)
     n = jnp.asarray(lengths or [S] * R, jnp.int32)
@@ -259,17 +273,50 @@ def test_prefill_kernel_is_the_layer_between_conv_and_o_proj(
         jnp.where(real[..., None], beta, 0.0))
     want_o = mr._rmsnorm(want_o, m["o_norm"]["scale"], eps) * jax.nn.sigmoid(
         wide(gate).reshape(want_o.shape))
-    o, s = jax.jit(lambda *t: kda.kda_prefill(*t, eps=eps, chunk=chunk))(
-        jax.nn.silu(a), f, beta, gate, m["dt_bias"], m["A_log"],
-        m["o_norm"]["scale"], n)
+    prefill = jax.jit(lambda a, f, beta, gate: kda.kda_prefill(
+        a, f, beta, gate, m["dt_bias"], m["A_log"], m["o_norm"]["scale"], n,
+        eps=eps, chunk=chunk))
+    o, s = prefill(jax.nn.silu(a), f, beta, gate)
     assert o.dtype == dtype and o.shape == (R, S, H * K)
     assert s.dtype == jnp.float32 and np.isfinite(np.asarray(s)).all()
+    assert np.isfinite(np.asarray(wide(o))).all()
+    T = chunk if S % chunk == 0 else S
+    over = (jnp.arange(S)[None, :] >= -(-n[:, None] // T) * T)[..., None]
+    assert not np.asarray(jnp.where(over, wide(o), 0)).any()
+    if over.any():   # whatever a chunk that is passed over holds is not read
+        nan = lambda t: jnp.where(over, jnp.nan, t)   # noqa: E731
+        o_nan, s_nan = prefill(nan(jax.nn.silu(a)), nan(f), nan(beta),
+                               nan(gate))
+        assert (np.asarray(wide(o_nan)) == np.asarray(wide(o))).all()
+        assert (np.asarray(s_nan) == np.asarray(s)).all()
     live = real[..., None]
     assert _rel(jnp.where(live, wide(o), 0),
                 jnp.where(live, want_o.reshape(o.shape), 0)) < tol
     assert _rel(s, want_s) < tol
     if lengths and 0 in lengths:   # a padding row leaves a zero state
         assert not np.asarray(s[lengths.index(0)]).any()
+
+
+@pytest.mark.parametrize("H", [1, 3, 4])
+def test_kernels_take_any_number_of_heads(H):
+    """A grid step takes four heads where four divide the layer's, else two,
+    else one (``_heads_a_step``): a layer's own arrays at an odd number of
+    heads, prepared operands at four (the engine tests' model has four, the
+    tests above two), over chunk edges, a prompt's end inside a chunk and, in
+    the layer's call, chunks passed over."""
+    assert [kda._heads_a_step(n) for n in (1, 2, 3, 4, 6, 32)] == [
+        1, 2, 1, 4, 2, 4]
+    lengths = [64, 21]
+    if H % 4:
+        _prefill_is_the_layer(2, 64, 16, lengths, False, jnp.float32, 1e-5, H)
+    else:
+        ops = _operands(2, 64, H, 16, H, lengths)
+        want_o, want_s = kda.kda_reference(*ops)
+        o, s = jax.jit(lambda *a: kda.kda_scan(*a, chunk=16))(*ops)
+        real = np.arange(64)[None] < np.asarray(lengths)[:, None]
+        real = real[..., None, None]
+        assert _rel(jnp.where(real, o, 0), jnp.where(real, want_o, 0)) < 1e-5
+        assert _rel(s, want_s) < 1e-5
 
 
 def test_step_kernel_steps_one_layer_in_place():
@@ -501,6 +548,35 @@ def test_engine_serves_preempts_and_counts_the_states_it_moves():
         _engine(expect_kda_heads=0)
     with pytest.raises(ValueError, match="export_kv"):
         eng.export_kv("nobody")
+
+
+def test_engine_counts_the_chunks_the_scan_passes_over():
+    """A window of prompts that end in the first, second, third and last
+    chunk of their bucket: the engine's two counters hold what ``kda_scan``'s
+    grid has a head and layer (a call's ``R x S / CHUNK``) and what of it
+    lies wholly behind a prompt's end, as counted by hand from the prompts'
+    lengths and buckets; the count itself over calls of other buckets and of
+    several rows, a padding row among them."""
+    for S, lens, want in ((256, [100], (2, 1)), (256, [129], (2, 0)),
+                          (1024, [1], (8, 7)), (512, [257, 384, 0], (12, 6)),
+                          (64, [5, 0], (2, 1))):    # one chunk of 64: the row
+        assert kda.scan_chunks(S, lens) == want
+    eng = _engine(max_num_seqs=2, max_model_len=4 * kda.CHUNK, num_pages=None,
+                  prefill_bucket_min=4 * kda.CHUNK)
+    rng = np.random.default_rng(4)
+    lengths = (100, 200, 300, 500)
+    eng.generate([rng.integers(0, VOCAB, n).tolist() for n in lengths],
+                 SamplingParams(max_tokens=2), decode_text=False)
+    m = eng.metrics
+    assert m["preempted"] == 0 and m["prefill_calls"] == len(lengths)
+    chunks = sum(eng._prefill_bucket(n) for n in lengths) // kda.CHUNK
+    real = sum(-(-n // kda.CHUNK) for n in lengths)      # 1 2 3 4
+    assert m["kda_scan_chunks"] == chunks == 16
+    assert m["kda_scan_chunks_skipped"] == chunks - real == 6
+    assert m["prefill_batch_tokens"] == chunks * kda.CHUNK
+    # no call of several rows fits the longest bucket: nothing is being made
+    # off this thread when the test ends
+    assert not eng._row_shapes.wanted
 
 
 def test_training_module_is_the_reference():
