@@ -59,6 +59,9 @@ class EngineConfig:
     # expert-parallel group and holds only ``expect_experts`` of them (0: it
     # holds them all), checked against the model the same way
     expect_routed_experts: int = 0
+    # the router's outputs that are no expert but the identity (0: every
+    # output is an expert), checked against the model the same way
+    expect_zero_experts: int = 0
     # width of the latent the cache holds a position (0: per-head K/V pages),
     # checked against the model as expect_experts is, and for its reason
     expect_latent_rank: int = 0

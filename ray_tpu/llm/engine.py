@@ -188,7 +188,9 @@ from ``__init__``, so two snapshots subtract):
   wherever they fell), ``moe_decode_assignments`` (those that fell on an
   expert HELD here: all of them, unless the model is one rank of an
   expert-parallel deployment, ``TransformerConfig.experts_held``),
-  ``moe_decode_experts_touched`` (held experts that got a row) and
+  ``moe_decode_zero_assignments`` (those that fell on a zero-compute
+  expert, ``TransformerConfig.zero_experts``: the identity, on every rank
+  alike), ``moe_decode_experts_touched`` (held experts that got a row) and
   ``moe_decode_max_load`` (rows of the fullest held expert). They come from
   ``Cache.moe_load``, a few KB copied out of the cache at dispatch (the
   next dispatch donates the cache) and read in ``emit`` with that step's
@@ -453,6 +455,11 @@ class JaxLLMEngine:
                 f"the deployment expects {expect[0]} experts a layer of "
                 f"{expect[1]} routed, the model holds "
                 f"{self.mcfg.n_experts_held} of {self.mcfg.n_experts}")
+        if self.mcfg.zero_experts != self.ecfg.expect_zero_experts:
+            raise ValueError(
+                f"the deployment expects {self.ecfg.expect_zero_experts} "
+                f"zero-compute experts among the router's outputs, the model "
+                f"has {self.mcfg.zero_experts}")
         if self.mcfg.kv_latent_rank != self.ecfg.expect_latent_rank:
             raise ValueError(
                 f"the deployment expects a latent cache of rank "
@@ -580,6 +587,7 @@ class JaxLLMEngine:
             "moe_decode_layer_steps": 0, "moe_decode_assignments": 0,
             "moe_decode_experts_touched": 0, "moe_decode_max_load": 0,
             "moe_decode_routed_assignments": 0,
+            "moe_decode_zero_assignments": 0,
             "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0,
             "shared_kv_live_tokens": 0, "shared_kv_read_tokens": 0,
             "window_live_tokens": 0, "prefill_cross_rows": 0,
@@ -1236,6 +1244,9 @@ class JaxLLMEngine:
         """``load`` [expert layers, E]: real rows per expert HELD here in one
         decode step of ``rows`` real rows."""
         m = self.metrics
+        if self.mcfg.zero_experts:  # their count rides in the last column
+            m["moe_decode_zero_assignments"] += int(load[:, -1].sum())
+            load = load[:, :-1]
         touched = int((load > 0).sum())
         held = int(load.sum())
         m["moe_decode_layer_steps"] += load.shape[0]

@@ -44,7 +44,10 @@ the step. The kinds, and where each keeps what:
   expanded to heads (the flash kernel, 192-wide q . k and 128-wide values);
   decode attends over the rows themselves with the up-projection absorbed,
   through ``ops/mla.py:mla_decode``, which reads only the pages that hold
-  live positions;
+  live positions. With ``q_latent_rank`` the queries are low-rank (down, a
+  norm, up to the heads: ``mla.q_lora``), and with ``latent_lora_scale`` the
+  queries and the normalised latent are scaled by their widths' ratios; the
+  SCALED latent is what a row holds, so both paths read it;
 - "full", "window", "conv" of a model with ``layer_kinds``, under ONE
   definition of where each lies (``_prompt_index``, ``_write_rings``,
   ``_decode_index``, ``_ring_blocks``): ``pages`` for each "full" layer's keys
@@ -108,8 +111,18 @@ norms, a scaled embedding, a multiplier on what a sublayer adds to the stream
 (``residual_scale``), a softmax scale of its own (``attn_scale``: the queries
 are scaled before the kernels, which divide by sqrt(head_dim)), a multiplier on
 the logits (``logit_scale``) and experts (all of them, or the share
-``experts_held`` of an expert-parallel rank) the config asks for is a field
-the block reads; at 1.0 / 0 the multipliers trace nothing.
+``experts_held`` of an expert-parallel rank, a router whose last outputs are
+zero-compute identity experts, ``zero_experts``) the config asks for is a
+field the block reads; at 1.0 / 0 the multipliers trace nothing.
+
+A block that is not mixer + FFN: under ``shortcut_moe`` the layers come in
+PAIRS (``n_layers`` counts sublayers, each with its own mixer, dense MLP and
+two norms, so a published layer keeps two rows a position); the even one
+also holds the pair's ONE expert branch, computed from the same normed input
+its dense MLP takes (``moe.shortcut``), carried past the odd one's mixer and
+MLP and added where the odd one ends (``_paired_rest``). ``moe_load`` has one
+entry a PAIR, and where the router has zero experts a last column that
+counts the valid assignments that fell on one.
 
 The decoder-hybrid-decoder ("sambay": Mamba layers, window and full
 DIFFERENTIAL attention, gated memory units, cross layers; LayerNorm, no
@@ -223,8 +236,9 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
     state by slot (``layer_kinds``) needs."""
     load = None
     layers = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
-    if layers:
-        load = jnp.zeros((layers, cfg.n_experts_held), jnp.int32)
+    if layers:  # a model with zero experts counts them in a last column
+        load = jnp.zeros((layers, cfg.n_experts_held + bool(cfg.zero_experts)),
+                         jnp.int32)
     if cfg.layer_kinds:
         if not max_num_seqs:
             raise ValueError("a model with layer_kinds keeps window rings and "
@@ -287,6 +301,12 @@ def _ffn(x, lp, cfg, valid, name):
     rows ``valid`` [B, S] marks real. Returns (y, load [E] or None)."""
     if "moe" not in lp:
         return _mlp(x, lp["mlp"], cfg.dtype), None
+    return _experts(x, lp, cfg, valid, name)
+
+
+def _experts(x, lp, cfg, valid, name):
+    """``lp``'s expert layer on x [B, S, D] for the rows ``valid`` [B, S]
+    marks real -> (y, load: ``ops/moe.py:expert_layer``'s)."""
     from ray_tpu.ops.moe import expert_layer
 
     p = lp["moe"]
@@ -298,7 +318,7 @@ def _ffn(x, lp, cfg, valid, name):
         router_bias=p.get("router_bias"),
         router_scale=cfg.routed_scaling_factor,
         router_norm_eps=cfg.router_norm_eps,
-        held=tuple(cfg.experts_held) or None)
+        held=tuple(cfg.experts_held) or None, zero_experts=cfg.zero_experts)
     y = y.reshape(x.shape)
     if "shared" in p:  # the experts every row goes through
         with jax.named_scope("moe.shared"):
@@ -337,9 +357,28 @@ def _latent_qkv(x, p, cfg, positions):
     k_pe are the same lanes, plain."""
     dtype = cfg.dtype
     r, nope = cfg.kv_latent_rank, cfg.qk_nope_head_dim
-    q = jnp.einsum("...d,dhk->...hk", x, p["q_proj"]["kernel"].astype(dtype))
+    if cfg.q_latent_rank:  # low-rank queries: down, a norm, up to the heads
+        with jax.named_scope("mla.q_lora"):
+            cq = _rmsnorm(jnp.einsum(
+                "...d,dr->...r", x, p["q_a_proj"]["kernel"].astype(dtype)),
+                p["q_a_norm"]["scale"], cfg.norm_eps)
+            q = jnp.einsum("...r,rhk->...hk", cq,
+                           p["q_b_proj"]["kernel"].astype(dtype))
+    else:
+        q = jnp.einsum("...d,dhk->...hk", x,
+                       p["q_proj"]["kernel"].astype(dtype))
     a = jnp.einsum("...d,dr->...r", x, p["kv_a_proj"]["kernel"].astype(dtype))
-    c = _rmsnorm(a[..., :r], p["kv_a_norm"]["scale"], cfg.norm_eps)
+    c_scale = p["kv_a_norm"]["scale"]
+    if cfg.latent_lora_scale:
+        # the queries times s_q; the normalised latent times s_kv, in the
+        # norm's own float32 (sqrt(12) is no bfloat16 number). The SCALED
+        # latent is what the cache row holds: both halves of kv_b_proj read
+        # it, expanded in prefill and absorbed in decode alike
+        from ray_tpu.models.transformer import latent_scales
+
+        s_q, s_kv = latent_scales(cfg)
+        q, c_scale = q * jnp.asarray(s_q, q.dtype), c_scale * s_kv
+    c = _rmsnorm(a[..., :r], c_scale, cfg.norm_eps)
     if cfg.layer_kinds and "latent" not in cfg.rope_kinds:
         q_pe, k_pe = q[..., nope:], a[..., r:]
     else:
@@ -1024,6 +1063,23 @@ def _kda_step(qkv, gates, lp, cfg, kept, layer, keep, op):
     return o, (ssm, conv.at[layer].set(rows))
 
 
+def _paired_rest(x, o, lp, cfg, valid, name, carried):
+    """``_block_rest`` of a sublayer of a shortcut-connected double layer
+    (``shortcut_moe``): every sublayer has a dense MLP; the even one also
+    computes the pair's expert branch from the SAME normed input and hands
+    it on, and the odd one adds what it was handed where it ends. Returns
+    (x, load, carried)."""
+    x = x + o.astype(x.dtype)
+    u = _normed(x, lp["mlp_norm"], cfg)
+    x = x + _mlp(u, lp["mlp"], cfg.dtype).astype(x.dtype)
+    if "moe" not in lp:
+        return x + carried.astype(x.dtype), None, None
+    with jax.named_scope("moe.shortcut"):
+        carried, load = _experts(
+            u, lp, cfg, valid[:, None] if valid.ndim == 1 else valid, name)
+    return x, load, carried
+
+
 def _block_rest(x, o, lp, cfg, valid, name):
     """The block after its mixer's output ``o`` [.., D], in the stream's
     type: the residual (a norm on the way out under ``sandwich_norm``), the
@@ -1204,7 +1260,7 @@ def _forward(p, cfg, cache, prompt=None, step=None):
     shapes = [x.shape[:2] for x in xs]
     x, positions, valid = map(_end_to_end, (xs, positions, valid))
     name = "moe_gmm_decode" if prompt is None else "moe_gmm_prefill"
-    loads = []
+    loads, carried = [], None
     for i, kind in enumerate(kinds):
         lp, at = p[f"layer_{i}"], kinds[:i].count(kind)
         if kind == "conv":
@@ -1229,7 +1285,11 @@ def _forward(p, cfg, cache, prompt=None, step=None):
             o = _dense(o, lp["kda"]["o_proj"], cfg.dtype)
         else:
             o = _attn_out(h, o, lp, cfg)
-        x, load = _block_rest(x, o, lp, cfg, valid, name)
+        if cfg.shortcut_moe:
+            x, load, carried = _paired_rest(x, o, lp, cfg, valid, name,
+                                            carried)
+        else:
+            x, load = _block_rest(x, o, lp, cfg, valid, name)
         if load is not None:
             loads.append(load)
     sides = _apart(x, shapes)

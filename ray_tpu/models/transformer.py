@@ -201,6 +201,34 @@ class TransformerConfig:
     residual_scale: float = 1.0
     attn_scale: float = 0.0
     logit_scale: float = 1.0
+    # low-rank queries of latent attention (0 = one full-rank q_proj): the
+    # layer's input goes down to ``q_latent_rank`` (``q_a_proj``), under an
+    # RMSNorm (``q_a_norm``) and up to the heads (``q_b_proj``)
+    q_latent_rank: int = 0
+    # the two latents scaled on their way up: the normalised query latent's
+    # product by sqrt(d_model / q_latent_rank), the normalised key-value
+    # latent (both halves of kv_b_proj read it; the rotated key does not) by
+    # sqrt(d_model / kv_latent_rank). Both follow from the widths
+    latent_lora_scale: bool = False
+    # the router's LAST ``zero_experts`` outputs are no expert but the
+    # identity: ``n_experts`` routed ones and these share one softmax and one
+    # top-k, and a row that chose one gets ``gate * x`` for it, on every rank
+    # of an expert-parallel deployment alike (ops/moe.py). ``router_bias``: a
+    # softmax router with a per-output selection bias (a parameter, as the
+    # sigmoid kind always has) that chooses and does not weigh
+    zero_experts: int = 0
+    router_bias: bool = False
+    # shortcut-connected double layers: layers come in PAIRS (``n_layers``
+    # counts the sublayers, each with its own mixer, dense MLP and two
+    # norms). The even one also holds the pair's ONE expert branch (``mlp``
+    # AND ``moe``): computed from the normed input its dense MLP takes,
+    # carried past the odd one's mixer and MLP, added where the odd one ends
+    shortcut_moe: bool = False
+    # what the router's matrix and its selection bias are drawn at (0 = 0.02
+    # and ROUTER_BIAS_STD): a softmax over hundreds of outputs is flat at
+    # 0.02, and a bias of 0.02 would then choose for every row alike
+    router_init_std: float = 0.0
+    router_bias_init_std: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -228,9 +256,16 @@ class TransformerConfig:
         return getattr(self, part + "_init_std") or (
             0.02 if part == "embed" else 0.02 / np.sqrt(2 * self.n_layers))
 
+    @property
+    def has_router_bias(self) -> bool:
+        return self.router_kind == "sigmoid" or self.router_bias
+
     def is_moe_layer(self, i: int) -> bool:
+        """Does layer ``i`` hold experts (under ``shortcut_moe``: beside its
+        dense MLP, the even sublayer of each pair)?"""
+        every = 2 if self.shortcut_moe else max(self.moe_every, 1)
         return (self.n_experts > 0 and i >= self.first_k_dense
-                and i % max(self.moe_every, 1) == 0)
+                and i % every == 0)
 
     def num_params(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
@@ -260,7 +295,10 @@ class TransformerConfig:
         if self.kv_latent_rank:
             r, rope = self.kv_latent_rank, self.qk_rope_head_dim
             latent = (
-                d * self.n_heads * (self.qk_nope_head_dim + rope)  # q
+                (d * self.n_heads * (self.qk_nope_head_dim + rope)
+                 if not self.q_latent_rank else  # q, or its low-rank pair
+                 self.q_latent_rank * (d + 1 + self.n_heads * (
+                     self.qk_nope_head_dim + rope)))
                 + d * (r + rope) + r  # latent down-projection and its norm
                 + r * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
                 + self.n_heads * self.v_head_dim * d  # o
@@ -285,13 +323,17 @@ class TransformerConfig:
                + d * self.kda_heads + self.kda_head_dim + 2 * d)
         mixer = {"conv": conv, "mamba2": mamba2, "kda": kda, "latent": latent}
         dense_mlp = 3 * d * (self.d_ff_dense or f)
-        moe_mlp = (self.n_experts_held * 3 * d * f + d * self.n_experts
+        outputs = self.n_experts + self.zero_experts
+        moe_mlp = (self.n_experts_held * 3 * d * f + d * outputs
                    + 3 * d * self.n_shared_experts * f
-                   + (self.n_experts if self.router_kind == "sigmoid" else 0))
+                   + (outputs if self.has_router_bias else 0))
         total = 0
         for i in range(self.n_layers):
             total += mixer.get(self.layer_kind(i), attn)
-            total += moe_mlp if self.is_moe_layer(i) else dense_mlp
+            if self.shortcut_moe:  # every sublayer's MLP, the even one's moe
+                total += dense_mlp + moe_mlp * self.is_moe_layer(i)
+            else:
+                total += moe_mlp if self.is_moe_layer(i) else dense_mlp
         return v * d + total + d + (0 if self.tie_embeddings else d * v)
 
 
@@ -416,10 +458,21 @@ class Attention(nn.Module):
         cfg = self.cfg
         H, r = cfg.n_heads, cfg.kv_latent_rank
         nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-        q = dense((H, nope + rope), ("embed", "heads", "head_dim"), "q_proj")(x)
+        if cfg.q_latent_rank:  # low-rank queries
+            cq = RMSNorm(cfg.norm_eps, cfg.dtype, axis=None, name="q_a_norm")(
+                dense(cfg.q_latent_rank, ("embed", None), "q_a_proj")(x))
+            q = dense((H, nope + rope), (None, "heads", "head_dim"),
+                      "q_b_proj")(cq)
+        else:
+            q = dense((H, nope + rope), ("embed", "heads", "head_dim"),
+                      "q_proj")(x)
         a = dense(r + rope, ("embed", None), "kv_a_proj")(x)
         c = RMSNorm(cfg.norm_eps, cfg.dtype, axis=None,
                     name="kv_a_norm")(a[..., :r])
+        if cfg.latent_lora_scale:  # in float32: sqrt(12) is no bfloat16
+            s_q, s_kv = latent_scales(cfg)
+            q = (q.astype(jnp.float32) * s_q).astype(q.dtype)
+            c = (c.astype(jnp.float32) * s_kv).astype(c.dtype)
         kv = dense((H, nope + cfg.v_head_dim), (None, "heads", "head_dim"),
                    "kv_b_proj")(c)
         q_pe, k_pe = q[..., nope:], a[..., None, r:]
@@ -430,6 +483,15 @@ class Attention(nn.Module):
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_pe, q_pe.shape)], axis=-1)
         return q, k, kv[..., nope:]
+
+
+def latent_scales(cfg: TransformerConfig) -> Tuple[float, float]:
+    """``latent_lora_scale``'s two factors: what the queries (the normalised
+    query latent's product) and the normalised key-value latent are
+    multiplied by; 1.0 for queries of full rank."""
+    d = cfg.d_model
+    return ((d / cfg.q_latent_rank) ** 0.5 if cfg.q_latent_rank else 1.0,
+            (d / cfg.kv_latent_rank) ** 0.5)
 
 
 class ShortConv(nn.Module):
@@ -635,6 +697,7 @@ class MoEMLP(nn.Module):
         cfg = self.cfg
         B, S, D = x.shape
         E, K = cfg.n_experts, cfg.experts_per_token
+        outputs = E + cfg.zero_experts   # the router's: the last are no expert
         N = B * S
         # GShard-style grouping: dispatch/combine one-hots are O(g*E*C) per
         # group with C ~ g*K/E, so memory/FLOPs stay linear in N instead of
@@ -648,16 +711,19 @@ class MoEMLP(nn.Module):
         C = max(1, int(cfg.capacity_factor * g * K / E))
         xf = x.reshape(G, g, D)
 
-        router = nn.Dense(E, use_bias=False, dtype=jnp.float32,
+        router = nn.Dense(outputs, use_bias=False, dtype=jnp.float32,
                           param_dtype=jnp.float32, name="router",
                           kernel_init=nn.with_logical_partitioning(
-                              nn.initializers.normal(0.02), ("embed", "expert")))
-        logits = router(xf.astype(jnp.float32))  # (G, g, E)
+                              nn.initializers.normal(
+                                  cfg.router_init_std or 0.02),
+                              ("embed", "expert")))
+        logits = router(xf.astype(jnp.float32))  # (G, g, outputs)
         bias = None
-        if cfg.router_kind == "sigmoid":
+        if cfg.has_router_bias:
             bias = self.param("router_bias", nn.with_logical_partitioning(
-                nn.initializers.normal(ROUTER_BIAS_STD), ("expert",)),
-                (E,), jnp.float32)
+                nn.initializers.normal(
+                    cfg.router_bias_init_std or ROUTER_BIAS_STD),
+                ("expert",)), (outputs,), jnp.float32)
 
         # top-k expert choice per token; (G, g, K) and the scores (G, g, E)
         gate_vals, expert_idx, probs = select_experts(
@@ -713,10 +779,14 @@ class MoEMLP(nn.Module):
         token_frac = jnp.mean(
             jax.nn.one_hot(expert_idx[..., 0], E, dtype=jnp.float32),
             axis=(0, 1))
-        prob_frac = jnp.mean(probs, axis=(0, 1))
+        prob_frac = jnp.mean(probs[..., :E], axis=(0, 1))
         aux = E * jnp.sum(token_frac * prob_frac)
         self.sow("losses", "moe_aux", aux)
         out = out.reshape(B, S, D)
+        if cfg.zero_experts:  # the identity for each choice past the routed
+            # ones (their one-hot rows above are zeros: no capacity, no row)
+            passed = jnp.where(expert_idx >= E, gate_vals, 0.0).sum(-1)
+            out = out + (passed.reshape(B, S, 1) * x).astype(out.dtype)
         if cfg.n_shared_experts:
             out = out + MLP(cfg, cfg.n_shared_experts * cfg.d_ff,
                             name="shared")(x)
@@ -928,7 +998,10 @@ class Block(nn.Module):
     kind: str = "full"
 
     @nn.compact
-    def __call__(self, x, positions, segment_ids=None):
+    def __call__(self, x, positions, segment_ids=None, carried=None):
+        """Under ``shortcut_moe`` returns ``(x, carried)``: the even sublayer
+        hands on its expert branch's output, the odd one adds what it is
+        handed where it ends."""
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)  # noqa: E731
         if self.kind == "conv":
@@ -945,6 +1018,12 @@ class Block(nn.Module):
                           t * jnp.asarray(cfg.residual_scale, t.dtype))
         h = x + into(norm("post_attn_norm")(a) if cfg.sandwich_norm else a)
         h = nn.with_logical_constraint(h, ("batch", "seq", "embed"))
+        if cfg.shortcut_moe:
+            u = norm("mlp_norm")(h)
+            out = h + into(MLP(cfg, cfg.d_ff_dense, name="mlp")(u))
+            if self.use_moe:
+                return out, MoEMLP(cfg, name="moe")(u)
+            return out + into(carried), None
         mlp = MoEMLP(cfg, name="moe") if self.use_moe else MLP(
             cfg, cfg.d_ff_dense, name="mlp")
         y = mlp(norm("mlp_norm")(h))
@@ -984,9 +1063,12 @@ class Transformer(nn.Module):
         if cfg.remat:
             block = nn.remat(Block, prevent_cse=False,
                              policy=jax.checkpoint_policies.nothing_saveable)
+        carried = None
         for i in range(cfg.n_layers):
             x = block(cfg, cfg.is_moe_layer(i), cfg.layer_kind(i),
-                      name=f"layer_{i}")(x, positions, segment_ids)
+                      name=f"layer_{i}")(x, positions, segment_ids, carried)
+            if cfg.shortcut_moe:
+                x, carried = x
         x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
         if cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype))

@@ -8,8 +8,8 @@ experts, whatever the load; there is no capacity and nothing falls through.
    float32 and at the highest matmul precision (``D x E`` is free, and matmul
    rounding then has no say in which experts a row gets);
 2. ``top_k`` of the probabilities, renormalised to sum to one only with
-   ``norm_topk_prob`` (``select_experts``; its ``sigmoid`` kind chooses by
-   sigmoid scores plus a selection bias and weighs by the scores alone);
+   ``norm_topk_prob`` (``select_experts``; a selection bias, the ``sigmoid``
+   kind's always, chooses and does not weigh: the scores alone do);
 3. rows that are padding or belong to an inactive slot get no expert: their
    assignments sort behind every real one, lie in no group, cost no expert
    compute and are not counted in the load;
@@ -22,11 +22,11 @@ experts, whatever the load; there is no capacity and nothing falls through.
    prefill call's thousands choice by choice (``_sum_by_choice``).
 
 One rank of an expert-parallel deployment is told which experts it holds
-(``held = (first, count)``): it routes over ALL the router's outputs, as every
-rank does, and computes the part of the result its own ``count`` experts
-give. An assignment to an expert held elsewhere sorts behind every group as
-an invalid row's does: it lies in no group, costs no row tile and adds
-nothing; there is no stand-in for the other ranks and no exchange.
+(``held = (first, count)``): it routes over ALL the router's outputs and
+computes the part of the result its own ``count`` experts give. An assignment
+to an expert held elsewhere sorts behind every group as an invalid row's
+does: it costs no row tile and adds nothing; no stand-in for the other ranks,
+no exchange. Zero-compute experts, and buffers by what is HELD: ``_by_held``.
 
 ``grouped_matmul`` is a Pallas kernel: for each (group, row tile) pair that
 holds a real row it multiplies the tile by that group's matrix, so a step
@@ -56,16 +56,15 @@ def select_experts(logits: jax.Array, top_k: int, norm_topk_prob: bool,
                    scale: float = 1.0, norm_eps: float = 1e-20):
     """Router logits [..., E] float32 -> (weights [..., top_k], experts
     [..., top_k] int32 in descending order of what chose them, scores
-    [..., E]). ``softmax``: the top_k of the softmax, renormalised to sum to
-    one only with ``norm_topk_prob``. ``sigmoid``: the scores are sigmoids;
-    the experts are the top_k of ``scores + bias``, a per-expert selection
-    bias that chooses and does not weigh; the weights are the chosen
-    experts' scores without it, renormalised with ``norm_topk_prob`` (divided
-    by their sum plus ``norm_eps``: published routers differ in it), times
-    ``scale``."""
+    [..., E]). The scores are the softmax or the sigmoids; the experts are
+    the top_k of ``scores + bias``, a per-output selection bias that chooses
+    and does not weigh (``softmax`` without one: of the scores); the weights
+    are the chosen scores without it, renormalised only with
+    ``norm_topk_prob`` (``sigmoid``: divided by their sum plus ``norm_eps``,
+    published routers differ in it), times ``scale``."""
     if kind == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(scores, top_k)
+        weights, experts = _chosen(scores, top_k, bias)
         if norm_topk_prob:
             weights = weights / jnp.maximum(
                 weights.sum(-1, keepdims=True), 1e-9)
@@ -75,9 +74,10 @@ def select_experts(logits: jax.Array, top_k: int, norm_topk_prob: bool,
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         if norm_topk_prob:
             weights = weights / (weights.sum(-1, keepdims=True) + norm_eps)
-        weights = weights * scale
     else:
         raise ValueError(f"unknown router kind {kind!r}")
+    if kind == "sigmoid" or scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32), scores
 
 
@@ -186,25 +186,39 @@ def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
                  name: str = "moe_gmm", router_kind: str = "softmax",
                  router_bias: Optional[jax.Array] = None,
                  router_scale: float = 1.0, router_norm_eps: float = 1e-20,
-                 held: Optional[Tuple[int, int]] = None
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 held: Optional[Tuple[int, int]] = None,
+                 zero_experts: int = 0) -> Tuple[jax.Array, jax.Array]:
     """x [T, D], valid [T] bool, router [D, R], w_gate / w_up [E, D, F],
     w_down [E, F, D] -> (y [T, D] in x's dtype, load [E] int32). ``load`` is
     the number of real rows each expert got; a row that is not valid gives
     zeros and loads nobody. The ``router_*`` arguments are ``route``'s.
     ``held = (first, E)``: the matrices are those of experts ``first .. first
-    + E`` of the router's ``R``; without it ``E`` is ``R`` and all are here."""
+    + E`` of the router's routed outputs; without it all of them are here.
+    ``zero_experts``: the router's LAST that many outputs are no expert but
+    the identity (``R`` = routed + zero): a row that chose one gets ``gate *
+    x`` for it, on every rank alike, and ``load`` is ``[E + 1]``, its last
+    entry the valid assignments that fell on a zero expert."""
     T, D = x.shape
     E = w_gate.shape[0]
+    routed = router.shape[1] - zero_experts
     weights, experts = route(x, router, top_k, norm_topk_prob, router_kind,
                              router_bias, router_scale, router_norm_eps)
+    if zero_experts:
+        # no row is sorted, gathered or multiplied for them: one weighted
+        # sum of gates a row, one multiply (added where y is float32, below)
+        with jax.named_scope("moe.zero"):
+            free = valid[:, None] & (experts >= routed)
+            passed = jnp.where(free, weights, 0.0).sum(-1)[:, None] \
+                * x.astype(jnp.float32)
     if held is not None:
-        assert held[1] == E and held[0] + E <= router.shape[1], (held, E)
+        assert held[1] == E and held[0] + E <= routed, (held, E, routed)
         experts = experts - held[0]
         valid = valid[:, None] & (experts >= 0) & (experts < E)
     else:
-        assert E == router.shape[1], (E, router.shape)
+        assert E == routed, (E, router.shape, zero_experts)
         valid = jnp.broadcast_to(valid[:, None], experts.shape)
+        if zero_experts:
+            valid = valid & (experts < E)
     # an assignment of an invalid row, or to an expert that is not here, goes
     # to "expert E": behind every group
     flat = jnp.where(valid, experts, E).reshape(-1)
@@ -212,9 +226,30 @@ def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
         0, dtype=jnp.int32)
     M = T * top_k
     tm, _ = _tiles(M, D, w_gate.shape[2], x.dtype.itemsize)
-    padded = -(-M // tm) * tm
+    window = held_window(M, E if held else 0, router.shape[1], tm)
+    padded = -(-M // (window or tm)) * (window or tm)
     flat = jnp.pad(flat, (0, padded - M), constant_values=E)
     order = jnp.argsort(flat, stable=True)          # sorted row -> assignment
+    if window:
+        y = _by_held(x, order, load, weights, w_gate, w_up, w_down,
+                     window=window, name=name)
+    else:
+        y = _by_assignment(x, order, load, weights, valid, w_gate, w_up,
+                           w_down, name)
+    if zero_experts:
+        with jax.named_scope("moe.zero"):
+            y = y + passed
+        load = jnp.concatenate([load, free.sum(dtype=jnp.int32)[None]])
+    return y.astype(x.dtype), load
+
+
+def _by_assignment(x, order, load, weights, valid, w_gate, w_up, w_down,
+                   name):
+    """Steps 4 and 5 over ALL ``padded`` sorted assignments, whoever holds
+    them -> y [T, D] float32 (a decode step's: in x's dtype, as the sum of
+    one gather leaves it)."""
+    T, top_k = weights.shape
+    M, padded = T * top_k, order.shape[0]
     rows = x[jnp.minimum(order // top_k, T - 1)]     # [padded, D]
     gate = grouped_matmul(rows, w_gate.astype(x.dtype), load, name=name)
     up = grouped_matmul(rows, w_up.astype(x.dtype), load, name=name)
@@ -224,10 +259,69 @@ def expert_layer(x: jax.Array, valid: jax.Array, router: jax.Array,
     where = jnp.zeros(padded, jnp.int32).at[order].set(
         jnp.arange(padded, dtype=jnp.int32))[:M].reshape(T, top_k)
     if M >= _BY_CHOICE_MIN:
-        return _sum_by_choice(out, where, weights, valid).astype(x.dtype), load
+        return _sum_by_choice(out, where, weights, valid)
     picked = out[where].astype(jnp.float32)           # [T, top_k, D]
     y = jnp.where(valid[..., None], picked * weights[..., None], 0.0)
-    return y.sum(1).astype(x.dtype), load
+    return y.sum(1)
+
+
+def held_window(assignments: int, held: int, outputs: int, tm: int) -> int:
+    """How many sorted rows ``_by_held`` gives the three products at a time
+    (whole row tiles of ``tm``, ``_tiles``' for the call), or 0 where the
+    layer goes by assignment: a decode step (under ``_BY_CHOICE_MIN``
+    assignments), a layer that holds all its experts (``held`` 0) or an
+    eighth and more of the router's ``outputs``: the scatter-add back costs
+    by the row where the other way's gather runs at its bytes' rate, so it
+    pays only where few rows are held (PERF.md section 6, PR 53: the layer
+    alone at both ways). Else twice what uniform routing sends here: the loop
+    runs once unless the call's routing is twice as uneven as that."""
+    if assignments < _BY_CHOICE_MIN or not held or held * 8 >= outputs:
+        return 0
+    expected = assignments * held / outputs
+    return min(-(-assignments // tm), max(1, -(-int(2 * expected) // tm))) * tm
+
+
+def _by_held(x, order, load, weights, w_gate, w_up, w_down, *, window: int,
+             name: str):
+    """Steps 4 and 5 sized by what is HELD. The sorted assignments that
+    belong to an expert held here lie first (``load.sum()`` of them, a
+    fiftieth of ``T x top_k`` at 16 experts of 768 outputs); everything
+    behind them is another rank's, a zero expert's or padding and is never
+    gathered. A ``lax.while_loop`` walks them ``window`` rows at a time:
+    gather ``[window, D]``, the three grouped products with the window's
+    share of each group, and the way back a weighted scatter-add of
+    ``[window, D]`` into ``[T, D]`` float32. Static shapes, no capacity: a
+    call that holds more than one window runs the body again, nothing is
+    dropped and nothing is ``T x top_k`` rows long. Returns y float32."""
+    T, top_k = weights.shape
+    ends = jnp.cumsum(load)
+    starts, mine = ends - load, ends[-1]
+    gates = weights.reshape(-1)
+    at = jnp.arange(window, dtype=jnp.int32)
+
+    def one(state):
+        w, y = state
+        first = w * window
+        picked = jax.lax.dynamic_slice(order, (first,), (window,))
+        live = first + at < mine
+        token = jnp.minimum(picked // top_k, T - 1)
+        rows = x[token]                                    # [window, D]
+        sizes = jnp.clip(ends, first, first + window) \
+            - jnp.clip(starts, first, first + window)
+        gate = grouped_matmul(rows, w_gate.astype(x.dtype), sizes, name=name)
+        up = grouped_matmul(rows, w_up.astype(x.dtype), sizes, name=name)
+        out = grouped_matmul(jax.nn.silu(gate) * up, w_down.astype(x.dtype),
+                             sizes, name=name)
+        g = gates[jnp.minimum(picked, gates.shape[0] - 1)]
+        # a row past the held ones was never written: dropped, not weighed
+        part = jnp.where(live[:, None],
+                         out.astype(jnp.float32) * g[:, None], 0.0)
+        return w + 1, y.at[token].add(part)
+
+    with jax.named_scope("moe.held"):
+        return jax.lax.while_loop(
+            lambda state: state[0] * window < mine, one,
+            (jnp.int32(0), jnp.zeros((T, x.shape[1]), jnp.float32)))[1]
 
 
 # The least ``T x top_k`` whose way back is ``_sum_by_choice``. Every decode
@@ -269,3 +363,13 @@ def _sum_by_choice(out: jax.Array, where: jax.Array, weights: jax.Array,
                          * weights[:, k, None], 0.0)
         y = term if y is None else y + term
     return y
+
+
+def _chosen(scores: jax.Array, top_k: int, bias: Optional[jax.Array]):
+    """``select_experts``' softmax kind: the top_k scores and whose they
+    are; with a selection ``bias`` the top_k of ``scores + bias`` choose and
+    the scores alone weigh. (Down here for the reason ``_BY_CHOICE_MIN`` is.)"""
+    if bias is None:
+        return jax.lax.top_k(scores, top_k)
+    _, experts = jax.lax.top_k(scores + bias, top_k)
+    return jnp.take_along_axis(scores, experts, axis=-1), experts

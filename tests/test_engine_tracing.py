@@ -52,6 +52,8 @@ KEYS = {
     "moe_decode_layer_steps", "moe_decode_assignments",
     "moe_decode_experts_touched", "moe_decode_max_load",
     "moe_decode_routed_assignments",
+    # those of them that fell on a zero-compute expert
+    "moe_decode_zero_assignments",
     # what a latent cache's decode read; 0 without one
     "mla_decode_live_tokens", "mla_decode_read_tokens",
     # a model with layer_kinds: its shared layer's pages, rings, recurrent
@@ -161,6 +163,8 @@ def test_metrics_complete_numeric_monotone(engine):
     assert m["sample_greedy_calls"] == m["sample_calls"]
     # no delta-rule layer: nothing for a kda_scan to walk or pass over
     assert m["kda_scan_chunks"] == m["kda_scan_chunks_skipped"] == 0
+    # no expert, least of all a zero-compute one
+    assert m["moe_decode_zero_assignments"] == 0
     # a token computed for a request that EOS had ended is not a generated one
     assert m["generated_tokens"] <= sum(4 + i for i in range(SLOTS + 2))
     assert m["between_steps_ms"] > 0
