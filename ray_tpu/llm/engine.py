@@ -221,7 +221,12 @@ from ``__init__``, so two snapshots subtract):
   S / CHUNK``) and ``kda_scan_chunks_skipped`` (those of them that lie
   wholly behind their row's length, a padding row's all: the kernel passes
   over them). Its latent layers count under ``mla_decode_*`` as a latent
-  model's do.
+  model's do. For every model, per prefill call: ``flash_q_blocks`` (the
+  query blocks ``flash_fwd``'s grid has a head for the call's ``[R, S]``,
+  ``ops/attention.py:q_blocks``: ``R x S / 512`` from 512 positions on, one
+  a row under that) and ``flash_q_blocks_skipped`` (those of them that lie
+  wholly behind their row's length, a padding row's all: the kernel passes
+  over them, in every attention layer of the prompt side alike).
 
 Such a model's rings and rows need no allocator: a slot owns its own, a
 prefill call overwrites all of them from the prompt (the engine tells it the
@@ -593,7 +598,8 @@ class JaxLLMEngine:
             "window_live_tokens": 0, "prefill_cross_rows": 0,
             "ssd_step_slots": 0, "ssd_step_live_slots": 0,
             "kda_step_slots": 0, "kda_step_live_slots": 0,
-            "kda_scan_chunks": 0, "kda_scan_chunks_skipped": 0}
+            "kda_scan_chunks": 0, "kda_scan_chunks_skipped": 0,
+            "flash_q_blocks": 0, "flash_q_blocks_skipped": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
 
@@ -1044,6 +1050,12 @@ class JaxLLMEngine:
             toks[i, :lens[i]] = self._slots[slot].cache_tokens
         where = jnp.asarray(where)
         rows = [jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(tables)]
+        # the grid steps of flash_fwd a head, and those it passes over
+        from ray_tpu.ops.attention import q_blocks
+
+        blocks, skipped = q_blocks(S, lens)
+        self.metrics["flash_q_blocks"] += blocks
+        self.metrics["flash_q_blocks_skipped"] += skipped
         if self._kda_layers:  # the grid steps of kda_scan, and those it skips
             from ray_tpu.ops.kda import scan_chunks
 

@@ -106,7 +106,11 @@ the step. The kinds, and where each keeps what:
 ``prefill`` is told the slot a row fills (``slots``), overwrites the slot's
 rings and rows from the prompt alone (which is how a slot is reset at
 admission and how a preempted request comes back) and leaves them at position
-``lengths - 1``. Whatever of a per-head q/k norm, an attention gate, sandwich
+``lengths - 1``. Every attention call of its prompt side hands the flash
+kernel ``lengths``: the query blocks wholly behind a row's end are passed
+over and come back zeros (``ops/attention.py``), as a "kda" layer's chunks
+do; nobody reads a position behind its row's end. Whatever of a per-head q/k
+norm, an attention gate, sandwich
 norms, a scaled embedding, a multiplier on what a sublayer adds to the stream
 (``residual_scale``), a softmax scale of its own (``attn_scale``: the queries
 are scaled before the kernels, which divide by sqrt(head_dim)), a multiplier on
@@ -388,9 +392,10 @@ def _latent_qkv(x, p, cfg, positions):
             _latent_row([c, k_pe], _latent_width(cfg)))
 
 
-def _latent_attention_expanded(q_nope, q_pe, c, k_pe, p, cfg):
+def _latent_attention_expanded(q_nope, q_pe, c, k_pe, p, cfg, lengths=None):
     """Prefill's path: keys and values up-projected from the latent to heads,
-    then plain causal attention over 192-wide q . k and 128-wide values."""
+    then plain causal attention over 192-wide q . k and 128-wide values,
+    which passes over what lies behind the rows' ``lengths``."""
     from ray_tpu.ops.attention import attention as attention_op
 
     nope = cfg.qk_nope_head_dim
@@ -401,7 +406,7 @@ def _latent_attention_expanded(q_nope, q_pe, c, k_pe, p, cfg):
         [kv[..., :nope], jnp.broadcast_to(k_pe[..., None, :], q_pe.shape)],
         axis=-1)
     return attention_op(q, k, kv[..., nope:], causal=True,
-                        impl=cfg.attention_impl)
+                        impl=cfg.attention_impl, lens=lengths)
 
 
 def _latent_attention_absorbed(q_nope, q_pe, rows, work, layer, p, cfg):
@@ -707,7 +712,7 @@ def _hybrid_prefill(p, cfg, cache, tokens, lengths, block_tables, slots):
             k, v = _row_heads(row, cfg)
             out = _diff_out(attention_op(
                 q, k, v, causal=True, impl=cfg.attention_impl,
-                window=W if kind == "window" else 0), m, i, cfg)
+                window=W if kind == "window" else 0, lens=lengths), m, i, cfg)
             if kind == "window":
                 rings = _write_rings(rings, window_i, slots, row, ring_pos)
                 window_i += 1
@@ -1117,7 +1122,7 @@ def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
     _, _, page, offset, _, ring_pos = index
     if kind == "latent":
         kept = kept.at[at, page, offset].set(row, mode="drop")
-        return _latent_attention_expanded(*q, lp["attn"], cfg), kept
+        return _latent_attention_expanded(*q, lp["attn"], cfg, lengths), kept
     rep = cfg.n_heads // cfg.n_kv_heads
     if kind == "dense":
         k, v = row
@@ -1126,8 +1131,8 @@ def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
         if rep != 1:
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        return attention_op(q, k, v, causal=True,
-                            impl=cfg.attention_impl), kept
+        return attention_op(q, k, v, causal=True, impl=cfg.attention_impl,
+                            lens=lengths), kept
     R, S = row.shape[:2]
     if kind == "window":
         kept = _write_rings(kept, at, slots, row, ring_pos)
@@ -1138,7 +1143,7 @@ def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
     return attention_op(
         q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
         causal=True, impl=cfg.attention_impl,
-        window=cfg.window if kind == "window" else 0), kept
+        window=cfg.window if kind == "window" else 0, lens=lengths), kept
 
 
 def _step_mixer(cfg, index, page_size, keep, op, kind, at, lp, kept, q, row):

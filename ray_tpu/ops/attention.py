@@ -26,6 +26,16 @@ call, the forward-only ones of the serving engine's prefill among them (a
 window, values with a head size of their own) and every call of heads of 128
 lanes or more, brings a head's rows together first, ``(B*H, S, D)``, and takes
 them apart after.
+A forward-only call that knows its rows' lengths (``lens``: the engine's
+prefill, whose prompts are padded to a bucket) prefetches them as scalars,
+and a program whose query block lies wholly behind its row's end does
+nothing: no loop, no fetch, zeros out (``_flash_fwd_lens_kernel``). Under a
+causal mask those are the dearest programs, each visits every key block
+before it: a 4,200-token prompt in a bucket of 8,192 keeps 45 of 136 visits
+(on the v5e ``bf16[48, 8192, 128]`` takes 7.81 ms at a full bucket, 4.97 at
+6,144 positions and 3.16 at 4,608, 7.69 without lengths: my chip run, PR 54).
+``q_blocks`` is the host's count of both. The differentiated path takes no
+lengths.
 ``_blocks`` sizes the blocks from the shapes; ``_use_pallas_bwd`` picks the
 backward from the shapes too: the pallas pair wherever its whole-sequence
 blocks fit fast memory (bfloat16: head_dim 128 up to 12,288 positions, 64 up
@@ -108,6 +118,20 @@ def _blocks(seq_len: int) -> tuple:
             return block, block
     raise ValueError("flash attention takes at most 128 positions or a "
                      f"multiple of 128, got {seq_len}")
+
+
+def q_blocks(seq_len: int, lens) -> tuple:
+    """On the host: the query blocks ``flash_fwd``'s grid has a head for rows
+    of ``lens`` real positions in a bucket of ``seq_len``, and those of them
+    a call that knows its rows' lengths passes over (a block wholly behind
+    its row's end; every block of a padding row). A bucket the kernel does
+    not take (``attention``'s rule: over 128 positions and no multiple of
+    128) has no grid."""
+    if seq_len > 128 and seq_len % 128:
+        return 0, 0
+    block_q, _ = _blocks(seq_len)
+    blocks = len(lens) * (seq_len // block_q)
+    return blocks, blocks - sum(-(-int(n) // block_q) for n in lens)
 
 
 def _lanes(d: int) -> int:
@@ -220,18 +244,20 @@ def _key_blocks(q_start, block_q, block_k, seq_len, causal):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
-                      sm_scale, window=0, heads=1):
+                      sm_scale, window=0, heads=1, q_block=None):
     """``heads`` 2: the program's blocks are one 128-lane tile of two 64-lane
     heads (``_each_head``), each with a softmax of its own: the statistics
     are lists, one entry a head, the accumulator is the one tile of ``o``
     (a whole tile a head read 4 ms a step slower in cell 1: PERF.md section
-    6, PR 47), and ``lse_ref`` holds a row a head."""
+    6, PR 47), and ``lse_ref`` holds a row a head. ``q_block``: the program's
+    query block, from a caller that has asked already (interpret mode finds
+    no ``program_id`` under a ``pl.when``)."""
     import jax.experimental.pallas as pl
 
     block_q, d = q_ref.shape[1], v_ref.shape[2]
     seq_len = k_ref.shape[1]
     scores = [_scores(q, sm_scale) for q in _each_head(q_ref[0], heads)]
-    q_start = pl.program_id(1) * block_q
+    q_start = (pl.program_id(1) if q_block is None else q_block) * block_q
 
     def step(masked):
         def body(i, carry):
@@ -278,6 +304,32 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         lse_ref[0, h:h + 1] = (m[h] + jnp.log(l_safe[h])).T
 
 
+def _flash_fwd_lens_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                           heads_a_row, **kernel):
+    """``_flash_fwd_kernel`` for a call that knows its rows' lengths (scalar
+    prefetch): a program whose query block lies WHOLLY behind its row's end
+    runs none of the loops. It writes zeros: what lies behind a prompt's end
+    is nobody's, but it goes on through the output projection and the MLP or
+    the experts into the next layer's norms, so it has to be finite. The
+    block that holds the end is computed whole: a real position gets what a
+    call without lengths gives it, from the same blocks in the same order."""
+    import jax.experimental.pallas as pl
+
+    q_block = pl.program_id(1)
+    real = (q_block * q_ref.shape[1]
+            < lens_ref[pl.program_id(0) // heads_a_row])
+
+    @pl.when(real)
+    def _():
+        _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                          q_block=q_block, **kernel)
+
+    @pl.when(jnp.logical_not(real))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        lse_ref[...] = jnp.zeros_like(lse_ref)
+
+
 def _to_bh(x):
     B, S, H, D = x.shape
     return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
@@ -304,13 +356,21 @@ def _whole_seq_params(S, D, Dv, itemsize, room=10 << 20):
         vmem_limit_bytes=held + (16 << 20))}
 
 
-def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool, window: int = 0):
+def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool, window: int = 0,
+                    lens=None):
     """Returns (o, lse) with o in (B, S, H, Dv) and lse in (B*H, 1, S). The
     values may have a head size of their own (latent attention: q . k over
     192, p . v over 128); the softmax scale is the key head size's. With
     ``window`` (causal only) a query sees the ``window`` newest keys up to its
     own, and key blocks left of the window are skipped as the ones above the
-    diagonal are."""
+    diagonal are. With ``lens`` (causal only; ``int32 [B]``, the real
+    positions of each row, 0 for a padding row) the query blocks wholly
+    behind a row's end are passed over (``_flash_fwd_lens_kernel``: zeros)
+    and fetch nothing: their index maps name the blocks the program before
+    them held (the row's last real query block; for a row with no real
+    position, K and V of the call's first head). The keys need no mask of
+    their own: padding lies behind every real query, where the causal mask
+    hides it."""
     import jax.experimental.pallas as pl
 
     B, S, H, D = q.shape
@@ -322,33 +382,57 @@ def _flash_fwd_impl(q, k, v, causal: bool, interpret: bool, window: int = 0):
     if window:
         assert causal, "a window is the causal mask's left edge"
         kernel = functools.partial(kernel, window=window)
-    out, lse = pl.pallas_call(
-        kernel,
+
+    def q_at(bh, qi, *lens):   # behind a row's end: its last real block
+        if lens:
+            last = jnp.maximum(lens[0][bh // H] - 1, 0) // block_q
+            qi = jnp.minimum(qi, last)
+        return bh, qi, 0
+
+    def kv_at(bh, qi, *lens):   # of a row with no real position: nobody's
+        if lens:
+            bh = jnp.where(lens[0][bh // H] > 0, bh, 0)
+        return bh, 0, 0
+
+    how = dict(
         grid=(B * H, S // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, S, Dv), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, block_q, D), q_at),
+            pl.BlockSpec((1, S, D), kv_at),
+            pl.BlockSpec((1, S, Dv), kv_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, Dv), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
-        ],
+            pl.BlockSpec((1, block_q, Dv), lambda bh, qi, *_: (bh, qi, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, qi, *_: (bh, 0, qi)),
+        ])
+    operands = (qt, kt, vt)
+    if lens is not None:
+        assert causal, "the causal mask is what hides a row's padding"
+        from jax.experimental.pallas import tpu as pltpu
+
+        kernel = functools.partial(
+            _flash_fwd_lens_kernel, heads_a_row=H, **kernel.keywords)
+        how = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **how))
+        operands = (lens.astype(jnp.int32), *operands)
+    out, lse = pl.pallas_call(
+        kernel,
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
+        **how,
         **_whole_seq_params(S, D, Dv, k.dtype.itemsize),
-    )(qt, kt, vt)
+    )(*operands)
     return _from_bh(out, B, H), lse
 
 
 def flash_attention_fwd(q, k, v, causal: bool = True,
-                        interpret: bool = False, window: int = 0):
+                        interpret: bool = False, window: int = 0, lens=None):
     """(B, S, H, D) flash forward via pallas (TPU) / interpret mode (CI)."""
-    return _flash_fwd_impl(q, k, v, causal, interpret, window)[0]
+    return _flash_fwd_impl(q, k, v, causal, interpret, window, lens)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -698,9 +782,14 @@ def _flash(q, k, v, causal: bool, interpret: bool):
 
 
 def attention(q, k, v, causal: bool = True, impl: str = "auto",
-              segment_ids: Optional[jax.Array] = None, window: int = 0):
-    """Dispatching attention op used by the flagship model. ``window``: the
-    forward alone (serving's prefill); the flash kernels' backward has none."""
+              segment_ids: Optional[jax.Array] = None, window: int = 0,
+              lens: Optional[jax.Array] = None):
+    """Dispatching attention op used by the flagship model. ``window``, and
+    ``lens`` (each row's real positions, padding behind them: the flash
+    kernel passes over the query blocks wholly behind a row's end and leaves
+    zeros there; the reference computes them, and nobody reads them): the
+    forward alone (serving's prefill); the flash kernels' backward has
+    neither."""
     if impl == "auto":
         from ray_tpu.utils import is_tpu
 
@@ -711,8 +800,9 @@ def attention(q, k, v, causal: bool = True, impl: str = "auto",
             and q.shape[-1] in (64, 128, 192, 256)
         )
         impl = "flash" if use_flash else "xla"
-    if window and impl in ("flash", "flash_interpret"):
-        return flash_attention_fwd(q, k, v, causal, impl != "flash", window)
+    if (window or lens is not None) and impl in ("flash", "flash_interpret"):
+        return flash_attention_fwd(q, k, v, causal, impl != "flash", window,
+                                   lens)
     if impl == "flash":
         return _flash(q, k, v, causal, False)
     if impl == "flash_interpret":

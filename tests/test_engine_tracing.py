@@ -66,7 +66,10 @@ KEYS = {
     # the same two for delta-rule layers, and the chunks their prefill
     # kernel's grid has and passes over; 0 without such layers
     "kda_step_slots", "kda_step_live_slots",
-    "kda_scan_chunks", "kda_scan_chunks_skipped"}
+    "kda_scan_chunks", "kda_scan_chunks_skipped",
+    # the query blocks a prefill call's flash kernel has a head, and those
+    # behind their row's end that it passes over
+    "flash_q_blocks", "flash_q_blocks_skipped"}
 LADDER = tuple(f"itl_over_{n}ms" for n in (25, 50, 100, 200, 400, 800))
 PHASES = ("admit_ms", "prefill_dispatch_ms", "decode_dispatch_ms",
           "sample_dispatch_ms", "readback_ms", "emit_ms")
@@ -163,6 +166,9 @@ def test_metrics_complete_numeric_monotone(engine):
     assert m["sample_greedy_calls"] == m["sample_calls"]
     # no delta-rule layer: nothing for a kda_scan to walk or pass over
     assert m["kda_scan_chunks"] == m["kda_scan_chunks_skipped"] == 0
+    # a prompt here fills its row's one query block, a padding row none
+    assert (m["flash_q_blocks"] - m["flash_q_blocks_skipped"]
+            == m["admitted"] > 0)
     # no expert, least of all a zero-compute one
     assert m["moe_decode_zero_assignments"] == 0
     # a token computed for a request that EOS had ended is not a generated one

@@ -352,6 +352,61 @@ def test_flash_reads_the_projections_layout_for_pairs_of_64_lane_heads(
             np.testing.assert_allclose(g, r, atol=2e-4, rtol=2e-4)
 
 
+# a call that knows its rows' lengths: (key / value head size, window, the
+# rows' lengths in a bucket of 512 under blocks of 128, the query blocks the
+# rows hold among the 4 a row that the grid has: counted by hand). The four
+# rows: none (a padding row), the whole bucket, one ending ON a block's edge
+# and one a position past it
+LENS_ROWS = ([0, 512, 256, 257], [0, 4, 2, 3])
+LENS_CASES = {
+    "128-one-row": (128, 128, 0, [257], [3]),
+    "128-one-row-of-a-position": (128, 128, 0, [1], [1]),
+    "128": (128, 128, 0, *LENS_ROWS),
+    "192-values-of-128": (192, 128, 0, *LENS_ROWS),
+    "64": (64, 64, 0, *LENS_ROWS),
+    "64-window-shorter-than-the-rows": (64, 64, 100, *LENS_ROWS),
+    "64-window-longer-than-two-rows": (64, 64, 300, *LENS_ROWS),
+    "128-window-of-a-block-one-row": (128, 128, 128, [130], [2]),
+    "192-values-of-128-window-longer-than-the-bucket": (192, 128, 1024,
+                                                        *LENS_ROWS),
+}
+
+
+@pytest.mark.parametrize("D,Dv,window,lens,held", list(LENS_CASES.values()),
+                         ids=list(LENS_CASES))
+def test_flash_passes_over_the_blocks_behind_a_rows_end(monkeypatch, D, Dv,
+                                                        window, lens, held):
+    """``attention(..., lens=)`` through the forward-only kernel (interpret
+    mode, float32): every real position reads what ``reference_attention``
+    gives it and, bit for bit, what the same kernel gives without lengths
+    (the same blocks in the same order); the query blocks wholly behind a
+    row's end are zeros, what lies behind the end inside the block that
+    holds it is finite; and ``q_blocks`` counts what the grid passes over."""
+    from ray_tpu.ops.attention import attention, flash_attention_fwd, q_blocks
+
+    S, block, H = 512, 128, 2
+    monkeypatch.setattr(ATTENTION, "_blocks", lambda seq_len: (block, block))
+    rq, rk, rv = jax.random.split(jax.random.PRNGKey(D + window + len(lens)), 3)
+    q = jax.random.normal(rq, (len(lens), S, H, D), jnp.float32)
+    k = jax.random.normal(rk, (len(lens), S, H, D), jnp.float32)
+    v = jax.random.normal(rv, (len(lens), S, H, Dv), jnp.float32)
+    got = np.asarray(attention(q, k, v, impl="flash_interpret", window=window,
+                               lens=jnp.asarray(lens, jnp.int32)))
+    whole = np.asarray(flash_attention_fwd(q, k, v, True, True, window))
+    want = np.asarray(reference_attention(q, k, v, window=window))
+    assert np.isfinite(got).all()
+    for row, (n, blocks) in enumerate(zip(lens, held)):
+        np.testing.assert_allclose(got[row, :n], want[row, :n], atol=2e-5,
+                                   rtol=2e-5)
+        np.testing.assert_array_equal(got[row, :n], whole[row, :n])
+        assert blocks == -(-n // block)
+        assert not got[row, blocks * block:].any()
+        if n % block:  # the block that holds the end is computed whole
+            np.testing.assert_array_equal(got[row, n:blocks * block],
+                                          whole[row, n:blocks * block])
+    assert q_blocks(S, lens) == (len(lens) * 4, len(lens) * 4 - sum(held))
+
+
 def _stable_text(lowered):
     """A lowering's StableHLO with each Mosaic kernel's module decoded and
     printed WITHOUT its source locations (file, line and column of every
@@ -396,15 +451,37 @@ SERVE_PREFILL_CALLS = {
 }
 
 
-@pytest.mark.parametrize("kind", list(SERVE_PREFILL_CALLS))
-def test_serve_prefill_attention_lowers_as_it_did(kind):
+# the same four calls told their rows' lengths, as the engine's prefill tells
+# them since PR 54 (the lengths prefetched as scalars, a program behind its
+# row's end passed over): sha256 of each lowering at that PR, jax 0.9.0
+SERVE_PREFILL_CALLS_WITH_LENS = {
+    "128-lane-heads": "21dcd0ba59bbae7a8f73eb43427a08cb"
+                      "d6eab52bca57ad2016a9bd6ac99f0faf",
+    "64-lane-heads": "e7a658ce4b140bcbf783c00c1556e87c"
+                     "f2d77bd342b99d08741e39f08f61dd89",
+    "window": "e914390b4ebaf29ec1ff86df24c4c83b"
+              "f921c72b6b122577c72bb76fdd533d09",
+    "latent-192-128": "c1842238e76849c42014940df359dcb2"
+                      "6749fbca23afb0310065eb65ff4a6c4f",
+}
+
+
+@pytest.mark.parametrize("kind,lens", [
+    (kind, lens) for lens in (False, True) for kind in SERVE_PREFILL_CALLS],
+    ids=[kind + "-lens" * lens for lens in (False, True)
+         for kind in SERVE_PREFILL_CALLS])
+def test_serve_prefill_attention_lowers_as_it_did(kind, lens):
     """The forward-only calls keep the ``(B*H, S, D)`` layout and the program
     they had before the differentiated calls moved (PR 47): each lowers for
     the TPU to the text it lowered to then. A PR that moves these calls to the
     projections' layout too (ROADMAP S1(l), S2(e)) changes the digests on
     purpose, with the serve cells measured; any other change to them is a
     change to seven cells' prefill that nobody asked for. (A new jax may
-    print the same program otherwise: take the digests again at the parent.)"""
+    print the same program otherwise: take the digests again at the parent.)
+    A call WITHOUT lengths is what a train step's forward lowers to where it
+    is not differentiated in place: its digests are PR 47's still. The
+    engine's calls carry their rows' lengths (``lens``) and have digests of
+    their own."""
     import hashlib
 
     from ray_tpu.ops.attention import attention
@@ -414,9 +491,13 @@ def test_serve_prefill_attention_lowers_as_it_did(kind):
     shape, dv, window, digest = SERVE_PREFILL_CALLS[kind]
     qk = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     v = jax.ShapeDtypeStruct(shape[:3] + (dv,), jnp.bfloat16)
-    lowered = jax.jit(lambda q, k, v: attention(
-        q, k, v, causal=True, impl="flash", window=window)).trace(
-            qk, qk, v).lower(lowering_platforms=("tpu",))
+    operands = (qk, qk, v)
+    if lens:
+        digest = SERVE_PREFILL_CALLS_WITH_LENS[kind]
+        operands += (jax.ShapeDtypeStruct(shape[:1], jnp.int32),)
+    lowered = jax.jit(lambda q, k, v, lens=None: attention(
+        q, k, v, causal=True, impl="flash", window=window, lens=lens)).trace(
+            *operands).lower(lowering_platforms=("tpu",))
     assert hashlib.sha256(
         _stable_text(lowered).encode()).hexdigest() == digest
 
