@@ -66,7 +66,7 @@ class EngineConfig:
     # checked against the model as expect_experts is, and for its reason
     expect_latent_rank: int = 0
     # layers with recurrent state (a decoder-hybrid-decoder's Mamba layers, a
-    # model's gated short convolutions, Mamba-2 or delta-rule layers; 0: none, every layer
+    # model's gated short convolutions, Mamba-2, delta-rule or power-retention layers; 0: none, every layer
     # keeps pages or a ring), checked the same way: such a model keeps rows by slot beside its
     # pages
     expect_state_layers: int = 0
@@ -82,6 +82,12 @@ class EngineConfig:
     # none): such a layer keeps a float32 matrix state a head and slot, checked
     # against the model the same way
     expect_kda_heads: int = 0
+    # key/value heads of the model's power-retention layers (0: it has none):
+    # such a layer keeps the symmetric square of each such head's keys
+    # against its values a slot, float32 (34.6 MB a slot and layer at 8 heads
+    # of 128): the largest thing any cache holds, checked against the model
+    # the same way
+    expect_retention_heads: int = 0
 
     def __post_init__(self):
         if self.max_model_len % self.page_size:

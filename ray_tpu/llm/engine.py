@@ -221,8 +221,19 @@ from ``__init__``, so two snapshots subtract):
   S / CHUNK``) and ``kda_scan_chunks_skipped`` (those of them that lie
   wholly behind their row's length, a padding row's all: the kernel passes
   over them). Its latent layers count under ``mla_decode_*`` as a latent
-  model's do. For every model, per prefill call: ``flash_q_blocks`` (the
-  query blocks ``flash_fwd``'s grid has a head for the call's ``[R, S]``,
+  model's do. For a model with power-retention layers (``"retention"``), per
+  decode step (riding ones too) and such layer: ``retention_state_slots``
+  (the slots whose state the step reads and writes: all of them,
+  ``ops/retention.py:retention_step`` walks every slot) and
+  ``retention_live_slots`` (those of them that decode) and, per prefill
+  call, ``retention_scan_chunks`` / ``retention_scan_chunks_skipped``
+  (``ops/retention.py:scan_chunks``: the chunks ``retention_scan``'s grid has
+  a head and such layer, and those wholly behind their row's length). A
+  model of such layers alone keeps nothing by position: its requests are
+  still handed pages and give them back (the accounting is every model's and
+  costs nothing), and the pages address nothing. For every model that has
+  an attention layer, per prefill call: ``flash_q_blocks`` (the query
+  blocks ``flash_fwd``'s grid has a head for the call's ``[R, S]``,
   ``ops/attention.py:q_blocks``: ``R x S / 512`` from 512 positions on, one
   a row under that) and ``flash_q_blocks_skipped`` (those of them that lie
   wholly behind their row's length, a padding row's all: the kernel passes
@@ -473,8 +484,10 @@ class JaxLLMEngine:
         kinds = self.mcfg.layer_kinds
         self._ssd_layers = kinds.count("mamba2")
         self._kda_layers = kinds.count("kda")
+        self._retention_layers = kinds.count("retention")
         state_layers = (kinds.count("mamba") + kinds.count("conv")
-                        + self._ssd_layers + self._kda_layers)
+                        + self._ssd_layers + self._kda_layers
+                        + self._retention_layers)
         if state_layers != self.ecfg.expect_state_layers:
             raise ValueError(
                 f"the deployment expects {self.ecfg.expect_state_layers} "
@@ -494,6 +507,12 @@ class JaxLLMEngine:
                 f"the deployment expects delta-rule layers of "
                 f"{self.ecfg.expect_kda_heads} heads, the model's have "
                 f"{self.mcfg.kda_heads}")
+        retention_heads = self.mcfg.n_kv_heads * bool(self._retention_layers)
+        if retention_heads != self.ecfg.expect_retention_heads:
+            raise ValueError(
+                f"the deployment expects power-retention layers of "
+                f"{self.ecfg.expect_retention_heads} key/value heads, the "
+                f"model's have {retention_heads}")
         self.tokenizer = get_tokenizer(config.tokenizer)
         self._mr = model_runner
         self._jax = jax
@@ -599,6 +618,8 @@ class JaxLLMEngine:
             "ssd_step_slots": 0, "ssd_step_live_slots": 0,
             "kda_step_slots": 0, "kda_step_live_slots": 0,
             "kda_scan_chunks": 0, "kda_scan_chunks_skipped": 0,
+            "retention_state_slots": 0, "retention_live_slots": 0,
+            "retention_scan_chunks": 0, "retention_scan_chunks_skipped": 0,
             "flash_q_blocks": 0, "flash_q_blocks_skipped": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
@@ -976,7 +997,7 @@ class JaxLLMEngine:
                 # positions the step attends over, through the block tables
                 attrs["live_tokens"] = int(
                     (self._seq_lens[self._active] + 1).sum())
-            if self._ssd_layers or self._kda_layers:
+            if self._ssd_layers or self._kda_layers or self._retention_layers:
                 # live slots whose state the step moves
                 attrs["state_slots"] = int(self._active.sum())
             load = None
@@ -1050,18 +1071,26 @@ class JaxLLMEngine:
             toks[i, :lens[i]] = self._slots[slot].cache_tokens
         where = jnp.asarray(where)
         rows = [jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(tables)]
-        # the grid steps of flash_fwd a head, and those it passes over
-        from ray_tpu.ops.attention import q_blocks
+        if mr.attends(self.mcfg):
+            # the grid steps of flash_fwd a head, and those it passes over (a
+            # model none of whose layers attends runs no flash_fwd)
+            from ray_tpu.ops.attention import q_blocks
 
-        blocks, skipped = q_blocks(S, lens)
-        self.metrics["flash_q_blocks"] += blocks
-        self.metrics["flash_q_blocks_skipped"] += skipped
+            blocks, skipped = q_blocks(S, lens)
+            self.metrics["flash_q_blocks"] += blocks
+            self.metrics["flash_q_blocks_skipped"] += skipped
         if self._kda_layers:  # the grid steps of kda_scan, and those it skips
             from ray_tpu.ops.kda import scan_chunks
 
             chunks, skipped = scan_chunks(S, lens)
             self.metrics["kda_scan_chunks"] += chunks
             self.metrics["kda_scan_chunks_skipped"] += skipped
+        if self._retention_layers:  # retention_scan's grid steps likewise
+            from ray_tpu.ops.retention import scan_chunks
+
+            chunks, skipped = scan_chunks(S, lens)
+            self.metrics["retention_scan_chunks"] += chunks
+            self.metrics["retention_scan_chunks_skipped"] += skipped
         if self.mcfg.layer_kinds:  # a model that keeps state by slot is told
             rows.append(where)
         carries = self._carries(R, S)
@@ -1112,7 +1141,7 @@ class JaxLLMEngine:
         call) attends over, into the counters of the model's kind."""
         if self.mcfg.kv_latent_rank:
             self._count_paged_reads("mla_decode")
-        elif self.mcfg.layer_kinds:
+        elif self.mcfg.layer_kinds and self._page_leaves():
             self._count_paged_reads("shared_kv")
             self.metrics["window_live_tokens"] += int(np.minimum(
                 self._seq_lens[self._active] + 1, self.mcfg.window).sum())
@@ -1122,6 +1151,10 @@ class JaxLLMEngine:
             self.metrics[name + "_step_slots"] += layers * len(self._slots)
             self.metrics[name + "_step_live_slots"] += (
                 layers * int(self._active.sum()))
+        self.metrics["retention_state_slots"] += (
+            self._retention_layers * len(self._slots))
+        self.metrics["retention_live_slots"] += (
+            self._retention_layers * int(self._active.sum()))
 
     def _count_slot_steps(self, prefilling: int = 0) -> None:
         """Every slot's row of the decode step being dispatched (alone, or

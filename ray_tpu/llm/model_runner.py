@@ -23,7 +23,8 @@ traffic is the rows it writes, not the cache.
 ONE per-layer composition (``_forward``) is both programs of every model but
 the decoder-hybrid-decoder: a prompt side, a step side, or both. It walks the
 layers' kinds (``_kinds``), asks the kind for its inputs (``_attn_inputs``,
-``_conv_gates``, ``_mamba2_inputs``, ``_kda_inputs``), hands each side's rows to the kind's mixer
+``_conv_gates``, ``_mamba2_inputs``, ``_kda_inputs``, ``_retention_inputs``),
+hands each side's rows to the kind's mixer
 (``_prompt_mixer``, ``_step_mixer``) and runs the residual, the norms, the MLP
 or the experts and the head once over all rows, so ``prefill`` can carry a
 decode step's rows beside its prompts (``riders``; ``rides`` says for which
@@ -101,7 +102,26 @@ the step. The kinds, and where each keeps what:
   beta's folds, the recurrence, the head's output norm and the gate in its
   tile, and writes ``o`` in the products' type as ``o_proj`` reads it. The
   step side (``[B, 1]`` rows) does the same arithmetic in XLA around
-  ``kda_step`` (``_kda_operands`` before it, the norm and gate after).
+  ``kda_step`` (``_kda_operands`` before it, the norm and gate after);
+- "retention" of such a model (power retention: gated power attention of
+  degree 2, ``models/transformer.py:Retention``): per layer and slot in
+  ``ssm`` the state of every key/value head, float32, the symmetric square
+  of its keys against their values laid by rotation with the normaliser's
+  matrix behind it, ``[key/value heads, head_dim / 2 + 2, head_dim,
+  head_dim]`` as ``ops/retention.py`` keeps it (34.6 MB a slot and layer at 8
+  heads of 128: most of what the chip holds), ``n_heads / n_kv_heads`` query
+  heads reading ONE state; no ``conv``, and nothing by position: a model of
+  such layers alone has no paged leaf, its block tables stay arguments of
+  both programs and address nothing. q, k and v are the attention kinds' three
+  products (``_qkv``: the per-head norms, then the rotation by the rows'
+  positions, on both sides alike) and one float32 product makes the gates.
+  Prefill runs the chunked recurrence over the bucket (``retention_scan``
+  through ``ops/retention.py:retention_prefill``, padding passed over from
+  ``lengths`` on, by the whole chunk where a chunk holds nothing else) and
+  WRITES the slot's state from the prompt alone, which is how a slot is reset
+  at admission, reused, or given back to a preempted request; a decode step
+  steps every slot's state once, in place (``retention_step``; beside a prompt
+  ``retention_riding`` with ``keep``); ``o_proj`` runs once over all rows.
 
 ``prefill`` is told the slot a row fills (``slots``), overwrites the slot's
 rings and rows from the prompt alone (which is how a slot is reset at
@@ -195,7 +215,16 @@ class Cache(NamedTuple):
     [heads, key lanes, value lanes] (``ops/kda.py``'s layout), and the last
     ``kda_conv - 1`` rows of the convolutions' input ``q | k | v``, ``3 x
     heads x head dim`` wide; its "latent" layers' rows lie in ``rows``, which
-    then has as many layers as the model has latent ones. ``moe_load``: for a model with
+    then has as many layers as the model has latent ones. Of a model with
+    "retention" layers: ``ssm`` alone, per such layer and slot (and one slot
+    past the last, where a prefill call's padding rows land) every
+    key/value head's state with its normaliser, float32, [key/value heads,
+    head_dim / 2 + 2, head_dim, head_dim] (``ops/retention.py``'s layout: the
+    normaliser is the last of those slabs, one leaf, so that one alias moves
+    both in place and every tile is whole); a model of NO paged kind (such
+    layers alone) holds no ``pages``, ``rows``, ``k`` or ``v`` at all, not
+    even an empty one: its block tables address nothing and ``_page_size`` is
+    0. ``moe_load``: for a model with
     experts, what the call's routing did. Rings and rows by slot belong to a
     SLOT: prefill overwrites all of a slot's from the prompt alone, which is
     also how a slot is reset at admission; a slot that is not active computes
@@ -205,7 +234,8 @@ class Cache(NamedTuple):
     rows: Optional[jax.Array] = None  # [L or latent layers, NP, P, W]
     pages: Optional[jax.Array] = None  # [full layers, NP, P, 2 KVH hd]
     rings: Optional[jax.Array] = None  # [window layers, B, window, 2 KVH hd]
-    # [mamba layers, B, N, inner] or [kda layers, B, H, K, K], float32
+    # [mamba layers, B, N, inner], [kda layers, B, H, K, K] or [retention
+    # layers, B + 1, KVH, hd / 2 + 2, hd, hd], float32
     ssm: Optional[jax.Array] = None
     # [mamba layers, ssm_conv - 1, B, inner (+ 2 N: "mamba2")], [conv layers,
     # conv_taps - 1, B, d_model] or [kda layers, kda_conv - 1, B, 3 H K]
@@ -219,8 +249,10 @@ PAGE_LEAVES = ("k", "v", "rows", "pages")
 
 
 def _page_size(cache: Cache) -> int:
-    return next(getattr(cache, name) for name in PAGE_LEAVES
-                if getattr(cache, name) is not None).shape[2]
+    """The positions of a page; 0 for a model that keeps nothing by position
+    (no leaf of ``PAGE_LEAVES``)."""
+    return next((getattr(cache, name).shape[2] for name in PAGE_LEAVES
+                 if getattr(cache, name) is not None), 0)
 
 
 def _latent_width(cfg: TransformerConfig) -> int:
@@ -252,6 +284,7 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
         window, full = kinds.count("window"), kinds.count("full")
         latent, kda = kinds.count("latent"), kinds.count("kda")
         mamba = kinds.count("mamba") + kinds.count("mamba2")
+        retention = kinds.count("retention")
         rows = state = None
         if mamba:  # a "mamba2" layer convolves x | B | C, a "mamba" layer x
             rows = (mamba, cfg.ssm_conv - 1, max_num_seqs, cfg.ssm_inner
@@ -264,11 +297,18 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
             H, K = cfg.kda_heads, cfg.kda_head_dim
             rows = (kda, cfg.kda_conv - 1, max_num_seqs, 3 * H * K)
             state = (kda, max_num_seqs, H, K, K)
+        elif retention:
+            from ray_tpu.ops.retention import check_degree, state_shape
+
+            check_degree(cfg.retention_degree)
+            # a slot past the last: where a padding row's state lands
+            state = (retention, max_num_seqs + 1, cfg.n_kv_heads,
+                     *state_shape(cfg.head_dim))
         return Cache(
             rows=jnp.zeros((latent, num_pages, page_size, _latent_width(cfg)),
                            cfg.dtype) if latent else None,
             pages=jnp.zeros((full, num_pages, page_size, row), cfg.dtype)
-            if full or not latent else None,
+            if full or not (latent or retention) else None,
             rings=jnp.zeros((window, max_num_seqs, cfg.window, row), cfg.dtype)
             if window else None,
             ssm=jnp.zeros(state, jnp.float32) if state else None,
@@ -585,9 +625,11 @@ def _prompt_index(cfg, cache, S, lengths, block_tables):
     P, W = _page_size(cache), cfg.window
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     in_prompt = positions < lengths[:, None]
-    page_for = jnp.take_along_axis(block_tables, positions // P, axis=1)
-    page = jnp.where(in_prompt, page_for, 0)
-    offset = jnp.where(in_prompt, positions % P, 0)
+    page = offset = None  # of a model that keeps nothing by position
+    if P:
+        page_for = jnp.take_along_axis(block_tables, positions // P, axis=1)
+        page = jnp.where(in_prompt, page_for, 0)
+        offset = jnp.where(in_prompt, positions % P, 0)
     last = jnp.maximum(lengths - 1, 0).astype(jnp.int32)[:, None]
     ring_pos = None
     if cache.rings is not None:
@@ -619,6 +661,8 @@ def _decode_index(cfg, cache, seq_lens, block_tables, active):
     slot = jnp.arange(B, dtype=jnp.int32)
     positions = seq_lens.astype(jnp.int32)
     at = positions[:, None]
+    if not P:  # nothing is kept by position: no page, no work list
+        return slot, positions, None, None, None, None, at
     cur_page = jnp.take_along_axis(block_tables, at // P, axis=1)[:, 0]
     page = jnp.where(active, cur_page, 0)
     offset = jnp.where(active, positions % P, 0)
@@ -784,7 +828,8 @@ def _hybrid_decode(p, cfg, cache, last_tokens, seq_lens, block_tables, active):
 # grouped-query heads, rotated or not by kind, pages for each "full" layer, a
 # ring for each "window" layer, conv_taps - 1 rows a slot for each "conv"
 # layer (no heads at all), a matrix state with a convolution tail a slot
-# for each "mamba2" and each "kda" layer and one row a position for each
+# for each "mamba2" and each "kda" layer, the symmetric square's state a slot
+# for each "retention" layer and one row a position for each
 # "latent" layer; with the per-head q/k norm, the attention gate,
 # the sandwich norms, the multipliers and the experts the config asks for. ``_embed`` decides
 # the residual stream's type
@@ -797,6 +842,15 @@ def _kinds(cfg: TransformerConfig) -> Tuple[str, ...]:
     ``k | v`` rows in ``pages`` under the paged kernel, here."""
     return cfg.layer_kinds or (
         ("latent" if cfg.kv_latent_rank else "dense",) * cfg.n_layers)
+
+
+def attends(cfg: TransformerConfig) -> bool:
+    """Does a prefill call of this model run attention over its rows: has it
+    a layer of a kind whose ``_prompt_mixer`` is ``attention`` (the
+    decoder-hybrid-decoder's differential attention too)? A model of "conv",
+    "mamba", "mamba2", "kda" or "retention" layers alone does not."""
+    return cfg.sambay or any(
+        kind in ("dense", "latent", "full", "window") for kind in _kinds(cfg))
 
 
 def _embed(p, cfg, tokens):
@@ -1068,6 +1122,55 @@ def _kda_step(qkv, gates, lp, cfg, kept, layer, keep, op):
     return o, (ssm, conv.at[layer].set(rows))
 
 
+def _retention_inputs(x, lp, cfg, positions):
+    """A "retention" layer's projections of the normalised stream x [.., D],
+    for all rows: q [.., H, hd], k and v [.., KVH, hd] as an attention layer
+    makes them (``_qkv``: three products, the per-head norms, then the
+    rotation by the rows' ``positions`` where ``rope_kinds`` names the kind)
+    and the log-gates [.., KVH], float32 from the product on (one gate a
+    key/value head, kernel and bias). Returns (log-gates, [q, k, v])."""
+    m = lp["retention"]
+    h = _normed(x, lp["attn_norm"], cfg)
+    with jax.named_scope("retention.inputs"):
+        q, k, v = _qkv(h, m, cfg, positions, "retention" in cfg.rope_kinds)
+        log_g = jax.nn.log_sigmoid(_dense(h, m["g_proj"], jnp.float32))
+    return log_g, [q, k, v]
+
+
+def _retention_prefill(qkv, log_g, cfg, ssm, layer, slots, lengths):
+    """A "retention" layer's recurrence over a prefill call's rows (q [R, S,
+    H, hd], k, v [R, S, KVH, hd], log_g [R, S, KVH]) from a zero state, and
+    ``ssm`` with the states of ``slots`` left at the prompts' last position
+    (the kernel writes each row's state into its slot of the leaf itself; a
+    padding row's slot is the one past the last, which the leaf has for it).
+    The kernel does nothing for a chunk that lies wholly behind ``lengths``
+    and forces no decay and no key from ``lengths`` on inside the chunk that
+    holds the end; ``o`` behind a prompt's end is zeros, which go on through
+    ``o_proj`` and the MLP like any row."""
+    from ray_tpu.ops.retention import retention_prefill
+
+    q, k, v = (t.reshape(*t.shape[:2], -1) for t in qkv)
+    with jax.named_scope("retention.scan"):
+        o, ssm = retention_prefill(q, k, v, log_g, lengths, ssm, layer, slots,
+                                   heads=(cfg.n_heads, cfg.n_kv_heads))
+    return o.reshape(qkv[0].shape), ssm
+
+
+def _retention_step(qkv, log_g, cfg, ssm, layer, keep, op):
+    """A "retention" layer's recurrence over a decode step's rows (q [B, 1,
+    H, hd], k, v [B, 1, KVH, hd], log_g [B, 1, KVH]): one step of every
+    slot's state in place (``ops/retention.py:retention_step``); with
+    ``keep`` [B] only the slots it marks move."""
+    from ray_tpu.ops.retention import retention_step
+
+    q, k, v = (t[:, 0] for t in qkv)
+    with jax.named_scope("retention.step"):
+        o, ssm = retention_step(
+            ssm, layer, q, k, v, log_g[:, 0], keep,
+            name="retention_step" if op == "decode" else "retention_" + op)
+    return o.astype(cfg.dtype)[:, None], ssm
+
+
 def _paired_rest(x, o, lp, cfg, valid, name, carried):
     """``_block_rest`` of a sublayer of a shortcut-connected double layer
     (``shortcut_moe``): every sublayer has a dense MLP; the even one also
@@ -1119,6 +1222,8 @@ def _prompt_mixer(cfg, index, slots, lengths, kind, at, lp, kept, q, row):
                                index[1])
     if kind == "kda":
         return _kda_prefill(row, q, lp, cfg, kept, at, slots, lengths)
+    if kind == "retention":
+        return _retention_prefill(row, q, cfg, kept, at, slots, lengths)
     _, _, page, offset, _, ring_pos = index
     if kind == "latent":
         kept = kept.at[at, page, offset].set(row, mode="drop")
@@ -1159,6 +1264,8 @@ def _step_mixer(cfg, index, page_size, keep, op, kind, at, lp, kept, q, row):
         return _mamba2_step(row, q, lp, cfg, kept, at, keep, op)
     if kind == "kda":
         return _kda_step(row, q, lp, cfg, kept, at, keep, op)
+    if kind == "retention":
+        return _retention_step(row, q, cfg, kept, at, keep, op)
     slot, positions, page, offset, work, ring_work, _ = index
     if kind == "latent":
         kept = kept.at[at, page, offset].set(row[:, 0], mode="drop")
@@ -1237,7 +1344,8 @@ def _forward(p, cfg, cache, prompt=None, step=None):
     kinds, plain = _kinds(cfg), not cfg.layer_kinds
     kept = {"dense": (cache.k, cache.v), "latent": cache.rows,
             "full": cache.pages, "window": cache.rings, "conv": cache.conv,
-            "mamba2": (cache.ssm, cache.conv), "kda": (cache.ssm, cache.conv)}
+            "mamba2": (cache.ssm, cache.conv), "kda": (cache.ssm, cache.conv),
+            "retention": cache.ssm}
     xs, positions, valid, mixers = [], [], [], []
     if prompt is not None:
         tokens, lengths, tables, slots = prompt
@@ -1274,6 +1382,8 @@ def _forward(p, cfg, cache, prompt=None, step=None):
             q, (row, gate) = _mamba2_inputs(x, lp, cfg)
         elif kind == "kda":  # "q": the two gates and beta, split likewise
             q, row = _kda_inputs(x, lp, cfg)
+        elif kind == "retention":  # "q": the log-gates; the row: q, k, v
+            q, row = _retention_inputs(x, lp, cfg, positions)
         else:
             h, q, row = _attn_inputs(x, lp, cfg, positions, kind)
         outs = []
@@ -1288,6 +1398,9 @@ def _forward(p, cfg, cache, prompt=None, step=None):
             o = _mamba2_out(gate, o, lp, cfg)
         elif kind == "kda":  # each side's norm and gate are its mixer's
             o = _dense(o, lp["kda"]["o_proj"], cfg.dtype)
+        elif kind == "retention":
+            o = jnp.einsum("...hk,hkd->...d", o, lp["retention"]["o_proj"][
+                "kernel"].astype(cfg.dtype))
         else:
             o = _attn_out(h, o, lp, cfg)
         if cfg.shortcut_moe:
@@ -1308,7 +1421,7 @@ def _forward(p, cfg, cache, prompt=None, step=None):
         logits = logits[:R], logits[R:]
     k, v = kept["dense"]
     state = next((kind for kind in ("mamba2", "kda") if kind in kinds), "")
-    ssm, conv = kept[state] if state else (None, kept["conv"])
+    ssm, conv = kept[state] if state else (kept["retention"], kept["conv"])
     return logits, Cache(
         k=k, v=v, rows=kept["latent"], pages=kept["full"],
         rings=kept["window"], ssm=ssm, conv=conv,

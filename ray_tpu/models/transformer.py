@@ -119,8 +119,8 @@ class TransformerConfig:
     # projections and a tied head (``HybridBlock``). "rms": the RMSNorm
     # block of every model without kinds (``Block``), plain grouped-query
     # heads under the causal mask ("full") or the window's ("window"); only
-    # "window", "full", "conv", "mamba2", "kda" and "latent" are kinds of such a
-    # block. "conv" is
+    # "window", "full", "conv", "mamba2", "kda", "retention" and "latent" are
+    # kinds of such a block. "conv" is
     # a gated short convolution (``ShortConv``): no attention, no position
     # embedding, and in serving ``conv_taps - 1`` rows a slot in place of pages
     # or a ring. "mamba2" is a Mamba-2 mixer (``Mamba2``): ``ssm_heads`` heads
@@ -131,9 +131,16 @@ class TransformerConfig:
     # heads of ``kda_head_dim`` key and value lanes, a matrix state [head
     # dim, head dim] a head, ``kda_conv`` taps on each of q, k and v; in
     # serving the state and the three convolutions' last ``kda_conv - 1``
-    # inputs a slot. "latent" is latent attention (``kv_latent_rank``) as ONE
-    # kind among others: one row a position in each such layer, rotated only
-    # where ``rope_kinds`` names it.
+    # inputs a slot. "retention" is a power-retention mixer (``Retention``:
+    # gated power attention of degree ``retention_degree``): ``n_heads`` query
+    # heads over ``n_kv_heads`` states of ``head_dim`` key and value lanes,
+    # each state the symmetric power of its keys against their values, float32,
+    # with its normaliser; q and k take the per-head norm (``qk_head_norm``)
+    # and, where ``rope_kinds`` names the kind, the rotation, as an attention
+    # layer's do; in serving the state a slot, and nothing by position: a
+    # model of such layers alone has no page. "latent" is latent attention
+    # (``kv_latent_rank``) as ONE kind among others: one row a position in
+    # each such layer, rotated only where ``rope_kinds`` names it.
     layer_kinds: Tuple[str, ...] = ()
     block: str = "sambay"
     window: int = 0
@@ -153,6 +160,17 @@ class TransformerConfig:
     kda_conv: int = 4
     kda_gate_rank: int = 0
     kda_init_std: float = 0.0
+    # the degree of a "retention" layer's power attention (0: the model has
+    # none; 2: the symmetric square, the only one implemented), the mean and
+    # the deviation its gate's bias is drawn at and the deviation of the
+    # gate's matrix (0 = the attention's; a sigmoid gate centred on 0 forgets
+    # half a state every position: seeded weights that are to show a state's
+    # carry state a bias, and a matrix that does not drown it) and the
+    # deviation its two head norms' scales are drawn at around 1 (0: ones; the
+    # norms and the rotation commute while a scale is uniform)
+    retention_degree: int = 0
+    retention_gate_init: Tuple[float, float, float] = (0.0, 0.0, 0.0)
+    retention_norm_init_std: float = 0.0
     # taps a channel of a "conv" layer's causal depthwise convolution (0: the
     # model has no such layer), and what its in_proj and out_proj are drawn
     # at (0 = the attention's): its output goes with their FOURTH power, the
@@ -321,7 +339,13 @@ class TransformerConfig:
         kda = (4 * d * wide + 3 * wide * self.kda_conv
                + 2 * (d * r + r * wide) + wide + self.kda_heads
                + d * self.kda_heads + self.kda_head_dim + 2 * d)
-        mixer = {"conv": conv, "mamba2": mamba2, "kda": kda, "latent": latent}
+        # q, o, k, v, the gate's projection with its bias (one gate a state),
+        # the two head norms and the block's two norms
+        kv = self.n_kv_heads * self.head_dim
+        retention = (2 * q + 2 * d * kv + (d + 1) * self.n_kv_heads
+                     + 2 * self.head_dim + 2 * d)
+        mixer = {"conv": conv, "mamba2": mamba2, "kda": kda, "latent": latent,
+                 "retention": retention}
         dense_mlp = 3 * d * (self.d_ff_dense or f)
         outputs = self.n_experts + self.zero_experts
         moe_mlp = (self.n_experts_held * 3 * d * f + d * outputs
@@ -378,11 +402,14 @@ class RMSNorm(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     axis: Optional[str] = "embed"  # logical axis of the scale
+    init_std: float = 0.0  # the scale is drawn around 1 at this (0: ones)
 
     @nn.compact
     def __call__(self, x):
+        init = nn.initializers.ones if not self.init_std else (
+            lambda *a: 1.0 + nn.initializers.normal(self.init_std)(*a))
         scale = self.param(
-            "scale", nn.with_logical_partitioning(nn.initializers.ones, (self.axis,)),
+            "scale", nn.with_logical_partitioning(init, (self.axis,)),
             (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
@@ -621,6 +648,57 @@ class KDA(nn.Module):
             * nn.sigmoid(gate.reshape(o.shape))
         return dense(cfg.d_model, ("mlp", "embed"), "o_proj")(
             o.reshape(*o.shape[:-2], H * K))
+
+
+class Retention(nn.Module):
+    """A "retention" layer's mixer, gated power attention of degree 2 (power
+    retention): ``q, k, v = q_proj(h), k_proj(h), v_proj(h)`` at ``n_heads``,
+    ``n_kv_heads`` and ``n_kv_heads`` heads of ``head_dim``, no bias; q and k
+    under their per-head RMSNorm (``qk_head_norm``) and then the rotation
+    (where ``rope_kinds`` names "retention"), as an attention layer's; ``log
+    g = log sigmoid(g_proj(h))`` in float32, one gate a key/value head (kernel
+    and bias); the recurrence of ``ops/retention.py``, query head ``h`` reading
+    the state of ``h // (n_heads / n_kv_heads)``; ``o_proj``. The TRAINING
+    side: the whole sequence at once through ``retention_reference`` (packed
+    sequences are not kept apart); the serving engine keeps the state and its
+    normaliser a slot (``llm/model_runner.py``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h, positions):
+        from ray_tpu.ops.retention import retention_reference
+
+        cfg, hd = self.cfg, self.cfg.head_dim
+        dense = lambda feats, axes, name, axis=-1: nn.DenseGeneral(  # noqa: E731
+            features=feats, axis=axis, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.normal(cfg.init_std("attn")), axes))
+        q = dense((cfg.n_heads, hd), ("embed", "heads", "head_dim"), "q_proj")(h)
+        k = dense((cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim"),
+                  "k_proj")(h)
+        v = dense((cfg.n_kv_heads, hd), ("embed", "kv_heads", "head_dim"),
+                  "v_proj")(h)
+        if cfg.qk_head_norm:  # head by head, one scale for all of them
+            norm = lambda name: RMSNorm(   # noqa: E731
+                cfg.norm_eps, cfg.dtype, axis=None, name=name,
+                init_std=cfg.retention_norm_init_std)
+            q, k = norm("q_norm")(q), norm("k_norm")(k)
+        if "retention" in cfg.rope_kinds:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        mean, std, matrix = cfg.retention_gate_init
+        gamma = nn.DenseGeneral(
+            features=cfg.n_kv_heads, dtype=jnp.float32,
+            param_dtype=cfg.param_dtype, name="g_proj",
+            kernel_init=nn.with_logical_partitioning(nn.initializers.normal(
+                matrix or cfg.init_std("attn")), ("embed", None)),
+            bias_init=lambda *a: mean + nn.initializers.normal(std)(*a))(h)
+        o, _, _ = retention_reference(q, k, v, jax.nn.log_sigmoid(gamma),
+                                      cfg.retention_degree)
+        return dense(cfg.d_model, ("heads", "head_dim", "embed"), "o_proj",
+                     axis=(-2, -1))(o.astype(cfg.dtype))
 
 
 def kda_qk_norm(q, k):
@@ -1010,6 +1088,9 @@ class Block(nn.Module):
             a = Mamba2(cfg, name="mamba")(norm("attn_norm")(x))
         elif self.kind == "kda":
             a = KDA(cfg, name="kda")(norm("attn_norm")(x))
+        elif self.kind == "retention":
+            a = Retention(cfg, name="retention")(norm("attn_norm")(x),
+                                                 positions)
         else:
             a = Attention(cfg, self.kind, name="attn")(
                 norm("attn_norm")(x), positions, segment_ids)

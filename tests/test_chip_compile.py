@@ -1148,3 +1148,56 @@ def test_longcat_prefill_holds_no_buffer_of_every_assignment(one_chip, rows,
     print(f"longcat prefill [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")
     assert 0 < live < int(15.5 * 2 ** 30)
+
+
+# -- one stage of Brumby-14B-Base (brumby, the benchmark's file) --------------------------
+
+
+def test_brumby_decode_steps_every_state_in_place(one_chip):
+    """Decode at 32 slots: ``retention_step`` once in each of the six layers
+    over the whole [6, 32 + 1, 8, 66, 128, 128] float32 leaf (6.85 GB), which is
+    written IN PLACE: one copy of it among the live bytes, and nothing a
+    state's size among the temporaries. The cache holds that leaf and no page;
+    the block tables are arguments that address nothing."""
+    cache, _, decode = _lower_rms_kinds(one_chip, "brumby-14b-base")
+    compiled = decode().compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(
+        r"%(retention_step\S*) = \(f32\[32,8,128,8\]", text))) == 6
+    assert text.count("tpu_custom_call") == 6
+    assert cache.ssm.shape == (6, 32 + 1, 8, 66, 128, 128)
+    assert cache.ssm.dtype == jnp.float32
+    assert all(leaf is None for leaf in (
+        cache.pages, cache.rows, cache.k, cache.v, cache.rings, cache.conv))
+    live, temp = _live(compiled)
+    held = cache.ssm.size * cache.ssm.dtype.itemsize
+    print(f"brumby decode, 32 slots: {live} bytes live, {temp} of "
+          f"temporaries; state {held}")
+    assert temp < 128 << 20
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
+@pytest.mark.parametrize("rows,bucket", [(1, 512), (32, 256), (1, 8192)])
+def test_brumby_prefill_writes_no_row_of_features(one_chip, rows, bucket):
+    """The largest call that carries the 32 slots' decode step, ``[1, 512]``
+    (``retention_riding`` in six layers), the benchmark check's every-slot
+    ``[32, 256]`` call and the largest bucket, ``[1, 8192]``, beside 7.08 GB
+    of weights and 6.64 GB of state: the chunked recurrence in six layers,
+    whose features live in the kernel's fast memory alone: NO array of a
+    prompt's positions by the symmetric square's width (8,256 exact, 8,320
+    by rotation, or a tiled 8,704 / 9,216) is anybody's result; under the
+    chip's 15.75 GiB. What an execution holds live is printed (``-s``) and
+    stands in PERF.md section 4."""
+    _, prefill, _ = _lower_rms_kinds(one_chip, "brumby-14b-base")
+    compiled = prefill(rows, bucket).compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(
+        rf"%(retention_scan\S*) = \(bf16\[{rows},{bucket},5120\]", text))) == 6
+    riding = len(set(re.findall(r"%(retention_riding\S*) = ", text)))
+    assert riding == (6 if (rows, bucket) == (1, 512) else 0)
+    assert text.count("tpu_custom_call") == 6 + riding
+    assert not re.search(r"\[[\d,]*(?:8256|8320|8704|9216)[,\]]", text)
+    live, temp = _live(compiled)
+    print(f"brumby prefill [{rows}, {bucket}]: {live} bytes live, "
+          f"{temp} of temporaries")
+    assert 0 < live < int(15.5 * 2 ** 30)

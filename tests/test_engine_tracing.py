@@ -67,6 +67,9 @@ KEYS = {
     # kernel's grid has and passes over; 0 without such layers
     "kda_step_slots", "kda_step_live_slots",
     "kda_scan_chunks", "kda_scan_chunks_skipped",
+    # the same four for power-retention layers; 0 without such layers
+    "retention_state_slots", "retention_live_slots",
+    "retention_scan_chunks", "retention_scan_chunks_skipped",
     # the query blocks a prefill call's flash kernel has a head, and those
     # behind their row's end that it passes over
     "flash_q_blocks", "flash_q_blocks_skipped"}
@@ -166,6 +169,10 @@ def test_metrics_complete_numeric_monotone(engine):
     assert m["sample_greedy_calls"] == m["sample_calls"]
     # no delta-rule layer: nothing for a kda_scan to walk or pass over
     assert m["kda_scan_chunks"] == m["kda_scan_chunks_skipped"] == 0
+    # no power-retention layer: no state of one for a step to move or a
+    # scan to build
+    assert m["retention_state_slots"] == m["retention_live_slots"] == 0
+    assert m["retention_scan_chunks"] == m["retention_scan_chunks_skipped"] == 0
     # a prompt here fills its row's one query block, a padding row none
     assert (m["flash_q_blocks"] - m["flash_q_blocks_skipped"]
             == m["admitted"] > 0)
