@@ -223,9 +223,16 @@ from ``__init__``, so two snapshots subtract):
   over them). Its latent layers count under ``mla_decode_*`` as a latent
   model's do. For a model with power-retention layers (``"retention"``), per
   decode step (riding ones too) and such layer: ``retention_state_slots``
-  (the slots whose state the step reads and writes: all of them,
-  ``ops/retention.py:retention_step`` walks every slot) and
-  ``retention_live_slots`` (those of them that decode) and, per prefill
+  (the slots whose state the step reads: all of them, both passes of
+  ``ops/retention.py`` walk every slot) and
+  ``retention_live_slots`` (those of them that decode); per decode step
+  (riding ones too) ``retention_steps`` (``decode_steps`` again, 0 for a
+  model without such layers) and ``retention_fold_steps``, those of them
+  that also WROTE every slot's state back (``retention_step``: the step
+  that found ``FOLD - 1`` positions pending, and every riding step; the
+  others ran ``retention_read``), counted by the program's own rule on the
+  host's mirror of ``Cache.pending_count``: a quarter of the steps where
+  few ride; and, per prefill
   call, ``retention_scan_chunks`` / ``retention_scan_chunks_skipped``
   (``ops/retention.py:scan_chunks``: the chunks ``retention_scan``'s grid has
   a head and such layer, and those wholly behind their row's length). A
@@ -485,6 +492,9 @@ class JaxLLMEngine:
         self._ssd_layers = kinds.count("mamba2")
         self._kda_layers = kinds.count("kda")
         self._retention_layers = kinds.count("retention")
+        # the host's copy of Cache.pending_count (power retention: the decode
+        # steps since the states were last written back)
+        self._pending_positions = 0
         state_layers = (kinds.count("mamba") + kinds.count("conv")
                         + self._ssd_layers + self._kda_layers
                         + self._retention_layers)
@@ -619,6 +629,7 @@ class JaxLLMEngine:
             "kda_step_slots": 0, "kda_step_live_slots": 0,
             "kda_scan_chunks": 0, "kda_scan_chunks_skipped": 0,
             "retention_state_slots": 0, "retention_live_slots": 0,
+            "retention_steps": 0, "retention_fold_steps": 0,
             "retention_scan_chunks": 0, "retention_scan_chunks_skipped": 0,
             "flash_q_blocks": 0, "flash_q_blocks_skipped": 0}
         # span attribute of decode_dispatch; none for a dense model
@@ -967,7 +978,7 @@ class JaxLLMEngine:
                 # every row a slot will read is new: a decode step's, or an
                 # admitted request's first
                 self._tokens = firsts
-                self._count_decode_reads()
+                self._count_decode_reads(riding=True)
                 self._count_slot_steps(prefilling=len(admitted))
                 m["decode_steps"] += 1
                 m["riding_steps"] += 1
@@ -1122,6 +1133,8 @@ class JaxLLMEngine:
                 logits, cache = prefill(self.cache, *rows[:-1])
             self.cache, buffer = cache, self._prefill_logits
             if carries:
+                # its step side folded, whether or not a slot rode it
+                self._pending_positions = 0
                 logits, step_logits = logits
                 if carry:
                     buffer = step_logits
@@ -1136,9 +1149,9 @@ class JaxLLMEngine:
                 self._up(self._block_tables, "tables"),
                 self._up(active, "active" if active.any() else "idle"))
 
-    def _count_decode_reads(self) -> None:
-        """What the decode step being dispatched (alone, or riding a prefill
-        call) attends over, into the counters of the model's kind."""
+    def _count_decode_reads(self, riding: bool = False) -> None:
+        """What the decode step being dispatched (alone, or ``riding`` a
+        prefill call) attends over, into the counters of the model's kind."""
         if self.mcfg.kv_latent_rank:
             self._count_paged_reads("mla_decode")
         elif self.mcfg.layer_kinds and self._page_leaves():
@@ -1155,6 +1168,15 @@ class JaxLLMEngine:
             self._retention_layers * len(self._slots))
         self.metrics["retention_live_slots"] += (
             self._retention_layers * int(self._active.sum()))
+        if self._retention_layers:
+            # the program's rule (ops/retention.py:retention_decode, advance)
+            # on the host's copy of the count it keeps in the cache
+            from ray_tpu.ops.retention import FOLD
+
+            fold = riding or self._pending_positions >= FOLD - 1
+            self.metrics["retention_steps"] += 1
+            self.metrics["retention_fold_steps"] += fold
+            self._pending_positions = 0 if fold else self._pending_positions + 1
 
     def _count_slot_steps(self, prefilling: int = 0) -> None:
         """Every slot's row of the decode step being dispatched (alone, or

@@ -118,10 +118,16 @@ the step. The kinds, and where each keeps what:
   Prefill runs the chunked recurrence over the bucket (``retention_scan``
   through ``ops/retention.py:retention_prefill``, padding passed over from
   ``lengths`` on, by the whole chunk where a chunk holds nothing else) and
-  WRITES the slot's state from the prompt alone, which is how a slot is reset
+  WRITES the slot's state from the prompt alone and empties its pending
+  positions, which is how a slot is reset
   at admission, reused, or given back to a preempted request; a decode step
-  steps every slot's state once, in place (``retention_step``; beside a prompt
-  ``retention_riding`` with ``keep``); ``o_proj`` runs once over all rows.
+  READS every slot's state once and keeps its position beside it, in
+  ``pending`` (``retention_read``: half the bytes of a step that writes),
+  and every ``FOLD``-th step, counted on the device in ``pending_count``,
+  folds the pending positions and its own into the state, in place
+  (``retention_step``; beside a prompt always, as ``retention_riding`` with
+  ``keep``): the same function, re-associated; ``o_proj`` runs once over all
+  rows.
 
 ``prefill`` is told the slot a row fills (``slots``), overwrites the slot's
 rings and rows from the prompt alone (which is how a slot is reset at
@@ -221,7 +227,15 @@ class Cache(NamedTuple):
     key/value head's state with its normaliser, float32, [key/value heads,
     head_dim / 2 + 2, head_dim, head_dim] (``ops/retention.py``'s layout: the
     normaliser is the last of those slabs, one leaf, so that one alias moves
-    both in place and every tile is whole); a model of NO paged kind (such
+    both in place and every tile is whole), which is the state as of the
+    slot's last WRITE-BACK (``ops/retention.py``: a decode step reads it
+    every position and writes it every ``FOLD``-th); beside it ``pending``,
+    per such layer the ``FOLD - 1`` positions since (each one's key, value
+    and log-gate, float32, [FOLD - 1, 3, slots, key/value heads, head_dim]; a
+    null one, ``k = 0`` and ``log g = 0``, where a slot has fewer), and
+    ``pending_count``, ONE int32 for all slots and layers: how many decode
+    steps' positions lie there, which is what decides on the device whether
+    a step reads or folds; a model of NO paged kind (such
     layers alone) holds no ``pages``, ``rows``, ``k`` or ``v`` at all, not
     even an empty one: its block tables address nothing and ``_page_size`` is
     0. ``moe_load``: for a model with
@@ -241,6 +255,9 @@ class Cache(NamedTuple):
     # conv_taps - 1, B, d_model] or [kda layers, kda_conv - 1, B, 3 H K]
     conv: Optional[jax.Array] = None
     moe_load: Optional[jax.Array] = None  # [expert layers, E] int32
+    # [retention layers, FOLD - 1, 3, B, KVH, hd] float32, and an int32
+    pending: Optional[jax.Array] = None
+    pending_count: Optional[jax.Array] = None
 
 
 # the leaves that block tables address: what a request's pages are gathered
@@ -285,7 +302,7 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
         latent, kda = kinds.count("latent"), kinds.count("kda")
         mamba = kinds.count("mamba") + kinds.count("mamba2")
         retention = kinds.count("retention")
-        rows = state = None
+        rows = state = pending = None
         if mamba:  # a "mamba2" layer convolves x | B | C, a "mamba" layer x
             rows = (mamba, cfg.ssm_conv - 1, max_num_seqs, cfg.ssm_inner
                     + ("mamba2" in kinds) * 2 * cfg.ssm_state)
@@ -298,12 +315,14 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
             rows = (kda, cfg.kda_conv - 1, max_num_seqs, 3 * H * K)
             state = (kda, max_num_seqs, H, K, K)
         elif retention:
-            from ray_tpu.ops.retention import check_degree, state_shape
+            from ray_tpu.ops.retention import FOLD, check_degree, state_shape
 
             check_degree(cfg.retention_degree)
             # a slot past the last: where a padding row's state lands
             state = (retention, max_num_seqs + 1, cfg.n_kv_heads,
                      *state_shape(cfg.head_dim))
+            pending = (retention, FOLD - 1, 3, max_num_seqs, cfg.n_kv_heads,
+                       cfg.head_dim)
         return Cache(
             rows=jnp.zeros((latent, num_pages, page_size, _latent_width(cfg)),
                            cfg.dtype) if latent else None,
@@ -312,7 +331,9 @@ def init_cache(cfg: TransformerConfig, num_pages: int, page_size: int,
             rings=jnp.zeros((window, max_num_seqs, cfg.window, row), cfg.dtype)
             if window else None,
             ssm=jnp.zeros(state, jnp.float32) if state else None,
-            conv=jnp.zeros(rows, cfg.dtype) if rows else None, moe_load=load)
+            conv=jnp.zeros(rows, cfg.dtype) if rows else None, moe_load=load,
+            pending=jnp.zeros(pending, jnp.float32) if pending else None,
+            pending_count=jnp.zeros((), jnp.int32) if pending else None)
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     if cfg.kv_latent_rank:
         return Cache(rows=jnp.zeros(
@@ -1137,38 +1158,49 @@ def _retention_inputs(x, lp, cfg, positions):
     return log_g, [q, k, v]
 
 
-def _retention_prefill(qkv, log_g, cfg, ssm, layer, slots, lengths):
+def _retention_prefill(qkv, log_g, cfg, kept, layer, slots, lengths):
     """A "retention" layer's recurrence over a prefill call's rows (q [R, S,
     H, hd], k, v [R, S, KVH, hd], log_g [R, S, KVH]) from a zero state, and
-    ``ssm`` with the states of ``slots`` left at the prompts' last position
+    ``kept`` (the state, the pending positions, their count) with the states
+    of ``slots`` left at the prompts' last position
     (the kernel writes each row's state into its slot of the leaf itself; a
-    padding row's slot is the one past the last, which the leaf has for it).
+    padding row's slot is the one past the last, which the leaf has for it)
+    and their pending positions EMPTIED: the slot's last tenant's are not
+    this request's, and a null position adds nothing whenever it is folded.
     The kernel does nothing for a chunk that lies wholly behind ``lengths``
     and forces no decay and no key from ``lengths`` on inside the chunk that
     holds the end; ``o`` behind a prompt's end is zeros, which go on through
     ``o_proj`` and the MLP like any row."""
     from ray_tpu.ops.retention import retention_prefill
 
+    ssm, pending, count = kept
     q, k, v = (t.reshape(*t.shape[:2], -1) for t in qkv)
     with jax.named_scope("retention.scan"):
         o, ssm = retention_prefill(q, k, v, log_g, lengths, ssm, layer, slots,
                                    heads=(cfg.n_heads, cfg.n_kv_heads))
-    return o.reshape(qkv[0].shape), ssm
+        # [layer, :, :, slot]: a padding row's slot is past the last, dropped
+        pending = pending.at[layer, :, :, slots].set(0.0, mode="drop")
+    return o.reshape(qkv[0].shape), (ssm, pending, count)
 
 
-def _retention_step(qkv, log_g, cfg, ssm, layer, keep, op):
+def _retention_step(qkv, log_g, cfg, kept, layer, keep, op):
     """A "retention" layer's recurrence over a decode step's rows (q [B, 1,
-    H, hd], k, v [B, 1, KVH, hd], log_g [B, 1, KVH]): one step of every
-    slot's state in place (``ops/retention.py:retention_step``); with
-    ``keep`` [B] only the slots it marks move."""
-    from ray_tpu.ops.retention import retention_step
+    H, hd], k, v [B, 1, KVH, hd], log_g [B, 1, KVH]), as the count of pending
+    positions says (``ops/retention.py:retention_decode``): every slot's
+    state read and the position kept beside it (``retention_read``), or, every
+    ``FOLD``-th step and whenever a prefill call carries the step, the
+    pending positions and this one folded into the state in place
+    (``retention_step``, ``retention_riding``). A slot ``keep`` [B] does not
+    mark takes a null position."""
+    from ray_tpu.ops.retention import retention_decode
 
+    ssm, pending, count = kept
     q, k, v = (t[:, 0] for t in qkv)
     with jax.named_scope("retention.step"):
-        o, ssm = retention_step(
-            ssm, layer, q, k, v, log_g[:, 0], keep,
-            name="retention_step" if op == "decode" else "retention_" + op)
-    return o.astype(cfg.dtype)[:, None], ssm
+        o, ssm, pending = retention_decode(
+            ssm, pending, count, layer, q, k, v, log_g[:, 0], keep,
+            riding=op == "riding")
+    return o.astype(cfg.dtype)[:, None], (ssm, pending, count)
 
 
 def _paired_rest(x, o, lp, cfg, valid, name, carried):
@@ -1345,7 +1377,7 @@ def _forward(p, cfg, cache, prompt=None, step=None):
     kept = {"dense": (cache.k, cache.v), "latent": cache.rows,
             "full": cache.pages, "window": cache.rings, "conv": cache.conv,
             "mamba2": (cache.ssm, cache.conv), "kda": (cache.ssm, cache.conv),
-            "retention": cache.ssm}
+            "retention": (cache.ssm, cache.pending, cache.pending_count)}
     xs, positions, valid, mixers = [], [], [], []
     if prompt is not None:
         tokens, lengths, tables, slots = prompt
@@ -1363,7 +1395,9 @@ def _forward(p, cfg, cache, prompt=None, step=None):
         _, at, *_, at_tables = index
         positions.append(at_tables if plain else at[:, None])
         valid.append(active if plain else active[:, None])
-        keep = None
+        # a "retention" layer's slot that does not decode appends a null
+        # position, so that a fold finds nothing of it to take
+        keep = active if "retention" in kinds else None
         if prompt is not None:
             keep = active
             index = (jnp.where(active, index[0], active.shape[0]), *index[1:])
@@ -1421,11 +1455,18 @@ def _forward(p, cfg, cache, prompt=None, step=None):
         logits = logits[:R], logits[R:]
     k, v = kept["dense"]
     state = next((kind for kind in ("mamba2", "kda") if kind in kinds), "")
-    ssm, conv = kept[state] if state else (kept["retention"], kept["conv"])
+    ssm, pending, count = kept["retention"]
+    ssm, conv = kept[state] if state else (ssm, kept["conv"])
+    if count is not None and step is not None:
+        # a step that rode a prefill call folded, whatever it found
+        from ray_tpu.ops.retention import advance
+
+        count = advance(count) if prompt is None else jnp.zeros_like(count)
     return logits, Cache(
         k=k, v=v, rows=kept["latent"], pages=kept["full"],
         rings=kept["window"], ssm=ssm, conv=conv,
-        moe_load=jnp.stack(loads) if loads else None)
+        moe_load=jnp.stack(loads) if loads else None, pending=pending,
+        pending_count=count)
 
 
 def _end_to_end(sides):

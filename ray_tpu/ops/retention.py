@@ -74,18 +74,51 @@ call of 32 rows holds no 1.1 GB array of their states. A padding row's lands
 in the slot past the engine's last, which the leaf has for that and nothing
 reads (34.6 MB a layer).
 
-``retention_step`` (Pallas, name ``retention_step``; ``retention_riding``
-where a prefill call carries the step) is one decode step of one layer for
-every slot: the grid is (slot, key/value head), a head's ``[n / 2 + 2, n,
-n]`` state is read once, updated and written once IN PLACE
-(``input_output_aliases`` on the whole ``[layers, B + 1, KVH, n / 2 + 2, n,
-n]`` leaf: the other layers' bytes are never touched and nothing is copied),
-and
-the query heads' ``phi(s q)^T S`` come out of the same pass, accumulated
-slab by slab on the vector unit and reduced once at the end. A slot with ``g
-= 1`` and ``k = 0`` keeps its state to the bit: that is how ``keep`` leaves
-the slots a prompt has just written untouched. It is bound by bytes: 2 x 34.6
-MB a slot.
+**A decode step reads a slot's state every position and writes it back
+every fourth** (``FOLD``). Between two write-backs a slot holds, beside its
+state as of the last one ``S_f``, the ``FOLD - 1`` positions since (their
+``k``, ``v`` and log-gates, float32, 36 KB a slot and layer at 8 heads; a
+null position ``k = 0``, ``log g = 0`` where it has fewer: one that adds
+nothing and decays nothing, whenever it is taken). It is the same function,
+re-associated: with ``Gamma`` the decay since the write-back and ``gamma_j``
+the decay from pending position ``j`` to now,
+
+    phi(s q)^T S_t = Gamma phi(s q)^T S_f + sum_j gamma_j (s q . k_j)^2 v_j
+    S_t            = Gamma S_f + sum_j gamma_j phi(k_j) v_j^T
+
+over the pending positions and the step's own (the normaliser likewise from
+``M_f``), float32 throughout, no position dropped and nothing rounded that
+was not. ``retention_decode`` is a layer's step: ONE count of the pending
+positions for all slots (``Cache.pending_count``, advanced by the program,
+``advance``) decides on the device (``lax.cond``) which of two passes runs:
+
+- ``retention_read`` (Pallas, name ``retention_read``), three steps of four:
+  the grid is (slot, key/value head), a head's ``[n / 2 + 2, n, n]`` state is
+  read ONCE and not written; the query heads' ``phi(s q)^T S_f`` and ``(s
+  q)^T M_f (s q)`` come out of the pass, accumulated slab by slab on the
+  vector unit; the pending positions' and the step's own terms are a few
+  hundred thousand multiply-adds around it (elementwise, so that they stay
+  float32), then the quotient. The step's ``k``, ``v``, ``log g`` join the
+  pending rows. Bound by bytes: 34.6 MB a slot, once;
+- ``retention_step`` (Pallas, name ``retention_step``; ``retention_riding``
+  where a prefill call carries the step, which ALWAYS folds), THE FOLD, every
+  fourth step: the state is read, takes the pending positions and the
+  step's own, and is written IN PLACE (``input_output_aliases`` on the whole
+  ``[layers, B + 1, KVH, n / 2 + 2, n, n]`` leaf: the other layers' bytes are
+  never touched and nothing is copied), the queries read from the same pass;
+  the caller empties the pending rows. With nothing pending it is one
+  position of the recurrence. A slot with ``g = 1`` and ``k = 0`` and
+  nothing pending keeps its state to the bit: that is how ``keep`` leaves the
+  slots a prompt has just written untouched. Bound by bytes: 2 x 34.6 MB a
+  slot.
+
+The read pass takes the leaf aliased too and writes one tile of the slot
+past the last (``retention_prefill``'s scratch, which nobody reads): both
+branches of the ``cond`` then hand back the leaf they were given, and the
+compiler has no reason to copy 6.6 GB. ``retention_prefill`` empties the
+pending rows of the slots it fills (``llm/model_runner.py``): a slot
+admitted between two folds has fewer positions pending than its neighbours,
+and its last tenant's are not its own.
 
 Off the TPU both kernels run in interpret mode.
 """
@@ -102,8 +135,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 # positions a grid step of retention_scan takes
 CHUNK = 512
+# a slot's state is written back every FOLD-th decode step: between two
+# write-backs it keeps the FOLD - 1 positions since (their k, v, log g)
+FOLD = 4
 # fast memory a kernel may use: a head's state twice (a block in, a block
-# out, each double-buffered in the step kernel) and a chunk's temporaries
+# out, each double-buffered in the fold's kernel) and a chunk's temporaries
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 _NN = (((1,), (0,)), ((), ()))
@@ -417,40 +453,59 @@ def retention_scan(q, k, v, log_g, lengths=None, *, chunk: int = CHUNK):
     return o.reshape(R, S, H, n), ssm[0, :R]
 
 
-# -- one decode step of one layer, every slot, in place --------------------------
+# -- one decode step of one layer, every slot: the read pass and the fold --------
 
 
-def _step_kernel(layer_ref, s_ref, rows_ref, cols_ref, o_ref, out_ref, *, rep):
-    """One slot's head: ``rows`` [.., n] holds the ``rep`` scaled queries, the
-    key and the gate as rows (features along the lanes), ``cols`` [n, ..] the
-    queries, the key and the value as columns."""
+def _pass_kernel(layer_ref, s_ref, rows_ref, cols_ref, o_ref, out_ref, *, rep,
+                 fold):
+    """One slot's head, the state read once. ``rows`` [.., n] holds the
+    ``rep`` scaled queries as rows (features along the lanes), ``cols`` [n,
+    ..] the same as columns; ``o`` takes each query's ``phi(s q)^T S`` in
+    lane ``j`` and, in lane ``rep + j``, the terms of ``(s q)^T M (s q)`` by
+    sublane (the caller sums them). Where the pass ``fold``s, ``rows`` also
+    holds the ``FOLD`` positions' keys and the decay since the last fold as
+    rows, ``cols`` each position's value and key as columns, times its decay
+    from there to now: the state takes them, is written back to ``out_ref``,
+    and the queries read what is written. Where it does not, ``out_ref`` is
+    one tile of the leaf's scratch slot, which nobody reads."""
     del layer_ref
     slabs, n = s_ref.shape[0] - 1, s_ref.shape[-1]
     rows, cols = rows_ref[...], cols_ref[...]
-    gate = rows[rep + 1:rep + 2]                                     # [1, n]
-    value = cols[:, rep + 1:rep + 2]                                 # [n, 1]
+    keys = range(rep, rep + FOLD)
+    if fold:
+        gate = rows[rep + FOLD:rep + FOLD + 1]                       # [1, n]
+    else:
+        out_ref[...] = jnp.zeros_like(out_ref)
     acc = [jnp.zeros((n, n), jnp.float32)] * rep
     for r in range(slabs):
         # every row times itself turned by r lanes: the queries' and the
-        # key's features of this slab
+        # keys' features of this slab
         feature = rows * (pltpu.roll(rows, r, 1) if r else rows)
-        new = gate * s_ref[r] + value * feature[rep:rep + 1]
-        out_ref[r] = new
+        slab = s_ref[r]
+        if fold:
+            slab = gate * slab
+            for p in keys:
+                slab = slab + cols[:, p:p + 1] * feature[p:p + 1]
+            out_ref[r] = slab
         for j in range(rep):
-            acc[j] = acc[j] + new * (feature[j:j + 1] * _slab_weight(r, n))
-    norm = gate * s_ref[slabs] + cols[:, rep:rep + 1] * rows[rep:rep + 1]
-    out_ref[slabs] = norm
-    lane = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 1)
-    o = jnp.zeros(cols.shape, jnp.float32)
+            acc[j] = acc[j] + slab * (feature[j:j + 1] * _slab_weight(r, n))
+    norm = s_ref[slabs]
+    if fold:
+        norm = gate * norm
+        for p in keys:
+            norm = norm + cols[:, FOLD + p:FOLD + p + 1] * rows[p:p + 1]
+        out_ref[slabs] = norm
+    lane = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+    o = jnp.zeros(o_ref.shape, jnp.float32)
     for j in range(rep):
         num = jnp.sum(acc[j], axis=1, keepdims=True)                 # [n, 1]
-        den = jnp.sum(jnp.sum(norm * rows[j:j + 1], axis=1, keepdims=True)
-                      * cols[:, j:j + 1], axis=0, keepdims=True)     # [1, 1]
-        o = jnp.where(lane == j, num / den, o)
+        den = jnp.sum(norm * rows[j:j + 1], axis=1, keepdims=True) \
+            * cols[:, j:j + 1]                                       # [n, 1]
+        o = jnp.where(lane == j, num, jnp.where(lane == rep + j, den, o))
     o_ref[...] = o
 
 
-def _retention_step(layer, ssm, rows, cols, *, rep, name, interpret):
+def _state_pass(layer, ssm, rows, cols, *, rep, fold, name, interpret):
     (B, KVH), state = rows.shape[:2], ssm.shape[3:]
     n = state[-1]
     state_spec = pl.BlockSpec(
@@ -460,13 +515,21 @@ def _retention_step(layer, ssm, rows, cols, *, rep, name, interpret):
                             lambda s, h, _: (s, h, 0, 0))
     col_spec = pl.BlockSpec((None, None, n, cols.shape[3]),
                             lambda s, h, _: (s, h, 0, 0))
+    width = -(-2 * rep // 8) * 8
+    out = pl.BlockSpec((None, None, n, width), lambda s, h, _: (s, h, 0, 0))
+    # the read pass hands the leaf back as the fold does, aliased, so that
+    # either branch of the caller's ``cond`` owns it and neither copies it;
+    # what it writes is one tile of the slot past the last
+    parked = pl.BlockSpec(
+        (None, None, None, None, 8, n),
+        lambda s, h, layer: (layer[0], ssm.shape[1] - 1, 0, 0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_step_kernel, rep=rep),
+        functools.partial(_pass_kernel, rep=rep, fold=fold),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, KVH),
             in_specs=[state_spec, row_spec, col_spec],
-            out_specs=[col_spec, state_spec]),
-        out_shape=[jax.ShapeDtypeStruct(cols.shape, jnp.float32),
+            out_specs=[out, state_spec if fold else parked]),
+        out_shape=[jax.ShapeDtypeStruct((B, KVH, n, width), jnp.float32),
                    jax.ShapeDtypeStruct(ssm.shape, ssm.dtype)],
         input_output_aliases={1: 1},
         compiler_params=pltpu.CompilerParams(
@@ -477,38 +540,143 @@ def _retention_step(layer, ssm, rows, cols, *, rep, name, interpret):
     )(layer, ssm, rows, cols)
 
 
-@functools.partial(jax.jit, static_argnames=("name",))
-def retention_step(ssm, layer, q, k, v, log_g, keep=None, *,
-                   name: str = "retention_step"):
-    """One position of ``retention_reference`` for every slot, on layer
-    ``layer`` (traced) of ``ssm`` [layers, B + 1, KVH, n / 2 + 2, n, n]
-    float32 (the slot past the last is ``retention_prefill``'s scratch: no
-    step touches it), which the caller hands over donated: q [B, H, n], k, v [B, KVH, n], log_g
-    [B, KVH] (<= 0), keep [B] (a slot it does not mark keeps its state to the
-    bit; None: all step) -> (o [B, H, n] float32, ``ssm`` with the layer's
-    state stepped). Jitted: a program of several such layers traces and
-    lowers the kernel once."""
+def _stacked(*parts):
+    """``parts`` [B, KVH, .., n] one under the other, zero rows up to whole
+    tiles of 8."""
+    rows = jnp.concatenate(parts, axis=2)
+    return jnp.pad(rows, [(0, 0), (0, 0), (0, -rows.shape[2] % 8), (0, 0)])
+
+
+def _by_state(q, KVH):
+    """q [B, H, n] scaled, float32, by the state its head reads: [B, KVH, H /
+    KVH, n]."""
     B, H, n = q.shape
-    KVH = k.shape[1]
-    rep = H // KVH
+    return q.astype(jnp.float32).reshape(B, KVH, H // KVH, n) * n ** -0.5
+
+
+def _positions(k, v, log_g, keep, pending):
+    """The ``FOLD`` positions a pass takes beside the state, oldest first, the
+    step's own last: their keys and values [B, KVH, FOLD, n], the decay from
+    each to now [B, KVH, FOLD] (1 for the step's own) and the decay since the
+    last fold [B, KVH], float32, every factor a sum of log-gates and at most
+    one; with them the step's own position as a pending row [3, B, KVH, n].
+    A slot ``keep`` does not mark gets a null position (no key, no decay)."""
     f32 = lambda t: t.astype(jnp.float32)   # noqa: E731
-    g, k = jnp.exp(f32(log_g)), f32(k)
+    k, v, log_g = f32(k), f32(v), f32(log_g)
     if keep is not None:
-        g = jnp.where(keep[:, None], g, 1.0)
-        k = jnp.where(keep[:, None, None], k, 0.0)
-    q = f32(q).reshape(B, KVH, rep, n) * n ** -0.5
-    pad = jnp.zeros((B, KVH, -(rep + 2) % 8, n), jnp.float32)
+        k, v = (jnp.where(keep[:, None, None], t, 0.0) for t in (k, v))
+        log_g = jnp.where(keep[:, None], log_g, 0.0)
+    own = jnp.stack([k, v, jnp.broadcast_to(log_g[..., None], k.shape)])
+    if pending is None:
+        pending = jnp.zeros((FOLD - 1, *own.shape), jnp.float32)
+    held = jnp.moveaxis(jnp.concatenate([pending, own[None]]), 0, 3)
+    keys, values, gates = held[0], held[1], held[2, ..., 0]
+    after = [jnp.zeros_like(log_g)]      # the log-gates behind a position
+    for p in range(FOLD - 1, 0, -1):
+        after.insert(0, after[0] + gates[..., p])
+    return (keys, values, jnp.exp(jnp.stack(after, axis=-1)),
+            jnp.exp(after[0] + gates[..., 0]), own)
 
-    def stacked(last):   # the queries, the key and one more row, to whole tiles
-        return jnp.concatenate([q, k[:, :, None], last, pad], axis=2)
 
-    rows = stacked(jnp.broadcast_to(g[..., None, None], (B, KVH, 1, n)))
-    cols = jnp.swapaxes(stacked(f32(v)[:, :, None]), 2, 3)
+def _call(kernel, layer, ssm, rows, cols):
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    o, ssm = jax.lax.platform_dependent(
+    return jax.lax.platform_dependent(
         layer, ssm, rows, cols,
-        tpu=functools.partial(_retention_step, rep=rep, name=name,
-                              interpret=False),
-        default=functools.partial(_retention_step, rep=rep, name=name,
-                                  interpret=True))
-    return jnp.swapaxes(o[..., :rep], 2, 3).reshape(B, H, n), ssm
+        tpu=functools.partial(kernel, interpret=False),
+        default=functools.partial(kernel, interpret=True))
+
+
+def _read_out(o, rep):
+    """A pass's ``o`` [B, KVH, n, ..] -> each query's sum over the state [B,
+    KVH, rep, n] and its normaliser [B, KVH, rep]."""
+    return (jnp.swapaxes(o[..., :rep], 2, 3),
+            jnp.sum(o[..., rep:2 * rep], axis=2))
+
+
+@functools.partial(jax.jit, static_argnames=("name",))
+def retention_step(ssm, layer, q, k, v, log_g, keep=None, pending=None, *,
+                   name: str = "retention_step"):
+    """THE FOLD: the positions since a slot's last write-back (``pending``
+    [FOLD - 1, 3, B, KVH, n] float32: each one's key, value and log-gate, the
+    last along the lanes, a null one ``k = 0``, ``log g = 0``; None: nothing
+    pending, and this is one position of ``retention_reference``) and the
+    step's own position into every slot's state, on layer ``layer`` (traced)
+    of ``ssm`` [layers, B + 1, KVH, n / 2 + 2, n, n] float32 (the slot past
+    the last is ``retention_prefill``'s scratch: no step touches it), which
+    the caller hands over donated, and the queries' outputs read from the same
+    pass: q [B, H, n], k, v [B, KVH, n], log_g [B, KVH] (<= 0), keep [B] (a
+    slot it does not mark takes a null position: with nothing pending it
+    keeps its state to the bit; None: all step) -> (o [B, H, n] float32,
+    ``ssm`` with the layer's state as of this position). The caller empties
+    the pending rows. Jitted: a program of several such layers traces and
+    lowers the kernel once."""
+    (B, H, n), KVH = q.shape, k.shape[1]
+    rep, q = H // KVH, _by_state(q, KVH)
+    keys, values, since, whole, _ = _positions(k, v, log_g, keep, pending)
+    rows = _stacked(q, keys, jnp.broadcast_to(whole[..., None, None],
+                                              (B, KVH, 1, n)))
+    cols = jnp.swapaxes(_stacked(q, values * since[..., None],
+                                 keys * since[..., None]), 2, 3)
+    o, ssm = _call(functools.partial(_state_pass, rep=rep, fold=True,
+                                     name=name), layer, ssm, rows, cols)
+    num, den = _read_out(o, rep)
+    return (num / den[..., None]).reshape(B, H, n), ssm
+
+
+@jax.jit
+def retention_read(ssm, layer, q, k, v, log_g, keep=None, pending=None):
+    """THE READ PASS (Pallas, name ``retention_read``): ``retention_step``'s
+    ``o`` for the same operands with the state read once and NOT written: the
+    queries against the state as of the last fold, decayed to now, plus the
+    pending positions and the step's own by ``phi(x) . phi(y) = (x . y)^2``.
+    Returns (o [B, H, n] float32, ``ssm``, the same bytes and donated as
+    the fold's, the step's own position as a pending row [3, B, KVH, n]:
+    what the caller keeps in place of a written state)."""
+    (B, H, n), KVH = q.shape, k.shape[1]
+    rep, q = H // KVH, _by_state(q, KVH)
+    keys, values, since, whole, own = _positions(k, v, log_g, keep, pending)
+    rows = _stacked(q)
+    o, ssm = _call(functools.partial(_state_pass, rep=rep, fold=False,
+                                     name="retention_read"),
+                   layer, ssm, rows, jnp.swapaxes(rows, 2, 3))
+    num, den = _read_out(o, rep)
+    # elementwise, then summed: float32 as it stands (a product on the TPU
+    # would round its operands)
+    weight = jnp.sum(q[:, :, :, None] * keys[:, :, None], axis=-1) ** 2 \
+        * since[:, :, None]                                # [B, KVH, rep, FOLD]
+    num = whole[..., None, None] * num + jnp.sum(
+        weight[..., None] * values[:, :, None], axis=3)
+    den = whole[..., None] * den + jnp.sum(weight, axis=-1)
+    return (num / den[..., None]).reshape(B, H, n), ssm, own
+
+
+@functools.partial(jax.jit, static_argnames=("riding",))
+def retention_decode(ssm, pending, count, layer, q, k, v, log_g, keep=None, *,
+                     riding: bool = False):
+    """One decode step of one layer for every slot, as the cache's own count
+    of the pending positions says: the read pass, whose position joins the
+    pending rows, or (``count`` = FOLD - 1; ``riding``, a step that a prefill
+    call carries: always, under the name ``retention_riding``) the fold, which
+    empties them. ``ssm`` and ``pending`` [layers, FOLD - 1, 3, B, KVH, n] are
+    the whole leaves, handed over donated -> (o [B, H, n] float32, ``ssm``,
+    ``pending``). The caller advances the count (``advance``), once a step."""
+    def fold(ssm, pending):
+        o, ssm = retention_step(
+            ssm, layer, q, k, v, log_g, keep, pending[layer],
+            name="retention_riding" if riding else "retention_step")
+        return o, ssm, pending.at[layer].set(0.0)
+
+    def read(ssm, pending):
+        o, ssm, own = retention_read(ssm, layer, q, k, v, log_g, keep,
+                                     pending[layer])
+        return o, ssm, pending.at[layer, count].set(own)
+
+    if riding:
+        return fold(ssm, pending)
+    return jax.lax.cond(count >= FOLD - 1, fold, read, ssm, pending)
+
+
+def advance(count):
+    """The count of pending positions after a decode step that found
+    ``count``: a fold leaves none."""
+    return jnp.where(count >= FOLD - 1, 0, count + 1)

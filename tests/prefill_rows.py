@@ -137,7 +137,7 @@ def padding_rows_write_nothing(eng, prompt, slot=1, first_page=2):
     assert (np.asarray(placed) == 7.0).all()
 
 
-def riders_equal_a_step_after_the_call(eng, rng, tol=1e-4):
+def riders_equal_a_step_after_the_call(eng, rng, tol=1e-4, settle=None):
     """A prefill call that carries a decode step (``prefill``'s ``riders``)
     against the same call and then ``decode_step``, from one cache: slot 0
     decodes (a prompt of 9, two steps in), slot 1 never held anything and is
@@ -146,7 +146,11 @@ def riders_equal_a_step_after_the_call(eng, rng, tol=1e-4):
     call's first row; the second row is padding. The prompt's logits, the
     step's logits and every leaf of the cache agree to ``tol`` of their norm;
     what the carrying call leaves of a slot that is neither filled nor active
-    is what it found; its ``moe_load`` is the two programs' summed."""
+    is what it found; its ``moe_load`` is the two programs' summed.
+    ``settle``: for a model whose two ways keep the same state in two forms
+    (power retention: a carried step folds its pending positions, a step
+    alone may keep them beside the state), what brings both caches to one
+    form before their leaves are compared."""
     mr, e, cfg = eng._mr, eng.ecfg, eng.mcfg
     B, MP, P = e.max_num_seqs, e.pages_per_seq, e.page_size
     assert mr.rides(cfg) and B >= 3
@@ -198,6 +202,8 @@ def riders_equal_a_step_after_the_call(eng, rng, tol=1e-4):
     filled = jax.tree.map(np.asarray, c1)
     step1, c1 = mr.decode_step(eng.params, cfg, c1, *step)
     (logits2, step2), c2 = mr.prefill(eng.params, cfg, cache, *call, step)
+    if settle is not None:
+        c1, c2 = settle(c1), settle(c2)
 
     def close(got, want, what):
         got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)

@@ -16,6 +16,7 @@ spoiled reference moves the logits by more than ten times that.
 """
 
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ import numpy as np
 import pytest
 
 from benchmarks.architectures import brumby as ref
+from benchmarks.registry import REPO, Cell
 from ray_tpu.llm import LLMConfig
 from ray_tpu.llm import model_runner as mr
 from ray_tpu.llm.config import EngineConfig, SamplingParams
@@ -259,6 +261,93 @@ def test_step_kernel_tells_a_state_kept_in_bfloat16():
     assert _rel(o, want_o) > 1e-3
 
 
+def _leaves(S0, z0, slots):
+    """The state [slots, KVH, D, V] in layer 1 of a leaf of two layers (the
+    other full of sevens) with its scratch slot, and no position pending."""
+    s0 = rt.rolled_state(S0, z0)
+    leaf = jnp.stack([jnp.full_like(s0, 7.0), s0])
+    leaf = jnp.concatenate([leaf, jnp.zeros_like(leaf[:, :1])], axis=1)
+    return leaf, jnp.zeros((2, rt.FOLD - 1, 3, slots, *s0.shape[1:2], HD))
+
+
+_decode = jax.jit(rt.retention_decode, donate_argnums=(0, 1))
+
+
+@pytest.mark.parametrize("rep", [1, 5])
+@pytest.mark.parametrize("first", range(rt.FOLD))
+def test_read_read_read_fold_is_the_recurrence(rep, first):
+    """Twelve decode steps of one layer from the state after eight
+    positions, the first finding ``first`` steps already counted: the read
+    pass (the state as of the last fold, the pending positions and its own by
+    ``(x . y)^2``) three times and then the fold, ``rep`` query heads a state,
+    against ``retention_reference`` at float32's own rounding: ``o`` at every
+    step, and after each fold the written state, no position left pending
+    and the other layer untouched. Between two folds the state is to the bit
+    what the last one wrote."""
+    key = jax.random.split(jax.random.PRNGKey(10 * rep + first), 4)
+    S, B = 8 + 12, 3
+    q = jax.random.normal(key[0], (B, S, rep * KVH, HD)) + 0.5
+    k = jax.random.normal(key[1], (B, S, KVH, HD)) + 0.5
+    v = jax.random.normal(key[2], (B, S, KVH, HD))
+    log_g = jax.nn.log_sigmoid(jax.random.normal(key[3], (B, S, KVH)) + 3.0)
+    want_o = rt.retention_reference(q, k, v, log_g)[0]
+    ssm, pending = _leaves(*rt.retention_reference(
+        q[:, :8], k[:, :8], v[:, :8], log_g[:, :8])[1:], B)
+    count, folds = jnp.asarray(first, jnp.int32), 0
+    for t in range(8, S):
+        before = np.asarray(ssm[1])
+        o, ssm, pending = _decode(ssm, pending, count, 1, q[:, t], k[:, t],
+                                  v[:, t], log_g[:, t])
+        assert _rel(o, want_o[:, t]) < 1e-5, t
+        if int(count) == rt.FOLD - 1:
+            _, S_, z_ = rt.retention_reference(
+                q[:, :t + 1], k[:, :t + 1], v[:, :t + 1], log_g[:, :t + 1])
+            assert _rel(ssm[1, :B], rt.rolled_state(S_, z_)) < 1e-5, t
+            assert not np.asarray(pending).any()
+            folds += 1
+        else:
+            assert (np.asarray(ssm[1, :B]) == before[:B]).all()
+            assert np.asarray(pending[1, int(count)]).any()
+        count = rt.advance(count)
+    assert folds == 3 and (np.asarray(ssm[0, :B]) == 7.0).all()
+    assert not np.asarray(pending[0]).any()
+
+
+def test_slot_that_does_not_step_holds_state_and_pending_to_the_bit():
+    """Four decode steps across a fold, under ``keep`` (a slot that does not
+    decode, alone or beside a prompt): slot 0 steps; slot 1, with nothing
+    pending, takes null positions and is to the bit what it was, state and
+    pending rows, through the reads AND through the fold; slot 2 went quiet
+    with one position pending: the fold takes that position and nothing else
+    (its state is the recurrence's over the nine positions it saw)."""
+    q, k, v, log_g, _ = _operands(3, 8 + 4, 5)
+    ssm, pending = _leaves(*rt.retention_reference(
+        q[:, :8], k[:, :8], v[:, :8], log_g[:, :8])[1:], 3)
+    count = jnp.zeros((), jnp.int32)
+    start = np.asarray(ssm[1])
+    for t in range(8, 12):
+        keep = jnp.asarray([True, False, t == 8])
+        o, ssm, pending = _decode(ssm, pending, count, 1, q[:, t], k[:, t],
+                                  v[:, t], log_g[:, t], keep)
+        count = rt.advance(count)
+        assert not np.asarray(pending[1, :, :, 1]).any()
+        if t < 11:
+            assert (np.asarray(ssm[1]) == start).all()
+            assert np.asarray(pending[1, :, :, 2]).any()
+    assert int(count) == 0 and not np.asarray(pending).any()
+    assert (np.asarray(ssm[1, 1]) == start[1]).all()
+    for slot, seen in ((0, 12), (2, 9)):
+        _, S_, z_ = rt.retention_reference(*(
+            t[slot:slot + 1, :seen] for t in (q, k, v, log_g)))
+        assert _rel(ssm[1, slot], rt.rolled_state(S_, z_)[0]) < 1e-5
+    # the same fold as a carried step makes it, whatever the count
+    _, again, _ = rt.retention_decode(
+        jnp.asarray(start)[None], jnp.zeros_like(pending[:1]), count, 0,
+        q[:, 8], k[:, 8], v[:, 8], log_g[:, 8],
+        jnp.asarray([False, False, False]), riding=True)
+    assert (np.asarray(again[0]) == start).all()
+
+
 # -- (b) the engine against the reference -------------------------------------------
 
 
@@ -282,6 +371,9 @@ def test_engine_matches_reference(engine, prompt_len, bucket):
     assert c.ssm.dtype == jnp.float32
     assert all(getattr(c, name) is None for name in mr.PAGE_LEAVES)
     assert c.conv is None and c.rings is None and c.moe_load is None
+    assert c.pending.shape == (2, rt.FOLD - 1, 3, 3, KVH, HD)
+    assert c.pending.dtype == jnp.float32 and c.pending_count.dtype == jnp.int32
+    assert int(c.pending_count) == 10 % rt.FOLD    # ten steps: two folds
     assert mr._page_size(c) == 0
     held = np.abs(np.asarray(c.ssm)).max(axis=(2, 3, 4, 5))
     assert held[:, 2].min() > 0
@@ -344,17 +436,46 @@ def test_bfloat16_engine_is_one_rounding_a_product():
 # -- (c) every part shows in the logits -------------------------------------------------
 
 
-@pytest.mark.parametrize("wrong", ref.WITHOUT)
+# the program's own faults with what a slot keeps between two folds: the
+# pending positions dropped before the fold reads them, and left standing
+# behind it (read again until the next positions overwrite them)
+PENDING_FAULTS = ("pending_dropped", "pending_twice")
+
+
+class _FaultyRun(_Run):
+    def __init__(self, eng, fault):
+        super().__init__(eng)
+        self.fault = fault
+
+    def decode(self, tokens):
+        c = self.cache
+        folds = int(c.pending_count) == rt.FOLD - 1
+        if folds and self.fault == "pending_dropped":
+            self.cache = c._replace(pending=jnp.zeros_like(c.pending))
+        held = jnp.copy(c.pending)
+        out = super().decode(tokens)
+        if folds and self.fault == "pending_twice":
+            self.cache = self.cache._replace(pending=held)
+        return out
+
+
+@pytest.mark.parametrize("wrong", ref.WITHOUT + PENDING_FAULTS)
 def test_wrong_part_fails_the_comparison(engine, wrong):
     """A reference that leaves a part of the mathematics out or does it
     wrong is ten tolerances away: the degree, the off-diagonal pairs'
     sqrt(2), the gate (none, twice, over the new term too), the normaliser
     (none, undecayed), which state a query head reads, the rotation, the
-    head norms and their order with the rotation."""
+    head norms and their order with the rotation. So is a PROGRAM that
+    loses the positions a slot keeps between two folds, or folds them
+    twice, against the reference as it stands."""
     toks = np.random.default_rng(7).integers(0, VOCAB, 13 + 6)
     got = _Run(engine).sequence(0, toks, 13)
     assert _rel(got, _reference(engine, toks)[12:]) < TOL
-    moved = _rel(got, _reference(engine, toks, without=(wrong,))[12:])
+    if wrong in PENDING_FAULTS:
+        bad = _FaultyRun(engine, wrong).sequence(0, toks, 13)
+        moved = _rel(bad, _reference(engine, toks)[12:])
+    else:
+        moved = _rel(got, _reference(engine, toks, without=(wrong,))[12:])
     print(f"without {wrong}: the logits move by {moved:.3g} of their norm")
     assert moved > 10 * TOL
 
@@ -384,7 +505,49 @@ def test_another_slots_state_fails_the_comparison(engine):
     assert _rel(bad, _reference(engine, toks)[9:]) > 10 * TOL
 
 
+@pytest.mark.parametrize("emptied", [True, False],
+                         ids=["emptied", "left-standing"])
+def test_slot_refilled_between_two_folds(engine, emptied):
+    """A slot handed on between two folds, its last tenant's two positions
+    still pending: the prefill call empties them with the state it writes,
+    and the new request's steps are the reference's; with the emptying taken
+    back (the rows the call found, put where it left zeros) the fold takes
+    a stranger's positions and the comparison FAILS."""
+    rng = np.random.default_rng(12)
+    gone, toks = rng.integers(0, VOCAB, 9 + 2), rng.integers(0, VOCAB, 5 + 6)
+    run = _Run(engine)
+    run.sequence(1, gone, 9)
+    found = jnp.copy(run.cache.pending)   # the call donates the cache
+    assert int(run.cache.pending_count) == 2
+    assert np.asarray(found[:, :2, :, 1]).any()
+    first = run.prefill(1, toks[:5])
+    assert int(run.cache.pending_count) == 2    # a call alone counts nothing
+    assert not np.asarray(run.cache.pending[:, :, :, 1]).any()
+    if not emptied:
+        run.cache = run.cache._replace(pending=found)
+    got = np.stack([first] + [run.decode({1: t})[1] for t in toks[5:]])
+    err = _rel(got, _reference(engine, toks)[4:])
+    assert err < TOL if emptied else err > 10 * TOL, err
+
+
 # -- (d) decode rows ride a prefill call --------------------------------------------------
+
+
+def _settled(cache):
+    """``cache`` with every slot's pending positions folded into its state
+    (a null position for everybody: what a step beside a prompt does to a
+    slot that does not decode) and none left: the form a carried step leaves
+    a cache in, so that a cache a step alone left can be laid beside it."""
+    ssm, pending = cache.ssm, cache.pending
+    B = pending.shape[3]
+    nobody = jnp.zeros((B,), bool)
+    for layer in range(ssm.shape[0]):
+        _, ssm, pending = rt.retention_decode(
+            ssm, pending, cache.pending_count, layer,
+            jnp.ones((B, H, HD)), *jnp.zeros((2, B, KVH, HD)),
+            jnp.zeros((B, KVH)), nobody, riding=True)
+    return cache._replace(ssm=ssm, pending=pending,
+                          pending_count=jnp.zeros_like(cache.pending_count))
 
 
 def test_decode_rows_ride_a_prefill_call(engine):
@@ -396,7 +559,7 @@ def test_decode_rows_ride_a_prefill_call(engine):
 
     assert mr.rides(engine.mcfg)
     prefill_rows.riders_equal_a_step_after_the_call(
-        engine, np.random.default_rng(7), 1e-4)
+        engine, np.random.default_rng(7), 1e-4, settle=_settled)
 
 
 def test_riding_calls_match_reference():
@@ -439,6 +602,13 @@ def test_engine_serves_preempts_and_counts_the_states_it_moves():
     m = eng.metrics
     assert m["preempted"] >= 1
     assert m["retention_state_slots"] == 2 * 3 * m["decode_steps"]
+    # a step in FOLD writes the states back, and every riding step does
+    assert m["retention_steps"] == m["decode_steps"] > 2 * rt.FOLD
+    plain = m["decode_steps"] - m["riding_steps"]
+    assert m["riding_steps"] <= m["retention_fold_steps"] \
+        <= m["riding_steps"] + plain // rt.FOLD
+    assert m["retention_fold_steps"] >= m["decode_steps"] // rt.FOLD
+    assert int(eng.cache.pending_count) == eng._pending_positions
     assert 0 < m["retention_live_slots"] <= m["retention_state_slots"]
     assert m["kda_step_slots"] == m["ssd_step_slots"] == 0
     assert m["shared_kv_live_tokens"] == m["mla_decode_live_tokens"] == 0
@@ -450,6 +620,104 @@ def test_engine_serves_preempts_and_counts_the_states_it_moves():
         _engine(expect_state_layers=0)
     with pytest.raises(ValueError, match="export_kv"):
         eng.export_kv("nobody")
+
+
+def test_admitted_at_every_count_preempted_and_ridden_between_folds():
+    """Requests through ``step()`` admitted when the count of pending
+    positions stands at 0, 1, 2 and 3: by a call alone, which leaves the
+    count and the other slots' pending positions as they are and empties its
+    own slot's, and by a call that carries the others' step, which folds
+    whatever is pending; one runs out of pages between two folds and is
+    prefilled again from all its tokens. Every request gets the tokens it
+    got alone, the host's count is the cache's own after every step, and a
+    step in four folds where nothing rides."""
+    eng = _engine(num_pages=24)
+    rng = np.random.default_rng(13)
+    # 36 tokens: the bucket of 64, which carries no step here; 5: one of 16.
+    # The two in the middle answer at length, so that the last finds slots
+    # decoding through a whole round of counts
+    prompts = [rng.integers(0, VOCAB, n).tolist() for n in (36, 37, 5, 38)]
+    sps = [SamplingParams(max_tokens=n) for n in (6, 16, 16, 6)]
+    assert [eng._carries(1, eng._prefill_bucket(len(p))) for p in prompts] \
+        == [False, False, True, False]
+    alone = [eng.generate([p], sp, decode_text=False)[0].token_ids
+             for p, sp in zip(prompts, sps)]
+    before, got, found = dict(eng.metrics), {}, []
+    left, start = list(enumerate(prompts)), eng._pending_positions
+    for _ in range(400):
+        if not (left or eng.has_unfinished()):
+            break
+        count = eng._pending_positions
+        wanted = (start + len(prompts) - len(left)) % rt.FOLD
+        if left and count == wanted and None in eng._slots \
+                and not eng._waiting:
+            i, p = left.pop(0)
+            eng.add_request(f"r{i}", p, sps[i])
+        admitted = eng.metrics["admitted"]
+        for out in eng.step():
+            if out.finished:
+                got[out.request_id] = out.token_ids
+        found += [count] * (eng.metrics["admitted"] - admitted)
+        assert int(eng.cache.pending_count) == eng._pending_positions
+    assert not left and not eng.has_unfinished()
+    assert [got[f"r{i}"] for i in range(len(prompts))] == alone
+    d = {k: eng.metrics[k] - v for k, v in before.items()}
+    assert set(found) == set(range(rt.FOLD)), found
+    assert d["preempted"] >= 1 and d["riding_steps"] >= 1
+    assert d["retention_steps"] == d["decode_steps"]
+    plain = d["decode_steps"] - d["riding_steps"]
+    assert d["riding_steps"] <= d["retention_fold_steps"] \
+        <= d["riding_steps"] + plain // rt.FOLD + 1
+
+
+def test_fold_metrics_read_a_window_as_data():
+    """The two per-layer metrics this mechanism brought are data files that
+    ``benchmarks.registry``'s cell reads: the share of the decode steps that
+    wrote the states back, from the engine's two counters, and the read
+    pass's device time a decode step, from the trace's ``retention_read``
+    operations; beside them ``retention.step_dev_ms`` now holds the folds
+    over ALL steps. A program without the counters and the kernel (the
+    parent's) leaves both out and nothing is raised."""
+    cell_name = "brumby-14b-base.completion-saturated-b32"
+    cell = Cell(cell_name, os.path.join(REPO, "BENCHMARK.json"))
+    listed = {m["name"]: m for m in cell.per_layer()}
+    for name, layer, source in (
+            ("retention.fold_share", "engine (llm/engine.py)",
+             "program_counter"),
+            ("retention.read_dev_ms", "kernels (ops/retention.py)",
+             "device_trace")):
+        assert listed[name]["workloads"] == [cell_name]
+        assert listed[name]["moves"] == "serve_tokens_per_s"
+        assert (listed[name]["layer"], listed[name]["source"]) == (layer, source)
+        assert set(cell.reader(name)) == {"reduce", "args"}
+    ops = {"retention_read (f32[32,8,128,16], f32[6,33,8,66,128,128])":
+           [732 * 1.466e-3, 732.0],
+           "retention_step (f32[32,8,128,16], f32[6,33,8,66,128,128])":
+           [244 * 3.39e-3, 244.0]}
+    trace = {"modules": {"jit_decode_step": {"count": 164, "total_s": 3.28}},
+             "window_s": 4.0, "busy_s": 3.5, "op_kinds": ops}
+    facts = {"max_num_seqs": 32, "peak_flops_per_s": 197e12,
+             "peak_hbm_bytes_per_s": 819e9}
+    window = {"decode_steps": 1631, "retention_steps": 1631,
+              "retention_fold_steps": 409, "riding_steps": 4}
+    got = {k: v["value"] for k, v in cell.per_layer_values(
+        {"trace": trace, "spans": {}, "counters": window, "facts": facts}
+    ).items()}
+    assert got["retention.fold_share"] == 409 / 1631
+    assert got["retention.read_dev_ms"] == pytest.approx(732 * 1.466 / 164)
+    assert got["retention.step_dev_ms"] == pytest.approx(244 * 3.39 / 164)
+    assert 70 < got["retention_step_roofline"] < 100   # on fold calls alone
+    # the parent: a fold every step under the one name, no such counter
+    parent = dict(trace, op_kinds={
+        "retention_step (f32[32,8,128,8], f32[6,33,8,66,128,128])":
+        [984 * 3.405e-3, 984.0]})
+    got = cell.per_layer_values(
+        {"trace": parent, "spans": {}, "facts": facts,
+         "counters": {"decode_steps": 1631, "riding_steps": 4}})
+    assert "retention.fold_share" not in got
+    assert "retention.read_dev_ms" not in got
+    assert got["retention.step_dev_ms"]["value"] == pytest.approx(
+        984 * 3.405 / 164)
 
 
 def test_engine_counts_the_chunks_the_scan_passes_over():

@@ -16,7 +16,14 @@ This does, on ``benchmarks/configs/brumby-14b-base.json``:
   ``[1, 512]`` and a 2-token one through ``[1, 256]``, calls that CARRY the
   decoding slots' step (``retention_riding`` with ``keep`` beside
   ``retention_scan``), and 32-slot decode steps between and after
-  (``tests/prefill_rows.py:teacher_forced_riding``).
+  (``tests/prefill_rows.py:teacher_forced_riding``). A slot's state is
+  written back every fourth decode step and whenever a call carries the
+  step (``ops/retention.py:FOLD``): the eight steps behind the last
+  admission cross two folds with three read passes before each, the count
+  the cache keeps is read before every step (``counts_before_steps``), and
+  the decode program, compiled again here for the chip it runs on, holds no
+  second copy of the 6.85 GB of state (``decode_temp_bytes``,
+  ``state_copies``).
 
 Every position's logits against ``benchmarks/architectures/brumby.py:
 forward`` (the ATTENTION form, blocked over query positions) in float32 at
@@ -50,6 +57,7 @@ elsewhere:
 import argparse
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -92,7 +100,7 @@ def main(argv=()) -> dict:
     from ray_tpu.llm import LLMConfig
     from ray_tpu.llm.config import EngineConfig
     from ray_tpu.llm.engine import JaxLLMEngine
-    from ray_tpu.ops.retention import scan_chunks
+    from ray_tpu.ops.retention import FOLD, scan_chunks
 
     t_start = time.time()
 
@@ -143,7 +151,23 @@ def main(argv=()) -> dict:
     want = reference()
     seqs = {slot: (draw(prompt), prompt, page)
             for prompt, slot, page in requests}
+
+    class Counting:
+        """``eng._mr`` with the cache's count of pending positions read
+        before every decode step."""
+        seen = []
+
+        def __getattr__(self, name):
+            return getattr(mr, name)
+
+        def decode_step(self, params, cfg, cache, *rows):
+            self.seen.append(int(cache.pending_count))
+            return mr.decode_step(params, cfg, cache, *rows)
+
+    eng._mr = Counting()
     got = teacher_forced_riding(eng, seqs, gap=1)
+    eng._mr = mr
+    out["counts_before_steps"] = Counting.seen
     note("three requests through [1, 4096], [1, 512] carrying, [1, 256] "
          "carrying, decode steps between and after")
     long_toks = seqs[LONG[1]][0]
@@ -176,6 +200,13 @@ def main(argv=()) -> dict:
     from_other_slot = decode_from(MIDDLE[1])
     note("the long request's steps from the padded end and from slot",
          MIDDLE[1])
+    idle = (jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32), tables,
+            jnp.zeros(B, bool))
+    compiled = mr.decode_step.lower(eng.params, mcfg, eng.cache,
+                                    *idle).compile()
+    out["decode_temp_bytes"] = compiled.memory_analysis().temp_size_in_bytes
+    out["state_copies"] = len(re.findall(
+        r"= f32\[6,33,8,66,128,128\]\S* copy\(", compiled.as_text()))
     stats = jax.devices()[0].memory_stats() or {}
     out["peak_gb"] = round(stats.get("peak_bytes_in_use", 0) / 1e9, 3)
     # the references want the room the state holds
@@ -222,7 +253,10 @@ def main(argv=()) -> dict:
         and carrying == [False, True, True]
         and out["state_shape"] == [6, 33, 8, 66, 128, 128]
         and out["state_dtype"] == "float32" and not out["paged_leaves"]
-        and out["long_call_chunks"] == (8, 1))
+        and out["long_call_chunks"] == (8, 1)
+        # the last request's eight steps: read, read, read, fold, twice
+        and out["counts_before_steps"][-8:] == list(range(FOLD)) * 2
+        and out["decode_temp_bytes"] < 128 << 20 and not out["state_copies"])
     print(json.dumps(out), flush=True)
     return out
 

@@ -1154,19 +1154,29 @@ def test_longcat_prefill_holds_no_buffer_of_every_assignment(one_chip, rows,
 
 
 def test_brumby_decode_steps_every_state_in_place(one_chip):
-    """Decode at 32 slots: ``retention_step`` once in each of the six layers
-    over the whole [6, 32 + 1, 8, 66, 128, 128] float32 leaf (6.85 GB), which is
-    written IN PLACE: one copy of it among the live bytes, and nothing a
-    state's size among the temporaries. The cache holds that leaf and no page;
-    the block tables are arguments that address nothing."""
+    """Decode at 32 slots: in each of the six layers ONE conditional on the
+    cache's count of pending positions, around ``retention_read`` (the state
+    read, a tile of the scratch slot written) and ``retention_step`` (the
+    fold: read and written), both over the whole [6, 32 + 1, 8, 66, 128, 128]
+    float32 leaf (6.85 GB), which either branch hands back IN PLACE: one copy
+    of it among the live bytes, NO copy of it anywhere in the program, and
+    nothing a state's size among the temporaries. The cache holds that leaf,
+    the pending positions (7 MB) and their count, and no page; the block
+    tables are arguments that address nothing."""
     cache, _, decode = _lower_rms_kinds(one_chip, "brumby-14b-base")
     compiled = decode().compile()
     text = compiled.as_text()
-    assert len(set(re.findall(
-        r"%(retention_step\S*) = \(f32\[32,8,128,8\]", text))) == 6
-    assert text.count("tpu_custom_call") == 6
+    for kernel in ("retention_step", "retention_read"):
+        assert len(set(re.findall(
+            rf"%({kernel}\S*) = \(f32\[32,8,128,16\]", text))) == 6
+    assert text.count("tpu_custom_call") == 12
+    assert len(re.findall(r" conditional\(", text)) == 6
+    assert not re.search(r"= f32\[6,33,8,66,128,128\]\S* copy\(", text)
     assert cache.ssm.shape == (6, 32 + 1, 8, 66, 128, 128)
     assert cache.ssm.dtype == jnp.float32
+    assert cache.pending.shape == (6, 3, 3, 32, 8, 128)
+    assert cache.pending.dtype == jnp.float32
+    assert cache.pending_count.shape == ()
     assert all(leaf is None for leaf in (
         cache.pages, cache.rows, cache.k, cache.v, cache.rings, cache.conv))
     live, temp = _live(compiled)

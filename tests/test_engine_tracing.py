@@ -70,6 +70,9 @@ KEYS = {
     # the same four for power-retention layers; 0 without such layers
     "retention_state_slots", "retention_live_slots",
     "retention_scan_chunks", "retention_scan_chunks_skipped",
+    # such a model's decode steps, and those of them that wrote the states
+    # back (a step in FOLD, and every riding one); 0 without such layers
+    "retention_steps", "retention_fold_steps",
     # the query blocks a prefill call's flash kernel has a head, and those
     # behind their row's end that it passes over
     "flash_q_blocks", "flash_q_blocks_skipped"}
@@ -172,6 +175,7 @@ def test_metrics_complete_numeric_monotone(engine):
     # no power-retention layer: no state of one for a step to move or a
     # scan to build
     assert m["retention_state_slots"] == m["retention_live_slots"] == 0
+    assert m["retention_steps"] == m["retention_fold_steps"] == 0
     assert m["retention_scan_chunks"] == m["retention_scan_chunks_skipped"] == 0
     # a prompt here fills its row's one query block, a padding row none
     assert (m["flash_q_blocks"] - m["flash_q_blocks_skipped"]

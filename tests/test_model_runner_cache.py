@@ -54,8 +54,9 @@ LEAVES = {
 def test_init_cache_gives_each_model_the_leaves_it_had(model):
     cache = mr.init_cache(_cfg(model), NUM_PAGES, PAGE, SLOTS)
     assert type(cache) is mr.Cache
+    # the last two are a "retention" layer's (PR 56): None for these six
     assert cache._fields == ("k", "v", "rows", "pages", "rings", "ssm", "conv",
-                             "moe_load")
+                             "moe_load", "pending", "pending_count")
     got = {name: (tuple(leaf.shape), str(leaf.dtype))
            for name, leaf in cache._asdict().items() if leaf is not None}
     assert got == LEAVES[model]
