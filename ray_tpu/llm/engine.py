@@ -72,179 +72,118 @@ caller's way back to ``step()`` cost it nothing. What makes that possible:
 
 What the engine says about itself (``LLMServer.engine_metrics()`` returns a
 copy of ``engine.metrics``: flat, numeric, only ever growing, every key there
-from ``__init__``, so two snapshots subtract):
+from ``__init__``, so two snapshots subtract; ``ray_tpu/serve/README.md`` is
+the operator's guide to reading them):
 
 - counts: ``steps`` (calls of ``step()`` that ran a program),
   ``overlapped_steps`` (those that did so while an earlier step's tokens were
-  unread: all but the first of a busy stretch), ``dropped_tokens``,
-  ``sample_calls`` (calls of the sampler program, ``jit_sample_tokens``: one
-  a prefill phase and one a decode step that rode none) and
-  ``sample_greedy_calls`` (those
-  with no positive temperature in any slot, counted from the host's own
-  ``_temps`` by the rule the program applies to its copy on the device: it
-  then takes its ``argmax`` branch and computes no shortlist),
-  ``prefill_steps`` (steps that ran a prefill phase), ``decode_steps`` (a
-  dropped token's step is one; so is a step whose decode rows rode a
-  prefill call) and, of those, ``riding_steps`` (the steps that did: no
+  unread), ``dropped_tokens``, ``sample_calls`` (calls of
+  ``jit_sample_tokens``: one a prefill phase and one a decode step that rode
+  none) and ``sample_greedy_calls`` (those with no positive temperature in
+  any slot, by the rule the program applies on the device: it then takes its
+  ``argmax`` branch), ``prefill_steps`` (steps that ran a prefill phase),
+  ``decode_steps`` (a dropped token's step is one; so is a step whose decode
+  rows rode a prefill call) and, of those, ``riding_steps`` (no
   ``jit_decode_step``, no sampler call and no read of their own, so
-  ``sample_calls == prefill_steps + decode_steps - riding_steps``);
-  ``generated_tokens``, ``itl_*`` and the ``*_live_tokens`` below count a
-  riding step's decode rows as any decode step's,
+  ``sample_calls == prefill_steps + decode_steps - riding_steps``; every
+  counter of decode rows counts a riding step's as any decode step's),
   ``admitted`` (requests, each one row of a prefill call) against
-  ``prefill_calls`` (prefill program calls: fewer, where rows shared one),
+  ``prefill_calls`` (program calls: fewer, where rows shared one),
   ``prefill_tokens`` (real prompt positions) against
-  ``prefill_batch_tokens`` (the ``R x S`` of every prefill call, padding rows
+  ``prefill_batch_tokens`` (the ``R x S`` of every call, padding rows
   included: what the device computes), ``generated_tokens``, ``preempted``,
-  ``compiles`` (first use of a prefill bucket or of decode: what the serving
-  path waited for), ``prefill_shapes_wanted`` (shapes of several rows asked
-  for off it, at their bucket's first use) and ``prefill_shapes_ready`` (those
-  compiled: fewer for good where one could not be made, which
-  ``prefill_shapes.RowShapes.failed`` and the log explain); program counts
-  move at dispatch, ``generated_tokens`` when a token is emitted;
+  ``compiles`` (first use of a prefill bucket or of decode on the serving
+  path), ``prefill_shapes_wanted`` / ``prefill_shapes_ready`` (shapes of
+  several rows asked for off it, and compiled: fewer for good where one could
+  not be made, which ``prefill_shapes.RowShapes.failed`` and the log
+  explain); program counts move at dispatch, ``generated_tokens`` at emit;
 - host milliseconds (``perf_counter_ns``): ``step_ms`` = ``host_ms`` +
-  ``readback_ms`` (blocked on the device in ``np.asarray(tokens)``: with a
-  step queued behind the one it waits for, the device's time and not the
-  host's); the phases ``admit_ms``, ``prefill_dispatch_ms``,
-  ``decode_dispatch_ms``, ``sample_dispatch_ms``, ``readback_ms``,
-  ``emit_ms`` add up to ``step_ms``;
+  ``readback_ms`` (blocked on the device in ``np.asarray(tokens)``); the
+  phases ``admit_ms``, ``prefill_dispatch_ms``, ``decode_dispatch_ms``,
+  ``sample_dispatch_ms``, ``readback_ms``, ``emit_ms`` add up to ``step_ms``;
   ``between_steps_ms`` is the caller's time from one ``step()`` to the next
   while work was left;
-- what each blocking read waited for, over the whole of a window and with no
-  profiler: ``prefill_phase_ms`` and ``decode_phase_ms``, with ``phase_ms``
-  their sum. A read of a prefill phase's first tokens waits behind that
-  phase's ``jit_prefill`` calls and their one sampler call, a read of a
-  decode step's tokens behind ``jit_decode_step`` and its sampler call; a
-  phase that carried a decode step is read once, as a prefill phase (it IS
-  ``jit_prefill`` calls: ``prefill_phase_ms`` then holds that decode step's
-  attention and its rows' share of everything else, ``prefill_phase_ms /
-  phase_ms`` is no longer "the share lost to prefill", and
-  ``decode_phase_calls`` is ``decode_steps - riding_steps``); each
-  adds the time from the end of the read before it (or from its own
-  dispatch, if that came later: the device had run dry) to the moment its
+- what each blocking read waited for, over a whole window and with no
+  profiler: ``prefill_phase_ms`` and ``decode_phase_ms``, ``phase_ms`` their
+  sum. A read of a prefill phase's first tokens waits behind that phase's
+  ``jit_prefill`` calls and their one sampler call, a read of a decode
+  step's behind ``jit_decode_step`` and its sampler call; a phase that
+  carried a decode step is read once, as a prefill phase (so
+  ``prefill_phase_ms / phase_ms`` is then no longer "the share lost to
+  prefill" and ``decode_phase_calls`` is ``decode_steps - riding_steps``);
+  each adds the time from the end of the read before it (or from its own
+  dispatch, if later: the device had run dry) to the moment its
   ``np.asarray(tokens)`` returned. While the reads truly block
-  (``readback_ms / phase_ms`` near 1 less the host's share of a step: the
-  device sets the pace) that is the device's time for the phase to a copy's
-  latency. ``prefill_phase_calls`` and ``decode_phase_calls`` count the
-  model's calls those reads waited behind (``prefill_calls`` and
-  ``decode_steps`` again, but moving with the read and not at dispatch, so a
-  window's two deltas cover the same calls): ``prefill_phase_ms /
-  prefill_phase_calls`` and ``decode_phase_ms / decode_phase_calls`` are a
-  prefill call's (of however many rows) and a decode step's time with their
-  share of the sampler, and ``prefill_phase_positions`` /
-  ``prefill_phase_real_positions`` are ``prefill_batch_tokens`` and
-  ``prefill_tokens`` again by the same rule: ``prefill_phase_positions /
-  prefill_phase_ms`` is what a padded position of prefill costs over every
-  call of a window, whatever buckets it drew. ``generated_tokens /
-  phase_ms`` is the tokens a busy millisecond brings: both move at a read,
-  so no request's end and no window's edge is in it. When the host arrives
-  late a read returns at once and the host's time lands on it: a prefill
-  phase shorter than the host's own way from one read to the next (dispatch,
-  emit, the caller's hop: 5-8 ms) reads as that long, and the decode step
-  behind it as much shorter; with ``readback_ms / phase_ms`` near 0 (the
-  device waits for the host; on the CPU backend, which runs a program inside
-  its dispatch, always) the split by kind says nothing. ``phase_ms`` stays
-  the busy time either way;
+  (``readback_ms / phase_ms`` near 1 less the host's share) that is the
+  device's time for the phase to a copy's latency; near 0 (the device waits
+  for the host; on the CPU backend always) the split by kind says nothing
+  and ``phase_ms`` stays the busy time. ``prefill_phase_calls``,
+  ``decode_phase_calls``, ``prefill_phase_positions`` and
+  ``prefill_phase_real_positions`` are ``prefill_calls``, ``decode_steps``,
+  ``prefill_batch_tokens`` and ``prefill_tokens`` again, moving with the read
+  and not at dispatch, so a window's deltas cover the same calls: a call's
+  and a step's time with their share of the sampler, what a padded position
+  of prefill costs whatever buckets a window drew, and ``generated_tokens /
+  phase_ms``, the tokens a busy millisecond brings, with no request's end
+  and no window's edge in it;
 - reads that stalled: a read that waited over ``_STALL_MS`` (1,000 ms: nearly
-  three times the longest program of any benchmark cell) for EACH call it stood
-  behind adds one to ``stalled_reads`` and its wait to ``stalled_read_ms``,
-  and the engine logs one line (``stalled read: kind= calls= bucket= rows=``,
-  the wait, the time since its dispatch, and whether ``compiles`` moved
-  since: a shape's first use on the serving path makes the read behind it
-  wait for the compile). ``stalled_read_ms / phase_ms`` is 0 in a sound run;
+  three times the longest program of any benchmark cell) for EACH call it
+  stood behind adds one to ``stalled_reads`` and its wait to
+  ``stalled_read_ms``, and the engine logs one line (``stalled read: kind=
+  calls= bucket= rows=``, the wait, the time since its dispatch, and whether
+  ``compiles`` moved since). ``stalled_read_ms / phase_ms`` is 0 in a sound
+  run;
 - every slot's row of every decode step (riding ones too), by what filled or
-  emptied it, counted at the step's dispatch: ``slot_steps`` (``max_num_seqs``
-  a step) = ``slot_steps_live`` (rows that decode; a row whose request a
-  stop token, read a step late, had ended is among them and
-  ``dropped_tokens`` says how many, so once everything is read
+  emptied it, at the step's dispatch: ``slot_steps`` (``max_num_seqs`` a
+  step) = ``slot_steps_live`` (rows that decode; once everything is read
   ``slot_steps_live == generated_tokens + dropped_tokens - admitted``) +
   ``slot_steps_prefilling`` (in a step whose decode rows ride a prefill
-  call, the slots that went to a request in that very step: the call runs
-  their prompts, their first decode row is the next step's; 0 where nothing
-  rides) + ``slot_steps_starved`` (empty, and the admission before the step
-  left nobody waiting: whoever offers the load set this step's batch, not
-  the engine) + ``slot_steps_page_blocked`` (empty though somebody waited:
-  the queue's head lacked pages, or ``_grow_pages`` found the pool empty and
-  sent a decoding request back to the queue). Nothing else empties a row: a
-  slot freed by length is free at its last dispatch and the next call admits
-  into it;
+  call, the slots that went to a request in that very step) +
+  ``slot_steps_starved`` (empty, and the admission before the step left
+  nobody waiting: whoever offers the load set this step's batch) +
+  ``slot_steps_page_blocked`` (empty though somebody waited: the queue's
+  head lacked pages, or ``_grow_pages`` sent a decoding request back to the
+  queue). Nothing else empties a row;
 - per request, summed: ``queue_wait_ms`` (``add_request`` to first
   admission) and ``ttft_ms`` (``add_request`` to first token);
 - the gaps a request sees between its tokens, each token dated by the moment
-  the read that brought it returned (what a streaming client would see):
-  ``itl_ms`` (their sum), ``itl_tokens`` (their number: every emitted token
-  but a request's first) and the ladder ``itl_over_25ms``, ``_50ms``,
-  ``_100ms``, ``_200ms``, ``_400ms``, ``_800ms`` (gaps strictly longer, so
-  no rung counts more than the one before it). A quantile is bracketed by
-  two rungs: the p-quantile lies at or under the first rung whose count is
-  at most ``(1 - p) x itl_tokens``, and over the rung before it. A decode
-  step alone is 14-21 ms, so a gap over 50 ms was spent behind somebody's
-  prefill phase; a preempted request's gap across its second prefill counts,
-  as its client waited for it;
+  the read that brought it returned: ``itl_ms`` (their sum), ``itl_tokens``
+  (their number: every emitted token but a request's first) and the ladder
+  ``itl_over_25ms``, ``_50ms``, ``_100ms``, ``_200ms``, ``_400ms``,
+  ``_800ms`` (gaps strictly longer, so the p-quantile lies at or under the
+  first rung whose count is at most ``(1 - p) x itl_tokens``). A decode step
+  alone is 14-21 ms, so a gap over 50 ms was spent behind somebody's prefill
+  phase; a preempted request's gap across its second prefill counts;
 - what routing did in the ``jit_decode_step`` calls, for a model with experts
-  (all 0 for a dense one; a carrying prefill call's ``moe_load`` mixes prompt
-  and decode rows and is not read): ``moe_decode_layer_steps`` (those calls x
-  expert layers) and, summed
-  over those, ``moe_decode_routed_assignments`` (active slots x top_k:
-  wherever they fell), ``moe_decode_assignments`` (those that fell on an
-  expert HELD here: all of them, unless the model is one rank of an
-  expert-parallel deployment, ``TransformerConfig.experts_held``),
-  ``moe_decode_zero_assignments`` (those that fell on a zero-compute
-  expert, ``TransformerConfig.zero_experts``: the identity, on every rank
-  alike), ``moe_decode_experts_touched`` (held experts that got a row) and
-  ``moe_decode_max_load`` (rows of the fullest held expert). They come from
-  ``Cache.moe_load``, a few KB copied out of the cache at dispatch (the
-  next dispatch donates the cache) and read in ``emit`` with that step's
-  tokens, a step later;
-- for a model with a latent cache (0 otherwise), per decode step and a layer:
-  ``mla_decode_live_tokens`` (positions the active slots attend over) and
-  ``mla_decode_read_tokens`` (positions of the pages ``mla_decode`` is given:
-  the live ones rounded up to whole pages);
-- for a model that keeps state by kind of layer (``layer_kinds``: pages for
-  its "full" layers, window rings and, in a decoder-hybrid-decoder, recurrent
-  rows by slot, or a gated short convolution's two rows by slot, which the
-  program keeps and the host has nothing to count for; 0 otherwise):
-  ``shared_kv_live_tokens`` and
-  ``shared_kv_read_tokens`` (the same two for a paged layer, counted once a
-  decode step, not once a layer that reads pages), ``window_live_tokens``
-  (filled ring entries the active slots attend over, a step and window layer)
-  and, for a decoder-hybrid-decoder alone, ``prefill_cross_rows`` (rows the
-  cross-decoder computed in prefill: one a row of a call, against
-  ``prefill_batch_tokens`` for the self-decoder). For a model with Mamba-2
-  layers (``"mamba2"``), per decode step (riding ones too) and such layer:
-  ``ssd_step_slots`` (the slots whose state the step reads and writes: all
-  of them, ``ops/ssd.py:ssd_step`` walks every slot) and
-  ``ssd_step_live_slots`` (those of them that decode). For a model with
-  delta-rule layers (``"kda"``) the same two under ``kda_step_slots`` /
-  ``kda_step_live_slots`` (``ops/kda.py:kda_step`` walks every slot too)
-  and, per prefill call, ``kda_scan_chunks`` (the chunks of ``CHUNK``
-  positions ``kda_scan``'s grid has a head and such layer: the call's ``R x
-  S / CHUNK``) and ``kda_scan_chunks_skipped`` (those of them that lie
-  wholly behind their row's length, a padding row's all: the kernel passes
-  over them). Its latent layers count under ``mla_decode_*`` as a latent
-  model's do. For a model with power-retention layers (``"retention"``), per
-  decode step (riding ones too) and such layer: ``retention_state_slots``
-  (the slots whose state the step reads: all of them, both passes of
-  ``ops/retention.py`` walk every slot) and
-  ``retention_live_slots`` (those of them that decode); per decode step
-  (riding ones too) ``retention_steps`` (``decode_steps`` again, 0 for a
-  model without such layers) and ``retention_fold_steps``, those of them
-  that also WROTE every slot's state back (``retention_step``: the step
-  that found ``FOLD - 1`` positions pending, and every riding step; the
-  others ran ``retention_read``), counted by the program's own rule on the
-  host's mirror of ``Cache.pending_count``: a quarter of the steps where
-  few ride; and, per prefill
-  call, ``retention_scan_chunks`` / ``retention_scan_chunks_skipped``
-  (``ops/retention.py:scan_chunks``: the chunks ``retention_scan``'s grid has
-  a head and such layer, and those wholly behind their row's length). A
-  model of such layers alone keeps nothing by position: its requests are
-  still handed pages and give them back (the accounting is every model's and
-  costs nothing), and the pages address nothing. For every model that has
-  an attention layer, per prefill call: ``flash_q_blocks`` (the query
-  blocks ``flash_fwd``'s grid has a head for the call's ``[R, S]``,
-  ``ops/attention.py:q_blocks``: ``R x S / 512`` from 512 positions on, one
-  a row under that) and ``flash_q_blocks_skipped`` (those of them that lie
-  wholly behind their row's length, a padding row's all: the kernel passes
-  over them, in every attention layer of the prompt side alike).
+  (a carrying prefill call's ``moe_load`` mixes prompt and decode rows and is
+  not read): ``moe_decode_layer_steps`` (those calls x expert layers) and,
+  summed over those, ``moe_decode_routed_assignments`` (active slots x
+  top_k), ``moe_decode_assignments`` (those that fell on an expert HELD here,
+  ``TransformerConfig.experts_held``), ``moe_decode_zero_assignments`` (on a
+  zero-compute expert, ``zero_experts``), ``moe_decode_experts_touched``
+  (held experts that got a row) and ``moe_decode_max_load`` (rows of the
+  fullest held expert). They come from ``Cache.moe_load``, a few KB copied
+  out of the cache at dispatch (the next dispatch donates the cache) and read
+  in ``emit`` with that step's tokens, a step later;
+- what the model's kinds of layer count (``llm/kinds/``: a record's
+  ``counters``, counted and defined by its ``Host``; every kind's keys are
+  here at 0 whatever the model): what a decode step attends over and reads
+  through the block tables and the rings (``mla_decode_*``, ``shared_kv_*``,
+  ``window_live_tokens``), the slots whose recurrent state a step moves
+  (``ssd_step_*``, ``kda_step_*``, ``retention_*_slots``, with
+  ``retention_steps`` and ``retention_fold_steps``), the chunks a prefill
+  call's scan has and passes over (``kda_scan_chunks*``,
+  ``retention_scan_chunks*``) and ``prefill_cross_rows``. A model of
+  recurrent layers alone keeps nothing by position: its requests are still
+  handed pages and give them back (the accounting is every model's and costs
+  nothing), and the pages address nothing;
+- for every model that has an attention layer, per prefill call:
+  ``flash_q_blocks`` (the query blocks ``flash_fwd``'s grid has a head for
+  the call's ``[R, S]``, ``ops/attention.py:q_blocks``: ``R x S / 512`` from
+  512 positions on, one a row under that) and ``flash_q_blocks_skipped``
+  (those of them that lie wholly behind their row's length, a padding row's
+  all: the kernel passes over them, in every attention layer of the prompt
+  side alike).
 
 Such a model's rings and rows need no allocator: a slot owns its own, a
 prefill call overwrites all of them from the prompt (the engine tells it the
@@ -302,8 +241,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ray_tpu.llm import prefill_shapes
+from ray_tpu.llm import kinds, prefill_shapes
 from ray_tpu.llm.config import EngineConfig, LLMConfig, SamplingParams
+from ray_tpu.llm.kinds import KINDS
 from ray_tpu.llm.tokenizer import get_tokenizer
 from ray_tpu.util import goodput, tracing
 
@@ -488,16 +428,18 @@ class JaxLLMEngine:
                 f"the deployment expects a latent cache of rank "
                 f"{self.ecfg.expect_latent_rank}, the model has "
                 f"{self.mcfg.kv_latent_rank}")
-        kinds = self.mcfg.layer_kinds
-        self._ssd_layers = kinds.count("mamba2")
-        self._kda_layers = kinds.count("kda")
-        self._retention_layers = kinds.count("retention")
-        # the host's copy of Cache.pending_count (power retention: the decode
-        # steps since the states were last written back)
-        self._pending_positions = 0
-        state_layers = (kinds.count("mamba") + kinds.count("conv")
-                        + self._ssd_layers + self._kda_layers
-                        + self._retention_layers)
+        # the host's side of the model's kinds of layer, what each counts
+        of = kinds.of(self.mcfg)
+        self._kinds: Dict[str, kinds.Host] = {
+            kind: KINDS[kind].Host(self.mcfg, of.count(kind),
+                                   self.ecfg.page_size)
+            for kind in dict.fromkeys(of)}
+        # has it a recurrent kind that counts the slots a step moves
+        self._counts_slots = any(
+            KINDS[kind].recurrent and KINDS[kind].counters
+            for kind in self._kinds)
+        state_layers = sum(host.layers for kind, host in self._kinds.items()
+                           if KINDS[kind].recurrent)
         if state_layers != self.ecfg.expect_state_layers:
             raise ValueError(
                 f"the deployment expects {self.ecfg.expect_state_layers} "
@@ -517,7 +459,8 @@ class JaxLLMEngine:
                 f"the deployment expects delta-rule layers of "
                 f"{self.ecfg.expect_kda_heads} heads, the model's have "
                 f"{self.mcfg.kda_heads}")
-        retention_heads = self.mcfg.n_kv_heads * bool(self._retention_layers)
+        retention_heads = self.mcfg.n_kv_heads * bool(
+            self.mcfg.retention_degree)
         if retention_heads != self.ecfg.expect_retention_heads:
             raise ValueError(
                 f"the deployment expects power-retention layers of "
@@ -622,15 +565,9 @@ class JaxLLMEngine:
             "moe_decode_experts_touched": 0, "moe_decode_max_load": 0,
             "moe_decode_routed_assignments": 0,
             "moe_decode_zero_assignments": 0,
-            "mla_decode_live_tokens": 0, "mla_decode_read_tokens": 0,
-            "shared_kv_live_tokens": 0, "shared_kv_read_tokens": 0,
-            "window_live_tokens": 0, "prefill_cross_rows": 0,
-            "ssd_step_slots": 0, "ssd_step_live_slots": 0,
-            "kda_step_slots": 0, "kda_step_live_slots": 0,
-            "kda_scan_chunks": 0, "kda_scan_chunks_skipped": 0,
-            "retention_state_slots": 0, "retention_live_slots": 0,
-            "retention_steps": 0, "retention_fold_steps": 0,
-            "retention_scan_chunks": 0, "retention_scan_chunks_skipped": 0,
+            # what the kinds of layer count, every kind's whatever the model
+            **dict.fromkeys((name for kind in KINDS.values()
+                             for name in kind.counters), 0),
             "flash_q_blocks": 0, "flash_q_blocks_skipped": 0}
         # span attribute of decode_dispatch; none for a dense model
         self._experts_attr: Dict[str, float] = {}
@@ -994,8 +931,6 @@ class JaxLLMEngine:
             m["prefill_calls"] += len(calls)
             m["prefill_tokens"] += real
             m["prefill_batch_tokens"] += positions
-            if self.mcfg.sambay:  # the cross-decoder ran one row a row of a call
-                m["prefill_cross_rows"] += rows
             self._active[slots] = True
             self._sent(firsts, admitted + riders, "prefill", len(calls),
                        bucket, positions=positions, real_positions=real)
@@ -1008,7 +943,7 @@ class JaxLLMEngine:
                 # positions the step attends over, through the block tables
                 attrs["live_tokens"] = int(
                     (self._seq_lens[self._active] + 1).sum())
-            if self._ssd_layers or self._kda_layers or self._retention_layers:
+            if self._counts_slots:
                 # live slots whose state the step moves
                 attrs["state_slots"] = int(self._active.sum())
             load = None
@@ -1090,18 +1025,6 @@ class JaxLLMEngine:
             blocks, skipped = q_blocks(S, lens)
             self.metrics["flash_q_blocks"] += blocks
             self.metrics["flash_q_blocks_skipped"] += skipped
-        if self._kda_layers:  # the grid steps of kda_scan, and those it skips
-            from ray_tpu.ops.kda import scan_chunks
-
-            chunks, skipped = scan_chunks(S, lens)
-            self.metrics["kda_scan_chunks"] += chunks
-            self.metrics["kda_scan_chunks_skipped"] += skipped
-        if self._retention_layers:  # retention_scan's grid steps likewise
-            from ray_tpu.ops.retention import scan_chunks
-
-            chunks, skipped = scan_chunks(S, lens)
-            self.metrics["retention_scan_chunks"] += chunks
-            self.metrics["retention_scan_chunks_skipped"] += skipped
         if self.mcfg.layer_kinds:  # a model that keeps state by slot is told
             rows.append(where)
         carries = self._carries(R, S)
@@ -1132,9 +1055,9 @@ class JaxLLMEngine:
                 carries = carry = False
                 logits, cache = prefill(self.cache, *rows[:-1])
             self.cache, buffer = cache, self._prefill_logits
+            for host in self._kinds.values():
+                host.count_prompt(self.metrics, S, lens, carries)
             if carries:
-                # its step side folded, whether or not a slot rode it
-                self._pending_positions = 0
                 logits, step_logits = logits
                 if carry:
                     buffer = step_logits
@@ -1151,32 +1074,11 @@ class JaxLLMEngine:
 
     def _count_decode_reads(self, riding: bool = False) -> None:
         """What the decode step being dispatched (alone, or ``riding`` a
-        prefill call) attends over, into the counters of the model's kind."""
-        if self.mcfg.kv_latent_rank:
-            self._count_paged_reads("mla_decode")
-        elif self.mcfg.layer_kinds and self._page_leaves():
-            self._count_paged_reads("shared_kv")
-            self.metrics["window_live_tokens"] += int(np.minimum(
-                self._seq_lens[self._active] + 1, self.mcfg.window).sum())
-        # a step moves every slot's state in each Mamba-2 or delta-rule layer
-        for name, layers in (("ssd", self._ssd_layers),
-                             ("kda", self._kda_layers)):
-            self.metrics[name + "_step_slots"] += layers * len(self._slots)
-            self.metrics[name + "_step_live_slots"] += (
-                layers * int(self._active.sum()))
-        self.metrics["retention_state_slots"] += (
-            self._retention_layers * len(self._slots))
-        self.metrics["retention_live_slots"] += (
-            self._retention_layers * int(self._active.sum()))
-        if self._retention_layers:
-            # the program's rule (ops/retention.py:retention_decode, advance)
-            # on the host's copy of the count it keeps in the cache
-            from ray_tpu.ops.retention import FOLD
-
-            fold = riding or self._pending_positions >= FOLD - 1
-            self.metrics["retention_steps"] += 1
-            self.metrics["retention_fold_steps"] += fold
-            self._pending_positions = 0 if fold else self._pending_positions + 1
+        prefill call) reads and moves, into the counters of the model's
+        kinds of layer."""
+        lens = self._seq_lens[self._active]
+        for host in self._kinds.values():
+            host.count_step(self.metrics, len(self._slots), lens, riding)
 
     def _count_slot_steps(self, prefilling: int = 0) -> None:
         """Every slot's row of the decode step being dispatched (alone, or
@@ -1292,20 +1194,6 @@ class JaxLLMEngine:
                 req.in_flight -= 1
         self._unread.clear()
         self._earlier = 0
-
-    def _count_paged_reads(self, prefix: str) -> None:
-        """What the decode step about to run attends over (``live``: positions
-        0..seq_len of every active slot) and what it reads for that (``read``:
-        the positions of the pages ``ops/mla.py:live_pages`` lists, an
-        inactive slot's one step over the scratch page included), for ONE
-        layer that reads them, into ``<prefix>_live_tokens`` / ``_read_tokens``."""
-        P = self.ecfg.page_size
-        lens = self._seq_lens[self._active].astype(np.int64)
-        live = int((lens + 1).sum())
-        read = int(((lens // P + 1) * P).sum()) \
-            + P * int((~self._active).sum())
-        self.metrics[prefix + "_live_tokens"] += live
-        self.metrics[prefix + "_read_tokens"] += read
 
     def _count_routing(self, load: np.ndarray, rows: int) -> None:
         """``load`` [expert layers, E]: real rows per expert HELD here in one
@@ -1426,17 +1314,17 @@ class JaxLLMEngine:
             "finish_reason": req.finish_reason,
             "params": req.params,
         }
-        # the cache's page leaves under their own names: "k" and "v", or a
-        # latent model's "rows"
-        for name in self._page_leaves():
-            state[name] = np.asarray(getattr(self.cache, name)[:, pages])
+        # the paged kinds' states under the kinds' names, each as the cache
+        # holds it with the request's pages alone
+        for kind in self._page_leaves():
+            state[kind] = self._jax.tree.map(
+                lambda leaf: np.asarray(leaf[:, pages]), self.cache[kind])
         self.abort_request(request_id)
         return state
 
     def _page_leaves(self) -> List[str]:
-        """The leaves this model's block tables address."""
-        return [name for name in self._mr.PAGE_LEAVES
-                if getattr(self.cache, name) is not None]
+        """The kinds whose state this model's block tables address."""
+        return [kind for kind in self.cache.states if KINDS[kind].paged]
 
     def _refuse_state_by_slot(self, what: str) -> None:
         """A request's state is a gather of its pages only where pages are
@@ -1462,7 +1350,7 @@ class JaxLLMEngine:
         self._drain()
         free_slots = [i for i, s in enumerate(self._slots) if s is None]
         leaves = self._page_leaves()
-        n_pages = state[leaves[0]].shape[1]
+        n_pages = self._jax.tree.leaves(state[leaves[0]])[0].shape[1]
         if not free_slots or len(self._free_pages) < n_pages:
             raise RuntimeError("decode engine has no capacity; retry")
         req = _Request(state["request_id"], list(state["prompt_tokens"]),
@@ -1475,9 +1363,10 @@ class JaxLLMEngine:
         req.slot = free_slots[0]
         req.pages = [self._free_pages.popleft() for _ in range(n_pages)]
         pages = jnp.asarray(np.asarray(req.pages, np.int32))
-        self.cache = self.cache._replace(**{
-            name: getattr(self.cache, name).at[:, pages].set(
-                jnp.asarray(state[name])) for name in leaves})
+        self.cache = self.cache.replace({
+            kind: self._jax.tree.map(
+                lambda leaf, new: leaf.at[:, pages].set(jnp.asarray(new)),
+                self.cache[kind], state[kind]) for kind in leaves})
         row = self._block_tables[req.slot]
         row[:] = 0
         row[:n_pages] = req.pages

@@ -53,9 +53,6 @@ from ray_tpu.llm import model_runner
 
 logger = logging.getLogger(__name__)
 
-export.register_namedtuple_serialization(
-    model_runner.Cache, serialized_name="ray_tpu.llm.Cache")
-
 # programs compiled at a time: a cold compile is 15-20 s of one core, and the
 # cores are the serving path's too
 _COMPILERS = 4

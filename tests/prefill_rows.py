@@ -16,15 +16,36 @@ import numpy as np
 
 from ray_tpu.llm.config import SamplingParams
 
-# where a cache leaf keeps a page or a slot: (axis, "page" | "slot")
-_LEAVES = {"k": (1, "page"), "v": (1, "page"), "rows": (1, "page"),
-           "pages": (1, "page"), "rings": (1, "slot"), "ssm": (1, "slot"),
-           "conv": (2, "slot")}
+# where a leaf of a kind's state keeps a page or a slot: (axis, "page" |
+# "slot"), under the kind's name or, a state of several, "<kind>.<field>"
+_LEAVES = {"dense.k": (1, "page"), "dense.v": (1, "page"),
+           "latent": (1, "page"), "full": (1, "page"), "window": (1, "slot"),
+           "conv": (2, "slot"), "retention.state": (1, "slot"),
+           **{f"{kind}.{field}": (axis, "slot")
+              for kind in ("mamba", "mamba2", "kda")
+              for field, axis in (("state", 1), ("tail", 2))}}
 
 
 def held(cache) -> set:
-    """The names of the leaves a model's cache holds (the rest are None)."""
-    return {name for name, leaf in cache._asdict().items() if leaf is not None}
+    """The kinds whose state a model's cache holds, and "moe_load" where it
+    has one."""
+    return set(cache.states) | (
+        set() if cache.moe_load is None else {"moe_load"})
+
+
+def named(cache) -> dict:
+    """name -> leaf of every leaf of ``cache``: "<kind>", or "<kind>.<field>"
+    where a kind's state is a NamedTuple, and "moe_load"."""
+    out = {} if cache.moe_load is None else {"moe_load": cache.moe_load}
+    for kind, state in cache.states.items():
+        fields = getattr(state, "_asdict", lambda: {"": state})()
+        out.update({f"{kind}.{f}".rstrip("."): x for f, x in fields.items()})
+    return out
+
+
+def leaf(cache, name):
+    """The leaf ``_LEAVES`` names, or None where the cache has no such."""
+    return named(cache).get(name)
 
 
 def kernels(compiled) -> collections.Counter:
@@ -90,19 +111,19 @@ def padding_rows_write_nothing(eng, prompt, slot=1, first_page=2):
         of every leaf that keeps pages or slots."""
         mine, rest = {}, {}
         for name, (axis, kind) in _LEAVES.items():
-            leaf = getattr(cache, name, None)
-            if leaf is None:
+            got = leaf(cache, name)
+            if got is None:
                 continue
-            leaf = np.moveaxis(np.asarray(leaf, np.float32), axis, 0)
-            keep = np.ones(len(leaf), bool)
+            got = np.moveaxis(np.asarray(got, np.float32), axis, 0)
+            keep = np.ones(len(got), bool)
             if kind == "page":
                 keep[0] = False
                 keep[own] = False
-                mine[name] = leaf[own]
+                mine[name] = got[own]
             elif told:  # rings and rows belong to the slot prefill was told
                 keep[slot] = False
-                mine[name] = leaf[slot]
-            rest[name] = leaf[keep]
+                mine[name] = got[slot]
+            rest[name] = got[keep]
         return mine, rest
 
     one, cache1, _ = call([True])
@@ -126,10 +147,10 @@ def padding_rows_write_nothing(eng, prompt, slot=1, first_page=2):
 
     logits, cache0, slots = call([False, False])
     for name, (axis, kind) in _LEAVES.items():
-        leaf = getattr(cache0, name, None)
-        if leaf is not None:
-            leaf = np.moveaxis(np.asarray(leaf, np.float32), axis, 0)
-            assert (leaf[1 if kind == "page" else 0:] == 3).all(), name
+        got = leaf(cache0, name)
+        if got is not None:
+            got = np.moveaxis(np.asarray(got, np.float32), axis, 0)
+            assert (got[1 if kind == "page" else 0:] == 3).all(), name
     if cache0.moe_load is not None:
         assert not np.asarray(cache0.moe_load).any()
     buffer = jnp.full((B, mcfg.vocab_size), 7.0, jnp.float32)
@@ -213,10 +234,10 @@ def riders_equal_a_step_after_the_call(eng, rng, tol=1e-4, settle=None):
     close(step2[0], step1[0], "the step's logits")
     assert step2.shape == (B, V) and logits2.shape == (2, V)
     for name, (axis, kind) in _LEAVES.items():
-        if getattr(c2, name, None) is None:
+        if leaf(c2, name) is None:
             continue
         got, after, before, then = (
-            np.moveaxis(np.asarray(getattr(c, name), np.float32), axis, 0)
+            np.moveaxis(np.asarray(leaf(c, name), np.float32), axis, 0)
             for c in (c2, c1, found, filled))
         assert np.abs(after - before).max() > 0, name
         if kind == "page":  # but the scratch page

@@ -99,7 +99,7 @@ def _engine_logits(eng, seqs, prompt_lens, steps, bucket=16, slots=None,
     tables = np.zeros((B, MP), np.int32)
     active = np.zeros(B, bool)
     cache = mr.init_cache(cfg, e.num_pages, e.page_size, B)
-    assert prefill_rows.held(cache) == {"pages", "rings", "moe_load"}
+    assert prefill_rows.held(cache) == {"full", "window", "moe_load"}
     got, page = {}, first_page
     for s, toks, n in zip(slots, seqs, prompt_lens):
         need = -(-len(toks) // e.page_size)
@@ -347,13 +347,13 @@ def test_cache_manager_places_state_to_the_bit(engine, model):
     e, cfg = eng.ecfg, eng.mcfg
     cache = mr.init_cache(cfg, e.num_pages, e.page_size, e.max_num_seqs)
     kinds = cfg.layer_kinds
-    assert cache.pages.shape[:3] == (kinds.count("full"), e.num_pages,
+    assert cache["full"].shape[:3] == (kinds.count("full"), e.num_pages,
                                      e.page_size)
-    assert cache.rings.shape[:3] == (kinds.count("window"), e.max_num_seqs,
+    assert cache["window"].shape[:3] == (kinds.count("window"), e.max_num_seqs,
                                      cfg.window)
-    assert (cache.ssm is None) == ("mamba" not in kinds)
+    assert ("mamba" in cache) == ("mamba" in kinds)
     # a ring is read in blocks of a page where the window is whole pages
-    assert mr._ring_blocks(cache.rings, cfg, e.page_size).shape[1:3] == (
+    assert mr._ring_blocks(cache["window"], cfg, e.page_size).shape[1:3] == (
         e.max_num_seqs * (cfg.window // e.page_size), e.page_size)
 
 

@@ -29,6 +29,7 @@ from ray_tpu.llm import LLMConfig
 from ray_tpu.llm import model_runner as mr
 from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.engine import JaxLLMEngine
+from ray_tpu.llm.kinds import KINDS
 from ray_tpu.models.transformer import CONFIGS, Transformer, TransformerConfig
 from ray_tpu.ops import retention as rt
 
@@ -367,15 +368,16 @@ def test_engine_matches_reference(engine, prompt_len, bucket):
     want = _reference(engine, toks)[prompt_len - 1:]
     assert _rel(got, want) < TOL, _rel(got, want)
     c = run.cache
-    assert c.ssm.shape == (2, 3 + 1, KVH, HD // 2 + 2, HD, HD)
-    assert c.ssm.dtype == jnp.float32
-    assert all(getattr(c, name) is None for name in mr.PAGE_LEAVES)
-    assert c.conv is None and c.rings is None and c.moe_load is None
-    assert c.pending.shape == (2, rt.FOLD - 1, 3, 3, KVH, HD)
-    assert c.pending.dtype == jnp.float32 and c.pending_count.dtype == jnp.int32
-    assert int(c.pending_count) == 10 % rt.FOLD    # ten steps: two folds
+    state, pending, count = c["retention"]
+    assert state.shape == (2, 3 + 1, KVH, HD // 2 + 2, HD, HD)
+    assert state.dtype == jnp.float32
+    assert not any(KINDS[kind].paged for kind in c.states)
+    assert set(c.states) == {"retention"} and c.moe_load is None
+    assert pending.shape == (2, rt.FOLD - 1, 3, 3, KVH, HD)
+    assert pending.dtype == jnp.float32 and count.dtype == jnp.int32
+    assert int(count) == 10 % rt.FOLD    # ten steps: two folds
     assert mr._page_size(c) == 0
-    held = np.abs(np.asarray(c.ssm)).max(axis=(2, 3, 4, 5))
+    held = np.abs(np.asarray(state)).max(axis=(2, 3, 4, 5))
     assert held[:, 2].min() > 0
 
 
@@ -415,7 +417,7 @@ def test_every_slot_prefill_call(engine):
     logits, run.cache = mr.prefill(
         engine.params, cfg, run.cache, jnp.asarray(batch),
         jnp.asarray([11, 0, 0], jnp.int32), jnp.asarray(run.tables))
-    assert not np.asarray(run.cache.ssm)[:, 1:].any()
+    assert not np.asarray(run.cache["retention"].state)[:, 1:].any()
     run.active[0], run.lens[0] = True, 11
     got = [np.asarray(logits[0])] + [run.decode({0: t})[0] for t in toks[11:]]
     assert _rel(np.stack(got), _reference(engine, toks)[10:]) < TOL
@@ -429,7 +431,7 @@ def test_bfloat16_engine_is_one_rounding_a_product():
     toks = np.random.default_rng(8).integers(0, VOCAB, 13 + 4)
     run = _Run(eng)
     got = run.sequence(1, toks, 13)
-    assert run.cache.ssm.dtype == jnp.float32
+    assert run.cache["retention"].state.dtype == jnp.float32
     assert _rel(got, _reference(eng, toks)[12:]) < 1e-1
 
 
@@ -437,6 +439,11 @@ def test_bfloat16_engine_is_one_rounding_a_product():
 
 
 # the program's own faults with what a slot keeps between two folds: the
+def _with(cache, **leaves):
+    """``cache`` with these leaves of its "retention" state replaced."""
+    return cache.replace({"retention": cache["retention"]._replace(**leaves)})
+
+
 # pending positions dropped before the fold reads them, and left standing
 # behind it (read again until the next positions overwrite them)
 PENDING_FAULTS = ("pending_dropped", "pending_twice")
@@ -448,14 +455,14 @@ class _FaultyRun(_Run):
         self.fault = fault
 
     def decode(self, tokens):
-        c = self.cache
-        folds = int(c.pending_count) == rt.FOLD - 1
+        c = self.cache["retention"]
+        folds = int(c.count) == rt.FOLD - 1
         if folds and self.fault == "pending_dropped":
-            self.cache = c._replace(pending=jnp.zeros_like(c.pending))
+            self.cache = _with(self.cache, pending=jnp.zeros_like(c.pending))
         held = jnp.copy(c.pending)
         out = super().decode(tokens)
         if folds and self.fault == "pending_twice":
-            self.cache = self.cache._replace(pending=held)
+            self.cache = _with(self.cache, pending=held)
         return out
 
 
@@ -517,14 +524,14 @@ def test_slot_refilled_between_two_folds(engine, emptied):
     gone, toks = rng.integers(0, VOCAB, 9 + 2), rng.integers(0, VOCAB, 5 + 6)
     run = _Run(engine)
     run.sequence(1, gone, 9)
-    found = jnp.copy(run.cache.pending)   # the call donates the cache
-    assert int(run.cache.pending_count) == 2
+    found = jnp.copy(run.cache["retention"].pending)   # the call donates it
+    assert int(run.cache["retention"].count) == 2
     assert np.asarray(found[:, :2, :, 1]).any()
     first = run.prefill(1, toks[:5])
-    assert int(run.cache.pending_count) == 2    # a call alone counts nothing
-    assert not np.asarray(run.cache.pending[:, :, :, 1]).any()
+    assert int(run.cache["retention"].count) == 2    # a call alone counts nothing
+    assert not np.asarray(run.cache["retention"].pending[:, :, :, 1]).any()
     if not emptied:
-        run.cache = run.cache._replace(pending=found)
+        run.cache = _with(run.cache, pending=found)
     got = np.stack([first] + [run.decode({1: t})[1] for t in toks[5:]])
     err = _rel(got, _reference(engine, toks)[4:])
     assert err < TOL if emptied else err > 10 * TOL, err
@@ -538,16 +545,16 @@ def _settled(cache):
     (a null position for everybody: what a step beside a prompt does to a
     slot that does not decode) and none left: the form a carried step leaves
     a cache in, so that a cache a step alone left can be laid beside it."""
-    ssm, pending = cache.ssm, cache.pending
+    ssm, pending, count = cache["retention"]
     B = pending.shape[3]
     nobody = jnp.zeros((B,), bool)
     for layer in range(ssm.shape[0]):
         _, ssm, pending = rt.retention_decode(
-            ssm, pending, cache.pending_count, layer,
+            ssm, pending, count, layer,
             jnp.ones((B, H, HD)), *jnp.zeros((2, B, KVH, HD)),
             jnp.zeros((B, KVH)), nobody, riding=True)
-    return cache._replace(ssm=ssm, pending=pending,
-                          pending_count=jnp.zeros_like(cache.pending_count))
+    return _with(cache, state=ssm, pending=pending,
+                 count=jnp.zeros_like(count))
 
 
 def test_decode_rows_ride_a_prefill_call(engine):
@@ -608,7 +615,7 @@ def test_engine_serves_preempts_and_counts_the_states_it_moves():
     assert m["riding_steps"] <= m["retention_fold_steps"] \
         <= m["riding_steps"] + plain // rt.FOLD
     assert m["retention_fold_steps"] >= m["decode_steps"] // rt.FOLD
-    assert int(eng.cache.pending_count) == eng._pending_positions
+    assert int(eng.cache["retention"].count) == eng._kinds["retention"].pending
     assert 0 < m["retention_live_slots"] <= m["retention_state_slots"]
     assert m["kda_step_slots"] == m["ssd_step_slots"] == 0
     assert m["shared_kv_live_tokens"] == m["mla_decode_live_tokens"] == 0
@@ -643,11 +650,11 @@ def test_admitted_at_every_count_preempted_and_ridden_between_folds():
     alone = [eng.generate([p], sp, decode_text=False)[0].token_ids
              for p, sp in zip(prompts, sps)]
     before, got, found = dict(eng.metrics), {}, []
-    left, start = list(enumerate(prompts)), eng._pending_positions
+    left, start = list(enumerate(prompts)), eng._kinds["retention"].pending
     for _ in range(400):
         if not (left or eng.has_unfinished()):
             break
-        count = eng._pending_positions
+        count = eng._kinds["retention"].pending
         wanted = (start + len(prompts) - len(left)) % rt.FOLD
         if left and count == wanted and None in eng._slots \
                 and not eng._waiting:
@@ -658,7 +665,7 @@ def test_admitted_at_every_count_preempted_and_ridden_between_folds():
             if out.finished:
                 got[out.request_id] = out.token_ids
         found += [count] * (eng.metrics["admitted"] - admitted)
-        assert int(eng.cache.pending_count) == eng._pending_positions
+        assert int(eng.cache["retention"].count) == eng._kinds["retention"].pending
     assert not left and not eng.has_unfinished()
     assert [got[f"r{i}"] for i in range(len(prompts))] == alone
     d = {k: eng.metrics[k] - v for k, v in before.items()}

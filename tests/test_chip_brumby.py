@@ -143,8 +143,8 @@ def main(argv=()) -> dict:
 
     out = {"device": jax.devices()[0].device_kind, "seed": SEED, "tol": TOL,
            "initializer": conf["initializer"], "calls_carrying": carrying,
-           "state_shape": list(eng.cache.ssm.shape),
-           "state_dtype": str(eng.cache.ssm.dtype),
+           "state_shape": list(eng.cache["retention"].state.shape),
+           "state_dtype": str(eng.cache["retention"].state.dtype),
            "paged_leaves": eng._page_leaves(),
            "long_call_chunks": scan_chunks(eng._prefill_bucket(LONG[0]),
                                            [LONG[0]])}
@@ -161,7 +161,7 @@ def main(argv=()) -> dict:
             return getattr(mr, name)
 
         def decode_step(self, params, cfg, cache, *rows):
-            self.seen.append(int(cache.pending_count))
+            self.seen.append(int(cache["retention"].count))
             return mr.decode_step(params, cfg, cache, *rows)
 
     eng._mr = Counting()

@@ -6,10 +6,10 @@ the tiling, too much fast memory in a kernel, a Mosaic kernel the
 partitioner is asked to split. A compile that passes is not a chip run:
 nothing here executes, so nothing here says a result is right or fast.
 
-All of them live in THIS one file and compile in the test's own process:
-the process that describes the topology loads the TPU's library and keeps it
-until it exits, so a second file (another xdist worker) or a child process
-could not. The topology is described inside a fixture, never at import.
+This file holds the kernels, the train step and the two serve cells without
+``layer_kinds`` (and the latent one); each serve configuration with kinds of
+state has a file of its own, ``test_chip_compile_<model>.py``, and
+``tests/chip_compile.py`` holds what they share.
 """
 
 import dataclasses
@@ -19,35 +19,10 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
+from chip_compile import (_float32_rows_a_choice, _live, _on,  # noqa: F401
+                          one_chip, topo)
 from ray_tpu.models import CONFIGS
-
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        desc = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # an executable for a described chip is written to the persistent cache
-    # but cannot be read back without the chip: keep these compiles out
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
 
 
 def _qkv(shape, sharding):
@@ -84,13 +59,6 @@ def _custom_calls(text):
     ``%jvp_flash_fwd_.1``."""
     return [line.split(" = ")[0] for line in text.splitlines()
             if "tpu_custom_call" in line]
-
-
-def _float32_rows_a_choice(text, top_k, hidden):
-    """The float32 ``[T, top_k, hidden]`` values of a compiled program: what
-    the expert layer's way back laid out before ``ops/moe.py`` summed a prefill
-    call's rows choice by choice (PR 46); a prefill program holds none."""
-    return re.findall(rf"= f32\[\d+,{top_k},{hidden}\]", text)
 
 
 def _flash_grads(q, k, v):
@@ -284,12 +252,6 @@ def test_train_step_hands_the_flash_kernels_the_projections_layout(topo):
     assert len(copies) <= 8 * layers, len(copies)
 
 
-def _on(tree, sharding):
-    return jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
-        tree)
-
-
 # the sparse serve cell's widths (OLMoE-1B-7B): 16/16 heads of 128 with the
 # q/k norm, 64 experts of width 1024, top-8 without renormalisation, bfloat16
 SPARSE = dict(n_kv_heads=16, d_ff=1024, n_experts=64, experts_per_token=8,
@@ -413,7 +375,8 @@ def test_engine_writes_the_kv_cache_in_place(one_chip, program, sparse):
     cache, lower = _lower_engine(one_chip, n_layers=4, bucket=256,
                                  sparse=sparse)
     compiled = lower[program]().compile()
-    L, NP, P, KVH, HD = cache.k.shape
+    k = cache["dense"].k
+    L, NP, P, KVH, HD = k.shape
     made = re.findall(r"^\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(",
                       compiled.as_text(), re.M)
     whole = f"bf16[{L},{NP},{P},{KVH},{HD}]"
@@ -429,7 +392,7 @@ def test_engine_writes_the_kv_cache_in_place(one_chip, program, sparse):
                for res, op in made) == 2 * L
     load = 0 if cache.moe_load is None else cache.moe_load.size * 4
     assert 0 <= compiled.memory_analysis().alias_size_in_bytes \
-        - 2 * cache.k.size * cache.k.dtype.itemsize <= load
+        - 2 * k.size * k.dtype.itemsize <= load
 
 
 def _sds(tree, shardings):
@@ -630,12 +593,6 @@ def _lower_latent(one_chip, n_layers):
         params, cfg, cache, i32(B), i32(B), i32(B, MP), active)
 
 
-def _live(compiled):
-    m = compiled.memory_analysis()
-    return (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes), m.temp_size_in_bytes
-
-
 def test_latent_decode_compiles_with_the_mla_kernel(one_chip):
     """Decode at 32 slots, the dense layer and one sparse one: one
     ``mla_decode`` call a layer under the name the trace's metrics read,
@@ -653,12 +610,13 @@ def test_latent_decode_compiles_with_the_mla_kernel(one_chip):
                               text))) == 3
     assert text.count("tpu_custom_call") == 5
     live, temp = _live(compiled)
-    L, NP, P, W = cache.rows.shape
+    rows = cache["latent"]
+    L, NP, P, W = rows.shape
     print(f"latent decode, 2 layers, 32 slots: {live} bytes live, "
-          f"{temp} of temporaries; cache {cache.rows.size * 2}")
+          f"{temp} of temporaries; cache {rows.size * 2}")
     assert (P, W) == (256, 640)
     assert temp < NP * P * W * 2 // 4
-    assert compiled.memory_analysis().alias_size_in_bytes >= cache.rows.size * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= rows.size * 2
 
 
 @pytest.mark.parametrize("rows,bucket", [(1, 512), (1, 8192)],
@@ -685,529 +643,3 @@ def test_latent_prefill_compiles_with_unequal_head_sizes(one_chip, rows,
     print(f"latent prefill, 2 layers, [{rows}, {bucket}]: {live} bytes live, "
           f"{temp} of temporaries")
     assert 0 < live < 16 << 30
-
-
-# -- the hybrid model's programs (Phi-4-mini-flash-reasoning, the benchmark's file)
-
-
-def _lower_hybrid(one_chip, n_layers=8):
-    """The engine's programs at the published widths of
-    ``benchmarks/configs/phi-4-mini-flash-reasoning.json`` and its job block's
-    geometry (48 slots x 10240, pages of 512), depth cut to ``n_layers`` with
-    the pattern kept (8: Mamba, window, Mamba, window, the Mamba layer that
-    hands its memory on, full, a gated memory unit, cross)."""
-    import json
-
-    import flax.linen as nn
-
-    from benchmarks.jobs import common
-    from benchmarks.registry import REPO, architecture
-    from ray_tpu.llm import model_runner as mr
-    from ray_tpu.llm.config import EngineConfig
-    from ray_tpu.models.transformer import Transformer
-
-    with open(os.path.join(REPO, "benchmarks", "configs",
-                           "phi-4-mini-flash-reasoning.json")) as f:
-        conf = json.load(f)
-    e = EngineConfig(**conf["job"]["engine"])
-    cfg = dataclasses.replace(
-        common.transformer_config(conf, e.max_model_len), n_layers=n_layers,
-        layer_kinds=architecture(conf).layer_kinds(n_layers),
-        attention_impl="flash")
-    params = _on(jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))), one_chip)
-    cache = _on(jax.eval_shape(lambda: mr.init_cache(
-        cfg, e.num_pages, e.page_size, e.max_num_seqs)), one_chip)
-    B, MP = e.max_num_seqs, e.pages_per_seq
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
-
-    def prefill(bucket):
-        return mr.prefill.lower(params, cfg, cache, i32(1, bucket), i32(1),
-                                i32(1, MP), i32(1))
-
-    return cache, prefill, lambda: mr.decode_step.lower(
-        params, cfg, cache, i32(B), i32(B), i32(B, MP), active)
-
-
-def test_hybrid_decode_compiles_with_the_paged_kernels(one_chip):
-    """Decode at 48 slots, one period of the pattern: the paged kernel under
-    the two names the trace's metrics read (once for each window layer's
-    rings, once each for the full and the cross layer over the shared pages),
-    every kind of state written in place (the whole cache aliased) and no
-    copy of pages or rings made for a kernel: the temporaries stay far below
-    one window layer's rings (126 MB)."""
-    cache, _, decode = _lower_hybrid(one_chip)
-    compiled = decode().compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(r"%(window_gqa_decode\S*) = bf16\[48,10,16,128\]",
-                              text))) == 2
-    assert len(set(re.findall(r"%(paged_gqa_decode\S*) = bf16\[48,10,16,128\]",
-                              text))) == 2
-    assert text.count("tpu_custom_call") == 4
-    live, temp = _live(compiled)
-    held = sum(x.size * x.dtype.itemsize for x in (
-        cache.pages, cache.rings, cache.ssm, cache.conv))
-    print(f"hybrid decode, 8 layers, 48 slots: {live} bytes live, {temp} of "
-          f"temporaries; cache {held}")
-    assert cache.pages.shape == (1, 961, 512, 2560)
-    assert cache.rings.shape == (2, 48, 512, 2560)
-    assert cache.ssm.shape == (3, 48, 16, 5120)
-    assert temp < cache.rings.size * 2 // 2 // 4
-    assert compiled.memory_analysis().alias_size_in_bytes >= held
-
-
-@pytest.mark.parametrize("bucket", [256, 8192])
-def test_hybrid_prefill_compiles_with_scan_and_window(one_chip, bucket):
-    """The engine's [1, S] prefill at the mix's least and largest bucket: the
-    scan kernel in each Mamba layer, the flash kernel over 64-wide scores and
-    128-wide values in the window layers (key blocks left of the window
-    skipped) and in the full layer; the cross layer attends from one row and
-    needs none. What an execution holds live is printed (``-s``); the full
-    depth is in PERF.md section 4."""
-    _, prefill, _ = _lower_hybrid(one_chip)
-    compiled = prefill(bucket).compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(
-        rf"%(ssm_scan\S*) = \(f32\[1,{bucket},5120\]", text))) == 3
-    assert len(set(re.findall(
-        rf"%(flash_fwd\S*) = \(bf16\[40,{bucket},128\]", text))) == 3
-    assert text.count("tpu_custom_call") == 6
-    live, temp = _live(compiled)
-    print(f"hybrid prefill, 8 layers, [1, {bucket}]: {live} bytes live, "
-          f"{temp} of temporaries")
-    assert 0 < live < 16 << 30
-
-
-# -- one rank of Trinity-Large-Preview (afmoe, the benchmark's file) ----------------
-
-
-def _lower_rms_kinds(one_chip, name="trinity-large-preview"):
-    """The engine's programs at the published widths and the whole cut of a
-    configuration file with an ``"rms"`` block by kind: ``benchmarks/configs/
-    trinity-large-preview.json`` (5 layers, 32 of 256 experts held, 32 slots x
-    16896, pages of 512) unless another is named."""
-    e, cfg, params, cache = _rms_kinds(one_chip, name, "flash")
-    B, MP = e.max_num_seqs, e.pages_per_seq
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    active = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
-
-    step = (i32(B), i32(B), i32(B, MP), active)
-
-    def prefill(rows, bucket):
-        # the engine's call: told its slot and, where the call's rows do not
-        # dwarf the step's, carrying a decode step's rows (PR 42:
-        # ``engine._carries``); the benchmark's check gives neither
-        from ray_tpu.llm.engine import _RIDE_ROWS
-
-        told = (i32(1),) if rows == 1 else ()
-        if told and bucket <= _RIDE_ROWS * B:
-            told += (step,)
-        return mr.prefill.lower(params, cfg, cache, i32(rows, bucket),
-                                i32(rows), i32(rows, MP), *told)
-
-    from ray_tpu.llm import model_runner as mr
-    return cache, prefill, lambda: mr.decode_step.lower(
-        params, cfg, cache, *step)
-
-
-def _rms_kinds(one_chip, name, attention_impl):
-    """``benchmarks/configs/<name>.json`` as the engine holds it: its
-    geometry, the model, and the shapes of its parameters and cache on
-    ``one_chip``. ``attention_impl``: ``"auto"`` is what the cell runs (the
-    flash kernel where the program is traced for the chip), ``"flash"`` what a
-    lowering in this process, which has no chip, must be told."""
-    import json
-
-    import flax.linen as nn
-
-    from benchmarks.jobs import common
-    from benchmarks.registry import REPO
-    from ray_tpu.llm import model_runner as mr
-    from ray_tpu.llm.config import EngineConfig
-    from ray_tpu.models.transformer import Transformer
-
-    with open(os.path.join(REPO, "benchmarks", "configs",
-                           name + ".json")) as f:
-        conf = json.load(f)
-    e = EngineConfig(**conf["job"]["engine"])
-    cfg = dataclasses.replace(
-        common.transformer_config(conf, e.max_model_len),
-        attention_impl=attention_impl)
-    params = _on(jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))), one_chip)
-    cache = _on(jax.eval_shape(lambda: mr.init_cache(
-        cfg, e.num_pages, e.page_size, e.max_num_seqs)), one_chip)
-    return e, cfg, params, cache
-
-
-def test_afmoe_decode_reads_rings_and_live_pages_and_nothing_else(one_chip):
-    """Decode at 32 slots x 16,896: the paged kernel over the rings of the four
-    sliding layers (8 blocks of 512 a ring) and over the one full layer's live
-    pages, plain heads (8 groups of 6 query rows padded to 16, 128 lanes);
-    three grouped matmuls in each of the four expert layers over the 32 held
-    experts; the cache written in place, and NO array a slot's whole length
-    long: nothing is gathered over ``Lmax``."""
-    cache, _, decode = _lower_rms_kinds(one_chip)
-    compiled = decode().compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(r"%(window_gqa_decode\S*) = bf16\[32,8,16,128\]",
-                              text))) == 4
-    assert len(set(re.findall(r"%(paged_gqa_decode\S*) = bf16\[32,8,16,128\]",
-                              text))) == 1
-    assert len(set(re.findall(r"%(moe_gmm_decode\S*) = bf16\[128,3072\]",
-                              text))) == 12
-    assert text.count("tpu_custom_call") == 17
-    assert cache.pages.shape == (1, 1057, 512, 2048)
-    assert cache.rings.shape == (4, 32, 4096, 2048)
-    assert cache.moe_load.shape == (4, 32) and cache.ssm is None
-    assert not re.search(r"\[32,(16896|33,512),", text)
-    live, temp = _live(compiled)
-    held = sum(x.size * x.dtype.itemsize
-               for x in (cache.pages, cache.rings))
-    print(f"afmoe decode, 32 slots: {live} bytes live, {temp} of "
-          f"temporaries; cache {held}")
-    assert temp < 64 << 20
-    assert compiled.memory_analysis().alias_size_in_bytes >= held
-
-
-@pytest.mark.parametrize("rows,bucket", [(1, 16384), (32, 256), (1, 512)])
-def test_afmoe_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
-    """The engine's largest call, ``[1, 16384]``, the benchmark check's
-    every-slot ``[32, 256]`` call, and the largest of the engine's calls that
-    carry the 32 slots' decode step beside the prompt, ``[1, 512]`` (the paged
-    kernel over four rings and one layer of pages: PR 42), beside 8.65 GB of
-    weights and 4.36 GB of pages and rings: five flash calls (the window's in
-    four of them) and twelve grouped matmuls, under the chip's 15.75 GiB.
-    What an execution holds live is printed (``-s``) and stands in PERF.md
-    section 4."""
-    _, prefill, _ = _lower_rms_kinds(one_chip)
-    compiled = prefill(rows, bucket).compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(
-        rf"%(flash_fwd\S*) = \(bf16\[{rows * 48},{bucket},128\]", text))) == 5
-    riding = len(set(re.findall(r"%((?:window|paged)_gqa_riding\S*) = ", text)))
-    assert riding == (5 if (rows, bucket) == (1, 512) else 0)
-    assert text.count("tpu_custom_call") == 17 + riding
-    assert len(set(re.findall(r"%(moe_gmm_prefill\S*) = bf16\[", text))) == 12
-    assert not _float32_rows_a_choice(text, 4, 3072)
-    live, temp = _live(compiled)
-    print(f"afmoe prefill [{rows}, {bucket}]: {live} bytes live, "
-          f"{temp} of temporaries")
-    assert 0 < live < int(15.5 * 2 ** 30)
-
-
-# -- LFM2-8B-A1B (lfm2_moe, the benchmark's file) ------------------------------------
-
-
-def test_lfm2_decode_shifts_rows_and_reads_live_pages(one_chip):
-    """Decode at 128 slots x 2,560: the paged kernel over the three attention
-    layers' live pages with two plain 64-lane key heads to a group (4 groups
-    of 8 query rows padded to 16, 128 lanes: whole tiles of a page's row);
-    three grouped matmuls in each of the twelve sparse layers over 512
-    assignments (two row tiles of 256); the eleven convolutions' rows shifted
-    in the cache, which is written in place; nothing gathered over a slot's
-    whole length."""
-    cache, _, decode = _lower_rms_kinds(one_chip, "lfm2-8b-a1b")
-    compiled = decode().compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(r"%(paged_gqa_decode\S*) = bf16\[128,4,16,128\]",
-                              text))) == 3
-    assert len(set(re.findall(r"%(moe_gmm_decode\S*) = bf16\[512,(?:1792|2048)\]",
-                              text))) == 36
-    assert text.count("tpu_custom_call") == 39
-    assert cache.pages.shape == (3, 1281, 256, 1024)
-    assert cache.conv.shape == (11, 2, 128, 2048)
-    assert cache.moe_load.shape == (12, 32)
-    assert cache.rings is None and cache.ssm is None
-    assert not re.search(r"\[128,(2560|10,256),", text)
-    live, temp = _live(compiled)
-    held = sum(x.size * x.dtype.itemsize for x in (cache.pages, cache.conv))
-    print(f"lfm2 decode, 128 slots: {live} bytes live, {temp} of "
-          f"temporaries; cache {held}")
-    assert temp < 256 << 20
-    assert compiled.memory_analysis().alias_size_in_bytes >= held
-
-
-@pytest.mark.parametrize("rows,bucket", [(1, 128), (1, 2048), (128, 256)])
-def test_lfm2_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
-    """The least and the largest bucket the mix reaches as the engine calls
-    them, ``[1, S]`` with a slot (the three between hold 11.40, 11.42 and
-    11.47 GB: compiled once, AOT, PR 40; this file is the suite's longest),
-    and the benchmark check's every-slot ``[128, 256]`` call (32,768 rows x
-    top-4 = 131,072 sorted rows in each expert layer), beside 9.33 GB of
-    weights and 2.03 GB of pages and rows: three flash calls and 36 grouped
-    matmuls (over the prompt's rows and, in the engine's call, the 128 decode
-    rows it carries since PR 42, whose attention is the paged kernel's in the
-    three attention layers), under the chip's 15.75 GiB. What an execution
-    holds live is printed (``-s``) and stands in PERF.md section 4."""
-    _, prefill, _ = _lower_rms_kinds(one_chip, "lfm2-8b-a1b")
-    compiled = prefill(rows, bucket).compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(
-        rf"%(flash_fwd\S*) = \(bf16\[{rows * 32},{bucket},64\]", text))) == 3
-    riding = len(set(re.findall(r"%(paged_gqa_riding\S*) = ", text)))
-    assert riding == (3 if rows == 1 else 0)
-    assert text.count("tpu_custom_call") == 39 + riding
-    assert len(set(re.findall(r"%(moe_gmm_prefill\S*) = bf16\[", text))) == 36
-    assert not _float32_rows_a_choice(text, 4, 2048)
-    live, temp = _live(compiled)
-    print(f"lfm2 prefill [{rows}, {bucket}]: {live} bytes live, "
-          f"{temp} of temporaries")
-    assert 0 < live < int(15.5 * 2 ** 30)
-
-
-
-# -- one rank of Granite-4.0-H-Small (granitemoehybrid, the benchmark's file) ---------
-
-
-def test_granite_decode_steps_every_state_in_place(one_chip):
-    """Decode at 40 slots x 4,608: ``ssd_step`` once in each of the nine
-    Mamba-2 layers over the whole [9, 40, 128, 8192] float32 leaf, which
-    like the pages and the tails is written IN PLACE (no copy of 1.5 GB of
-    state among the temporaries); the paged kernel over the one attention
-    layer's live pages; three grouped matmuls in each of the ten expert
-    layers over the 36 held experts and 400 assignments."""
-    cache, _, decode = _lower_rms_kinds(one_chip, "granite-4.0-h-small")
-    compiled = decode().compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(r"%(ssd_step\S*) = \(f32\[40,1,8192\]", text))) == 9
-    assert len(set(re.findall(r"%(paged_gqa_decode\S*) = bf16\[40,8,16,128\]",
-                              text))) == 1
-    assert len(set(re.findall(r"%(moe_gmm_decode\S*) = bf16\[\d+,(?:768|4096)\]",
-                              text))) == 30
-    assert text.count("tpu_custom_call") == 40
-    assert cache.ssm.shape == (9, 40, 128, 8192) and cache.ssm.dtype == jnp.float32
-    assert cache.conv.shape == (9, 3, 40, 8448)
-    assert cache.pages.shape == (1, 361, 512, 2048)
-    assert cache.moe_load.shape == (10, 36) and cache.rings is None
-    live, temp = _live(compiled)
-    held = sum(x.size * x.dtype.itemsize
-               for x in (cache.ssm, cache.conv, cache.pages))
-    print(f"granite decode, 40 slots: {live} bytes live, {temp} of "
-          f"temporaries; cache {held}")
-    assert temp < 64 << 20
-    assert compiled.memory_analysis().alias_size_in_bytes >= held
-
-
-@pytest.mark.parametrize("rows,bucket", [(1, 256), (40, 256), (1, 4096)])
-def test_granite_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
-    """The least bucket as the engine calls it, ``[1, 256]`` with a slot,
-    CARRYING the 40 slots' decode step (``ssd_riding`` in nine layers,
-    ``paged_gqa_riding`` in one), and the benchmark check's every-slot ``[40,
-    256]`` call, the largest program of the cell (10,240 rows x top-10), beside
-    9.51 GB of weights and 2.29 GB of state, tails and pages: the chunked scan
-    in nine layers, one flash call, thirty grouped matmuls, under the chip's
-    15.75 GiB; and the largest bucket, ``[1, 4096]`` (40,960 sorted rows a
-    layer; too long to carry a step), which held 13.80 GB while the way back
-    laid a float32 ``[4096, 10, 4096]`` out and holds 12.67 since PR 46."""
-    _, prefill, _ = _lower_rms_kinds(one_chip, "granite-4.0-h-small")
-    compiled = prefill(rows, bucket).compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(
-        rf"%(ssd_scan\S*) = \(f32\[{rows},{bucket},8192\]", text))) == 9
-    assert len(set(re.findall(
-        rf"%(flash_fwd\S*) = \(bf16\[{rows * 32},{bucket},128\]", text))) == 1
-    riding = len(set(re.findall(r"%((?:ssd|paged_gqa)_riding\S*) = ", text)))
-    assert riding == (10 if (rows, bucket) == (1, 256) else 0)
-    assert text.count("tpu_custom_call") == 40 + riding
-    assert len(set(re.findall(r"%(moe_gmm_prefill\S*) = bf16\[", text))) == 30
-    assert not _float32_rows_a_choice(text, 10, 4096)
-    live, temp = _live(compiled)
-    print(f"granite prefill [{rows}, {bucket}]: {live} bytes live, "
-          f"{temp} of temporaries")
-    assert 0 < live < int(15.5 * 2 ** 30)
-
-
-# -- one rank of Kimi-Linear-48B-A3B (kimi_linear, the benchmark's file) ------------------
-
-
-def test_kimi_decode_steps_every_state_in_place(one_chip):
-    """Decode at 64 slots x 16,896: ``kda_step`` once in each of the six
-    delta-rule layers over the whole [6, 64, 32, 128, 128] float32 leaf, which
-    like the latent rows and the tails is written IN PLACE (no copy of 0.8 GB
-    of state among the temporaries); ``mla_decode`` over the two latent
-    layers' live pages, found by their rank among the latent layers; three
-    grouped matmuls in each of the seven expert layers over the 64 held
-    experts and 512 assignments."""
-    cache, _, decode = _lower_rms_kinds(one_chip, "kimi-linear-48b-a3b")
-    compiled = decode().compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(r"%(kda_step\S*) = \(f32\[64,32,128\]", text))) == 6
-    assert len(set(re.findall(r"%(mla_decode\S*) = ", text))) == 2
-    assert len(set(re.findall(r"%(moe_gmm_decode\S*) = bf16\[\d+,(?:1024|2304)\]",
-                              text))) == 21
-    assert text.count("tpu_custom_call") == 29
-    assert cache.ssm.shape == (6, 64, 32, 128, 128) and cache.ssm.dtype == jnp.float32
-    assert cache.conv.shape == (6, 3, 64, 12288)
-    assert cache.rows.shape == (2, 64 * 33 + 1, 512, 640)
-    assert cache.moe_load.shape == (7, 64)
-    assert cache.pages is None and cache.rings is None and cache.k is None
-    live, temp = _live(compiled)
-    held = sum(x.size * x.dtype.itemsize
-               for x in (cache.ssm, cache.conv, cache.rows))
-    print(f"kimi decode, 64 slots: {live} bytes live, {temp} of "
-          f"temporaries; cache {held}")
-    assert temp < 128 << 20
-    assert compiled.memory_analysis().alias_size_in_bytes >= held
-
-
-@pytest.mark.parametrize("rows,bucket", [(1, 512), (64, 256), (1, 16384)])
-def test_kimi_prefill_fits_beside_every_slots_state(one_chip, rows, bucket):
-    """The least bucket the mix reaches as the engine calls it, ``[1, 512]``
-    with a slot, CARRYING the 64 slots' decode step (``kda_riding`` in six
-    layers; the latent mixer runs a prompt's rows and a step's in one program),
-    the benchmark check's every-slot ``[64, 256]`` call (16,384 rows x top-8)
-    and the largest bucket, ``[1, 16384]``, beside 7.54 GB of weights and 3.6
-    GB of state, tails and latent rows: the chunked delta rule in six layers,
-    two flash calls over 192-wide q . k, twenty-one grouped matmuls, under the
-    chip's 15.75 GiB. The kernel writes ``o`` normalised, gated and in the
-    stored type, ``[rows, bucket, 4096]`` as ``o_proj`` reads it, and no
-    float32 array of a prompt's positions by all heads' lanes, flat or by
-    head (what the layer's elementwise passes wrote while XLA made them:
-    the float32 ``f``, ``g`` and ``o``), is anybody's result."""
-    _, prefill, _ = _lower_rms_kinds(one_chip, "kimi-linear-48b-a3b")
-    compiled = prefill(rows, bucket).compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(
-        rf"%(kda_scan\S*) = \(bf16\[{rows},{bucket},4096\]", text))) == 6
-    assert not re.findall(
-        rf"= \(?f32\[{rows},{bucket},(?:4096|32,128)\]", text)
-    assert len(set(re.findall(r"%(flash_fwd\S*) = ", text))) == 2
-    riding = len(set(re.findall(r"%(kda_riding\S*) = ", text)))
-    assert riding == (6 if (rows, bucket) == (1, 512) else 0)
-    assert len(set(re.findall(r"%(mla_decode\S*) = ", text))) == riding // 3
-    assert len(set(re.findall(r"%(moe_gmm_prefill\S*) = bf16\[", text))) == 21
-    live, temp = _live(compiled)
-    print(f"kimi prefill [{rows}, {bucket}]: {live} bytes live, "
-          f"{temp} of temporaries")
-    assert 0 < live < int(15.5 * 2 ** 30)
-
-
-# -- one rank of LongCat-Flash-Omni's language model (longcat_flash) ---------------------
-
-
-def test_longcat_decode_reads_eight_sublayers_rows_in_place(one_chip):
-    """Decode at 32 slots x 8,704: ``mla_decode`` once in each of the EIGHT
-    sublayers (two a published layer) over the [8, 545, 512, 640] rows, which
-    are written in place; three grouped matmuls in each of the FOUR expert
-    branches over the 16 held experts and 384 assignments (two row tiles of 256), by assignment (a
-    decode step is far under the least count that goes by what is held)."""
-    cache, _, decode = _lower_rms_kinds(one_chip, "longcat-flash-omni")
-    compiled = decode().compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(r"%(mla_decode\S*) = ", text))) == 8
-    assert len(set(re.findall(r"%(moe_gmm_decode\S*) = bf16\[512,(?:2048|6144)\]",
-                              text))) == 12
-    assert text.count("tpu_custom_call") == 20
-    assert cache.rows.shape == (8, 32 * 17 + 1, 512, 640)
-    assert cache.moe_load.shape == (4, 17)
-    assert cache.pages is None and cache.ssm is None and cache.k is None
-    live, temp = _live(compiled)
-    held = cache.rows.size * cache.rows.dtype.itemsize
-    print(f"longcat decode, 32 slots: {live} bytes live, {temp} of "
-          f"temporaries; cache {held}")
-    assert temp < 128 << 20
-    assert compiled.memory_analysis().alias_size_in_bytes >= held
-
-
-@pytest.mark.parametrize("rows,bucket", [(1, 512), (32, 256), (1, 8192)])
-def test_longcat_prefill_holds_no_buffer_of_every_assignment(one_chip, rows,
-                                                             bucket):
-    """The largest call that carries the 32 slots' decode step, ``[1, 512]``,
-    the benchmark check's every-slot ``[32, 256]`` call and the largest
-    bucket, ``[1, 8192]`` (8,192 rows x top-12 = 98,304 assignments each, of
-    which about 2,048 are held here), beside 10.35 GB of weights and 2.86 GB
-    of latent rows: eight flash calls at 64 heads over 192-wide q . k, the
-    three grouped products of each of four expert branches inside ONE loop a
-    branch over windows of 4,096 sorted rows, and NO array as long as the
-    assignments by the hidden or the expert width (by assignment that is 1.2
-    GB of gathered rows alone a branch): under the chip's 15.75 GiB."""
-    from ray_tpu.ops.moe import held_window
-
-    _, prefill, _ = _lower_rms_kinds(one_chip, "longcat-flash-omni")
-    compiled = prefill(rows, bucket).compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(
-        rf"%(flash_fwd\S*) = \(bf16\[{rows * 64},{bucket},128\]", text))) == 8
-    riding = (rows, bucket) == (1, 512)
-    assert len(set(re.findall(r"%(mla_decode\S*) = ", text))) == 8 * riding
-    T = rows * bucket + 32 * riding
-    window = held_window(T * 12, 16, 768, 256)
-    assert window == {512: 512, 256: 4096, 8192: 4096}[bucket]
-    assert len(set(re.findall(
-        rf"%(moe_gmm_prefill\S*) = bf16\[{window},(?:2048|6144)\]", text))) == 12
-    assert not re.search(rf"\[(?:{T * 12}|12,{T}),(?:2048|6144)\]", text)
-    live, temp = _live(compiled)
-    print(f"longcat prefill [{rows}, {bucket}]: {live} bytes live, "
-          f"{temp} of temporaries")
-    assert 0 < live < int(15.5 * 2 ** 30)
-
-
-# -- one stage of Brumby-14B-Base (brumby, the benchmark's file) --------------------------
-
-
-def test_brumby_decode_steps_every_state_in_place(one_chip):
-    """Decode at 32 slots: in each of the six layers ONE conditional on the
-    cache's count of pending positions, around ``retention_read`` (the state
-    read, a tile of the scratch slot written) and ``retention_step`` (the
-    fold: read and written), both over the whole [6, 32 + 1, 8, 66, 128, 128]
-    float32 leaf (6.85 GB), which either branch hands back IN PLACE: one copy
-    of it among the live bytes, NO copy of it anywhere in the program, and
-    nothing a state's size among the temporaries. The cache holds that leaf,
-    the pending positions (7 MB) and their count, and no page; the block
-    tables are arguments that address nothing."""
-    cache, _, decode = _lower_rms_kinds(one_chip, "brumby-14b-base")
-    compiled = decode().compile()
-    text = compiled.as_text()
-    for kernel in ("retention_step", "retention_read"):
-        assert len(set(re.findall(
-            rf"%({kernel}\S*) = \(f32\[32,8,128,16\]", text))) == 6
-    assert text.count("tpu_custom_call") == 12
-    assert len(re.findall(r" conditional\(", text)) == 6
-    assert not re.search(r"= f32\[6,33,8,66,128,128\]\S* copy\(", text)
-    assert cache.ssm.shape == (6, 32 + 1, 8, 66, 128, 128)
-    assert cache.ssm.dtype == jnp.float32
-    assert cache.pending.shape == (6, 3, 3, 32, 8, 128)
-    assert cache.pending.dtype == jnp.float32
-    assert cache.pending_count.shape == ()
-    assert all(leaf is None for leaf in (
-        cache.pages, cache.rows, cache.k, cache.v, cache.rings, cache.conv))
-    live, temp = _live(compiled)
-    held = cache.ssm.size * cache.ssm.dtype.itemsize
-    print(f"brumby decode, 32 slots: {live} bytes live, {temp} of "
-          f"temporaries; state {held}")
-    assert temp < 128 << 20
-    assert compiled.memory_analysis().alias_size_in_bytes >= held
-
-
-@pytest.mark.parametrize("rows,bucket", [(1, 512), (32, 256), (1, 8192)])
-def test_brumby_prefill_writes_no_row_of_features(one_chip, rows, bucket):
-    """The largest call that carries the 32 slots' decode step, ``[1, 512]``
-    (``retention_riding`` in six layers), the benchmark check's every-slot
-    ``[32, 256]`` call and the largest bucket, ``[1, 8192]``, beside 7.08 GB
-    of weights and 6.64 GB of state: the chunked recurrence in six layers,
-    whose features live in the kernel's fast memory alone: NO array of a
-    prompt's positions by the symmetric square's width (8,256 exact, 8,320
-    by rotation, or a tiled 8,704 / 9,216) is anybody's result; under the
-    chip's 15.75 GiB. What an execution holds live is printed (``-s``) and
-    stands in PERF.md section 4."""
-    _, prefill, _ = _lower_rms_kinds(one_chip, "brumby-14b-base")
-    compiled = prefill(rows, bucket).compile()
-    text = compiled.as_text()
-    assert len(set(re.findall(
-        rf"%(retention_scan\S*) = \(bf16\[{rows},{bucket},5120\]", text))) == 6
-    riding = len(set(re.findall(r"%(retention_riding\S*) = ", text)))
-    assert riding == (6 if (rows, bucket) == (1, 512) else 0)
-    assert text.count("tpu_custom_call") == 6 + riding
-    assert not re.search(r"\[[\d,]*(?:8256|8320|8704|9216)[,\]]", text)
-    live, temp = _live(compiled)
-    print(f"brumby prefill [{rows}, {bucket}]: {live} bytes live, "
-          f"{temp} of temporaries")
-    assert 0 < live < int(15.5 * 2 ** 30)

@@ -132,7 +132,7 @@ def main() -> dict:
 
     out = {"device": jax.devices()[0].device_kind, "seed": SEED, "tol": TOL,
            "initializer": conf["initializer"], "calls_carrying": carrying,
-           "state_dtype": str(eng.cache.ssm.dtype)}
+           "state_dtype": str(eng.cache["kda"].state.dtype)}
     want = reference()
     # (1) 102 chunks and thirteen pages through [1, 8192]; two prompts whose
     # calls carry the decoding slots' step; 64-slot decode steps

@@ -136,10 +136,10 @@ def grouped(eng, note) -> dict:
     def state(rows):
         """What the rows' requests left: their pages and their two rows."""
         pages = np.concatenate([
-            np.asarray(eng.cache.pages[:, first:first - (-len(t) // e.page_size)],
+            np.asarray(eng.cache["full"][:, first:first - (-len(t) // e.page_size)],
                        np.float32).reshape(-1)
             for t, _, first in rows])
-        conv = np.asarray(eng.cache.conv[:, :, [s for _, s, _ in rows]],
+        conv = np.asarray(eng.cache["conv"][:, :, [s for _, s, _ in rows]],
                           np.float32)
         return pages, conv
 
@@ -166,11 +166,13 @@ def grouped(eng, note) -> dict:
         alone = [np.asarray(call(mr.prefill, [r], S)[0]) for r in rows]
         want_pages, want_conv = state(rows)
         # spoil what the one-row calls left, so the group's writes are seen
-        eng.cache = eng.cache._replace(
-            pages=eng.cache.pages.at[:, rows[0][2]:page].set(0),
-            conv=eng.cache.conv.at[:, :, [s for _, s, _ in rows]].set(0))
-        untouched = (np.asarray(eng.cache.pages[:, page:page + 4], np.float32),
-                     np.asarray(eng.cache.conv[:, :, B - 1], np.float32))
+        eng.cache = eng.cache.replace({
+            "full": eng.cache["full"].at[:, rows[0][2]:page].set(0),
+            "conv": eng.cache["conv"].at[
+                :, :, [s for _, s, _ in rows]].set(0)})
+        untouched = (
+            np.asarray(eng.cache["full"][:, page:page + 4], np.float32),
+            np.asarray(eng.cache["conv"][:, :, B - 1], np.float32))
         fn = compiled(R, S)
         got = np.asarray(call(fn, rows, S, R))
         got_pages, got_conv = state(rows)
@@ -178,9 +180,9 @@ def grouped(eng, note) -> dict:
                 "pages": rel(got_pages, want_pages),
                 "conv": rel(got_conv, want_conv)}
         same = (np.array_equal(untouched[0], np.asarray(
-            eng.cache.pages[:, page:page + 4], np.float32))
+            eng.cache["full"][:, page:page + 4], np.float32))
             and np.array_equal(untouched[1], np.asarray(
-                eng.cache.conv[:, :, B - 1], np.float32)))
+                eng.cache["conv"][:, :, B - 1], np.float32)))
         out[f"group_{name}"] = dict(errs, others_untouched=same)
         note(f"[{R}, {S}] against {len(rows)} one-row calls:", errs, same)
         # two programs of different shapes round apart in bfloat16, and a

@@ -139,7 +139,7 @@ def main(argv=()) -> dict:
 
     out = {"device": jax.devices()[0].device_kind, "seed": SEED, "tol": TOL,
            "initializer": conf["initializer"], "calls_carrying": carrying,
-           "held_windows": windows, "rows_shape": list(eng.cache.rows.shape)}
+           "held_windows": windows, "rows_shape": list(eng.cache["latent"].shape)}
     want = reference()
     # seven pages in eight sublayers through [1, 4096]; two prompts whose
     # calls carry the decoding slots' step; 32-slot decode steps

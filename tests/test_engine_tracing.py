@@ -376,8 +376,7 @@ def test_one_row_calls_leave_what_the_padded_batch_leaves(engine):
         engine.params, engine.mcfg,
         mr.init_cache(engine.mcfg, e.num_pages, e.page_size),
         jnp.asarray(toks), jnp.asarray(full), jnp.asarray(tables))
-    for got_pages, want_pages in ((engine.cache.k, cache.k),
-                                  (engine.cache.v, cache.v)):
+    for got_pages, want_pages in zip(engine.cache["dense"], cache["dense"]):
         got_pages = np.asarray(got_pages[:, owned], np.float32)
         want_pages = np.asarray(want_pages[:, owned], np.float32)
         assert np.abs(want_pages).max() > 0.1

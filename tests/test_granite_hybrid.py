@@ -211,10 +211,11 @@ def test_engine_matches_reference(engine, prompt_len, bucket):
     want = _reference(engine, toks)[prompt_len - 1:]
     assert _rel(got, want) < TOL, _rel(got, want)
     c = run.cache
-    assert c.ssm.shape == (6, 3, 16, 128) and c.ssm.dtype == jnp.float32
-    assert c.conv.shape == (6, 3, 3, 128 + 2 * 16) and c.rings is None
-    assert c.pages.shape[0] == 2
-    assert np.abs(np.asarray(c.ssm)[:, 2]).max(axis=(1, 2)).min() > 0
+    state, tail = c["mamba2"]
+    assert state.shape == (6, 3, 16, 128) and state.dtype == jnp.float32
+    assert tail.shape == (6, 3, 3, 128 + 2 * 16) and "window" not in c
+    assert c["full"].shape[0] == 2
+    assert np.abs(np.asarray(state)[:, 2]).max(axis=(1, 2)).min() > 0
     load = np.asarray(c.moe_load)     # the last step: one row, top-3 of 8
     assert load.shape == (8, 4) and (load.sum(1) <= 3).all()
 
@@ -257,8 +258,8 @@ def test_every_slot_prefill_call(engine):
     logits, run.cache = mr.prefill(
         engine.params, cfg, run.cache, jnp.asarray(batch),
         jnp.asarray([11, 0, 0], jnp.int32), jnp.asarray(run.tables))
-    assert not np.asarray(run.cache.ssm)[:, 1:].any()
-    assert not np.asarray(run.cache.conv)[:, :, 1:].any()
+    assert not np.asarray(run.cache["mamba2"].state)[:, 1:].any()
+    assert not np.asarray(run.cache["mamba2"].tail)[:, :, 1:].any()
     run.active[0], run.lens[0] = True, 11
     got = [np.asarray(logits[0])] + [run.decode({0: t})[0] for t in toks[11:]]
     assert _rel(np.stack(got), _reference(engine, toks)[10:]) < TOL

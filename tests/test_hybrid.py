@@ -91,7 +91,7 @@ def _engine_logits(eng, seqs, prompt_lens, steps, bucket=16, slots=None):
     tables = np.zeros((B, MP), np.int32)
     active = np.zeros(B, bool)
     cache = mr.init_cache(cfg, e.num_pages, e.page_size, B)
-    assert prefill_rows.held(cache) == {"pages", "rings", "ssm", "conv"}
+    assert prefill_rows.held(cache) == {"full", "window", "mamba"}
     got, page = {}, 1
     for s, toks, n in zip(slots, seqs, prompt_lens):
         need = -(-len(toks) // e.page_size)
@@ -237,12 +237,12 @@ def test_prefill_state_is_the_last_real_position(engine):
     (la, a), (lb, b) = run(16), run(32)
     assert _rel(lb, la) < 1e-5
     # page 0 is scratch: the padded bucket's padding lands there
-    for x, y in zip((a.pages[:, 1:], a.rings, a.ssm, a.conv),
-                    (b.pages[:, 1:], b.rings, b.ssm, b.conv)):
+    for x, y in zip((a["full"][:, 1:], a["window"], *a["mamba"]),
+                    (b["full"][:, 1:], b["window"], *b["mamba"])):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
                                    atol=1e-5)
-    assert float(jnp.abs(a.ssm[:, 1]).max()) > 0        # the slot asked for
-    assert float(jnp.abs(a.ssm[:, 0]).max()) == 0       # and no other
+    assert float(jnp.abs(a["mamba"].state[:, 1]).max()) > 0        # the slot asked for
+    assert float(jnp.abs(a["mamba"].state[:, 0]).max()) == 0       # and no other
 
 
 def test_engine_serves_and_resumes_after_preemption():

@@ -29,6 +29,7 @@ from ray_tpu.llm import LLMConfig
 from ray_tpu.llm import model_runner as mr
 from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.engine import JaxLLMEngine
+from ray_tpu.llm.kinds import kda as kda_kind
 from ray_tpu.models.transformer import CONFIGS, Transformer
 from ray_tpu.ops import kda
 
@@ -267,7 +268,7 @@ def _prefill_is_the_layer(R, S, chunk, lengths, extreme, dtype, tol, H):
     n = jnp.asarray(lengths or [S] * R, jnp.int32)
     real = jnp.arange(S)[None, :] < n[:, None]
     wide = lambda t: t.astype(jnp.float32)   # noqa: E731
-    q, k, v, g = mr._kda_operands(wide(a), wide(f), m, cfg)
+    q, k, v, g = kda_kind._operands(wide(a), wide(f), m, cfg)
     want_o, want_s = kda.kda_reference(
         q, k, v, jnp.where(real[..., None, None], g, 0.0),
         jnp.where(real[..., None], beta, 0.0))
@@ -360,12 +361,13 @@ def test_engine_matches_reference(engine, prompt_len, bucket):
     want = _reference(engine, toks)[prompt_len - 1:]
     assert _rel(got, want) < TOL, _rel(got, want)
     c = run.cache
-    assert c.ssm.shape == (3, 3, 4, 16, 16) and c.ssm.dtype == jnp.float32
-    assert c.conv.shape == (3, 3, 3, 3 * 64)
-    assert c.rows.shape == (2, 14, PAGE, 128)
-    assert c.pages is None and c.rings is None and c.k is None
-    assert np.abs(np.asarray(c.ssm)[:, 2]).max(axis=(1, 2, 3)).min() > 0
-    used = np.abs(np.asarray(c.rows, np.float32)).sum(axis=(2, 3)) > 0
+    state, tail = c["kda"]
+    assert state.shape == (3, 3, 4, 16, 16) and state.dtype == jnp.float32
+    assert tail.shape == (3, 3, 3, 3 * 64)
+    assert c["latent"].shape == (2, 14, PAGE, 128)
+    assert set(c.states) == {"latent", "kda"}
+    assert np.abs(np.asarray(state)[:, 2]).max(axis=(1, 2, 3)).min() > 0
+    used = np.abs(np.asarray(c["latent"], np.float32)).sum(axis=(2, 3)) > 0
     assert used[:, 5:5 + -(-len(toks) // PAGE)].all() and not used[:, 1:5].any()
     load = np.asarray(c.moe_load)     # the last step: one row, top-3 of 8
     assert load.shape == (4, 2) and (load.sum(1) <= 3).all()
@@ -409,8 +411,8 @@ def test_every_slot_prefill_call(engine):
     logits, run.cache = mr.prefill(
         engine.params, cfg, run.cache, jnp.asarray(batch),
         jnp.asarray([11, 0, 0], jnp.int32), jnp.asarray(run.tables))
-    assert not np.asarray(run.cache.ssm)[:, 1:].any()
-    assert not np.asarray(run.cache.conv)[:, :, 1:].any()
+    assert not np.asarray(run.cache["kda"].state)[:, 1:].any()
+    assert not np.asarray(run.cache["kda"].tail)[:, :, 1:].any()
     run.active[0], run.lens[0] = True, 11
     got = [np.asarray(logits[0])] + [run.decode({0: t})[0] for t in toks[11:]]
     assert _rel(np.stack(got), _reference(engine, toks)[10:]) < TOL
@@ -424,7 +426,7 @@ def test_bfloat16_engine_is_one_rounding_a_product():
     eng = _engine(dict(OVERRIDES, dtype=jnp.bfloat16))
     toks = np.random.default_rng(8).integers(0, VOCAB, 13 + 4)
     got = _Run(eng).sequence(1, toks, 13, _pages(2, len(toks)))
-    assert _Run(eng).cache.ssm.dtype == jnp.float32
+    assert _Run(eng).cache["kda"].state.dtype == jnp.float32
     assert _rel(got, _reference(eng, toks)[12:]) < 1e-1
 
 

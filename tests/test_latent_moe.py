@@ -29,6 +29,7 @@ from ray_tpu.llm import LLMConfig
 from ray_tpu.llm import model_runner as mr
 from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.engine import JaxLLMEngine
+from ray_tpu.llm.kinds import attention
 from ray_tpu.models.transformer import Transformer, TransformerConfig
 from ray_tpu.ops.attention import flash_attention_fwd, reference_attention
 from ray_tpu.ops.mla import live_pages, mla_decode
@@ -110,7 +111,7 @@ def _engine_logits(eng, seqs, prompt_lens, steps):
         lens[s] = n
         active[s] = True
     cache = mr.init_cache(cfg, e.num_pages, e.page_size)
-    assert prefill_rows.held(cache) == {"rows", "moe_load"}
+    assert prefill_rows.held(cache) == {"latent", "moe_load"}
     logits, cache = mr.prefill(eng.params, cfg, cache, jnp.asarray(batch),
                                jnp.asarray(lens), jnp.asarray(tables))
     got = {s: [np.asarray(logits[s])] for s in range(len(seqs))}
@@ -205,8 +206,8 @@ def test_engine_agrees_with_the_plain_reference(engine, run):
     assert _worst(engine, run) < TOL
     cache = run[2]
     # one 128-lane row a position and layer for all heads: c | k_pe | 0
-    assert cache.rows.shape == (LAYERS, engine.ecfg.num_pages, 8, 128)
-    assert not np.asarray(cache.rows[..., R + ROPE:]).any()
+    assert cache["latent"].shape == (LAYERS, engine.ecfg.num_pages, 8, 128)
+    assert not np.asarray(cache["latent"][..., R + ROPE:]).any()
     assert cache.moe_load.shape == (LAYERS - 1, E)
 
 
@@ -245,13 +246,13 @@ def test_absorbed_decode_equals_expanded_attention():
          "kv_b_proj": {"kernel": jax.random.normal(ks[3], (R, H, NOPE + DV)) * 0.2}}
     x = jax.random.normal(ks[4], (1, S, D))
     positions = jnp.arange(S, dtype=jnp.int32)[None]
-    q_nope, q_pe, c, k_pe, row = mr._latent_qkv(x, p, cfg, positions)
-    want = mr._latent_attention_expanded(q_nope, q_pe, c, k_pe, p, cfg)[0, -1]
+    q_nope, q_pe, c, k_pe, row = attention._latent_qkv(x, p, cfg, positions)
+    want = attention._latent_attention_expanded(q_nope, q_pe, c, k_pe, p, cfg)[0, -1]
     pages = jnp.zeros((2, 5, P, row.shape[-1])).at[1, 1:4].set(
         jnp.pad(row[0], ((0, 3 * P - S), (0, 0))).reshape(3, P, -1))
     tables = jnp.asarray([[1, 2, 3, 0]], jnp.int32)
     work = live_pages(jnp.asarray([S - 1]), jnp.asarray([True]), tables, P)
-    got = mr._latent_attention_absorbed(q_nope[:, -1], q_pe[:, -1], pages, work,
+    got = attention._latent_attention_absorbed(q_nope[:, -1], q_pe[:, -1], pages, work,
                                         1, p, cfg)[0]
     assert got.shape == want.shape == (H, DV)
     assert _rel(got, want) < 1e-5
@@ -402,7 +403,7 @@ def test_config_defaults_select_nothing_new():
     moe = dataclasses.replace(cfg, n_experts=4, moe_every=2, n_layers=4)
     assert [moe.is_moe_layer(i) for i in range(4)] == [True, False, True, False]
     assert not any(cfg.is_moe_layer(i) for i in range(8))
-    assert prefill_rows.held(mr.init_cache(cfg, 3, 8)) == {"k", "v"}
+    assert prefill_rows.held(mr.init_cache(cfg, 3, 8)) == {"dense"}
 
 
 # -- the engine's accounting ------------------------------------------------------------
@@ -498,8 +499,8 @@ def test_export_kv_round_trip_carries_the_latent_rows():
     whole = _engine().generate([prompt], params, decode_text=False)[0].token_ids
     a, b = _engine(), _engine()
     state = a.prefill_only("r", prompt, params)
-    assert "rows" in state and "k" not in state
-    assert state["rows"].shape == (LAYERS, 2, 8, 128)
+    assert "latent" in state and "dense" not in state
+    assert state["latent"].shape == (LAYERS, 2, 8, 128)
     b.add_request_with_kv(state)
     done = []
     while b.has_unfinished():

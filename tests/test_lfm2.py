@@ -169,9 +169,10 @@ def test_engine_matches_reference(engine, prompt_len, bucket):
     got = run.sequence(2, toks, prompt_len, _pages(5, len(toks)), bucket)
     want = _reference(engine, toks)[prompt_len - 1:]
     assert _rel(got, want) < TOL, _rel(got, want)
-    conv = np.asarray(run.cache.conv)
-    assert conv.shape == (5, 2, 3, 64) and run.cache.rings is None
-    assert run.cache.ssm is None and run.cache.pages.shape[0] == 2
+    conv = np.asarray(run.cache["conv"])
+    assert conv.shape == (5, 2, 3, 64) and "window" not in run.cache
+    assert set(run.cache.states) == {"full", "conv"}
+    assert run.cache["full"].shape[0] == 2
     assert np.abs(conv[:, :, 2]).max(axis=-1).min() > 0
     load = np.asarray(run.cache.moe_load)     # the last step: one row, top-2
     assert load.shape == (5, EXPERTS) and (load.sum(1) == 2).all()
@@ -180,7 +181,7 @@ def test_engine_matches_reference(engine, prompt_len, bucket):
 def test_prompt_of_one_token_leaves_one_real_row(engine):
     run = _Run(engine)
     run.prefill(1, [7], _pages(1, 1))
-    conv = np.asarray(run.cache.conv)                     # [layers, 2, B, d]
+    conv = np.asarray(run.cache["conv"])                  # [layers, 2, B, d]
     assert np.abs(conv[:, 0, 1]).max() == 0
     assert np.abs(conv[:, 1, 1]).max(axis=-1).min() > 0
     assert np.abs(conv[:, :, [0, 2]]).max() == 0          # nobody else's rows
@@ -241,7 +242,7 @@ def test_every_slot_prefill_call(engine):
     logits, run.cache = mr.prefill(
         engine.params, cfg, run.cache, jnp.asarray(batch),
         jnp.asarray([11, 0, 0], jnp.int32), jnp.asarray(run.tables))
-    assert np.abs(np.asarray(run.cache.conv)[:, :, 1:]).max() == 0
+    assert np.abs(np.asarray(run.cache["conv"])[:, :, 1:]).max() == 0
     run.active[0], run.lens[0] = True, 11
     got = [np.asarray(logits[0])] + [run.decode({0: t})[0] for t in toks[11:]]
     assert _rel(np.stack(got), _reference(engine, toks)[10:]) < TOL

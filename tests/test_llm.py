@@ -101,6 +101,7 @@ def test_cache_rows_land_at_their_pages_and_nothing_else_moves(n_kv_heads):
     import jax.numpy as jnp
 
     from ray_tpu.llm import model_runner as mr
+    from ray_tpu.llm.kinds.attention import KV
     from ray_tpu.models.transformer import CONFIGS, Transformer
 
     cfg = dataclasses.replace(CONFIGS["tiny"], n_kv_heads=n_kv_heads,
@@ -116,7 +117,7 @@ def test_cache_rows_land_at_their_pages_and_nothing_else_moves(n_kv_heads):
     fed = rng.integers(1, cfg.vocab_size, (4, S + steps)).astype(np.int32)
     shape = (cfg.n_layers, NP, P, n_kv_heads, cfg.head_dim)
     before = [rng.standard_normal(shape).astype(np.float32) for _ in "kv"]
-    cache = mr.Cache(*(jnp.asarray(a) for a in before))  # k, v
+    cache = mr.Cache({"dense": KV(*(jnp.asarray(a) for a in before))})
 
     prompt = np.where(np.arange(S)[None] < lengths[:, None], fed[:, :S], 0)
     _, cache = mr.prefill(params, cfg, cache, jnp.asarray(prompt),
@@ -134,8 +135,8 @@ def test_cache_rows_land_at_their_pages_and_nothing_else_moves(n_kv_heads):
 
     import prefill_rows
 
-    assert prefill_rows.held(cache) == {"k", "v"}
-    got = [np.asarray(cache.k), np.asarray(cache.v)]
+    assert prefill_rows.held(cache) == {"dense"}
+    got = [np.asarray(leaf) for leaf in cache["dense"]]
     untouched = np.ones(shape[:3], bool)
     untouched[:, 0] = False  # scratch page: masked writes land there
     for b in np.flatnonzero(active):

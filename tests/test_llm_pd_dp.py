@@ -36,7 +36,7 @@ def test_kv_export_import_matches_monolithic():
     state = prefill_eng.prefill_only(
         "r1", prompt, SamplingParams(max_tokens=10))
     assert state["generated"], "prefill must emit the first token"
-    assert state["k"].shape[0] == mono.mcfg.n_layers
+    assert state["dense"].k.shape[0] == mono.mcfg.n_layers
     # prefill engine released its slot/pages
     assert prefill_eng.num_active() == 0
     decode_eng.add_request_with_kv(state)

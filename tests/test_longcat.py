@@ -286,7 +286,7 @@ def test_cache_holds_a_row_a_sublayer_and_counts_the_zero_experts(engine):
     zero experts' count."""
     toks = np.random.default_rng(1).integers(0, VOCAB, 9)
     run = _Run(engine)
-    assert run.cache.rows.shape[0] == 4 and run.cache.pages is None
+    assert run.cache["latent"].shape[0] == 4 and "full" not in run.cache
     run.prefill(0, toks, _pages(1, 9))
     load = np.asarray(run.cache.moe_load)
     assert load.shape == (2, 3)

@@ -4,9 +4,11 @@ the leaves ``init_cache`` gives each tiny configuration (values written from
 the tree before the three cache classes became one), and the policy of which
 models' prefill call may carry a decode step."""
 
+import jax
 import jax.numpy as jnp
 import pytest
 
+import prefill_rows
 from ray_tpu.llm import model_runner as mr
 from ray_tpu.llm.config import LLMConfig
 
@@ -28,25 +30,26 @@ def _cfg(model):
                      model_overrides=overrides).transformer_config()
 
 
-# init_cache(cfg, 7, 4, 3) at the parent of PR 43: every leaf that is not None
+# init_cache(cfg, 7, 4, 3) at the parent of PR 43: every leaf, under the
+# kind that keeps it since PR 57 ("<kind>.<field>" where a state has several)
 LEAVES = {
-    "dense": {"k": ((2, 7, 4, 2, 16), "bfloat16"),
-              "v": ((2, 7, 4, 2, 16), "bfloat16")},
-    "sparse": {"k": ((2, 7, 4, 4, 16), "float32"),
-               "v": ((2, 7, 4, 4, 16), "float32"),
+    "dense": {"dense.k": ((2, 7, 4, 2, 16), "bfloat16"),
+              "dense.v": ((2, 7, 4, 2, 16), "bfloat16")},
+    "sparse": {"dense.k": ((2, 7, 4, 4, 16), "float32"),
+               "dense.v": ((2, 7, 4, 4, 16), "float32"),
                "moe_load": ((2, 8), "int32")},
-    "latent": {"rows": ((3, 7, 4, 128), "float32"),
+    "latent": {"latent": ((3, 7, 4, 128), "float32"),
                "moe_load": ((2, 8), "int32")},
-    "afmoe": {"pages": ((1, 7, 4, 128), "float32"),
-              "rings": ((4, 3, 8, 128), "float32"),
+    "afmoe": {"full": ((1, 7, 4, 128), "float32"),
+              "window": ((4, 3, 8, 128), "float32"),
               "moe_load": ((4, 4), "int32")},
-    "lfm2": {"pages": ((2, 7, 4, 64), "float32"),
+    "lfm2": {"full": ((2, 7, 4, 64), "float32"),
              "conv": ((5, 2, 3, 64), "float32"),
              "moe_load": ((5, 8), "int32")},
-    "sambay": {"pages": ((1, 7, 4, 48), "float32"),
-               "rings": ((2, 3, 8, 48), "float32"),
-               "ssm": ((3, 3, 16, 96), "float32"),
-               "conv": ((3, 3, 3, 96), "float32")},
+    "sambay": {"full": ((1, 7, 4, 48), "float32"),
+               "window": ((2, 3, 8, 48), "float32"),
+               "mamba.state": ((3, 3, 16, 96), "float32"),
+               "mamba.tail": ((3, 3, 3, 96), "float32")},
 }
 
 
@@ -54,14 +57,12 @@ LEAVES = {
 def test_init_cache_gives_each_model_the_leaves_it_had(model):
     cache = mr.init_cache(_cfg(model), NUM_PAGES, PAGE, SLOTS)
     assert type(cache) is mr.Cache
-    # the last two are a "retention" layer's (PR 56): None for these six
-    assert cache._fields == ("k", "v", "rows", "pages", "rings", "ssm", "conv",
-                             "moe_load", "pending", "pending_count")
     got = {name: (tuple(leaf.shape), str(leaf.dtype))
-           for name, leaf in cache._asdict().items() if leaf is not None}
+           for name, leaf in prefill_rows.named(cache).items()}
     assert got == LEAVES[model]
-    # the leaves a request's pages are gathered from when it moves
-    assert mr.PAGE_LEAVES == ("k", "v", "rows", "pages")
+    # among a program's arguments the leaves lie in the table's order
+    assert [tuple(leaf.shape) for leaf in jax.tree.leaves(cache)] == [
+        shape for shape, _ in LEAVES[model].values()]
 
 
 @pytest.mark.parametrize("model", ["dense", "sparse", "latent", "sambay"])
@@ -82,7 +83,7 @@ def test_no_decode_rows_ride_a_model_whose_policy_says_no(model):
     with pytest.raises(ValueError, match="no decode rows ride"):
         mr.prefill(None, cfg, cache, *rows, riders=riders)
     # refused before anything was donated
-    assert not any(leaf.is_deleted() for leaf in cache if leaf is not None)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(cache))
 
 
 @pytest.mark.parametrize("model", ["afmoe", "lfm2"])

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from prefill_rows import kernels
-from test_chip_compile import _live, _rms_kinds, one_chip, topo  # noqa: F401
+from chip_compile import _live, _rms_kinds, one_chip, topo  # noqa: F401
 
 
 @pytest.mark.parametrize("name,shape,held", [
