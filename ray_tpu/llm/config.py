@@ -88,6 +88,11 @@ class EngineConfig:
     # of 128): the largest thing any cache holds, checked against the model
     # the same way
     expect_retention_heads: int = 0
+    # the model's Mamba-1 layers normalise the step size's low-rank input, B
+    # and C (``TransformerConfig.ssm_inner_norms``: Jamba's), checked against
+    # the model the same way: a lost override would serve a scan without its
+    # three norms under the deployment's name
+    expect_ssm_inner_norms: bool = False
 
     def __post_init__(self):
         if self.max_model_len % self.page_size:

@@ -170,7 +170,8 @@ the operator's guide to reading them):
   here at 0 whatever the model): what a decode step attends over and reads
   through the block tables and the rings (``mla_decode_*``, ``shared_kv_*``,
   ``window_live_tokens``), the slots whose recurrent state a step moves
-  (``ssd_step_*``, ``kda_step_*``, ``retention_*_slots``, with
+  (``ssm_step_*`` with ``ssm_steps``, ``ssd_step_*``, ``kda_step_*``,
+  ``retention_*_slots``, with
   ``retention_steps`` and ``retention_fold_steps``), the chunks a prefill
   call's scan has and passes over (``kda_scan_chunks*``,
   ``retention_scan_chunks*``) and ``prefill_cross_rows``. A model of
@@ -210,8 +211,8 @@ is unread; ``experts``: experts touched
 per layer in the newest decode step the host has read, and ``held``: that
 step's assignments per layer to experts held here, models with experts only; ``live_tokens``: positions the step attends over through the block
 tables, models with a latent cache or with ``layer_kinds`` only;
-``state_slots``: the decoding slots, whose matrix states the step moves, models
-with Mamba-2 or delta-rule layers only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
+``state_slots``: the decoding slots, whose recurrent states the step moves, models
+with a recurrent kind that counts them only), ``.sample_dispatch``, then ``.readback`` and ``.emit`` once for
 every sampler call of the step before. ``.readback`` names what it waited
 for (arguments ``kind``: ``prefill`` or ``decode``; ``calls``: the prefill
 calls behind it, 1 for a decode step; ``bucket``: the largest of those
@@ -466,6 +467,11 @@ class JaxLLMEngine:
                 f"the deployment expects power-retention layers of "
                 f"{self.ecfg.expect_retention_heads} key/value heads, the "
                 f"model's have {retention_heads}")
+        if self.mcfg.ssm_inner_norms != self.ecfg.expect_ssm_inner_norms:
+            raise ValueError(
+                f"the deployment expects Mamba-1 layers with inner norms: "
+                f"{self.ecfg.expect_ssm_inner_norms}, the model's have them: "
+                f"{self.mcfg.ssm_inner_norms}")
         self.tokenizer = get_tokenizer(config.tokenizer)
         self._mr = model_runner
         self._jax = jax

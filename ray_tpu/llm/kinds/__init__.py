@@ -161,7 +161,8 @@ KINDS = {kind.name: kind for kind in (
     Kind("cross", "sambay.cross", counters=("prefill_cross_rows",)),
     Kind("gmu", "sambay.gmu"),
     Kind("conv", "conv", recurrent=True),
-    Kind("mamba", "sambay.mamba", recurrent=True),
+    Kind("mamba", "mamba", recurrent=True, counters=(
+        "ssm_step_slots", "ssm_step_live_slots", "ssm_steps")),
     Kind("mamba2", "mamba2", recurrent=True, counters=(
         "ssd_step_slots", "ssd_step_live_slots", "ssd_steps",
         "ssd_fold_steps")),

@@ -45,7 +45,7 @@ DIFFERENTIAL attention, gated memory units, cross layers; LayerNorm, no
 position embedding) is a composition of its own (``_hybrid_prefill``,
 ``_hybrid_decode``) over the same cache and the same index: the states of its
 "full", "window" and "mamba" kinds (``kinds/attention.py``,
-``kinds/sambay.py``). Its prefill runs the self-decoder over the prompt (the
+``kinds/mamba.py``). Its prefill runs the self-decoder over the prompt (the
 scan through ``ops/ssm.py``, padding passed over with ``dt = 0``; the window
 through the flash kernel, blocks left of it skipped) and the cross-decoder on
 the ONE last position, since those layers write no state and the engine reads
@@ -248,20 +248,39 @@ def _memory_unit(h, memory, m, cfg):
 
 def _mamba_inputs(a, m, cfg):
     """The convolved, activated input a [.., I] -> (a, dt, B, C), the scan's
-    operands in float32."""
+    operands in float32; under ``ssm_inner_norms`` the step size's low-rank
+    input, B and C each through an RMSNorm of their own first."""
     N, R = cfg.ssm_state, cfg.ssm_dt_rank
     a = jax.nn.silu(a)
     x = _dense(a, m["x_proj"], jnp.float32)
-    dt = jax.nn.softplus(_dense(x[..., :R], m["dt_proj"], jnp.float32))
-    return a, dt, x[..., R:R + N], x[..., R + N:]
+
+    def normed(t, name):
+        if not cfg.ssm_inner_norms:
+            return t
+        return _rmsnorm(t, m[name]["scale"], cfg.norm_eps)
+
+    dt = jax.nn.softplus(_dense(normed(x[..., :R], "dt_norm"), m["dt_proj"],
+                                jnp.float32))
+    return (a, dt, normed(x[..., R:R + N], "b_norm"),
+            normed(x[..., R + N:], "c_norm"))
+
+
+def _mamba_skip(y, a, m):
+    """The scan's y [.., I] float32 with the skip ``D a``."""
+    return y + m["D"] * a.astype(jnp.float32)
+
+
+def _mamba_gated(y, z, m, cfg):
+    """y [.., I] float32 with its skip, gated by ``z``, through out_proj."""
+    return _dense(y.astype(cfg.dtype) * jax.nn.silu(z), m["out_proj"],
+                  cfg.dtype)
 
 
 def _mamba_output(y, a, z, m, cfg):
     """The scan's y [.., I] float32 -> (the mixer's output, the memory a
     gated memory unit reads: y with the D term, before the gate)."""
-    y = y + m["D"] * a.astype(jnp.float32)
-    return _dense(y.astype(cfg.dtype) * jax.nn.silu(z), m["out_proj"],
-                  cfg.dtype), y
+    y = _mamba_skip(y, a, m)
+    return _mamba_gated(y, z, m, cfg), y
 
 
 def _diff_qkv(h, m, cfg):

@@ -119,8 +119,12 @@ class TransformerConfig:
     # projections and a tied head (``HybridBlock``). "rms": the RMSNorm
     # block of every model without kinds (``Block``), plain grouped-query
     # heads under the causal mask ("full") or the window's ("window"); only
-    # "window", "full", "conv", "mamba2", "kda", "retention" and "latent" are
-    # kinds of such a block. "conv" is
+    # "window", "full", "conv", "mamba", "mamba2", "kda", "retention" and
+    # "latent" are kinds of such a block. "mamba" there is the same Mamba-1
+    # selective scan as a mixer of its own (``Mamba``: ``ssm_inner`` channels,
+    # a state [ssm_inner, ssm_state], ``ssm_conv`` taps, step sizes through a
+    # rank of ``ssm_dt_rank``, and under ``ssm_inner_norms`` an RMSNorm on
+    # each of the step size's low-rank input, B and C). "conv" is
     # a gated short convolution (``ShortConv``): no attention, no position
     # embedding, and in serving ``conv_taps - 1`` rows a slot in place of pages
     # or a ring. "mamba2" is a Mamba-2 mixer (``Mamba2``): ``ssm_heads`` heads
@@ -148,6 +152,10 @@ class TransformerConfig:
     ssm_state: int = 16
     ssm_conv: int = 4       # taps of the causal depthwise convolution
     ssm_dt_rank: int = 0
+    # a "mamba" layer of an "rms" block normalises what ``x_proj`` gives: the
+    # step size's low-rank input, B and C each under an RMSNorm with a learned
+    # scale (Jamba's three inner norms)
+    ssm_inner_norms: bool = False
     # heads of a "mamba2" layer (0: the model has none); B and C are shared by
     # all of them (one group)
     ssm_heads: int = 0
@@ -332,6 +340,13 @@ class TransformerConfig:
         mamba2 = (d * (inner + xbc + self.ssm_heads) + inner * d
                   + xbc * (self.ssm_conv + 1) + 3 * self.ssm_heads + inner
                   + 2 * d)
+        # in_proj (u | z), x_proj (dt | B | C), dt_proj with its bias,
+        # out_proj, the taps and their bias, A_log, D, the three inner norms
+        # where the model has them, and the block's two norms
+        R, N = self.ssm_dt_rank, self.ssm_state
+        mamba = (d * 2 * inner + inner * (R + 2 * N) + (R + 1) * inner
+                 + inner * d + inner * (self.ssm_conv + 1 + N + 1)
+                 + (R + 2 * N) * self.ssm_inner_norms + 2 * d)
         # q | k | v and o, the three convolutions' taps, the decay's low-rank
         # pair with dt_bias and A_log, beta's projection, the output gate's
         # pair, the output norm and the block's two norms
@@ -344,8 +359,8 @@ class TransformerConfig:
         kv = self.n_kv_heads * self.head_dim
         retention = (2 * q + 2 * d * kv + (d + 1) * self.n_kv_heads
                      + 2 * self.head_dim + 2 * d)
-        mixer = {"conv": conv, "mamba2": mamba2, "kda": kda, "latent": latent,
-                 "retention": retention}
+        mixer = {"conv": conv, "mamba": mamba, "mamba2": mamba2, "kda": kda,
+                 "latent": latent, "retention": retention}
         dense_mlp = 3 * d * (self.d_ff_dense or f)
         outputs = self.n_experts + self.zero_experts
         moe_mlp = (self.n_experts_held * 3 * d * f + d * outputs
@@ -548,6 +563,62 @@ class ShortConv(nn.Module):
             cfg.conv_taps ** -0.5), (cfg.conv_taps, d), cfg.param_dtype)
         y = c * causal_conv(b * z, w.astype(cfg.dtype), 0)
         return dense(d, ("mlp", "embed"), "out_proj")(y)
+
+
+class Mamba(nn.Module):
+    """A "mamba" layer's mixer under an "rms" block, a Mamba-1 selective scan:
+    ``u | z = in_proj(h)`` (each ``ssm_inner`` wide), ``a = silu(causal_conv(u)
+    + conv_bias)``, ``r | B | C = x_proj(a)`` (widths ``ssm_dt_rank``,
+    ``ssm_state``, ``ssm_state``; float32), under ``ssm_inner_norms`` each
+    through its own RMSNorm (``dt_norm``, ``b_norm``, ``c_norm``), ``dt =
+    softplus(dt_proj(r))`` with dt_proj's bias, the recurrence of
+    ``ops/ssm.py`` with ``A = -exp(A_log)`` [inner, state], ``y = y + D a``,
+    ``out_proj(y * silu(z))``. No bias but the convolution's and dt_proj's, no
+    position embedding. The TRAINING side: the whole sequence at once through
+    ``selective_scan_reference`` (packed sequences are not kept apart); the
+    serving engine keeps the state and the last ``ssm_conv - 1`` rows of ``u``
+    a slot (``llm/kinds/mamba.py``). The decoder-hybrid-decoder's
+    ``HybridMixer`` holds the same arithmetic without the norms."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h):
+        from ray_tpu.ops.ssm import selective_scan_reference
+
+        cfg = self.cfg
+        inner, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+        dense = lambda feats, name, std, dtype=None: nn.DenseGeneral(  # noqa: E731
+            features=feats, use_bias=False, dtype=dtype or cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name,
+            kernel_init=nn.initializers.normal(std))
+        uz = dense(2 * inner, "in_proj", cfg.init_std("ssm_proj"))(h)
+        u, z = uz[..., :inner], uz[..., inner:]
+        w = self.param("conv_kernel", nn.initializers.normal(
+            cfg.ssm_conv ** -0.5), (cfg.ssm_conv, inner), cfg.param_dtype)
+        b = self.param("conv_bias", nn.initializers.normal(CONV_BIAS_STD),
+                       (inner,), cfg.param_dtype)
+        a = nn.silu(causal_conv(u, w.astype(cfg.dtype), b.astype(cfg.dtype)))
+        x = dense(R + 2 * N, "x_proj", cfg.init_std("ssm_x"), jnp.float32)(a)
+        r, Bm, Cm = x[..., :R], x[..., R:R + N], x[..., R + N:]
+        if cfg.ssm_inner_norms:
+            r, Bm, Cm = (
+                RMSNorm(cfg.norm_eps, jnp.float32, axis=None, name=n)(t)
+                for t, n in ((r, "dt_norm"), (Bm, "b_norm"), (Cm, "c_norm")))
+        dt = jax.nn.softplus(nn.DenseGeneral(
+            inner, dtype=jnp.float32, param_dtype=jnp.float32, name="dt_proj",
+            kernel_init=nn.initializers.normal(R ** -0.5),
+            bias_init=_dt_bias_init)(r))
+        A_log = self.param(
+            "A_log", lambda *_: jnp.broadcast_to(jnp.log(jnp.arange(
+                1, N + 1, dtype=jnp.float32)), (inner, N)))
+        D = self.param("D", nn.initializers.ones, (inner,), jnp.float32)
+        y, _ = selective_scan_reference(
+            dt, a, Bm, Cm, -jnp.exp(A_log),
+            jnp.zeros((h.shape[0], inner, N), jnp.float32))
+        y = y + D * a.astype(jnp.float32)
+        return dense(cfg.d_model, "out_proj", cfg.init_std("ssm_proj"))(
+            y.astype(cfg.dtype) * nn.silu(z))
 
 
 class Mamba2(nn.Module):
@@ -1084,6 +1155,8 @@ class Block(nn.Module):
         norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)  # noqa: E731
         if self.kind == "conv":
             a = ShortConv(cfg, name="conv")(norm("attn_norm")(x))
+        elif self.kind == "mamba":
+            a = Mamba(cfg, name="mamba")(norm("attn_norm")(x))
         elif self.kind == "mamba2":
             a = Mamba2(cfg, name="mamba")(norm("attn_norm")(x))
         elif self.kind == "kda":

@@ -16,9 +16,19 @@ hands them over as [B, S, N, 1], which pads each to a tile (67 MB a layer at
 [S, inner, N] ever exists outside the state of the position at hand.
 
 A position with ``dt = 0`` leaves the state as it was and adds nothing: that
-is how padding behind a prompt is passed over. Off the TPU the kernel runs in
-interpret mode. One decode step needs no scan: ``llm/model_runner.py``
-computes it in place.
+is how padding behind a prompt is passed over, and how a slot that may not
+move sits out a decode step.
+
+One decode step is the recurrence's one position for every slot, and what it
+costs is the state: 327,680 bytes a slot and layer at 5,120 channels, read and
+written, against a few KB of operands. ``ssm_step`` is that pass as a kernel
+of its own: the grid is (block of slots, block of ``inner``), the state is the
+cache's whole leaf ``[layers, slots, N, inner]`` aliased in and out with the
+layer in the index map, so a step moves the one layer's states and nothing
+else of the leaf, and ``y`` comes out of the same pass. It writes the state at
+EVERY step. (The decoder-hybrid-decoder's own decode loop,
+``llm/model_runner.py:_hybrid_decode``, still computes its step in place with
+XLA's fusions.) Off the TPU both kernels run in interpret mode.
 """
 
 from __future__ import annotations
@@ -38,6 +48,11 @@ from jax.experimental.pallas import tpu as pltpu
 # the vector registers and is still ahead)
 CHUNK = 128
 BLOCK = 2560
+# slots a grid step of ``ssm_step`` walks (the sublanes of a float32 tile of
+# ``dt``, ``a`` and ``y`` rows) and the lanes it takes at a time: a block of
+# 8 slots x 16 x 2,560 float32 is 1.3 MB each way
+STEP_SLOTS = 8
+STEP_LANES = 512
 
 
 def selective_scan_reference(dt, a, Bm, Cm, A, s0):
@@ -116,3 +131,69 @@ def selective_scan(dt, a, Bm, Cm, A, s0=None):
         f32(s0),
         tpu=functools.partial(_ssm_scan, interpret=False),
         default=functools.partial(_ssm_scan, interpret=True))
+
+
+def _step_kernel(s_ref, dt_ref, a_ref, b_ref, c_ref, A_ref, y_ref, out_ref):
+    """A block of slots' block of lanes, each state read once and written
+    once: ``dt``, ``a`` [slots, W] rows, ``b``, ``c`` [slots, N, 1] columns."""
+    slots, _, W = s_ref.shape
+    lanes = STEP_LANES if W % STEP_LANES == 0 else W
+
+    def body(k, _):
+        at = pl.ds(pl.multiple_of(k * lanes, lanes), lanes)
+        A = A_ref[:, at]                                    # (N, lanes)
+        for j in range(slots):
+            dt = dt_ref[pl.ds(j, 1), at]                    # (1, lanes)
+            u = dt * a_ref[pl.ds(j, 1), at]
+            s = jnp.exp(A * dt) * s_ref[j, :, at] + b_ref[j] * u
+            out_ref[j, :, at] = s
+            y_ref[pl.ds(j, 1), at] = jnp.sum(s * c_ref[j], axis=0,
+                                             keepdims=True)
+        return _
+
+    jax.lax.fori_loop(0, W // lanes, body, 0)
+
+
+def _ssm_step(ssm, dt, a, b, c, A, *, layer: int, name: str,
+              interpret: bool):
+    _, B, N, inner = ssm.shape
+    G = STEP_SLOTS if B % STEP_SLOTS == 0 else B
+    W = BLOCK if inner % BLOCK == 0 else inner
+    row = pl.BlockSpec((G, W), lambda s, i: (s, i))
+    col = pl.BlockSpec((G, N, 1), lambda s, i: (s, 0, 0))
+    state = pl.BlockSpec((None, G, N, W), lambda s, i: (layer, s, 0, i))
+    return pl.pallas_call(
+        _step_kernel,
+        grid=(B // G, inner // W),
+        in_specs=[state, row, row, col, col,
+                  pl.BlockSpec((N, W), lambda s, i: (0, i))],
+        out_specs=[row, state],
+        out_shape=[jax.ShapeDtypeStruct((B, inner), jnp.float32),
+                   jax.ShapeDtypeStruct(ssm.shape, ssm.dtype)],
+        input_output_aliases={0: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name=name,
+    )(ssm, dt, a, b, c, A)
+
+
+def ssm_step(ssm, layer: int, dt, a, Bm, Cm, A, keep=None, *,
+             name: str = "ssm_step"):
+    """One position of ``selective_scan_reference`` for every slot, on layer
+    ``layer`` of ``ssm`` [layers, B, N, I] float32 (the KERNEL's layout),
+    which the caller hands over donated: dt (after softplus), a [B, I], Bm, Cm
+    [B, N], A [I, N], keep [B] (a slot it does not mark takes ``dt = 0`` and
+    keeps its state to the bit; None: all step) -> (y [B, I] float32, ``ssm``
+    with the layer's states as of this position). ``name``: the kernel's in
+    a device trace ("ssm_riding" where the step rides a prefill call)."""
+    f32 = lambda t: t.astype(jnp.float32)   # noqa: E731
+    dt = f32(dt)
+    if keep is not None:
+        dt = jnp.where(keep[:, None], dt, 0.0)
+    return jax.lax.platform_dependent(
+        ssm, dt, f32(a), f32(Bm)[..., None], f32(Cm)[..., None], f32(A).T,
+        tpu=functools.partial(_ssm_step, layer=layer, name=name,
+                              interpret=False),
+        default=functools.partial(_ssm_step, layer=layer, name=name,
+                                  interpret=True))

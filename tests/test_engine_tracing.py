@@ -66,6 +66,9 @@ KEYS = {
     # such a model's decode steps, and those of them that wrote the states
     # back (a step in FOLD, and every riding one); 0 without such layers
     "ssd_steps", "ssd_fold_steps",
+    # the slots whose Mamba-1 state a decode step moves, those of them that
+    # decode, and such a model's decode steps; 0 without such layers
+    "ssm_step_slots", "ssm_step_live_slots", "ssm_steps",
     # the same two for delta-rule layers, and the chunks their prefill
     # kernel's grid has and passes over; 0 without such layers
     "kda_step_slots", "kda_step_live_slots",
@@ -183,6 +186,8 @@ def test_metrics_complete_numeric_monotone(engine):
     # ``decode_steps`` and ``riding_steps <= ssd_fold_steps``:
     # ``tests/test_granite_hybrid.py``)
     assert m["ssd_steps"] == m["ssd_fold_steps"] == m["ssd_step_slots"] == 0
+    # nor a Mamba-1 layer (``tests/test_jamba.py``)
+    assert m["ssm_steps"] == m["ssm_step_slots"] == m["ssm_step_live_slots"] == 0
     assert m["retention_scan_chunks"] == m["retention_scan_chunks_skipped"] == 0
     # a prompt here fills its row's one query block, a padding row none
     assert (m["flash_q_blocks"] - m["flash_q_blocks_skipped"]
@@ -899,3 +904,43 @@ def test_decode_span_says_how_many_tokens_and_the_longest_gap(timed,
                                      abs=1e-5)  # doubles around 1.7e9 s
     assert "tokens" not in spans["a", "engine.prefill"]
     assert "max_gap_ms" not in spans["a", "engine.queued"]
+
+
+def test_mamba_kind_runs_under_its_two_scopes_and_kernel_names():
+    """The "mamba" kind of ``_forward`` (a two-layer model: Mamba, attention)
+    puts its prompt side under ``ssm.prefill`` and its step side under
+    ``ssm.step`` (the names the decoder-hybrid-decoder's loops use), around
+    kernels a device trace finds by name: ``ssm_scan``; ``ssm_step`` in a
+    decode step alone, ``ssm_riding`` where the step rides a prefill call."""
+    import dataclasses
+
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    import test_jamba
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.models.transformer import CONFIGS, Transformer
+
+    published = dict(test_jamba.PUBLISHED, num_hidden_layers=2,
+                     attn_layer_period=2, attn_layer_offset=1)
+    cfg = dataclasses.replace(CONFIGS["tiny"], **dict(
+        test_jamba.ref.program_overrides(published, 64), dtype=jnp.float32,
+        remat=False))
+    assert cfg.layer_kinds == ("mamba", "full")
+    B, MP, P = 3, 4, 16
+    toks = jnp.zeros((1, BUCKET), jnp.int32)
+    params = jax.eval_shape(lambda: nn.meta.unbox(Transformer(cfg).init(
+        jax.random.PRNGKey(0), toks)))
+    cache = jax.eval_shape(lambda: mr.init_cache(cfg, 1 + B * MP, P, B))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)   # noqa: E731
+    step = (i32(B), i32(B), i32(B, MP), jax.ShapeDtypeStruct((B,), jnp.bool_))
+    decode = mr.decode_step.lower(params, cfg, cache, *step).as_text(
+        debug_info=True)
+    assert "ssm.step" in decode and "ssm.prefill" not in decode
+    assert "ssm_step" in decode
+    assert "ssm_riding" not in decode and "ssm_scan" not in decode
+    call = mr.prefill.lower(params, cfg, cache, i32(1, BUCKET), i32(1),
+                            i32(1, MP), i32(1), step).as_text(debug_info=True)
+    for name in ("ssm.prefill", "ssm.step", "ssm_scan", "ssm_riding"):
+        assert name in call, name
